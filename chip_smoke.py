@@ -1,0 +1,761 @@
+#!/usr/bin/env python3
+"""Does ray_tpu still start on the chip? The quickest end-to-end proof.
+
+    python3 chip_smoke.py                # the real run: needs a TPU
+    python3 chip_smoke.py --rehearse     # CPU, tiny model, kernels interpreted
+
+Drives the two main paths once, through the entry points a user calls, at
+the full width of the 953M Llama-shaped config both benches use (hidden
+2048, 16 layers, 16 heads of 128, vocab 32,000; random weights from a
+seed), and checks what comes out by the repo's own means:
+
+* ``kernels``   flash attention forward/backward, dense and paged decode
+                attention (bf16 and int8 arena), COMPILED, against their
+                references within the tolerances in ``TOLERANCE``;
+* ``train``     ``ShardedTrainer`` on one device, batch 5 x 2048: loss
+                finite and falling, one compiled signature, the Mosaic
+                custom calls present in the compiled step;
+* ``serve``     ``ray_tpu.init()`` -> ``serve.run(build_continuous_llama_
+                app(...))`` -> ``serve.start_http()`` -> concurrent HTTP
+                requests, unary and streamed, over two prefill buckets;
+* ``multichip`` (four or more devices; otherwise printed as skipped) the
+                train step on an ``fsdp=4`` mesh against the one-chip loss,
+                and four one-chip replicas behind the router.
+
+This parent process never initialises JAX: a process that has touched JAX
+holds the chip, and a child that needs it then fails or hangs. Each phase
+is a child that owns the chip for its duration, so a failing phase is
+named and cannot leave the chip held. Any phase failing fails the run:
+the exit code is non-zero and no result line is printed. On success the
+last line of standard output is one JSON object,
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+
+``--rehearse`` runs the same control flow at ``LlamaConfig.tiny()`` under
+``JAX_PLATFORMS=cpu`` with Pallas kernels in interpret mode. It says so in
+its output, proves nothing about the chip, and is what to run before
+spending chip time.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import signal
+import subprocess
+import sys
+import threading
+import time
+
+PHASES = ("kernels", "train", "serve", "multichip")
+# The multichip phase is two children: the trainer's state must be gone
+# from the chips before four serving replicas load theirs.
+CHILDREN = {"kernels": ("kernels",), "train": ("train",),
+            "serve": ("serve",),
+            "multichip": ("multichip-train", "multichip-serve")}
+PHASE_TIMEOUT_S = {"kernels": 420, "train": 480, "serve": 600,
+                   "multichip-train": 900, "multichip-serve": 900}
+RESULT_TAG = "PHASE_RESULT "
+NO_ACCELERATOR_RC = 3    # a child found no TPU: no later phase can pass
+REHEARSAL_BANNER = (
+    "REHEARSAL: LlamaConfig.tiny() on JAX_PLATFORMS=cpu with Pallas "
+    "kernels INTERPRETED. This checks control flow only and says nothing "
+    "about the chip.")
+
+# Largest |kernel - reference| allowed, as a fraction of the reference's
+# largest magnitude. Inputs and outputs are bf16 (8 significand bits:
+# one rounding is 2^-9 relative); the references run in fp32 at
+# matmul precision "highest" on the same bf16 inputs. Gradients pass
+# through two more bf16 roundings (dO, and the recomputed P) than the
+# forward. The chip run prints what was measured next to each bound.
+TOLERANCE = {"flash_fwd": 1e-2, "flash_bwd": 2e-2,
+             "decode_bf16": 1e-2, "decode_int8": 1e-2}
+# fsdp=4 vs one-chip first-step loss: the same bf16 model, sums reduced
+# across four devices in another order.
+LOSS_RTOL = 5e-3
+MIN_CHIPS_MULTI = 4
+
+
+# ---------------------------------------------------------------- parent
+def _run_child(phase: str, rehearse: bool, env: dict):
+    """Run one phase in its own process group; echo its output; return
+    (exit code, result dict or None)."""
+    cmd = [sys.executable, os.path.abspath(__file__), "--phase", phase]
+    if rehearse:
+        cmd.append("--rehearse")
+    proc = subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE,
+                            stderr=subprocess.STDOUT, text=True,
+                            start_new_session=True)
+
+    def kill_group():
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+
+    timer = threading.Timer(PHASE_TIMEOUT_S[phase], kill_group)
+    timer.start()
+    result = None
+    try:
+        for line in proc.stdout:
+            if line.startswith(RESULT_TAG):
+                result = json.loads(line[len(RESULT_TAG):])
+            else:
+                sys.stdout.write(line)
+                sys.stdout.flush()
+        rc = proc.wait()
+    finally:
+        timer.cancel()
+        kill_group()   # the phase is over: nothing it started survives it
+    return rc, result
+
+
+def _parent(phases, rehearse: bool) -> int:
+    env = dict(os.environ)
+    if rehearse:
+        print(REHEARSAL_BANNER, flush=True)
+        env["JAX_PLATFORMS"] = "cpu"
+        env["RAY_TPU_PALLAS_INTERPRET"] = "1"
+        flags = [f for f in env.get("XLA_FLAGS", "").split()
+                 if "xla_force_host_platform_device_count" not in f]
+        env["XLA_FLAGS"] = " ".join(
+            flags + ["--xla_force_host_platform_device_count=8"])
+    device = None
+    failed = []
+    t_all = time.monotonic()
+    for phase in phases:
+        if phase == "multichip" and device is not None \
+                and device["count"] < MIN_CHIPS_MULTI:
+            print(f"[multichip] skipped: {device['count']} device(s) "
+                  f"present, {MIN_CHIPS_MULTI} needed", flush=True)
+            continue
+        for child in CHILDREN[phase]:
+            t0 = time.monotonic()
+            rc, result = _run_child(child, rehearse, env)
+            dt = time.monotonic() - t0
+            ok = rc == 0 and result is not None and result.get("ok")
+            print(f"[{child}] {'PASSED' if ok else 'FAILED'} in {dt:.0f}s "
+                  f"(exit code {rc})", flush=True)
+            if not ok:
+                failed.append(child)
+                if rc == NO_ACCELERATOR_RC:
+                    print("[chip_smoke] FAILED: no accelerator", flush=True)
+                    return 1
+                continue
+            device = device or result["device"]
+    print(f"[chip_smoke] total {time.monotonic() - t_all:.0f}s", flush=True)
+    if failed or device is None:
+        print(f"[chip_smoke] FAILED: {', '.join(failed) or 'no phase ran'}",
+              flush=True)
+        return 1
+    out = {"ok": True, "device": device}
+    if rehearse:
+        out["rehearsal"] = True
+        print(REHEARSAL_BANNER)
+    print(json.dumps(out), flush=True)
+    return 0
+
+
+# --------------------------------------------------------------- children
+def _say(phase: str, msg: str) -> None:
+    print(f"[{phase}] {msg}", flush=True)
+
+
+def _open_device(phase: str, rehearse: bool) -> dict:
+    """First touch of JAX in a child: place the compile cache, report the
+    device, and refuse to go on without an accelerator."""
+    import jax
+
+    from ray_tpu.util import compile_cache
+
+    cache_dir = compile_cache.ensure()
+    devices = jax.devices()
+    info = {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+    _say(phase, f"platform: {info['platform']}, device_kind: "
+                f"{info['kind']}, devices: {info['count']}")
+    _say(phase, f"compile cache: {cache_dir}")
+    if rehearse:
+        _say(phase, REHEARSAL_BANNER)
+        if info["platform"] != "cpu":
+            raise SystemExit(f"[{phase}] --rehearse must run on the CPU "
+                             f"backend, got {info['platform']}")
+    elif info["platform"] != "tpu":
+        _say(phase, f"no accelerator: JAX reports platform "
+                    f"{info['platform']!r}; this check only counts on a "
+                    "TPU (use --rehearse for a CPU dry run)")
+        sys.exit(NO_ACCELERATOR_RC)
+    return info
+
+
+def _finish(phase: str, info: dict, **extra) -> None:
+    from ray_tpu.util import compile_cache
+
+    counts = compile_cache.counts()
+    _say(phase, f"persistent compile cache: {counts['hits']} hits, "
+                f"{counts['misses']} misses")
+    print(RESULT_TAG + json.dumps(
+        {"ok": True, "device": info, "cache": counts, **extra}), flush=True)
+
+
+def _full_config(**kw):
+    from ray_tpu.models import llama
+
+    return llama.LlamaConfig(
+        vocab_size=32000, hidden_size=2048, intermediate_size=5632,
+        num_layers=16, num_heads=16, num_kv_heads=16, head_dim=128,
+        max_seq_len=2048, **kw)
+
+
+def _mosaic_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _check(phase: str, name: str, got, ref, bound: float) -> None:
+    import numpy as np
+
+    assert got.shape == ref.shape and got.dtype == ref.dtype, name
+    same = bool(np.array_equal(np.asarray(got), np.asarray(ref)))
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    assert np.isfinite(got).all(), f"{name}: non-finite kernel output"
+    err = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+    _say(phase, f"{name}: max|kernel-ref|/max|ref| = {err:.2e} "
+                f"(bound {bound:.0e}); bit-identical: {same}")
+    assert err <= bound, f"{name}: {err:.3e} exceeds {bound:.0e}"
+
+
+def phase_kernels(rehearse: bool) -> None:
+    phase = "kernels"
+    info = _open_device(phase, rehearse)
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.paged_kv import quantize_kv
+    from ray_tpu.ops.attention import (flash_applicable, flash_attention,
+                                       mha_reference)
+    from ray_tpu.ops.decode_attention import (_interpret_default,
+                                              decode_attention,
+                                              decode_attention_reference)
+    from ray_tpu.ops.paged_decode_attention import (
+        paged_attention_reference, paged_decode_attention)
+
+    if not rehearse:
+        assert "RAY_TPU_PALLAS_INTERPRET" not in os.environ, \
+            "RAY_TPU_PALLAS_INTERPRET is set: kernels would not compile"
+        assert _interpret_default() is False
+    bf16 = jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(0), 12)
+
+    # -- flash attention, forward and backward -----------------------------
+    b, s, h, d = (1, 256, 2, 128) if rehearse else (2, 2048, 16, 128)
+    q, k, v, w = (jax.random.normal(keys[i], (b, s, h, d), jnp.float32)
+                  .astype(bf16) for i in range(4))
+    assert flash_applicable(s, s, d)
+
+    def flash_loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32) * w.astype(jnp.float32))
+
+    def ref_loss(q, k, v):
+        return jnp.sum(mha_reference(q, k, v, causal=True)
+                       .astype(jnp.float32) * w.astype(jnp.float32))
+
+    fwd = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
+    bwd = jax.jit(jax.grad(flash_loss, argnums=(0, 1, 2)))
+    if not rehearse:
+        n_fwd = _mosaic_calls(fwd.lower(q, k, v).compile())
+        n_bwd = _mosaic_calls(bwd.lower(q, k, v).compile())
+        _say(phase, f"flash: {n_fwd} Mosaic call(s) forward, {n_bwd} in "
+                    "the gradient program")
+        assert n_fwd >= 1 and n_bwd >= 3
+    with jax.default_matmul_precision("highest"):
+        ref_out = jax.jit(
+            lambda q, k, v: mha_reference(q, k, v, causal=True))(q, k, v)
+        ref_g = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v)
+    _check(phase, f"flash forward [{b},{s},{h},{d}] bf16",
+           fwd(q, k, v), ref_out, TOLERANCE["flash_fwd"])
+    for name, g, rg in zip(("dq", "dk", "dv"), bwd(q, k, v), ref_g):
+        _check(phase, f"flash backward {name}", g, rg,
+               TOLERANCE["flash_bwd"])
+
+    # -- decode attention: dense, paged bf16, paged int8 -------------------
+    shapes = [(4, 4, 2, 32, 4)] if rehearse else \
+        [(32, 16, 16, 64, 8), (32, 32, 8, 64, 8)]   # MHA, then GQA
+    for slots, hq, hkv, bs, nb in shapes:
+        tag = f"{slots} slots, {hq}/{hkv} heads, block {bs}"
+        s_max = bs * nb
+        qd = jax.random.normal(keys[4], (slots, hq, d), jnp.float32
+                               ).astype(bf16)
+        positions = jax.random.randint(keys[5], (slots,), 0, s_max)
+        positions = positions.at[0].set(0).at[1].set(s_max - 1)
+        ck, cv = (jax.random.normal(keys[6 + i], (slots, s_max, hkv, d),
+                                    jnp.float32).astype(bf16)
+                  for i in range(2))
+        # The same K/V as a scrambled heads-major arena + block tables.
+        perm = jax.random.permutation(keys[8], slots * nb) + 1
+        tables = perm.reshape(slots, nb).astype(jnp.int32)
+
+        def to_arena(c):
+            blocks = c.reshape(slots * nb, bs, hkv, d).swapaxes(1, 2)
+            arena = jnp.zeros((slots * nb + 1, hkv, bs, d), c.dtype)
+            return arena.at[perm].set(blocks)
+
+        ak, av = to_arena(ck), to_arena(cv)
+        kq, ks = quantize_kv(ak)
+        vq, vs = quantize_kv(av)
+
+        dense = jax.jit(lambda *a: decode_attention(*a, use_kernel=True))
+        paged = jax.jit(
+            lambda *a: paged_decode_attention(*a, use_kernel=True))
+        paged8 = jax.jit(lambda q, k, v, t, p, ks, vs: paged_decode_attention(
+            q, k, v, t, p, k_scale=ks, v_scale=vs, use_kernel=True))
+        if not rehearse:
+            for fn, args in ((dense, (qd, ck, cv, positions)),
+                             (paged, (qd, ak, av, tables, positions)),
+                             (paged8, (qd, kq, vq, tables, positions,
+                                       ks, vs))):
+                assert _mosaic_calls(fn.lower(*args).compile()) == 1
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(decode_attention_reference)(qd, ck, cv, positions)
+            ref8 = jax.jit(
+                lambda q, k, v, t, p, ks, vs: paged_attention_reference(
+                    q, k, v, t, p, k_scale=ks, v_scale=vs))(
+                qd, kq, vq, tables, positions, ks, vs)
+            pref = jax.jit(paged_attention_reference)(
+                qd, ak, av, tables, positions)
+        _check(phase, f"dense decode ({tag})",
+               dense(qd, ck, cv, positions), ref, TOLERANCE["decode_bf16"])
+        _check(phase, f"paged decode bf16 ({tag})",
+               paged(qd, ak, av, tables, positions), pref,
+               TOLERANCE["decode_bf16"])
+        _check(phase, f"paged decode int8 ({tag})",
+               paged8(qd, kq, vq, tables, positions, ks, vs), ref8,
+               TOLERANCE["decode_int8"])
+    _finish(phase, info)
+
+
+def _train_once(phase, config, mesh, batch_size, seq_len, steps, rehearse):
+    """Init + ``steps`` steps on one repeated batch. Returns (losses,
+    trainer, state)."""
+    import jax
+
+    from ray_tpu.models.training import (ShardedTrainer, default_optimizer,
+                                         synthetic_batch)
+
+    trainer = ShardedTrainer(
+        config, mesh,
+        # One warm-up step (its learning rate is 0), then full rate, so a
+        # handful of steps is enough to see the loss move.
+        optimizer=default_optimizer(learning_rate=3e-4, warmup_steps=1,
+                                    total_steps=100))
+    t0 = time.perf_counter()
+    state = trainer.init_state(0)
+    batch = trainer.shard_batch(
+        synthetic_batch(batch_size, seq_len, config.vocab_size))
+    jax.block_until_ready(state.params)
+    _say(phase, f"state initialised in {time.perf_counter() - t0:.1f}s")
+    losses = []
+    for i in range(steps):
+        t0 = time.perf_counter()
+        state, metrics = trainer.train_step(state, batch)
+        losses.append(float(metrics["loss"]))
+        _say(phase, f"step {i}: loss {losses[-1]:.4f} "
+                    f"({time.perf_counter() - t0:.2f}s"
+                    f"{', compile included' if i == 0 else ''})")
+    assert trainer._step._cache_size() == 1, \
+        f"{trainer._step._cache_size()} compiled signatures, expected 1"
+    (compiled,) = trainer._step._compiled.values()
+    n_calls = _mosaic_calls(compiled)
+    _say(phase, f"compiled train step holds {n_calls} Mosaic custom "
+                "call(s)")
+    if not rehearse:
+        assert n_calls >= 3, "flash attention gave way to mha_reference"
+    return losses, trainer, state
+
+
+def _print_memory(phase: str) -> None:
+    import jax
+
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        if stats:
+            _say(phase, f"device {dev.id}: bytes_in_use "
+                        f"{stats.get('bytes_in_use', 0) / 2**30:.2f} GiB, "
+                        f"peak {stats.get('peak_bytes_in_use', 0) / 2**30:.2f}"
+                        f" GiB of {stats.get('bytes_limit', 0) / 2**30:.2f}")
+
+
+def phase_train(rehearse: bool) -> None:
+    phase = "train"
+    info = _open_device(phase, rehearse)
+    import math
+
+    import jax
+
+    from ray_tpu.models import llama
+    from ray_tpu.parallel import MeshConfig, make_mesh
+
+    if rehearse:
+        config = llama.LlamaConfig.tiny()
+        batch_size, seq_len = 4, 64
+    else:
+        config = _full_config(remat=True)
+        batch_size, seq_len = 5, 2048
+    _say(phase, f"{llama.num_params(config) / 1e6:.0f}M parameters, batch "
+                f"{batch_size} x {seq_len}, one-device mesh")
+    mesh = make_mesh(MeshConfig(fsdp=-1), devices=jax.devices()[:1])
+    losses, _, _ = _train_once(phase, config, mesh, batch_size, seq_len,
+                               steps=5, rehearse=rehearse)
+    assert all(math.isfinite(x) for x in losses), losses
+    assert losses[-1] < losses[0], f"loss did not fall: {losses}"
+    _print_memory(phase)
+    _finish(phase, info, losses=losses)
+
+
+# ------------------------------------------------------------ serve phases
+DEPLOYMENT = "ContinuousLlamaDeployment"
+
+
+def _post(port: int, path: str, payload: dict, timeout: float = 300.0):
+    import http.client
+
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        conn.request("POST", path, body=json.dumps(payload),
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        body = resp.read()
+        if resp.status != 200:
+            raise RuntimeError(f"POST {path}: HTTP {resp.status}: "
+                               f"{body[:300]!r}")
+        return body
+    finally:
+        conn.close()
+
+
+def _complete(port: int, prompt, max_tokens: int, streamed: bool):
+    payload = {"prompt_token_ids": prompt, "max_tokens": max_tokens}
+    if streamed:
+        body = _post(port, f"/{DEPLOYMENT}/stream/generate", payload)
+        items = [json.loads(line) for line in body.splitlines() if line]
+        return [t for t in items if isinstance(t, int)]
+    return json.loads(_post(port, f"/{DEPLOYMENT}", payload))["token_ids"]
+
+
+def _wave(port: int, requests, max_tokens: int):
+    """Send every (prompt, streamed) request concurrently; returns the
+    token lists in order. Any failed request raises."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    with ThreadPoolExecutor(max_workers=len(requests)) as pool:
+        futs = [pool.submit(_complete, port, p, max_tokens, s)
+                for p, s in requests]
+        return [f.result() for f in futs]
+
+
+def _replicas(n: int, timeout_s: float = 240.0):
+    """Wait until the controller routes ``n`` replicas that answer a
+    health call; return their actor handles."""
+    import ray_tpu
+
+    controller = ray_tpu.get_actor("__serve_controller__")
+    deadline = time.monotonic() + timeout_s
+    last = None
+    while time.monotonic() < deadline:
+        reps = ray_tpu.get(controller.get_replicas.remote(DEPLOYMENT),
+                           timeout=30)
+        if len(reps) == n:
+            try:
+                for r in reps:
+                    ray_tpu.get(r.health.remote(), timeout=5)
+                return reps
+            except Exception as e:  # noqa: BLE001 — constructing, or dead
+                last = e
+        time.sleep(0.5)
+    raise AssertionError(
+        f"{n} healthy replica(s) of {DEPLOYMENT} never came up in "
+        f"{timeout_s:.0f}s; the last health call said: {last!r}")
+
+
+def _engine_info(replica) -> dict:
+    import ray_tpu
+
+    return ray_tpu.get(
+        replica.handle_request.remote("engine_info", (), {}), timeout=60)
+
+
+def _serve_setup(phase, rehearse, num_replicas):
+    """init -> serve.run -> start_http, as a user would. Returns
+    (port, replicas, config, sizes)."""
+    import jax
+
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
+    from ray_tpu.llm import build_continuous_llama_app
+    from ray_tpu.models import llama
+
+    if rehearse:
+        # The sandbox has no chips to detect: name as many as the phase
+        # needs so replica placement runs its real path on CPU devices.
+        ray_tpu.init(num_tpus=num_replicas)
+        config = llama.LlamaConfig.tiny()
+        sizes = {"num_slots": 4, "max_len": 128, "block_size": 16,
+                 "new": 8, "short": (5, 12), "long": (20, 40)}
+    else:
+        detected = TPUAcceleratorManager.detect_num_chips()
+        _say(phase, f"chips detected without JAX: {detected}; JAX sees "
+                    f"{len(jax.devices())}")
+        assert detected == len(jax.devices()), \
+            "chip detection disagrees with JAX"
+        ray_tpu.init()
+        config = _full_config()
+        sizes = {"num_slots": 32, "max_len": 512, "block_size": 64,
+                 "new": 32, "short": (20, 50), "long": (100, 200)}
+    _say(phase, f"runtime resources: {ray_tpu.cluster_resources()}")
+    t0 = time.perf_counter()
+    serve.run(build_continuous_llama_app(
+        config=config, num_replicas=num_replicas,
+        num_slots=sizes["num_slots"], max_len=sizes["max_len"],
+        block_size=sizes["block_size"]))
+    port = serve.start_http(port=0)
+    replicas = _replicas(num_replicas)
+    _say(phase, f"{num_replicas} replica(s) up in "
+                f"{time.perf_counter() - t0:.1f}s, HTTP on port {port}")
+    return port, replicas, config, sizes
+
+
+def _serve_teardown() -> None:
+    import ray_tpu
+    from ray_tpu import serve
+
+    serve.stop_http()
+    serve.shutdown()
+    ray_tpu.shutdown()
+
+
+def _prompts(config, lengths, seed):
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, config.vocab_size, n).tolist() for n in lengths]
+
+
+def _native_planes(phase: str) -> None:
+    """Which shared-memory store and channel this process got: the
+    native (g++-built) ones, or the pure-Python fallback."""
+    from ray_tpu._private.native_build import native_lib_path
+
+    for name in ("shm_store", "shm_channel"):
+        path = native_lib_path(name)
+        _say(phase, f"{name}: " + (f"native ({path})" if path else
+                                   "pure-Python fallback (g++ build failed "
+                                   "or RAY_TPU_DISABLE_NATIVE set)"))
+
+
+def phase_serve(rehearse: bool) -> None:
+    phase = "serve"
+    info = _open_device(phase, rehearse)
+    from ray_tpu._private import xla_monitor
+
+    try:
+        port, (replica,), config, sz = _serve_setup(phase, rehearse, 1)
+        _native_planes(phase)
+        # Short prompts fit the first prefill bucket and never match the
+        # prefix cache (under one block), so a resend takes the SAME
+        # path and must return the same tokens. Long prompts reach two
+        # more buckets; resent, they take the prefix-hit path, whose
+        # agreement with the cold path is reported, not required (a
+        # random model's logits are near-flat: an ulp flips the argmax).
+        short = _prompts(config, [sz["short"][0]] * 2 + [sz["short"][1]] * 2,
+                         seed=1)
+        long_ = _prompts(config, [sz["long"][0]] * 2 + [sz["long"][1]] * 2,
+                         seed=2)
+        requests = ([(p, False) for p in short] + [(p, True) for p in short]
+                    + [(p, i % 2 == 0) for i, p in enumerate(long_)])
+        t0 = time.perf_counter()
+        first = _wave(port, requests, sz["new"])
+        _say(phase, f"wave 1 (warm-up): {len(requests)} concurrent requests"
+                    f" in {time.perf_counter() - t0:.1f}s")
+        warm = xla_monitor.program_stats("cb_tick")
+        t0 = time.perf_counter()
+        second = _wave(port, requests, sz["new"])
+        _say(phase, f"wave 2: {len(requests)} concurrent requests in "
+                    f"{time.perf_counter() - t0:.1f}s")
+        for toks in first + second:
+            assert len(toks) == sz["new"], (len(toks), sz["new"])
+            assert all(0 <= t < config.vocab_size for t in toks)
+        n = len(short)
+        for i in range(n):
+            assert first[i] == first[n + i] == second[i] == second[n + i], \
+                f"short prompt {i}: the same prompt returned other tokens"
+        agree = sum(a == b for a, b in zip(first[2 * n:], second[2 * n:]))
+        _say(phase, f"same prompt, same tokens: {n} short prompts x 4 "
+                    f"sends identical; prefix-hit resend identical to the "
+                    f"cold send for {agree} of {len(long_)} long prompts")
+        after = xla_monitor.program_stats("cb_tick")
+        _say(phase, f"cb_tick: {after['compiles']} compile(s), "
+                    f"{after['retraces']} retrace(s), "
+                    f"{after['compile_seconds']:.1f}s compiling; cb_prefill:"
+                    f" {xla_monitor.program_stats('cb_prefill')['compiles']}"
+                    " bucket program(s)")
+        assert after["compiles"] == warm["compiles"] == 1, (warm, after)
+        eng = _engine_info(replica)
+        _say(phase, f"engine: use_decode_kernel={eng['use_decode_kernel']},"
+                    f" paged={eng['paged']}, kv_dtype={eng['kv_dtype']}, "
+                    f"device {eng['device']}")
+        assert eng["paged"] is True
+        if not rehearse:
+            assert eng["use_decode_kernel"] is True
+            assert eng["device"]["platform"] == "tpu"
+        _print_memory(phase)
+    finally:
+        _serve_teardown()
+    _finish(phase, info)
+
+
+def phase_multichip_train(rehearse: bool) -> None:
+    phase = "multichip-train"
+    info = _open_device(phase, rehearse)
+    import math
+
+    import jax
+
+    from ray_tpu.models import llama
+    from ray_tpu.parallel import MeshConfig, make_mesh
+
+    devices = jax.devices()[:MIN_CHIPS_MULTI]
+    assert len(devices) == MIN_CHIPS_MULTI, len(devices)
+    if rehearse:
+        config = llama.LlamaConfig.tiny()
+        batch_size, seq_len = 4, 64
+    else:
+        config = _full_config(remat=True)
+        batch_size, seq_len = 4, 2048
+    mesh1 = make_mesh(MeshConfig(fsdp=-1), devices=devices[:1])
+    losses1, trainer, state = _train_once(
+        phase, config, mesh1, batch_size, seq_len, steps=1,
+        rehearse=rehearse)
+    del trainer, state
+    mesh4 = make_mesh(MeshConfig(fsdp=MIN_CHIPS_MULTI), devices=devices)
+    _say(phase, f"fsdp={MIN_CHIPS_MULTI} mesh device ids: "
+                f"{[d.id for d in mesh4.devices.flat]}")
+    losses4, trainer, state = _train_once(
+        phase, config, mesh4, batch_size, seq_len, steps=3,
+        rehearse=rehearse)
+    # Spread, not replicated and not all on device 0: every parameter
+    # and every optimizer moment has four shards on four devices, each a
+    # quarter of the whole.
+    leaves = jax.tree.leaves((state.params, state.opt_state))
+    sharded = 0
+    for leaf in leaves:
+        if leaf.ndim == 0:
+            continue          # step counters are replicated scalars
+        shards = leaf.addressable_shards
+        assert len({s.device.id for s in shards}) == MIN_CHIPS_MULTI
+        assert all(s.data.size * MIN_CHIPS_MULTI == leaf.size
+                   for s in shards), (leaf.shape, shards[0].data.shape)
+        sharded += 1
+    _say(phase, f"{sharded} parameter/optimizer arrays, each in "
+                f"{MIN_CHIPS_MULTI} quarter shards on {MIN_CHIPS_MULTI} "
+                "distinct devices")
+    rel = abs(losses4[0] - losses1[0]) / abs(losses1[0])
+    _say(phase, f"first-step loss: one chip {losses1[0]:.5f}, fsdp="
+                f"{MIN_CHIPS_MULTI} {losses4[0]:.5f} (relative difference "
+                f"{rel:.1e}, bound {LOSS_RTOL:.0e})")
+    assert rel <= LOSS_RTOL
+    assert all(math.isfinite(x) for x in losses4)
+    assert losses4[-1] < losses4[0], f"loss did not fall: {losses4}"
+    _print_memory(phase)
+    _finish(phase, info, losses=losses4)
+
+
+def phase_multichip_serve(rehearse: bool) -> None:
+    phase = "multichip-serve"
+    info = _open_device(phase, rehearse)
+    import ray_tpu
+
+    n = MIN_CHIPS_MULTI
+    try:
+        port, replicas, config, sz = _serve_setup(phase, rehearse, n)
+        prompts = _prompts(config, [sz["short"][0]] * n, seed=3)
+        infos = []
+        for i, (rep, prompt) in enumerate(zip(replicas, prompts)):
+            # One request straight to each replica: all four must answer.
+            out = ray_tpu.get(rep.handle_request.remote(
+                "__call__", ({"prompt_token_ids": prompt,
+                              "max_tokens": sz["new"]},), {}), timeout=300)
+            assert len(out["token_ids"]) == sz["new"]
+            eng = _engine_info(rep)
+            infos.append(eng)
+            mem = eng["memory_stats"] or {}
+            _say(phase, f"replica {i}: device {eng['device']}, params on "
+                        f"{eng['params_device_ids']}, arena on "
+                        f"{eng['arena_device_ids']}, bytes_in_use "
+                        f"{mem.get('bytes_in_use', 0) / 2**30:.2f} GiB, "
+                        f"use_decode_kernel={eng['use_decode_kernel']}")
+            assert eng["params_device_ids"] == [eng["device"]["id"]]
+            assert eng["arena_device_ids"] == [eng["device"]["id"]]
+        ids = [e["device"]["id"] for e in infos]
+        assert len(set(ids)) == n, f"replicas share a chip: {ids}"
+        # Then through the router, as clients would.
+        reqs = [(p, i % 2 == 0) for i, p in
+                enumerate(_prompts(config, [sz["short"][1]] * 2 * n, seed=4))]
+        outs = _wave(port, reqs, sz["new"])
+        assert all(len(t) == sz["new"] for t in outs)
+        _say(phase, f"{len(reqs)} routed HTTP requests answered")
+        _print_memory(phase)
+    finally:
+        _serve_teardown()
+    _finish(phase, info)
+
+
+def _child(phase: str, rehearse: bool) -> int:
+    """Run one phase and leave at once. A phase that is stuck says where
+    (every thread's stack) shortly before the parent would kill it; the
+    hard exit skips interpreter teardown, where a wedged runtime thread
+    could sit on the chip until that kill."""
+    import faulthandler
+    import traceback
+
+    faulthandler.dump_traceback_later(PHASE_TIMEOUT_S[phase] - 20, exit=True)
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    code = 0
+    try:
+        CHILD_FNS[phase](rehearse)
+    except SystemExit as e:
+        code = e.code if isinstance(e.code, int) else 1
+    except BaseException:  # noqa: BLE001 — report, then leave
+        traceback.print_exc()
+        code = 1
+    sys.stdout.flush()
+    sys.stderr.flush()
+    os._exit(code)
+
+
+CHILD_FNS = {"kernels": phase_kernels, "train": phase_train,
+             "serve": phase_serve, "multichip-train": phase_multichip_train,
+             "multichip-serve": phase_multichip_serve}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU dry run at LlamaConfig.tiny(), kernels "
+                         "interpreted; proves nothing about the chip")
+    ap.add_argument("--phases", default=",".join(PHASES),
+                    help="comma-separated subset of: " + ", ".join(PHASES))
+    ap.add_argument("--phase", choices=sorted(CHILD_FNS),
+                    help=argparse.SUPPRESS)   # a child of this script
+    args = ap.parse_args()
+    if args.phase:
+        return _child(args.phase, args.rehearse)
+    phases = [p.strip() for p in args.phases.split(",") if p.strip()]
+    unknown = sorted(set(phases) - set(PHASES))
+    if unknown:
+        ap.error(f"unknown phase(s): {unknown}")
+    return _parent(phases, args.rehearse)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

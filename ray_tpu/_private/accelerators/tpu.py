@@ -12,8 +12,9 @@ ICI-connected chips.
 
 from __future__ import annotations
 
+import glob
 import os
-import sys
+import re
 from typing import Dict, List, Optional
 
 VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
@@ -21,8 +22,41 @@ NUM_CHIPS_OVERRIDE_ENV = "RAY_TPU_NUM_CHIPS"
 ACCELERATOR_TYPE_ENV = "TPU_ACCELERATOR_TYPE"  # e.g. "v5litepod-256"
 WORKER_ID_ENV = "TPU_WORKER_ID"
 
-# chips per host for known generations (host = TPU VM).
-_CHIPS_PER_HOST = {"v2": 4, "v3": 4, "v4": 4, "v5litepod": 8, "v5p": 4, "v6e": 8}
+# Generations whose accelerator-type suffix counts CHIPS (one core per
+# chip) vs TensorCores (two per chip). A host (TPU VM) holds up to 8 chips
+# of the former when the whole slice fits one host, else 4; always up to
+# 4 of the latter.
+_SUFFIX_COUNTS_CHIPS = ("v5litepod", "v6e")
+_SUFFIX_COUNTS_CORES = ("v2", "v3", "v4", "v5p")
+
+
+def _chip_device_files() -> List[str]:
+    """The chips the kernel exposes to this host: one ``/dev/accel<N>``
+    each under the accel driver, one numbered ``/dev/vfio/<N>`` group
+    each under VFIO. Looking costs nothing and holds nothing — unlike
+    initialising a JAX backend, which takes every chip for this process."""
+    accel = glob.glob("/dev/accel[0-9]*")
+    if accel:
+        return accel
+    return [p for p in glob.glob("/dev/vfio/*")
+            if os.path.basename(p).isdigit()]
+
+
+def _chips_per_host(acc_type: str) -> int:
+    m = re.fullmatch(r"([a-z0-9]+)-(\d+)", acc_type.strip().lower())
+    if m is None:
+        raise ValueError(
+            f"cannot parse {ACCELERATOR_TYPE_ENV}={acc_type!r} "
+            "(expected <generation>-<count>, e.g. v5litepod-4)")
+    gen, count = m.group(1), int(m.group(2))
+    if gen in _SUFFIX_COUNTS_CHIPS:
+        return count if count <= 8 else 4
+    if gen in _SUFFIX_COUNTS_CORES:
+        return min(max(count // 2, 1), 4)
+    raise ValueError(
+        f"unknown TPU generation {gen!r} in {ACCELERATOR_TYPE_ENV}="
+        f"{acc_type!r}; set {NUM_CHIPS_OVERRIDE_ENV} to the host's chip "
+        "count")
 
 
 class TPUAcceleratorManager:
@@ -33,24 +67,21 @@ class TPUAcceleratorManager:
 
     @staticmethod
     def detect_num_chips() -> int:
-        """Number of TPU chips visible to this host, without importing jax
-        unless it is already loaded."""
+        """Number of TPU chips visible to this host. Never touches JAX:
+        a process that initialises the backend to count chips owns them
+        all, and the workers it then starts find none."""
         override = os.environ.get(NUM_CHIPS_OVERRIDE_ENV)
         if override is not None:
             return int(override)
         visible = os.environ.get(VISIBLE_CHIPS_ENV)
         if visible:
             return len([c for c in visible.split(",") if c != ""])
-        if "jax" in sys.modules:
-            try:
-                jax = sys.modules["jax"]
-                return len([d for d in jax.devices() if d.platform != "cpu"])
-            except Exception:
-                pass
+        files = _chip_device_files()
+        if files:
+            return len(files)
         acc_type = os.environ.get(ACCELERATOR_TYPE_ENV)
         if acc_type:
-            gen = acc_type.split("-")[0]
-            return _CHIPS_PER_HOST.get(gen, 4)
+            return _chips_per_host(acc_type)
         return 0
 
     @staticmethod
@@ -73,6 +104,19 @@ class TPUAcceleratorManager:
         (``worker.py:991``, ``backend_executor.py:278``)."""
         os.environ[VISIBLE_CHIPS_ENV] = ",".join(str(c) for c in chip_ids)
         # jax reads TPU_VISIBLE_CHIPS via libtpu at first init.
+
+    @staticmethod
+    def jax_device(chip: int):
+        """The JAX device that is host chip ``chip`` in THIS process: a
+        worker started with ``TPU_VISIBLE_CHIPS`` sees only its own
+        chips, renumbered from 0; a process that sees the whole host
+        (the in-process runtime) indexes them directly."""
+        import jax
+
+        visible = os.environ.get(VISIBLE_CHIPS_ENV)
+        if visible:
+            chip = [int(c) for c in visible.split(",") if c != ""].index(chip)
+        return jax.local_devices()[chip]
 
     @staticmethod
     def node_resources() -> Dict[str, float]:

@@ -27,6 +27,7 @@ import asyncio
 import contextvars
 import inspect
 import logging
+import math
 import queue
 import threading
 import time
@@ -158,12 +159,21 @@ class _LocalActor:
             # into it (placement_group_capture_child_tasks).
             from ray_tpu._private import pg_context
             pg_context.set(*self.pg_ctx)
+        # The constructor runs under a task context so it can ask the
+        # runtime which chips it was given (get_accelerator_ids).
+        token = _context.set(_TaskCtx(
+            TaskID.for_actor_task(self.actor_id), self.actor_id,
+            name=f"{self.cls.__name__}.__init__"))
         try:
+            self.runtime._acquire_chips(
+                self.actor_id, self.options.task_resources().get("TPU", 0))
             self.instance = self.cls(*self.init_args, **self.init_kwargs)
         except BaseException as e:  # noqa: BLE001
             self._die(exceptions.RayTaskError.from_exception(
                 e, f"{self.cls.__name__}.__init__"))
             return
+        finally:
+            _context.reset(token)
         self.runtime._actor_started(self.actor_id)
         if self.is_async:
             self._run_async_loop()
@@ -490,6 +500,13 @@ class LocalRuntime(CoreRuntime):
             total["TPU"] = float(num_tpus)
         total.update(resources or {})
         self.ledger = _ResourceLedger(total)
+        # A chip is a device, not a quantity: an actor that demands TPU
+        # is handed specific chip indices, which it alone holds until it
+        # dies (one process drives every chip of the host, so each
+        # replica must know WHICH one is its own).
+        self._free_chips: List[int] = list(range(int(num_tpus)))
+        self._actor_chips: Dict[ActorID, List[int]] = {}
+        self._chips_cv = threading.Condition()
         # Placement groups, single-node edition: a group reserves its summed
         # resources from the main ledger at creation; PG-targeted tasks then
         # charge per-bundle ledgers (bundle_index=-1 charges a group-level
@@ -904,7 +921,40 @@ class LocalRuntime(CoreRuntime):
             if meta and meta["state"] == "STARTING":
                 meta["state"] = "ALIVE"
 
+    def _acquire_chips(self, actor_id: ActorID, demand: float) -> None:
+        """Block the starting actor until ``demand`` whole chips are
+        free and hand them to it (pending-actor semantics; a demand the
+        host can never meet is an error, not a hang)."""
+        n = math.ceil(demand)
+        if n <= 0:
+            return
+        total = int(self.ledger.total.get("TPU", 0))
+        if n > total:
+            raise ValueError(
+                f"actor demands {n} TPU chip(s) but this host has {total}")
+        with self._chips_cv:
+            while len(self._free_chips) < n:
+                if self._shutdown:
+                    raise RuntimeError("runtime shut down before a chip "
+                                       "became free")
+                self._chips_cv.wait(0.5)
+            self._actor_chips[actor_id] = [
+                self._free_chips.pop(0) for _ in range(n)]
+
+    def accelerator_ids(self) -> Dict[str, List[str]]:
+        """Chips held by the actor whose code is running on this thread
+        (reference: ``RuntimeContext.get_accelerator_ids``)."""
+        ctx = current_task_context()
+        with self._chips_cv:
+            chips = self._actor_chips.get(ctx.actor_id, []) \
+                if ctx is not None else []
+            return {"TPU": [str(c) for c in chips]}
+
     def _actor_died(self, actor_id, cause):
+        with self._chips_cv:
+            self._free_chips.extend(self._actor_chips.pop(actor_id, []))
+            self._free_chips.sort()
+            self._chips_cv.notify_all()
         with self._lock:
             meta = self._actor_meta.get(actor_id)
             if meta:

@@ -233,14 +233,6 @@ def _flush_pending_kv() -> None:
 
 
 # ------------------------------------------------------------- signatures
-def _tracer_type():
-    try:
-        from jax.core import Tracer
-    except Exception:  # noqa: BLE001 - jax.core reshuffles across versions
-        from jax._src.core import Tracer
-    return Tracer
-
-
 def _leaf_sig(x) -> Tuple:
     aval = getattr(x, "aval", None)
     if aval is not None:
@@ -295,9 +287,10 @@ class InstrumentedJit:
 
     Dispatch: per-signature AOT executables (``lower().compile()``) so
     compile events are first-class; nested calls under an outer trace
-    inline through the plain jit, and any AOT failure degrades the
-    wrapper to plain jit permanently (observability must never take the
-    hot path down).
+    inline through the plain jit. A failed compile raises the compiler's
+    error; a compiled executable that fails at DISPATCH degrades the
+    wrapper to plain jit (observability must never take the hot path
+    down).
 
     ``shape_policy``:
 
@@ -351,7 +344,7 @@ class InstrumentedJit:
         self._last_call: Optional[float] = None
         self._external_timing = False
         self._lock = threading.Lock()
-        self._tracer = _tracer_type()
+        self._tracer = jax.core.Tracer
 
     # Anything not overridden (``lower``, ``eval_shape``, ...) behaves
     # like the underlying jit.
@@ -432,17 +425,12 @@ class InstrumentedJit:
                                           cost=None)
                 return out
             else:
-                try:
-                    t0 = time.perf_counter()
-                    lowered = self._jitted.lower(*args, **kwargs)
-                    entry = lowered.compile()
-                    dt = time.perf_counter() - t0
-                except Exception:  # noqa: BLE001
-                    logger.exception(
-                        "xla_monitor: AOT compile of %r failed; "
-                        "degrading to plain jit", self.name)
-                    self._degraded = True
-                    return self._jitted(*args, **kwargs)
+                # A compile error propagates: retrying the same program
+                # through the plain jit would compile it a second time
+                # (minutes, on the chip) and bury the first message.
+                t0 = time.perf_counter()
+                entry = self._jitted.lower(*args, **kwargs).compile()
+                dt = time.perf_counter() - t0
                 self._compiled[key] = entry
                 self._observe_compile(key, leaf_sigs, dt,
                                       cost=_harvest_cost(entry))

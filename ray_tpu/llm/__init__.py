@@ -132,8 +132,15 @@ class ContinuousLlamaDeployment:
         import threading
         import uuid
 
+        import ray_tpu
+        from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
         from ray_tpu.models.continuous_batching import ContinuousBatcher
 
+        # A replica that holds a chip puts its whole engine on THAT chip;
+        # one that holds none (CPU runs) leaves placement to JAX.
+        chips = ray_tpu.get_runtime_context().get_accelerator_ids()["TPU"]
+        self.device = (TPUAcceleratorManager.jax_device(int(chips[0]))
+                       if chips else None)
         self.config = config or llama.LlamaConfig.tiny()
         if params is None and checkpoint_path:
             params = _params_from_checkpoint(checkpoint_path)
@@ -150,7 +157,7 @@ class ContinuousLlamaDeployment:
             num_blocks=num_blocks, prefix_cache=prefix_cache,
             sampling=sampling, spec_k=spec_k,
             spec_draft_layers=spec_draft_layers,
-            spec_adaptive=spec_adaptive, role=role)
+            spec_adaptive=spec_adaptive, role=role, device=self.device)
         # Reservation tickets are engine-local ids; the nonce scopes a
         # ticket to THIS replica so a router whose reserve and
         # decode_from calls landed on different replicas cannot spend
@@ -210,6 +217,26 @@ class ContinuousLlamaDeployment:
         trace = dict(rctx)
         trace.setdefault("tenant", multiplex.get_request_tenant())
         return trace
+
+    def engine_info(self) -> Dict[str, Any]:
+        """What this replica's engine actually runs and where: the
+        resolved data-plane switches, the device its arrays live on and
+        that device's memory (``chip_smoke.py`` checks these per
+        replica)."""
+        import jax
+
+        eng = self.batcher
+        dev = self.device or jax.devices()[0]
+        return {"use_decode_kernel": eng.use_decode_kernel,
+                "paged": eng.paged, "kv_dtype": eng.kv_dtype,
+                "device": {"id": dev.id, "platform": dev.platform,
+                           "kind": dev.device_kind},
+                "params_device_ids": sorted({
+                    d.id for x in jax.tree.leaves(eng.params)
+                    for d in x.devices()}),
+                "arena_device_ids": sorted(
+                    d.id for d in eng.cache.k.devices()),
+                "memory_stats": dev.memory_stats()}
 
     def pressure(self) -> Dict[str, Any]:
         """Live engine pressure for the serve pressure endpoint (queue
@@ -549,6 +576,19 @@ class ContinuousLlamaDeployment:
         return {"token_ids": tokens}
 
 
+def _chip_per_replica() -> Dict[str, Any]:
+    """Actor options for an engine replica: one whole chip where there
+    are chips (each replica must own the device its arena lives on),
+    nothing on a CPU-only cluster."""
+    import ray_tpu
+    from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
+
+    chips = (ray_tpu.cluster_resources().get("TPU", 0)
+             if ray_tpu.is_initialized()
+             else TPUAcceleratorManager.detect_num_chips())
+    return {"num_tpus": 1} if chips >= 1 else {}
+
+
 def build_continuous_llama_app(config: Optional[llama.LlamaConfig] = None,
                                num_replicas: int = 1, num_slots: int = 8,
                                max_len: int = 512, sync_every: int = 1,
@@ -563,7 +603,8 @@ def build_continuous_llama_app(config: Optional[llama.LlamaConfig] = None,
                                spec_draft_layers: Optional[int] = None,
                                spec_adaptive: Optional[bool] = None,
                                checkpoint_path: Optional[str] = None):
-    dep = ContinuousLlamaDeployment.options(num_replicas=num_replicas)
+    dep = ContinuousLlamaDeployment.options(
+        num_replicas=num_replicas, ray_actor_options=_chip_per_replica())
     # Keyword bind so per-deploy ``init_kwargs`` overrides (serve config
     # files) can retarget any engine knob without positional conflicts.
     return dep.bind(config=config, num_slots=num_slots, max_len=max_len,
@@ -589,11 +630,14 @@ def build_disagg_llama_apps(name: str = "llm",
     and declare the role group, or use :func:`deploy_disagg_llama`
     which does all three."""
     engine_kwargs.setdefault("paged", True)
+    chip = _chip_per_replica()
     prefill = ContinuousLlamaDeployment.options(
-        name=f"{name}-prefill", num_replicas=num_prefill).bind(
+        name=f"{name}-prefill", num_replicas=num_prefill,
+        ray_actor_options=chip).bind(
         config=config, role="prefill", **engine_kwargs)
     decode = ContinuousLlamaDeployment.options(
-        name=f"{name}-decode", num_replicas=num_decode).bind(
+        name=f"{name}-decode", num_replicas=num_decode,
+        ray_actor_options=chip).bind(
         config=config, role="decode", **engine_kwargs)
     return prefill, decode
 

@@ -24,16 +24,20 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.util import jax_compat as _jax_compat  # noqa: F401 - pins
-# partitionable threefry BEFORE any param init traces: sharded init must
-# produce the same values on every mesh layout (see jax_compat docstring).
-from ray_tpu.ops.attention import flash_attention, mha_reference
+from ray_tpu.ops.attention import (flash_applicable, flash_attention,
+                                   mha_reference)
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.parallel import constrain, mesh_shape
+from ray_tpu.util import compile_cache
+
+# Every process that builds a model imports this module before its first
+# compile (trainers, engines, replicas, workers): place the persistent
+# compilation cache here, once, for all of them.
+compile_cache.ensure()
 
 Params = Dict[str, Any]
 
@@ -191,21 +195,28 @@ def _attend(q, k, v, config: LlamaConfig, mesh: Optional[Mesh]):
     if mode == "reference":
         return mha_reference(q, k, v, causal=True)
     if mode == "ring":
-        from jax.sharding import PartitionSpec as P
-
-        from ray_tpu.util.jax_compat import shard_map
-
-        qspec = P(("data", "fsdp"), "seq", "tensor", None)
-        kvspec = P(("data", "fsdp"), "seq", "tensor", None)
-        fn = shard_map(
-            functools.partial(ring_attention, axis_name="seq", causal=True),
-            mesh=mesh,
-            in_specs=(qspec, kvspec, kvspec),
-            out_specs=qspec,
-            check_vma=False,
-        )
-        return fn(q, k, v)
-    return flash_attention(q, k, v, causal=True)
+        fn = functools.partial(ring_attention, axis_name="seq", causal=True)
+        spec = P(("data", "fsdp"), "seq", "tensor", None)
+    elif (mesh is None or mesh.size == 1
+          or not flash_applicable(q.shape[1], k.shape[1], q.shape[3])):
+        # One device, or shapes the dispatcher hands to mha_reference
+        # anyway (plain HLO, which GSPMD partitions by itself).
+        return flash_attention(q, k, v, causal=True)
+    else:
+        # A Mosaic kernel cannot be partitioned by GSPMD: each device
+        # runs it on its own batch rows and heads.
+        shape = mesh_shape(mesh)
+        rows = shape.get("data", 1) * shape.get("fsdp", 1)
+        if (q.shape[0] % rows or q.shape[2] % shape.get("tensor", 1)
+                or k.shape[2] % shape.get("tensor", 1)):
+            raise ValueError(
+                f"flash attention on mesh {shape}: batch {q.shape[0]} must "
+                f"divide over data*fsdp={rows}, and heads {q.shape[2]}/"
+                f"{k.shape[2]} over tensor={shape.get('tensor', 1)}")
+        fn = functools.partial(flash_attention, causal=True)
+        spec = P(("data", "fsdp"), None, "tensor", None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def forward(

@@ -5,7 +5,9 @@ private ``[S_max]`` stripe, so every decode tick streams ``S_max``
 entries per slot regardless of how many are live — at 32 slots x 512
 max_len with ~40-token requests that is >10x pure padding traffic. The
 paged layout mirrors vLLM's KV manager: one arena of fixed-size blocks
-(``[L, num_blocks, block_size, KVH, D]``) shared by all slots, a
+(``[L, num_blocks, KVH, block_size, D]``, heads ahead of the block's
+token axis so the paged kernel streams whole trailing tiles — see
+``ops/paged_decode_attention.py``) shared by all slots, a
 per-slot block table naming the blocks it filled, and a free-list
 allocator on the host. A slot's attention reads only its live blocks;
 freeing a slot returns its blocks for immediate reuse; and block
@@ -77,7 +79,7 @@ def quantize_kv(x):
 
 
 class PagedKVCache(NamedTuple):
-    """KV arena: k/v ``[L, NB, bs, KVH, D]``; scales ``[L, NB, bs, KVH]``
+    """KV arena: k/v ``[L, NB, KVH, bs, D]``; scales ``[L, NB, KVH, bs]``
     fp32 when the arena is int8, else None."""
 
     k: jnp.ndarray
@@ -87,7 +89,7 @@ class PagedKVCache(NamedTuple):
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[3]
 
     @property
     def num_blocks(self) -> int:
@@ -101,8 +103,8 @@ class PagedKVCache(NamedTuple):
     def create(cls, config: llama.LlamaConfig, num_blocks: int,
                block_size: int, kv_dtype: str = "bf16") -> "PagedKVCache":
         kv_dtype = resolve_kv_dtype(kv_dtype)
-        shape = (config.num_layers, num_blocks, block_size,
-                 config.num_kv_heads, config.head_dim)
+        shape = (config.num_layers, num_blocks, config.num_kv_heads,
+                 block_size, config.head_dim)
         if kv_dtype == "int8":
             return cls(k=jnp.zeros(shape, jnp.int8),
                        v=jnp.zeros(shape, jnp.int8),
@@ -114,7 +116,7 @@ class PagedKVCache(NamedTuple):
     def token_bytes(self) -> int:
         """Arena bytes one live token occupies across all layers (the
         live-traffic estimate the achieved-bandwidth gauges use)."""
-        layers, _, _, kvh, d = self.k.shape
+        layers, _, kvh, _, d = self.k.shape
         n = 2 * layers * kvh * d * jnp.dtype(self.k.dtype).itemsize
         if self.k_scale is not None:
             n += 2 * layers * kvh * 4

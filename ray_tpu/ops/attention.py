@@ -28,10 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu is importable on CPU builds of jax as well
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.decode_attention import _interpret_default
 
@@ -345,7 +342,7 @@ def flash_applicable(
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     return not (
-        sq < 8 or sq % block_q or sk % block_k or d % 128 or pltpu is None
+        sq < 8 or sq % block_q or sk % block_k or d % 128
         or (causal and sq > sk)
     )
 

@@ -10,13 +10,18 @@ copies), tripling the bytes moved per tick. This kernel fuses the length
 mask, online softmax, and PV product into one pass that streams K and V
 through VMEM in their storage dtype (bf16 on TPU) with fp32 accumulation.
 
-Structure mirrors ``ops/attention.py``: grid ``(batch, kv_heads,
-k_blocks)`` with the innermost dimension sequential on TPU so the running
-max / sum / accumulator live in VMEM scratch; GQA keeps the query group
-``[G, D]`` resident per program (G = Hq // Hkv), so K/V are read exactly
-once per kv head. Per-slot lengths arrive as scalars in SMEM and gate
-both the block grid (blocks wholly past a slot's position are skipped)
-and the in-block mask.
+Grid ``(batch, k_blocks)`` with the innermost dimension sequential on
+TPU so the running max / sum / accumulator live in VMEM scratch. Each
+step streams one ``[block_k, KVH, D]`` stripe covering EVERY kv head:
+the TPU lowering only accepts blocks whose last two dims are whole
+(8, 128) tiles or the full array dims, so a block cannot slice one head
+out of the ``KVH`` axis. Heads become the batch dim of the two MXU
+contractions; GQA keeps each head's query group ``[G, D]`` resident
+(G = Hq // Hkv), so K/V are read exactly once. Per-slot positions ride
+scalar prefetch (SMEM) and gate both the block grid (blocks wholly past
+a slot's position are skipped) and the in-block mask. The online-softmax
+core (:func:`_init_state` / :func:`_attend_block` / :func:`_finalize`)
+is shared with the paged kernel (``ops/paged_decode_attention.py``).
 
 Dispatch: :func:`decode_attention` runs the kernel on TPU when the
 shapes tile, interpret mode when forced (CPU tier-1 tests), and the XLA
@@ -35,10 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu is importable on CPU builds of jax as well
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 # The reference masks with -1e30 (not -inf: fully-masked garbage rows in
 # inactive slots must softmax to finite values, not NaN). Kept identical
@@ -95,54 +97,84 @@ def decode_attention_reference(q, cache_k, cache_v, positions,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
+def _init_state(acc_ref, m_ref, l_ref):
+    m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _attend_block(q, k, v, pos, first_col, acc_ref, m_ref, l_ref, *, scale,
+                  k_scale=None, v_scale=None):
+    """One online-softmax step over a K/V block, all kv heads at once.
+
+    q [KVH, G, D]; k/v [KVH, T, D] in storage dtype (upcast here);
+    ``pos`` the slot's absolute query position, ``first_col`` the
+    absolute position of the block's first key. ``k_scale``/``v_scale``
+    [KVH, T] dequantize an int8 block: they scale the score and
+    probability COLUMNS (keys ride the lane axis of both), which equals
+    scaling K/V rows without relayouting the scales onto sublanes."""
+    q = q.astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale          # [KVH, G, T]
+    if k_scale is not None:
+        s = s * k_scale[:, None, :]
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    s = jnp.where(pos >= first_col + cols, s, MASK_VALUE)
+
+    m_prev = m_ref[:, :, :1]                                 # [KVH, G, 1]
+    l_prev = l_ref[:, :, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)                                   # [KVH, G, T]
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    if v_scale is not None:
+        p = p * v_scale[:, None, :]
+    pv = jax.lax.dot_general(
+        p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)                  # [KVH, G, D]
+    acc_ref[:] = acc_ref[:] * alpha + pv
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _finalize(o_ref, acc_ref, l_ref):
+    l = l_ref[:, :, :1]
+    # Position 0 is always live, so l > 0 for every real slot; guard
+    # anyway so padded grid rows emit zeros rather than NaN.
+    safe_l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+
+
+def _scratch(hkv: int, group: int, d: int):
+    return [pltpu.VMEM((hkv, group, d), jnp.float32),
+            pltpu.VMEM((hkv, group, 128), jnp.float32),
+            pltpu.VMEM((hkv, group, 128), jnp.float32)]
+
+
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
                    l_ref, *, scale, block_k, num_k_blocks):
-    ik = pl.program_id(2)
+    ik = pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        _init_state(acc_ref, m_ref, l_ref)
 
     # The query sits at absolute position `pos`; cache entries at
     # [0..pos] are live. Blocks strictly past it contribute nothing.
-    pos = pos_ref[0]
-    run = ik * block_k <= pos
+    pos = pos_ref[pl.program_id(0)]
 
-    @pl.when(run)
+    @pl.when(ik * block_k <= pos)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)              # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)           # [bk, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                        # [G, bk]
-        g = s.shape[0]
-        cols = jax.lax.broadcasted_iota(jnp.int32, (g, block_k), 1)
-        s = jnp.where(pos >= ik * block_k + cols, s, MASK_VALUE)
-
-        m_prev = m_ref[:, :1]                            # [G, 1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                           # [G, bk]
-        alpha = jnp.exp(m_prev - m_new)                  # [G, 1]
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-
-        v = v_ref[0, :, 0].astype(jnp.float32)           # [bk, D]
-        pv = jnp.dot(p, v, preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        # The dense cache keeps heads BEHIND the sequence axis; the
+        # contractions want them leading.
+        _attend_block(q_ref[0], jnp.swapaxes(k_ref[0], 0, 1),
+                      jnp.swapaxes(v_ref[0], 0, 1), pos, ik * block_k,
+                      acc_ref, m_ref, l_ref, scale=scale)
 
     @pl.when(ik == num_k_blocks - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        # Position 0 is always live, so l > 0 for every real slot; guard
-        # anyway so padded grid rows emit zeros rather than NaN.
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, acc_ref, l_ref)
 
 
 def _decode_fused(q, cache_k, cache_v, positions, *, scale, block_k,
@@ -153,28 +185,23 @@ def _decode_fused(q, cache_k, cache_v, positions, *, scale, block_k,
     nk = pl.cdiv(s_max, block_k)
 
     qg = q.reshape(b, hkv, group, d)
-    grid = (b, hkv, nk)
-    pos_spec = pl.BlockSpec((1,), lambda b_, h, j: (b_,),
-                            memory_space=pltpu.SMEM)
-    q_spec = pl.BlockSpec((1, 1, group, d), lambda b_, h, j: (b_, h, 0, 0))
-    kv_spec = pl.BlockSpec((1, block_k, 1, d),
-                           lambda b_, h, j: (b_, j, h, 0))
-    out_spec = pl.BlockSpec((1, 1, group, d), lambda b_, h, j: (b_, h, 0, 0))
-
+    q_spec = pl.BlockSpec((1, hkv, group, d), lambda b_, j, po: (b_, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, block_k, hkv, d),
+                           lambda b_, j, po: (b_, j, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        scratch_shapes=_scratch(hkv, group, d),
+    )
     kernel = functools.partial(
         _decode_kernel, scale=scale, block_k=block_k, num_k_blocks=nk)
     itemsize = jnp.dtype(cache_k.dtype).itemsize
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[pos_spec, q_spec, kv_spec, kv_spec],
-        out_specs=out_spec,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-        ],
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             # One query row per slot: 2 matmuls over the live prefix.
@@ -193,10 +220,7 @@ def decode_applicable(s_max: int, d: int, hq: int, hkv: int, *,
     kernel for these shapes on TPU (vs the XLA reference). Kept next to
     the kernel so diagnostics (bench_serve.py) can't drift from the real
     dispatch predicate."""
-    return not (
-        pltpu is None or hq % hkv or d % 128
-        or s_max % min(block_k, s_max)
-    )
+    return not (hq % hkv or d % 128 or s_max % min(block_k, s_max))
 
 
 def decode_attention(
@@ -227,13 +251,6 @@ def decode_attention(
         use_kernel = (jax.default_backend() == "tpu"
                       and decode_applicable(s_max, d, hq, hkv,
                                             block_k=block_k))
-    elif use_kernel and pltpu is None:
-        # Forcing the kernel on a jax build without pallas-TPU support
-        # must fail loudly: a silent reference fallback would make
-        # parity tests pass vacuously and perf flags lie.
-        raise RuntimeError(
-            "decode_attention(use_kernel=True) needs "
-            "jax.experimental.pallas.tpu, which this jax build lacks")
     if not use_kernel:
         return decode_attention_reference(q, cache_k, cache_v, positions,
                                           scale)

@@ -3,22 +3,26 @@
 The dense fused kernel (``ops/decode_attention.py``) still streams each
 slot's full ``S_max`` stripe of the pooled cache per tick — a slot 40
 tokens into a 512-token cache pays for 512. Here the pooled cache is an
-ARENA of fixed-size blocks (``[num_blocks, block_size, KVH, D]``) and
+ARENA of fixed-size blocks (``[num_blocks, KVH, block_size, D]``) and
 each slot owns a small BLOCK TABLE naming the blocks it has actually
 filled, so a tick reads only live prefix blocks (vLLM paged-attention,
 on TPU: block tables ride scalar prefetch so the BlockSpec ``index_map``
 can gather arena blocks by table lookup before the kernel body runs).
+The arena is HEADS-MAJOR inside a block: the TPU lowering takes a block
+only in whole trailing (sublane, lane) tiles, and ``(block_size, D)``
+tiles exactly for bf16 and int8 at any head count, where a trailing
+``(KVH, D)`` would pad small head counts up to a tile in HBM.
 
 Two bandwidth levers stack:
 
-* **Paging** — grid ``(batch, kv_heads, table_blocks)`` with dead table
-  entries repeating the last live block: pallas skips the re-fetch when
-  the mapped block index does not change between sequential grid steps,
-  so a slot's dead tail costs ~zero HBM traffic (and ``pl.when`` skips
-  its compute).
+* **Paging** — grid ``(batch, table_blocks)``, one whole block (every
+  kv head) per step, with dead table entries repeating the last live
+  block: pallas skips the re-fetch when the mapped block index does not
+  change between sequential grid steps, so a slot's dead tail costs
+  ~zero HBM traffic (and ``pl.when`` skips its compute).
 * **int8 KV quantization** — the arena stores K/V as int8 with
   per-token/per-kv-head fp32 scales kept in block-shaped sidecars
-  (``[num_blocks, block_size, KVH]``), gathered by the same table;
+  (``[num_blocks, KVH, block_size]``), gathered by the same table;
   dequantization happens in-register after the block is resident, so
   bytes-per-token roughly halve against bf16.
 
@@ -40,8 +44,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ray_tpu.ops.decode_attention import (MASK_VALUE, _interpret_default,
-                                          pltpu)
+from ray_tpu.ops.decode_attention import (_attend_block, _finalize,
+                                          _init_state, _interpret_default,
+                                          _scratch, pltpu)
 
 
 def dequantize_block(x, scale):
@@ -51,13 +56,13 @@ def dequantize_block(x, scale):
 
 
 def gather_kv(arena, tables):
-    """Linearize a slot's blocks: arena [NB, bs, KVH, D] gathered through
-    tables [B, nb] -> [B, nb*bs, KVH, D] (the dense-layout view the
-    reference path attends over)."""
+    """Linearize a slot's blocks: arena [NB, KVH, bs, ...] gathered
+    through tables [B, nb] -> [B, nb*bs, KVH, ...] (the dense-layout view
+    the reference path attends over)."""
     b, nb = tables.shape
-    bs = arena.shape[1]
-    g = arena[tables]                       # [B, nb, bs, KVH, D]
-    return g.reshape(b, nb * bs, *arena.shape[2:])
+    hkv, bs = arena.shape[1], arena.shape[2]
+    g = jnp.swapaxes(arena[tables], 2, 3)   # [B, nb, bs, KVH, ...]
+    return g.reshape(b, nb * bs, hkv, *arena.shape[3:])
 
 
 def paged_attention_reference(q, arena_k, arena_v, tables, positions,
@@ -66,7 +71,7 @@ def paged_attention_reference(q, arena_k, arena_v, tables, positions,
     """XLA reference: gather blocks into dense layout, dequantize when the
     arena is quantized, then run the positional-mask softmax attention.
 
-    q [B, Hq, D]; arena [NB, bs, KVH, D]; tables [B, nb] (row j = slot's
+    q [B, Hq, D]; arena [NB, KVH, bs, D]; tables [B, nb] (row j = slot's
     j-th logical block; dead entries may repeat blocks — masked out by
     ``positions``); positions [B].
     """
@@ -75,10 +80,8 @@ def paged_attention_reference(q, arena_k, arena_v, tables, positions,
     ck = gather_kv(arena_k, tables)
     cv = gather_kv(arena_v, tables)
     if k_scale is not None:
-        ck = dequantize_block(ck, gather_kv(k_scale[..., None],
-                                            tables)[..., 0])
-        cv = dequantize_block(cv, gather_kv(v_scale[..., None],
-                                            tables)[..., 0])
+        ck = dequantize_block(ck, gather_kv(k_scale, tables))
+        cv = dequantize_block(cv, gather_kv(v_scale, tables))
     return decode_attention_reference(q, ck, cv, positions,
                                       scale).astype(q.dtype)
 
@@ -93,102 +96,65 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        _init_state(acc_ref, m_ref, l_ref)
 
     # The slot's query sits at absolute position `pos`; logical blocks
     # wholly past it are dead (their table entries repeat the last live
     # block, so the pipeline fetches nothing new for them either).
-    pos = pos_ref[b]
-    run = j * block_size <= pos
+    pos = pos_ref[pl.program_id(0)]
 
-    @pl.when(run)
+    @pl.when(j * block_size <= pos)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)              # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)           # [bs, D]
-        if quantized:
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                        # [G, bs]
-        g = s.shape[0]
-        cols = jax.lax.broadcasted_iota(jnp.int32, (g, block_size), 1)
-        s = jnp.where(pos >= j * block_size + cols, s, MASK_VALUE)
-
-        m_prev = m_ref[:, :1]                            # [G, 1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                           # [G, bs]
-        alpha = jnp.exp(m_prev - m_new)                  # [G, 1]
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-
-        v = v_ref[0, :, 0].astype(jnp.float32)           # [bs, D]
-        if quantized:
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        pv = jnp.dot(p, v, preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        _attend_block(q_ref[0], k_ref[0], v_ref[0], pos, j * block_size,
+                      acc_ref, m_ref, l_ref, scale=scale,
+                      k_scale=ks_ref[0] if quantized else None,
+                      v_scale=vs_ref[0] if quantized else None)
 
     @pl.when(j == num_blocks - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, acc_ref, l_ref)
 
 
 def _paged_fused(q, arena_k, arena_v, tables, positions, *, k_scale,
                  v_scale, scale, interpret):
     b, hq, d = q.shape
-    _, block_size, hkv, _ = arena_k.shape
+    _, hkv, block_size, _ = arena_k.shape
     nb = tables.shape[1]
     group = hq // hkv
     quantized = k_scale is not None
 
     qg = q.reshape(b, hkv, group, d)
-    q_spec = pl.BlockSpec((1, 1, group, d),
-                          lambda b_, h, j, tab, po: (b_, h, 0, 0))
+    q_spec = pl.BlockSpec((1, hkv, group, d),
+                          lambda b_, j, tab, po: (b_, 0, 0, 0))
     # The table gather IS the index_map: scalar-prefetched block tables
     # choose which arena block each grid step streams into VMEM.
-    kv_spec = pl.BlockSpec((1, block_size, 1, d),
-                           lambda b_, h, j, tab, po: (tab[b_, j], 0, h, 0))
+    kv_spec = pl.BlockSpec((1, hkv, block_size, d),
+                           lambda b_, j, tab, po: (tab[b_, j], 0, 0, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     inputs = [qg, arena_k, arena_v]
     if quantized:
-        sc_spec = pl.BlockSpec((1, block_size, 1),
-                               lambda b_, h, j, tab, po: (tab[b_, j], 0, h))
+        sc_spec = pl.BlockSpec((1, hkv, block_size),
+                               lambda b_, j, tab, po: (tab[b_, j], 0, 0))
         in_specs += [sc_spec, sc_spec]
         inputs += [k_scale, v_scale]
-    out_spec = pl.BlockSpec((1, 1, group, d),
-                            lambda b_, h, j, tab, po: (b_, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, nb),
+        grid=(b, nb),
         in_specs=in_specs,
-        out_specs=out_spec,
-        scratch_shapes=[
-            pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-        ],
+        out_specs=q_spec,
+        scratch_shapes=_scratch(hkv, group, d),
     )
     kernel = functools.partial(
         _paged_kernel, scale=scale, block_size=block_size, num_blocks=nb,
         quantized=quantized)
     itemsize = jnp.dtype(arena_k.dtype).itemsize
-    # Grid (b, hkv, nb): every kv head re-streams its [bs, d] slice of
-    # each table block, so worst-case KV traffic carries the hkv factor.
-    kv_bytes = 2 * b * hkv * nb * block_size * d * itemsize
+    kv_bytes = 2 * b * nb * hkv * block_size * d * itemsize
     if quantized:
-        kv_bytes += 2 * b * hkv * nb * block_size * 4    # fp32 scales
+        kv_bytes += 2 * b * nb * hkv * block_size * 4    # fp32 scales
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -210,7 +176,7 @@ def paged_applicable(block_size: int, d: int, hq: int, hkv: int) -> bool:
     """True when auto-dispatch takes the paged fused kernel on TPU for
     these shapes (lane-tiling head_dim, sublane-tiling blocks, whole
     query groups)."""
-    return not (pltpu is None or hq % hkv or d % 128 or block_size % 32)
+    return not (hq % hkv or d % 128 or block_size % 32)
 
 
 def paged_decode_attention(
@@ -228,8 +194,8 @@ def paged_decode_attention(
 ) -> jnp.ndarray:
     """Decode-step attention over a paged KV arena.
 
-    q [B, Hq, D]; arena_k/v [NB, bs, KVH, D] (int8 when ``k_scale`` /
-    ``v_scale`` [NB, bs, KVH] are given); tables [B, nb] int32 block
+    q [B, Hq, D]; arena_k/v [NB, KVH, bs, D] (int8 when ``k_scale`` /
+    ``v_scale`` [NB, KVH, bs] are given); tables [B, nb] int32 block
     table (row j = the slot's j-th logical block; dead tail entries
     should repeat the last live block); positions [B].
 
@@ -238,7 +204,7 @@ def paged_decode_attention(
     mode off-TPU — the CPU tier-1 path); False forces the reference.
     """
     b, hq, d = q.shape
-    block_size, hkv = arena_k.shape[1], arena_k.shape[2]
+    hkv, block_size = arena_k.shape[1], arena_k.shape[2]
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     if (k_scale is None) != (v_scale is None):
@@ -247,10 +213,6 @@ def paged_decode_attention(
     if use_kernel is None:
         use_kernel = (jax.default_backend() == "tpu"
                       and paged_applicable(block_size, d, hq, hkv))
-    elif use_kernel and pltpu is None:
-        raise RuntimeError(
-            "paged_decode_attention(use_kernel=True) needs "
-            "jax.experimental.pallas.tpu, which this jax build lacks")
     if not use_kernel:
         return paged_attention_reference(q, arena_k, arena_v, tables,
                                          positions, scale,

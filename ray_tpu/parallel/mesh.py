@@ -83,23 +83,26 @@ def make_mesh(
 ) -> Mesh:
     """Build a named Mesh over ``devices`` (default: all global devices).
 
-    Uses :func:`jax.experimental.mesh_utils.create_device_mesh` when all
-    global devices are used so the logical mesh is laid out along the physical
-    ICI torus (nearest-neighbor collectives stay on-link); otherwise falls
-    back to a reshape of the explicit device list.
+    TPU devices are laid out by
+    :func:`jax.experimental.mesh_utils.create_device_mesh`, so the logical
+    mesh follows the physical ICI torus (nearest-neighbor collectives stay
+    on-link). If that fails the error is raised: a reshape in enumeration
+    order would silently decide which chips are neighbours. Other
+    platforms, and a single device, have no topology to honour and are
+    reshaped in the order given.
     """
     config = config or MeshConfig()
     devices = list(devices) if devices is not None else jax.devices()
     sizes = config.resolve(len(devices))
     shape = tuple(sizes[a] for a in MESH_AXES)
-    try:
+    if devices[0].platform == "tpu" and len(devices) > 1:
         from jax.experimental import mesh_utils
 
         dev_array = mesh_utils.create_device_mesh(
             shape, devices=devices,
             allow_split_physical_axes=allow_split_physical_axes,
         )
-    except Exception:
+    else:
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, MESH_AXES)
 

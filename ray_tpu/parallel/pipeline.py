@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu.util.jax_compat import shard_map
 
 
 def pipelined(
@@ -91,7 +90,7 @@ def pipelined(
             return jax.lax.psum(masked, axis_name)
 
         spec_params = jax.tree.map(lambda _: P(axis_name), stage_params)
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(spec_params, P()),
             out_specs=P(),

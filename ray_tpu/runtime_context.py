@@ -5,7 +5,8 @@ Re-design of the reference (reference: ``python/ray/runtime_context.py``).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+import os
+from typing import Any, Dict, List, Optional
 
 from ray_tpu._private import worker as _worker
 
@@ -48,6 +49,17 @@ class RuntimeContext:
     def get_assigned_resources(self) -> Dict[str, float]:
         getter = getattr(self._core, "assigned_resources", None)
         return getter() if getter else {}
+
+    def get_accelerator_ids(self) -> Dict[str, List[str]]:
+        """Host chip indices held by the running actor, as
+        ``{"TPU": ["2"]}`` (reference: ``get_accelerator_ids``). The
+        in-process runtime hands chips out per actor; a cluster worker
+        was started with only its own chips visible."""
+        getter = getattr(self._core, "accelerator_ids", None)
+        if getter is not None:
+            return getter()
+        visible = os.environ.get("TPU_VISIBLE_CHIPS", "")
+        return {"TPU": [c for c in visible.split(",") if c]}
 
     def get_placement_group_id(self) -> Optional[str]:
         getter = getattr(self._core, "current_placement_group_id", None)

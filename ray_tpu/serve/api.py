@@ -1063,8 +1063,19 @@ class ServeController:
                 if birth is not None and \
                         now - birth < self.REPLICA_STARTUP_GRACE_S:
                     live.append(r)  # still starting: keep, don't churn
-                else:
-                    self._replica_birth.pop(id(r), None)
+                elif self._replica_birth.pop(id(r), None) is not None:
+                    # Never answered inside the grace: it is replaced, so
+                    # it must also die. Left alive it keeps its resources
+                    # — a chip is exclusive, so the replacement would
+                    # wait on it forever while nothing routes to it.
+                    logger.warning(
+                        "serve: replica of %s did not finish starting in "
+                        "%.0fs; killing and replacing it", name,
+                        self.REPLICA_STARTUP_GRACE_S)
+                    try:
+                        ray_tpu.kill(r)
+                    except Exception:  # noqa: BLE001 — already gone
+                        pass
         current = live
         opts: Dict[str, Any] = dict(spec.get("actor_options") or {})
         opts["max_concurrency"] = spec["max_concurrency"]

@@ -120,20 +120,13 @@ class TrainWorker:
 
         kwargs = {}
         if timeout_s is not None:
-            # jax's initialization_timeout is in seconds; old jax
-            # versions lack the kwarg entirely (TypeError → retry bare).
+            # jax's initialization_timeout is in whole seconds.
             kwargs["initialization_timeout"] = max(int(timeout_s), 1)
         try:
-            try:
-                jax.distributed.initialize(
-                    coordinator_address=coordinator,
-                    num_processes=num_processes,
-                    process_id=self.rank, **kwargs)
-            except TypeError:
-                jax.distributed.initialize(
-                    coordinator_address=coordinator,
-                    num_processes=num_processes,
-                    process_id=self.rank)
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=num_processes,
+                process_id=self.rank, **kwargs)
         except RuntimeError as e:
             if "already" not in str(e).lower():
                 raise

@@ -63,7 +63,7 @@ class FailureConfig:
     lapsed heartbeats — have their own budget (``RAY_TPU_MAX_RESTARTS``),
     preemptions theirs (``RAY_TPU_MAX_PREEMPTIONS``), and worker-set
     resizes theirs (``RAY_TPU_MAX_RESIZES``); see
-    ``ray_tpu/train/elastic.py`` for the full taxonomy.
+    ``ray_tpu/train/elastic.py`` for the full classification.
     """
 
     max_failures: int = 0  # 0 = no retries, -1 = infinite

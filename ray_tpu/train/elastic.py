@@ -1,4 +1,4 @@
-"""Elastic training control: failure taxonomy, resize signals, budgets.
+"""Elastic training control: failure classification, resize signals, budgets.
 
 Reference blueprint: Ray Train v2 elastic worker groups + the GCS
 fault-tolerance machinery (``train/v2/_internal/execution/controller``):

@@ -209,7 +209,7 @@ class JaxTrainer:
         )
 
         failure_cfg: FailureConfig = rc.failure_config
-        # Per-cause budgets (elastic.py taxonomy). Preemptions/resizes are
+        # Per-cause budgets (elastic.py classification). Preemptions/resizes are
         # routine on TPU pods, not failures: each gets its own budget
         # instead of consuming max_failures; infrastructure loss gets the
         # restart budget.

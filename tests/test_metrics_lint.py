@@ -237,7 +237,7 @@ def test_serve_replica_lifecycle_series_are_cataloged():
         if m.name in required:
             assert m.description.strip() and "deployment" in m.tag_keys
         if m.name.startswith("ray_tpu_serve_replica_"):
-            # The failure taxonomy rides the cause tag
+            # The failure classification rides the cause tag
             # (scale_down/preemption vs died/drain vs
             # resubmit/resume/drain_reject).
             assert "cause" in m.tag_keys, m.name
@@ -306,7 +306,7 @@ def test_router_dispatch_paths_handle_actor_death_through_the_journal():
 def test_disagg_kv_transfer_series_are_cataloged_and_pinned():
     """The disaggregated prefill/decode handoff plane (ISSUE 20): the
     KV-transfer series ship described + tagged with the hop direction,
-    the handoff ledger counter carries the outcome taxonomy, request
+    the handoff ledger counter carries the outcome classification, request
     histograms carry the role tag, and a SOURCE LINT pins every
     cross-replica export/import call site to the journal-gated helper
     (serve/kv_transfer.py) — a bare channel write of arena bytes beside
@@ -387,7 +387,7 @@ def test_train_elasticity_series_are_cataloged():
         if m.name in required:
             assert m.description.strip() and "trainer" in m.tag_keys
         if m.name == "ray_tpu_train_restarts_total":
-            # The failure taxonomy rides the cause tag
+            # The failure classification rides the cause tag
             # (worker_lost/hang/preemption/resize/user).
             assert "cause" in m.tag_keys
 

@@ -8,8 +8,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.util.jax_compat import shard_map
-
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.norms import layer_norm, rms_norm
 from ray_tpu.ops.ring_attention import ring_attention, ulysses_attention
@@ -96,7 +94,7 @@ def test_sequence_parallel_attention(impl, causal):
     q, k, v = _qkv(b=2, s=512, hq=8, hkv=4, d=64)
     with jax.default_matmul_precision("highest"):
         ref = mha_reference(q, k, v, causal=causal)
-        out = shard_map(
+        out = jax.shard_map(
             functools.partial(impl, causal=causal, axis_name="seq"),
             mesh=mesh,
             in_specs=(P(None, "seq"),) * 3,
@@ -111,7 +109,7 @@ def test_ring_attention_grads():
     q, k, v = _qkv(b=1, s=256, hq=4, hkv=4, d=64)
 
     def loss_ring(q, k, v):
-        out = shard_map(
+        out = jax.shard_map(
             functools.partial(ring_attention, causal=True, axis_name="seq"),
             mesh=mesh, in_specs=(P(None, "seq"),) * 3,
             out_specs=P(None, "seq"), check_vma=False,

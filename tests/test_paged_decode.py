@@ -39,12 +39,15 @@ def _paged_inputs(b=3, hq=4, hkv=2, d=16, bs=32, nb_slot=4, seed=0,
     if scramble:
         ids = np.random.default_rng(seed).permutation(ids)
     tables = ids.reshape(b, nb_slot).astype(np.int32)
-    arena_k = np.zeros((nb_total, bs, hkv, d), np.asarray(ck).dtype)
+    arena_k = np.zeros((nb_total, hkv, bs, d), np.asarray(ck).dtype)
     arena_v = np.zeros_like(arena_k)
     for i in range(b):
         for j in range(nb_slot):
-            arena_k[tables[i, j]] = np.asarray(ck[i, j * bs:(j + 1) * bs])
-            arena_v[tables[i, j]] = np.asarray(cv[i, j * bs:(j + 1) * bs])
+            # Heads-major inside a block: [bs, KVH, D] -> [KVH, bs, D].
+            arena_k[tables[i, j]] = np.asarray(
+                ck[i, j * bs:(j + 1) * bs]).swapaxes(0, 1)
+            arena_v[tables[i, j]] = np.asarray(
+                cv[i, j * bs:(j + 1) * bs]).swapaxes(0, 1)
     return (q, ck, cv, jnp.asarray(arena_k), jnp.asarray(arena_v),
             jnp.asarray(tables), None)
 
@@ -159,7 +162,7 @@ def test_paged_cache_create_dtypes():
     cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
     dense = PagedKVCache.create(cfg, num_blocks=9, block_size=16)
     assert not dense.quantized and dense.k_scale is None
-    assert dense.k.shape[1:3] == (9, 16)
+    assert dense.k.shape[1:4] == (9, cfg.num_kv_heads, 16)
     q8 = PagedKVCache.create(cfg, num_blocks=9, block_size=16,
                              kv_dtype="int8")
     assert q8.quantized and q8.k.dtype == jnp.int8

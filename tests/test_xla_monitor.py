@@ -30,6 +30,46 @@ def _counter_value(counter, program: str) -> float:
     return 0.0
 
 
+# ------------------------------------------------ compile: errors, cache
+
+
+def test_failed_compile_raises_once_with_its_own_message():
+    """No second attempt through the plain jit: on the chip that would
+    double a minutes-long failing compile and bury the first error."""
+    traces = []
+
+    @xm.instrument(name=_name("refused"))
+    def f(x):
+        traces.append(1)
+        raise ValueError("the compiler's message")
+
+    with pytest.raises(ValueError, match="the compiler's message"):
+        f(jnp.ones((4,)))
+    assert len(traces) == 1
+    assert f._degraded is False
+
+
+def test_aot_compile_goes_through_the_persistent_cache():
+    """The dispatcher compiles with ``lower().compile()``; a second
+    wrapper around identical code must find the first one's entry in the
+    persistent cache (tests/conftest.py places it and lowers the
+    size/time thresholds to zero)."""
+    from ray_tpu.util import compile_cache
+
+    def make():
+        def persistent_probe(x):
+            return jnp.tanh(x) @ x.T + 7.0
+        return persistent_probe
+
+    x = jnp.ones((33, 17))
+    xm.instrument(make(), name=_name("cache_a"))(x)    # writes (or hits)
+    before = compile_cache.counts()
+    xm.instrument(make(), name=_name("cache_b"))(x)
+    after = compile_cache.counts()
+    assert after["hits"] == before["hits"] + 1, (before, after)
+    assert after["misses"] == before["misses"]
+
+
 # -------------------------------------------------- retrace detection
 
 
