@@ -206,6 +206,13 @@ SERVE_REQ_QUEUE = Histogram(
     "KV slot / the admission loop)",
     boundaries=(0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0),
     tag_keys=_REQ_TAGS)
+SERVE_REQ_LOCK_WAIT = Histogram(
+    "ray_tpu_serve_request_lock_wait_seconds",
+    "Before the TTFT clock starts: from the replica method's entry to "
+    "holding the engine lock for submit (the tick thread holds that lock "
+    "across each step); span engine.submit_wait",
+    boundaries=(0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0),
+    tag_keys=_REQ_TAGS)
 SERVE_REQ_ARENA_WAIT = Histogram(
     "ray_tpu_serve_request_arena_wait_seconds",
     "TTFT component: time the request sat at the head of the admission "
@@ -384,6 +391,52 @@ CB_TICK_MS = Histogram(
     boundaries=(0.5, 1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0,
                 500.0, 1000.0),
     tag_keys=("engine",))
+# The engine thread's time by phase (``tracing.phase`` in
+# models/continuous_batching.py and the replica's tick loop): with
+# per-tick sync the device idles while that thread is anywhere but inside
+# a program's dispatch+fetch, so these sums split the chip's idle share
+# by host cause. Each has a name of its own so a reader that sums label
+# sets away can still tell them apart; the same intervals are
+# ``engine.*`` annotations in a profiler trace (util/profile_gaps.py).
+_STEP_MS_BOUNDS = (0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 1.0, 2.0, 5.0, 10.0,
+                   20.0, 50.0, 100.0, 500.0)
+CB_STEP_LOCK_WAIT_MS = Histogram(
+    "ray_tpu_cb_step_lock_wait_ms",
+    "Milliseconds the replica's tick thread waited to get the engine "
+    "lock back from submitting/cancelling callers, per tick-loop turn "
+    "(span engine.lock_wait)",
+    boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
+CB_STEP_ADMIT_MS = Histogram(
+    "ray_tpu_cb_step_admit_ms",
+    "Host milliseconds of admission per step, the prefill programs "
+    "taken out: queue scan, block allocation, prefix match, building "
+    "the batch, first-token bookkeeping (span engine.admit)",
+    boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
+CB_PREFILL_MS = Histogram(
+    "ray_tpu_cb_prefill_ms",
+    "Wall milliseconds per prefill BATCH: uploads, dispatch, compute, "
+    "first-token fetch (span engine.prefill; the per-request "
+    "ray_tpu_serve_request_prefill_seconds counts a batch once per row)",
+    boundaries=(1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0, 500.0,
+                1000.0, 5000.0),
+    tag_keys=("engine",))
+CB_STEP_UPLOAD_MS = Histogram(
+    "ray_tpu_cb_step_upload_ms",
+    "Host milliseconds re-uploading slot state (tokens, positions, step, "
+    "block tables, limits) after membership changed (span engine.upload)",
+    boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
+CB_STEP_ACCOUNT_MS = Histogram(
+    "ray_tpu_cb_step_account_ms",
+    "Host milliseconds of the engine thread's own bookkeeping: slot/KV "
+    "gauges, the speculation controller, the XLA monitor's "
+    "note_execution with the live-byte estimate (span engine.account)",
+    boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
+CB_STEP_APPLY_MS = Histogram(
+    "ray_tpu_cb_step_apply_ms",
+    "Host milliseconds booking fetched tokens: per-request callbacks "
+    "into the stream queues, finish detection, end-of-stream puts "
+    "(span engine.apply)",
+    boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
 CB_PREFILL_REQUESTS = Counter(
     "ray_tpu_cb_prefill_requests_total",
     "Requests admitted into KV slots via (batched bucketed) prefill",

@@ -11,6 +11,8 @@ with ``@serve.batch`` merging concurrent requests into one batched decode
 
 from __future__ import annotations
 
+import contextlib
+import time
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -174,19 +176,32 @@ class ContinuousLlamaDeployment:
     def _tick_loop(self) -> None:
         import logging
 
+        from ray_tpu._private import metrics_defs as mdefs
+        from ray_tpu.util import tracing
+
         log = logging.getLogger(__name__)
+        tags = self.batcher._mtags
         while True:
             self._work.wait()
             try:
-                with self._lock:
+                # Callers take this lock to submit and cancel; the device
+                # idles while this thread waits to get it back.
+                with tracing.phase("engine.lock_wait",
+                                   mdefs.CB_STEP_LOCK_WAIT_MS, tags):
+                    self._lock.acquire()
+                try:
                     if not self.batcher.has_work():
                         self._work.clear()
                         continue
                     finished = self.batcher.step()
-                for rid in finished:
-                    q = self._queues.get(rid)
-                    if q is not None:
-                        q.put(None)  # end-of-stream
+                finally:
+                    self._lock.release()
+                with tracing.phase("engine.apply", mdefs.CB_STEP_APPLY_MS,
+                                   tags):
+                    for rid in finished:
+                        q = self._queues.get(rid)
+                        if q is not None:
+                            q.put(None)  # end-of-stream
             except Exception as e:  # noqa: BLE001
                 # Engine error (OOM, bad request reaching the kernel):
                 # fail every in-flight stream explicitly and reset the
@@ -217,6 +232,29 @@ class ContinuousLlamaDeployment:
         trace = dict(rctx)
         trace.setdefault("tenant", multiplex.get_request_tenant())
         return trace
+
+    @contextlib.contextmanager
+    def _submitting(self, entered: float,
+                    trace: Optional[Dict[str, Any]]):
+        """Hold the engine lock for a submit or import; yields when it
+        was got. With ``entered``, the replica method's entry, that is
+        what precedes the engine's own TTFT clock: ``serve.hop`` (the
+        router's ``remote()`` to ``entered``, emitted here; only a
+        traced request carries ``route_ts``) and the wait for the lock,
+        which the tick thread holds across each step and which the
+        caller hands to ``batcher.note_submit_wait`` with the request
+        id its submit returned."""
+        from ray_tpu.util import tracing
+
+        if trace is not None and trace.get("route_ts") is not None:
+            tracing.emit_span(
+                "serve.hop", trace_id=trace.get("trace_id", ""),
+                parent_span_id=trace.get("parent_span_id", ""),
+                ts=trace["route_ts"], dur=entered - trace["route_ts"],
+                kind="route", request_id=trace.get("request_id", ""),
+                deployment=trace.get("deployment", ""))
+        with self._lock:
+            yield time.time()
 
     def engine_info(self) -> Dict[str, Any]:
         """What this replica's engine actually runs and where: the
@@ -370,6 +408,7 @@ class ContinuousLlamaDeployment:
         exactly what the ingress journal recovers from."""
         from ray_tpu._private import chaos
 
+        entered = time.time()
         resumed_tokens = 0
         if isinstance(prompt_token_ids, dict):
             payload = prompt_token_ids
@@ -391,10 +430,11 @@ class ContinuousLlamaDeployment:
         if chaos.enabled():
             chaos.inject("serve_replica", phase="prefill",
                          tokens=len(prompt_token_ids))
-        with self._lock:
+        with self._submitting(entered, trace) as locked:
             rid = self.batcher.submit(list(prompt_token_ids),
                                       max_new_tokens=int(max_tokens),
                                       trace=trace)
+            self.batcher.note_submit_wait(rid, entered, locked)
             self._queues[rid] = q
         self._work.set()
         done = False
@@ -449,6 +489,7 @@ class ContinuousLlamaDeployment:
         from ray_tpu._private import chaos
         from ray_tpu.serve import kv_transfer
 
+        entered = time.time()
         prompt = list(payload["prompt_token_ids"])
         max_tokens = int(payload.get("max_tokens", 16))
         resumed_tokens = int(payload.get("resumed_tokens", 0) or 0)
@@ -463,10 +504,11 @@ class ContinuousLlamaDeployment:
             chaos.inject("serve_replica", phase="prefill",
                          tokens=len(prompt))
         q = self._queue_mod.Queue()
-        with self._lock:
+        with self._submitting(entered, trace) as locked:
             rid = self.batcher.submit(prompt,
                                       max_new_tokens=max_tokens,
                                       trace=trace)
+            self.batcher.note_submit_wait(rid, entered, locked)
             self._queues[rid] = q
         self._work.set()
         tokens: List[int] = []
@@ -525,6 +567,7 @@ class ContinuousLlamaDeployment:
         from ray_tpu._private import chaos
         from ray_tpu.serve import kv_transfer
 
+        entered = time.time()
         manifest = request["manifest"]
         ticket = request.get("reservation")
         res_id = None
@@ -533,7 +576,7 @@ class ContinuousLlamaDeployment:
             res_id = ticket.get("res_id")
         trace = self._request_trace()
         q = self._queue_mod.Queue()
-        with self._lock:
+        with self._submitting(entered, trace) as locked:
             # The engine fires its first-token callback during the
             # import, before any queue could be registered under the
             # fresh rid — the manifest's first_token is delivered
@@ -541,6 +584,7 @@ class ContinuousLlamaDeployment:
             rid = kv_transfer.receive_handoff(
                 self.batcher, manifest, reservation=res_id,
                 trace=trace, deployment=self._req_deployment())
+            self.batcher.note_submit_wait(rid, entered, locked)
             self._queues[rid] = q
         self._work.set()
         done = False

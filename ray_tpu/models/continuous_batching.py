@@ -77,6 +77,9 @@ from ray_tpu.ops.paged_decode_attention import (paged_applicable,
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.util import tracing
 
+# Children of a ``tracing.phase``: names in the profiler's trace only.
+_annotation = jax.profiler.TraceAnnotation
+
 
 def _apply_rope_batched(x, cos, sin):
     """RoPE with per-batch angles: x [B, 1, H, D], cos/sin [B, D//2]."""
@@ -1328,6 +1331,26 @@ class ContinuousBatcher:
                 "request_id": t.get("request_id", ""),
                 "rid": rec["rid"]}
 
+    def note_submit_wait(self, rid: int, entered: float,
+                         locked: float) -> None:
+        """The head of ``rid``'s TTFT chain, which ``submit`` cannot see:
+        its caller entered the replica method at ``entered`` and held
+        the engine's lock (the one ``step`` runs under) at ``locked``,
+        both ``time.time()``. Call it under that lock, right after the
+        submit or import that returned ``rid``."""
+        rec = self._req_meta.get(rid)
+        if rec is None:
+            return          # finished inside the submit: nothing to tag
+        from ray_tpu._private import metrics_defs as mdefs
+
+        rec["lock_wait_s"] = max(locked - entered, 0.0)
+        mdefs.SERVE_REQ_LOCK_WAIT.observe(rec["lock_wait_s"],
+                                          tags=self._req_tags(rec))
+        if rec["traced"]:
+            tracing.emit_span("engine.submit_wait", ts=entered,
+                              dur=rec["lock_wait_s"],
+                              **self._span_common(rec))
+
     def _note_first_token(self, rec: Dict[str, Any], prefill_t0: float,
                           first_tok_ts: float) -> None:
         """First token just landed for ``rec``'s request: close the TTFT
@@ -1387,6 +1410,7 @@ class ContinuousBatcher:
         trace = rec.get("trace") or {}
         self.request_breakdowns.append({
             "rid": rid, "outcome": outcome, "tokens": tokens,
+            "lock_wait_s": rec.get("lock_wait_s"),
             "queue_s": rec.get("queue_s"),
             "arena_wait_s": rec.get("arena_wait_s"),
             "prefill_s": rec.get("prefill_s"),
@@ -2110,6 +2134,16 @@ class ContinuousBatcher:
             return
         from ray_tpu._private import metrics_defs as mdefs
 
+        with tracing.phase("engine.admit", mdefs.CB_STEP_ADMIT_MS,
+                           self._mtags) as admit:
+            self._admit_waiting(admit)
+
+    def _admit_waiting(self, admit: "tracing.phase") -> None:
+        """``_admit``'s work, inside its ``engine.admit`` phase: each
+        prefill program below is an ``engine.prefill`` phase that takes
+        its time out of ``admit``'s, so ``admit`` books host work only."""
+        from ray_tpu._private import metrics_defs as mdefs
+
         # Drain every admissible request FIRST, grouped by (pow-2 suffix
         # bucket, matched-prefix blocks) — compile reuse, never beyond
         # the cache length — so an admission burst costs one prefill
@@ -2215,28 +2249,34 @@ class ContinuousBatcher:
                     k = min(len(new_blocks), npb_w)
                     tables_w[i, :k] = new_blocks[:k]
                     ptables[i, :m] = blocks[:m]
-            t0 = time.perf_counter()
             pt0 = time.time()  # wall-clock anchor for the prefill span
-            pstep = self._place(np.int32(self._prefill_count))
-            self._prefill_count += 1
-            if self.paged:
-                first, self.cache = self._prefill(
-                    self.params, self._place(tokens), self.cache,
-                    self._place(ptables), self._place(tables_w),
-                    self._place(last_idx), pstep)
-            else:
-                first, self.cache = self._prefill(
-                    self.params, self._place(tokens), self.cache,
-                    self._place(slots), self._place(last_idx), pstep)
-            first = np.asarray(first)            # N ints, one transfer
+            with tracing.phase("engine.prefill", mdefs.CB_PREFILL_MS,
+                               self._mtags, outer=admit) as prefill:
+                with _annotation("engine.prefill.dispatch"):
+                    pstep = self._place(np.int32(self._prefill_count))
+                    self._prefill_count += 1
+                    if self.paged:
+                        first, self.cache = self._prefill(
+                            self.params, self._place(tokens), self.cache,
+                            self._place(ptables), self._place(tables_w),
+                            self._place(last_idx), pstep)
+                    else:
+                        first, self.cache = self._prefill(
+                            self.params, self._place(tokens), self.cache,
+                            self._place(slots), self._place(last_idx),
+                            pstep)
+                with _annotation("engine.prefill.fetch"):
+                    first = np.asarray(first)    # N ints, one transfer
             # The fetch syncs the dispatch, so this interval is the real
             # prefill cost — bench_serve derives prefill tokens/s from
             # it without decode/queueing time polluting the denominator,
             # and the XLA monitor turns it into achieved-FLOPs/bandwidth
             # gauges against this bucket's compiler cost analysis.
-            prefill_wall = time.perf_counter() - t0
+            prefill_wall = prefill.ms / 1e3
             self.prefill_seconds += prefill_wall
-            self._prefill.note_execution(prefill_wall)
+            with tracing.phase("engine.account", mdefs.CB_STEP_ACCOUNT_MS,
+                               self._mtags, outer=admit):
+                self._prefill.note_execution(prefill_wall)
             self._prefill_shapes.add((n_pad, padded_len))
             true_tokens = int(last_idx[:n].sum()) + n
             self.prefill_batches += 1
@@ -2331,26 +2371,30 @@ class ContinuousBatcher:
                                  tokens=len(st["out"]))
 
     def _upload_state(self) -> None:
-        tokens = np.zeros(self.num_slots, np.int32)
-        positions = np.zeros(self.num_slots, np.int32)
-        for slot, st in self._slots.items():
-            tokens[slot] = st["last"]
-            positions[slot] = st["pos"]
-        self._d_tokens = self._place(tokens)
-        self._d_positions = self._place(positions)
-        # The device sampling-step counter rewinds to the host-applied
-        # count: speculative ticks a rewind discarded replay the SAME
-        # step numbers, so sampled decode reproduces exactly like greedy.
-        self._d_step = self._place(np.int32(self._applied_steps))
-        if self.paged:
-            tables = np.zeros((self.num_slots, self.max_blocks), np.int32)
-            limits = np.zeros(self.num_slots, np.int32)
-            for slot, blocks in self._slot_blocks.items():
-                tables[slot] = self._table_row(blocks)
-                limits[slot] = len(blocks) * self.block_size
-            self._d_tables = self._place(tables)
-            self._d_limits = self._place(limits)
-        self._dirty = False
+        from ray_tpu._private import metrics_defs as mdefs
+
+        with tracing.phase("engine.upload", mdefs.CB_STEP_UPLOAD_MS,
+                           self._mtags):
+            tokens = np.zeros(self.num_slots, np.int32)
+            positions = np.zeros(self.num_slots, np.int32)
+            for slot, st in self._slots.items():
+                tokens[slot] = st["last"]
+                positions[slot] = st["pos"]
+            self._d_tokens = self._place(tokens)
+            self._d_positions = self._place(positions)
+            # The device sampling-step counter rewinds to the host-applied
+            # count: speculative ticks a rewind discarded replay the SAME
+            # step numbers, so sampled decode reproduces exactly like greedy.
+            self._d_step = self._place(np.int32(self._applied_steps))
+            if self.paged:
+                tables = np.zeros((self.num_slots, self.max_blocks), np.int32)
+                limits = np.zeros(self.num_slots, np.int32)
+                for slot, blocks in self._slot_blocks.items():
+                    tables[slot] = self._table_row(blocks)
+                    limits[slot] = len(blocks) * self.block_size
+                self._d_tables = self._place(tables)
+                self._d_limits = self._place(limits)
+            self._dirty = False
 
     def _run_tick(self):
         """Dispatch one decode tick. Returns the device row to fetch:
@@ -2426,55 +2470,56 @@ class ContinuousBatcher:
         recorded per traced request for the decode-window spans (windows
         must attach BEFORE ``_maybe_finish`` pops the record, so this
         rides the apply loop, not a post-pass)."""
-        finished_any = False
-        applied = 0
-        drafted = 0
-        accepted = 0
-        track = window is not None and self._traced_live > 0
-        if track:
-            w1 = window[1]
-            w0 = window[0] if window[0] is not None else w1
-            entries: Dict[int, list] = {}
-        # One device tick == one sampling step regardless of how many
-        # tokens it committed (spec windows burn exactly one step number),
-        # so the rewind counter advances per ROW, not per token.
-        self._applied_steps += len(nxt_rows)
-        for row in nxt_rows:
-            if isinstance(row, tuple):
-                toks, counts = row   # spec tick: ([B, k+1], [B]) committed
-            else:
-                toks, counts = row, None
-            for slot, rid in membership:
-                st = self._slots.get(slot)
-                if st is None or st["rid"] != rid:
-                    continue  # finished earlier in this batch: skip tail
-                n = 1 if counts is None else int(counts[slot])
-                if counts is not None:
-                    drafted += toks.shape[1] - 1
-                    accepted += n - 1
-                for j in range(n):
-                    tok = int(toks[slot]) if counts is None else int(
-                        toks[slot, j])
-                    if self.token_callback is not None:
-                        self.token_callback(rid, tok)
-                    st["out"].append(tok)
-                    st["last"] = tok
-                    st["pos"] += 1
-                    applied += 1
-                    if track:
-                        self._record_window_token(rid, entries, w0, w1)
-                    self._maybe_finish(slot)
-                    if slot not in self._slots:
-                        # EOS / max_new mid-window: the rest of the
-                        # committed window is past the request's end —
-                        # drop it (device-side overrun rewinds with the
-                        # dirty re-upload the finish already forces).
-                        finished_any = True
-                        break
-        self.decoded_tokens += applied
-        if applied or drafted:
-            from ray_tpu._private import metrics_defs as mdefs
+        from ray_tpu._private import metrics_defs as mdefs
 
+        with tracing.phase("engine.apply", mdefs.CB_STEP_APPLY_MS,
+                           self._mtags):
+            finished_any = False
+            applied = 0
+            drafted = 0
+            accepted = 0
+            track = window is not None and self._traced_live > 0
+            if track:
+                w1 = window[1]
+                w0 = window[0] if window[0] is not None else w1
+                entries: Dict[int, list] = {}
+            # One device tick == one sampling step regardless of how many
+            # tokens it committed (spec windows burn exactly one step number),
+            # so the rewind counter advances per ROW, not per token.
+            self._applied_steps += len(nxt_rows)
+            for row in nxt_rows:
+                if isinstance(row, tuple):
+                    toks, counts = row   # spec tick: ([B, k+1], [B]) committed
+                else:
+                    toks, counts = row, None
+                for slot, rid in membership:
+                    st = self._slots.get(slot)
+                    if st is None or st["rid"] != rid:
+                        continue  # finished earlier in this batch: skip tail
+                    n = 1 if counts is None else int(counts[slot])
+                    if counts is not None:
+                        drafted += toks.shape[1] - 1
+                        accepted += n - 1
+                    for j in range(n):
+                        tok = int(toks[slot]) if counts is None else int(
+                            toks[slot, j])
+                        if self.token_callback is not None:
+                            self.token_callback(rid, tok)
+                        st["out"].append(tok)
+                        st["last"] = tok
+                        st["pos"] += 1
+                        applied += 1
+                        if track:
+                            self._record_window_token(rid, entries, w0, w1)
+                        self._maybe_finish(slot)
+                        if slot not in self._slots:
+                            # EOS / max_new mid-window: the rest of the
+                            # committed window is past the request's end —
+                            # drop it (device-side overrun rewinds with the
+                            # dirty re-upload the finish already forces).
+                            finished_any = True
+                            break
+            self.decoded_tokens += applied
             if applied:
                 mdefs.CB_DECODE_TOKENS.inc(applied, tags=self._mtags)
             if drafted:
@@ -2484,7 +2529,7 @@ class ContinuousBatcher:
                 mdefs.CB_SPEC_DRAFT_TOKENS.inc(drafted, tags=self._mtags)
                 mdefs.CB_SPEC_ACCEPTED_TOKENS.inc(accepted,
                                                   tags=self._mtags)
-        return finished_any
+            return finished_any
 
     # Accept-rate controller thresholds: shrink k below LOW (drafts are
     # wasting verify bandwidth), grow above HIGH (more look-ahead pays),
@@ -2572,28 +2617,35 @@ class ContinuousBatcher:
             # request still alive. Drains under load and streaming
             # timeouts must ride it out.
             chaos.inject("serve_tick", engine=self._mtags["engine"])
-        self._emit_gauges()
-        if self.sync_every == 1:
-            self._adapt_spec_k()
+        sync = self.sync_every == 1
+        with tracing.phase("engine.account", mdefs.CB_STEP_ACCOUNT_MS,
+                           self._mtags):
+            self._emit_gauges()
+            if sync:
+                self._adapt_spec_k()
+        if sync:
             self._admit()
             if self._slots:
                 if self._dirty:
                     self._upload_state()
                 w0 = time.time() if self._traced_live else None
-                t0 = time.perf_counter()
-                nxt_dev = self._run_tick()
-                if isinstance(nxt_dev, tuple):
-                    nxt = (np.asarray(nxt_dev[0]), np.asarray(nxt_dev[1]))
-                else:
-                    nxt = np.asarray(nxt_dev)  # 4 bytes/slot
                 # Per-tick sync: the fetch IS the device sync, so this is
                 # the honest tick latency (dispatch + compute + fetch) —
                 # also the denominator for the tick's achieved-FLOPs/
                 # bandwidth gauges. The bytes hint keeps achieved
                 # bandwidth priced off LIVE tokens, not the compiled
                 # worst case.
-                tick_wall = time.perf_counter() - t0
-                mdefs.CB_TICK_MS.observe(tick_wall * 1e3, tags=self._mtags)
+                with tracing.phase("engine.tick", mdefs.CB_TICK_MS,
+                                   self._mtags) as tick:
+                    with _annotation("engine.tick.dispatch"):
+                        nxt_dev = self._run_tick()
+                    with _annotation("engine.tick.fetch"):
+                        if isinstance(nxt_dev, tuple):
+                            nxt = (np.asarray(nxt_dev[0]),
+                                   np.asarray(nxt_dev[1]))
+                        else:
+                            nxt = np.asarray(nxt_dev)  # 4 bytes/slot
+                tick_wall = tick.ms / 1e3
                 # Paged ticks get the live-byte hint (the compiled cost
                 # prices every table entry as live); the dense program's
                 # own cost analysis is already accurate — including the
@@ -2603,11 +2655,13 @@ class ContinuousBatcher:
                 # priced for k draft passes + the wider verify window.
                 tick_fn = (self._spec_ticks[self._last_tick_k]
                            if self._last_tick_k else self._tick)
-                tick_fn.note_execution(
-                    tick_wall,
-                    bytes_hint=(self.tick_bytes_estimate(
-                        spec_k=self._last_tick_k)
-                                if self.paged else None))
+                with tracing.phase("engine.account",
+                                   mdefs.CB_STEP_ACCOUNT_MS, self._mtags):
+                    tick_fn.note_execution(
+                        tick_wall,
+                        bytes_hint=(self.tick_bytes_estimate(
+                            spec_k=self._last_tick_k)
+                                    if self.paged else None))
                 if self._apply_tokens(
                         [nxt], [(s, st["rid"])
                                 for s, st in self._slots.items()],

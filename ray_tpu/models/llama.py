@@ -260,31 +260,35 @@ def forward(
 
     def layer_fn(carry, layer):
         x, aux_sum = carry
-        h = rms_norm(x, layer["attn_norm"], c.rms_eps)
-        q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(c.dtype))
-        k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(c.dtype))
-        v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(c.dtype))
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if mesh is not None:
-            q = constrain(q, mesh, "batch", "seq", "act_heads", None)
-            k = constrain(k, mesh, "batch", "seq", "act_kv_heads", None)
-            v = constrain(v, mesh, "batch", "seq", "act_kv_heads", None)
-        q = checkpoint_name(q, "q")
-        k = checkpoint_name(k, "k")
-        v = checkpoint_name(v, "v")
-        o = _attend(q, k, v, c, mesh)
-        o = checkpoint_name(o, "attn_out")
-        o = jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
-        x = x + o
-        if mesh is not None:
-            x = constrain(x, mesh, "batch", "seq", "act_embed")
+        # Scope names ride each instruction's metadata into the compiled
+        # program and the profiler's trace; they change no arithmetic.
+        with jax.named_scope("attention"):
+            h = rms_norm(x, layer["attn_norm"], c.rms_eps)
+            q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(c.dtype))
+            k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(c.dtype))
+            v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(c.dtype))
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            if mesh is not None:
+                q = constrain(q, mesh, "batch", "seq", "act_heads", None)
+                k = constrain(k, mesh, "batch", "seq", "act_kv_heads", None)
+                v = constrain(v, mesh, "batch", "seq", "act_kv_heads", None)
+            q = checkpoint_name(q, "q")
+            k = checkpoint_name(k, "k")
+            v = checkpoint_name(v, "v")
+            o = _attend(q, k, v, c, mesh)
+            o = checkpoint_name(o, "attn_out")
+            o = jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
+            x = x + o
+            if mesh is not None:
+                x = constrain(x, mesh, "batch", "seq", "act_embed")
 
-        h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
-        down, aux = mlp(h, layer)
-        x = x + down
-        if mesh is not None:
-            x = constrain(x, mesh, "batch", "seq", "act_embed")
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
+            down, aux = mlp(h, layer)
+            x = x + down
+            if mesh is not None:
+                x = constrain(x, mesh, "batch", "seq", "act_embed")
         return (x, aux_sum + aux), None
 
     body = layer_fn
@@ -398,10 +402,11 @@ def loss_fn(
         nll, correct = chunk_stats(xc, tc, mc)
         return (carry[0] + nll, carry[1] + correct), None
 
-    (nll_sum, correct_sum), _ = jax.lax.scan(
-        scan_body, (jnp.zeros(()), jnp.zeros(())),
-        (xs.transpose(1, 0, 2, 3), ts.transpose(1, 0, 2),
-         ms.transpose(1, 0, 2)))
+    with jax.named_scope("loss_head"):
+        (nll_sum, correct_sum), _ = jax.lax.scan(
+            scan_body, (jnp.zeros(()), jnp.zeros(())),
+            (xs.transpose(1, 0, 2, 3), ts.transpose(1, 0, 2),
+             ms.transpose(1, 0, 2)))
     total = jnp.maximum(jnp.sum(m), 1.0)
     loss = nll_sum / total
     acc = correct_sum / total

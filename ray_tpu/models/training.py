@@ -270,10 +270,11 @@ class ShardedTrainer:
         def step_fn(state: TrainState, batch: Dict[str, jnp.ndarray]):
             compute = _grads_direct if M == 1 else _grads_microbatched
             loss_val, metrics, grads = compute(state.params, batch)
-            updates, new_opt = optimizer.update(
-                grads, state.opt_state, state.params
-            )
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = optimizer.update(
+                    grads, state.opt_state, state.params
+                )
+                new_params = optax.apply_updates(state.params, updates)
             new_params = jax.tree.map(
                 jax.lax.with_sharding_constraint, new_params, self.param_shardings
             )

@@ -155,6 +155,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",
         cost_estimate=pl.CostEstimate(
             flops=4 * b * hq * sq * sk * d // (2 if causal else 1),
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
@@ -274,6 +275,7 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid over q-heads; each q-head contributes to its kv head. To
@@ -302,6 +304,7 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     dk = dk_ph.reshape(b, hkv, group, sk, d).sum(axis=2).astype(k.dtype)

@@ -203,6 +203,7 @@ def _decode_fused(q, cache_k, cache_v, positions, *, scale, block_k,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
+        name="decode_attn",
         cost_estimate=pl.CostEstimate(
             # One query row per slot: 2 matmuls over the live prefix.
             flops=4 * b * hq * s_max * d,

@@ -160,6 +160,7 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, *, k_scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attn",
         cost_estimate=pl.CostEstimate(
             # Static worst case: every table entry live. The engine feeds
             # the monitor a live-token byte estimate for achieved-BW.

@@ -1725,7 +1725,11 @@ class DeploymentHandle:
         # (emitted from the replica long after this returns) can parent
         # to it; the span itself closes when dispatch completes.
         route_span = tracing.gen_id()
-        rctx = {**rctx, "parent_span_id": route_span}
+        # ``route_ts``: the replica closes ``serve.hop`` (here to its
+        # method's entry) against it; every path to a replica, resumes
+        # included, passes through here.
+        rctx = {**rctx, "parent_span_id": route_span,
+                "route_ts": time.time()}
         with tracing.explicit_span(
                 "serve.route", trace_id=rctx.get("trace_id", ""),
                 span_id=route_span, parent_span_id=parent, kind="route",

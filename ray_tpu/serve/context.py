@@ -28,7 +28,9 @@ def get_request_context() -> Optional[Dict[str, Any]]:
     """The in-flight serve request's context, or None outside a serve
     call. Keys: ``request_id``, ``trace_id``, ``parent_span_id``,
     ``deployment``, ``tenant`` (the multiplexed model id, '' for
-    single-tenant deployments), and — on a request the recovery journal
+    single-tenant deployments), ``route_ts`` (``time.time()`` when the
+    handle dispatched it: the start of the ``serve.hop`` span) and — on
+    a request the recovery journal
     re-dispatched after replica death or a drain reject — ``attempt``
     (1-based redispatch count; absent on the first attempt). The ids
     stay IDENTICAL across attempts: a resumed request is one trace whose

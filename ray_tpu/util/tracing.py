@@ -67,6 +67,19 @@ def _ids() -> str:
     return uuid.uuid4().hex[:16]
 
 
+def _record(name: str, kind: str, trace_id: str, span_id: str,
+            parent_span_id: Optional[str], ts: float, dur: float,
+            **attrs) -> None:
+    """Hand one SPAN task-event to the reporter: the one place that
+    knows the record's shape (``ray-tpu timeline`` reads it back)."""
+    _get_reporter().add({
+        "state": "SPAN", "name": name, "kind": kind,
+        "task_id": span_id,
+        "trace_id": trace_id, "span_id": span_id,
+        "parent_span_id": parent_span_id or "",
+        "ts": ts, "dur": max(dur, 0.0), **_process_ids(), **attrs})
+
+
 def gen_id() -> str:
     """A fresh 16-hex trace/span/request id (public: the serve plane
     mints request ids and pre-allocates span ids with it)."""
@@ -86,13 +99,7 @@ def emit_span(name: str, *, trace_id: str, ts: float, dur: float,
     if not enabled():
         return ""
     span_id = span_id or _ids()
-    ids = _process_ids()
-    _get_reporter().add({
-        "state": "SPAN", "name": name, "kind": kind,
-        "task_id": span_id,
-        "trace_id": trace_id, "span_id": span_id,
-        "parent_span_id": parent_span_id or "",
-        "ts": ts, "dur": max(dur, 0.0), **ids, **attrs})
+    _record(name, kind, trace_id, span_id, parent_span_id, ts, dur, **attrs)
     return span_id
 
 
@@ -117,13 +124,8 @@ def explicit_span(name: str, *, trace_id: str,
         yield span_id
     finally:
         _local.ctx = prev
-        ids = _process_ids()
-        _get_reporter().add({
-            "state": "SPAN", "name": name, "kind": kind,
-            "task_id": span_id,
-            "trace_id": trace_id, "span_id": span_id,
-            "parent_span_id": parent_span_id or "",
-            "ts": t0, "dur": time.time() - t0, **ids, **attrs})
+        _record(name, kind, trace_id, span_id, parent_span_id, t0,
+                time.time() - t0, **attrs)
 
 
 @contextmanager
@@ -158,13 +160,56 @@ def _span_impl(name: str, kind: str = "task",
         yield span_id
     finally:
         _local.ctx = prev
-        ids = _process_ids()
-        _get_reporter().add({
-            "state": "SPAN", "name": name, "kind": kind,
-            "task_id": span_id,
-            "trace_id": trace_id, "span_id": span_id,
-            "parent_span_id": parent_span_id or "",
-            "ts": t0, "dur": time.time() - t0, **ids, **attrs})
+        _record(name, kind, trace_id, span_id, parent_span_id, t0,
+                time.time() - t0, **attrs)
+
+
+class phase:
+    """One measurement of one stretch of a hot thread, read two ways::
+
+        with tracing.phase("engine.upload", mdefs.CB_STEP_UPLOAD_MS, tags):
+            ...
+
+    The counter: the elapsed milliseconds are observed into ``hist``,
+    always. The span: the same interval is a
+    ``jax.profiler.TraceAnnotation``, which lands in the profiler's
+    trace, on the device events' clock, while a profiler session is
+    active (``ray-tpu profile``, ``benchmark/run.py --trace 1``) and
+    costs a flag check otherwise; ``util/profile_gaps.py`` lays the
+    device's idle gaps against these. A phase opened with
+    ``outer=<the phase around it>`` takes its time out of that one's
+    observation, so the two histograms add up to the outer interval.
+    ``ms`` holds the whole elapsed time after the block. ``RAY_TPU_TRACING``
+    does not switch it: that gates the request spans, this is a metric.
+    ``jax`` is imported on first use, so the control plane can import
+    this module without it."""
+
+    __slots__ = ("ms", "_hist", "_tags", "_outer", "_inner_ms",
+                 "_annotation", "_t0")
+    _annotate = None    # jax.profiler.TraceAnnotation, once imported
+
+    def __init__(self, name: str, hist, tags: Optional[Dict[str, str]] = None,
+                 outer: Optional["phase"] = None):
+        if phase._annotate is None:
+            import jax.profiler
+
+            phase._annotate = jax.profiler.TraceAnnotation
+        self._annotation = phase._annotate(name)
+        self._hist, self._tags, self._outer = hist, tags, outer
+        self._inner_ms = 0.0
+        self.ms = 0.0
+
+    def __enter__(self) -> "phase":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.ms = (time.perf_counter() - self._t0) * 1e3
+        self._annotation.__exit__(*exc)
+        self._hist.observe(self.ms - self._inner_ms, tags=self._tags)
+        if self._outer is not None:
+            self._outer._inner_ms += self.ms
 
 
 def _process_ids() -> Dict[str, str]:
@@ -187,12 +232,8 @@ def inject_context(spec) -> None:
     else:
         trace_id, parent = ctx
     submit_span = _ids()
-    ids = _process_ids()
-    _get_reporter().add({
-        "state": "SPAN", "name": f"submit:{spec.name}", "kind": "submit",
-        "task_id": submit_span,
-        "trace_id": trace_id, "span_id": submit_span,
-        "parent_span_id": parent, "ts": time.time(), "dur": 0.0, **ids})
+    _record(f"submit:{spec.name}", "submit", trace_id, submit_span, parent,
+            time.time(), 0.0)
     spec.trace_id = trace_id
     spec.parent_span_id = submit_span
 
@@ -242,4 +283,4 @@ def spans_to_chrome_events(records: List[Dict[str, Any]]) \
 
 __all__ = ["enabled", "span", "execute_span", "inject_context",
            "current", "set_context", "spans_to_chrome_events",
-           "gen_id", "emit_span", "explicit_span"]
+           "gen_id", "emit_span", "explicit_span", "phase"]

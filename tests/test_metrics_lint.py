@@ -8,6 +8,8 @@ metadata. New framework metrics belong in ``_private/metrics_defs.py``.
 
 import inspect
 
+import pytest
+
 from ray_tpu._private import metrics_defs
 from ray_tpu.util import metrics as metrics_mod
 
@@ -167,6 +169,33 @@ def test_serve_request_series_are_cataloged():
             if m.name != "ray_tpu_serve_request_latency_seconds":
                 # Attribution tags: per-deployment AND per-tenant.
                 assert {"deployment", "tenant"} <= set(m.tag_keys), m.name
+
+
+@pytest.mark.parametrize("name,tag,span", [
+    ("ray_tpu_cb_step_lock_wait_ms", "engine", "engine.lock_wait"),
+    ("ray_tpu_cb_step_admit_ms", "engine", "engine.admit"),
+    ("ray_tpu_cb_prefill_ms", "engine", "engine.prefill"),
+    ("ray_tpu_cb_step_upload_ms", "engine", "engine.upload"),
+    ("ray_tpu_cb_step_account_ms", "engine", "engine.account"),
+    ("ray_tpu_cb_step_apply_ms", "engine", "engine.apply"),
+    ("ray_tpu_serve_request_lock_wait_seconds", "deployment",
+     "engine.submit_wait"),
+])
+def test_engine_phase_series_are_cataloged(name, tag, span):
+    """The engine thread's phase counters and the head of the TTFT chain
+    (benchmark/metrics/*.json read them by these names): a histogram
+    each, described, tagged, documented in the README catalog, and the
+    description names the span that is the same measurement."""
+    import pathlib
+
+    import ray_tpu
+
+    (m,) = [m for m in _framework_metrics() if m.name == name]
+    assert isinstance(m, metrics_mod.Histogram)
+    assert tag in m.tag_keys and span in m.description
+    readme = (pathlib.Path(ray_tpu.__file__).resolve().parent.parent
+              / "README.md").read_text()
+    assert name in readme and span in readme
 
 
 def test_train_ingest_series_are_cataloged():

@@ -167,6 +167,117 @@ def test_tracing_disabled_records_no_windows_but_keeps_metrics():
         tracing._reporter = old
 
 
+def _hist_totals(*hists):
+    """``{histogram name: (sum, count)}``, label sets summed."""
+    out = {}
+    for h in hists:
+        got = {"sum": 0.0, "count": 0.0}
+        for name, _, v in h.samples():
+            got[name.rsplit("_", 1)[1]] += v
+        out[h.name] = (got["sum"], got["count"])
+    return out
+
+
+def test_engine_phases_cover_the_steps_wall_time():
+    """Every stretch of ``step()`` is inside a ``tracing.phase``: over a
+    run with admissions, re-uploads, ticks and finishes, each phase
+    histogram gains observations, and their sums add up to the wall
+    time of the steps within 10% (no part of the engine thread's loop is
+    left untimed, and the prefill program is not booked twice)."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    phases = (mdefs.CB_STEP_ADMIT_MS, mdefs.CB_PREFILL_MS,
+              mdefs.CB_STEP_UPLOAD_MS, mdefs.CB_TICK_MS,
+              mdefs.CB_STEP_ACCOUNT_MS, mdefs.CB_STEP_APPLY_MS)
+    cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
+    eng = ContinuousBatcher(cfg, num_slots=4, max_len=64, paged=True,
+                            block_size=16)
+    for i in range(4):                       # compile outside the clock
+        eng.submit([1, 2, 3, i + 1], max_new_tokens=4)
+    eng.run_to_completion()
+    before, batches_before = _hist_totals(*phases), eng.prefill_batches
+    for i in range(9):                       # 9 requests over 4 slots
+        eng.submit([1, 2, 3, i + 1], max_new_tokens=6 + i % 3)
+    wall_ms, steps = 0.0, 0
+    while eng.has_work():
+        t0 = time.perf_counter()
+        eng.step()
+        wall_ms += (time.perf_counter() - t0) * 1e3
+        steps += 1
+    after = _hist_totals(*phases)
+    gained = {k: (after[k][0] - before[k][0], after[k][1] - before[k][1])
+              for k in after}
+    assert all(n > 0 for _, n in gained.values()), gained
+    assert gained[mdefs.CB_TICK_MS.name][1] == steps
+    assert (gained[mdefs.CB_PREFILL_MS.name][1]
+            == eng.prefill_batches - batches_before)
+    covered = sum(ms for ms, _ in gained.values())
+    assert covered <= wall_ms
+    assert covered == pytest.approx(wall_ms, rel=0.10), (gained, wall_ms)
+
+
+def test_generate_under_a_held_lock_books_the_wait(ray_start_regular,
+                                                   span_capture):
+    """The head of the TTFT chain: a caller that finds the engine lock
+    taken (the tick thread holds it across each step) waits before
+    ``submit`` stamps anything. ``generate()`` books that wait in
+    SERVE_REQ_LOCK_WAIT and the request's breakdown, and, traced, as
+    ``serve.hop`` (router dispatch to entry) and ``engine.submit_wait``
+    spans that meet ``engine.queue`` without a gap; the tick thread's
+    own wait for the lock has its histogram too."""
+    import threading
+
+    from ray_tpu._private import metrics_defs as mdefs
+    from ray_tpu.llm import ContinuousLlamaDeployment
+    from ray_tpu.serve import context as serve_context
+
+    dep = ContinuousLlamaDeployment._cls_or_fn(
+        config=llama.LlamaConfig.tiny(dtype=jnp.float32), num_slots=2,
+        max_len=64)
+    list(dep.generate([1, 2, 3], 3))                 # compile
+    before = _hist_totals(mdefs.SERVE_REQ_LOCK_WAIT,
+                          mdefs.CB_STEP_LOCK_WAIT_MS)
+    held = 0.25
+    route_ts = time.time() - 0.05                    # "dispatched" 50 ms ago
+    rctx = _trace(request_id="req-lock", trace_id="c" * 16)
+    token = serve_context._set_request_context({**rctx,
+                                                "route_ts": route_ts})
+    dep._lock.acquire()
+    threading.Timer(held, dep._lock.release).start()
+    try:
+        t0 = time.time()
+        out = list(dep.generate([1, 2, 3], 3))
+    finally:
+        serve_context._reset_request_context(token)
+    assert len(out) == 3
+    after = _hist_totals(mdefs.SERVE_REQ_LOCK_WAIT,
+                         mdefs.CB_STEP_LOCK_WAIT_MS)
+    name = mdefs.SERVE_REQ_LOCK_WAIT.name
+    assert after[name][1] - before[name][1] == 1
+    waited = after[name][0] - before[name][0]
+    assert held * 0.8 <= waited <= held + 0.5, waited
+    tick = mdefs.CB_STEP_LOCK_WAIT_MS.name
+    assert after[tick][1] > before[tick][1]
+    (bd,) = [b for b in dep.batcher.request_breakdowns
+             if b["request_id"] == "req-lock"]
+    assert bd["lock_wait_s"] == pytest.approx(waited)
+    spans = {s["name"]: s for s in span_capture.records
+             if s.get("request_id") == "req-lock"}
+    assert {"serve.hop", "engine.submit_wait", "engine.queue",
+            "engine.prefill"} <= set(spans), sorted(spans)
+    hop, wait, queue = (spans["serve.hop"], spans["engine.submit_wait"],
+                        spans["engine.queue"])
+    assert all(s["trace_id"] == rctx["trace_id"]
+               and s["parent_span_id"] == rctx["parent_span_id"]
+               for s in (hop, wait, queue))
+    assert hop["ts"] == route_ts and hop["kind"] == "route"
+    assert hop["ts"] + hop["dur"] == pytest.approx(wait["ts"], abs=1e-6)
+    assert wait["ts"] == pytest.approx(t0, abs=0.05)
+    assert wait["dur"] == pytest.approx(waited)
+    # submit stamps its clock right after the lock is got: no gap.
+    assert wait["ts"] + wait["dur"] == pytest.approx(queue["ts"], abs=0.02)
+
+
 def test_pressure_snapshot_and_replica_probe():
     """Engine pressure snapshot carries the router's inputs, and the
     serve Replica wrapper merges a hosted deployment's pressure() into
@@ -257,6 +368,91 @@ def test_event_buffer_drops_are_counted():
         pub.add({"i": i})
     assert count() == before + 5  # cap//2 shed on overflow
     assert dropped_counts().get("publisher:TEST_DROPS", 0) >= 5
+
+
+def test_streamed_request_chain_has_no_gap_and_sums_to_client_ttft(
+        ray_start_regular, span_capture):
+    """One streamed HTTP request, traced: ``serve.ingress > serve.route >
+    serve.hop > engine.submit_wait > engine.queue > engine.prefill`` is
+    one trace, each link starts where the one before it ended, and what
+    the links add up to is the client's time to first token less the
+    first token's way back out (replica -> proxy -> socket), which no
+    span covers yet."""
+    from ray_tpu import serve
+    from ray_tpu.llm import ContinuousLlamaDeployment
+
+    serve.run(ContinuousLlamaDeployment.options(name="ChainLlama").bind(
+        num_slots=2, max_len=64), name="chain")
+    port = serve.start_http(port=0)
+    try:
+        _check_streamed_chain(port, span_capture)
+    finally:
+        serve.stop_http()
+        serve.shutdown()
+
+
+def _check_streamed_chain(port, span_capture):
+    import http.client
+
+    def stream(req_id):
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+        body = json.dumps({"prompt_token_ids": [1, 2, 3], "max_tokens": 4})
+        sent = time.time()
+        conn.request("POST", "/ChainLlama/stream/generate",
+                     body=body, headers={"Content-Type": "application/json",
+                                         "x-request-id": req_id})
+        resp = conn.getresponse()
+        assert resp.status == 200, resp.read()
+        first_line = resp.readline()        # http.client undoes the chunks
+        first = time.time()
+        rest = resp.read()
+        conn.close()
+        tokens = [json.loads(x) for x in (first_line + rest).splitlines()
+                  if x.strip()]
+        assert len(tokens) == 4, tokens
+        return sent, first
+
+    stream("req-chain-warmup")                   # compiles
+    req_id = "req-chain-0123456789abcdef"
+    sent, first = stream(req_id)
+    deadline = time.monotonic() + 10    # the ingress span closes last
+    while time.monotonic() < deadline:
+        trace = [e for e in span_capture.records
+                 if e.get("request_id") == req_id]
+        if any(e["name"] == "serve.ingress" for e in trace):
+            break
+        time.sleep(0.05)
+    assert len({e["trace_id"] for e in trace}) == 1
+    by_name = {e["name"]: e for e in trace}
+    chain = ["serve.ingress", "serve.route", "serve.hop",
+             "engine.submit_wait", "engine.queue", "engine.prefill"]
+    assert set(chain) <= set(by_name), sorted(by_name)
+    ingress, route, hop, wait, queue, prefill = (by_name[n] for n in chain)
+    assert route["parent_span_id"] == ingress["span_id"]
+    for e in (hop, wait, queue, prefill):
+        assert e["parent_span_id"] == route["span_id"], e["name"]
+
+    def end(e):
+        return e["ts"] + e["dur"]
+
+    # Each link starts where the last ended (within scheduling noise).
+    assert sent <= ingress["ts"] <= route["ts"] + 1e-3
+    assert hop["ts"] == pytest.approx(route["ts"], abs=0.01)
+    assert end(hop) == pytest.approx(wait["ts"], abs=1e-4)
+    assert end(wait) == pytest.approx(queue["ts"], abs=0.01)
+    arena = by_name.get("engine.arena_wait")
+    assert end(queue) + (arena["dur"] if arena else 0.0) == pytest.approx(
+        prefill["ts"], abs=0.01)
+    parts = ((route["ts"] - ingress["ts"]) + hop["dur"] + wait["dur"]
+             + queue["dur"] + (arena["dur"] if arena else 0.0)
+             + prefill["dur"])
+    assert parts == pytest.approx(end(prefill) - ingress["ts"], abs=0.03)
+    client_ttft = first - sent
+    way_back = first - end(prefill)
+    assert 0 <= way_back < 1.0, way_back
+    assert parts + (ingress["ts"] - sent) + way_back == pytest.approx(
+        client_ttft, abs=0.03)
+    assert parts <= client_ttft
 
 
 @pytest.fixture()

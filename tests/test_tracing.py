@@ -125,3 +125,79 @@ def test_tracing_disabled_adds_no_spans():
         assert tracing.current() is None
     finally:
         ray_tpu.shutdown()
+
+
+def _hist(name):
+    from ray_tpu.util.metrics import Histogram
+
+    return Histogram(name, "test", boundaries=(1.0, 10.0),
+                     tag_keys=("engine",))
+
+
+def _sum_count(hist):
+    got = {name.rsplit("_", 1)[1]: v for name, _, v in hist.samples()}
+    return got.get("sum", 0.0), got.get("count", 0.0)
+
+
+def test_phase_observes_its_histogram_and_takes_an_inner_phase_out():
+    """The counter half of ``tracing.phase``: always on (no
+    RAY_TPU_TRACING, no profiler session), one observation a block, and
+    an inner phase opened with ``outer=`` is taken out of the outer
+    one's observation, so the two add up to the outer interval."""
+    assert not tracing.enabled()
+    outer_h, inner_h = _hist("t_phase_outer_ms"), _hist("t_phase_inner_ms")
+    tags = {"engine": "e0"}
+    with tracing.phase("t.outer", outer_h, tags) as outer:
+        time.sleep(0.02)
+        with tracing.phase("t.inner", inner_h, tags, outer=outer) as inner:
+            time.sleep(0.03)
+    assert inner.ms >= 30 and outer.ms >= inner.ms + 20
+    (o_sum, o_n), (i_sum, i_n) = _sum_count(outer_h), _sum_count(inner_h)
+    assert o_n == 1 and i_n == 1
+    assert i_sum == pytest.approx(inner.ms)
+    assert o_sum == pytest.approx(outer.ms - inner.ms)
+    assert o_sum + i_sum == pytest.approx(outer.ms)
+    # An exception still closes the interval and books it.
+    with pytest.raises(KeyError):
+        with tracing.phase("t.outer", outer_h, tags):
+            raise KeyError("x")
+    assert _sum_count(outer_h)[1] == 2
+
+
+def test_phase_is_a_span_only_while_a_profiler_session_is_active(tmp_path):
+    """The span half: a ``TraceAnnotation`` of the same interval, which
+    reaches a trace only under a profiler session, at the host tracer
+    level the benchmark's harness uses (1), on the profiler's clock."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    hist = _hist("t_phase_span_ms")
+
+    def names_in_trace(where):
+        paths = glob.glob(str(where / "plugins" / "profile" / "*" /
+                              "*.xplane.pb"))
+        return {ev.name
+                for path in paths
+                for plane in ProfileData.from_file(path).planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events}
+
+    with tracing.phase("t.before_session", hist):
+        pass
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with tracing.phase("t.in_session", hist):
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    with tracing.phase("t.after_session", hist):
+        pass
+    assert _sum_count(hist)[1] == 3          # the counter saw all three
+    names = names_in_trace(tmp_path)
+    assert "t.in_session" in names
+    assert not {"t.before_session", "t.after_session"} & names
