@@ -224,6 +224,15 @@ def _check(phase: str, name: str, got, ref, bound: float) -> None:
     assert err <= bound, f"{name}: {err:.3e} exceeds {bound:.0e}"
 
 
+def _same(phase: str, name: str, got, ref) -> None:
+    import numpy as np
+
+    assert got.shape == ref.shape and got.dtype == ref.dtype, name
+    diff = int(np.sum(np.asarray(got) != np.asarray(ref)))
+    _say(phase, f"{name}: {diff} of {got.size} elements differ")
+    assert diff == 0, f"{name}: not bit-identical"
+
+
 def phase_kernels(rehearse: bool) -> None:
     phase = "kernels"
     info = _open_device(phase, rehearse)
@@ -237,7 +246,7 @@ def phase_kernels(rehearse: bool) -> None:
                                               decode_attention,
                                               decode_attention_reference)
     from ray_tpu.ops.paged_decode_attention import (
-        paged_attention_reference, paged_decode_attention)
+        paged_attention_reference, paged_decode_attention, paged_kv_write)
 
     if not rehearse:
         assert "RAY_TPU_PALLAS_INTERPRET" not in os.environ, \
@@ -331,6 +340,42 @@ def phase_kernels(rehearse: bool) -> None:
         _check(phase, f"paged decode int8 ({tag})",
                paged8(qd, kq, vq, tables, positions, ks, vs), ref8,
                TOLERANCE["decode_int8"])
+
+        # -- the engine's form: whole arena, layer index, in-place write --
+        # Layer 0 holds (K, V), the last layer (V, K): a read at either
+        # must be the slab call's bits, whatever lies in between.
+        mid = jnp.zeros_like(ak)
+        ak3, av3 = jnp.stack([ak, mid, av]), jnp.stack([av, mid, ak])
+        layered = jax.jit(lambda q, k, v, t, p, li: paged_decode_attention(
+            q, k, v, t, p, layer=li, use_kernel=True))
+        for li, (sk, sv) in ((0, (ak, av)), (2, (av, ak))):
+            _same(phase, f"paged decode at layer {li} of 3 ({tag})",
+                  layered(qd, ak3, av3, tables, positions, jnp.int32(li)),
+                  paged(qd, sk, sv, tables, positions))
+        # A tick's write (one token a slot) and a verify window's (4
+        # tokens that straddle a block boundary in every third slot;
+        # every fourth slot is freed and aims at the garbage block).
+        write = jax.jit(paged_kv_write, donate_argnums=(0,))
+        for width in (1, 4):
+            start = jnp.where(jnp.arange(slots) % 3 == 0, bs - 2,
+                              positions % (s_max - width))
+            start = start.at[0].set(0).at[1].set(s_max - width)
+            wpos = start[:, None] + jnp.arange(width)[None, :]
+            blk = jnp.take_along_axis(tables, wpos // bs, axis=1)
+            blk = jnp.where((jnp.arange(slots) % 4 == 3)[:, None], 0, blk)
+            off = wpos % bs
+            for name, arena, new in (
+                    ("bf16", ak3, ck[:, :width]),
+                    ("int8", jnp.stack([kq, kq, vq]), vq[:slots, :, :width]
+                     .swapaxes(1, 2)),
+                    ("scale", jnp.stack([ks, ks, vs]), vs[:slots, :, :width]
+                     .swapaxes(1, 2))):
+                want = arena.at[1, blk.reshape(-1), :, off.reshape(-1)].set(
+                    new.reshape(-1, *new.shape[2:]))
+                got = write(arena + 0, new, jnp.int32(1), blk, off)
+                # The garbage block holds whichever freed row came last.
+                _same(phase, f"paged_kv_write {name}, {width} token(s) a "
+                             f"slot ({tag})", got[:, 1:], want[:, 1:])
     _finish(phase, info)
 
 

@@ -73,7 +73,8 @@ from ray_tpu.ops.decode_attention import (decode_applicable,
                                           decode_attention_reference,
                                           env_flag)
 from ray_tpu.ops.paged_decode_attention import (paged_applicable,
-                                                paged_decode_attention)
+                                                paged_decode_attention,
+                                                paged_kv_write)
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.util import tracing
 
@@ -108,6 +109,64 @@ def _scatter_arena(arena, new, block_idx, offset):
     Freed slots all target the garbage block — duplicate indices write
     byte-garbage there, which nothing ever attends."""
     return arena.at[block_idx, :, offset].set(new.astype(arena.dtype))
+
+
+def _write_then_attend(arenas, li, q, k_new, v_new, block_idx, offset,
+                       tables, positions, scale, use_kernel: bool):
+    """The layer body the paged tick, the self-draft and verify share:
+    write each slot's S new tokens' K/V into layer ``li`` (S = 1 for a
+    tick, k+1 for verify), then attend every window position over the
+    slot's blocks. ``arenas`` = (k, v, k_scale, v_scale), each the WHOLE
+    ``[L, NB, KVH, bs, ...]`` array as the layer scan carries it (scales
+    None for a bf16 arena); q/k_new/v_new [B, S, Hq|KVH, D];
+    block_idx/offset/positions [B, S]. All S writes land before any
+    query attends, which position masking makes safe (query j sees
+    [0..p+j] only). Returns (o [B, S, Hq, D], arenas').
+
+    With the kernels the write is a Mosaic call aliased onto the carry
+    and the read takes the layer as a scalar, so no slab ever exists.
+    Without them (shapes that do not tile; the CPU reference) the slab
+    is sliced out, scattered by XLA and put back. On the TPU any XLA
+    write into the heads-major arena makes the compiler relayout the
+    slab for the scatter and again for the reader: that is what this
+    path costs there, and why the kernel path never takes it."""
+    def each(fn, *columns):
+        """``fn`` over (k, v, k_scale, v_scale); an absent scale stays
+        None."""
+        return tuple(None if col[0] is None else fn(*col)
+                     for col in zip(*columns))
+
+    new = (k_new, v_new, None, None)
+    if arenas[2] is not None:
+        # Per-token/per-head scales reduce over D only, so a
+        # window-batched quantize is bitwise the tick's.
+        kq, ksc = quantize_kv(k_new)
+        vq, vsc = quantize_kv(v_new)
+        new = (kq, vq, ksc, vsc)
+    if use_kernel:
+        arenas = view = each(
+            lambda a, n: paged_kv_write(a, n, li, block_idx, offset),
+            arenas, new)
+        layer = li
+    else:
+        flat_idx, flat_off = block_idx.reshape(-1), offset.reshape(-1)
+        view = each(
+            lambda a, n: _scatter_arena(
+                jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False),
+                n.reshape(-1, *n.shape[2:]), flat_idx, flat_off),
+            arenas, new)
+        arenas = each(
+            lambda a, slab: jax.lax.dynamic_update_index_in_dim(
+                a, slab, li, 0),
+            arenas, view)
+        layer = None
+    ck, cv, ks, vs = view
+    outs = [paged_decode_attention(q[:, j], ck, cv, tables,
+                                   positions[:, j], scale, layer=layer,
+                                   k_scale=ks, v_scale=vs,
+                                   use_kernel=use_kernel)
+            for j in range(q.shape[1])]   # unrolled: S = k+1, small
+    return jnp.stack(outs, axis=1), arenas
 
 
 def _blocks_to_ctx(a, n: int):
@@ -227,7 +286,6 @@ def _draft_forward_paged(params, n_draft, tokens, positions, tables,
     verify will rewrite identically. Returns (draft logits [B, V]
     through the target's final norm + lm_head, updated cache)."""
     c = config
-    quantized = cache.quantized
     bs = cache.block_size
     cos, sin = rope_frequencies(c.head_dim, 0, c.rope_theta,
                                 positions=positions)
@@ -239,47 +297,20 @@ def _draft_forward_paged(params, n_draft, tokens, positions, tables,
     offset = positions % bs
 
     def layer_fn(carry, layer):
-        x, ck_all, cv_all, ks_all, vs_all, li = carry
-        ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
+        x, arenas, li = carry
         q, k, v = _layer_qkv(x, layer, cos, sin, c)
-        k_tok, v_tok = k[:, 0], v[:, 0]
-        ksl = vsl = None
-        if quantized:
-            kq, ksc = quantize_kv(k_tok)
-            vq, vsc = quantize_kv(v_tok)
-            ksl = jax.lax.dynamic_index_in_dim(ks_all, li, 0,
-                                               keepdims=False)
-            vsl = jax.lax.dynamic_index_in_dim(vs_all, li, 0,
-                                               keepdims=False)
-            ksl = _scatter_arena(ksl, ksc, block_idx, offset)
-            vsl = _scatter_arena(vsl, vsc, block_idx, offset)
-        else:
-            kq, vq = k_tok, v_tok
-        ck = _scatter_arena(ck, kq, block_idx, offset)
-        cv = _scatter_arena(cv, vq, block_idx, offset)
-        o = paged_decode_attention(q[:, 0], ck, cv, tables, positions,
-                                   scale, k_scale=ksl, v_scale=vsl,
-                                   use_kernel=use_kernel)
-        o = o.astype(x.dtype)
-        ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
-        cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-        if quantized:
-            ks_all = jax.lax.dynamic_update_index_in_dim(ks_all, ksl,
-                                                         li, 0)
-            vs_all = jax.lax.dynamic_update_index_in_dim(vs_all, vsl,
-                                                         li, 0)
-        x = _layer_finish(x, o, layer, c)
-        return (x, ck_all, cv_all, ks_all, vs_all, li + 1), None
+        o, arenas = _write_then_attend(
+            arenas, li, q, k, v, block_idx[:, None], offset[:, None],
+            tables, positions[:, None], scale, use_kernel)
+        x = _layer_finish(x, o[:, 0].astype(x.dtype), layer, c)
+        return (x, arenas, li + 1), None
 
     sliced = jax.tree.map(lambda a: a[:n_draft], params["layers"])
-    carry0 = (x, cache.k, cache.v, cache.k_scale, cache.v_scale,
-              jnp.int32(0))
-    (x, nk, nv, nks, nvs, _), _ = jax.lax.scan(layer_fn, carry0, sliced)
+    (x, arenas, _), _ = jax.lax.scan(
+        layer_fn, (x, tuple(cache), jnp.int32(0)), sliced)
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     logits = lm_head_logits(x, params, c)
-    return logits[:, 0], PagedKVCache(k=nk, v=nv, k_scale=nks,
-                                      v_scale=nvs)
+    return logits[:, 0], PagedKVCache(*arenas)
 
 
 def _draft_forward_dense(dparams, tokens, positions, dcache: KVCache,
@@ -331,7 +362,6 @@ def _verify_forward_paged(params, tokens, positions, tables, limits,
     writes redirect to the garbage block exactly like the plain tick.
     Returns (fp32 logits [B, S, V], updated cache)."""
     c = config
-    quantized = cache.quantized
     bs = cache.block_size
     b, s = tokens.shape
     cos, sin = rope_frequencies(c.head_dim, 0, c.rope_theta,
@@ -342,55 +372,23 @@ def _verify_forward_paged(params, tokens, positions, tables, limits,
     scale = c.head_dim ** -0.5
     gathered = jnp.take_along_axis(tables, positions // bs, axis=1)
     block_idx = jnp.where(positions < limits[:, None], gathered,
-                          GARBAGE_BLOCK).reshape(-1)          # [B*S]
-    offset = (positions % bs).reshape(-1)
+                          GARBAGE_BLOCK)                      # [B, S]
+    offset = positions % bs
 
     def layer_fn(carry, layer):
-        x, ck_all, cv_all, ks_all, vs_all, li = carry
-        ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
+        x, arenas, li = carry
         q, k, v = _layer_qkv_window(x, layer, cos, sin, c)
-        k_tok = k.reshape(b * s, *k.shape[2:])
-        v_tok = v.reshape(b * s, *v.shape[2:])
-        ksl = vsl = None
-        if quantized:
-            # Per-token/per-head scales reduce over D only, so the
-            # window-batched quantize is bitwise the tick's.
-            kq, ksc = quantize_kv(k_tok)
-            vq, vsc = quantize_kv(v_tok)
-            ksl = jax.lax.dynamic_index_in_dim(ks_all, li, 0,
-                                               keepdims=False)
-            vsl = jax.lax.dynamic_index_in_dim(vs_all, li, 0,
-                                               keepdims=False)
-            ksl = _scatter_arena(ksl, ksc, block_idx, offset)
-            vsl = _scatter_arena(vsl, vsc, block_idx, offset)
-        else:
-            kq, vq = k_tok, v_tok
-        ck = _scatter_arena(ck, kq, block_idx, offset)
-        cv = _scatter_arena(cv, vq, block_idx, offset)
-        outs = []
-        for j in range(s):  # unrolled: s = k+1, small and static
-            outs.append(paged_decode_attention(
-                q[:, j], ck, cv, tables, positions[:, j], scale,
-                k_scale=ksl, v_scale=vsl, use_kernel=use_kernel))
-        o = jnp.stack(outs, axis=1).astype(x.dtype)       # [B, S, H, D]
-        ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
-        cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-        if quantized:
-            ks_all = jax.lax.dynamic_update_index_in_dim(ks_all, ksl,
-                                                         li, 0)
-            vs_all = jax.lax.dynamic_update_index_in_dim(vs_all, vsl,
-                                                         li, 0)
-        x = _layer_finish_window(x, o, layer, c)
-        return (x, ck_all, cv_all, ks_all, vs_all, li + 1), None
+        o, arenas = _write_then_attend(
+            arenas, li, q, k, v, block_idx, offset, tables, positions,
+            scale, use_kernel)
+        x = _layer_finish_window(x, o.astype(x.dtype), layer, c)
+        return (x, arenas, li + 1), None
 
-    carry0 = (x, cache.k, cache.v, cache.k_scale, cache.v_scale,
-              jnp.int32(0))
-    (x, nk, nv, nks, nvs, _), _ = jax.lax.scan(layer_fn, carry0,
-                                               params["layers"])
+    (x, arenas, _), _ = jax.lax.scan(
+        layer_fn, (x, tuple(cache), jnp.int32(0)), params["layers"])
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     logits = lm_head_logits(x, params, c)
-    return logits, PagedKVCache(k=nk, v=nv, k_scale=nks, v_scale=nvs)
+    return logits, PagedKVCache(*arenas)
 
 
 def _spec_tick_paged(params, tokens, positions, tables, limits,
@@ -517,7 +515,6 @@ def _decode_tick_paged(params, tokens, positions, tables, limits,
     freed slots point wholesale at the garbage block); ``limits`` [B] is
     each slot's table-covered token count (reserved_blocks * bs)."""
     c = config
-    quantized = cache.quantized
     bs = cache.block_size
     cos, sin = rope_frequencies(c.head_dim, 0, c.rope_theta,
                                 positions=positions)
@@ -537,48 +534,20 @@ def _decode_tick_paged(params, tokens, positions, tables, limits,
     offset = positions % bs                                      # [B]
 
     def layer_fn(carry, layer):
-        x, ck_all, cv_all, ks_all, vs_all, li = carry
-        ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
+        x, arenas, li = carry
         q, k, v = _layer_qkv(x, layer, cos, sin, c)
-        k_tok, v_tok = k[:, 0], v[:, 0]                  # [B, KVH, D]
-        ksl = vsl = None
-        if quantized:
-            kq, ksc = quantize_kv(k_tok)
-            vq, vsc = quantize_kv(v_tok)
-            ksl = jax.lax.dynamic_index_in_dim(ks_all, li, 0,
-                                               keepdims=False)
-            vsl = jax.lax.dynamic_index_in_dim(vs_all, li, 0,
-                                               keepdims=False)
-            ksl = _scatter_arena(ksl, ksc, block_idx, offset)
-            vsl = _scatter_arena(vsl, vsc, block_idx, offset)
-        else:
-            kq, vq = k_tok, v_tok
-        ck = _scatter_arena(ck, kq, block_idx, offset)
-        cv = _scatter_arena(cv, vq, block_idx, offset)
-        o = paged_decode_attention(q[:, 0], ck, cv, tables, positions,
-                                   scale, k_scale=ksl, v_scale=vsl,
-                                   use_kernel=use_kernel)
-        o = o.astype(x.dtype)
-        ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
-        cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-        if quantized:
-            ks_all = jax.lax.dynamic_update_index_in_dim(ks_all, ksl,
-                                                         li, 0)
-            vs_all = jax.lax.dynamic_update_index_in_dim(vs_all, vsl,
-                                                         li, 0)
-        x = _layer_finish(x, o, layer, c)
-        return (x, ck_all, cv_all, ks_all, vs_all, li + 1), None
+        o, arenas = _write_then_attend(
+            arenas, li, q, k, v, block_idx[:, None], offset[:, None],
+            tables, positions[:, None], scale, use_kernel)
+        x = _layer_finish(x, o[:, 0].astype(x.dtype), layer, c)
+        return (x, arenas, li + 1), None
 
-    carry0 = (x, cache.k, cache.v, cache.k_scale, cache.v_scale,
-              jnp.int32(0))
-    (x, nk, nv, nks, nvs, _), _ = jax.lax.scan(layer_fn, carry0,
-                                               params["layers"])
+    (x, arenas, _), _ = jax.lax.scan(
+        layer_fn, (x, tuple(cache), jnp.int32(0)), params["layers"])
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     logits = lm_head_logits(x, params, c)
     next_tokens = _next_tokens(logits, step, sampling)
-    new_cache = PagedKVCache(k=nk, v=nv, k_scale=nks, v_scale=nvs)
-    return next_tokens, positions + 1, new_cache, step + 1
+    return next_tokens, positions + 1, PagedKVCache(*arenas), step + 1
 
 
 def _prefill_forward_paged(params, tokens, positions, pk, pv, config,

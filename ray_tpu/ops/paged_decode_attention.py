@@ -30,6 +30,14 @@ Same online-softmax core as the dense kernel: fp32 accumulation with a
 running max/sum in VMEM scratch; per-slot positions arrive via scalar
 prefetch and gate both block skip and the in-block causal mask.
 
+The engine hands both kernels the WHOLE arena ``[L, NB, KVH, bs, D]``
+with the layer as one more scalar-prefetch operand, and writes the
+tick's new token rows through :func:`paged_kv_write`, a second Mosaic
+call aliased arena-in -> arena-out. XLA never sees a per-layer slab, so
+it has none to slice, relayout for a scatter, and copy back (on the
+v5e those four slab moves a layer for K and V each were 72% of a
+48-slot tick's device time).
+
 Dispatch mirrors ``decode_attention``: kernel on TPU when shapes tile,
 interpret mode when forced (CPU tier-1), XLA reference otherwise.
 """
@@ -65,18 +73,29 @@ def gather_kv(arena, tables):
     return g.reshape(b, nb * bs, hkv, *arena.shape[3:])
 
 
+def _layer_slab(a, layer):
+    """One layer's slab of a whole arena ``[L, NB, ...]``; a slab passes
+    through."""
+    if layer is None or a is None:
+        return a
+    return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+
+
 def paged_attention_reference(q, arena_k, arena_v, tables, positions,
                               scale: Optional[float] = None, *,
-                              k_scale=None, v_scale=None):
+                              layer=None, k_scale=None, v_scale=None):
     """XLA reference: gather blocks into dense layout, dequantize when the
     arena is quantized, then run the positional-mask softmax attention.
 
-    q [B, Hq, D]; arena [NB, KVH, bs, D]; tables [B, nb] (row j = slot's
-    j-th logical block; dead entries may repeat blocks — masked out by
+    q [B, Hq, D]; arena [NB, KVH, bs, D], or the whole [L, NB, KVH, bs,
+    D] with ``layer``; tables [B, nb] (row j = slot's j-th logical
+    block; dead entries may repeat blocks — masked out by
     ``positions``); positions [B].
     """
     from ray_tpu.ops.decode_attention import decode_attention_reference
 
+    arena_k, arena_v, k_scale, v_scale = (
+        _layer_slab(a, layer) for a in (arena_k, arena_v, k_scale, v_scale))
     ck = gather_kv(arena_k, tables)
     cv = gather_kv(arena_v, tables)
     if k_scale is not None:
@@ -90,7 +109,12 @@ def paged_attention_reference(q, arena_k, arena_v, tables, positions,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
+def _layer_operand(layer):
+    """The layer index as the rank-1 int32 array scalar prefetch takes."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _paged_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                   scale, block_size, num_blocks, quantized):
     if quantized:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
@@ -109,40 +133,43 @@ def _paged_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(j * block_size <= pos)
     def _body():
-        _attend_block(q_ref[0], k_ref[0], v_ref[0], pos, j * block_size,
-                      acc_ref, m_ref, l_ref, scale=scale,
-                      k_scale=ks_ref[0] if quantized else None,
-                      v_scale=vs_ref[0] if quantized else None)
+        _attend_block(q_ref[0], k_ref[0, 0], v_ref[0, 0], pos,
+                      j * block_size, acc_ref, m_ref, l_ref, scale=scale,
+                      k_scale=ks_ref[0, 0] if quantized else None,
+                      v_scale=vs_ref[0, 0] if quantized else None)
 
     @pl.when(j == num_blocks - 1)
     def _fin():
         _finalize(o_ref, acc_ref, l_ref)
 
 
-def _paged_fused(q, arena_k, arena_v, tables, positions, *, k_scale,
+def _paged_fused(q, arena_k, arena_v, tables, positions, *, layer, k_scale,
                  v_scale, scale, interpret):
     b, hq, d = q.shape
-    _, hkv, block_size, _ = arena_k.shape
+    _, _, hkv, block_size, _ = arena_k.shape
     nb = tables.shape[1]
     group = hq // hkv
     quantized = k_scale is not None
 
     qg = q.reshape(b, hkv, group, d)
     q_spec = pl.BlockSpec((1, hkv, group, d),
-                          lambda b_, j, tab, po: (b_, 0, 0, 0))
-    # The table gather IS the index_map: scalar-prefetched block tables
-    # choose which arena block each grid step streams into VMEM.
-    kv_spec = pl.BlockSpec((1, hkv, block_size, d),
-                           lambda b_, j, tab, po: (tab[b_, j], 0, 0, 0))
+                          lambda b_, j, ly, tab, po: (b_, 0, 0, 0))
+    # The table gather IS the index_map: the scalar-prefetched layer and
+    # block tables choose which arena block each grid step streams into
+    # VMEM.
+    kv_spec = pl.BlockSpec(
+        (1, 1, hkv, block_size, d),
+        lambda b_, j, ly, tab, po: (ly[0], tab[b_, j], 0, 0, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     inputs = [qg, arena_k, arena_v]
     if quantized:
-        sc_spec = pl.BlockSpec((1, hkv, block_size),
-                               lambda b_, j, tab, po: (tab[b_, j], 0, 0))
+        sc_spec = pl.BlockSpec(
+            (1, 1, hkv, block_size),
+            lambda b_, j, ly, tab, po: (ly[0], tab[b_, j], 0, 0))
         in_specs += [sc_spec, sc_spec]
         inputs += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, nb),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -169,12 +196,95 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, *, k_scale,
             + q.size * jnp.dtype(q.dtype).itemsize,
             transcendentals=b * hq * nb * block_size,
         ),
-    )(tables.astype(jnp.int32), positions.astype(jnp.int32), *inputs)
+    )(_layer_operand(layer), tables.astype(jnp.int32),
+      positions.astype(jnp.int32), *inputs)
     return out.reshape(b, hq, d)
 
 
+# ---------------------------------------------------------------------------
+# In-place token write
+# ---------------------------------------------------------------------------
+
+def _write_kernel(layer_ref, blk_ref, off_ref, new_ref, arena_ref, out_ref,
+                  *, window):
+    """Merge this slot's window rows into one arena block. Grid step
+    ``t`` of slot ``b`` holds the block that window token ``t * (S-1)``
+    lands in; every window token aimed at that block replaces its row,
+    all other bytes go back as read. The select is exact: bf16 rides
+    through fp32 and int8 through int32, the row test is on int32."""
+    base = pl.program_id(0) * window
+    target = blk_ref[base + pl.program_id(1) * (window - 1)]
+    blk = arena_ref[0, 0]                            # [KVH, bs, ...]
+    wide = jnp.int32 if blk.dtype == jnp.int8 else jnp.float32
+    rows = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    merged = blk.astype(wide)
+    for j in range(window):   # static: 1 for a tick, k+1 for verify
+        hit = (rows == off_ref[base + j]) & (blk_ref[base + j] == target)
+        merged = jnp.where(hit, new_ref[0, j].astype(wide), merged)
+    out_ref[0, 0] = merged.astype(out_ref.dtype)
+
+
+def paged_kv_write(arena, new, layer, block_idx, offset):
+    """Write token rows into the whole arena IN PLACE (aliased in -> out).
+
+    arena [L, NB, KVH, bs, ...] (K/V ``[..., D]``, or the fp32 scale
+    sidecar with no trailing axis); ``new`` [B, S, KVH, ...] holds each
+    slot's S consecutive tokens, token (b, j) bound for row ``offset[b,
+    j]`` of block ``block_idx[b, j]`` of layer ``layer`` (traced).
+
+    Grid ``(B, min(S, 2))``, one whole block in and out per step. A
+    window of consecutive positions spans at most two blocks when
+    ``S - 1 <= bs``: the first and the last token's. A window inside one
+    block repeats it in the second step, which pallas neither re-fetches
+    nor writes back in between, and the merge is idempotent. DIFFERENT
+    slots must not name the same block unless its bytes are never read:
+    the second slot's step would merge into the copy fetched before the
+    first one's write landed. The engine's live slots never do (a slot
+    writes only blocks it owns alone; prefix-shared blocks are full);
+    freed slots all aim at the garbage block.
+    """
+    b, s = block_idx.shape
+    hkv, bs = arena.shape[2], arena.shape[3]
+    rest = arena.shape[4:]
+    if s - 1 > bs:
+        raise ValueError(f"window of {s} tokens can span more than two "
+                         f"blocks of {bs}")
+    zeros = (0,) * len(rest)
+    # Rows ride a unit axis where the block has ``bs``, so the kernel
+    # broadcasts along it and never moves heads between tile axes.
+    new = new.astype(arena.dtype).reshape(b, s, hkv, 1, *rest)
+    new_spec = pl.BlockSpec(
+        (1, s, hkv, 1, *rest),
+        lambda b_, t, ly, blk, off: (b_, 0, 0, 0, *zeros))
+    arena_spec = pl.BlockSpec(
+        (1, 1, hkv, bs, *rest),
+        lambda b_, t, ly, blk, off: (
+            ly[0], blk[b_ * s + t * (s - 1)], 0, 0, *zeros))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, min(s, 2)),
+        in_specs=[new_spec, arena_spec],
+        out_specs=arena_spec,
+    )
+    block_bytes = (hkv * bs * math.prod(rest)
+                   * jnp.dtype(arena.dtype).itemsize)
+    return pl.pallas_call(
+        functools.partial(_write_kernel, window=s),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+        # Operand 4 counts the three scalar-prefetch arrays and ``new``.
+        input_output_aliases={4: 0},
+        interpret=_interpret_default(),
+        name="paged_kv_write",
+        cost_estimate=pl.CostEstimate(
+            flops=0, transcendentals=0,
+            bytes_accessed=2 * b * min(s, 2) * block_bytes),
+    )(_layer_operand(layer), block_idx.astype(jnp.int32).reshape(-1),
+      offset.astype(jnp.int32).reshape(-1), new, arena)
+
+
 def paged_applicable(block_size: int, d: int, hq: int, hkv: int) -> bool:
-    """True when auto-dispatch takes the paged fused kernel on TPU for
+    """True when auto-dispatch takes the paged fused kernels on TPU for
     these shapes (lane-tiling head_dim, sublane-tiling blocks, whole
     query groups)."""
     return not (hq % hkv or d % 128 or block_size % 32)
@@ -188,6 +298,7 @@ def paged_decode_attention(
     positions: jnp.ndarray,
     scale: Optional[float] = None,
     *,
+    layer=None,
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
     use_kernel: Optional[bool] = None,
@@ -195,17 +306,21 @@ def paged_decode_attention(
 ) -> jnp.ndarray:
     """Decode-step attention over a paged KV arena.
 
-    q [B, Hq, D]; arena_k/v [NB, KVH, bs, D] (int8 when ``k_scale`` /
-    ``v_scale`` [NB, KVH, bs] are given); tables [B, nb] int32 block
-    table (row j = the slot's j-th logical block; dead tail entries
-    should repeat the last live block); positions [B].
+    q [B, Hq, D]; arena_k/v the whole arena [L, NB, KVH, bs, D] read at
+    ``layer`` (a traced int32 scalar), or one slab [NB, KVH, bs, D] with
+    ``layer`` None; int8 when ``k_scale`` / ``v_scale`` (the arena's
+    shape less D) are given; tables [B, nb] int32 block table (row j =
+    the slot's j-th logical block; dead tail entries should repeat the
+    last live block); positions [B].
 
     ``use_kernel``: None = auto (fused kernel on TPU when the shapes
     tile, XLA reference elsewhere); True forces the kernel (interpret
     mode off-TPU — the CPU tier-1 path); False forces the reference.
     """
     b, hq, d = q.shape
-    hkv, block_size = arena_k.shape[1], arena_k.shape[2]
+    if (layer is None) != (arena_k.ndim == 4):
+        raise ValueError("a whole arena needs `layer`; a slab takes none")
+    hkv, block_size = arena_k.shape[-3], arena_k.shape[-2]
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     if (k_scale is None) != (v_scale is None):
@@ -216,10 +331,16 @@ def paged_decode_attention(
                       and paged_applicable(block_size, d, hq, hkv))
     if not use_kernel:
         return paged_attention_reference(q, arena_k, arena_v, tables,
-                                         positions, scale,
+                                         positions, scale, layer=layer,
                                          k_scale=k_scale, v_scale=v_scale)
     if interpret is None:
         interpret = _interpret_default()
-    return _paged_fused(q, arena_k, arena_v, tables, positions,
+    if layer is None:
+        # A slab is an arena of one layer (a leading unit axis is free).
+        layer = 0
+        arena_k, arena_v = arena_k[None], arena_v[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    return _paged_fused(q, arena_k, arena_v, tables, positions, layer=layer,
                         k_scale=k_scale, v_scale=v_scale, scale=scale,
                         interpret=interpret)

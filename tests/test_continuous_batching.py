@@ -394,6 +394,63 @@ def test_paged_kernel_engine_parity(setup, pallas_interpret):
         assert toks == _reference(gen, prompt, m)
 
 
+def test_live_rows_never_share_a_write_block(setup, pallas_interpret):
+    """``paged_kv_write`` merges each row into the block as fetched, so
+    two rows of one tick may name the same block only if nothing reads
+    it. Over a run that crosses block boundaries, frees and re-admits
+    slots and splices shared prefix blocks: every live row of every tick
+    writes a block no other slot's table holds (shared prefix blocks are
+    full, hence never written), freed rows all aim at the garbage block,
+    and the kernel path's greedy tokens are the reference path's."""
+    from ray_tpu.models.paged_kv import GARBAGE_BLOCK
+
+    config, gen, _ = setup
+    bs = 16
+    rng = np.random.default_rng(23)
+    shared = list(rng.integers(1, 250, size=2 * bs))      # two full blocks
+    reqs = [(shared + list(rng.integers(1, 250, size=n)), m)
+            for n, m in [(3, 20), (9, 37), (1, 5), (14, 18), (6, 33)]]
+    reqs.append((list(rng.integers(1, 250, size=11)), 24))
+    ticks = []
+
+    def run(use_kernel):
+        eng = ContinuousBatcher(config, params=gen.params, num_slots=3,
+                                max_len=128, paged=True, block_size=bs,
+                                use_decode_kernel=use_kernel)
+        run_tick = eng._run_tick
+
+        def watched():
+            ticks.append((np.asarray(eng._d_positions),
+                          np.asarray(eng._d_tables),
+                          np.asarray(eng._d_limits), sorted(eng._slots)))
+            return run_tick()
+
+        if use_kernel:
+            eng._run_tick = watched
+        rids = [eng.submit(p, max_new_tokens=m) for p, m in reqs]
+        out = eng.run_to_completion()
+        assert eng.prefix_hit_tokens > 0
+        return [out[r] for r in rids]
+
+    assert run(True) == run(False)
+    assert len(ticks) > 2 * bs
+    freed_rows = crossings = 0
+    for positions, tables, limits, live in ticks:
+        written = np.where(positions < limits,
+                           tables[np.arange(3), positions // bs],
+                           GARBAGE_BLOCK)
+        crossings += int(np.sum(positions[live] % bs == 0))
+        for slot in range(3):
+            if slot not in live:
+                freed_rows += 1
+                assert written[slot] == GARBAGE_BLOCK
+                continue
+            assert written[slot] != GARBAGE_BLOCK
+            others = np.delete(tables, slot, axis=0)
+            assert written[slot] not in others, (slot, written, tables)
+    assert freed_rows and crossings > len(reqs)
+
+
 def test_paged_int8_generates_plausibly(setup):
     """int8 arena: exact greedy parity is not promised (quantization
     perturbs logits), but generation must complete, reuse blocks, and

@@ -17,7 +17,8 @@ from ray_tpu.models.paged_kv import (GARBAGE_BLOCK, BlockAllocator,
 from ray_tpu.ops.decode_attention import decode_attention_reference
 from ray_tpu.ops.paged_decode_attention import (paged_applicable,
                                                 paged_attention_reference,
-                                                paged_decode_attention)
+                                                paged_decode_attention,
+                                                paged_kv_write)
 
 
 def _paged_inputs(b=3, hq=4, hkv=2, d=16, bs=32, nb_slot=4, seed=0,
@@ -154,6 +155,130 @@ def test_paged_int8_attention_close_to_fp32(pallas_interpret, use_kernel):
                                    v_scale=vs, use_kernel=not use_kernel)
     np.testing.assert_allclose(np.asarray(out), np.asarray(other),
                                atol=2e-6)
+
+
+# ------------------------------------- whole arena: layer index, in-place write
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_layer_indexed_read_equals_slab_call(pallas_interpret, use_kernel,
+                                             kv_dtype, layer):
+    """Reading layer ``li`` of the whole arena is bit for bit the call
+    on that layer's slab: the layer only steers the block fetch."""
+    q, _, _, ak, av, tables, _ = _paged_inputs(seed=7, dtype=jnp.bfloat16)
+    pos = jnp.asarray([0, 63, 127], jnp.int32)
+    slabs = {"k_scale": None, "v_scale": None}
+    if kv_dtype == "int8":
+        ak, slabs["k_scale"] = quantize_kv(ak)
+        av, slabs["v_scale"] = quantize_kv(av)
+
+    def arena(a, other):
+        """``a`` at ``layer``, ``other``'s bytes in the two layers
+        beside it (a read of the wrong layer cannot pass)."""
+        if a is None:
+            return None
+        return jnp.stack([a if i == layer else other for i in range(3)])
+
+    whole = paged_decode_attention(
+        q, arena(ak, av), arena(av, ak), tables, pos,
+        layer=jnp.int32(layer), use_kernel=use_kernel,
+        k_scale=arena(slabs["k_scale"], slabs["v_scale"]),
+        v_scale=arena(slabs["v_scale"], slabs["k_scale"]))
+    slab = paged_decode_attention(q, ak, av, tables, pos,
+                                  use_kernel=use_kernel, **slabs)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(slab))
+
+
+def test_layer_argument_must_match_arena_rank():
+    q, _, _, ak, av, tables, _ = _paged_inputs()
+    pos = jnp.asarray([0, 1, 2], jnp.int32)
+    with pytest.raises(ValueError, match="layer"):
+        paged_decode_attention(q, ak[None], av[None], tables, pos)
+    with pytest.raises(ValueError, match="layer"):
+        paged_decode_attention(q, ak, av, tables, pos, layer=0)
+
+
+def _write_case(hkv, kind, width, bs=32, d=16, slots=6, nb_slot=3, seed=0):
+    """A 3-layer arena with live bytes everywhere, and one write: slot
+    i's ``width`` consecutive tokens start at ``starts[i]`` of its own
+    blocks. Slot 0 starts at row 0 of a block, slot 1 ends on the last
+    row of one, slot 2 straddles a boundary when ``width`` > 1, and
+    slots 3 and 5 are freed (every row aims at the garbage block)."""
+    rng = np.random.default_rng(seed)
+    nb = slots * nb_slot + 1
+    trailing = () if kind == "scale" else (d,)
+    shape = (3, nb, hkv, bs) + trailing
+    if kind == "int8":
+        arena = rng.integers(-127, 128, shape).astype(np.int8)
+        new = rng.integers(-127, 128, (slots, width, hkv) + trailing
+                           ).astype(np.int8)
+    else:
+        dtype = jnp.float32 if kind == "scale" else jnp.bfloat16
+        arena = jnp.asarray(rng.standard_normal(shape), dtype)
+        new = jnp.asarray(
+            rng.standard_normal((slots, width, hkv) + trailing), dtype)
+    tables = rng.permutation(np.arange(1, nb)).reshape(slots, nb_slot)
+    starts = np.array([0, bs - width, bs - 1, 5, bs + 3, 40])
+    pos = starts[:, None] + np.arange(width)[None, :]
+    block_idx = np.take_along_axis(tables, pos // bs, axis=1)
+    block_idx[[3, 5]] = GARBAGE_BLOCK
+    return (jnp.asarray(arena), jnp.asarray(new),
+            jnp.asarray(block_idx, jnp.int32),
+            jnp.asarray(pos % bs, jnp.int32))
+
+
+# kv heads of the MHA 16/16 and the GQA 32/8 shapes.
+@pytest.mark.parametrize("hkv", [16, 8])
+@pytest.mark.parametrize("kind", ["bf16", "int8", "scale"])
+@pytest.mark.parametrize("width", [1, 4])
+def test_write_kernel_equals_xla_scatter(pallas_interpret, hkv, kind,
+                                         width):
+    """``paged_kv_write`` stores byte for byte what the XLA scatter on
+    the layer's slab stores (K/V rows of a bf16 or an int8 arena, and
+    the fp32 scale rows through the same kernel): a tick's one token a
+    slot and a verify window's four; every other layer untouched. The
+    garbage block is compared nowhere: it holds whichever freed row the
+    scatter or the kernel happened to keep, and nothing reads it."""
+    from ray_tpu.models.continuous_batching import _scatter_arena
+
+    arena, new, block_idx, offset = _write_case(hkv, kind, width)
+    li = 1
+    got = paged_kv_write(arena, new, jnp.int32(li), block_idx, offset)
+    want = _scatter_arena(arena[li], new.reshape(-1, *new.shape[2:]),
+                          block_idx.reshape(-1), offset.reshape(-1))
+    assert got.dtype == arena.dtype and got.shape == arena.shape
+    live = np.arange(arena.shape[1]) != GARBAGE_BLOCK
+    np.testing.assert_array_equal(np.asarray(got[li])[live],
+                                  np.asarray(want)[live])
+    for other in (0, 2):
+        np.testing.assert_array_equal(np.asarray(got[other]),
+                                      np.asarray(arena[other]))
+    # The rows did land (the comparison above is not two no-ops).
+    b, j = 2, width - 1
+    np.testing.assert_array_equal(
+        np.asarray(got[li, block_idx[b, j], :, offset[b, j]]),
+        np.asarray(new[b, j]))
+
+
+def test_write_kernel_window_second_block_is_garbage(pallas_interpret):
+    """A verify window that overruns its slot's reservation: the tokens
+    before the boundary land in the slot's last block, the ones past it
+    in the garbage block, and no other block changes."""
+    arena, new, block_idx, offset = _write_case(8, "bf16", 4)
+    block_idx = block_idx.at[2, 1:].set(GARBAGE_BLOCK)  # slot 2 straddles
+    got = paged_kv_write(arena, new, jnp.int32(0), block_idx, offset)
+    np.testing.assert_array_equal(
+        np.asarray(got[0, block_idx[2, 0], :, offset[2, 0]]),
+        np.asarray(new[2, 0]))
+    touched = np.unique(np.asarray(block_idx))
+    rest = np.setdiff1d(np.arange(arena.shape[1]), touched)
+    np.testing.assert_array_equal(np.asarray(got[0])[rest],
+                                  np.asarray(arena[0])[rest])
+    with pytest.raises(ValueError, match="two blocks"):
+        paged_kv_write(arena, jnp.zeros((1, 34, 8, 16), jnp.bfloat16),
+                       jnp.int32(0), jnp.zeros((1, 34), jnp.int32),
+                       jnp.zeros((1, 34), jnp.int32))
 
 
 def test_paged_cache_create_dtypes():

@@ -8,10 +8,14 @@ can only be interpreted passes every CPU test and is refused on the chip.
 host in milliseconds; ``RAY_TPU_PALLAS_INTERPRET=0`` makes the
 dispatchers emit the real kernel. (Lowering is not compiling: Mosaic's
 own checks and VMEM limits need the chipless AOT compile described in
-README "Development", or the chip.)
+README "Development", or the chip.) The last test here IS such a
+compile: the paged decode tick for a described v5e, whose HLO must hold
+no copy of a layer's arena slab. Run it before asking for chip time on
+the tick.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +25,8 @@ from ray_tpu.models import llama
 from ray_tpu.models.training import ShardedTrainer
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.decode_attention import decode_attention
-from ray_tpu.ops.paged_decode_attention import paged_decode_attention
+from ray_tpu.ops.paged_decode_attention import (paged_decode_attention,
+                                                paged_kv_write)
 from ray_tpu.parallel import MeshConfig, make_mesh
 
 S = jax.ShapeDtypeStruct
@@ -66,24 +71,40 @@ def test_dense_decode_lowers(hq, hkv):
 
 @pytest.mark.parametrize("hq,hkv", HEADS)
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_paged_decode_lowers(hq, hkv, kv_dtype):
-    """32 slots, block 64, 8-block tables over a 257-block arena."""
+@pytest.mark.parametrize("layers", [None, 16])
+def test_paged_decode_lowers(hq, hkv, kv_dtype, layers):
+    """32 slots, block 64, 8-block tables over a 257-block arena: one
+    slab, and the whole 16-layer arena read at a traced layer."""
     q = S((32, hq, 128), BF16)
     tables, positions = S((32, 8), jnp.int32), S((32,), jnp.int32)
-    if kv_dtype == "int8":
-        arena = S((257, hkv, 64, 128), jnp.int8)
-        scale = S((257, hkv, 64), jnp.float32)
+    lead = () if layers is None else (layers,)
+    arena = S(lead + (257, hkv, 64, 128),
+              jnp.int8 if kv_dtype == "int8" else BF16)
+    scales = [S(lead + (257, hkv, 64), jnp.float32)] * 2 \
+        if kv_dtype == "int8" else [None, None]
 
-        def fn(q, k, v, t, p, ks, vs):
-            return paged_decode_attention(q, k, v, t, p, k_scale=ks,
-                                          v_scale=vs, use_kernel=True)
+    def fn(q, k, v, t, p, ks, vs, li):
+        return paged_decode_attention(q, k, v, t, p, k_scale=ks, v_scale=vs,
+                                      layer=li, use_kernel=True)
 
-        assert _mosaic_calls(fn, q, arena, arena, tables, positions,
-                             scale, scale) == 1
-    else:
-        arena = S((257, hkv, 64, 128), BF16)
-        fn = functools.partial(paged_decode_attention, use_kernel=True)
-        assert _mosaic_calls(fn, q, arena, arena, tables, positions) == 1
+    li = None if layers is None else S((), jnp.int32)
+    assert _mosaic_calls(fn, q, arena, arena, tables, positions, *scales,
+                         li) == 1
+
+
+@pytest.mark.parametrize("hkv", [16, 8])
+@pytest.mark.parametrize("kind", ["bf16", "int8", "scale"])
+@pytest.mark.parametrize("width", [1, 5])
+def test_paged_kv_write_lowers(hkv, kind, width):
+    """The in-place write: a tick's one token a slot and a verify
+    window's five, into K/V of either dtype and the fp32 scale rows."""
+    trailing = () if kind == "scale" else (128,)
+    dtype = {"bf16": BF16, "int8": jnp.int8, "scale": jnp.float32}[kind]
+    arena = S((16, 257, hkv, 64) + trailing, dtype)
+    new = S((32, width, hkv) + trailing, dtype)
+    where = S((32, width), jnp.int32)
+    assert _mosaic_calls(paged_kv_write, arena, new, S((), jnp.int32),
+                         where, where) == 1
 
 
 @pytest.mark.parametrize("fsdp", [1, 4])
@@ -104,3 +125,93 @@ def test_sharded_train_step_lowers_with_flash(fsdp):
             trainer._step._jitted, platforms=["tpu"])(state, batch)
     # flash forward, its remat replay, dq, dk/dv — inside the layer scan.
     assert exported.mlir_module().count("tpu_custom_call") >= 3
+
+
+# ------------------------------------------- the compiled tick's arena moves
+
+# Mistral-7B widths (benchmark/configs/mistral-7b-v0.3-l16.json) and
+# serve_chat's engine sizes; two layers are enough for a layer loop.
+_TICK_LAYERS, _TICK_SLOTS, _TICK_BLOCKS, _TICK_BS, _TICK_LEN = (
+    2, 48, 1000, 64, 2048)
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a v5e that is described, not attached. Built inside a
+    fixture: only the worker that runs this file may load libtpu."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def compile_paged_tick(sharding, kv_dtype):
+    """AOT-compile the paged decode tick at the sizes above for
+    ``sharding``'s chip."""
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models.paged_kv import PagedKVCache
+
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_layers=_TICK_LAYERS, num_heads=32, num_kv_heads=8,
+        head_dim=128, max_seq_len=_TICK_LEN, rope_theta=1e6,
+        rms_eps=1e-5)
+
+    def spec(a):
+        return S(a.shape, a.dtype, sharding=sharding)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        functools.partial(llama.init_params, cfg), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        PagedKVCache.create, cfg, _TICK_BLOCKS, _TICK_BS,
+        kv_dtype=kv_dtype)))
+    row = S((_TICK_SLOTS,), jnp.int32, sharding=sharding)
+    tables = S((_TICK_SLOTS, _TICK_LEN // _TICK_BS), jnp.int32,
+               sharding=sharding)
+    step = S((), jnp.int32, sharding=sharding)
+    tick = functools.partial(cb._decode_tick_paged, config=cfg,
+                             use_kernel=True)
+    # Argument order and donation as the engine's ``cb_tick``.
+    return jax.jit(tick, donate_argnums=(5,)).lower(
+        params, row, row, tables, row, cache, step).compile()
+
+
+def arena_moves(hlo_text: str, trailing: str):
+    """Instructions of compiled HLO whose result is one layer's slab or
+    the whole arena (``[L?, NB, KVH, bs`` + ``trailing`` + ``]``) and
+    that are not one of the two kernels: the slices, relayout copies,
+    scatters and write-backs the in-place write exists to remove. A
+    parameter, a loop's tuple plumbing and a bitcast move no bytes."""
+    shaped = re.compile(rf"= \(?\w+\[(\d+,)?{_TICK_BLOCKS},8,{_TICK_BS}"
+                        rf"{trailing}\]")
+    free = ("custom_call_target=\"tpu_custom_call\"", " parameter(",
+            " get-tuple-element(", " tuple(", " while(", " bitcast(")
+    return [line.strip() for line in hlo_text.splitlines()
+            if shaped.search(line) and not any(f in line for f in free)]
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_compiled_paged_tick_moves_no_arena_slab(v5e_chip, kv_dtype):
+    """48 slots over a 1000-block arena: each layer of the compiled tick
+    touches the arena through ``paged_kv_write`` (K, V, and the two
+    scale sidecars of an int8 arena) and ``paged_decode_attn`` and
+    nothing else, and the program needs less scratch than one slab."""
+    compiled = compile_paged_tick(v5e_chip, kv_dtype)
+    hlo = compiled.as_text()
+    assert arena_moves(hlo, ",128") == []
+    # The fp32 scale sidecar [L, NB, KVH, bs] rests in HBM with NB as
+    # its minor axis (the TPU's own choice for a 64-wide last axis), so
+    # the entry computation relayouts it once in and once out for
+    # Mosaic: 4 bytes a token row, outside the layer loop. No layer may.
+    layer_loop = hlo[:hlo.index("\nENTRY ")]
+    assert arena_moves(layer_loop, "") == []
+    writes = 4 if kv_dtype == "int8" else 2
+    assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == writes
+    assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 1
+    slab = _TICK_BLOCKS * 8 * _TICK_BS * 128 * (
+        1 if kv_dtype == "int8" else 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < slab
