@@ -11,7 +11,13 @@ seed), and checks what comes out by the repo's own means:
 
 * ``kernels``   flash attention forward/backward, dense and paged decode
                 attention (bf16 and int8 arena), COMPILED, against their
-                references within the tolerances in ``TOLERANCE``;
+                references within the tolerances in ``TOLERANCE``; the
+                routed block's grouped matmul (``moe_gmm``) against a
+                masked loop over the experts;
+* ``moe``       the OLMoE family's bf16 forward against the float32
+                reference at the published widths, and three faults
+                (an expert dropped, weights renormalised, no QK-norm)
+                shown to land outside the benchmark's tolerances;
 * ``train``     ``ShardedTrainer`` on one device, batch 5 x 2048: loss
                 finite and falling, one compiled signature, the Mosaic
                 custom calls present in the compiled step;
@@ -47,13 +53,13 @@ import sys
 import threading
 import time
 
-PHASES = ("kernels", "train", "serve", "multichip")
+PHASES = ("kernels", "moe", "train", "serve", "multichip")
 # The multichip phase is two children: the trainer's state must be gone
 # from the chips before four serving replicas load theirs.
-CHILDREN = {"kernels": ("kernels",), "train": ("train",),
+CHILDREN = {"kernels": ("kernels",), "moe": ("moe",), "train": ("train",),
             "serve": ("serve",),
             "multichip": ("multichip-train", "multichip-serve")}
-PHASE_TIMEOUT_S = {"kernels": 420, "train": 480, "serve": 600,
+PHASE_TIMEOUT_S = {"kernels": 420, "moe": 600, "train": 480, "serve": 600,
                    "multichip-train": 900, "multichip-serve": 900}
 RESULT_TAG = "PHASE_RESULT "
 NO_ACCELERATOR_RC = 3    # a child found no TPU: no later phase can pass
@@ -69,7 +75,7 @@ REHEARSAL_BANNER = (
 # through two more bf16 roundings (dO, and the recomputed P) than the
 # forward. The chip run prints what was measured next to each bound.
 TOLERANCE = {"flash_fwd": 1e-2, "flash_bwd": 2e-2,
-             "decode_bf16": 1e-2, "decode_int8": 1e-2}
+             "decode_bf16": 1e-2, "decode_int8": 1e-2, "moe_gmm": 1e-2}
 # fsdp=4 vs one-chip first-step loss: the same bf16 model, sums reduced
 # across four devices in another order.
 LOSS_RTOL = 5e-3
@@ -288,8 +294,10 @@ def phase_kernels(rehearse: bool) -> None:
                TOLERANCE["flash_bwd"])
 
     # -- decode attention: dense, paged bf16, paged int8 -------------------
+    # MHA at OLMoE's serving cell (16 KV heads, a query group of ONE, 48
+    # slots over 16 blocks of 64), then GQA.
     shapes = [(4, 4, 2, 32, 4)] if rehearse else \
-        [(32, 16, 16, 64, 8), (32, 32, 8, 64, 8)]   # MHA, then GQA
+        [(48, 16, 16, 64, 16), (32, 32, 8, 64, 8)]
     for slots, hq, hkv, bs, nb in shapes:
         tag = f"{slots} slots, {hq}/{hkv} heads, block {bs}"
         s_max = bs * nb
@@ -376,7 +384,106 @@ def phase_kernels(rehearse: bool) -> None:
                 # The garbage block holds whichever freed row came last.
                 _same(phase, f"paged_kv_write {name}, {width} token(s) a "
                              f"slot ({tag})", got[:, 1:], want[:, 1:])
+
+    # -- grouped matmul over sorted rows (the routed block's experts) ------
+    # Stacked [3, X, K, N] weights read at layer 1, against a masked loop
+    # over the experts in XLA, at OLMoE's widths: decode (48 slots x top
+    # 8), a one-row and an eight-row prefill of 128 tokens.
+    from ray_tpu.ops.moe import grouped_matmul
+
+    x_, widths, row_counts = ((8, [(64, 32)], [24, 300]) if rehearse else
+                              (64, [(2048, 1024), (1024, 2048)],
+                               [384, 1024, 8192]))
+    gmm = jax.jit(lambda a, w, g: grouped_matmul(a, w, g, jnp.int32(1),
+                                                 use_kernel=True))
+
+    @jax.jit
+    def masked_loop(a, w, g):
+        ends = jnp.cumsum(g)
+        rows = jnp.arange(a.shape[0])
+        out = jnp.zeros((a.shape[0], w.shape[-1]), jnp.float32)
+        for e in range(w.shape[1]):
+            mine = (rows >= ends[e] - g[e]) & (rows < ends[e])
+            out = jnp.where(mine[:, None], jnp.dot(
+                a, w[1, e], preferred_element_type=jnp.float32), out)
+        return out.astype(a.dtype)
+
+    for kk, nn in widths:
+        w = (jax.random.normal(keys[9], (3, x_, kk, nn), jnp.float32)
+             * kk ** -0.5).astype(bf16)
+        for m in row_counts:
+            a = jax.random.normal(keys[10], (m, kk), jnp.float32).astype(bf16)
+            # Uneven groups, two of them empty, summing to m.
+            cuts = jnp.sort(jax.random.randint(keys[11], (x_ - 3,), 0, m))
+            g = jnp.diff(jnp.concatenate(
+                [jnp.zeros(1, cuts.dtype), cuts, jnp.full(1, m, cuts.dtype)]))
+            g = jnp.concatenate([g[:1], jnp.zeros(2, g.dtype), g[1:]]
+                                ).astype(jnp.int32)
+            if not rehearse:
+                assert _mosaic_calls(gmm.lower(a, w, g).compile()) == 1
+            _check(phase, f"moe_gmm [{m},{kk}] x [{x_},{kk},{nn}] bf16",
+                   gmm(a, w, g), masked_loop(a, w, g), TOLERANCE["moe_gmm"])
     _finish(phase, info)
+
+
+def phase_moe(rehearse: bool) -> None:
+    """The OLMoE family's forward (bf16, the dropless routed block on its
+    kernel, QK-norm) against ``benchmark/reference_olmoe.py`` (float32)
+    at the published widths on 4 layers; then the same comparison with
+    one fault at a time in the PROGRAM's config, to show that the
+    tolerances of the benchmark's configuration file catch each: the
+    eighth expert dropped, the top-8 weights renormalised, QK-norm
+    skipped. Tokens are the faulty forward's own greedy choices under
+    teacher forcing; their gap is read off the reference's logits."""
+    phase = "moe"
+    info = _open_device(phase, rehearse)
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import reference_olmoe
+    from ray_tpu.models import llama
+
+    if rehearse:
+        config = llama.LlamaConfig.tiny(
+            num_experts=8, num_experts_per_tok=2, qk_norm=True,
+            intermediate_size=32, num_kv_heads=4)
+        seq = 48
+    else:
+        config = llama.LlamaConfig.olmoe_1b_7b(num_layers=4, remat=False)
+        seq = 160
+    params = jax.jit(lambda k: llama.init_params(config, k))(
+        jax.random.PRNGKey(0))
+    tokens = np.random.default_rng(25).integers(1, config.vocab_size, seq)
+    want = np.asarray(reference_olmoe.logits(params, tokens, config))
+    want_routes = np.sort(np.asarray(
+        reference_olmoe.router_choices(params, tokens, config)), -1)
+    top_k = config.num_experts_per_tok
+    faults = {
+        "as published": config,
+        "one routed expert dropped": dataclasses.replace(
+            config, num_experts_per_tok=top_k - 1),
+        "top-k weights renormalised": dataclasses.replace(
+            config, norm_topk_prob=True),
+        "QK-norm skipped": dataclasses.replace(config, qk_norm=False),
+    }
+    results = {}
+    for name, cfg in faults.items():
+        got, routes = jax.jit(lambda p, t, cfg=cfg: llama.forward(
+            p, t, cfg, return_routes=True))(params, jnp.asarray(tokens)[None])
+        chosen = np.asarray(jnp.argmax(got[0], axis=-1))
+        under = (want.max(-1) - want[np.arange(seq), chosen]) / want.std(-1)
+        routes = np.sort(np.asarray(routes), -1)
+        agree = (float(np.mean(np.all(routes == want_routes, axis=-1)))
+                 if routes.shape == want_routes.shape else 0.0)
+        results[name] = {"worst_gap_sd": float(under.max()),
+                         "router_agreement": agree}
+        _say(phase, f"{name}: worst chosen-token gap {under.max():.4f} sd, "
+                    f"top-{top_k} sets equal to the reference's "
+                    f"{100 * agree:.2f}%")
+    _finish(phase, info, faults=results)
 
 
 def _train_once(phase, config, mesh, batch_size, seq_len, steps, rehearse):
@@ -778,7 +885,8 @@ def _child(phase: str, rehearse: bool) -> int:
     os._exit(code)
 
 
-CHILD_FNS = {"kernels": phase_kernels, "train": phase_train,
+CHILD_FNS = {"kernels": phase_kernels, "moe": phase_moe,
+             "train": phase_train,
              "serve": phase_serve, "multichip-train": phase_multichip_train,
              "multichip-serve": phase_multichip_serve}
 

@@ -383,6 +383,24 @@ CB_DECODE_TOKENS = Counter(
     "ray_tpu_cb_decode_tokens_total",
     "Tokens produced by the continuous-batching decode loop",
     ("engine",))
+CB_MOE_ASSIGNMENTS = Counter(
+    "ray_tpu_cb_moe_assignments_total",
+    "(token, expert) assignments the routed block computed in decode "
+    "ticks, all layers (every slot routes, live or not)",
+    ("engine",))
+_SHARE_BOUNDS = (0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 0.95, 0.99,
+                 1.0)
+CB_MOE_TOUCHED_SHARE = Histogram(
+    "ray_tpu_cb_moe_experts_touched_share",
+    "Per decode tick: the share of (layer, expert) pairs that got at "
+    "least one row, so whose weights the tick read",
+    boundaries=_SHARE_BOUNDS, tag_keys=("engine",))
+CB_MOE_LOAD_IMBALANCE = Histogram(
+    "ray_tpu_cb_moe_load_imbalance",
+    "Per decode tick: the busiest expert's rows over the mean expert's, "
+    "averaged over layers (1 = perfectly even)",
+    boundaries=(1.0, 1.25, 1.5, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0, 16.0, 64.0),
+    tag_keys=("engine",))
 CB_TICK_MS = Histogram(
     "ray_tpu_cb_tick_ms",
     "Wall milliseconds per decode tick (dispatch+compute+fetch with "

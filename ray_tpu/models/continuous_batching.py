@@ -218,22 +218,28 @@ def _layer_qkv(x, layer, cos, sin, c):
     numerics change here reaches both data planes at once — the
     paged-on/off bit-parity contract depends on that."""
     h = rms_norm(x, layer["attn_norm"], c.rms_eps)
-    q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(c.dtype))
-    k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(c.dtype))
-    v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(c.dtype))
+    q, k, v = llama.project_qkv(h, layer, c)
     return (_apply_rope_batched(q, cos, sin),
             _apply_rope_batched(k, cos, sin), v)
 
 
-def _layer_finish(x, o, layer, c):
-    """Shared per-layer tail: attention output projection + gated MLP."""
+def _mlp_residual(x, layer, c, experts, li, use_kernel):
+    """``x + MLP(norm(x))`` through the family's one MLP function
+    (:func:`llama.mlp_block`: dense SwiGLU, or the routed block reading
+    the stacked ``experts`` at layer ``li``). Returns (x, rows): the
+    routed block's per-expert assignment counts, None for a dense
+    model. Every engine program's layer ends here."""
+    h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
+    down, routed = llama.mlp_block(h, layer, c, experts, li,
+                                   use_kernel=use_kernel)
+    return x + down, None if routed is None else routed.rows
+
+
+def _layer_finish(x, o, layer, c, experts=None, li=None, use_kernel=None):
+    """Shared per-layer tail: attention output projection + the MLP."""
     x = x + jnp.einsum("bhd,hde->be", o,
                        layer["wo"].astype(c.dtype))[:, None, :]
-    h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
-    gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(c.dtype))
-    up = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(c.dtype))
-    return x + jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
-                          layer["w_down"].astype(c.dtype))
+    return _mlp_residual(x, layer, c, experts, li, use_kernel)
 
 
 def _apply_rope_window(x, cos, sin):
@@ -258,21 +264,16 @@ def _layer_qkv_window(x, layer, cos, sin, c):
     dims, the E-axis accumulation is untouched — which the spec-on/off
     bit-parity tests pin down."""
     h = rms_norm(x, layer["attn_norm"], c.rms_eps)
-    q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(c.dtype))
-    k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(c.dtype))
-    v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(c.dtype))
+    q, k, v = llama.project_qkv(h, layer, c)
     return (_apply_rope_window(q, cos, sin),
             _apply_rope_window(k, cos, sin), v)
 
 
-def _layer_finish_window(x, o, layer, c):
+def _layer_finish_window(x, o, layer, c, experts=None, li=None,
+                         use_kernel=None):
     """:func:`_layer_finish` over a verify window: o [B, S, H, D]."""
     x = x + jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
-    h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
-    gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(c.dtype))
-    up = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(c.dtype))
-    return x + jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
-                          layer["w_down"].astype(c.dtype))
+    return _mlp_residual(x, layer, c, experts, li, use_kernel)
 
 
 def _draft_forward_paged(params, n_draft, tokens, positions, tables,
@@ -296,16 +297,18 @@ def _draft_forward_paged(params, n_draft, tokens, positions, tables,
     block_idx = jnp.where(positions < limits, gathered, GARBAGE_BLOCK)
     offset = positions % bs
 
+    sliced, experts = llama.split_layers(params, n_draft)
+
     def layer_fn(carry, layer):
         x, arenas, li = carry
         q, k, v = _layer_qkv(x, layer, cos, sin, c)
         o, arenas = _write_then_attend(
             arenas, li, q, k, v, block_idx[:, None], offset[:, None],
             tables, positions[:, None], scale, use_kernel)
-        x = _layer_finish(x, o[:, 0].astype(x.dtype), layer, c)
+        x, _ = _layer_finish(x, o[:, 0].astype(x.dtype), layer, c,
+                             experts, li, use_kernel)
         return (x, arenas, li + 1), None
 
-    sliced = jax.tree.map(lambda a: a[:n_draft], params["layers"])
     (x, arenas, _), _ = jax.lax.scan(
         layer_fn, (x, tuple(cache), jnp.int32(0)), sliced)
     x = rms_norm(x, params["final_norm"], c.rms_eps)
@@ -336,12 +339,12 @@ def _draft_forward_dense(dparams, tokens, positions, dcache: KVCache,
                              use_kernel=False)
         ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
         cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-        x = _layer_finish(x, o, layer, c)
+        x, _ = _layer_finish(x, o, layer, c, experts, li)
         return (x, ck_all, cv_all, li + 1), None
 
+    scanned, experts = llama.split_layers(dparams)
     (x, nk, nv, _), _ = jax.lax.scan(
-        layer_fn, (x, dcache.k, dcache.v, jnp.int32(0)),
-        dparams["layers"])
+        layer_fn, (x, dcache.k, dcache.v, jnp.int32(0)), scanned)
     x = rms_norm(x, dparams["final_norm"], c.rms_eps)
     logits = lm_head_logits(x, dparams, c)
     return logits[:, 0], KVCache(k=nk, v=nv)
@@ -381,11 +384,13 @@ def _verify_forward_paged(params, tokens, positions, tables, limits,
         o, arenas = _write_then_attend(
             arenas, li, q, k, v, block_idx, offset, tables, positions,
             scale, use_kernel)
-        x = _layer_finish_window(x, o.astype(x.dtype), layer, c)
+        x, _ = _layer_finish_window(x, o.astype(x.dtype), layer, c,
+                                    experts, li, use_kernel)
         return (x, arenas, li + 1), None
 
+    scanned, experts = llama.split_layers(params)
     (x, arenas, _), _ = jax.lax.scan(
-        layer_fn, (x, tuple(cache), jnp.int32(0)), params["layers"])
+        layer_fn, (x, tuple(cache), jnp.int32(0)), scanned)
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     logits = lm_head_logits(x, params, c)
     return logits, PagedKVCache(*arenas)
@@ -489,11 +494,12 @@ def _decode_tick(params, tokens, positions, cache: KVCache, step,
                              use_kernel=use_kernel)
         ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
         cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-        x = _layer_finish(x, o, layer, c)
+        x, _ = _layer_finish(x, o, layer, c, experts, li, use_kernel)
         return (x, ck_all, cv_all, li + 1), None
 
+    scanned, experts = llama.split_layers(params)
     (x, new_k, new_v, _), _ = jax.lax.scan(
-        layer_fn, (x, cache.k, cache.v, jnp.int32(0)), params["layers"])
+        layer_fn, (x, cache.k, cache.v, jnp.int32(0)), scanned)
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     # lm_head in the params' storage dtype with fp32 accumulation (shared
     # with the prefill path) — bf16 params are no longer upcast in HBM.
@@ -539,19 +545,27 @@ def _decode_tick_paged(params, tokens, positions, tables, limits,
         o, arenas = _write_then_attend(
             arenas, li, q, k, v, block_idx[:, None], offset[:, None],
             tables, positions[:, None], scale, use_kernel)
-        x = _layer_finish(x, o[:, 0].astype(x.dtype), layer, c)
-        return (x, arenas, li + 1), None
+        x, rows = _layer_finish(x, o[:, 0].astype(x.dtype), layer, c,
+                                experts, li, use_kernel)
+        return (x, arenas, li + 1), rows
 
-    (x, arenas, _), _ = jax.lax.scan(
-        layer_fn, (x, tuple(cache), jnp.int32(0)), params["layers"])
+    scanned, experts = llama.split_layers(params)
+    (x, arenas, _), rows = jax.lax.scan(
+        layer_fn, (x, tuple(cache), jnp.int32(0)), scanned)
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     logits = lm_head_logits(x, params, c)
     next_tokens = _next_tokens(logits, step, sampling)
-    return next_tokens, positions + 1, PagedKVCache(*arenas), step + 1
+    state = (next_tokens, positions + 1, PagedKVCache(*arenas), step + 1)
+    if rows is None:
+        return state
+    # A routed model's tick also reports each layer's per-expert row
+    # counts [L, X], packed BEHIND the token vector so the host's one
+    # fetch a tick brings both (a second array would be a second sync).
+    return state + (jnp.concatenate([next_tokens, rows.reshape(-1)]),)
 
 
 def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
-                           quantized):
+                           quantized, use_kernel=None):
     """Prefill forward over ``[shared prefix ++ suffix]``.
 
     ``tokens`` [N, S] are the suffix at absolute ``positions`` [S]
@@ -573,12 +587,13 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     x = params["embed"].astype(c.dtype)[tokens]
     scale = c.head_dim ** -0.5
 
-    def layer_fn(x, inputs):
+    scanned, experts = llama.split_layers(params)
+
+    def layer_fn(carry, inputs):
+        x, li = carry
         layer, pk_l, pv_l = inputs
         h = rms_norm(x, layer["attn_norm"], c.rms_eps)
-        q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(c.dtype))
-        k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(c.dtype))
-        v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(c.dtype))
+        q, k, v = llama.project_qkv(h, layer, c)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         if quantized:
@@ -595,15 +610,12 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
         ck = jnp.concatenate([pk_l, k_att], axis=1)   # [N, P+S, KVH, D]
         cv = jnp.concatenate([pv_l, v_att], axis=1)
         o = _attend_cached(q, ck, cv, positions, scale)
-        x = x + jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
-        h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
-        gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(c.dtype))
-        up = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(c.dtype))
-        x = x + jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
-                           layer["w_down"].astype(c.dtype))
-        return x, stored
+        x, _ = _layer_finish_window(x, o, layer, c, experts, li,
+                                    use_kernel)
+        return (x, li + 1), stored
 
-    x, stored = jax.lax.scan(layer_fn, x, (params["layers"], pk, pv))
+    (x, _), stored = jax.lax.scan(layer_fn, (x, jnp.int32(0)),
+                                  (scanned, pk, pv))
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     logits = lm_head_logits(x, params, c)
     return logits, stored
@@ -967,6 +979,9 @@ class ContinuousBatcher:
             self.params[k].nbytes
             for k in ("embed", "final_norm", "lm_head"))
         self._layer_param_bytes = self.param_bytes - self._head_param_bytes
+        self._expert_param_bytes = sum(
+            self.params["layers"][k].nbytes for k in llama.EXPERT_KEYS
+            if k in self.params["layers"])
         self._draft_params = (self._place(self.drafter.params)
                               if self.spec_k and self.drafter.external
                               else None)
@@ -974,6 +989,10 @@ class ContinuousBatcher:
             x.nbytes for x in jax.tree_util.tree_leaves(self._draft_params))
         self._draft_cache = None
         self.token_callback = token_callback
+        # (rid, token) callbacks of the last applied tick that the
+        # per-tick-sync step holds back until it has dispatched the NEXT
+        # tick: see ``_emit_held``.
+        self._held_tokens: List[tuple] = []
         if self.paged:
             # Table width covers max_len PLUS the spec look-ahead: a spec
             # tick writes draft/verify K/V up to position p + spec_k, and
@@ -1111,7 +1130,7 @@ class ContinuousBatcher:
                     params, tokens, positions,
                     _blocks_to_ctx(pk.astype(cfg.dtype), n),
                     _blocks_to_ctx(pv.astype(cfg.dtype), n),
-                    cfg, cache.quantized)
+                    cfg, cache.quantized, use_kernel)
                 flat_tables = tables_w.reshape(-1)           # [N * npb]
 
                 to_blocks = functools.partial(_ctx_to_blocks,
@@ -1630,6 +1649,7 @@ class ContinuousBatcher:
         self._waiting.clear()
         self._free = list(range(self.num_slots))
         self._finished.clear()
+        self._held_tokens = []
         self._buf = []
         self._pending = None
         # Parked handoffs and import reservations die with the arena
@@ -1988,7 +2008,15 @@ class ContinuousBatcher:
             live = sum(-(-(st["pos"] + 1) // bs) * bs
                        for st in self._slots.values())
             live_bytes = live * self.cache.token_bytes()
-            total = self.param_bytes + live_bytes
+            # A routed model streams only the experts its rows touch: at
+            # most rows x top-k of each layer's X (every slot routes,
+            # live or not), over each position of a spec window.
+            c = self.config
+            idle_experts = (max(1.0 - self.num_slots * (1 + spec_k)
+                                * c.num_experts_per_tok / c.num_experts,
+                                0.0) if c.num_experts else 0.0)
+            total = (self.param_bytes + live_bytes
+                     - int(self._expert_param_bytes * idle_experts))
             if spec_k:
                 if self._draft_cache is not None:
                     dcfg = self.drafter.config
@@ -2390,9 +2418,12 @@ class ContinuousBatcher:
             self.spec_tick_count += 1
             self._last_tick_k = k
             return (committed, counts)
+        fetch = None
         if self.paged:
+            # A routed model's tick has a fifth output: the row to fetch
+            # (tokens with the expert row counts packed behind them).
             (self._d_tokens, self._d_positions, self.cache,
-             self._d_step) = self._tick(
+             self._d_step, *fetch) = self._tick(
                 self.params, self._d_tokens, self._d_positions,
                 self._d_tables, self._d_limits, self.cache, self._d_step)
         else:
@@ -2402,7 +2433,7 @@ class ContinuousBatcher:
                 self.cache, self._d_step)
         self.base_tick_count += 1
         self._last_tick_k = 0
-        return self._d_tokens
+        return fetch[0] if fetch else self._d_tokens
 
     def _record_window_token(self, rid: int, entries: Dict[int, list],
                              w0: float, w1: float) -> None:
@@ -2432,14 +2463,34 @@ class ContinuousBatcher:
             return
         entries[rid] = ent
 
-    def _apply_tokens(self, nxt_rows, membership, window=None) -> bool:
+    def _emit_held(self) -> None:
+        """Make the token callbacks :meth:`_apply_tokens` held back. A
+        callback wakes the request's stream (threads that serialise and
+        send the token, all under the interpreter lock this thread also
+        needs). Made right after a tick's tokens are booked, 48 streams
+        contend with this thread all through its host work, while the
+        device idles; made right after the NEXT tick is dispatched, they
+        run while the device computes and this thread sleeps in the
+        fetch. A step that finished a request emits before it returns,
+        so a stream's tokens always precede its end."""
+        held, self._held_tokens = self._held_tokens, []
+        for rid, tok in held:
+            self.token_callback(rid, tok)
+
+    def _apply_tokens(self, nxt_rows, membership, window=None,
+                      hold: bool = False) -> bool:
         """Book one or more fetched tick rows; returns True when any
         request finished (membership changed). ``window`` is the
         (wall_start, wall_end) of the sync window these rows cover —
         recorded per traced request for the decode-window spans (windows
         must attach BEFORE ``_maybe_finish`` pops the record, so this
-        rides the apply loop, not a post-pass)."""
+        rides the apply loop, not a post-pass). ``hold`` keeps the token
+        callbacks for :meth:`_emit_held` instead of making them here."""
         from ray_tpu._private import metrics_defs as mdefs
+
+        callback = self.token_callback
+        if hold and callback is not None:
+            callback = lambda rid, tok: self._held_tokens.append((rid, tok))
 
         with tracing.phase("engine.apply", mdefs.CB_STEP_APPLY_MS,
                            self._mtags):
@@ -2472,8 +2523,8 @@ class ContinuousBatcher:
                     for j in range(n):
                         tok = int(toks[slot]) if counts is None else int(
                             toks[slot, j])
-                        if self.token_callback is not None:
-                            self.token_callback(rid, tok)
+                        if callback is not None:
+                            callback(rid, tok)
                         st["out"].append(tok)
                         st["last"] = tok
                         st["pos"] += 1
@@ -2550,6 +2601,31 @@ class ContinuousBatcher:
             self._spec_cur_k = self._spec_ladder_ks[idx + 1]
             self._spec_window.clear()
 
+    def _note_expert_rows(self, rows) -> None:
+        """Feed the routed block's registry metrics from fetched plain
+        tick rows: behind its ``num_slots`` tokens each carries the
+        tick's per-layer, per-expert assignment counts ``[L, X]`` (every
+        slot routes, live or not: the device computed them all). A dense
+        model's rows, a dense-cache tick's and a spec tick's carry
+        none."""
+        c = self.config
+        if not (c.num_experts and self.paged):
+            return
+        from ray_tpu._private import metrics_defs as mdefs
+
+        for row in rows:
+            if isinstance(row, tuple):
+                continue
+            counts = row[self.num_slots:].reshape(-1, c.num_experts)
+            mdefs.CB_MOE_ASSIGNMENTS.inc(int(counts.sum()),
+                                         tags=self._mtags)
+            mdefs.CB_MOE_TOUCHED_SHARE.observe(
+                float(np.count_nonzero(counts)) / counts.size,
+                tags=self._mtags)
+            mdefs.CB_MOE_LOAD_IMBALANCE.observe(
+                float(np.mean(counts.max(axis=1) / counts.mean(axis=1))),
+                tags=self._mtags)
+
     def _emit_gauges(self) -> None:
         from ray_tpu._private import metrics_defs as mdefs
 
@@ -2608,6 +2684,8 @@ class ContinuousBatcher:
                                    self._mtags) as tick:
                     with _annotation("engine.tick.dispatch"):
                         nxt_dev = self._run_tick()
+                    with _annotation("engine.tick.emit"):
+                        self._emit_held()   # the previous tick's tokens
                     with _annotation("engine.tick.fetch"):
                         if isinstance(nxt_dev, tuple):
                             nxt = (np.asarray(nxt_dev[0]),
@@ -2626,6 +2704,7 @@ class ContinuousBatcher:
                            if self._last_tick_k else self._tick)
                 with tracing.phase("engine.account",
                                    mdefs.CB_STEP_ACCOUNT_MS, self._mtags):
+                    self._note_expert_rows([nxt])
                     tick_fn.note_execution(
                         tick_wall,
                         bytes_hint=(self.tick_bytes_estimate(
@@ -2635,8 +2714,10 @@ class ContinuousBatcher:
                         [nxt], [(s, st["rid"])
                                 for s, st in self._slots.items()],
                         window=(w0, time.time())
-                        if w0 is not None else None):
+                        if w0 is not None else None, hold=True):
                     self._dirty = True
+            if self._finished:
+                self._emit_held()
             out, self._finished = self._finished, {}
             return out
         return self._step_buffered()
@@ -2744,6 +2825,7 @@ class ContinuousBatcher:
                                 if self.paged else None))
             self._bw_window_t0 = now
             self._bw_window_ticks = 0
+            self._note_expert_rows(rows)
             if self._apply_tokens(rows, membership,
                                   window=(win0, time.time())):
                 self._buf = []

@@ -97,13 +97,14 @@ def _attend_cached(q, cache_k, cache_v, q_positions, scale):
     return out.reshape(b, s, hq, d).astype(q.dtype)
 
 
-def _block(x, layer, cache_k, cache_v, positions, cos, sin, c):
-    """One decoder layer over tokens at ``positions``, updating the cache."""
+def _block(x, layer, cache_k, cache_v, positions, cos, sin, c,
+           experts=None, li=None):
+    """One decoder layer over tokens at ``positions``, updating the cache
+    (``experts`` / ``li``: :func:`llama.split_layers`' stacked expert
+    weights and this layer's index, for a routed model)."""
     scale = c.head_dim ** -0.5
     h = rms_norm(x, layer["attn_norm"], c.rms_eps)
-    q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(c.dtype))
-    k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(c.dtype))
-    v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(c.dtype))
+    q, k, v = llama.project_qkv(h, layer, c)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     # Scatter new K/V into the cache at their absolute positions.
@@ -114,10 +115,7 @@ def _block(x, layer, cache_k, cache_v, positions, cos, sin, c):
     o = _attend_cached(q, cache_k, cache_v, positions, scale)
     x = x + jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
     h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
-    gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(c.dtype))
-    up = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(c.dtype))
-    x = x + jnp.einsum("bsm,me->bse", jax.nn.silu(gate) * up,
-                       layer["w_down"].astype(c.dtype))
+    x = x + llama.mlp_block(h, layer, c, experts, li)[0]
     return x, cache_k, cache_v
 
 
@@ -145,14 +143,17 @@ def _forward_cached(params, tokens, positions, cache: KVCache,
                                 positions=positions)
     x = params["embed"].astype(c.dtype)[tokens]
 
-    def layer_fn(carry, inputs):
-        x = carry
-        layer, ck, cv = inputs
-        x, ck, cv = _block(x, layer, ck, cv, positions, cos, sin, c)
-        return x, (ck, cv)
+    scanned, experts = llama.split_layers(params)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_fn, x, (params["layers"], cache.k, cache.v))
+    def layer_fn(carry, inputs):
+        x, li = carry
+        layer, ck, cv = inputs
+        x, ck, cv = _block(x, layer, ck, cv, positions, cos, sin, c,
+                           experts, li)
+        return (x, li + 1), (ck, cv)
+
+    (x, _), (new_k, new_v) = jax.lax.scan(
+        layer_fn, (x, jnp.int32(0)), (scanned, cache.k, cache.v))
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     logits = lm_head_logits(x, params, c)
     return logits, KVCache(k=new_k, v=new_v)

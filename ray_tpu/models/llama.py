@@ -64,6 +64,16 @@ class LlamaConfig:
     remat_policy: str = "full"
     # attention: "auto" | "flash" | "ring" | "reference"
     attention: str = "auto"
+    # The OLMoE family (published keys ``num_experts``,
+    # ``num_experts_per_tok``, ``norm_topk_prob``): with experts, every
+    # layer's MLP is the dropless routed block of ops/moe.py and
+    # ``intermediate_size`` is ONE expert's width. 0 = dense SwiGLU.
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    norm_topk_prob: bool = False
+    # A learned RMSNorm over the WHOLE projected q and k vectors, before
+    # the split into heads and before rope (OLMoE's modeling code).
+    qk_norm: bool = False
 
     @staticmethod
     def llama2_7b(**kw) -> "LlamaConfig":
@@ -80,6 +90,17 @@ class LlamaConfig:
                            intermediate_size=14336, num_layers=32,
                            num_heads=32, num_kv_heads=8,
                            rope_theta=500000.0, max_seq_len=8192, **kw)
+
+    @staticmethod
+    def olmoe_1b_7b(**kw) -> "LlamaConfig":
+        """allenai/OLMoE-1B-7B-0125: 64 experts of 1024, top 8 of a
+        softmax over all 64, not renormalised; MHA; QK-norm."""
+        return LlamaConfig(**{**dict(
+            vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+            num_layers=16, num_heads=16, num_kv_heads=16, head_dim=128,
+            max_seq_len=4096, rope_theta=10000.0, rms_eps=1e-5,
+            num_experts=64, num_experts_per_tok=8, norm_topk_prob=False,
+            qk_norm=True), **kw})
 
     @staticmethod
     def tiny(**kw) -> "LlamaConfig":
@@ -109,6 +130,18 @@ def logical_axes(config: LlamaConfig) -> Params:
         "w_up": ("layers", "embed", "mlp"),
         "w_down": ("layers", "mlp", "embed"),
     }
+    if config.qk_norm:
+        layer["q_norm"] = ("layers", None)
+        layer["k_norm"] = ("layers", None)
+    if config.num_experts:
+        for name in ("w_gate", "w_up", "w_down"):
+            del layer[name]
+        layer.update({
+            "w_router": ("layers", "embed", None),
+            "moe_gate": ("layers", "experts", "embed", "mlp"),
+            "moe_up": ("layers", "experts", "embed", "mlp"),
+            "moe_down": ("layers", "experts", "mlp", "embed"),
+        })
     return {
         "embed": ("vocab", "embed"),
         "layers": layer,
@@ -146,10 +179,28 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
         "wv": stacked(keys[2], E, E, KV, D),
         "wo": stacked(keys[3], H * D, H, D, E),
         "mlp_norm": jnp.ones((L, E), c.dtype),
-        "w_gate": stacked(keys[4], E, E, M),
-        "w_up": stacked(keys[5], E, E, M),
-        "w_down": stacked(keys[6], M, M, E),
     }
+    if c.qk_norm:
+        # Not ones: rms(q) is already about 1 under this init, so a unit
+        # weight would make the norm a near-identity no check could see.
+        k_q, k_k = jax.random.split(jax.random.fold_in(k_layers, 8))
+        layers["q_norm"] = jax.random.uniform(
+            k_q, (L, H * D), jnp.float32, 0.5, 1.5).astype(c.dtype)
+        layers["k_norm"] = jax.random.uniform(
+            k_k, (L, KV * D), jnp.float32, 0.5, 1.5).astype(c.dtype)
+    if c.num_experts:
+        # mixtral.py's names and layout; the router stays float32.
+        X = c.num_experts
+        layers["w_router"] = jax.random.normal(
+            jax.random.fold_in(k_layers, 7), (L, E, X),
+            jnp.float32) * E ** -0.5
+        layers["moe_gate"] = stacked(keys[4], E, X, E, M)
+        layers["moe_up"] = stacked(keys[5], E, X, E, M)
+        layers["moe_down"] = stacked(keys[6], M, X, M, E)
+    else:
+        layers["w_gate"] = stacked(keys[4], E, E, M)
+        layers["w_up"] = stacked(keys[5], E, E, M)
+        layers["w_down"] = stacked(keys[6], M, M, E)
     return {
         "embed": dense_init(k_embed, c.vocab_size, E, scale=1.0),
         "layers": layers,
@@ -178,6 +229,67 @@ def truncated(config: LlamaConfig, params: Params,
     sliced["layers"] = jax.tree.map(lambda a: a[:num_layers],
                                     params["layers"])
     return cfg, sliced
+
+
+EXPERT_KEYS = ("moe_gate", "moe_up", "moe_down")
+
+
+def split_layers(params: Params, num_layers: Optional[int] = None):
+    """``(scanned, experts)``: the per-layer tree a layer scan slices
+    (its first ``num_layers`` layers), and the stacked expert weights
+    ``[L, X, ...]``, which it must NOT slice: a scan's slice of a
+    custom call's operand is a copy (805 MB a layer at OLMoE's sizes),
+    so the routed block reads them in place at a layer index. ``experts``
+    is None for a dense model."""
+    layers = params["layers"]
+    experts = {k: layers[k] for k in EXPERT_KEYS if k in layers} or None
+    scanned = {k: v for k, v in layers.items() if k not in EXPERT_KEYS}
+    if num_layers is not None:
+        scanned = jax.tree.map(lambda a: a[:num_layers], scanned)
+    return scanned, experts
+
+
+def project_qkv(h, layer, c: LlamaConfig):
+    """The layer's q, k, v projections of normed ``h [B, S, E]``, before
+    rope: ``q [B, S, H, D]``, ``k``/``v [B, S, KVH, D]``. Every forward
+    of the family (training, the dense-cache generator, each engine
+    program) projects here, so QK-norm reaches all of them at once."""
+    q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(c.dtype))
+    k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(c.dtype))
+    v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(c.dtype))
+    if c.qk_norm:
+        def whole(x, w):
+            flat = x.reshape(*x.shape[:2], -1)
+            return rms_norm(flat, w, c.rms_eps).reshape(x.shape)
+
+        q, k = whole(q, layer["q_norm"]), whole(k, layer["k_norm"])
+    return q, k, v
+
+
+def mlp_block(h, layer, c: LlamaConfig, experts=None, li=None,
+              mesh: Optional[Mesh] = None, use_kernel=None):
+    """The layer's MLP sublayer on normed ``h [B, S, E]``: ``(out,
+    routed)``. Dense SwiGLU (``routed`` None), or, for a config with
+    experts, the dropless routed block over ``experts`` (the stacked
+    ``[L, X, ...]`` tree of :func:`split_layers`) read at layer ``li``;
+    ``routed`` is its :class:`~ray_tpu.ops.moe.Routed`: per-expert
+    assignment counts ``[X]`` and each token's experts ``[B * S, k]``."""
+    if c.num_experts:
+        from ray_tpu.ops import moe
+
+        b, s, e = h.shape
+        out, routed = moe.routed_block(
+            h.reshape(b * s, e), layer["w_router"], experts, li,
+            top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob,
+            use_kernel=use_kernel)
+        return out.reshape(b, s, e), routed
+    gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(c.dtype))
+    up = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(c.dtype))
+    act = jax.nn.silu(gate) * up
+    if mesh is not None:
+        act = constrain(act, mesh, "batch", "seq", "act_mlp")
+    return jnp.einsum("bsm,me->bse", act,
+                      layer["w_down"].astype(c.dtype)), None
 
 
 def _select_attention(config: LlamaConfig, mesh: Optional[Mesh]):
@@ -226,8 +338,15 @@ def forward(
     mesh: Optional[Mesh] = None,
     return_hidden: bool = False,
     mlp_fn=None,
+    return_routes: bool = False,
 ):
     """Compute logits [B, S, V] (fp32) for int32 tokens [B, S].
+
+    For a config with experts (the OLMoE family) every layer's MLP is
+    the dropless routed block, chosen by the config alone;
+    ``return_routes=True`` then returns ``(logits, routes)`` with
+    ``routes [L, B * S, k]`` the experts each position chose, best
+    first (what a check against a reference compares).
 
     ``mlp_fn(h, layer) -> (out, aux_scalar)`` swaps the dense SwiGLU block
     for another token-mixing-free sublayer — the MoE family
@@ -247,26 +366,23 @@ def forward(
 
     from jax.ad_checkpoint import checkpoint_name
 
-    def dense_mlp(h, layer):
-        gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(c.dtype))
-        up = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(c.dtype))
-        act = jax.nn.silu(gate) * up
-        if mesh is not None:
-            act = constrain(act, mesh, "batch", "seq", "act_mlp")
-        down = jnp.einsum("bsm,me->bse", act, layer["w_down"].astype(c.dtype))
-        return down, jnp.zeros((), jnp.float32)
+    scanned, experts = split_layers(params)
 
-    mlp = mlp_fn or dense_mlp
+    def config_mlp(h, layer, li):
+        down, routed = mlp_block(h, layer, c, experts, li, mesh=mesh)
+        return (down, jnp.zeros((), jnp.float32),
+                None if routed is None else routed.experts)
+
+    mlp = ((lambda h, layer, li: mlp_fn(h, layer) + (None,)) if mlp_fn
+           else config_mlp)
 
     def layer_fn(carry, layer):
-        x, aux_sum = carry
+        x, aux_sum, li = carry
         # Scope names ride each instruction's metadata into the compiled
         # program and the profiler's trace; they change no arithmetic.
         with jax.named_scope("attention"):
             h = rms_norm(x, layer["attn_norm"], c.rms_eps)
-            q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(c.dtype))
-            k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(c.dtype))
-            v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(c.dtype))
+            q, k, v = project_qkv(h, layer, c)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
             if mesh is not None:
@@ -285,11 +401,11 @@ def forward(
 
         with jax.named_scope("mlp"):
             h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
-            down, aux = mlp(h, layer)
+            down, aux, routes = mlp(h, layer, li)
             x = x + down
             if mesh is not None:
                 x = constrain(x, mesh, "batch", "seq", "act_embed")
-        return (x, aux_sum + aux), None
+        return (x, aux_sum + aux, li + 1), routes
 
     body = layer_fn
     if c.remat:
@@ -315,9 +431,10 @@ def forward(
                 "expected 'full', 'attn_out', or 'mlp_only'"
             )
         body = jax.checkpoint(layer_fn, policy=policy)
-    (x, aux_total), _ = jax.lax.scan(
+    (x, aux_total, _), routes = jax.lax.scan(
         lambda carry, lp: body(carry, lp),
-        (x, jnp.zeros((), jnp.float32)), params["layers"])
+        (x, jnp.zeros((), jnp.float32), jnp.int32(0)),
+        params["layers"] if mlp_fn else scanned)
 
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     if return_hidden:
@@ -332,6 +449,8 @@ def forward(
     )
     if mesh is not None:
         logits = constrain(logits, mesh, "batch", "seq", "act_vocab")
+    if return_routes:
+        return logits, routes
     return logits
 
 
@@ -424,7 +543,9 @@ def num_params(config: LlamaConfig) -> int:
         2 * c.hidden_size
         + c.hidden_size * c.num_heads * c.head_dim * 2
         + c.hidden_size * c.num_kv_heads * c.head_dim * 2
-        + 3 * c.hidden_size * c.intermediate_size
+        + 3 * c.hidden_size * c.intermediate_size * max(c.num_experts, 1)
+        + c.hidden_size * c.num_experts
+        + c.qk_norm * (c.num_heads + c.num_kv_heads) * c.head_dim
     )
     return (
         c.vocab_size * c.hidden_size * 2

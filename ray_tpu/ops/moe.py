@@ -10,14 +10,32 @@ SURVEY.md §2.3); ray_tpu provides EP natively as the ``expert`` mesh axis:
   ``expert`` mesh axis; dispatch/combine einsums become all-to-alls on ICI
   when sharded (XLA inserts them from the shardings — the
   ``ragged_all_to_all`` of SURVEY §2.3 expressed GSPMD-style).
+
+That capacity-bounded :func:`moe_layer` is the TRAINING block (Mixtral,
+the ``expert`` mesh axis). Serving routes through :func:`routed_block`
+instead, which is DROPLESS: in a served batch a dropped token would make
+one user's answer depend on who else shares the batch. It sorts the
+``T x k`` assignments by expert and runs a grouped matrix multiplication
+over the sorted rows (:func:`grouped_matmul`: the ``moe_gmm`` Mosaic
+kernel on the TPU, ``jax.lax.ragged_dot`` elsewhere), so no ``[T, E, C]``
+tensor exists, an untouched expert's weights are never read, and every
+routed token is computed. The kernel takes the STACKED ``[L, X, K, N]``
+weights with the layer as a scalar-prefetch operand (as the paged
+attention kernels take the arena), so a layer scan never slices, and so
+never copies, a layer's experts.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+import functools
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.decode_attention import _interpret_default
 
 
 def router_topk(
@@ -131,3 +149,200 @@ MOE_LOGICAL_AXES = {
     "w_up": ("experts", "embed", "mlp"),
     "w_down": ("experts", "mlp", "embed"),
 }
+
+
+# ---------------------------------------------------------------------------
+# Dropless routed block (serving; the OLMoE family)
+# ---------------------------------------------------------------------------
+
+def route_softmax_topk(h: jnp.ndarray, w_router: jnp.ndarray, k: int,
+                       renormalise: bool = False
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """OLMoE's router: softmax over ALL experts in float32, then the top
+    ``k`` of the probabilities, their weights those ``k`` entries as
+    they are (``renormalise`` divides them by their sum: the published
+    ``norm_topk_prob``). h [T, E], w_router [E, X] -> (weights [T, k]
+    float32, idx [T, k] int32). Not :func:`router_topk`, which is
+    Mixtral's softmax over the top-k logits."""
+    logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    weights, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if renormalise:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, idx.astype(jnp.int32)
+
+
+def _gmm_tiles(m: int, k: int, n: int, num_groups: int, itemsize: int):
+    """(tm, tn). A group that straddles a row tile is visited, and its
+    weights read, once per tile, so tiles are tall where groups are
+    (about twice the mean group, 128 to 512 rows); ``tn`` keeps one
+    ``[K, tn]`` weight tile at or under 2 MiB."""
+    tm = 128
+    while tm < 512 and tm * num_groups < 2 * m:
+        tm *= 2
+    tn = n
+    while tn % 256 == 0 and k * tn * itemsize > (2 << 20):
+        tn //= 2
+    return tm, tn
+
+
+def gmm_applicable(k: int, n: int, itemsize: int = 2) -> bool:
+    """True when auto-dispatch takes the ``moe_gmm`` kernel on the TPU:
+    lane-tiling widths, and a ``[K, 128]`` weight tile that fits."""
+    return not (k % 128 or n % 128) and k * 128 * itemsize <= (4 << 20)
+
+
+def _visits(group_sizes, tm: int, tiles_m: int):
+    """The kernel's schedule. Rows are sorted by group, so group ``g``
+    owns rows ``[bounds[g], bounds[g + 1])`` and meets the row tiles
+    ``bounds[g] // tm .. (bounds[g + 1] - 1) // tm``: one VISIT each.
+    At most ``tiles_m + X - 1`` visits exist (a tile boundary splits at
+    most one group); the schedule is padded to that length with repeats
+    of the last real visit, which fetch nothing new and compute nothing.
+    Returns (group of visit, tile of visit, bounds [X + 1], visits)."""
+    x = group_sizes.shape[0]
+    ends = jnp.cumsum(group_sizes)
+    bounds = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
+    first = bounds[:-1] // tm
+    per_group = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
+    visit_ends = jnp.cumsum(per_group)
+    total = visit_ends[-1]
+    v = jnp.minimum(jnp.arange(tiles_m + x - 1), jnp.maximum(total - 1, 0))
+    gid = jnp.minimum(jnp.searchsorted(visit_ends, v, side="right"), x - 1)
+    tid = first[gid] + v - (visit_ends[gid] - per_group[gid])
+    return (gid.astype(jnp.int32), tid.astype(jnp.int32),
+            bounds.astype(jnp.int32), total.astype(jnp.int32).reshape(1))
+
+
+def _gmm_kernel(layer_ref, gid_ref, tid_ref, bounds_ref, total_ref,
+                lhs_ref, rhs_ref, out_ref, *, tm):
+    v = pl.program_id(1)
+
+    @pl.when(v < total_ref[0])
+    def _visit():
+        g = gid_ref[v]
+        acc = jnp.dot(lhs_ref[...], rhs_ref[0, 0],
+                      preferred_element_type=jnp.float32)
+        rows = tid_ref[v] * tm + jax.lax.broadcasted_iota(
+            jnp.int32, acc.shape, 0)
+        mine = (rows >= bounds_ref[g]) & (rows < bounds_ref[g + 1])
+        # The tile stays resident while consecutive visits name it: each
+        # group of the tile fills in its own rows.
+        out_ref[...] = jnp.where(
+            mine, acc, out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+
+
+def _gmm_fused(lhs, rhs, group_sizes, layer, *, interpret):
+    m, k = lhs.shape
+    _, x, _, n = rhs.shape
+    itemsize = jnp.dtype(rhs.dtype).itemsize
+    tm, tn = _gmm_tiles(m, k, n, x, itemsize)
+    tiles_m = -(-m // tm)
+    if tiles_m * tm != m:
+        lhs = jnp.pad(lhs, ((0, tiles_m * tm - m), (0, 0)))
+    gid, tid, bounds, total = _visits(group_sizes, tm, tiles_m)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(n // tn, tiles_m + x - 1),
+        in_specs=[
+            pl.BlockSpec((tm, k),
+                         lambda j, v, ly, gid, tid, bnd, tot: (tid[v], 0)),
+            pl.BlockSpec((1, 1, k, tn),
+                         lambda j, v, ly, gid, tid, bnd, tot:
+                         (ly[0], gid[v], 0, j)),
+        ],
+        out_specs=pl.BlockSpec(
+            (tm, tn), lambda j, v, ly, gid, tid, bnd, tot: (tid[v], j)),
+    )
+    out = pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((tiles_m * tm, n), lhs.dtype),
+        interpret=interpret,
+        name="moe_gmm",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            # Static worst case: every expert touched.
+            flops=2 * m * k * n, transcendentals=0,
+            bytes_accessed=(x * k * n + m * k + m * n) * itemsize),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), gid, tid, bounds, total,
+      lhs, rhs)
+    return out[:m]
+
+
+def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
+                   group_sizes: jnp.ndarray, layer=None, *,
+                   use_kernel: Optional[bool] = None) -> jnp.ndarray:
+    """``out[r] = lhs[r] @ rhs[layer, g(r)]`` for rows sorted by group.
+
+    lhs [M, K], its first ``sum(group_sizes)`` rows in group order (rows
+    past them come back undefined); rhs the stacked ``[L, X, K, N]``
+    read at ``layer`` (a traced int32 scalar), or ``[X, K, N]`` with
+    ``layer`` None; group_sizes [X] int32. ``use_kernel``: None = the
+    ``moe_gmm`` kernel on the TPU when the widths tile,
+    ``jax.lax.ragged_dot`` elsewhere; True forces the kernel (interpret
+    mode off the TPU: the CPU tier-1 path); False forces ``ragged_dot``,
+    which needs the layer's experts as an array of their own."""
+    if (layer is None) != (rhs.ndim == 3):
+        raise ValueError("stacked weights need `layer`; one layer's take none")
+    interpret = _interpret_default()
+    tiles = gmm_applicable(rhs.shape[-2], rhs.shape[-1],
+                           jnp.dtype(rhs.dtype).itemsize)
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu" and tiles
+    if use_kernel and (interpret or tiles):
+        if layer is None:
+            layer, rhs = 0, rhs[None]
+        return _gmm_fused(lhs, rhs.astype(lhs.dtype), group_sizes, layer,
+                          interpret=interpret)
+    if layer is not None:
+        rhs = jax.lax.dynamic_index_in_dim(rhs, layer, 0, keepdims=False)
+    return jax.lax.ragged_dot(lhs, rhs.astype(lhs.dtype),
+                              group_sizes.astype(jnp.int32))
+
+
+class Routed(NamedTuple):
+    """What :func:`routed_block` did besides its output."""
+    rows: jnp.ndarray      # [X] int32: assignments each expert computed
+    experts: jnp.ndarray   # [T, k] int32: each token's experts, best first
+
+
+def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
+                 experts: Dict[str, jnp.ndarray], layer=None, *,
+                 top_k: int, norm_topk: bool = False,
+                 use_kernel: Optional[bool] = None
+                 ) -> Tuple[jnp.ndarray, Routed]:
+    """The dropless SwiGLU expert block on normed tokens x [T, E]:
+    ``sum_e p_e * down_e(silu(gate_e x) * up_e x)`` over each token's
+    ``top_k`` experts. ``experts``: ``moe_gate``/``moe_up`` [L, X, E, M]
+    and ``moe_down`` [L, X, M, E] read at ``layer``, or one layer's
+    ``[X, ...]`` with ``layer`` None. No capacity: every assignment is
+    computed, and a token's result does not depend on the other rows.
+    Returns (out [T, E], :class:`Routed`)."""
+    t, _ = x.shape
+    num_experts = w_router.shape[-1]
+    gmm = functools.partial(grouped_matmul, layer=layer,
+                            use_kernel=use_kernel)
+    with jax.named_scope("moe"):
+        with jax.named_scope("route"):
+            weights, idx = route_softmax_topk(x, w_router, top_k, norm_topk)
+        with jax.named_scope("sort"):
+            flat = idx.reshape(-1)                       # [T * k]
+            order = jnp.argsort(flat, stable=True)       # sorted -> flat
+            rows = jnp.zeros(num_experts, jnp.int32).at[flat].add(1)
+            xs = x[order // top_k]                       # [T * k, E]
+        with jax.named_scope("experts"):
+            act = (jax.nn.silu(gmm(xs, experts["moe_gate"], rows))
+                   * gmm(xs, experts["moe_up"], rows))
+            ys = gmm(act, experts["moe_down"], rows)
+        with jax.named_scope("combine"):
+            # Back to [T, k, E] in each token's own top-k order, so the
+            # weighted sum adds the same terms in the same order whoever
+            # else is in the batch.
+            place = jnp.zeros_like(order).at[order].set(
+                jnp.arange(order.shape[0], dtype=order.dtype))
+            out = jnp.einsum("tk,tke->te", weights,
+                             ys[place].reshape(t, top_k, -1).astype(
+                                 jnp.float32))
+    return out.astype(x.dtype), Routed(rows, idx)

@@ -1058,18 +1058,24 @@ class ServeController:
 
                 mdefs.SERVE_REPLICA_DEATHS.inc(
                     tags={"deployment": name, "cause": "died"})
-            except Exception:  # noqa: BLE001 — timeout: starting OR dead
-                birth = self._replica_birth.get(id(r))
-                if birth is not None and \
-                        now - birth < self.REPLICA_STARTUP_GRACE_S:
-                    live.append(r)  # still starting: keep, don't churn
-                elif self._replica_birth.pop(id(r), None) is not None:
-                    # Never answered inside the grace: it is replaced, so
+            except Exception:  # noqa: BLE001 — timeout: starting, busy OR dead
+                # A replica that HAD answered and now misses a probe is
+                # busy (a full engine and a hundred open streams can hold
+                # a 2s probe off) or hung: it gets the same grace, from
+                # its first missed probe. Dropped at once it would leave
+                # the routing table while alive and holding its chip, and
+                # its replacement could never start.
+                birth = self._replica_birth.setdefault(id(r), now)
+                if now - birth < self.REPLICA_STARTUP_GRACE_S:
+                    live.append(r)  # starting or busy: keep, don't churn
+                else:
+                    # No answer for the whole grace: it is replaced, so
                     # it must also die. Left alive it keeps its resources
                     # — a chip is exclusive, so the replacement would
                     # wait on it forever while nothing routes to it.
+                    self._replica_birth.pop(id(r), None)
                     logger.warning(
-                        "serve: replica of %s did not finish starting in "
+                        "serve: replica of %s answered no health probe for "
                         "%.0fs; killing and replacing it", name,
                         self.REPLICA_STARTUP_GRACE_S)
                     try:

@@ -653,3 +653,30 @@ def test_buffered_admission_not_starved(setup):
     assert r_long in out and len(out[r_long]) == 100
     # The long request's output is unaffected by the mid-flight rewinds.
     assert out[r_long] == _reference(gen, [1, 2, 3], 100)
+
+
+@pytest.mark.parametrize("sync_every", [1, 3])
+def test_token_callbacks_are_whole_and_in_order_when_a_request_ends(
+        setup, sync_every):
+    """The per-tick-sync step holds a tick's token callbacks until it
+    has dispatched the next tick (the streams then run while the device
+    computes). Whatever it holds, by the time ``step`` REPORTS a request
+    finished the callbacks have delivered all its tokens, in order: a
+    stream's end-marker is put right after ``step`` returns."""
+    config, gen, _ = setup
+    seen = {}
+    eng = ContinuousBatcher(
+        config, params=gen.params, num_slots=2, max_len=64,
+        sync_every=sync_every,
+        token_callback=lambda rid, tok: seen.setdefault(rid, []).append(tok))
+    rng = np.random.default_rng(0)
+    want = {eng.submit(rng.integers(1, config.vocab_size, n).tolist(), m): m
+            for n, m in ((5, 3), (9, 7), (4, 1), (12, 6))}
+    done = {}
+    while eng.has_work():
+        finished = eng.step()
+        for rid, out in finished.items():
+            assert seen[rid] == out and len(out) == want[rid], rid
+        done.update(finished)
+    assert set(done) == set(want)
+    assert not eng._held_tokens

@@ -427,3 +427,27 @@ def test_cancel_pending_task(ray_start_regular):
     with pytest.raises(exceptions.TaskCancelledError):
         ray_tpu.get(v, timeout=10)
     ray_tpu.get(b)
+
+
+def test_memory_store_wait_on_one_object_sleeps_on_its_event():
+    """One object is waited for on its own event: ready at once when it
+    is put (no polling step to wait out), and back at the timeout when
+    it never comes."""
+    import threading
+    import time
+
+    from ray_tpu._private.ids import ObjectID
+    from ray_tpu._private.memory_store import MemoryStore
+
+    store = MemoryStore()
+    oid = ObjectID.from_random()
+    threading.Timer(0.05, store.put, args=(oid, 7)).start()
+    t0 = time.monotonic()
+    ready, not_ready = store.wait([oid], 1, timeout=5.0)
+    assert ready == [oid] and not_ready == [] and time.monotonic() - t0 < 1.0
+    missing = ObjectID.from_random()
+    t0 = time.monotonic()
+    ready, not_ready = store.wait([missing], 1, timeout=0.1)
+    assert ready == [] and not_ready == [missing]
+    assert 0.09 <= time.monotonic() - t0 < 1.0
+    assert store.wait([oid, missing], 1, timeout=0.1)[0] == [oid]

@@ -1,0 +1,307 @@
+"""The OLMoE family (a config with experts) on the CPU at small sizes:
+8 experts, top 2, hidden 64, 2 layers, MHA, QK-norm. The dropless
+routed block, the one MLP / one q-k-v hook, and the engine, against
+``benchmark/reference_olmoe.py`` (plain float32, a masked loop over the
+experts) on seeded weights.
+
+Tolerances. float32 against the float32 reference: 1e-4 on O(1) logits,
+since both sides hold the same numbers and differ only in operation
+order. bfloat16 against float32: a bf16 router can flip an expert where
+the k-th and (k+1)-th probabilities nearly tie, which moves a logit by a
+STEP, not by rounding; so bf16 is held to the chosen-token gap the
+benchmark uses (how far under the reference's maximum the engine's
+token lies, in standard deviations of that position's logits), at 0.5:
+a token drawn at random lies about 3 under at this vocabulary.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark import reference, reference_olmoe  # noqa: E402
+from ray_tpu.models import llama  # noqa: E402
+from ray_tpu.models.continuous_batching import ContinuousBatcher  # noqa: E402
+from ray_tpu.models.inference import LlamaGenerator  # noqa: E402
+from ray_tpu.ops import moe  # noqa: E402
+
+
+def tiny(**kw):
+    kw = {**dict(num_experts=8, num_experts_per_tok=2, qk_norm=True,
+                 intermediate_size=32, num_kv_heads=4, dtype=jnp.float32,
+                 attention="reference"), **kw}
+    return llama.LlamaConfig.tiny(**kw)
+
+
+@pytest.fixture(scope="module")
+def model():
+    config = tiny()
+    return config, llama.init_params(config, jax.random.PRNGKey(0))
+
+
+def _prompts(n, lengths=(5, 9, 17, 12, 7, 21, 3, 14), seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 256, lengths[i % len(lengths)]).tolist()
+            for i in range(n)]
+
+
+def _serve(config, params, prompts, max_new=6, **engine):
+    engine = {**dict(num_slots=4, max_len=64, block_size=16), **engine}
+    eng = ContinuousBatcher(config, params=params, **engine)
+    rids = [eng.submit(p, max_new) for p in prompts]
+    out = eng.run_to_completion()
+    return [out[r] for r in rids]
+
+
+# --------------------------------------------------------------- router
+
+def test_router_is_softmax_over_all_then_topk_unrenormalised():
+    h = jax.random.normal(jax.random.PRNGKey(1), (11, 64))
+    w = jax.random.normal(jax.random.PRNGKey(2), (64, 8)) / 8
+    weights, idx = moe.route_softmax_topk(h, w, 3)
+    probs = np.asarray(jax.nn.softmax(h @ w, axis=-1))
+    order = np.argsort(-probs, axis=-1)[:, :3]
+    np.testing.assert_array_equal(np.asarray(idx), order)
+    np.testing.assert_allclose(np.asarray(weights),
+                               np.take_along_axis(probs, order, -1),
+                               rtol=1e-5)
+    assert np.all(np.asarray(weights).sum(-1) < 0.95)  # not renormalised
+    renorm, _ = moe.route_softmax_topk(h, w, 3, renormalise=True)
+    np.testing.assert_allclose(np.asarray(renorm).sum(-1), 1.0, rtol=1e-6)
+    # Mixtral's router (softmax over the top-k logits) is another function.
+    mixtral, _ = moe.router_topk(h @ w, 3)
+    assert not np.allclose(np.asarray(mixtral), np.asarray(weights))
+
+
+def test_norm_topk_prob_changes_the_block(model):
+    config, params = model
+    tokens = jnp.asarray(_prompts(1, (24,))[0])[None]
+    plain = llama.forward(params, tokens, config)
+    renorm = llama.forward(
+        params, tokens, llama.dataclasses.replace(config, norm_topk_prob=True))
+    assert float(jnp.max(jnp.abs(plain - renorm))) > 1e-2
+
+
+# ------------------------------------------------------ the routed block
+
+def _masked_loop(x, w_router, experts, top_k):
+    weights, idx = moe.route_softmax_topk(x, w_router, top_k)
+    out = jnp.zeros_like(x)
+    for e in range(w_router.shape[1]):
+        p = jnp.sum(jnp.where(idx == e, weights, 0.0), axis=-1)
+        y = (jax.nn.silu(x @ experts["moe_gate"][e])
+             * (x @ experts["moe_up"][e])) @ experts["moe_down"][e]
+        out = out + p[:, None] * y
+    return out
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["ragged_dot", "moe_gmm_interpreted"])
+@pytest.mark.parametrize("tokens", [6, 48, 300])
+def test_routed_block_computes_every_assignment(tokens, use_kernel):
+    """Dropless at any load: 6 rows leave experts untouched, 300 rows of
+    top 2 over 8 experts put about 75 on each (a capacity of 1.25 x the
+    mean would drop some), several row tiles deep."""
+    keys = jax.random.split(jax.random.PRNGKey(tokens), 5)
+    x = jax.random.normal(keys[0], (tokens, 64))
+    w_router = jax.random.normal(keys[1], (64, 8))
+    experts = {"moe_gate": jax.random.normal(keys[2], (3, 8, 64, 32)) / 8,
+               "moe_up": jax.random.normal(keys[3], (3, 8, 64, 32)) / 8,
+               "moe_down": jax.random.normal(keys[4], (3, 8, 32, 64)) / 6}
+    out, routed = jax.jit(lambda x, li: moe.routed_block(
+        x, w_router, experts, li, top_k=2, use_kernel=use_kernel))(
+            x, jnp.int32(1))
+    want = _masked_loop(x, w_router,
+                        {k: v[1] for k, v in experts.items()}, 2)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+    assert int(routed.rows.sum()) == tokens * 2
+    np.testing.assert_array_equal(
+        np.asarray(routed.rows),
+        np.bincount(np.asarray(routed.experts).ravel(), minlength=8))
+
+
+def test_gmm_schedule_visits_every_group_tile_pair_once():
+    sizes = jnp.asarray([0, 130, 0, 5, 121, 0, 300, 0], jnp.int32)
+    gid, tid, bounds, total = moe._visits(sizes, 128, 5)
+    n = int(total[0])
+    pairs = list(zip(np.asarray(gid)[:n].tolist(),
+                     np.asarray(tid)[:n].tolist()))
+    assert pairs == [(1, 0), (1, 1), (3, 1), (4, 1), (6, 2), (6, 3), (6, 4)]
+    # the tail repeats the last real visit: nothing new to fetch
+    assert set(zip(np.asarray(gid)[n:].tolist(),
+                   np.asarray(tid)[n:].tolist())) == {(6, 4)}
+    assert np.asarray(bounds).tolist() == [0, 0, 130, 130, 135, 256, 256,
+                                           556, 556]
+
+
+# ------------------------------------------- system against the reference
+
+def test_forward_matches_reference_and_routes_agree(model):
+    config, params = model
+    tokens = _prompts(1, (40,), seed=4)[0]
+    want = reference_olmoe.logits(params, tokens, config)
+    with jax.default_matmul_precision("highest"):
+        got, routes = llama.forward(params, jnp.asarray(tokens)[None],
+                                    config, return_routes=True)
+    # float32 both sides, other operation order (see the module's top).
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
+                               rtol=0, atol=1e-4)
+    ref_routes = reference_olmoe.router_choices(params, tokens, config)
+    np.testing.assert_array_equal(np.sort(np.asarray(routes), -1),
+                                  np.sort(np.asarray(ref_routes), -1))
+
+
+@pytest.mark.parametrize("engine", [
+    dict(paged=True), dict(paged=True, use_decode_kernel=True),
+    dict(paged=False)], ids=["paged", "paged_kernels_interpreted", "dense"])
+def test_engine_tokens_are_the_references_argmax(model, engine):
+    """Prefill, then decode through the cache: every token the float32
+    engine chose is the reference's own argmax (gap 0), or lies within
+    1e-4 of it in logits."""
+    config, params = model
+    prompts = _prompts(3, seed=7)
+    for prompt, chosen in zip(prompts, _serve(config, params, prompts,
+                                              **engine)):
+        lg = reference_olmoe.logits(params, (prompt + chosen)[:-1], config)
+        lg = np.asarray(lg[len(prompt) - 1:])
+        under = lg.max(-1) - lg[np.arange(len(chosen)), chosen]
+        assert np.all(under <= 1e-4), under
+
+
+def test_generator_matches_engine(model):
+    config, params = model
+    prompt = _prompts(1, (9,), seed=2)
+    gen = LlamaGenerator(config, params=params, max_len=32)
+    want = np.asarray(gen.generate(np.asarray(prompt), max_new_tokens=5))[0]
+    assert _serve(config, params, prompt, max_new=5)[0] == want.tolist()
+
+
+def test_bf16_engine_within_the_stated_gap():
+    config = tiny(dtype=jnp.bfloat16)
+    params = llama.init_params(config, jax.random.PRNGKey(0))
+    prompts = _prompts(3, seed=9)
+    worst = 0.0
+    for prompt, chosen in zip(prompts, _serve(config, params, prompts)):
+        gaps = reference_olmoe.chosen_gaps(params, prompt, chosen, config)
+        worst = max(worst, float(jnp.max(gaps)))
+    assert worst <= 0.5, worst     # the module's top says why 0.5
+
+
+def test_qk_norm_on_and_off_differ(model):
+    config, params = model
+    tokens = jnp.asarray(_prompts(1, (24,))[0])[None]
+    on = llama.forward(params, tokens, config)
+    off = llama.forward(params, tokens,
+                        llama.dataclasses.replace(config, qk_norm=False))
+    assert float(jnp.max(jnp.abs(on - off))) > 1e-2
+    ref_off = reference_olmoe.logits(
+        params, tokens[0], llama.dataclasses.replace(config, qk_norm=False))
+    np.testing.assert_allclose(np.asarray(off[0]), np.asarray(ref_off),
+                               atol=1e-4)
+
+
+# ------------------------------------------------- the dropless property
+
+@pytest.mark.parametrize("use_decode_kernel", [None, True],
+                         ids=["xla", "kernels_interpreted"])
+def test_a_requests_tokens_do_not_depend_on_its_batch(model,
+                                                      use_decode_kernel):
+    """One request alone against the same request among 7 others:
+    identical tokens. Under capacity routing the neighbours' rows could
+    push this request's tokens out of an expert."""
+    config, params = model
+    prompts = _prompts(8, seed=11)
+    alone = _serve(config, params, prompts[:1], max_new=10, num_slots=8,
+                   use_decode_kernel=use_decode_kernel)[0]
+    crowd = _serve(config, params, prompts, max_new=10, num_slots=8,
+                   use_decode_kernel=use_decode_kernel)[0]
+    assert alone == crowd
+
+
+# ------------------------------------- one expert is the dense Llama model
+
+def _as_dense(config, params):
+    dense = llama.dataclasses.replace(config, num_experts=0,
+                                      num_experts_per_tok=0)
+    layers = dict(params["layers"])
+    for moe_key, key in (("moe_gate", "w_gate"), ("moe_up", "w_up"),
+                         ("moe_down", "w_down")):
+        layers[key] = layers.pop(moe_key)[:, 0]
+    del layers["w_router"]
+    return dense, dict(params, layers=layers)
+
+
+def test_one_expert_top_one_is_the_dense_llama_engine():
+    """softmax over one expert is 1, so the routed block IS the dense
+    SwiGLU block on the same weights."""
+    config = tiny(num_experts=1, num_experts_per_tok=1, qk_norm=False)
+    params = llama.init_params(config, jax.random.PRNGKey(3))
+    dense, dense_params = _as_dense(config, params)
+    prompts = _prompts(3, seed=5)
+    assert (_serve(config, params, prompts)
+            == _serve(dense, dense_params, prompts))
+    tokens = prompts[1] + [7, 8, 9]
+    np.testing.assert_allclose(
+        np.asarray(reference_olmoe.logits(params, tokens, config)),
+        np.asarray(reference.logits(dense_params, tokens, dense)),
+        atol=1e-5)
+
+
+# ------------------------------------------------------------ bookkeeping
+
+def test_tick_reports_expert_rows_in_the_token_fetch(model):
+    from ray_tpu._private import metrics_defs as mdefs
+
+    config, params = model
+    eng = ContinuousBatcher(config, params=params, num_slots=4, max_len=64,
+                            block_size=16)
+
+    def total(metric):
+        return sum(v for name, _, v in metric.samples()
+                   if name.endswith(("_total", "_count")))
+
+    before = total(mdefs.CB_MOE_ASSIGNMENTS), total(mdefs.CB_MOE_TOUCHED_SHARE)
+    eng.submit(_prompts(1)[0], 4)
+    eng.run_to_completion()
+    ticks = eng.base_tick_count
+    # every slot routes, live or not: slots x top-k x layers a tick
+    assert (total(mdefs.CB_MOE_ASSIGNMENTS) - before[0]
+            == ticks * 4 * 2 * config.num_layers)
+    assert total(mdefs.CB_MOE_TOUCHED_SHARE) - before[1] == ticks
+
+
+def test_tick_bytes_estimate_counts_the_experts_a_tick_can_touch():
+    config = tiny(num_experts=64, num_experts_per_tok=2)
+    eng = ContinuousBatcher(config, num_slots=4, max_len=32, block_size=16)
+    expert_bytes = sum(eng.params["layers"][k].nbytes
+                       for k in llama.EXPERT_KEYS)
+    # 4 slots x top 2 touch at most 8 of each layer's 64 experts
+    assert (eng.tick_bytes_estimate()
+            == eng.param_bytes - expert_bytes * 56 // 64)
+    full = ContinuousBatcher(config, num_slots=32, max_len=32, block_size=16)
+    assert full.tick_bytes_estimate() == full.param_bytes
+
+
+def test_params_and_axes_of_the_family():
+    config = tiny()
+    shapes = jax.eval_shape(lambda k: llama.init_params(config, k),
+                            jax.random.PRNGKey(0))
+    layers = shapes["layers"]
+    assert layers["w_router"].shape == (2, 64, 8)
+    assert layers["w_router"].dtype == jnp.float32
+    assert layers["moe_gate"].shape == (2, 8, 64, 32)
+    assert layers["moe_down"].shape == (2, 8, 32, 64)
+    assert layers["q_norm"].shape == (2, 64)
+    assert "w_gate" not in layers
+    axes = llama.logical_axes(config)["layers"]
+    assert set(axes) == set(layers)
+    assert all(len(axes[k]) == layers[k].ndim for k in layers)
+    assert llama.num_params(config) == sum(
+        int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
+    published = llama.LlamaConfig.olmoe_1b_7b()
+    assert 6.9e9 < llama.num_params(published) < 6.93e9

@@ -215,3 +215,55 @@ def test_compiled_paged_tick_moves_no_arena_slab(v5e_chip, kv_dtype):
     slab = _TICK_BLOCKS * 8 * _TICK_BS * 128 * (
         1 if kv_dtype == "int8" else 2)
     assert compiled.memory_analysis().temp_size_in_bytes < slab
+
+
+# --------------------------------------------- the routed block (OLMoE)
+
+@pytest.mark.parametrize("rows", [384, 8192])
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])
+def test_moe_gmm_lowers(rows, k, n):
+    """The grouped kernel at OLMoE's widths, decode and prefill row
+    counts, reading 12 stacked layers at a traced layer index."""
+    from ray_tpu.ops.moe import grouped_matmul
+
+    fn = functools.partial(grouped_matmul, use_kernel=True)
+    assert _mosaic_calls(fn, S((rows, k), BF16), S((12, 64, k, n), BF16),
+                         S((64,), jnp.int32), S((), jnp.int32)) == 1
+
+
+def test_compiled_olmoe_tick_copies_no_expert_weights(v5e_chip):
+    """serve_moe_decode's tick (published widths, 48 slots, 512 blocks,
+    two layers): each layer calls ``moe_gmm`` three times on the
+    STACKED expert weights, and no other instruction has a layer's
+    expert-weight shape: a per-layer slice would copy 805 MB a layer a
+    tick. MHA: both paged kernels compile at 16 KV heads, group 1."""
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models.paged_kv import PagedKVCache
+
+    cfg = llama.LlamaConfig.olmoe_1b_7b(num_layers=2, max_seq_len=1024)
+
+    def spec(a):
+        return S(a.shape, a.dtype, sharding=v5e_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        functools.partial(llama.init_params, cfg), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        PagedKVCache.create, cfg, 512, 64, kv_dtype="bf16")))
+    row = S((48,), jnp.int32, sharding=v5e_chip)
+    tables = S((48, 16), jnp.int32, sharding=v5e_chip)
+    step = S((), jnp.int32, sharding=v5e_chip)
+    tick = functools.partial(cb._decode_tick_paged, config=cfg,
+                             use_kernel=True)
+    compiled = jax.jit(tick, donate_argnums=(5,)).lower(
+        params, row, row, tables, row, cache, step).compile()
+    hlo = compiled.as_text()
+    shaped = re.compile(r"= \(?\w+\[(\d+,)?64,(2048,1024|1024,2048)\]")
+    free = ("custom_call_target=\"tpu_custom_call\"", " parameter(",
+            " get-tuple-element(", " tuple(", " while(", " bitcast(")
+    assert [line.strip() for line in hlo.splitlines()
+            if shaped.search(line) and not any(f in line for f in free)] == []
+    assert len(re.findall(r"%moe_gmm[.\d]* = ", hlo)) == 3
+    assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 1
+    assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == 2
+    # One expert matrix is 4 MB: the program's scratch is far below it.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2048 * 1024 * 2
