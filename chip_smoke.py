@@ -13,7 +13,10 @@ seed), and checks what comes out by the repo's own means:
                 attention (bf16 and int8 arena), COMPILED, against their
                 references within the tolerances in ``TOLERANCE``; the
                 routed block's grouped matmul (``moe_gmm``) against a
-                masked loop over the experts;
+                masked loop over the experts; ``paged_decode_attn``
+                bit for bit against the walk over every table entry it
+                replaced (PR 26), and both timed alone at the three
+                serve cells' shapes and fill;
 * ``moe``       the OLMoE family's bf16 forward against the float32
                 reference at the published widths, and three faults
                 (an expert dropped, weights renormalised, no QK-norm)
@@ -59,7 +62,7 @@ PHASES = ("kernels", "moe", "train", "serve", "multichip")
 CHILDREN = {"kernels": ("kernels",), "moe": ("moe",), "train": ("train",),
             "serve": ("serve",),
             "multichip": ("multichip-train", "multichip-serve")}
-PHASE_TIMEOUT_S = {"kernels": 420, "moe": 600, "train": 480, "serve": 600,
+PHASE_TIMEOUT_S = {"kernels": 600, "moe": 600, "train": 480, "serve": 600,
                    "multichip-train": 900, "multichip-serve": 900}
 RESULT_TAG = "PHASE_RESULT "
 NO_ACCELERATOR_RC = 3    # a child found no TPU: no later phase can pass
@@ -239,6 +242,179 @@ def _same(phase: str, name: str, got, ref) -> None:
     assert diff == 0, f"{name}: not bit-identical"
 
 
+def walk_every_entry(q, arena_k, arena_v, tables, positions, *, layer=None,
+                     k_scale=None, v_scale=None):
+    """``paged_decode_attn`` as it was before PR 26, kept as the
+    yardstick: grid ``(slots, table entries)``, every entry a grid step
+    and a dead one skipped by ``pl.when``. A live slot's blocks go
+    through the same ``_attend_block`` in the same order as in
+    ``paged_decode_attention``, so its row must come out the same bits;
+    a freed slot attends the garbage block at position 0. Arguments as
+    ``paged_decode_attention``'s."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from ray_tpu.ops.decode_attention import (_attend_block, _finalize,
+                                              _init_state,
+                                              _interpret_default, _scratch,
+                                              pltpu)
+
+    if layer is None:
+        layer = 0
+        arena_k, arena_v = arena_k[None], arena_v[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    b, hq, d = q.shape
+    hkv, bs = arena_k.shape[2], arena_k.shape[3]
+    nb, group = tables.shape[1], hq // hkv
+    quantized = k_scale is not None
+
+    def kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest):
+        if quantized:
+            ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        else:
+            o_ref, acc_ref, m_ref, l_ref = rest
+        j = pl.program_id(1)
+        pos = pos_ref[pl.program_id(0)]
+
+        @pl.when(j == 0)
+        def _init():
+            _init_state(acc_ref, m_ref, l_ref)
+
+        @pl.when(j * bs <= pos)
+        def _body():
+            _attend_block(q_ref[0], k_ref[0, 0], v_ref[0, 0], pos, j * bs,
+                          acc_ref, m_ref, l_ref, scale=d ** -0.5,
+                          k_scale=ks_ref[0, 0] if quantized else None,
+                          v_scale=vs_ref[0, 0] if quantized else None)
+
+        @pl.when(j == nb - 1)
+        def _fin():
+            _finalize(o_ref, acc_ref, l_ref)
+
+    q_spec = pl.BlockSpec((1, hkv, group, d),
+                          lambda b_, j, ly, tab, po: (b_, 0, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, hkv, bs, d),
+        lambda b_, j, ly, tab, po: (ly[0], tab[b_, j], 0, 0, 0))
+    sc_spec = pl.BlockSpec(
+        (1, 1, hkv, bs), lambda b_, j, ly, tab, po: (ly[0], tab[b_, j], 0, 0))
+    scales = [k_scale, v_scale] if quantized else []
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, nb),
+            in_specs=[q_spec, kv_spec, kv_spec] + [sc_spec] * len(scales),
+            out_specs=q_spec, scratch_shapes=_scratch(hkv, group, d)),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
+        interpret=_interpret_default(), name="paged_walk_every_entry",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
+      positions.astype(jnp.int32), q.reshape(b, hkv, group, d), arena_k,
+      arena_v, *scales)
+    return out.reshape(b, hq, d)
+
+
+# The paged kernel alone at each serve cell's shapes and fill: (cell,
+# slots, table entries, q heads, kv heads, layers, arena blocks, live
+# blocks of each live slot, us a call of the every-entry walk in that
+# cell's trace (PERF.md section 5, PR 24 and PR 25)). Slots without
+# blocks are freed, as most of serve_chat's are at its arrival rate.
+PAGED_CELLS = (
+    ("serve_chat", 48, 32, 32, 8, 16, 1000, {6 * i: 6 for i in range(8)},
+     209),
+    ("serve_prefill_heavy", 8, 18, 32, 8, 16, 145,
+     {i: 13 for i in range(8)}, 71),
+    ("serve_moe_decode", 48, 16, 16, 16, 12, 512,
+     {i: 4 + i % 2 for i in range(48)}, 292),
+)
+PAGED_REHEARSAL_CELLS = (
+    ("tiny", 4, 4, 4, 2, 2, 17, {0: 2, 2: 4}, None),)
+
+
+def _paged_cell_inputs(slots, nb, hq, hkv, layers, blocks, live, bs, d):
+    """One cell's kernel inputs: (q, [arena_k, arena_v], tables,
+    positions, limits). Live slots own their blocks alone and stand
+    near the end of their last one; the others are freed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    key = jax.random.split(jax.random.PRNGKey(26), 3)
+    arena = [jax.random.normal(k, (layers, blocks, hkv, bs, d),
+                               jnp.bfloat16) for k in key[:2]]
+    q = jax.random.normal(key[2], (slots, hq, d), jnp.bfloat16)
+    tables = np.zeros((slots, nb), np.int32)
+    positions = np.zeros(slots, np.int32)
+    limits = np.zeros(slots, np.int32)
+    ids = iter(range(1, blocks))
+    for slot, n in live.items():
+        # A dead tail repeats the last live block (`_table_row`).
+        row = [next(ids) for _ in range(n)]
+        tables[slot] = row + [row[-1]] * (nb - n)
+        positions[slot] = n * bs - 1 - slot % bs
+        limits[slot] = n * bs
+    return (q, arena) + tuple(map(jnp.asarray, (tables, positions, limits)))
+
+
+def _time_us(call, q, arena, layers: int, reps: int) -> float:
+    """Microseconds a call of ``call(q, k, v, layer)``: ``reps`` calls
+    inside one program (a call dispatched by itself is mostly
+    dispatch), the layer index turning with the call."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def loop(q, k, v):
+        def body(i, acc):
+            return acc + call(q, k, v, i % layers).astype(jnp.float32)
+        return jax.lax.fori_loop(0, reps, body,
+                                 jnp.zeros(q.shape, jnp.float32))
+
+    loop(q, *arena).block_until_ready()
+    t0 = time.perf_counter()
+    loop(q, *arena).block_until_ready()
+    return (time.perf_counter() - t0) / reps * 1e6
+
+
+def _time_paged_cells(phase: str, device_kind: str, rehearse: bool) -> None:
+    """Time ``paged_decode_attn`` and the walk it replaced, each alone,
+    beside the time the live blocks' bytes take at the device's HBM
+    peak. The schedule is made once, outside the loop, as the engine
+    makes it."""
+    from benchmark import peaks
+    from ray_tpu.ops.paged_decode_attention import (paged_decode_attention,
+                                                    paged_visits)
+
+    bs, d, reps = (32, 128, 4) if rehearse else (64, 128, 1024)
+    # The rehearsal's CPU has no peak on record, and no time to compare.
+    hbm = None if rehearse else peaks.for_device(device_kind)[
+        "hbm_bytes_per_s"]
+    for cell, *shape, was_us in (PAGED_REHEARSAL_CELLS if rehearse
+                                 else PAGED_CELLS):
+        slots, nb, _, hkv, layers, _, live = shape
+        q, arena, tables, positions, limits = _paged_cell_inputs(
+            *shape, bs, d)
+        visits = paged_visits(tables, positions, limits, block_size=bs)
+        new_us = _time_us(lambda q, k, v, li: paged_decode_attention(
+            q, k, v, tables, positions, layer=li, visits=visits,
+            use_kernel=True), q, arena, layers, reps)
+        old_us = _time_us(lambda q, k, v, li: walk_every_entry(
+            q, k, v, tables, positions, layer=li), q, arena, layers, reps)
+        n_live = sum(live.values())
+        what = (f"paged_decode_attn alone, {cell} ({slots} x {nb} entries, "
+                f"{n_live} live, {slots - len(live)} slots freed)")
+        if rehearse:
+            _say(phase, f"{what}: both kernels ran; a rehearsal times "
+                        "nothing")
+            continue
+        live_bytes = n_live * 2 * hkv * bs * d * 2    # K and V, bf16
+        _say(phase, f"{what}: {new_us:.1f} us a call; every-entry walk "
+                    f"{old_us:.1f} us here, {was_us} us in the cell's "
+                    f"trace; live bytes / {hbm / 1e9:.0f} GB/s = "
+                    f"{live_bytes / hbm * 1e6:.1f} us")
+
+
 def phase_kernels(rehearse: bool) -> None:
     phase = "kernels"
     info = _open_device(phase, rehearse)
@@ -349,6 +525,23 @@ def phase_kernels(rehearse: bool) -> None:
                paged8(qd, kq, vq, tables, positions, ks, vs), ref8,
                TOLERANCE["decode_int8"])
 
+        # -- PR 26: live blocks only, against the walk over every entry ---
+        # Every fourth slot is freed (limit 0): its row comes back zero;
+        # every other row is the walk's, bit for bit.
+        freed = jnp.arange(slots) % 4 == 3
+        limits = jnp.where(freed, 0, s_max).astype(jnp.int32)
+        for name, (k_, v_), scales in (
+                ("bf16", (ak, av), {}),
+                ("int8", (kq, vq), {"k_scale": ks, "v_scale": vs})):
+            got = jax.jit(lambda q, k, v, sc: paged_decode_attention(
+                q, k, v, tables, positions, limits=limits, use_kernel=True,
+                **sc))(qd, k_, v_, scales)
+            want = jax.jit(lambda q, k, v, sc: walk_every_entry(
+                q, k, v, tables, positions, **sc))(qd, k_, v_, scales)
+            _same(phase, f"paged decode {name}, live blocks only against "
+                         f"every entry ({tag})", got,
+                  jnp.where(freed[:, None, None], 0, want))
+
         # -- the engine's form: whole arena, layer index, in-place write --
         # Layer 0 holds (K, V), the last layer (V, K): a read at either
         # must be the slab call's bits, whatever lies in between.
@@ -423,6 +616,7 @@ def phase_kernels(rehearse: bool) -> None:
                 assert _mosaic_calls(gmm.lower(a, w, g).compile()) == 1
             _check(phase, f"moe_gmm [{m},{kk}] x [{x_},{kk},{nn}] bf16",
                    gmm(a, w, g), masked_loop(a, w, g), TOLERANCE["moe_gmm"])
+    _time_paged_cells(phase, info["kind"], rehearse)
     _finish(phase, info)
 
 

@@ -401,6 +401,12 @@ CB_MOE_LOAD_IMBALANCE = Histogram(
     "averaged over layers (1 = perfectly even)",
     boundaries=(1.0, 1.25, 1.5, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0, 16.0, 64.0),
     tag_keys=("engine",))
+CB_PAGED_LIVE_BLOCK_SHARE = Histogram(
+    "ray_tpu_cb_paged_live_block_share",
+    "Per decode tick: block-table entries that hold a key a query may "
+    "see, over num_slots x max_blocks; the paged attention kernel "
+    "visits those entries and no others",
+    boundaries=_SHARE_BOUNDS, tag_keys=("engine",))
 CB_TICK_MS = Histogram(
     "ray_tpu_cb_tick_ms",
     "Wall milliseconds per decode tick (dispatch+compute+fetch with "

@@ -74,6 +74,7 @@ from ray_tpu.ops.decode_attention import (decode_applicable,
                                           env_flag)
 from ray_tpu.ops.paged_decode_attention import (paged_applicable,
                                                 paged_decode_attention,
+                                                paged_visits,
                                                 paged_kv_write)
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.util import tracing
@@ -111,15 +112,29 @@ def _scatter_arena(arena, new, block_idx, offset):
     return arena.at[block_idx, :, offset].set(new.astype(arena.dtype))
 
 
+def _window_visits(tables, positions, limits, block_size: int,
+                   use_kernel: bool):
+    """The attention kernel's schedule for each of a window's S
+    positions (``positions`` [B, S]): made ONCE a program, before the
+    layer loop, because XLA leaves it inside the loop otherwise. A freed
+    slot (limit 0) is never visited. None without the kernel."""
+    if not use_kernel:
+        return None
+    return [paged_visits(tables, positions[:, j], limits,
+                         block_size=block_size)
+            for j in range(positions.shape[1])]
+
+
 def _write_then_attend(arenas, li, q, k_new, v_new, block_idx, offset,
-                       tables, positions, scale, use_kernel: bool):
+                       tables, positions, visits, scale, use_kernel: bool):
     """The layer body the paged tick, the self-draft and verify share:
     write each slot's S new tokens' K/V into layer ``li`` (S = 1 for a
     tick, k+1 for verify), then attend every window position over the
     slot's blocks. ``arenas`` = (k, v, k_scale, v_scale), each the WHOLE
     ``[L, NB, KVH, bs, ...]`` array as the layer scan carries it (scales
     None for a bf16 arena); q/k_new/v_new [B, S, Hq|KVH, D];
-    block_idx/offset/positions [B, S]. All S writes land before any
+    block_idx/offset/positions [B, S]; ``visits`` from
+    :func:`_window_visits`. All S writes land before any
     query attends, which position masking makes safe (query j sees
     [0..p+j] only). Returns (o [B, S, Hq, D], arenas').
 
@@ -163,6 +178,7 @@ def _write_then_attend(arenas, li, q, k_new, v_new, block_idx, offset,
     ck, cv, ks, vs = view
     outs = [paged_decode_attention(q[:, j], ck, cv, tables,
                                    positions[:, j], scale, layer=layer,
+                                   visits=visits and visits[j],
                                    k_scale=ks, v_scale=vs,
                                    use_kernel=use_kernel)
             for j in range(q.shape[1])]   # unrolled: S = k+1, small
@@ -296,6 +312,8 @@ def _draft_forward_paged(params, n_draft, tokens, positions, tables,
         tables, (positions // bs)[:, None], axis=1)[:, 0]
     block_idx = jnp.where(positions < limits, gathered, GARBAGE_BLOCK)
     offset = positions % bs
+    visits = _window_visits(tables, positions[:, None], limits, bs,
+                            use_kernel)
 
     sliced, experts = llama.split_layers(params, n_draft)
 
@@ -304,7 +322,7 @@ def _draft_forward_paged(params, n_draft, tokens, positions, tables,
         q, k, v = _layer_qkv(x, layer, cos, sin, c)
         o, arenas = _write_then_attend(
             arenas, li, q, k, v, block_idx[:, None], offset[:, None],
-            tables, positions[:, None], scale, use_kernel)
+            tables, positions[:, None], visits, scale, use_kernel)
         x, _ = _layer_finish(x, o[:, 0].astype(x.dtype), layer, c,
                              experts, li, use_kernel)
         return (x, arenas, li + 1), None
@@ -377,13 +395,14 @@ def _verify_forward_paged(params, tokens, positions, tables, limits,
     block_idx = jnp.where(positions < limits[:, None], gathered,
                           GARBAGE_BLOCK)                      # [B, S]
     offset = positions % bs
+    visits = _window_visits(tables, positions, limits, bs, use_kernel)
 
     def layer_fn(carry, layer):
         x, arenas, li = carry
         q, k, v = _layer_qkv_window(x, layer, cos, sin, c)
         o, arenas = _write_then_attend(
             arenas, li, q, k, v, block_idx, offset, tables, positions,
-            scale, use_kernel)
+            visits, scale, use_kernel)
         x, _ = _layer_finish_window(x, o.astype(x.dtype), layer, c,
                                     experts, li, use_kernel)
         return (x, arenas, li + 1), None
@@ -519,7 +538,8 @@ def _decode_tick_paged(params, tokens, positions, tables, limits,
     block tables, so the attention streams only live blocks. ``tables``
     [B, max_blocks] int32 (dead tail entries repeat the last live block;
     freed slots point wholesale at the garbage block); ``limits`` [B] is
-    each slot's table-covered token count (reserved_blocks * bs)."""
+    each slot's table-covered token count (reserved_blocks * bs), 0 for
+    a freed slot, which the attention kernel then never visits."""
     c = config
     bs = cache.block_size
     cos, sin = rope_frequencies(c.head_dim, 0, c.rope_theta,
@@ -538,13 +558,15 @@ def _decode_tick_paged(params, tokens, positions, tables, limits,
         tables, (positions // bs)[:, None], axis=1)[:, 0]        # [B]
     block_idx = jnp.where(positions < limits, gathered, GARBAGE_BLOCK)
     offset = positions % bs                                      # [B]
+    visits = _window_visits(tables, positions[:, None], limits, bs,
+                            use_kernel)
 
     def layer_fn(carry, layer):
         x, arenas, li = carry
         q, k, v = _layer_qkv(x, layer, cos, sin, c)
         o, arenas = _write_then_attend(
             arenas, li, q, k, v, block_idx[:, None], offset[:, None],
-            tables, positions[:, None], scale, use_kernel)
+            tables, positions[:, None], visits, scale, use_kernel)
         x, rows = _layer_finish(x, o[:, 0].astype(x.dtype), layer, c,
                                 experts, li, use_kernel)
         return (x, arenas, li + 1), rows
@@ -1982,7 +2004,29 @@ class ContinuousBatcher:
                 "live_tokens": live,
                 "frag_ratio": max(1.0 - live / cap, 0.0) if cap else 0.0}
 
-    def tick_bytes_estimate(self, spec_k: Optional[int] = None) -> int:
+    def _live_blocks(self) -> int:
+        """Block-table entries that hold a key the next tick's queries
+        may see: the visits ``paged_decode_attn`` makes a layer."""
+        bs = self.block_size
+        return sum(st["pos"] // bs + 1 for st in self._slots.values())
+
+    def _account_tick(self, tick_fn, wall_s: float, spec_k: int) -> None:
+        """Feed one tick (or a buffered window's mean tick) to the XLA
+        monitor. A paged tick gets the live-byte hint, because the
+        compiled cost prices every table entry as live, and books the
+        share of entries that were: both from one pass over the slots."""
+        from ray_tpu._private import metrics_defs as mdefs
+
+        hint = None
+        if self.paged:
+            live = self._live_blocks()
+            mdefs.CB_PAGED_LIVE_BLOCK_SHARE.observe(
+                live / (self.num_slots * self.max_blocks), tags=self._mtags)
+            hint = self.tick_bytes_estimate(spec_k=spec_k, live_blocks=live)
+        tick_fn.note_execution(wall_s, bytes_hint=hint)
+
+    def tick_bytes_estimate(self, spec_k: Optional[int] = None,
+                            live_blocks: Optional[int] = None) -> int:
         """HBM bytes one decode tick actually streams: the full parameter
         set plus the LIVE tokens' arena traffic (paged) or every slot's
         padded stripe (dense). This is the live-traffic figure the
@@ -2000,14 +2044,14 @@ class ContinuousBatcher:
         if spec_k is None:
             spec_k = self._spec_cur_k if self.spec_k else 0
         if self.paged:
-            # The kernel streams WHOLE blocks (the run guard skips
-            # compute, not the fetch), so round each slot's live prefix
-            # up to block granularity — otherwise the figure would be
-            # block-size-invariant and the block_size sweep meaningless.
-            bs = self.block_size
-            live = sum(-(-(st["pos"] + 1) // bs) * bs
-                       for st in self._slots.values())
-            live_bytes = live * self.cache.token_bytes()
+            # The kernel streams WHOLE blocks, so each slot's live
+            # prefix counts rounded up to block granularity — otherwise
+            # the figure would be block-size-invariant and the
+            # block_size sweep meaningless.
+            if live_blocks is None:
+                live_blocks = self._live_blocks()
+            live_bytes = (live_blocks * self.block_size
+                          * self.cache.token_bytes())
             # A routed model streams only the experts its rows touch: at
             # most rows x top-k of each layer's X (every slot routes,
             # live or not), over each position of a spec window.
@@ -2115,10 +2159,10 @@ class ContinuousBatcher:
         return self.allocator.alloc(n)
 
     def _table_row(self, blocks: List[int]) -> List[int]:
-        # Dead tail entries REPEAT the last live block: pallas skips the
-        # re-fetch when consecutive grid steps map to the same block, so
-        # a slot's unreached tail costs ~zero HBM traffic. (Entries past
-        # a slot's position are masked regardless.)
+        # Dead tail entries repeat the last live block. The attention
+        # kernel never visits them (its schedule ends at the slot's
+        # position: `paged_visits`); the XLA reference gathers and
+        # masks them, so they must name a block.
         tail = blocks[-1] if blocks else GARBAGE_BLOCK
         return blocks + [tail] * (self.max_blocks - len(blocks))
 
@@ -2693,23 +2737,18 @@ class ContinuousBatcher:
                         else:
                             nxt = np.asarray(nxt_dev)  # 4 bytes/slot
                 tick_wall = tick.ms / 1e3
-                # Paged ticks get the live-byte hint (the compiled cost
-                # prices every table entry as live); the dense program's
-                # own cost analysis is already accurate — including the
-                # kernel-off fp32 re-read traffic a hand estimate would
-                # miss — so dense keeps it. Spec ticks report against
-                # THEIR program (per-k instrumented jit) with the hint
-                # priced for k draft passes + the wider verify window.
+                # The dense program's own cost analysis is already
+                # accurate — including the kernel-off fp32 re-read
+                # traffic a hand estimate would miss — so only paged
+                # ticks get a hint. Spec ticks report against THEIR
+                # program (per-k instrumented jit) with the hint priced
+                # for k draft passes + the wider verify window.
                 tick_fn = (self._spec_ticks[self._last_tick_k]
                            if self._last_tick_k else self._tick)
                 with tracing.phase("engine.account",
                                    mdefs.CB_STEP_ACCOUNT_MS, self._mtags):
                     self._note_expert_rows([nxt])
-                    tick_fn.note_execution(
-                        tick_wall,
-                        bytes_hint=(self.tick_bytes_estimate(
-                            spec_k=self._last_tick_k)
-                                    if self.paged else None))
+                    self._account_tick(tick_fn, tick_wall, self._last_tick_k)
                 if self._apply_tokens(
                         [nxt], [(s, st["rid"])
                                 for s, st in self._slots.items()],
@@ -2819,10 +2858,9 @@ class ContinuousBatcher:
             now = time.perf_counter()
             if self._bw_window_t0 is not None and self._bw_window_ticks:
                 tick_fn = self._spec_ticks[wk] if wk else self._tick
-                tick_fn.note_execution(
-                    (now - self._bw_window_t0) / self._bw_window_ticks,
-                    bytes_hint=(self.tick_bytes_estimate(spec_k=wk)
-                                if self.paged else None))
+                self._account_tick(
+                    tick_fn,
+                    (now - self._bw_window_t0) / self._bw_window_ticks, wk)
             self._bw_window_t0 = now
             self._bw_window_ticks = 0
             self._note_expert_rows(rows)

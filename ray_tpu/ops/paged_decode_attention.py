@@ -15,11 +15,23 @@ tiles exactly for bf16 and int8 at any head count, where a trailing
 
 Two bandwidth levers stack:
 
-* **Paging** — grid ``(batch, table_blocks)``, one whole block (every
-  kv head) per step, with dead table entries repeating the last live
-  block: pallas skips the re-fetch when the mapped block index does not
-  change between sequential grid steps, so a slot's dead tail costs
-  ~zero HBM traffic (and ``pl.when`` skips its compute).
+* **Paging** — a 1-D grid over the VISITS a tick needs and no others:
+  one step per (slot, logical block) that holds a key the slot's query
+  may see, one whole block (every kv head) per step, slot-major so a
+  slot's running softmax state stays in VMEM between its steps. The
+  schedule (:func:`paged_visits`) rides scalar prefetch and its length
+  is the grid's bound, a traced scalar. A freed slot has no visit and
+  its output row stays zero; a dead table entry is never stepped over.
+  Blocks still arrive through BlockSpecs, which pipeline across slot
+  boundaries; a kernel that copies them itself out of ``pl.ANY``
+  operands cannot read an int8 arena, because Mosaic refuses any slice
+  of an HBM array whose minor axis is under 128 lanes, and the scale
+  sidecar's is ``block_size``.
+  A grid step is not free: on the v5e one that ``pl.when`` skips costs
+  0.11-0.2 us with no HBM traffic at all, a live one 0.35 us plus its
+  block's bytes, so a ``(batch, table_blocks)`` grid over 48 slots x 32
+  entries with 48 of them live spends five sixths of its time on
+  entries that hold nothing (224 us against 34, PERF.md section 6).
 * **int8 KV quantization** — the arena stores K/V as int8 with
   per-token/per-kv-head fp32 scales kept in block-shaped sidecars
   (``[num_blocks, KVH, block_size]``), gathered by the same table;
@@ -28,7 +40,7 @@ Two bandwidth levers stack:
 
 Same online-softmax core as the dense kernel: fp32 accumulation with a
 running max/sum in VMEM scratch; per-slot positions arrive via scalar
-prefetch and gate both block skip and the in-block causal mask.
+prefetch and set both the schedule and the in-block causal mask.
 
 The engine hands both kernels the WHOLE arena ``[L, NB, KVH, bs, D]``
 with the layer as one more scalar-prefetch operand, and writes the
@@ -114,63 +126,94 @@ def _layer_operand(layer):
     return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
-def _paged_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                  scale, block_size, num_blocks, quantized):
+def paged_visits(tables, positions, limits=None, *, block_size: int):
+    """The kernel's schedule: one VISIT per (slot, logical block) that
+    holds a key the slot's query may see, slot-major, and nothing else.
+    A slot's query sits at absolute position ``pos``, so its blocks
+    ``[0, pos // bs]`` are live (clamped to the table); a freed slot
+    (``limits`` 0; every slot is live without ``limits``) owns none.
+    Returns (slot of visit, logical block of visit, both ``[B * nb]``
+    and valid past the end, visits ``[1]``).
+
+    A dozen small XLA ops, which the compiler leaves inside a layer
+    loop although nothing in them depends on the layer: a caller with
+    such a loop makes the schedule once, before it, and hands it to
+    :func:`paged_decode_attention` as ``visits``."""
+    b, nb = tables.shape
+    positions = positions.astype(jnp.int32)
+    n_live = jnp.minimum(positions // block_size + 1, nb)
+    if limits is not None:
+        n_live = jnp.where(limits > 0, n_live, 0)
+    ends = jnp.cumsum(n_live)
+    v = jnp.arange(b * nb, dtype=jnp.int32)
+    # Compare-all, not a binary search: one fusion, no loop on device.
+    before = ends[None, :] <= v[:, None]                     # [V, B]
+    slot = jnp.minimum(jnp.sum(before, axis=1), b - 1)
+    start = jnp.sum(jnp.where(before, n_live[None, :], 0), axis=1)
+    block = jnp.clip(v - start, 0, nb - 1)
+    return (slot.astype(jnp.int32), block.astype(jnp.int32),
+            ends[-1:].astype(jnp.int32))
+
+
+def _paged_kernel(layer_ref, tables_ref, pos_ref, slot_ref, block_ref,
+                  q_ref, k_ref, v_ref, *rest, scale, block_size, num_blocks,
+                  quantized):
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        ks_ref, vs_ref, _, o_ref, acc_ref, m_ref, l_ref = rest
     else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    j = pl.program_id(1)
+        _, o_ref, acc_ref, m_ref, l_ref = rest
+    visit = pl.program_id(0)
+    pos = pos_ref[slot_ref[visit]]
+    j = block_ref[visit]
 
     @pl.when(j == 0)
     def _init():
         _init_state(acc_ref, m_ref, l_ref)
 
-    # The slot's query sits at absolute position `pos`; logical blocks
-    # wholly past it are dead (their table entries repeat the last live
-    # block, so the pipeline fetches nothing new for them either).
-    pos = pos_ref[pl.program_id(0)]
+    _attend_block(q_ref[0], k_ref[0, 0], v_ref[0, 0], pos, j * block_size,
+                  acc_ref, m_ref, l_ref, scale=scale,
+                  k_scale=ks_ref[0, 0] if quantized else None,
+                  v_scale=vs_ref[0, 0] if quantized else None)
 
-    @pl.when(j * block_size <= pos)
-    def _body():
-        _attend_block(q_ref[0], k_ref[0, 0], v_ref[0, 0], pos,
-                      j * block_size, acc_ref, m_ref, l_ref, scale=scale,
-                      k_scale=ks_ref[0, 0] if quantized else None,
-                      v_scale=vs_ref[0, 0] if quantized else None)
-
-    @pl.when(j == num_blocks - 1)
+    @pl.when(j == jnp.minimum(pos // block_size, num_blocks - 1))
     def _fin():
         _finalize(o_ref, acc_ref, l_ref)
 
 
-def _paged_fused(q, arena_k, arena_v, tables, positions, *, layer, k_scale,
-                 v_scale, scale, interpret):
+def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
+                 k_scale, v_scale, scale, interpret):
     b, hq, d = q.shape
     _, _, hkv, block_size, _ = arena_k.shape
     nb = tables.shape[1]
     group = hq // hkv
     quantized = k_scale is not None
+    slot_of, block_of, count = visits
 
     qg = q.reshape(b, hkv, group, d)
-    q_spec = pl.BlockSpec((1, hkv, group, d),
-                          lambda b_, j, ly, tab, po: (b_, 0, 0, 0))
-    # The table gather IS the index_map: the scalar-prefetched layer and
-    # block tables choose which arena block each grid step streams into
-    # VMEM.
+    q_spec = pl.BlockSpec(
+        (1, hkv, group, d),
+        lambda v, ly, tab, po, sl, bl: (sl[v], 0, 0, 0))
+    # The table gather IS the index_map: the scalar-prefetched layer,
+    # schedule and block tables choose which arena block each visit
+    # streams into VMEM.
     kv_spec = pl.BlockSpec(
         (1, 1, hkv, block_size, d),
-        lambda b_, j, ly, tab, po: (ly[0], tab[b_, j], 0, 0, 0))
+        lambda v, ly, tab, po, sl, bl: (ly[0], tab[sl[v], bl[v]], 0, 0, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     inputs = [qg, arena_k, arena_v]
     if quantized:
         sc_spec = pl.BlockSpec(
             (1, 1, hkv, block_size),
-            lambda b_, j, ly, tab, po: (ly[0], tab[b_, j], 0, 0))
+            lambda v, ly, tab, po, sl, bl: (ly[0], tab[sl[v], bl[v]], 0, 0))
         in_specs += [sc_spec, sc_spec]
         inputs += [k_scale, v_scale]
+    # The output starts as zeros and only visited slots are written, so
+    # a freed slot's row comes back zero at no grid step of its own.
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    inputs.append(jnp.zeros_like(qg))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, nb),
+        num_scalar_prefetch=5,
+        grid=(count[0],),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=_scratch(hkv, group, d),
@@ -186,6 +229,8 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, *, layer, k_scale,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
+        # Operand index counts the five scalar-prefetch arrays.
+        input_output_aliases={5 + len(inputs) - 1: 0},
         interpret=interpret,
         name="paged_decode_attn",
         cost_estimate=pl.CostEstimate(
@@ -197,7 +242,7 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, *, layer, k_scale,
             transcendentals=b * hq * nb * block_size,
         ),
     )(_layer_operand(layer), tables.astype(jnp.int32),
-      positions.astype(jnp.int32), *inputs)
+      positions.astype(jnp.int32), slot_of, block_of, *inputs)
     return out.reshape(b, hq, d)
 
 
@@ -299,6 +344,8 @@ def paged_decode_attention(
     scale: Optional[float] = None,
     *,
     layer=None,
+    limits: Optional[jnp.ndarray] = None,
+    visits=None,
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
     use_kernel: Optional[bool] = None,
@@ -310,8 +357,17 @@ def paged_decode_attention(
     ``layer`` (a traced int32 scalar), or one slab [NB, KVH, bs, D] with
     ``layer`` None; int8 when ``k_scale`` / ``v_scale`` (the arena's
     shape less D) are given; tables [B, nb] int32 block table (row j =
-    the slot's j-th logical block; dead tail entries should repeat the
-    last live block); positions [B].
+    the slot's j-th logical block; dead tail entries must name a block,
+    which the kernel never visits and the reference masks); positions
+    [B].
+
+    ``limits`` [B] int32: a slot whose limit is 0 is freed: the kernel
+    does not visit it and its row comes back zero (the reference attends
+    whatever its table names; nothing reads that row). Without it every
+    slot is live. ``visits``: the kernel's schedule, from
+    :func:`paged_visits` on the same tables, positions and limits, for
+    a caller that makes it once for many layers; ``limits`` is then not
+    read.
 
     ``use_kernel``: None = auto (fused kernel on TPU when the shapes
     tile, XLA reference elsewhere); True forces the kernel (interpret
@@ -341,6 +397,9 @@ def paged_decode_attention(
         arena_k, arena_v = arena_k[None], arena_v[None]
         if k_scale is not None:
             k_scale, v_scale = k_scale[None], v_scale[None]
-    return _paged_fused(q, arena_k, arena_v, tables, positions, layer=layer,
-                        k_scale=k_scale, v_scale=v_scale, scale=scale,
-                        interpret=interpret)
+    if visits is None:
+        visits = paged_visits(tables, positions, limits,
+                              block_size=block_size)
+    return _paged_fused(q, arena_k, arena_v, tables, positions, visits,
+                        layer=layer, k_scale=k_scale, v_scale=v_scale,
+                        scale=scale, interpret=interpret)

@@ -394,6 +394,32 @@ def test_paged_kernel_engine_parity(setup, pallas_interpret):
         assert toks == _reference(gen, prompt, m)
 
 
+def test_tick_books_the_share_of_table_entries_it_visits(setup):
+    """Every paged tick observes ``ray_tpu_cb_paged_live_block_share``:
+    live blocks over slots x table entries, what the attention kernel
+    visits. One request at positions 40..44 of 32-token blocks holds 2
+    of the 4 x 4 entries in every tick; the three free slots hold none."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    config, gen, _ = setup
+    eng = ContinuousBatcher(config, params=gen.params, num_slots=4,
+                            max_len=128, paged=True, block_size=32)
+
+    def read():
+        """(sum, count) over every engine's label set."""
+        samples = mdefs.CB_PAGED_LIVE_BLOCK_SHARE.samples()
+        return tuple(sum(v for name, _, v in samples if name.endswith(end))
+                     for end in ("_sum", "_count"))
+
+    sum0, count0 = read()
+    eng.submit(list(range(1, 41)), max_new_tokens=5)
+    eng.run_to_completion()
+    ticks = eng.base_tick_count
+    total, count = read()
+    assert ticks >= 4 and count - count0 == ticks
+    assert total - sum0 == pytest.approx(ticks * 2 / 16)
+
+
 def test_live_rows_never_share_a_write_block(setup, pallas_interpret):
     """``paged_kv_write`` merges each row into the block as fetched, so
     two rows of one tick may name the same block only if nothing reads

@@ -18,7 +18,7 @@ from ray_tpu.ops.decode_attention import decode_attention_reference
 from ray_tpu.ops.paged_decode_attention import (paged_applicable,
                                                 paged_attention_reference,
                                                 paged_decode_attention,
-                                                paged_kv_write)
+                                                paged_kv_write, paged_visits)
 
 
 def _paged_inputs(b=3, hq=4, hkv=2, d=16, bs=32, nb_slot=4, seed=0,
@@ -155,6 +155,118 @@ def test_paged_int8_attention_close_to_fp32(pallas_interpret, use_kernel):
                                    v_scale=vs, use_kernel=not use_kernel)
     np.testing.assert_allclose(np.asarray(out), np.asarray(other),
                                atol=2e-6)
+
+
+# ------------------------------------ live blocks only (the visit schedule)
+
+def _walk_case(hq, hkv, kv_dtype, layered, b=5, seed=11):
+    """Kernel arguments for one arena form: (q, args, kwargs). bf16 or
+    int8 storage; a slab, or the middle layer of a whole arena whose
+    other layers hold other bytes."""
+    q, _, _, ak, av, tables, _ = _paged_inputs(
+        b=b, hq=hq, hkv=hkv, seed=seed, dtype=jnp.bfloat16)
+    kw = {}
+    if kv_dtype == "int8":
+        ak, kw["k_scale"] = quantize_kv(ak)
+        av, kw["v_scale"] = quantize_kv(av)
+    if layered:
+        ak, av = jnp.stack([av, ak, av]), jnp.stack([ak, av, ak])
+        kw = {n: jnp.stack([a * 2, a, a * 3]) for n, a in kw.items()}
+        kw["layer"] = jnp.int32(1)
+    return q, (ak, av, tables), kw
+
+
+# The served models' head layouts: Mistral's GQA 32/8, OLMoE's MHA 16/16.
+@pytest.mark.parametrize("hq,hkv", [(32, 8), (16, 16)])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("layered", [False, True])
+def test_live_block_visits_equal_every_entry_walk(pallas_interpret, hq, hkv,
+                                                  kv_dtype, layered):
+    """The kernel visits only the blocks a query may see; the walk it
+    replaced (kept in ``chip_smoke.py``) stepped over every table entry
+    and skipped the dead ones. Same blocks, same order, same arithmetic:
+    the same bits, and both within tolerance of the XLA reference.
+    Positions: a block's last row, the next block's first, the table's
+    last row, one past the table (clamped to it), and 0."""
+    from chip_smoke import walk_every_entry
+
+    q, args, kw = _walk_case(hq, hkv, kv_dtype, layered)
+    bs, nb = 32, 4
+    pos = jnp.asarray([bs - 1, bs, nb * bs - 1, nb * bs + 40, 0], jnp.int32)
+    out = paged_decode_attention(q, *args, pos, use_kernel=True, **kw)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(walk_every_entry(q, *args, pos, **kw)))
+    ref = paged_attention_reference(q, *args, pos, **kw)
+    np.testing.assert_allclose(np.asarray(out, jnp.float32),
+                               np.asarray(ref, jnp.float32), atol=3e-2)
+
+
+@pytest.mark.parametrize("case", ["all_freed", "all_full", "interleaved",
+                                  "omitted"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_limits_decide_which_slots_are_visited(pallas_interpret, kv_dtype,
+                                               case):
+    """``limits`` 0 marks a freed slot: never visited, its row exactly
+    zero, whatever the garbage block holds (NaN here: nothing may read
+    it). Every live row is the walk's, bit for bit; without ``limits``
+    every slot is live."""
+    from chip_smoke import walk_every_entry
+
+    q, (ak, av, tables), kw = _walk_case(32, 8, kv_dtype, layered=False)
+    if kv_dtype == "bf16":
+        ak, av = (a.at[GARBAGE_BLOCK].set(jnp.nan) for a in (ak, av))
+    else:
+        kw = {n: a.at[GARBAGE_BLOCK].set(jnp.nan) for n, a in kw.items()}
+    b, s_max = q.shape[0], 128
+    pos = jnp.asarray([5, 40, s_max - 1, 64, 100], jnp.int32)
+    live = {"all_freed": np.zeros(b, bool), "all_full": np.ones(b, bool),
+            "interleaved": np.arange(b) % 2 == 0,
+            "omitted": np.ones(b, bool)}[case]
+    if case == "all_full":
+        pos = jnp.full(b, s_max - 1, jnp.int32)
+    # A freed slot as the engine leaves it: every table entry the
+    # garbage block, position 0.
+    tables = jnp.where(live[:, None], tables, GARBAGE_BLOCK)
+    pos = jnp.where(live, pos, 0)
+    limits = None if case == "omitted" else jnp.asarray(live * s_max,
+                                                        jnp.int32)
+    out = np.asarray(paged_decode_attention(
+        q, ak, av, tables, pos, limits=limits, use_kernel=True, **kw),
+        np.float32)
+    want = np.asarray(walk_every_entry(q, ak, av, tables, pos, **kw),
+                      np.float32)
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out[live], want[live])
+    np.testing.assert_array_equal(out[~live], 0.0)
+    if case == "omitted":
+        np.testing.assert_array_equal(out, np.asarray(paged_decode_attention(
+            q, ak, av, tables, pos, limits=jnp.full(b, s_max, jnp.int32),
+            use_kernel=True, **kw), np.float32))
+
+
+def test_visit_schedule_lists_live_blocks_slot_major(pallas_interpret):
+    """``paged_visits``: slot i contributes blocks 0..pos // bs (clamped
+    to the table) and a freed slot none; a schedule made ahead of the
+    call, as the engine makes it before its layer loop, gives the same
+    bits as one made inside it."""
+    q, (ak, av, tables), _ = _walk_case(4, 2, "bf16", layered=False)
+    pos = jnp.asarray([31, 32, 500, 7, 127], jnp.int32)
+    limits = jnp.asarray([128, 128, 128, 0, 128], jnp.int32)
+    slot, block, count = paged_visits(tables, pos, limits, block_size=32)
+    want = [(0, 0), (1, 0), (1, 1), (2, 0), (2, 1), (2, 2), (2, 3),
+            (4, 0), (4, 1), (4, 2), (4, 3)]
+    assert int(count[0]) == len(want)
+    assert list(zip(np.asarray(slot)[:len(want)].tolist(),
+                    np.asarray(block)[:len(want)].tolist())) == want
+    # Past the end the lists still name a slot and a block of the table.
+    assert slot.shape == block.shape == (5 * 4,)
+    assert (np.asarray(slot) < 5).all() and (np.asarray(block) < 4).all()
+    np.testing.assert_array_equal(
+        np.asarray(paged_decode_attention(
+            q, ak, av, tables, pos, visits=(slot, block, count),
+            use_kernel=True)),
+        np.asarray(paged_decode_attention(
+            q, ak, av, tables, pos, limits=limits, use_kernel=True)))
 
 
 # ------------------------------------- whole arena: layer index, in-place write

@@ -72,9 +72,12 @@ def test_dense_decode_lowers(hq, hkv):
 @pytest.mark.parametrize("hq,hkv", HEADS)
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 @pytest.mark.parametrize("layers", [None, 16])
-def test_paged_decode_lowers(hq, hkv, kv_dtype, layers):
+@pytest.mark.parametrize("limited", [False, True])
+def test_paged_decode_lowers(hq, hkv, kv_dtype, layers, limited):
     """32 slots, block 64, 8-block tables over a 257-block arena: one
-    slab, and the whole 16-layer arena read at a traced layer."""
+    slab, and the whole 16-layer arena read at a traced layer; every
+    slot live, and freed slots told apart by ``limits``. The grid's
+    bound is the traced count of live blocks either way."""
     q = S((32, hq, 128), BF16)
     tables, positions = S((32, 8), jnp.int32), S((32,), jnp.int32)
     lead = () if layers is None else (layers,)
@@ -83,13 +86,14 @@ def test_paged_decode_lowers(hq, hkv, kv_dtype, layers):
     scales = [S(lead + (257, hkv, 64), jnp.float32)] * 2 \
         if kv_dtype == "int8" else [None, None]
 
-    def fn(q, k, v, t, p, ks, vs, li):
+    def fn(q, k, v, t, p, ks, vs, li, lim):
         return paged_decode_attention(q, k, v, t, p, k_scale=ks, v_scale=vs,
-                                      layer=li, use_kernel=True)
+                                      layer=li, limits=lim, use_kernel=True)
 
     li = None if layers is None else S((), jnp.int32)
+    lim = S((32,), jnp.int32) if limited else None
     assert _mosaic_calls(fn, q, arena, arena, tables, positions, *scales,
-                         li) == 1
+                         li, lim) == 1
 
 
 @pytest.mark.parametrize("hkv", [16, 8])
@@ -212,9 +216,21 @@ def test_compiled_paged_tick_moves_no_arena_slab(v5e_chip, kv_dtype):
     writes = 4 if kv_dtype == "int8" else 2
     assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == writes
     assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 1
-    slab = _TICK_BLOCKS * 8 * _TICK_BS * 128 * (
-        1 if kv_dtype == "int8" else 2)
-    assert compiled.memory_analysis().temp_size_in_bytes < slab
+    # Scratch: the kernel's schedule, made once before the layer loop
+    # (48 x 32 visits against 48 slot ends), and the zeroed output it
+    # fills in, 0.7 MB together since PR 26; an int8 arena adds the
+    # relayout of its scale sidecars. Not one block-row of a slab more
+    # (0 and 1,408,512 bytes at PR 25, whose kernel had no schedule).
+    scratch = compiled.memory_analysis().temp_size_in_bytes
+    assert scratch <= (1 << 20) + (1_408_512 if kv_dtype == "int8" else 0)
+    # The schedule reaches the layer body as loop state: XLA does not
+    # hoist it, so nothing there may compute a visit list.
+    body = next(c for c in hlo.split("\n\n")
+                if re.search(r"%paged_decode_attn[.\d]* = ", c))
+    visits = _TICK_SLOTS * (_TICK_LEN // _TICK_BS)
+    assert [line.strip() for line in body.splitlines()
+            if f"= s32[{visits}]" in line
+            and " get-tuple-element(" not in line] == []
 
 
 # --------------------------------------------- the routed block (OLMoE)
