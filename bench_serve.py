@@ -51,7 +51,6 @@ import sys
 import time
 
 import jax
-import jax.numpy as jnp
 
 HBM_GBPS = {
     "TPU v5 lite": 819e9,   # v5e
@@ -136,7 +135,7 @@ def _prefix_phase(config, params, num_slots, max_len, sync_every,
     for on in (True, False):
         eng = ContinuousBatcher(config, params=params,
                                 num_slots=num_slots, max_len=max_len,
-                                sync_every=sync_every, paged=True,
+                                sync_every=sync_every,
                                 block_size=block_size, prefix_cache=on)
         # Warm-up = the steady state of a serving replica: the system
         # prompt is resident AND both prefill program shapes (cold full
@@ -265,8 +264,7 @@ def _spec_phase(config, params, num_slots, max_len, prompt_len, ticks,
         del eng  # release the previous point's arena first
         eng = ContinuousBatcher(config, params=pp,
                                 num_slots=num_slots, max_len=max_len,
-                                sync_every=1, paged=True,
-                                spec_k=k, spec_draft_layers=dl,
+                                sync_every=1, spec_k=k, spec_draft_layers=dl,
                                 spec_adaptive=False)
         tps, med, _ = _measure_decode(eng, num_slots, max_len,
                                       prompt_len, ticks)
@@ -369,8 +367,7 @@ def _disagg_phase(config, params, num_slots, max_len, block_size,
 
     colo = ContinuousBatcher(config, params=params, role="both",
                              num_slots=num_slots, max_len=max_len,
-                             sync_every=1, paged=True,
-                             block_size=block_size,
+                             sync_every=1, block_size=block_size,
                              token_callback=on_token)
     def _run_colo(reqs):
         t0 = time.perf_counter()
@@ -400,8 +397,7 @@ def _disagg_phase(config, params, num_slots, max_len, block_size,
     # on a free decode slot (production pre-reserves; the bench polls).
     pre = ContinuousBatcher(config, params=params, role="prefill",
                             num_slots=num_slots, max_len=max_len,
-                            sync_every=1, paged=True,
-                            block_size=block_size)
+                            sync_every=1, block_size=block_size)
     # Role-specific sizing is one of disaggregation's levers: a decode
     # slot costs arena blocks, not prefill compute, so a decode-role
     # engine runs more concurrent generations than a colocated engine
@@ -409,8 +405,7 @@ def _disagg_phase(config, params, num_slots, max_len, block_size,
     decode_slots = 2 * num_slots
     dec = ContinuousBatcher(config, params=params, role="decode",
                             num_slots=decode_slots, max_len=max_len,
-                            sync_every=1, paged=True,
-                            block_size=block_size)
+                            sync_every=1, block_size=block_size)
     submit_ts.clear()
     split_ttft = []
     handoff_walls = []
@@ -611,16 +606,11 @@ def main() -> None:
     cost_bytes, tick_flops = _tick_cost_stats()
 
     # Roofline: params + average live KV prefix, read once per tick,
-    # priced at the engine's OWN storage bytes-per-token (paged arena or
-    # dense bf16). The 10%-of-bf16-dense criterion stays fixed across
-    # configs so vs_baseline remains comparable round over round.
+    # priced at the arena's OWN storage bytes-per-token. The
+    # 10%-of-bf16 criterion stays fixed across configs so vs_baseline
+    # remains comparable round over round.
     avg_pos = (prompt_len + max_len) / 2
-    if eng.paged:
-        per_token = eng.cache.token_bytes()
-    else:
-        per_token = (2 * config.num_layers * config.num_kv_heads
-                     * config.head_dim
-                     * jnp.dtype(config.dtype).itemsize)
+    per_token = eng.cache.token_bytes()
     kv_bytes = num_slots * avg_pos * per_token
     bf16_per_token = (2 * config.num_layers * config.num_kv_heads
                       * config.head_dim * 2)
@@ -638,7 +628,7 @@ def main() -> None:
         del s_eng  # release the previous config's arena before allocating
         s_eng = ContinuousBatcher(config, num_slots=num_slots,
                                   max_len=max_len, sync_every=sync_every,
-                                  paged=True, block_size=bs,
+                                  block_size=bs,
                                   kv_dtype=kv_dtype, params=eng.params)
         tps, _, lb = _measure_decode(s_eng, num_slots, max_len,
                                      prompt_len, sweep_ticks)
@@ -676,8 +666,7 @@ def main() -> None:
         "bytes_read_per_tick_live": int(live_bytes),
         "tick_flops": tick_flops,
         "decode_kernel": eng.use_decode_kernel,
-        "paged": eng.paged,
-        "block_size": eng.block_size if eng.paged else None,
+        "block_size": eng.block_size,
         "kv_dtype": eng.kv_dtype,
         "sweep": sweep,
         "num_slots": num_slots,

@@ -9,7 +9,7 @@ the full width of the 953M Llama-shaped config both benches use (hidden
 2048, 16 layers, 16 heads of 128, vocab 32,000; random weights from a
 seed), and checks what comes out by the repo's own means:
 
-* ``kernels``   flash attention forward/backward, dense and paged decode
+* ``kernels``   flash attention forward/backward and paged decode
                 attention (bf16 and int8 arena), COMPILED, against their
                 references within the tolerances in ``TOLERANCE``; the
                 routed block's grouped matmul (``moe_gmm``) against a
@@ -255,10 +255,10 @@ def walk_every_entry(q, arena_k, arena_v, tables, positions, *, layer=None,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    from ray_tpu.ops.decode_attention import (_attend_block, _finalize,
-                                              _init_state,
-                                              _interpret_default, _scratch,
-                                              pltpu)
+    from ray_tpu.ops.dispatch import interpret_default
+    from ray_tpu.ops.paged_decode_attention import (_attend_block, _finalize,
+                                                    _init_state, _scratch,
+                                                    pltpu)
 
     if layer is None:
         layer = 0
@@ -308,7 +308,7 @@ def walk_every_entry(q, arena_k, arena_v, tables, positions, *, layer=None,
             in_specs=[q_spec, kv_spec, kv_spec] + [sc_spec] * len(scales),
             out_specs=q_spec, scratch_shapes=_scratch(hkv, group, d)),
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        interpret=_interpret_default(), name="paged_walk_every_entry",
+        interpret=interpret_default(), name="paged_walk_every_entry",
     )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
       positions.astype(jnp.int32), q.reshape(b, hkv, group, d), arena_k,
       arena_v, *scales)
@@ -424,16 +424,15 @@ def phase_kernels(rehearse: bool) -> None:
     from ray_tpu.models.paged_kv import quantize_kv
     from ray_tpu.ops.attention import (flash_applicable, flash_attention,
                                        mha_reference)
-    from ray_tpu.ops.decode_attention import (_interpret_default,
-                                              decode_attention,
-                                              decode_attention_reference)
+    from ray_tpu.ops.dispatch import interpret_default
     from ray_tpu.ops.paged_decode_attention import (
-        paged_attention_reference, paged_decode_attention, paged_kv_write)
+        decode_attention_reference, paged_attention_reference,
+        paged_decode_attention, paged_kv_write)
 
     if not rehearse:
         assert "RAY_TPU_PALLAS_INTERPRET" not in os.environ, \
             "RAY_TPU_PALLAS_INTERPRET is set: kernels would not compile"
-        assert _interpret_default() is False
+        assert interpret_default() is False
     bf16 = jnp.bfloat16
     keys = jax.random.split(jax.random.PRNGKey(0), 12)
 
@@ -469,7 +468,7 @@ def phase_kernels(rehearse: bool) -> None:
         _check(phase, f"flash backward {name}", g, rg,
                TOLERANCE["flash_bwd"])
 
-    # -- decode attention: dense, paged bf16, paged int8 -------------------
+    # -- decode attention: paged bf16, paged int8 --------------------------
     # MHA at OLMoE's serving cell (16 KV heads, a query group of ONE, 48
     # slots over 16 blocks of 64), then GQA.
     shapes = [(4, 4, 2, 32, 4)] if rehearse else \
@@ -497,14 +496,12 @@ def phase_kernels(rehearse: bool) -> None:
         kq, ks = quantize_kv(ak)
         vq, vs = quantize_kv(av)
 
-        dense = jax.jit(lambda *a: decode_attention(*a, use_kernel=True))
         paged = jax.jit(
             lambda *a: paged_decode_attention(*a, use_kernel=True))
         paged8 = jax.jit(lambda q, k, v, t, p, ks, vs: paged_decode_attention(
             q, k, v, t, p, k_scale=ks, v_scale=vs, use_kernel=True))
         if not rehearse:
-            for fn, args in ((dense, (qd, ck, cv, positions)),
-                             (paged, (qd, ak, av, tables, positions)),
+            for fn, args in ((paged, (qd, ak, av, tables, positions)),
                              (paged8, (qd, kq, vq, tables, positions,
                                        ks, vs))):
                 assert _mosaic_calls(fn.lower(*args).compile()) == 1
@@ -516,8 +513,8 @@ def phase_kernels(rehearse: bool) -> None:
                 qd, kq, vq, tables, positions, ks, vs)
             pref = jax.jit(paged_attention_reference)(
                 qd, ak, av, tables, positions)
-        _check(phase, f"dense decode ({tag})",
-               dense(qd, ck, cv, positions), ref, TOLERANCE["decode_bf16"])
+        _check(phase, f"paged reference against the dense context's ({tag})",
+               pref, ref, TOLERANCE["decode_bf16"])
         _check(phase, f"paged decode bf16 ({tag})",
                paged(qd, ak, av, tables, positions), pref,
                TOLERANCE["decode_bf16"])
@@ -948,9 +945,7 @@ def phase_serve(rehearse: bool) -> None:
         assert after["compiles"] == warm["compiles"] == 1, (warm, after)
         eng = _engine_info(replica)
         _say(phase, f"engine: use_decode_kernel={eng['use_decode_kernel']},"
-                    f" paged={eng['paged']}, kv_dtype={eng['kv_dtype']}, "
-                    f"device {eng['device']}")
-        assert eng["paged"] is True
+                    f" kv_dtype={eng['kv_dtype']}, device {eng['device']}")
         if not rehearse:
             assert eng["use_decode_kernel"] is True
             assert eng["device"]["platform"] == "tpu"

@@ -88,11 +88,17 @@ class ContinuousLlamaDeployment:
     join mid-flight and stream tokens as decode ticks produce them. Use
     with handle ``stream=True`` (or plain calls for full completions)."""
 
+    # Constructor options that are gone, and what a serve config whose
+    # ``init_kwargs`` still sets one is told when it deploys.
+    removed_init_kwargs = {
+        "paged": "paged= was removed in PR 27: the paged arena is the "
+                 "only KV plane, drop the key"}
+
     def __init__(self, config: Optional[llama.LlamaConfig] = None,
                  params=None, num_slots: int = 8, max_len: int = 512,
                  eos_token: Optional[int] = None, sync_every: int = 1,
                  use_decode_kernel: Optional[bool] = None,
-                 paged: Optional[bool] = None, block_size: int = 64,
+                 block_size: int = 64,
                  kv_dtype: Optional[str] = None,
                  num_blocks: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
@@ -103,7 +109,7 @@ class ContinuousLlamaDeployment:
                  checkpoint_path: Optional[str] = None,
                  role: Optional[str] = None):
         """Engine knobs (``num_slots``, ``max_len``, ``sync_every``,
-        ``use_decode_kernel``, and the paged-KV plane's ``paged`` /
+        ``use_decode_kernel``, and the paged-KV plane's
         ``block_size`` / ``kv_dtype`` / ``num_blocks`` / ``sampling``)
         pass straight to the ContinuousBatcher and are overridable
         per-deploy via the serve config ``init_kwargs`` (see
@@ -154,7 +160,7 @@ class ContinuousLlamaDeployment:
             self.config, params=params, num_slots=num_slots,
             max_len=max_len, eos_token=eos_token,
             token_callback=self._on_token, sync_every=sync_every,
-            use_decode_kernel=use_decode_kernel, paged=paged,
+            use_decode_kernel=use_decode_kernel,
             block_size=block_size, kv_dtype=kv_dtype,
             num_blocks=num_blocks, prefix_cache=prefix_cache,
             sampling=sampling, spec_k=spec_k,
@@ -266,7 +272,7 @@ class ContinuousLlamaDeployment:
         eng = self.batcher
         dev = self.device or jax.devices()[0]
         return {"use_decode_kernel": eng.use_decode_kernel,
-                "paged": eng.paged, "kv_dtype": eng.kv_dtype,
+                "kv_dtype": eng.kv_dtype,
                 "device": {"id": dev.id, "platform": dev.platform,
                            "kind": dev.device_kind},
                 "params_device_ids": sorted({
@@ -637,7 +643,6 @@ def build_continuous_llama_app(config: Optional[llama.LlamaConfig] = None,
                                num_replicas: int = 1, num_slots: int = 8,
                                max_len: int = 512, sync_every: int = 1,
                                use_decode_kernel: Optional[bool] = None,
-                               paged: Optional[bool] = None,
                                block_size: int = 64,
                                kv_dtype: Optional[str] = None,
                                num_blocks: Optional[int] = None,
@@ -653,7 +658,7 @@ def build_continuous_llama_app(config: Optional[llama.LlamaConfig] = None,
     # files) can retarget any engine knob without positional conflicts.
     return dep.bind(config=config, num_slots=num_slots, max_len=max_len,
                     sync_every=sync_every,
-                    use_decode_kernel=use_decode_kernel, paged=paged,
+                    use_decode_kernel=use_decode_kernel,
                     block_size=block_size, kv_dtype=kv_dtype,
                     num_blocks=num_blocks, prefix_cache=prefix_cache,
                     sampling=sampling, spec_k=spec_k,
@@ -669,11 +674,9 @@ def build_disagg_llama_apps(name: str = "llm",
     """(prefill_app, decode_app) Application pair for disaggregated
     serving, named ``<name>-prefill`` / ``<name>-decode``: the same
     engine knobs on both sides (geometry MUST match — the import
-    rejects mismatched block_size/kv_dtype/model dims), the paged-KV
-    plane forced on (roles require an arena to hand off). Deploy both
+    rejects mismatched block_size/kv_dtype/model dims). Deploy both
     and declare the role group, or use :func:`deploy_disagg_llama`
     which does all three."""
-    engine_kwargs.setdefault("paged", True)
     chip = _chip_per_replica()
     prefill = ContinuousLlamaDeployment.options(
         name=f"{name}-prefill", num_replicas=num_prefill,

@@ -16,17 +16,17 @@ length masking because a slot's garbage cache entries live only at
 positions strictly greater than its next decode position — every decode
 overwrites position ``p`` before attending ``[0..p]``.
 
-The KV data plane is PAGED by default (``models/paged_kv.py``): slots
-share one block arena through per-slot block tables, so a tick's
-attention streams only the blocks a slot actually filled — no
-``S_max`` padding traffic — with optional int8 arena storage halving
-bytes-per-token again. ``paged=False`` keeps the dense pooled cache
-(one private ``[S_max]`` stripe per slot). Sampling (temperature/top-p)
-runs in-device inside the tick jit either way; only token ids cross to
-the host.
+The KV data plane is PAGED (``models/paged_kv.py``): slots share one
+block arena through per-slot block tables, so a tick's attention
+streams only the blocks a slot actually filled — no ``S_max`` padding
+traffic — with optional int8 arena storage halving bytes-per-token
+again. Sampling (temperature/top-p) runs in-device inside the tick jit;
+only token ids cross to the host. One forward over the arena
+(:func:`_forward_paged`) is the decode tick (a window of 1), the
+speculative verify pass (k+1) and the self-draft (1, the first layers).
 
-CROSS-REQUEST PREFIX CACHING (default on for paged engines,
-``prefix_cache`` / ``RAY_TPU_PREFIX_CACHE``): admission matches each
+CROSS-REQUEST PREFIX CACHING (default on, ``prefix_cache`` /
+``RAY_TPU_PREFIX_CACHE``): admission matches each
 prompt's longest block-aligned prefix against a radix index of blocks
 already resident in the arena (``paged_kv.RadixBlockIndex``), splices
 the matched blocks into the slot's table READ-ONLY (decode writes start
@@ -68,31 +68,17 @@ from ray_tpu.models.paged_kv import (GARBAGE_BLOCK, BlockAllocator,
 from ray_tpu.models.sampling import (SPEC_DRAFT_SALT, SamplingParams,
                                      filtered_probs, sample_tokens,
                                      spec_commit, step_key)
-from ray_tpu.ops.decode_attention import (decode_applicable,
-                                          decode_attention,
-                                          decode_attention_reference,
-                                          env_flag)
-from ray_tpu.ops.paged_decode_attention import (paged_applicable,
+from ray_tpu.ops.dispatch import env_flag
+from ray_tpu.ops.paged_decode_attention import (decode_attention_reference,
+                                                paged_applicable,
                                                 paged_decode_attention,
-                                                paged_visits,
-                                                paged_kv_write)
+                                                paged_kv_write,
+                                                paged_visits)
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.util import tracing
 
 # Children of a ``tracing.phase``: names in the profiler's trace only.
 _annotation = jax.profiler.TraceAnnotation
-
-
-def _apply_rope_batched(x, cos, sin):
-    """RoPE with per-batch angles: x [B, 1, H, D], cos/sin [B, D//2]."""
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
-    c = cos[:, None, None, :]
-    s = sin[:, None, None, :]
-    return jnp.concatenate(
-        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(dtype)
 
 
 def _scatter_slot(cache, new, positions):
@@ -127,10 +113,10 @@ def _window_visits(tables, positions, limits, block_size: int,
 
 def _write_then_attend(arenas, li, q, k_new, v_new, block_idx, offset,
                        tables, positions, visits, scale, use_kernel: bool):
-    """The layer body the paged tick, the self-draft and verify share:
-    write each slot's S new tokens' K/V into layer ``li`` (S = 1 for a
-    tick, k+1 for verify), then attend every window position over the
-    slot's blocks. ``arenas`` = (k, v, k_scale, v_scale), each the WHOLE
+    """The attention half of :func:`_forward_paged`'s layer: write each
+    slot's S new tokens' K/V into layer ``li`` (S = 1 for a tick, k+1
+    for verify), then attend every window position over the slot's
+    blocks. ``arenas`` = (k, v, k_scale, v_scale), each the WHOLE
     ``[L, NB, KVH, bs, ...]`` array as the layer scan carries it (scales
     None for a bf16 arena); q/k_new/v_new [B, S, Hq|KVH, D];
     block_idx/offset/positions [B, S]; ``visits`` from
@@ -140,11 +126,12 @@ def _write_then_attend(arenas, li, q, k_new, v_new, block_idx, offset,
 
     With the kernels the write is a Mosaic call aliased onto the carry
     and the read takes the layer as a scalar, so no slab ever exists.
-    Without them (shapes that do not tile; the CPU reference) the slab
-    is sliced out, scattered by XLA and put back. On the TPU any XLA
-    write into the heads-major arena makes the compiler relayout the
-    slab for the scatter and again for the reader: that is what this
-    path costs there, and why the kernel path never takes it."""
+    Without them (shapes that do not tile; every CPU run that does not
+    ask for the interpreted kernels) the slab is sliced out, scattered
+    by XLA and put back. On the TPU any XLA write into the heads-major
+    arena makes the compiler relayout the slab for the scatter and again
+    for the reader: that is what this path costs there, and why the
+    kernel path never takes it."""
     def each(fn, *columns):
         """``fn`` over (k, v, k_scale, v_scale); an absent scale stays
         None."""
@@ -204,12 +191,6 @@ def _ctx_to_blocks(a, bs: int):
     return a.reshape(lyr, n * (s // bs), hkv, bs, *a.shape[5:])
 
 
-# The XLA reference single-query attention lives next to the fused
-# kernel (ops/decode_attention.py); keep the old name importable — it is
-# the parity baseline the kernel tests compare against.
-_attend_decode = decode_attention_reference
-
-
 def _next_tokens(logits, step, sampling: SamplingParams, salt: int = 0):
     """In-device token selection from tick/prefill logits [B, 1, V]:
     greedy argmax, or temperature/top-p sampling keyed off the
@@ -229,120 +210,110 @@ _PREFILL_SALT = 1  # prefill sampling stream, distinct from decode's
 
 
 def _layer_qkv(x, layer, cos, sin, c):
-    """Shared per-layer projections for the dense and paged ticks:
-    attn-norm, Q/K/V einsums, RoPE on Q and K (V unrotated). Any
-    numerics change here reaches both data planes at once — the
-    paged-on/off bit-parity contract depends on that."""
+    """Every engine layer's first half: attn-norm, Q/K/V projections,
+    RoPE on Q and K (V unrotated). x [B, S, E]; cos/sin [S, D//2] where
+    the rows share their positions (prefill) or [B, S, D//2] per (slot,
+    position). S is 1 for a tick and k+1 for a verify window: the window
+    rides the batch dims and the E-axis accumulation is untouched, so
+    position j of a window gets the bits S = 1 gives it, which the
+    spec-on/off parity tests pin down."""
     h = rms_norm(x, layer["attn_norm"], c.rms_eps)
     q, k, v = llama.project_qkv(h, layer, c)
-    return (_apply_rope_batched(q, cos, sin),
-            _apply_rope_batched(k, cos, sin), v)
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
-def _mlp_residual(x, layer, c, experts, li, use_kernel):
-    """``x + MLP(norm(x))`` through the family's one MLP function
-    (:func:`llama.mlp_block`: dense SwiGLU, or the routed block reading
-    the stacked ``experts`` at layer ``li``). Returns (x, rows): the
-    routed block's per-expert assignment counts, None for a dense
-    model. Every engine program's layer ends here."""
+def _layer_finish(x, o, layer, c, experts=None, li=None, use_kernel=None):
+    """Every engine layer's second half: the attention output projection
+    of o [B, S, H, D], then ``x + MLP(norm(x))`` through the family's
+    one MLP function (:func:`llama.mlp_block`: dense SwiGLU, or the
+    routed block reading the stacked ``experts`` at layer ``li``).
+    Returns (x, rows): the routed block's per-expert assignment counts,
+    None for a dense model."""
+    x = x + jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
     h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
     down, routed = llama.mlp_block(h, layer, c, experts, li,
                                    use_kernel=use_kernel)
     return x + down, None if routed is None else routed.rows
 
 
-def _layer_finish(x, o, layer, c, experts=None, li=None, use_kernel=None):
-    """Shared per-layer tail: attention output projection + the MLP."""
-    x = x + jnp.einsum("bhd,hde->be", o,
-                       layer["wo"].astype(c.dtype))[:, None, :]
-    return _mlp_residual(x, layer, c, experts, li, use_kernel)
+def _forward_paged(params, tokens, positions, tables, limits,
+                   cache: PagedKVCache, config: llama.LlamaConfig,
+                   use_kernel: bool, n_layers: Optional[int] = None):
+    """The engine's ONE forward over the paged arena: each slot's window
+    of S tokens [B, S] at per-slot absolute ``positions`` [B, S]
+    (= p .. p+S-1), reading and writing the arena through the slot's
+    block table. The decode tick is S = 1, the speculative verify pass
+    S = k+1, and the self-draft S = 1 through the FIRST ``n_layers``
+    layers only: the truncated stack computes bitwise the target's
+    layer-[0:n) K/V, so its context is already resident and its writes
+    are the bytes verify rewrites identically.
 
+    ``tables`` [B, max_blocks] int32 (dead tail entries repeat the last
+    live block; freed slots point wholesale at the garbage block);
+    ``limits`` [B] is each slot's table-covered token count
+    (reserved_blocks * bs), 0 for a freed slot, which the attention
+    kernel then never visits.
 
-def _apply_rope_window(x, cos, sin):
-    """RoPE with per-(slot, position) angles: x [B, S, H, D], cos/sin
-    [B, S, D//2] — the k+1-token verify-window analog of
-    :func:`_apply_rope_batched` (same elementwise math, broadcast over
-    heads instead of over a singleton window)."""
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
-    c = cos[:, :, None, :]
-    s = sin[:, :, None, :]
-    return jnp.concatenate(
-        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(dtype)
+    Projections and the MLP run batched over the window — a verify pass
+    streams the parameters ONCE for all k+1 positions, which is the
+    speculative roofline lever — while attention runs per window
+    position (:func:`_write_then_attend`).
 
-
-def _layer_qkv_window(x, layer, cos, sin, c):
-    """:func:`_layer_qkv` over a k+1-token verify window: x [B, S, E],
-    per-(slot, position) RoPE angles [B, S, D//2]. The projections are
-    the same contractions as the s=1 tick — the window rides the batch
-    dims, the E-axis accumulation is untouched — which the spec-on/off
-    bit-parity tests pin down."""
-    h = rms_norm(x, layer["attn_norm"], c.rms_eps)
-    q, k, v = llama.project_qkv(h, layer, c)
-    return (_apply_rope_window(q, cos, sin),
-            _apply_rope_window(k, cos, sin), v)
-
-
-def _layer_finish_window(x, o, layer, c, experts=None, li=None,
-                         use_kernel=None):
-    """:func:`_layer_finish` over a verify window: o [B, S, H, D]."""
-    x = x + jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
-    return _mlp_residual(x, layer, c, experts, li, use_kernel)
-
-
-def _draft_forward_paged(params, n_draft, tokens, positions, tables,
-                         limits, cache: PagedKVCache,
-                         config: llama.LlamaConfig, use_kernel: bool):
-    """One self-draft forward: tokens [B] at ``positions`` through the
-    FIRST ``n_draft`` target layers, reading and writing the target's
-    OWN paged arena (same tables/limits/garbage redirect as the tick).
-    The truncated stack computes bitwise the target's layer-[0:n) K/V,
-    so context is already resident and the draft's writes are the bytes
-    verify will rewrite identically. Returns (draft logits [B, V]
-    through the target's final norm + lm_head, updated cache)."""
+    Returns (fp32 logits [B, S, V] through the final norm + lm_head,
+    the updated cache, and a routed model's per-layer per-expert row
+    counts [L, X], None for a dense model)."""
     c = config
     bs = cache.block_size
     cos, sin = rope_frequencies(c.head_dim, 0, c.rope_theta,
-                                positions=positions)
-    x = params["embed"].astype(c.dtype)[tokens][:, None, :]
+                                positions=positions)      # [B, S, D//2]
+    x = params["embed"].astype(c.dtype)[tokens]               # [B, S, E]
     scale = c.head_dim ** -0.5
-    gathered = jnp.take_along_axis(
-        tables, (positions // bs)[:, None], axis=1)[:, 0]
-    block_idx = jnp.where(positions < limits, gathered, GARBAGE_BLOCK)
+    # Resolve each position's target block through the slot's table once
+    # (shared by every layer's write). Speculative ticks can OVERRUN a
+    # slot's reservation (the host detects finishes up to 2K ticks
+    # late): past ``limits`` the table tail would alias the write onto
+    # the slot's LAST LIVE block — and a later rewind would replay over
+    # the corrupted K/V. Redirect overrun writes, and a freed slot's, to
+    # the garbage block instead.
+    gathered = jnp.take_along_axis(tables, positions // bs, axis=1)
+    block_idx = jnp.where(positions < limits[:, None], gathered,
+                          GARBAGE_BLOCK)                      # [B, S]
     offset = positions % bs
-    visits = _window_visits(tables, positions[:, None], limits, bs,
-                            use_kernel)
+    visits = _window_visits(tables, positions, limits, bs, use_kernel)
 
-    sliced, experts = llama.split_layers(params, n_draft)
+    scanned, experts = llama.split_layers(params, n_layers)
 
     def layer_fn(carry, layer):
+        # The arena rides the CARRY, updated in place layer by layer,
+        # not scan xs/ys: as per-iteration inputs/outputs XLA
+        # materializes full cache copies every tick.
         x, arenas, li = carry
         q, k, v = _layer_qkv(x, layer, cos, sin, c)
         o, arenas = _write_then_attend(
-            arenas, li, q, k, v, block_idx[:, None], offset[:, None],
-            tables, positions[:, None], visits, scale, use_kernel)
-        x, _ = _layer_finish(x, o[:, 0].astype(x.dtype), layer, c,
-                             experts, li, use_kernel)
-        return (x, arenas, li + 1), None
+            arenas, li, q, k, v, block_idx, offset, tables, positions,
+            visits, scale, use_kernel)
+        x, rows = _layer_finish(x, o.astype(x.dtype), layer, c, experts,
+                                li, use_kernel)
+        return (x, arenas, li + 1), rows
 
-    (x, arenas, _), _ = jax.lax.scan(
-        layer_fn, (x, tuple(cache), jnp.int32(0)), sliced)
+    (x, arenas, _), rows = jax.lax.scan(
+        layer_fn, (x, tuple(cache), jnp.int32(0)), scanned)
     x = rms_norm(x, params["final_norm"], c.rms_eps)
+    # lm_head in the params' storage dtype with fp32 accumulation
+    # (shared with prefill): bf16 params are never upcast in HBM.
     logits = lm_head_logits(x, params, c)
-    return logits[:, 0], PagedKVCache(*arenas)
+    return logits, PagedKVCache(*arenas), rows
 
 
 def _draft_forward_dense(dparams, tokens, positions, dcache: KVCache,
                          dconfig: llama.LlamaConfig):
-    """External-drafter decode step over the drafter's own dense
-    per-slot cache (reference attention — the drafter is small by
-    construction, so the fused kernel buys nothing). Returns
-    (logits [B, V], updated cache)."""
+    """External-drafter decode step over the drafter's OWN dense
+    per-slot cache (another model's cache, not a second plane for the
+    target; reference attention — the drafter is small by construction).
+    tokens/positions [B]. Returns (logits [B, V], updated cache)."""
     c = dconfig
     cos, sin = rope_frequencies(c.head_dim, 0, c.rope_theta,
-                                positions=positions)
+                                positions=positions[:, None])
     x = dparams["embed"].astype(c.dtype)[tokens][:, None, :]
     scale = c.head_dim ** -0.5
 
@@ -353,11 +324,10 @@ def _draft_forward_dense(dparams, tokens, positions, dcache: KVCache,
         q, k, v = _layer_qkv(x, layer, cos, sin, c)
         ck = _scatter_slot(ck, k[:, 0].astype(ck.dtype), positions)
         cv = _scatter_slot(cv, v[:, 0].astype(cv.dtype), positions)
-        o = decode_attention(q[:, 0], ck, cv, positions, scale,
-                             use_kernel=False)
+        o = decode_attention_reference(q[:, 0], ck, cv, positions, scale)
         ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
         cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-        x, _ = _layer_finish(x, o, layer, c, experts, li)
+        x, _ = _layer_finish(x, o[:, None], layer, c, experts, li)
         return (x, ck_all, cv_all, li + 1), None
 
     scanned, experts = llama.split_layers(dparams)
@@ -366,53 +336,6 @@ def _draft_forward_dense(dparams, tokens, positions, dcache: KVCache,
     x = rms_norm(x, dparams["final_norm"], c.rms_eps)
     logits = lm_head_logits(x, dparams, c)
     return logits[:, 0], KVCache(k=nk, v=nv)
-
-
-def _verify_forward_paged(params, tokens, positions, tables, limits,
-                          cache: PagedKVCache, config: llama.LlamaConfig,
-                          use_kernel: bool):
-    """ONE batched verify pass over each slot's k+1-token window: tokens
-    [B, S] at per-slot absolute ``positions`` [B, S] (= p .. p+k).
-
-    Projections and the MLP run batched over the window — verify streams
-    the parameters ONCE for all k+1 positions, which is the speculative
-    roofline lever — while attention runs per window position through the
-    EXISTING paged decode path. All k+1 positions' K/V scatter before any
-    query attends, which is safe because position masking hides in-window
-    successors (query j sees [0..p+j] only), and overrun/freed-slot
-    writes redirect to the garbage block exactly like the plain tick.
-    Returns (fp32 logits [B, S, V], updated cache)."""
-    c = config
-    bs = cache.block_size
-    b, s = tokens.shape
-    cos, sin = rope_frequencies(c.head_dim, 0, c.rope_theta,
-                                positions=positions.reshape(-1))
-    cos = cos.reshape(b, s, -1)
-    sin = sin.reshape(b, s, -1)
-    x = params["embed"].astype(c.dtype)[tokens]               # [B, S, E]
-    scale = c.head_dim ** -0.5
-    gathered = jnp.take_along_axis(tables, positions // bs, axis=1)
-    block_idx = jnp.where(positions < limits[:, None], gathered,
-                          GARBAGE_BLOCK)                      # [B, S]
-    offset = positions % bs
-    visits = _window_visits(tables, positions, limits, bs, use_kernel)
-
-    def layer_fn(carry, layer):
-        x, arenas, li = carry
-        q, k, v = _layer_qkv_window(x, layer, cos, sin, c)
-        o, arenas = _write_then_attend(
-            arenas, li, q, k, v, block_idx, offset, tables, positions,
-            visits, scale, use_kernel)
-        x, _ = _layer_finish_window(x, o.astype(x.dtype), layer, c,
-                                    experts, li, use_kernel)
-        return (x, arenas, li + 1), None
-
-    scanned, experts = llama.split_layers(params)
-    (x, arenas, _), _ = jax.lax.scan(
-        layer_fn, (x, tuple(cache), jnp.int32(0)), scanned)
-    x = rms_norm(x, params["final_norm"], c.rms_eps)
-    logits = lm_head_logits(x, params, c)
-    return logits, PagedKVCache(*arenas)
 
 
 def _spec_tick_paged(params, tokens, positions, tables, limits,
@@ -447,9 +370,11 @@ def _spec_tick_paged(params, tokens, positions, tables, limits,
             logits_d, dcache = _draft_forward_dense(
                 draft_params, tok, pos, dcache, draft_config)
         else:
-            logits_d, dcache = _draft_forward_paged(
-                params, n_draft, tok, pos, tables, limits, dcache,
-                config, use_kernel)
+            # The self-draft shares the target's arena (layers [0:n)).
+            logits_d, dcache, _ = _forward_paged(
+                params, tok[:, None], pos[:, None], tables, limits,
+                dcache, config, use_kernel, n_layers=n_draft)
+            logits_d = logits_d[:, 0]
         if sampling.greedy:
             nxt = jnp.argmax(logits_d, axis=-1).astype(jnp.int32)
         else:
@@ -467,9 +392,8 @@ def _spec_tick_paged(params, tokens, positions, tables, limits,
         cache = dcache  # self-draft wrote the shared arena layers [0:n)
     window = jnp.stack([tokens] + d_tokens, axis=1)          # [B, k+1]
     window_pos = positions[:, None] + jnp.arange(k + 1)[None, :]
-    logits, cache = _verify_forward_paged(params, window, window_pos,
-                                          tables, limits, cache, config,
-                                          use_kernel)
+    logits, cache, _ = _forward_paged(params, window, window_pos, tables,
+                                      limits, cache, config, use_kernel)
     drafts = jnp.stack(d_tokens, axis=1)
     probs = jnp.stack(d_probs, axis=1) if d_probs else None
     committed, counts = spec_commit(drafts, probs, logits, step, sampling)
@@ -480,104 +404,21 @@ def _spec_tick_paged(params, tokens, positions, tables, limits,
             dcache if external else None, step + 1)
 
 
-def _decode_tick(params, tokens, positions, cache: KVCache, step,
-                 config: llama.LlamaConfig, use_kernel: bool = False,
-                 sampling: SamplingParams = SamplingParams()):
-    """One decode step for every slot: tokens [B] at per-slot absolute
-    ``positions`` [B]. Returns (next_tokens [B], positions+1, cache,
-    step+1) — ``step`` is the device-resident sampling counter.
-
-    ``use_kernel`` (static) routes attention through the fused pallas
-    decode kernel — one pass over the KV pool in its storage dtype —
-    instead of the fp32-upcast whole-cache einsums of the reference."""
-    c = config
-    cos, sin = rope_frequencies(c.head_dim, 0, c.rope_theta,
-                                positions=positions)  # [B, D//2]
-    x = params["embed"].astype(c.dtype)[tokens][:, None, :]   # [B, 1, E]
-    scale = c.head_dim ** -0.5
-
-    def layer_fn(carry, inputs):
-        # Cache rides the CARRY (updated in place layer by layer via
-        # dynamic_update_slice), not scan xs/ys: threading it as
-        # per-iteration inputs/outputs made XLA materialize full cache
-        # copies every tick — the decode tick was 2-3x the HBM roofline
-        # from copy traffic alone.
-        x, ck_all, cv_all, li = carry
-        layer = inputs
-        ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
-        q, k, v = _layer_qkv(x, layer, cos, sin, c)
-        ck = _scatter_slot(ck, k[:, 0].astype(ck.dtype), positions)
-        cv = _scatter_slot(cv, v[:, 0].astype(cv.dtype), positions)
-        o = decode_attention(q[:, 0], ck, cv, positions, scale,
-                             use_kernel=use_kernel)
-        ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
-        cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-        x, _ = _layer_finish(x, o, layer, c, experts, li, use_kernel)
-        return (x, ck_all, cv_all, li + 1), None
-
-    scanned, experts = llama.split_layers(params)
-    (x, new_k, new_v, _), _ = jax.lax.scan(
-        layer_fn, (x, cache.k, cache.v, jnp.int32(0)), scanned)
-    x = rms_norm(x, params["final_norm"], c.rms_eps)
-    # lm_head in the params' storage dtype with fp32 accumulation (shared
-    # with the prefill path) — bf16 params are no longer upcast in HBM.
-    logits = lm_head_logits(x, params, c)
-    # Token selection stays ON DEVICE: the host needs 4 bytes per slot,
-    # not the [B, V] logits.
-    next_tokens = _next_tokens(logits, step, sampling)
-    return next_tokens, positions + 1, KVCache(k=new_k, v=new_v), step + 1
-
-
 def _decode_tick_paged(params, tokens, positions, tables, limits,
                        cache: PagedKVCache, step,
                        config: llama.LlamaConfig, use_kernel: bool = False,
                        sampling: SamplingParams = SamplingParams()):
-    """Paged decode step: same per-layer structure as :func:`_decode_tick`
-    but K/V scatter/attention go through the block arena + per-slot
-    block tables, so the attention streams only live blocks. ``tables``
-    [B, max_blocks] int32 (dead tail entries repeat the last live block;
-    freed slots point wholesale at the garbage block); ``limits`` [B] is
-    each slot's table-covered token count (reserved_blocks * bs), 0 for
-    a freed slot, which the attention kernel then never visits."""
-    c = config
-    bs = cache.block_size
-    cos, sin = rope_frequencies(c.head_dim, 0, c.rope_theta,
-                                positions=positions)
-    x = params["embed"].astype(c.dtype)[tokens][:, None, :]
-    scale = c.head_dim ** -0.5
-    # This tick writes at `positions`: resolve each slot's target block
-    # through its table once (shared by every layer's scatter).
-    # Speculative ticks can OVERRUN a slot's reservation (the host
-    # detects finishes up to 2K ticks late): past ``limits`` the table
-    # tail would alias the write onto the slot's LAST LIVE block — and a
-    # later rewind would replay over the corrupted K/V. Redirect overrun
-    # writes to the garbage block instead (the dense engine's analog:
-    # overrun writes land in the slot's private tail, harmlessly).
-    gathered = jnp.take_along_axis(
-        tables, (positions // bs)[:, None], axis=1)[:, 0]        # [B]
-    block_idx = jnp.where(positions < limits, gathered, GARBAGE_BLOCK)
-    offset = positions % bs                                      # [B]
-    visits = _window_visits(tables, positions[:, None], limits, bs,
-                            use_kernel)
-
-    def layer_fn(carry, layer):
-        x, arenas, li = carry
-        q, k, v = _layer_qkv(x, layer, cos, sin, c)
-        o, arenas = _write_then_attend(
-            arenas, li, q, k, v, block_idx[:, None], offset[:, None],
-            tables, positions[:, None], visits, scale, use_kernel)
-        x, rows = _layer_finish(x, o[:, 0].astype(x.dtype), layer, c,
-                                experts, li, use_kernel)
-        return (x, arenas, li + 1), rows
-
-    scanned, experts = llama.split_layers(params)
-    (x, arenas, _), rows = jax.lax.scan(
-        layer_fn, (x, tuple(cache), jnp.int32(0)), scanned)
-    x = rms_norm(x, params["final_norm"], c.rms_eps)
-    logits = lm_head_logits(x, params, c)
+    """One decode step for every slot, the S = 1 case of
+    :func:`_forward_paged`: tokens [B] at per-slot absolute
+    ``positions`` [B]. Returns (next_tokens [B], positions+1, cache,
+    step+1) — ``step`` is the device-resident sampling counter. Token
+    selection stays ON DEVICE: the host needs 4 bytes per slot, not the
+    [B, V] logits."""
+    logits, cache, rows = _forward_paged(
+        params, tokens[:, None], positions[:, None], tables, limits,
+        cache, config, use_kernel)
     next_tokens = _next_tokens(logits, step, sampling)
-    state = (next_tokens, positions + 1, PagedKVCache(*arenas), step + 1)
+    state = (next_tokens, positions + 1, cache, step + 1)
     if rows is None:
         return state
     # A routed model's tick also reports each layer's per-expert row
@@ -598,11 +439,7 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     ``stored`` is the suffix K/V in ARENA form — int8 arenas quantize
     IN-LOOP and attention reads the dequantized values, so what a later
     prefix-sharer gathers back from the arena is bit-identical to what
-    this prefill attended: the prefix-cache on/off parity contract.
-    With P=0 and no quantization this computes exactly what the dense
-    mini-cache prefill (:func:`~ray_tpu.models.inference._forward_cached`)
-    computed — same ops in the same order — so paged-vs-dense parity is
-    untouched."""
+    this prefill attended: the prefix-cache on/off parity contract."""
     c = config
     cos, sin = rope_frequencies(c.head_dim, tokens.shape[1], c.rope_theta,
                                 positions=positions)
@@ -614,10 +451,7 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     def layer_fn(carry, inputs):
         x, li = carry
         layer, pk_l, pv_l = inputs
-        h = rms_norm(x, layer["attn_norm"], c.rms_eps)
-        q, k, v = llama.project_qkv(h, layer, c)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q, k, v = _layer_qkv(x, layer, cos, sin, c)
         if quantized:
             kq, ksc = quantize_kv(k)
             vq, vsc = quantize_kv(v)
@@ -632,8 +466,7 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
         ck = jnp.concatenate([pk_l, k_att], axis=1)   # [N, P+S, KVH, D]
         cv = jnp.concatenate([pv_l, v_att], axis=1)
         o = _attend_cached(q, ck, cv, positions, scale)
-        x, _ = _layer_finish_window(x, o, layer, c, experts, li,
-                                    use_kernel)
+        x, _ = _layer_finish(x, o, layer, c, experts, li, use_kernel)
         return (x, li + 1), stored
 
     (x, _), stored = jax.lax.scan(layer_fn, (x, jnp.int32(0)),
@@ -661,21 +494,9 @@ def _bucket_floor(n: int) -> int:
     return 0 if n <= 0 else 1 << (n.bit_length() - 1)
 
 
-def _resolve_paged(paged: Optional[bool]) -> bool:
-    """Engine-level paging toggle: explicit arg > RAY_TPU_PAGED_KV env >
-    on (the paged arena is the default data plane)."""
-    if paged is None:
-        paged = env_flag("RAY_TPU_PAGED_KV")
-    if paged is None:
-        return True
-    return bool(paged)
-
-
 def _resolve_prefix_cache(prefix_cache: Optional[bool]) -> bool:
     """Cross-request prefix reuse toggle: explicit arg >
-    RAY_TPU_PREFIX_CACHE env > on. Only meaningful on paged engines —
-    the radix index shares arena blocks, which the dense per-slot
-    stripes cannot."""
+    RAY_TPU_PREFIX_CACHE env > on."""
     if prefix_cache is None:
         prefix_cache = env_flag("RAY_TPU_PREFIX_CACHE")
     if prefix_cache is None:
@@ -706,26 +527,18 @@ def _resolve_role(role: Optional[str]) -> str:
     return role
 
 
-def _resolve_decode_kernel(config: llama.LlamaConfig, max_len: int,
+def _resolve_decode_kernel(config: llama.LlamaConfig,
                            use_decode_kernel: Optional[bool],
-                           paged: bool = False,
-                           block_size: int = 64) -> bool:
-    """Engine-level kernel toggle: explicit arg > RAY_TPU_DECODE_KERNEL
-    env > auto (fused kernel on TPU when the shapes tile; the XLA
-    reference elsewhere — CPU tests opt in explicitly and run the kernel
-    in interpret mode). The paged engine dispatches the paged kernel
-    (``ops/paged_decode_attention.py``), the dense engine the dense
-    one."""
+                           block_size: int) -> bool:
+    """Whether the engine's programs call the Pallas kernels
+    (``ops/paged_decode_attention.py``, ``ops/moe.py``): an explicit
+    argument forces either way (CPU tests pass True and run them
+    interpreted); None means on a TPU when the shapes tile, the XLA
+    reference elsewhere."""
     if use_decode_kernel is None:
-        use_decode_kernel = env_flag("RAY_TPU_DECODE_KERNEL")
-    if use_decode_kernel is None:
-        if jax.default_backend() != "tpu":
-            return False
-        if paged:
-            return paged_applicable(block_size, config.head_dim,
-                                    config.num_heads, config.num_kv_heads)
-        return decode_applicable(max_len, config.head_dim,
-                                 config.num_heads, config.num_kv_heads)
+        return (jax.default_backend() == "tpu"
+                and paged_applicable(block_size, config.head_dim,
+                                     config.num_heads, config.num_kv_heads))
     return bool(use_decode_kernel)
 
 
@@ -782,7 +595,6 @@ class ContinuousBatcher:
                  eos_token: Optional[int] = None, token_callback=None,
                  sync_every: int = 1,
                  use_decode_kernel: Optional[bool] = None,
-                 paged: Optional[bool] = None,
                  block_size: int = 64,
                  kv_dtype: Optional[str] = None,
                  num_blocks: Optional[int] = None,
@@ -819,24 +631,22 @@ class ContinuousBatcher:
         would under ``sync_every=1``, and sampling keys are derived from
         that global step counter.
 
-        ``use_decode_kernel`` routes decode attention through the fused
-        pallas kernel (paged or dense variant); ``None`` resolves via
-        ``RAY_TPU_DECODE_KERNEL`` then auto (TPU with tiling shapes).
-        Outputs are bit-identical kernel on/off.
+        ``use_decode_kernel`` routes decode attention and the K/V write
+        through the paged Pallas kernels; ``None`` is auto (TPU with
+        tiling shapes). Outputs are bit-identical kernel on/off.
 
-        PAGED KV plane (``paged``, default on; ``RAY_TPU_PAGED_KV=0``
-        reverts the default): the cache is a shared arena of
+        PAGED KV plane: the cache is a shared arena of
         ``block_size``-token blocks with per-slot block tables — decode
-        reads only live blocks instead of every slot's padded ``S_max``
-        stripe, and admission reserves blocks all-or-nothing so a
+        reads only live blocks instead of a padded ``S_max`` stripe a
+        slot, and admission reserves blocks all-or-nothing so a
         request can also wait on arena space. ``kv_dtype`` ('bf16' |
         'int8', or ``RAY_TPU_KV_DTYPE``) selects arena storage; int8
         halves KV bytes with per-token/per-head scales. ``num_blocks``
         sizes the arena (default: enough for every slot at ``max_len``,
         plus the reserved garbage block).
 
-        ``prefix_cache`` (default on for paged engines;
-        ``RAY_TPU_PREFIX_CACHE`` env) enables CROSS-REQUEST PREFIX
+        ``prefix_cache`` (default on; ``RAY_TPU_PREFIX_CACHE`` env)
+        enables CROSS-REQUEST PREFIX
         REUSE: a radix index over block-aligned prompt chunks lets a
         new request splice blocks another request already prefilled
         into its table read-only and prefill only its novel suffix;
@@ -849,8 +659,8 @@ class ContinuousBatcher:
         greedy argmax. Sampled decode is deterministic under a fixed
         ``sampling.seed``.
 
-        SPECULATIVE DECODING (``spec_k`` > 0, or ``RAY_TPU_SPEC_K``;
-        paged engines only — the rewind substrate): each tick a cheap
+        SPECULATIVE DECODING (``spec_k`` > 0, or ``RAY_TPU_SPEC_K``):
+        each tick a cheap
         drafter proposes up to ``spec_k`` tokens per slot, one batched
         verify pass scores all k+1 positions through the same paged
         attention path, and per-slot acceptance commits a variable
@@ -869,8 +679,8 @@ class ContinuousBatcher:
         sampling that preserves the target distribution and replays
         deterministically across buffered rewinds.
 
-        DISAGGREGATED ROLES (``role`` / ``RAY_TPU_SERVE_ROLE``; paged
-        engines only): ``"prefill"`` runs admission + prefill and parks
+        DISAGGREGATED ROLES (``role`` / ``RAY_TPU_SERVE_ROLE``):
+        ``"prefill"`` runs admission + prefill and parks
         each request at its FIRST token with its arena blocks retained
         for :meth:`export_kv_payload` — the engine never decode-ticks,
         so a long prefill burst cannot stall anyone's TPOT.
@@ -889,28 +699,19 @@ class ContinuousBatcher:
         self.eos_token = eos_token
         self.sync_every = max(1, int(sync_every))
         self.sampling = SamplingParams.coerce(sampling)
-        self.paged = _resolve_paged(paged)
         self.role = _resolve_role(role)
-        if self.role != "both" and not self.paged:
-            raise ValueError(
-                "disaggregated prefill/decode roles need the paged KV "
-                "plane (block-granular export/import); use paged=True "
-                "or role='both'")
         self.block_size = int(block_size)
-        if self.paged and (self.block_size < 8
-                           or self.block_size & (self.block_size - 1)):
+        if self.block_size < 8 or self.block_size & (self.block_size - 1):
             # Prompt padding buckets are powers of two; a non-pow2 block
             # would make the padded length a non-multiple of the block
             # and break the prefill block reshape.
             raise ValueError(
                 f"block_size must be a power of two >= 8, "
                 f"got {self.block_size}")
-        self.kv_dtype = resolve_kv_dtype(kv_dtype) if self.paged else None
-        self.prefix_cache = self.paged and _resolve_prefix_cache(
-            prefix_cache)
+        self.kv_dtype = resolve_kv_dtype(kv_dtype)
+        self.prefix_cache = _resolve_prefix_cache(prefix_cache)
         self.use_decode_kernel = _resolve_decode_kernel(
-            config, max_len, use_decode_kernel, paged=self.paged,
-            block_size=self.block_size)
+            config, use_decode_kernel, self.block_size)
         # Speculative-decode knobs resolve BEFORE the arena is sized:
         # reservations carry spec_k look-ahead tokens (rejected draft
         # writes must land in already-reserved blocks), so max_blocks /
@@ -918,11 +719,6 @@ class ContinuousBatcher:
         self.spec_k = _resolve_spec_k(spec_k)
         self.drafter = drafter
         if self.spec_k:
-            if not self.paged:
-                raise ValueError(
-                    "speculative decoding needs the paged KV plane (the "
-                    "garbage-block rewind substrate); use paged=True or "
-                    "spec_k=0")
             if self.drafter is None:
                 self.drafter = SelfDrafter(spec_draft_layers)
             if self.drafter.external:
@@ -1015,31 +811,25 @@ class ContinuousBatcher:
         # per-tick-sync step holds back until it has dispatched the NEXT
         # tick: see ``_emit_held``.
         self._held_tokens: List[tuple] = []
-        if self.paged:
-            # Table width covers max_len PLUS the spec look-ahead: a spec
-            # tick writes draft/verify K/V up to position p + spec_k, and
-            # those writes must stay inside the slot's own reservation
-            # (the garbage redirect is for overrun PAST it).
-            self.max_blocks = -(-(max_len + self.spec_k)
-                                // self.block_size)
-            self.num_blocks = int(
-                num_blocks if num_blocks is not None
-                else num_slots * self.max_blocks + 1)
-            self.cache = self._new_cache()
-            self.allocator = BlockAllocator(self.num_blocks)
-            self._slot_blocks: Dict[int, List[int]] = {}
-            # Radix index over block-aligned prompt chunks -> resident
-            # arena blocks (None with the prefix cache off). Slots track
-            # their pinned index nodes so release can deref instead of
-            # freeing shared blocks.
-            self._prefix = RadixBlockIndex() if self.prefix_cache else None
-            self._slot_nodes: Dict[int, List[Any]] = {}
-            self._d_tables = None
-            self._d_limits = None
-        else:
-            self._prefix = None
-            self._slot_nodes = {}
-            self.cache = self._new_cache()
+        # Table width covers max_len PLUS the spec look-ahead: a spec
+        # tick writes draft/verify K/V up to position p + spec_k, and
+        # those writes must stay inside the slot's own reservation
+        # (the garbage redirect is for overrun PAST it).
+        self.max_blocks = -(-(max_len + self.spec_k) // self.block_size)
+        self.num_blocks = int(
+            num_blocks if num_blocks is not None
+            else num_slots * self.max_blocks + 1)
+        self.cache = self._new_cache()
+        self.allocator = BlockAllocator(self.num_blocks)
+        self._slot_blocks: Dict[int, List[int]] = {}
+        # Radix index over block-aligned prompt chunks -> resident
+        # arena blocks (None with the prefix cache off). Slots track
+        # their pinned index nodes so release can deref instead of
+        # freeing shared blocks.
+        self._prefix = RadixBlockIndex() if self.prefix_cache else None
+        self._slot_nodes: Dict[int, List[Any]] = {}
+        self._d_tables = None
+        self._d_limits = None
         self._free: List[int] = list(range(num_slots))
         self._slots: Dict[int, Dict[str, Any]] = {}   # slot -> request
         # Device-resident decode state: last tokens + positions + the
@@ -1099,127 +889,90 @@ class ContinuousBatcher:
         # silent while a stray odd shape raises
         # ray_tpu_xla_retraces_total. The tick has exactly ONE legitimate
         # signature.
-        prefill_dims = (max_len, num_slots)
-        if self.paged:
-            # Prefix-aware suffix groups add legitimate non-pow2 dims:
-            # suffix buckets clamped to the table capacity left after a
-            # matched prefix. Matched-block counts themselves bucket to
-            # powers of two in admission (_bucket_floor) — already
-            # silent under the bucketed policy — so the clamp takes
-            # only log-many values, and this whitelist ENFORCES that
-            # bound: an exact-m regression would raise
-            # ray_tpu_xla_retraces_total.
-            ms = {0}
-            m = 1
-            while m <= self.max_blocks:
-                ms.add(m)
-                m *= 2
-            prefill_dims += (0,)
-            prefill_dims += tuple(self.block_size * (self.max_blocks - v)
-                                  for v in sorted(ms))
+        # Prefix-aware suffix groups add legitimate non-pow2 dims:
+        # suffix buckets clamped to the table capacity left after a
+        # matched prefix. Matched-block counts themselves bucket to
+        # powers of two in admission (_bucket_floor) — already silent
+        # under the bucketed policy — so the clamp takes only log-many
+        # values, and this whitelist ENFORCES that bound: an exact-m
+        # regression would raise ray_tpu_xla_retraces_total.
+        ms = {0}
+        m = 1
+        while m <= self.max_blocks:
+            ms.add(m)
+            m *= 2
+        prefill_dims = (max_len, num_slots, 0) + tuple(
+            self.block_size * (self.max_blocks - v) for v in sorted(ms))
 
-        if self.paged:
-            @xla_monitor.instrument(name="cb_prefill",
-                                    shape_policy="bucketed",
-                                    allowed_dims=prefill_dims,
-                                    donate_argnums=(2,))
-            def prefill(params, tokens, cache, ptables, tables_w,
-                        last_idx, pstep):
-                # BATCHED BUCKETED PREFILL, paged + prefix-aware: tokens
-                # [N, S] holds N same-group SUFFIXES (prompt tokens not
-                # covered by matched prefix blocks; the whole prompt
-                # when nothing matched); ``ptables`` [N, m] names the
-                # shared arena blocks holding each row's m-block prefix
-                # (READ-ONLY — gathered, dequantized when int8, never
-                # written); ``tables_w`` [N, S // bs] names the blocks
-                # the suffix K/V land in (overflow entries point at the
-                # garbage block). Only N first tokens leave the device.
-                n, s_pad = tokens.shape
-                m = ptables.shape[1]
-                positions = m * block_size_c + jnp.arange(s_pad)
-                flat_p = ptables.reshape(-1)                 # [N * m]
-                pk = cache.k[:, flat_p]
-                pv = cache.v[:, flat_p]
-                if cache.quantized:
-                    pk = (pk.astype(jnp.float32)
-                          * cache.k_scale[:, flat_p][..., None]
-                          ).astype(cfg.dtype)
-                    pv = (pv.astype(jnp.float32)
-                          * cache.v_scale[:, flat_p][..., None]
-                          ).astype(cfg.dtype)
+        @xla_monitor.instrument(name="cb_prefill",
+                                shape_policy="bucketed",
+                                allowed_dims=prefill_dims,
+                                donate_argnums=(2,))
+        def prefill(params, tokens, cache, ptables, tables_w,
+                    last_idx, pstep):
+            # BATCHED BUCKETED PREFILL, paged + prefix-aware: tokens
+            # [N, S] holds N same-group SUFFIXES (prompt tokens not
+            # covered by matched prefix blocks; the whole prompt
+            # when nothing matched); ``ptables`` [N, m] names the
+            # shared arena blocks holding each row's m-block prefix
+            # (READ-ONLY — gathered, dequantized when int8, never
+            # written); ``tables_w`` [N, S // bs] names the blocks
+            # the suffix K/V land in (overflow entries point at the
+            # garbage block). Only N first tokens leave the device.
+            n, s_pad = tokens.shape
+            m = ptables.shape[1]
+            positions = m * block_size_c + jnp.arange(s_pad)
+            flat_p = ptables.reshape(-1)                 # [N * m]
+            pk = cache.k[:, flat_p]
+            pv = cache.v[:, flat_p]
+            if cache.quantized:
+                pk = (pk.astype(jnp.float32)
+                      * cache.k_scale[:, flat_p][..., None]
+                      ).astype(cfg.dtype)
+                pv = (pv.astype(jnp.float32)
+                      * cache.v_scale[:, flat_p][..., None]
+                      ).astype(cfg.dtype)
 
-                logits, stored = _prefill_forward_paged(
-                    params, tokens, positions,
-                    _blocks_to_ctx(pk.astype(cfg.dtype), n),
-                    _blocks_to_ctx(pv.astype(cfg.dtype), n),
-                    cfg, cache.quantized, use_kernel)
-                flat_tables = tables_w.reshape(-1)           # [N * npb]
+            logits, stored = _prefill_forward_paged(
+                params, tokens, positions,
+                _blocks_to_ctx(pk.astype(cfg.dtype), n),
+                _blocks_to_ctx(pv.astype(cfg.dtype), n),
+                cfg, cache.quantized, use_kernel)
+            flat_tables = tables_w.reshape(-1)           # [N * npb]
 
-                to_blocks = functools.partial(_ctx_to_blocks,
-                                              bs=block_size_c)
+            to_blocks = functools.partial(_ctx_to_blocks,
+                                          bs=block_size_c)
 
-                if cache.quantized:
-                    kq, vq, ksc, vsc = stored
-                    new_cache = PagedKVCache(
-                        k=cache.k.at[:, flat_tables].set(to_blocks(kq)),
-                        v=cache.v.at[:, flat_tables].set(to_blocks(vq)),
-                        k_scale=cache.k_scale.at[:, flat_tables].set(
-                            to_blocks(ksc)),
-                        v_scale=cache.v_scale.at[:, flat_tables].set(
-                            to_blocks(vsc)))
-                else:
-                    k_s, v_s = stored
-                    dt = cache.k.dtype
-                    new_cache = PagedKVCache(
-                        k=cache.k.at[:, flat_tables].set(
-                            to_blocks(k_s.astype(dt))),
-                        v=cache.v.at[:, flat_tables].set(
-                            to_blocks(v_s.astype(dt))))
-                last = jnp.take_along_axis(
-                    logits, last_idx[:, None, None], axis=1)  # [N, 1, V]
-                first = _next_tokens(last, pstep, sampling_cfg,
-                                     salt=_PREFILL_SALT)
-                return first, new_cache
+            if cache.quantized:
+                kq, vq, ksc, vsc = stored
+                new_cache = PagedKVCache(
+                    k=cache.k.at[:, flat_tables].set(to_blocks(kq)),
+                    v=cache.v.at[:, flat_tables].set(to_blocks(vq)),
+                    k_scale=cache.k_scale.at[:, flat_tables].set(
+                        to_blocks(ksc)),
+                    v_scale=cache.v_scale.at[:, flat_tables].set(
+                        to_blocks(vsc)))
+            else:
+                k_s, v_s = stored
+                dt = cache.k.dtype
+                new_cache = PagedKVCache(
+                    k=cache.k.at[:, flat_tables].set(
+                        to_blocks(k_s.astype(dt))),
+                    v=cache.v.at[:, flat_tables].set(
+                        to_blocks(v_s.astype(dt))))
+            last = jnp.take_along_axis(
+                logits, last_idx[:, None, None], axis=1)  # [N, 1, V]
+            first = _next_tokens(last, pstep, sampling_cfg,
+                                 salt=_PREFILL_SALT)
+            return first, new_cache
 
-            @xla_monitor.instrument(name="cb_tick", donate_argnums=(5,))
-            def tick(params, tokens, positions, tables, limits, cache,
-                     step):
-                return _decode_tick_paged(params, tokens, positions,
-                                          tables, limits, cache, step,
-                                          cfg, use_kernel=use_kernel,
-                                          sampling=sampling_cfg)
-        else:
-            @xla_monitor.instrument(name="cb_prefill",
-                                    shape_policy="bucketed",
-                                    allowed_dims=prefill_dims,
-                                    donate_argnums=(2,))
-            def prefill(params, tokens, cache, slots, last_idx, pstep):
-                # BATCHED BUCKETED PREFILL: tokens [N, L] holds N
-                # same-bucket prompts destined for KV slots ``slots``
-                # [N]; ``last_idx`` [N] is each prompt's true_len - 1.
-                # Slot gather + write-back live INSIDE the jit with the
-                # pooled cache donated, so an admission burst is one
-                # in-place program, not N whole-cache copies. Only the N
-                # first tokens leave the device (selection on chip), not
-                # [N, L, V] logits.
-                positions = jnp.arange(tokens.shape[1])
-                slot_cache = KVCache(k=jnp.take(cache.k, slots, axis=1),
-                                     v=jnp.take(cache.v, slots, axis=1))
-                logits, sc = _forward_cached(params, tokens, positions,
-                                             slot_cache, cfg)
-                cache = KVCache(k=cache.k.at[:, slots].set(sc.k),
-                                v=cache.v.at[:, slots].set(sc.v))
-                last = jnp.take_along_axis(
-                    logits, last_idx[:, None, None], axis=1)  # [N, 1, V]
-                first = _next_tokens(last, pstep, sampling_cfg,
-                                     salt=_PREFILL_SALT)
-                return first, cache
-
-            @xla_monitor.instrument(name="cb_tick", donate_argnums=(3,))
-            def tick(params, tokens, positions, cache, step):
-                return _decode_tick(params, tokens, positions, cache,
-                                    step, cfg, use_kernel=use_kernel,
-                                    sampling=sampling_cfg)
+        @xla_monitor.instrument(name="cb_tick", donate_argnums=(5,))
+        def tick(params, tokens, positions, tables, limits, cache,
+                 step):
+            return _decode_tick_paged(params, tokens, positions,
+                                      tables, limits, cache, step,
+                                      cfg, use_kernel=use_kernel,
+                                      sampling=sampling_cfg)
 
         self._prefill = prefill
         self._tick = tick
@@ -1258,14 +1011,9 @@ class ContinuousBatcher:
 
     def _new_cache(self):
         with jax.default_device(self.device):
-            if self.paged:
-                cache = PagedKVCache.create(
-                    self.config, self.num_blocks, self.block_size,
-                    self.kv_dtype)
-            else:
-                cache = KVCache.create(self.config, self.num_slots,
-                                       self.max_len)
-        return self._place(cache)
+            return self._place(PagedKVCache.create(
+                self.config, self.num_blocks, self.block_size,
+                self.kv_dtype))
 
     def _new_draft_cache(self):
         with jax.default_device(self.device):
@@ -1460,9 +1208,9 @@ class ContinuousBatcher:
         """Live engine pressure — the router/autoscaler input: queue
         depth, slot occupancy, free KV arena blocks, and the prefill
         token backlog still waiting for admission."""
-        free_blocks = self.allocator.free_count if self.paged else 0
+        free_blocks = self.allocator.free_count
         cached = (self._prefix.cached_count
-                  if self.paged and self._prefix is not None else 0)
+                  if self._prefix is not None else 0)
         return {
             "queue_depth": len(self._waiting),
             "active_slots": len(self._slots),
@@ -1472,7 +1220,7 @@ class ContinuousBatcher:
             # capacity is free + cached, which the router/shedding
             # thresholds should use instead of raw free.
             "kv_blocks_cached": cached,
-            "kv_blocks_total": (self.num_blocks - 1 if self.paged else 0),
+            "kv_blocks_total": self.num_blocks - 1,
             # Draft look-ahead blocks are RESERVED capacity (the
             # allocator already excludes them from kv_blocks_free — no
             # phantom free arena for the admission gate or the arbiter
@@ -1593,8 +1341,8 @@ class ContinuousBatcher:
             rid = next(self._rid)
             self._finished[rid] = []
             return rid
-        if self.paged and self._blocks_needed(
-                len(prompt_tokens), max_new_tokens) > self.num_blocks - 1:
+        if self._blocks_needed(len(prompt_tokens),
+                               max_new_tokens) > self.num_blocks - 1:
             # A reservation larger than the whole arena can NEVER be
             # satisfied: admitting it to the queue would wedge the FIFO
             # head (and every request behind it) forever.
@@ -1619,19 +1367,18 @@ class ContinuousBatcher:
 
     def _release_slot(self, slot: int) -> None:
         self._free.append(slot)
-        if self.paged:
-            blocks = self._slot_blocks.pop(slot, None)
-            nodes = self._slot_nodes.pop(slot, None)
-            if nodes:
-                # Indexed (shared/shareable) blocks: deref — refcount 0
-                # parks them in the LRU "cached" state instead of the
-                # free list, so a later prefix match revives them and
-                # arena pressure reclaims them before admission blocks.
-                self._prefix.release(nodes)
-                shared = {nd.block for nd in nodes}
-                blocks = [b for b in (blocks or []) if b not in shared]
-            if blocks:
-                self.allocator.free(blocks)
+        blocks = self._slot_blocks.pop(slot, None)
+        nodes = self._slot_nodes.pop(slot, None)
+        if nodes:
+            # Indexed (shared/shareable) blocks: deref — refcount 0
+            # parks them in the LRU "cached" state instead of the
+            # free list, so a later prefix match revives them and
+            # arena pressure reclaims them before admission blocks.
+            self._prefix.release(nodes)
+            shared = {nd.block for nd in nodes}
+            blocks = [b for b in (blocks or []) if b not in shared]
+        if blocks:
+            self.allocator.free(blocks)
 
     def cancel(self, rid: int) -> bool:
         """Drop a request (client disconnected): frees its slot / queue
@@ -1682,14 +1429,13 @@ class ContinuousBatcher:
         # failure the old buffers may already be deleted, so rebuild the
         # pool or every later step would raise "Array has been deleted".
         self.cache = self._new_cache()
-        if self.paged:
-            self.allocator.reset()
-            self._slot_blocks.clear()
-            self._slot_nodes.clear()
-            if self._prefix is not None:
-                # The rebuilt arena holds zeros: every cached prefix
-                # entry would alias garbage, so the index restarts cold.
-                self._prefix.clear()
+        self.allocator.reset()
+        self._slot_blocks.clear()
+        self._slot_nodes.clear()
+        if self._prefix is not None:
+            # The rebuilt arena holds zeros: every cached prefix
+            # entry would alias garbage, so the index restarts cold.
+            self._prefix.clear()
         self._applied_steps = 0
         self._bw_window_t0 = None
         self._bw_window_ticks = 0
@@ -1983,10 +1729,7 @@ class ContinuousBatcher:
         """Arena occupancy: live blocks used/total, LRU-cached and
         refcount-shared prefix blocks, live tokens, and the
         fragmentation ratio (reserved-but-unwritten fraction of used
-        blocks). Dense engines report zeros."""
-        if not self.paged:
-            return {"used": 0, "total": 0, "cached": 0, "shared": 0,
-                    "live_tokens": 0, "frag_ratio": 0.0}
+        blocks)."""
         cached = self._prefix.cached_count if self._prefix is not None \
             else 0
         shared = self._prefix.shared_count if self._prefix is not None \
@@ -2012,27 +1755,24 @@ class ContinuousBatcher:
 
     def _account_tick(self, tick_fn, wall_s: float, spec_k: int) -> None:
         """Feed one tick (or a buffered window's mean tick) to the XLA
-        monitor. A paged tick gets the live-byte hint, because the
-        compiled cost prices every table entry as live, and books the
-        share of entries that were: both from one pass over the slots."""
+        monitor, with the live-byte hint, because the compiled cost
+        prices every table entry as live, and book the share of entries
+        that were: both from one pass over the slots."""
         from ray_tpu._private import metrics_defs as mdefs
 
-        hint = None
-        if self.paged:
-            live = self._live_blocks()
-            mdefs.CB_PAGED_LIVE_BLOCK_SHARE.observe(
-                live / (self.num_slots * self.max_blocks), tags=self._mtags)
-            hint = self.tick_bytes_estimate(spec_k=spec_k, live_blocks=live)
-        tick_fn.note_execution(wall_s, bytes_hint=hint)
+        live = self._live_blocks()
+        mdefs.CB_PAGED_LIVE_BLOCK_SHARE.observe(
+            live / (self.num_slots * self.max_blocks), tags=self._mtags)
+        tick_fn.note_execution(wall_s, bytes_hint=self.tick_bytes_estimate(
+            spec_k=spec_k, live_blocks=live))
 
     def tick_bytes_estimate(self, spec_k: Optional[int] = None,
                             live_blocks: Optional[int] = None) -> int:
         """HBM bytes one decode tick actually streams: the full parameter
-        set plus the LIVE tokens' arena traffic (paged) or every slot's
-        padded stripe (dense). This is the live-traffic figure the
-        achieved-bandwidth gauges and bench_serve report — the compiled
-        program's static cost analysis can only ever price the worst
-        case.
+        set plus the LIVE tokens' arena traffic. This is the
+        live-traffic figure the achieved-bandwidth gauges and
+        bench_serve report — the compiled program's static cost analysis
+        can only ever price the worst case.
 
         ``spec_k`` prices a SPECULATIVE tick (defaults to the k the
         engine currently dispatches): each of the k draft passes streams
@@ -2043,46 +1783,40 @@ class ContinuousBatcher:
         the achieved-bandwidth gauges."""
         if spec_k is None:
             spec_k = self._spec_cur_k if self.spec_k else 0
-        if self.paged:
-            # The kernel streams WHOLE blocks, so each slot's live
-            # prefix counts rounded up to block granularity — otherwise
-            # the figure would be block-size-invariant and the
-            # block_size sweep meaningless.
-            if live_blocks is None:
-                live_blocks = self._live_blocks()
-            live_bytes = (live_blocks * self.block_size
-                          * self.cache.token_bytes())
-            # A routed model streams only the experts its rows touch: at
-            # most rows x top-k of each layer's X (every slot routes,
-            # live or not), over each position of a spec window.
-            c = self.config
-            idle_experts = (max(1.0 - self.num_slots * (1 + spec_k)
-                                * c.num_experts_per_tok / c.num_experts,
-                                0.0) if c.num_experts else 0.0)
-            total = (self.param_bytes + live_bytes
-                     - int(self._expert_param_bytes * idle_experts))
-            if spec_k:
-                if self._draft_cache is not None:
-                    dcfg = self.drafter.config
-                    ditem = jnp.dtype(self._draft_cache.k.dtype).itemsize
-                    dstripes = (2 * dcfg.num_layers * self.num_slots
-                                * self.max_len * dcfg.num_kv_heads
-                                * dcfg.head_dim * ditem)
-                    draft_pass = self._draft_param_bytes + dstripes
-                else:
-                    frac = self.spec_draft_layers / self.config.num_layers
-                    draft_pass = (self._layer_param_bytes * frac
-                                  + self._head_param_bytes
-                                  + live_bytes * frac)
-                # k draft passes + k EXTRA verify query positions (the
-                # base figure already counts one arena pass).
-                total += spec_k * (draft_pass + live_bytes)
-            return total
+        # The kernel streams WHOLE blocks, so each slot's live
+        # prefix counts rounded up to block granularity — otherwise
+        # the figure would be block-size-invariant and the
+        # block_size sweep meaningless.
+        if live_blocks is None:
+            live_blocks = self._live_blocks()
+        live_bytes = (live_blocks * self.block_size
+                      * self.cache.token_bytes())
+        # A routed model streams only the experts its rows touch: at
+        # most rows x top-k of each layer's X (every slot routes,
+        # live or not), over each position of a spec window.
         c = self.config
-        itemsize = jnp.dtype(self.cache.k.dtype).itemsize
-        per_slot = (2 * c.num_layers * self.max_len * c.num_kv_heads
-                    * c.head_dim * itemsize)
-        return self.param_bytes + self.num_slots * per_slot
+        idle_experts = (max(1.0 - self.num_slots * (1 + spec_k)
+                            * c.num_experts_per_tok / c.num_experts,
+                            0.0) if c.num_experts else 0.0)
+        total = (self.param_bytes + live_bytes
+                 - int(self._expert_param_bytes * idle_experts))
+        if spec_k:
+            if self._draft_cache is not None:
+                dcfg = self.drafter.config
+                ditem = jnp.dtype(self._draft_cache.k.dtype).itemsize
+                dstripes = (2 * dcfg.num_layers * self.num_slots
+                            * self.max_len * dcfg.num_kv_heads
+                            * dcfg.head_dim * ditem)
+                draft_pass = self._draft_param_bytes + dstripes
+            else:
+                frac = self.spec_draft_layers / self.config.num_layers
+                draft_pass = (self._layer_param_bytes * frac
+                              + self._head_param_bytes
+                              + live_bytes * frac)
+            # k draft passes + k EXTRA verify query positions (the
+            # base figure already counts one arena pass).
+            total += spec_k * (draft_pass + live_bytes)
+        return total
 
     def _blocks_needed(self, prompt_len: int, max_new: int) -> int:
         # Spec decode needs spec_k look-ahead tokens past the committed
@@ -2098,17 +1832,15 @@ class ContinuousBatcher:
                 - -(-(prompt_len + max_new) // self.block_size))
 
     def _can_admit_head(self) -> bool:
-        """True when the FIFO head could admit RIGHT NOW (free slot and,
-        when paged, enough free arena blocks — counting LRU-cached
-        blocks the allocator can reclaim and prefix blocks a radix
-        match would cover). The buffered engine uses this to decide
+        """True when the FIFO head could admit RIGHT NOW (free slot and
+        enough free arena blocks — counting LRU-cached blocks the
+        allocator can reclaim and prefix blocks a radix match would
+        cover). The buffered engine uses this to decide
         whether forcing a sync boundary is worth it — an arena-blocked
         head must not collapse speculative pipelining to one tick per
         sync while it waits for blocks."""
         if not (self._waiting and self._free):
             return False
-        if not self.paged:
-            return True
         req = self._waiting[0]
         need = self._blocks_needed(len(req["prompt"]), req["max_new"])
         avail = self.allocator.free_count
@@ -2190,74 +1922,68 @@ class ContinuousBatcher:
         # the cache length — so an admission burst costs one prefill
         # dispatch per group instead of one per request. Slots are
         # independent, so batched admission is bit-identical to the old
-        # one-at-a-time loop. Paged engines reserve each request's NOVEL
-        # blocks all-or-nothing (FIFO: when the head of the queue
+        # one-at-a-time loop. Each request's NOVEL blocks are reserved
+        # all-or-nothing (FIFO: when the head of the queue
         # doesn't fit the arena even after LRU reclaim, admission
         # stops); matched prefix blocks are pinned read-only instead of
         # allocated, so prefill cost and arena demand both scale with
         # novel tokens.
         bs = self.block_size
-        padded_cap = (self.max_blocks * bs if self.paged else self.max_len)
+        padded_cap = self.max_blocks * bs
         groups: Dict[tuple, List] = {}
         draft_pending: List = []   # (slot, prompt) for the ext. drafter
         while self._waiting and self._free:
             req = self._waiting[0]
-            blocks: List[int] = []
             matched: List[Any] = []
             chunks: List[tuple] = []
             m = 0
-            suffix = req["prompt"]
             meta = self._req_meta.get(req["rid"])
-            if self.paged:
-                if self._prefix is not None:
-                    chunks = self._req_chunks(req)
-                    matched = self._prefix.match(
-                        chunks[:self._match_cap(req)])
-                    # Bucket the match DOWN to a power of two so the
-                    # compiled prefill program count stays log-bounded
-                    # in m (see _bucket_floor); the released tail
-                    # parks young in the LRU, still resident for the
-                    # next matcher and evictable by _alloc_blocks.
-                    m = _bucket_floor(len(matched))
-                    if m < len(matched):
-                        self._prefix.release(matched[m:])
-                        matched = matched[:m]
-                need = self._blocks_needed(len(req["prompt"]),
-                                           req["max_new"]) - m
-                got = self._alloc_blocks(need)
-                if got is None:
-                    # Head blocked on arena space with a slot free: from
-                    # here until admission the wait is ARENA wait, not
-                    # queue wait — the TTFT decomposition splits there.
-                    if matched:
-                        self._prefix.release(matched)
-                    if meta is not None and "arena_blocked" not in meta:
-                        meta["arena_blocked"] = time.time()
-                    break
-                blocks = [nd.block for nd in matched] + got
-                suffix = req["prompt"][m * bs:]
-                padded_len = min(_bucket(len(suffix)),
-                                 padded_cap - m * bs)
-                padded_len = max(padded_len, bs)  # at least one block
-                if self._prefix is not None:
-                    self.prefix_hit_tokens += m * bs
-                    self.prefix_miss_tokens += len(suffix)
-                    if m:
-                        self.prefix_hit_requests += 1
-                        mdefs.CB_PREFIX_HIT_TOKENS.inc(m * bs,
-                                                       tags=self._mtags)
-                    mdefs.CB_PREFIX_MISS_TOKENS.inc(len(suffix),
-                                                    tags=self._mtags)
-            else:
-                padded_len = min(_bucket(len(req["prompt"])), padded_cap)
+            if self._prefix is not None:
+                chunks = self._req_chunks(req)
+                matched = self._prefix.match(
+                    chunks[:self._match_cap(req)])
+                # Bucket the match DOWN to a power of two so the
+                # compiled prefill program count stays log-bounded
+                # in m (see _bucket_floor); the released tail
+                # parks young in the LRU, still resident for the
+                # next matcher and evictable by _alloc_blocks.
+                m = _bucket_floor(len(matched))
+                if m < len(matched):
+                    self._prefix.release(matched[m:])
+                    matched = matched[:m]
+            need = self._blocks_needed(len(req["prompt"]),
+                                       req["max_new"]) - m
+            got = self._alloc_blocks(need)
+            if got is None:
+                # Head blocked on arena space with a slot free: from
+                # here until admission the wait is ARENA wait, not
+                # queue wait — the TTFT decomposition splits there.
+                if matched:
+                    self._prefix.release(matched)
+                if meta is not None and "arena_blocked" not in meta:
+                    meta["arena_blocked"] = time.time()
+                break
+            blocks = [nd.block for nd in matched] + got
+            suffix = req["prompt"][m * bs:]
+            padded_len = min(_bucket(len(suffix)),
+                             padded_cap - m * bs)
+            padded_len = max(padded_len, bs)  # at least one block
+            if self._prefix is not None:
+                self.prefix_hit_tokens += m * bs
+                self.prefix_miss_tokens += len(suffix)
+                if m:
+                    self.prefix_hit_requests += 1
+                    mdefs.CB_PREFIX_HIT_TOKENS.inc(m * bs,
+                                                   tags=self._mtags)
+                mdefs.CB_PREFIX_MISS_TOKENS.inc(len(suffix),
+                                                tags=self._mtags)
             self._waiting.popleft()
             if meta is not None:
                 meta["admit"] = time.time()
                 meta["blocks"] = len(blocks)
                 meta["prefix_tokens"] = m * bs
             slot = self._free.pop()
-            if self.paged:
-                self._slot_blocks[slot] = blocks
+            self._slot_blocks[slot] = blocks
             groups.setdefault((padded_len, m), []).append(
                 (req, slot, blocks, matched, suffix, chunks))
         for (padded_len, m), group in groups.items():
@@ -2270,42 +1996,33 @@ class ContinuousBatcher:
             # (Duplicated prefix gathers are reads — trivially safe.)
             n_pad = min(_bucket(n, floor=1), self.num_slots)
             tokens = np.zeros((n_pad, padded_len), np.int32)
-            slots = np.zeros(n_pad, np.int32)
             last_idx = np.zeros(n_pad, np.int32)
-            npb_w = padded_len // bs if self.paged else 0
+            npb_w = padded_len // bs
             tables_w = np.full((n_pad, npb_w), GARBAGE_BLOCK, np.int32)
             ptables = np.full((n_pad, m), GARBAGE_BLOCK, np.int32)
             for i in range(n_pad):
                 req, slot, blocks, matched, suffix, chunks = \
                     group[min(i, n - 1)]
                 tokens[i, :len(suffix)] = suffix
-                slots[i] = slot
                 last_idx[i] = len(suffix) - 1
-                if self.paged:
-                    # Suffix K/V land in the slot's NEW blocks (the
-                    # matched prefix is read-only); bucket-padding
-                    # overflow past the reservation writes masked
-                    # garbage to block 0.
-                    new_blocks = blocks[m:]
-                    k = min(len(new_blocks), npb_w)
-                    tables_w[i, :k] = new_blocks[:k]
-                    ptables[i, :m] = blocks[:m]
+                # Suffix K/V land in the slot's NEW blocks (the
+                # matched prefix is read-only); bucket-padding
+                # overflow past the reservation writes masked
+                # garbage to block 0.
+                new_blocks = blocks[m:]
+                k = min(len(new_blocks), npb_w)
+                tables_w[i, :k] = new_blocks[:k]
+                ptables[i, :m] = blocks[:m]
             pt0 = time.time()  # wall-clock anchor for the prefill span
             with tracing.phase("engine.prefill", mdefs.CB_PREFILL_MS,
                                self._mtags, outer=admit) as prefill:
                 with _annotation("engine.prefill.dispatch"):
                     pstep = self._place(np.int32(self._prefill_count))
                     self._prefill_count += 1
-                    if self.paged:
-                        first, self.cache = self._prefill(
-                            self.params, self._place(tokens), self.cache,
-                            self._place(ptables), self._place(tables_w),
-                            self._place(last_idx), pstep)
-                    else:
-                        first, self.cache = self._prefill(
-                            self.params, self._place(tokens), self.cache,
-                            self._place(slots), self._place(last_idx),
-                            pstep)
+                    first, self.cache = self._prefill(
+                        self.params, self._place(tokens), self.cache,
+                        self._place(ptables), self._place(tables_w),
+                        self._place(last_idx), pstep)
                 with _annotation("engine.prefill.fetch"):
                     first = np.asarray(first)    # N ints, one transfer
             # The fetch syncs the dispatch, so this interval is the real
@@ -2354,9 +2071,8 @@ class ContinuousBatcher:
                     # Reserved-but-speculative block head-room, reported
                     # by pressure_snapshot (router congestion must see
                     # it as occupied, not free).
-                    "la_blocks": (self._lookahead_blocks(
-                        len(req["prompt"]), req["max_new"])
-                        if self.paged else 0),
+                    "la_blocks": self._lookahead_blocks(
+                        len(req["prompt"]), req["max_new"]),
                 }
                 self._maybe_finish(slot)
                 if self.role == "prefill":
@@ -2427,14 +2143,13 @@ class ContinuousBatcher:
             # count: speculative ticks a rewind discarded replay the SAME
             # step numbers, so sampled decode reproduces exactly like greedy.
             self._d_step = self._place(np.int32(self._applied_steps))
-            if self.paged:
-                tables = np.zeros((self.num_slots, self.max_blocks), np.int32)
-                limits = np.zeros(self.num_slots, np.int32)
-                for slot, blocks in self._slot_blocks.items():
-                    tables[slot] = self._table_row(blocks)
-                    limits[slot] = len(blocks) * self.block_size
-                self._d_tables = self._place(tables)
-                self._d_limits = self._place(limits)
+            tables = np.zeros((self.num_slots, self.max_blocks), np.int32)
+            limits = np.zeros(self.num_slots, np.int32)
+            for slot, blocks in self._slot_blocks.items():
+                tables[slot] = self._table_row(blocks)
+                limits[slot] = len(blocks) * self.block_size
+            self._d_tables = self._place(tables)
+            self._d_limits = self._place(limits)
             self._dirty = False
 
     def _run_tick(self):
@@ -2444,7 +2159,7 @@ class ContinuousBatcher:
         k = 0 — spec off, or the accept-rate controller parked at the
         bottom rung — this dispatches the EXACT pre-spec ``cb_tick``
         program: same jit, same arguments, same device sequence."""
-        k = self._spec_cur_k if (self.spec_k and self.paged) else 0
+        k = self._spec_cur_k if self.spec_k else 0
         if k > 0:
             tick = self._get_spec_tick(k)
             if self._draft_cache is not None:
@@ -2462,19 +2177,12 @@ class ContinuousBatcher:
             self.spec_tick_count += 1
             self._last_tick_k = k
             return (committed, counts)
-        fetch = None
-        if self.paged:
-            # A routed model's tick has a fifth output: the row to fetch
-            # (tokens with the expert row counts packed behind them).
-            (self._d_tokens, self._d_positions, self.cache,
-             self._d_step, *fetch) = self._tick(
-                self.params, self._d_tokens, self._d_positions,
-                self._d_tables, self._d_limits, self.cache, self._d_step)
-        else:
-            (self._d_tokens, self._d_positions, self.cache,
-             self._d_step) = self._tick(
-                self.params, self._d_tokens, self._d_positions,
-                self.cache, self._d_step)
+        # A routed model's tick has a fifth output: the row to fetch
+        # (tokens with the expert row counts packed behind them).
+        (self._d_tokens, self._d_positions, self.cache,
+         self._d_step, *fetch) = self._tick(
+            self.params, self._d_tokens, self._d_positions,
+            self._d_tables, self._d_limits, self.cache, self._d_step)
         self.base_tick_count += 1
         self._last_tick_k = 0
         return fetch[0] if fetch else self._d_tokens
@@ -2650,10 +2358,9 @@ class ContinuousBatcher:
         tick rows: behind its ``num_slots`` tokens each carries the
         tick's per-layer, per-expert assignment counts ``[L, X]`` (every
         slot routes, live or not: the device computed them all). A dense
-        model's rows, a dense-cache tick's and a spec tick's carry
-        none."""
+        model's rows and a spec tick's carry none."""
         c = self.config
-        if not (c.num_experts and self.paged):
+        if not c.num_experts:
             return
         from ray_tpu._private import metrics_defs as mdefs
 
@@ -2678,16 +2385,13 @@ class ContinuousBatcher:
         mdefs.CB_WAITING_REQUESTS.set(len(self._waiting), tags=self._mtags)
         mdefs.CB_SLOT_OCCUPANCY.set(active / max(self.num_slots, 1),
                                     tags=self._mtags)
-        if self.paged:
-            kv = self.kv_block_stats()
-            mdefs.CB_KV_BLOCKS_USED.set(kv["used"], tags=self._mtags)
-            mdefs.CB_KV_BLOCKS_TOTAL.set(kv["total"], tags=self._mtags)
-            mdefs.CB_KV_FRAG_RATIO.set(kv["frag_ratio"], tags=self._mtags)
-            if self._prefix is not None:
-                mdefs.CB_KV_BLOCKS_CACHED.set(kv["cached"],
-                                              tags=self._mtags)
-                mdefs.CB_KV_BLOCKS_SHARED.set(kv["shared"],
-                                              tags=self._mtags)
+        kv = self.kv_block_stats()
+        mdefs.CB_KV_BLOCKS_USED.set(kv["used"], tags=self._mtags)
+        mdefs.CB_KV_BLOCKS_TOTAL.set(kv["total"], tags=self._mtags)
+        mdefs.CB_KV_FRAG_RATIO.set(kv["frag_ratio"], tags=self._mtags)
+        if self._prefix is not None:
+            mdefs.CB_KV_BLOCKS_CACHED.set(kv["cached"], tags=self._mtags)
+            mdefs.CB_KV_BLOCKS_SHARED.set(kv["shared"], tags=self._mtags)
         if self.spec_k:
             mdefs.CB_SPEC_ACCEPT_RATE.set(self.spec_accept_rate,
                                           tags=self._mtags)
@@ -2721,9 +2425,7 @@ class ContinuousBatcher:
                 # Per-tick sync: the fetch IS the device sync, so this is
                 # the honest tick latency (dispatch + compute + fetch) —
                 # also the denominator for the tick's achieved-FLOPs/
-                # bandwidth gauges. The bytes hint keeps achieved
-                # bandwidth priced off LIVE tokens, not the compiled
-                # worst case.
+                # bandwidth gauges.
                 with tracing.phase("engine.tick", mdefs.CB_TICK_MS,
                                    self._mtags) as tick:
                     with _annotation("engine.tick.dispatch"):
@@ -2737,12 +2439,9 @@ class ContinuousBatcher:
                         else:
                             nxt = np.asarray(nxt_dev)  # 4 bytes/slot
                 tick_wall = tick.ms / 1e3
-                # The dense program's own cost analysis is already
-                # accurate — including the kernel-off fp32 re-read
-                # traffic a hand estimate would miss — so only paged
-                # ticks get a hint. Spec ticks report against THEIR
-                # program (per-k instrumented jit) with the hint priced
-                # for k draft passes + the wider verify window.
+                # Spec ticks report against THEIR program (per-k
+                # instrumented jit) with the bytes hint priced for k
+                # draft passes + the wider verify window.
                 tick_fn = (self._spec_ticks[self._last_tick_k]
                            if self._last_tick_k else self._tick)
                 with tracing.phase("engine.account",
@@ -2851,8 +2550,8 @@ class ContinuousBatcher:
             # wall time since the last sync cover the ticks dispatched in
             # between, so window/ticks is the steady-state per-tick cost.
             # Feed it (with the live-byte hint) to the achieved-bandwidth
-            # gauges — without this the gauges would price the paged tick at
-            # the compiled worst case instead of live tokens. Spec
+            # gauges — without this the gauges would price the tick at the
+            # compiled worst case instead of live tokens. Spec
             # windows report against their per-k program with the hint
             # priced for the drafts + wider verify those ticks ran.
             now = time.perf_counter()

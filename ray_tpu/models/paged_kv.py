@@ -54,8 +54,8 @@ KV_DTYPES = ("bf16", "int8")
 
 
 def resolve_kv_dtype(kv_dtype: Optional[str]) -> str:
-    """Explicit arg > ``RAY_TPU_KV_DTYPE`` env > bf16 (storage parity
-    with the dense cache)."""
+    """Explicit arg > ``RAY_TPU_KV_DTYPE`` env > bf16 (the model's own
+    dtype: nothing is quantized)."""
     if kv_dtype is None:
         kv_dtype = os.environ.get("RAY_TPU_KV_DTYPE", "").strip().lower() \
             or "bf16"

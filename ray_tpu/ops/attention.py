@@ -30,7 +30,7 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.decode_attention import _interpret_default
+from ray_tpu.ops.dispatch import interpret_default
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -381,7 +381,7 @@ def flash_attention(
     if interpret is None:
         # RAY_TPU_PALLAS_INTERPRET overrides (the pallas_interpret test
         # fixture), else interpret everywhere but real TPU.
-        interpret = _interpret_default()
+        interpret = interpret_default()
 
     # Kernels use [B, H, S, D].
     qt = q.transpose(0, 2, 1, 3)

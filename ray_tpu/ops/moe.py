@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.decode_attention import _interpret_default
+from ray_tpu.ops.dispatch import interpret_default
 
 
 def router_topk(
@@ -286,7 +286,7 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
     which needs the layer's experts as an array of their own."""
     if (layer is None) != (rhs.ndim == 3):
         raise ValueError("stacked weights need `layer`; one layer's take none")
-    interpret = _interpret_default()
+    interpret = interpret_default()
     tiles = gmm_applicable(rhs.shape[-2], rhs.shape[-1],
                            jnp.dtype(rhs.dtype).itemsize)
     if use_kernel is None:

@@ -1,13 +1,13 @@
 """Paged decode attention: block-table gather over a shared KV arena.
 
-The dense fused kernel (``ops/decode_attention.py``) still streams each
-slot's full ``S_max`` stripe of the pooled cache per tick — a slot 40
-tokens into a 512-token cache pays for 512. Here the pooled cache is an
-ARENA of fixed-size blocks (``[num_blocks, KVH, block_size, D]``) and
-each slot owns a small BLOCK TABLE naming the blocks it has actually
-filled, so a tick reads only live prefix blocks (vLLM paged-attention,
-on TPU: block tables ride scalar prefetch so the BlockSpec ``index_map``
-can gather arena blocks by table lookup before the kernel body runs).
+A decode tick attends ONE query token per slot against that slot's
+cached prefix: pure HBM bandwidth. The cache is an ARENA of fixed-size
+blocks (``[num_blocks, KVH, block_size, D]``) and each slot owns a small
+BLOCK TABLE naming the blocks it has actually filled, so a tick reads
+only live prefix blocks — a slot 40 tokens into a 512-token reservation
+pays for one block, not 512 positions (vLLM paged-attention, on TPU:
+block tables ride scalar prefetch so the BlockSpec ``index_map`` can
+gather arena blocks by table lookup before the kernel body runs).
 The arena is HEADS-MAJOR inside a block: the TPU lowering takes a block
 only in whole trailing (sublane, lane) tiles, and ``(block_size, D)``
 tiles exactly for bf16 and int8 at any head count, where a trailing
@@ -38,9 +38,13 @@ Two bandwidth levers stack:
   dequantization happens in-register after the block is resident, so
   bytes-per-token roughly halve against bf16.
 
-Same online-softmax core as the dense kernel: fp32 accumulation with a
-running max/sum in VMEM scratch; per-slot positions arrive via scalar
-prefetch and set both the schedule and the in-block causal mask.
+The online-softmax core (:func:`_init_state` / :func:`_attend_block` /
+:func:`_finalize`): K and V stream through VMEM in their storage dtype
+with fp32 accumulation and a running max/sum in VMEM scratch; heads are
+the batch dim of the two MXU contractions and GQA keeps each head's
+query group ``[G, D]`` resident, so a block is read exactly once.
+Per-slot positions arrive via scalar prefetch and set both the schedule
+and the in-block causal mask.
 
 The engine hands both kernels the WHOLE arena ``[L, NB, KVH, bs, D]``
 with the layer as one more scalar-prefetch operand, and writes the
@@ -50,8 +54,10 @@ it has none to slice, relayout for a scatter, and copy back (on the
 v5e those four slab moves a layer for K and V each were 72% of a
 48-slot tick's device time).
 
-Dispatch mirrors ``decode_attention``: kernel on TPU when shapes tile,
-interpret mode when forced (CPU tier-1), XLA reference otherwise.
+Dispatch: kernel on TPU when shapes tile, interpret mode when forced
+(CPU tier-1; ``RAY_TPU_PALLAS_INTERPRET``), the XLA reference
+(:func:`paged_attention_reference` over :func:`decode_attention_reference`)
+otherwise.
 """
 
 from __future__ import annotations
@@ -63,10 +69,41 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.decode_attention import (_attend_block, _finalize,
-                                          _init_state, _interpret_default,
-                                          _scratch, pltpu)
+from ray_tpu.ops.dispatch import interpret_default
+
+# The reference masks with -1e30 (not -inf: fully-masked garbage rows in
+# inactive slots must softmax to finite values, not NaN). Kept identical
+# in the kernel so kernel-on/off greedy decode stays token-for-token
+# stable.
+MASK_VALUE = -1e30
+
+
+def decode_attention_reference(q, cache_k, cache_v, positions,
+                               scale: Optional[float] = None):
+    """Single-token attention with per-slot positions over a dense
+    per-slot context: what :func:`paged_attention_reference` attends
+    once it has gathered a slot's blocks, and what the external drafter
+    attends over its private cache.
+
+    q [B, H, D]; cache [B, S_max, KVH, D]; positions [B] (the absolute
+    position each slot's query occupies).
+    """
+    b, hq, d = q.shape
+    s_max, hkv = cache_k.shape[1], cache_k.shape[2]
+    scale = scale if scale is not None else d ** -0.5
+    group = hq // hkv
+    qg = q.reshape(b, hkv, group, d).astype(jnp.float32)
+    logits = jnp.einsum("bhgd,bkhd->bhgk", qg,
+                        cache_k.astype(jnp.float32)) * scale
+    slots = jnp.arange(s_max)
+    mask = positions[:, None] >= slots[None, :]             # [B, S_max]
+    logits = jnp.where(mask[:, None, None, :], logits, MASK_VALUE)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhgk,bkhd->bhgd", probs,
+                     cache_v.astype(jnp.float32))
+    return out.reshape(b, hq, d).astype(q.dtype)
 
 
 def dequantize_block(x, scale):
@@ -104,8 +141,6 @@ def paged_attention_reference(q, arena_k, arena_v, tables, positions,
     block; dead entries may repeat blocks — masked out by
     ``positions``); positions [B].
     """
-    from ray_tpu.ops.decode_attention import decode_attention_reference
-
     arena_k, arena_v, k_scale, v_scale = (
         _layer_slab(a, layer) for a in (arena_k, arena_v, k_scale, v_scale))
     ck = gather_kv(arena_k, tables)
@@ -120,6 +155,61 @@ def paged_attention_reference(q, arena_k, arena_v, tables, positions,
 # ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
+
+def _init_state(acc_ref, m_ref, l_ref):
+    m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _attend_block(q, k, v, pos, first_col, acc_ref, m_ref, l_ref, *, scale,
+                  k_scale=None, v_scale=None):
+    """One online-softmax step over a K/V block, all kv heads at once.
+
+    q [KVH, G, D]; k/v [KVH, T, D] in storage dtype (upcast here);
+    ``pos`` the slot's absolute query position, ``first_col`` the
+    absolute position of the block's first key. ``k_scale``/``v_scale``
+    [KVH, T] dequantize an int8 block: they scale the score and
+    probability COLUMNS (keys ride the lane axis of both), which equals
+    scaling K/V rows without relayouting the scales onto sublanes."""
+    q = q.astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale          # [KVH, G, T]
+    if k_scale is not None:
+        s = s * k_scale[:, None, :]
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    s = jnp.where(pos >= first_col + cols, s, MASK_VALUE)
+
+    m_prev = m_ref[:, :, :1]                                 # [KVH, G, 1]
+    l_prev = l_ref[:, :, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)                                   # [KVH, G, T]
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    if v_scale is not None:
+        p = p * v_scale[:, None, :]
+    pv = jax.lax.dot_general(
+        p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)                  # [KVH, G, D]
+    acc_ref[:] = acc_ref[:] * alpha + pv
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _finalize(o_ref, acc_ref, l_ref):
+    l = l_ref[:, :, :1]
+    # Position 0 is always live, so l > 0 for every real slot; guard
+    # anyway so padded grid rows emit zeros rather than NaN.
+    safe_l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+
+
+def _scratch(hkv: int, group: int, d: int):
+    return [pltpu.VMEM((hkv, group, d), jnp.float32),
+            pltpu.VMEM((hkv, group, 128), jnp.float32),
+            pltpu.VMEM((hkv, group, 128), jnp.float32)]
+
 
 def _layer_operand(layer):
     """The layer index as the rank-1 int32 array scalar prefetch takes."""
@@ -319,7 +409,7 @@ def paged_kv_write(arena, new, layer, block_idx, offset):
         out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
         # Operand 4 counts the three scalar-prefetch arrays and ``new``.
         input_output_aliases={4: 0},
-        interpret=_interpret_default(),
+        interpret=interpret_default(),
         name="paged_kv_write",
         cost_estimate=pl.CostEstimate(
             flops=0, transcendentals=0,
@@ -390,7 +480,7 @@ def paged_decode_attention(
                                          positions, scale, layer=layer,
                                          k_scale=k_scale, v_scale=v_scale)
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     if layer is None:
         # A slab is an arena of one layer (a leading unit axis is free).
         layer = 0

@@ -1935,9 +1935,12 @@ def _deploy_application(controller, app: Application,
         if sig is not None and var_kw is None:
             unknown = set(dep.init_kwargs) - set(sig.parameters)
             if unknown:
+                removed = getattr(dep._cls_or_fn, "removed_init_kwargs", {})
                 raise ValueError(
                     f"init_kwargs {sorted(unknown)} not accepted by "
-                    f"{dep.name}'s constructor")
+                    f"{dep.name}'s constructor" + "".join(
+                        f"; {removed[k]}" for k in sorted(unknown)
+                        if k in removed))
         try:
             bound = sig.bind_partial(*args, **kwargs)
             for key, value in dep.init_kwargs.items():

@@ -76,7 +76,7 @@ def pallas_interpret(monkeypatch):
     """Force pallas kernels into interpret mode so TPU kernel tests run
     under tier-1 (``JAX_PLATFORMS=cpu``) without TPU-only skips.
 
-    The ops dispatchers (``ops/decode_attention.py``) resolve
+    The ops dispatchers (``ops/dispatch.py::interpret_default``) resolve
     ``interpret=None`` via ``RAY_TPU_PALLAS_INTERPRET`` before falling
     back to backend detection, so this works on CPU (where it is also
     the backend default) AND pins interpret mode on a TPU host — kernel

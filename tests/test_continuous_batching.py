@@ -350,33 +350,26 @@ def test_bf16_lm_head_argmax_parity():
 
 # ------------------------------------------------- paged KV + sampling
 
-def test_paged_on_off_bit_identical(setup):
-    """The paged arena data plane (block tables, arena scatter, paged
-    attention) produces token-for-token identical greedy output to the
-    dense pooled cache, and to the sequential generator — across block
-    sizes and with slot churn."""
+@pytest.mark.parametrize("block_size", [32, 64])
+def test_block_sizes_match_the_generator(setup, block_size):
+    """The arena data plane (block tables, arena scatter, paged
+    attention) produces token-for-token the sequential generator's
+    greedy output at either block size, with slot churn."""
     config, gen, _ = setup
     rng = np.random.default_rng(21)
     reqs = [(list(rng.integers(1, 250, size=n)), m)
             for n, m in [(5, 7), (33, 4), (17, 9), (9, 3), (40, 6)]]
-    results = {}
-    for key, kwargs in {"dense": dict(paged=False),
-                        "paged32": dict(paged=True, block_size=32),
-                        "paged64": dict(paged=True, block_size=64)}.items():
-        eng = ContinuousBatcher(config, params=gen.params, num_slots=3,
-                                max_len=128, **kwargs)
-        assert eng.paged is kwargs["paged"]
-        rids = [eng.submit(p, max_new_tokens=m) for p, m in reqs]
-        out = eng.run_to_completion()
-        results[key] = [out[r] for r in rids]
-    assert results["dense"] == results["paged32"] == results["paged64"]
-    for (prompt, m), toks in zip(reqs, results["dense"]):
-        assert toks == _reference(gen, prompt, m)
+    eng = ContinuousBatcher(config, params=gen.params, num_slots=3,
+                            max_len=128, block_size=block_size)
+    rids = [eng.submit(p, max_new_tokens=m) for p, m in reqs]
+    out = eng.run_to_completion()
+    for (prompt, m), rid in zip(reqs, rids):
+        assert out[rid] == _reference(gen, prompt, m)
 
 
 def test_paged_kernel_engine_parity(setup, pallas_interpret):
-    """Paged engine with the fused paged kernel (interpret mode on CPU)
-    == paged reference == dense engine, greedy."""
+    """The engine with the fused paged kernels (interpret mode on CPU)
+    == the engine on the XLA path == the generator, greedy."""
     config, gen, _ = setup
     rng = np.random.default_rng(22)
     reqs = [(list(rng.integers(1, 250, size=n)), m)
@@ -384,7 +377,7 @@ def test_paged_kernel_engine_parity(setup, pallas_interpret):
     results = {}
     for uk in (False, True):
         eng = ContinuousBatcher(config, params=gen.params, num_slots=2,
-                                max_len=128, paged=True, block_size=32,
+                                max_len=128, block_size=32,
                                 use_decode_kernel=uk)
         rids = [eng.submit(p, max_new_tokens=m) for p, m in reqs]
         out = eng.run_to_completion()
@@ -403,7 +396,7 @@ def test_tick_books_the_share_of_table_entries_it_visits(setup):
 
     config, gen, _ = setup
     eng = ContinuousBatcher(config, params=gen.params, num_slots=4,
-                            max_len=128, paged=True, block_size=32)
+                            max_len=128, block_size=32)
 
     def read():
         """(sum, count) over every engine's label set."""
@@ -441,7 +434,7 @@ def test_live_rows_never_share_a_write_block(setup, pallas_interpret):
 
     def run(use_kernel):
         eng = ContinuousBatcher(config, params=gen.params, num_slots=3,
-                                max_len=128, paged=True, block_size=bs,
+                                max_len=128, block_size=bs,
                                 use_decode_kernel=use_kernel)
         run_tick = eng._run_tick
 
@@ -484,7 +477,7 @@ def test_paged_int8_generates_plausibly(setup):
     config, gen, _ = setup
     rng = np.random.default_rng(23)
     eng = ContinuousBatcher(config, params=gen.params, num_slots=2,
-                            max_len=128, paged=True, block_size=32,
+                            max_len=128, block_size=32,
                             kv_dtype="int8")
     assert eng.cache.quantized
     reqs = [(list(rng.integers(1, 250, size=n)), m)
@@ -508,7 +501,7 @@ def test_paged_block_accounting_and_arena_exhaustion(setup):
     # arithmetic (with it on, finished prompts park blocks in the radix
     # LRU instead of freeing them — covered by test_prefix_cache.py).
     eng = ContinuousBatcher(config, params=gen.params, num_slots=3,
-                            max_len=128, paged=True, block_size=16,
+                            max_len=128, block_size=16,
                             num_blocks=7, prefix_cache=False)
     r1 = eng.submit(list(range(1, 30)), max_new_tokens=3)   # 2 blocks
     r2 = eng.submit(list(range(1, 40)), max_new_tokens=25)  # 4 blocks
@@ -538,7 +531,7 @@ def test_paged_buffered_arena_wait_keeps_pipelining(setup):
     until blocks free, then admit and finish the waiter."""
     config, gen, _ = setup
     eng = ContinuousBatcher(config, params=gen.params, num_slots=3,
-                            max_len=128, paged=True, block_size=16,
+                            max_len=128, block_size=16,
                             num_blocks=5, sync_every=4)
     r1 = eng.submit(list(range(1, 40)), max_new_tokens=20)  # 4 blocks
     r2 = eng.submit([1, 2, 3], max_new_tokens=3)            # waits: 0 free
@@ -587,6 +580,107 @@ def test_paged_overrun_write_lands_in_garbage_block():
     assert not np.all(np.asarray(new_cache.k[:, 2])[:, 1] == 7.7)
 
 
+_FORWARD_MODELS = {
+    "dense": dict(),
+    "routed": dict(num_experts=8, num_experts_per_tok=2, qk_norm=True,
+                   intermediate_size=32, num_kv_heads=4),
+}
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla", "kernels_interpreted"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("model", list(_FORWARD_MODELS))
+def test_one_forward_serves_tick_draft_and_verify(pallas_interpret, model,
+                                                  kv_dtype, use_kernel):
+    """``_forward_paged`` is the tick (a window of 1), the verify pass
+    (k+1) and the self-draft (1, the first layers) at once, so: position
+    j of a k+1 window gives the logits and leaves the arena bytes that
+    j+1 successive windows of 1 do, and ``n_layers=n`` writes exactly
+    layers [0:n) of what the full forward writes and leaves the rest
+    alone. Slot 0 straddles a block boundary, slot 2 overruns its
+    reservation into the garbage block, slot 3 is freed."""
+    import jax
+
+    from ray_tpu.models.continuous_batching import _forward_paged
+    from ray_tpu.models.paged_kv import GARBAGE_BLOCK, PagedKVCache
+
+    cfg = llama.LlamaConfig.tiny(dtype=jnp.float32,
+                                 **_FORWARD_MODELS[model])
+    params = llama.init_params(cfg, jax.random.PRNGKey(1))
+    bs, k = 16, 3
+    rng = np.random.default_rng(5)
+    # A resident context: every block holds bytes of its own.
+    cache = PagedKVCache.create(cfg, num_blocks=12, block_size=bs,
+                                kv_dtype=kv_dtype)
+    if kv_dtype == "int8":
+        cache = PagedKVCache(
+            *(jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+              for a in (cache.k, cache.v)),
+            *(jnp.asarray(rng.uniform(0.001, 0.02, a.shape), jnp.float32)
+              for a in (cache.k_scale, cache.v_scale)))
+    else:
+        cache = PagedKVCache(*(
+            jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+            for a in (cache.k, cache.v)))
+    tables = jnp.asarray([[1, 2, 3, 3], [4, 5, 5, 5], [6, 7, 7, 7],
+                          [GARBAGE_BLOCK] * 4], jnp.int32)
+    limits = jnp.asarray([48, 32, 32, 0], jnp.int32)
+    first = jnp.asarray([14, 3, 30, 0], jnp.int32)
+    tokens = jnp.asarray(rng.integers(1, 250, (4, k + 1)), jnp.int32)
+    positions = first[:, None] + jnp.arange(k + 1)[None, :]
+    forward = jax.jit(_forward_paged, static_argnums=(6, 7, 8))
+
+    def live(c):
+        """Every arena array less the garbage block, which holds
+        whichever overrun or freed row landed last."""
+        return [np.asarray(a)[:, 1:] for a in c if a is not None]
+
+    window_logits, window_cache, _ = forward(
+        params, tokens, positions, tables, limits, cache, cfg, use_kernel,
+        None)
+    stepped = cache
+    for j in range(k + 1):
+        logits, stepped, rows = forward(
+            params, tokens[:, j:j + 1], positions[:, j:j + 1], tables,
+            limits, stepped, cfg, use_kernel, None)
+        assert (rows is None) == (model == "dense")
+        np.testing.assert_array_equal(np.asarray(window_logits[:3, j]),
+                                      np.asarray(logits[:3, 0]))
+    for got, want in zip(live(window_cache), live(stepped)):
+        np.testing.assert_array_equal(got, want)
+    # The writes landed: slot 0's window crosses from block 1 to 2.
+    assert not np.array_equal(live(window_cache)[0][:, 0:2],
+                              live(cache)[0][:, 0:2])
+
+    n = 1
+    _, drafted, _ = forward(params, tokens[:, :1], positions[:, :1], tables,
+                            limits, cache, cfg, use_kernel, n)
+    _, full, _ = forward(params, tokens[:, :1], positions[:, :1], tables,
+                         limits, cache, cfg, use_kernel, None)
+    for got, whole, before in zip(live(drafted), live(full), live(cache)):
+        np.testing.assert_array_equal(got[:n], whole[:n])
+        np.testing.assert_array_equal(got[n:], before[n:])
+
+
+def test_removed_engine_switches_are_refused(setup):
+    """PR 27 removed the dense KV plane and its ``paged`` switch: an old
+    script's ``ContinuousBatcher(paged=...)`` fails naming the argument,
+    and a serve config whose ``init_kwargs`` still carries it is refused
+    when it deploys, with the removal named, not by a ``TypeError``
+    inside a replica."""
+    from ray_tpu.llm import ContinuousLlamaDeployment
+    from ray_tpu.serve.api import _deploy_application
+
+    config, gen, _ = setup
+    with pytest.raises(TypeError, match="paged"):
+        ContinuousBatcher(config, params=gen.params, paged=False)
+    app = ContinuousLlamaDeployment.options(
+        init_kwargs={"paged": False, "num_slots": 4}).bind(config=config)
+    with pytest.raises(ValueError, match="removed in PR 27"):
+        _deploy_application(None, app, {})
+
+
 def test_paged_buffered_overrun_heavy_parity(setup):
     """sync_every>1 with requests whose reservations the device overruns
     during speculation (finish detection lags 2K ticks): outputs stay
@@ -598,7 +692,7 @@ def test_paged_buffered_overrun_heavy_parity(setup):
     outs = {}
     for k in (1, 8):
         eng = ContinuousBatcher(config, params=gen.params, num_slots=3,
-                                max_len=64, paged=True, block_size=16,
+                                max_len=64, block_size=16,
                                 sync_every=k)
         ra = eng.submit(pa, max_new_tokens=26)
         rc = eng.submit(pc, max_new_tokens=20)
@@ -615,10 +709,10 @@ def test_paged_rejects_non_pow2_block_size():
     config = llama.LlamaConfig.tiny(dtype=jnp.float32)
     with pytest.raises(ValueError, match="power of two"):
         ContinuousBatcher(config, num_slots=2, max_len=128,
-                          paged=True, block_size=96)
+                          block_size=96)
     with pytest.raises(ValueError, match="power of two"):
         ContinuousBatcher(config, num_slots=2, max_len=128,
-                          paged=True, block_size=4)
+                          block_size=4)
 
 
 def test_sampling_deterministic_and_distinct():

@@ -40,8 +40,7 @@ def _engine(config, params, role, **kw):
     kw.setdefault("max_len", 128)
     kw.setdefault("block_size", 16)
     kw.setdefault("num_blocks", 64)
-    return ContinuousBatcher(config, params=params, paged=True,
-                             role=role, **kw)
+    return ContinuousBatcher(config, params=params, role=role, **kw)
 
 
 def _park(pre, prompt, max_new):
@@ -368,7 +367,7 @@ def disagg_app(setup):
     config, _ = setup
     deploy_disagg_llama("dllm", config=config, num_prefill=2,
                         num_decode=2, num_slots=4, max_len=128,
-                        paged=True, block_size=16, num_blocks=64,
+                        block_size=16, num_blocks=64,
                         prefix_cache=True)
     port = serve.start_http(port=0)
     yield port

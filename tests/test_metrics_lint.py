@@ -556,8 +556,8 @@ def test_framework_jits_go_through_the_instrumented_wrapper():
 
 
 def test_engine_tick_and_prefill_entry_points_are_instrumented():
-    """The continuous-batching hot-loop entry points (tick + prefill,
-    paged AND dense) must stay under ``xla_monitor.instrument`` — their
+    """The continuous-batching hot-loop entry points (tick + prefill)
+    must stay under ``xla_monitor.instrument`` — their
     compiles, retraces, and cost analyses feed the decode-roofline
     regression harness, so an accidental downgrade to a raw jit is a
     silent observability hole."""
@@ -568,10 +568,9 @@ def test_engine_tick_and_prefill_entry_points_are_instrumented():
     from ray_tpu.models.continuous_batching import ContinuousBatcher
 
     cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
-    for paged in (True, False):
-        eng = ContinuousBatcher(cfg, num_slots=2, max_len=64, paged=paged)
-        assert isinstance(eng._tick, InstrumentedJit), paged
-        assert isinstance(eng._prefill, InstrumentedJit), paged
+    eng = ContinuousBatcher(cfg, num_slots=2, max_len=64)
+    assert isinstance(eng._tick, InstrumentedJit)
+    assert isinstance(eng._prefill, InstrumentedJit)
 
 
 def test_pool_and_autoscaler_series_are_cataloged():

@@ -157,8 +157,8 @@ def test_forward_matches_reference_and_routes_agree(model):
 
 
 @pytest.mark.parametrize("engine", [
-    dict(paged=True), dict(paged=True, use_decode_kernel=True),
-    dict(paged=False)], ids=["paged", "paged_kernels_interpreted", "dense"])
+    dict(), dict(use_decode_kernel=True)],
+    ids=["paged", "paged_kernels_interpreted"])
 def test_engine_tokens_are_the_references_argmax(model, engine):
     """Prefill, then decode through the cache: every token the float32
     engine chose is the reference's own argmax (gap 0), or lies within

@@ -14,8 +14,8 @@ import pytest
 from ray_tpu.models.paged_kv import (GARBAGE_BLOCK, BlockAllocator,
                                      PagedKVCache, quantize_kv,
                                      resolve_kv_dtype)
-from ray_tpu.ops.decode_attention import decode_attention_reference
-from ray_tpu.ops.paged_decode_attention import (paged_applicable,
+from ray_tpu.ops.paged_decode_attention import (decode_attention_reference,
+                                                paged_applicable,
                                                 paged_attention_reference,
                                                 paged_decode_attention,
                                                 paged_kv_write, paged_visits)
@@ -300,6 +300,69 @@ def test_layer_indexed_read_equals_slab_call(pallas_interpret, use_kernel,
     slab = paged_decode_attention(q, ak, av, tables, pos,
                                   use_kernel=use_kernel, **slabs)
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(slab))
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_kernel_under_layer_scan(pallas_interpret, kv_dtype):
+    """The engine's calling convention: inside ``jit(lax.scan)`` over
+    layers, the whole arena in the carry, the layer index a traced
+    scalar of the carry, the write aliased onto the carry and the read
+    after it. Each layer's output is the XLA reference's on that
+    layer's slab with the same rows scattered in, and the arena that
+    comes out holds them."""
+    from ray_tpu.models.continuous_batching import _scatter_arena
+
+    q, _, _, ak, av, tables, _ = _paged_inputs(seed=5, dtype=jnp.bfloat16)
+    pos = jnp.asarray([0, 63, 127], jnp.int32)
+    bs = ak.shape[2]
+    block_idx = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)
+    offset = (pos % bs)[:, None]
+    layers = 3
+    # Layer i holds bytes of its own, so a read or a write of the wrong
+    # layer cannot pass.
+    arenas = [jnp.stack([jnp.roll(a, i, axis=0) for i in range(layers)])
+              for a in (ak, av)]
+    rows = jax.random.normal(
+        jax.random.PRNGKey(9), (layers, 2, q.shape[0], 1) + ak.shape[1:2]
+        + ak.shape[3:], jnp.float32).astype(jnp.bfloat16)
+    news = [rows[:, 0], rows[:, 1]]
+    if kv_dtype == "int8":
+        # (k, v) -> (k, v, k_scale, v_scale), the arena's own order.
+        arenas, news = (
+            [x[i] for i in (0, 1) for x in map(quantize_kv, pair)]
+            for pair in (arenas, news))
+
+    @jax.jit
+    def run(arenas, news):
+        def body(carry, new):
+            arenas, li = carry
+            arenas = tuple(paged_kv_write(a, n, li, block_idx, offset)
+                           for a, n in zip(arenas, new))
+            out = paged_decode_attention(
+                q, arenas[0], arenas[1], tables, pos, layer=li,
+                use_kernel=True,
+                **(dict(k_scale=arenas[2], v_scale=arenas[3])
+                   if len(arenas) == 4 else {}))
+            return (arenas, li + 1), out
+        (arenas, _), outs = jax.lax.scan(
+            body, (tuple(arenas), jnp.int32(0)), tuple(news))
+        return arenas, outs
+
+    got_arenas, outs = run(arenas, news)
+    for li in range(layers):
+        slabs = [_scatter_arena(a[li], n[li][:, 0], block_idx[:, 0],
+                                offset[:, 0])
+                 for a, n in zip(arenas, news)]
+        for got, want in zip(got_arenas, slabs):
+            np.testing.assert_array_equal(np.asarray(got[li]),
+                                          np.asarray(want))
+        want = paged_attention_reference(
+            q, slabs[0], slabs[1], tables, pos,
+            **(dict(k_scale=slabs[2], v_scale=slabs[3])
+               if len(slabs) == 4 else {}))
+        np.testing.assert_allclose(
+            np.asarray(outs[li], np.float32), np.asarray(want, np.float32),
+            atol=2e-2)
 
 
 def test_layer_argument_must_match_arena_rank():

@@ -44,7 +44,6 @@ def _reference(gen, prompt, n):
 def _engine(config, gen, **kwargs):
     kwargs.setdefault("num_slots", 3)
     kwargs.setdefault("max_len", 128)
-    kwargs.setdefault("paged", True)
     kwargs.setdefault("block_size", BS)
     return ContinuousBatcher(config, params=gen.params, **kwargs)
 

@@ -130,8 +130,8 @@ def test_arena_wait_is_attributed_separately(span_capture):
     routing needs."""
     cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
     # Arena sized so ONE request's reservation fits but two don't.
-    eng = ContinuousBatcher(cfg, num_slots=2, max_len=64, paged=True,
-                            block_size=16, num_blocks=3)
+    eng = ContinuousBatcher(cfg, num_slots=2, max_len=64, block_size=16,
+                            num_blocks=3)
     r1 = eng.submit([1, 2, 3], max_new_tokens=20, trace=_trace(
         request_id="req-a", trace_id="a" * 16))
     r2 = eng.submit([4, 5, 6], max_new_tokens=20, trace=_trace(
@@ -190,8 +190,7 @@ def test_engine_phases_cover_the_steps_wall_time():
               mdefs.CB_STEP_UPLOAD_MS, mdefs.CB_TICK_MS,
               mdefs.CB_STEP_ACCOUNT_MS, mdefs.CB_STEP_APPLY_MS)
     cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
-    eng = ContinuousBatcher(cfg, num_slots=4, max_len=64, paged=True,
-                            block_size=16)
+    eng = ContinuousBatcher(cfg, num_slots=4, max_len=64, block_size=16)
     for i in range(4):                       # compile outside the clock
         eng.submit([1, 2, 3, i + 1], max_new_tokens=4)
     eng.run_to_completion()
@@ -283,8 +282,7 @@ def test_pressure_snapshot_and_replica_probe():
     serve Replica wrapper merges a hosted deployment's pressure() into
     its probe reply."""
     cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
-    eng = ContinuousBatcher(cfg, num_slots=1, max_len=64, paged=True,
-                            block_size=16)
+    eng = ContinuousBatcher(cfg, num_slots=1, max_len=64, block_size=16)
     eng.submit([1, 2, 3], max_new_tokens=4)
     eng.submit([1, 2, 3], max_new_tokens=4)  # second waits: 1 slot
     eng.step()

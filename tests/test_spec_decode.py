@@ -36,7 +36,7 @@ def _reference(gen, prompt, n):
 
 def _run(config, params, reqs, **kw):
     eng = ContinuousBatcher(config, params=params, num_slots=4,
-                            max_len=128, paged=True, **kw)
+                            max_len=128, **kw)
     rids = [eng.submit(list(p), max_new_tokens=m) for p, m in reqs]
     out = eng.run_to_completion()
     return [out[r] for r in rids], eng
@@ -238,8 +238,7 @@ def test_sampled_spec_deterministic_and_rewind_replay(setup):
 
 def test_spec_k0_is_exactly_the_old_path(setup):
     """spec_k=0 never builds a spec program: the engine dispatches the
-    plain cb_tick only, and a spec request on the dense plane is a
-    config error (the rewind substrate is the paged arena)."""
+    plain cb_tick only."""
     config, gen = setup
     rng = np.random.default_rng(44)
     reqs = [(list(rng.integers(1, 250, size=5)), 6)]
@@ -248,18 +247,15 @@ def test_spec_k0_is_exactly_the_old_path(setup):
     assert eng.spec_tick_count == 0 and not eng._spec_ticks
     assert eng.base_tick_count > 0
     assert eng.drafter is None
-    with pytest.raises(ValueError, match="paged"):
-        ContinuousBatcher(config, params=gen.params, num_slots=2,
-                          max_len=128, paged=False, spec_k=2)
     with pytest.raises(ValueError, match="spec_k"):
         ContinuousBatcher(config, params=gen.params, num_slots=2,
-                          max_len=128, paged=True, spec_k=-1)
+                          max_len=128, spec_k=-1)
     with pytest.raises(ValueError, match="vocab"):
         small = llama.LlamaConfig.tiny(dtype=jnp.float32)
         import dataclasses
         bad = dataclasses.replace(small, vocab_size=small.vocab_size * 2)
         ContinuousBatcher(config, params=gen.params, num_slots=2,
-                          max_len=128, paged=True, spec_k=2,
+                          max_len=128, spec_k=2,
                           drafter=ExternalLlamaDrafter(bad))
 
 
@@ -298,7 +294,7 @@ def test_adaptive_k_probe_reenters_after_park(setup, monkeypatch):
     rng = np.random.default_rng(46)
     prompt = list(rng.integers(1, 250, size=5))
     eng = ContinuousBatcher(config, params=gen.params, num_slots=2,
-                            max_len=128, paged=True, spec_k=2,
+                            max_len=128, spec_k=2,
                             spec_adaptive=True,
                             drafter=SelfDrafter(1))
     eng._spec_cur_k = 0  # as if the ladder bottomed out
@@ -316,7 +312,7 @@ def test_lookahead_blocks_reserved_and_reported(setup):
     outstanding look-ahead so routers don't see phantom free arena."""
     config, gen = setup
     eng = ContinuousBatcher(config, params=gen.params, num_slots=2,
-                            max_len=64, paged=True, block_size=8,
+                            max_len=64, block_size=8,
                             spec_k=4, spec_adaptive=False,
                             spec_draft_layers=1, prefix_cache=False)
     # ceil((5 + 10 + 4)/8) = 3 blocks; without look-ahead it would be 2.
@@ -333,7 +329,7 @@ def test_lookahead_blocks_reserved_and_reported(setup):
     # Spec-off engines reserve WITHOUT the look-ahead (same math as the
     # seed) and report zero.
     eng0 = ContinuousBatcher(config, params=gen.params, num_slots=2,
-                             max_len=64, paged=True, block_size=8)
+                             max_len=64, block_size=8)
     assert eng0._blocks_needed(5, 10) == 2
     assert eng0.pressure_snapshot()["kv_blocks_spec_lookahead"] == 0
     assert rid is not None

@@ -8,10 +8,10 @@ can only be interpreted passes every CPU test and is refused on the chip.
 host in milliseconds; ``RAY_TPU_PALLAS_INTERPRET=0`` makes the
 dispatchers emit the real kernel. (Lowering is not compiling: Mosaic's
 own checks and VMEM limits need the chipless AOT compile described in
-README "Development", or the chip.) The last test here IS such a
-compile: the paged decode tick for a described v5e, whose HLO must hold
-no copy of a layer's arena slab. Run it before asking for chip time on
-the tick.
+README "Development", or the chip.) The last tests here ARE such
+compiles: the decode tick and the speculative tick for a described v5e,
+whose HLO must hold no copy of a layer's arena slab. Run them before
+asking for chip time on the engine's forward.
 """
 
 import functools
@@ -24,7 +24,6 @@ import pytest
 from ray_tpu.models import llama
 from ray_tpu.models.training import ShardedTrainer
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.decode_attention import decode_attention
 from ray_tpu.ops.paged_decode_attention import (paged_decode_attention,
                                                 paged_kv_write)
 from ray_tpu.parallel import MeshConfig, make_mesh
@@ -59,14 +58,6 @@ def test_flash_forward_and_gradient_lower(hq, hkv):
 
     # forward (for residuals) + dq + dk/dv
     assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
-
-
-@pytest.mark.parametrize("hq,hkv", HEADS)
-def test_dense_decode_lowers(hq, hkv):
-    q = S((32, hq, 128), BF16)
-    cache = S((32, 512, hkv, 128), BF16)
-    fn = functools.partial(decode_attention, use_kernel=True)
-    assert _mosaic_calls(fn, q, cache, cache, S((32,), jnp.int32)) == 1
 
 
 @pytest.mark.parametrize("hq,hkv", HEADS)
@@ -153,9 +144,10 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def compile_paged_tick(sharding, kv_dtype):
+def compile_paged_tick(sharding, kv_dtype, spec_k=0):
     """AOT-compile the paged decode tick at the sizes above for
-    ``sharding``'s chip."""
+    ``sharding``'s chip; with ``spec_k``, the speculative tick (that
+    many one-layer self-drafts, then a verify window of ``spec_k + 1``)."""
     from ray_tpu.models import continuous_batching as cb
     from ray_tpu.models.paged_kv import PagedKVCache
 
@@ -177,9 +169,15 @@ def compile_paged_tick(sharding, kv_dtype):
     tables = S((_TICK_SLOTS, _TICK_LEN // _TICK_BS), jnp.int32,
                sharding=sharding)
     step = S((), jnp.int32, sharding=sharding)
-    tick = functools.partial(cb._decode_tick_paged, config=cfg,
-                             use_kernel=True)
-    # Argument order and donation as the engine's ``cb_tick``.
+    if spec_k:
+        tick = functools.partial(
+            cb._spec_tick_paged, config=cfg, k=spec_k, n_draft=1,
+            use_kernel=True, sampling=cb.SamplingParams())
+    else:
+        tick = functools.partial(cb._decode_tick_paged, config=cfg,
+                                 use_kernel=True)
+    # Argument order and donation as the engine's ``cb_tick`` and
+    # ``cb_spec_tick``.
     return jax.jit(tick, donate_argnums=(5,)).lower(
         params, row, row, tables, row, cache, step).compile()
 
@@ -231,6 +229,21 @@ def test_compiled_paged_tick_moves_no_arena_slab(v5e_chip, kv_dtype):
     assert [line.strip() for line in body.splitlines()
             if f"= s32[{visits}]" in line
             and " get-tuple-element(" not in line] == []
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_compiled_spec_tick_moves_no_arena_slab(v5e_chip, kv_dtype):
+    """The speculative tick runs the decode tick's own forward at other
+    widths (four one-layer drafts of a window of 1, then a verify window
+    of 5), so the tick's guard holds for it too: the arena is touched by
+    the two kernels alone, the verify layer writing once per arena and
+    attending once per window position."""
+    hlo = compile_paged_tick(v5e_chip, kv_dtype, spec_k=4).as_text()
+    assert arena_moves(hlo, ",128") == []
+    assert arena_moves(hlo[:hlo.index("\nENTRY ")], "") == []
+    arenas = 4 if kv_dtype == "int8" else 2
+    assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == 5 * arenas
+    assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 4 + 5
 
 
 # --------------------------------------------- the routed block (OLMoE)
