@@ -407,11 +407,18 @@ CB_PAGED_LIVE_BLOCK_SHARE = Histogram(
     "see, over num_slots x max_blocks; the paged attention kernel "
     "visits those entries and no others",
     boundaries=_SHARE_BOUNDS, tag_keys=("engine",))
+CB_TICK_OVERLAPPED = Counter(
+    "ray_tpu_cb_tick_overlapped_total",
+    "Decode ticks dispatched while another tick was still in flight: "
+    "the device went into them without waiting for the host",
+    ("engine",))
 CB_TICK_MS = Histogram(
     "ray_tpu_cb_tick_ms",
-    "Wall milliseconds per decode tick (dispatch+compute+fetch with "
-    "per-tick sync; dispatch only when speculative buffering overlaps "
-    "the fetch)",
+    "Wall milliseconds per decode tick, one observation a tick: the "
+    "time between two consecutive token rows reaching the host (from "
+    "the dispatch where the device was idle or prefilling before it); "
+    "dispatch only when speculative buffering (sync_every > 1) "
+    "overlaps the fetch",
     boundaries=(0.5, 1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0,
                 500.0, 1000.0),
     tag_keys=("engine",))

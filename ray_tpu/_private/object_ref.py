@@ -229,6 +229,18 @@ class ObjectRefGenerator:
                     f"{self._task_id.hex()[:16]} did not arrive in "
                     f"{timeout}s")
 
+    def ready(self) -> bool:
+        """Whether the next item is already stored, so that ``next()``
+        returns its ref without waiting. Never blocks."""
+        from ray_tpu._private import worker as _worker
+
+        ref = ObjectRef(
+            ObjectID.from_task(self._task_id, STREAM_INDEX_BASE + self._i),
+            owner_address=self._owner_address, skip_ref_count=True)
+        ready, _ = _worker.global_worker().core.wait(
+            [ref], num_returns=1, timeout=0, fetch_local=True)
+        return bool(ready)
+
     def completed(self) -> ObjectRef:
         """Ref resolving when the whole stream has been produced."""
         return self._length_ref

@@ -152,7 +152,13 @@ class ContinuousLlamaDeployment:
         self.config = config or llama.LlamaConfig.tiny()
         if params is None and checkpoint_path:
             params = _params_from_checkpoint(checkpoint_path)
-        self._queues: Dict[int, "queue.Queue"] = {}
+        # One stream queue a request. A SimpleQueue's put takes no
+        # Python-level lock, so the tick thread's token callbacks never
+        # wait for a consumer: with a hundred stream threads sharing the
+        # interpreter lock, a consumer descheduled inside Queue.get()
+        # held the queue's mutex, and the tick thread with it, for
+        # seconds (PR 28, serve_moe_decode).
+        self._queues: Dict[int, "queue.SimpleQueue"] = {}
         self._lock = threading.Lock()
         self._work = threading.Event()
         self._queue_mod = queue
@@ -190,8 +196,10 @@ class ContinuousLlamaDeployment:
         while True:
             self._work.wait()
             try:
-                # Callers take this lock to submit and cancel; the device
-                # idles while this thread waits to get it back.
+                # Callers take this lock to submit and cancel. A step
+                # leaves one tick queued on the device, so the device
+                # idles only if this thread gets the lock back later
+                # than that tick ends.
                 with tracing.phase("engine.lock_wait",
                                    mdefs.CB_STEP_LOCK_WAIT_MS, tags):
                     self._lock.acquire()
@@ -303,8 +311,10 @@ class ContinuousLlamaDeployment:
 
         Taking ``self._lock`` IS the tick-boundary guarantee: the tick
         thread holds the same lock around ``batcher.step()``, so the swap
-        lands strictly between ticks — in-flight requests keep their KV
-        cache and continue under the new weights, un-dropped. Emits the
+        lands strictly between steps (the one tick a step leaves queued
+        on the device finishes on the old weights) — in-flight requests
+        keep their KV cache and continue under the new weights,
+        un-dropped. Emits the
         ``rl.weight_swap`` flight event (caused by the trainer's publish
         event when a ``manifest`` is supplied, so ``ray-tpu why run``
         reconstructs the publish→swap chain) and counts the swap by
@@ -431,7 +441,7 @@ class ContinuousLlamaDeployment:
             # un-killed run never produced. (Only resumes check this:
             # an ORIGINAL prompt may legitimately end with EOS.)
             return
-        q = self._queue_mod.Queue()
+        q = self._queue_mod.SimpleQueue()
         trace = self._request_trace()
         if chaos.enabled():
             chaos.inject("serve_replica", phase="prefill",
@@ -509,7 +519,7 @@ class ContinuousLlamaDeployment:
         if chaos.enabled():
             chaos.inject("serve_replica", phase="prefill",
                          tokens=len(prompt))
-        q = self._queue_mod.Queue()
+        q = self._queue_mod.SimpleQueue()
         with self._submitting(entered, trace) as locked:
             rid = self.batcher.submit(prompt,
                                       max_new_tokens=max_tokens,
@@ -581,7 +591,7 @@ class ContinuousLlamaDeployment:
                 ticket.get("nonce") == self._nonce:
             res_id = ticket.get("res_id")
         trace = self._request_trace()
-        q = self._queue_mod.Queue()
+        q = self._queue_mod.SimpleQueue()
         with self._submitting(entered, trace) as locked:
             # The engine fires its first-token callback during the
             # import, before any queue could be registered under the

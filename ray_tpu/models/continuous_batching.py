@@ -615,10 +615,20 @@ class ContinuousBatcher:
         process driving four chips holds four engines, one per chip.
         ``None`` leaves placement to JAX's default device.
 
+        ``sync_every=1`` (the default) books one tick's tokens a step
+        with ONE tick queued behind the one that runs: ``step`` dispatches
+        tick n+1 before it fetches tick n, so the device never waits for
+        the host between ticks, every stream still gets one token a tick,
+        and no tick is thrown away (see :meth:`step`).
+
         ``sync_every=K > 1`` enables SPECULATIVE BUFFERED decode: host
         syncs per K ticks. The engine runs K ticks per host
         synchronization, fetching token batches double-buffered so the
-        transfer overlaps the next K ticks' compute. Decode is
+        transfer overlaps the next K ticks' compute: tokens reach a
+        stream K at a time, an end discards up to 2K ticks, and an
+        admission forces a boundary. Since the per-tick step keeps the
+        device as busy without any of that, nothing is left that this
+        mode does better (ROADMAP D3). Decode is
         deterministic (greedy, and sampled decode is
         keyed off a device-threaded step counter), so ticks run ahead of
         host bookkeeping speculatively; when a request finishes, the
@@ -807,10 +817,6 @@ class ContinuousBatcher:
             x.nbytes for x in jax.tree_util.tree_leaves(self._draft_params))
         self._draft_cache = None
         self.token_callback = token_callback
-        # (rid, token) callbacks of the last applied tick that the
-        # per-tick-sync step holds back until it has dispatched the NEXT
-        # tick: see ``_emit_held``.
-        self._held_tokens: List[tuple] = []
         # Table width covers max_len PLUS the spec look-ahead: a spec
         # tick writes draft/verify K/V up to position p + spec_k, and
         # those writes must stay inside the slot's own reservation
@@ -839,7 +845,13 @@ class ContinuousBatcher:
         self._d_tokens = None
         self._d_positions = None
         self._d_step = None
+        self._d_members = None    # [(slot, rid)] the device state stands for
         self._applied_steps = 0   # host mirror of the device step counter
+        # Ticks dispatched and not yet booked, oldest first (``step``
+        # keeps one queued behind the one that runs), and the clock of
+        # the last row that reached the host.
+        self._inflight: deque = deque()
+        self._row_landed = 0.0
         self._prefill_count = 0   # per-dispatch prefill sampling stream
         # Buffered-mode achieved-bandwidth window: wall time and tick
         # count between consecutive fetch syncs.
@@ -974,8 +986,16 @@ class ContinuousBatcher:
                                       cfg, use_kernel=use_kernel,
                                       sampling=sampling_cfg)
 
+        @xla_monitor.instrument(name="cb_merge_tokens")
+        def merge_tokens(fresh, host_tokens, device_tokens):
+            # With a tick in flight a running slot's last token is still
+            # on the device; only a fresh slot's (a prefill's or an
+            # import's first token) comes from the host.
+            return jnp.where(fresh, host_tokens, device_tokens)
+
         self._prefill = prefill
         self._tick = tick
+        self._merge_tokens = merge_tokens
 
         if self.spec_k and self.drafter.external:
             # The external drafter keeps its own dense per-slot cache;
@@ -1258,10 +1278,11 @@ class ContinuousBatcher:
         post-init assignment of ``self.params`` (a tick-boundary source
         lint enforces this). The caller must hold the engine's tick
         exclusion (the serve deployment swaps under its engine lock, so
-        no compiled tick is in flight); the next ``_run_tick`` dispatch
-        reads the fresh tree. The KV cache and every in-flight request's
-        device state are untouched: in-flight generations continue
-        un-dropped under the new weights.
+        no ``step`` is running). A tick already dispatched, the one
+        ``step`` keeps queued, finishes on the old weights; the next
+        ``_run_tick`` dispatch reads the fresh tree. The KV cache and
+        every in-flight request's device state are untouched: in-flight
+        generations continue un-dropped under the new weights.
 
         The new tree must match the old one structurally (same treedef,
         same leaf shapes/dtypes) — the compiled tick programs were traced
@@ -1418,9 +1439,13 @@ class ContinuousBatcher:
         self._waiting.clear()
         self._free = list(range(self.num_slots))
         self._finished.clear()
-        self._held_tokens = []
         self._buf = []
         self._pending = None
+        # A tick in flight is dropped unfetched, and with it the device's
+        # copy of the decode state (it may be the output of the program
+        # that failed).
+        self._inflight.clear()
+        self._d_tokens = self._d_members = None
         # Parked handoffs and import reservations die with the arena
         # (allocator.reset below reclaims their blocks wholesale).
         self._handoff_ready.clear()
@@ -1463,8 +1488,12 @@ class ContinuousBatcher:
         return self.prefix_hit_tokens / total if total else 0.0
 
     def has_work(self) -> bool:
+        """True while a ``step`` has something to do: a live or waiting
+        request, finishes not yet returned, or a tick dispatched whose
+        tokens are not booked yet (one is always queued while requests
+        decode; the buffered path holds up to 2K)."""
         return bool(self._slots or self._waiting or self._finished
-                    or self._buf or self._pending)
+                    or self._inflight or self._buf or self._pending)
 
     # --------------------------------------------- disaggregated handoff
     def _park_for_handoff(self, slot: int, req: Dict[str, Any]) -> None:
@@ -2023,6 +2052,15 @@ class ContinuousBatcher:
                         self.params, self._place(tokens), self.cache,
                         self._place(ptables), self._place(tables_w),
                         self._place(last_idx), pstep)
+                # The program queues behind the tick in flight, whose
+                # row reaches the host first: land it here, on its own
+                # clock. The device starts the prefill at that moment,
+                # so the prefill's clock does too.
+                queued = [t for t in self._inflight if t["wall"] is None]
+                for tick in queued:
+                    self._land(tick)
+                behind = prefill.elapsed_ms() if queued else 0.0
+                prefill.exclude(behind)
                 with _annotation("engine.prefill.fetch"):
                     first = np.asarray(first)    # N ints, one transfer
             # The fetch syncs the dispatch, so this interval is the real
@@ -2030,7 +2068,7 @@ class ContinuousBatcher:
             # it without decode/queueing time polluting the denominator,
             # and the XLA monitor turns it into achieved-FLOPs/bandwidth
             # gauges against this bucket's compiler cost analysis.
-            prefill_wall = prefill.ms / 1e3
+            prefill_wall = (prefill.ms - behind) / 1e3
             self.prefill_seconds += prefill_wall
             with tracing.phase("engine.account", mdefs.CB_STEP_ACCOUNT_MS,
                                self._mtags, outer=admit):
@@ -2127,29 +2165,71 @@ class ContinuousBatcher:
             self._finish_request(st["rid"], "finished",
                                  tokens=len(st["out"]))
 
-    def _upload_state(self) -> None:
+    def _ahead(self) -> Dict[tuple, int]:
+        """(slot, rid) -> ticks in flight that decode a token for it:
+        how far the device runs ahead of ``st["out"]`` and ``st["pos"]``."""
+        ahead: Dict[tuple, int] = {}
+        for tick in self._inflight:
+            for member in tick["members"]:
+                ahead[member] = ahead.get(member, 0) + 1
+        return ahead
+
+    def _next_members(self) -> List[tuple]:
+        """The (slot, rid) pairs the next tick decodes a token for: every
+        live request but those whose answer the ticks in flight complete.
+        ``max_new`` is host-known at dispatch, so such a finish costs no
+        overrun row; an EOS is not, and leaves one (see :meth:`step`)."""
+        ahead = self._ahead()
+        return [(slot, st["rid"]) for slot, st in self._slots.items()
+                if len(st["out"]) + ahead.get((slot, st["rid"]), 0)
+                < st["max_new"]]
+
+    def _upload_state(self, members: Optional[List[tuple]] = None) -> None:
+        """Bring the device's decode state up to date for a tick over
+        ``members`` (default: every live slot): whatever the host knows
+        a tick ahead is rebuilt from its books. Blocks are reserved at
+        admission, so tables and limits change only with membership; a
+        slot's position is ``pos`` plus the ticks in flight that advance
+        it; a row left out (freed, or finishing in flight) gets limit 0
+        and the garbage table row, so the tick neither visits nor writes
+        it. The LAST TOKEN of a slot with a tick in flight is that
+        tick's output, still on the device: only a fresh slot's comes
+        from the host, merged in on the device."""
         from ray_tpu._private import metrics_defs as mdefs
 
         with tracing.phase("engine.upload", mdefs.CB_STEP_UPLOAD_MS,
                            self._mtags):
+            if members is None:
+                members = [(s, st["rid"]) for s, st in self._slots.items()]
+            ahead = self._ahead()
             tokens = np.zeros(self.num_slots, np.int32)
+            fresh = np.zeros(self.num_slots, bool)
             positions = np.zeros(self.num_slots, np.int32)
-            for slot, st in self._slots.items():
-                tokens[slot] = st["last"]
-                positions[slot] = st["pos"]
-            self._d_tokens = self._place(tokens)
-            self._d_positions = self._place(positions)
-            # The device sampling-step counter rewinds to the host-applied
-            # count: speculative ticks a rewind discarded replay the SAME
-            # step numbers, so sampled decode reproduces exactly like greedy.
-            self._d_step = self._place(np.int32(self._applied_steps))
             tables = np.zeros((self.num_slots, self.max_blocks), np.int32)
             limits = np.zeros(self.num_slots, np.int32)
-            for slot, blocks in self._slot_blocks.items():
+            for slot, rid in members:
+                st = self._slots[slot]
+                running = ahead.get((slot, rid), 0)
+                fresh[slot] = not running
+                tokens[slot] = st["last"]
+                positions[slot] = st["pos"] + running
+                blocks = self._slot_blocks[slot]
                 tables[slot] = self._table_row(blocks)
                 limits[slot] = len(blocks) * self.block_size
+            tokens = self._place(tokens)
+            self._d_tokens = self._merge_tokens(
+                self._place(fresh), tokens,
+                tokens if self._d_tokens is None else self._d_tokens)
+            self._d_positions = self._place(positions)
+            # The device sampling-step counter is the host-applied count
+            # plus the ticks in flight. After a buffered rewind that is
+            # the applied count alone: the ticks it discarded replay the
+            # SAME step numbers, so sampled decode reproduces like greedy.
+            self._d_step = self._place(
+                np.int32(self._applied_steps + len(self._inflight)))
             self._d_tables = self._place(tables)
             self._d_limits = self._place(limits)
+            self._d_members = members
             self._dirty = False
 
     def _run_tick(self):
@@ -2215,35 +2295,19 @@ class ContinuousBatcher:
             return
         entries[rid] = ent
 
-    def _emit_held(self) -> None:
-        """Make the token callbacks :meth:`_apply_tokens` held back. A
-        callback wakes the request's stream (threads that serialise and
-        send the token, all under the interpreter lock this thread also
-        needs). Made right after a tick's tokens are booked, 48 streams
-        contend with this thread all through its host work, while the
-        device idles; made right after the NEXT tick is dispatched, they
-        run while the device computes and this thread sleeps in the
-        fetch. A step that finished a request emits before it returns,
-        so a stream's tokens always precede its end."""
-        held, self._held_tokens = self._held_tokens, []
-        for rid, tok in held:
-            self.token_callback(rid, tok)
-
-    def _apply_tokens(self, nxt_rows, membership, window=None,
-                      hold: bool = False) -> bool:
+    def _apply_tokens(self, nxt_rows, membership, window=None) -> bool:
         """Book one or more fetched tick rows; returns True when any
         request finished (membership changed). ``window`` is the
         (wall_start, wall_end) of the sync window these rows cover —
         recorded per traced request for the decode-window spans (windows
         must attach BEFORE ``_maybe_finish`` pops the record, so this
-        rides the apply loop, not a post-pass). ``hold`` keeps the token
-        callbacks for :meth:`_emit_held` instead of making them here."""
+        rides the apply loop, not a post-pass). The token callbacks are
+        made here, as each token is booked: the per-tick step has the
+        next tick queued on the device by then, so the streams they
+        wake run while the device computes."""
         from ray_tpu._private import metrics_defs as mdefs
 
         callback = self.token_callback
-        if hold and callback is not None:
-            callback = lambda rid, tok: self._held_tokens.append((rid, tok))
-
         with tracing.phase("engine.apply", mdefs.CB_STEP_APPLY_MS,
                            self._mtags):
             finished_any = False
@@ -2397,10 +2461,99 @@ class ContinuousBatcher:
                                           tags=self._mtags)
             mdefs.CB_SPEC_K.set(self._spec_cur_k, tags=self._mtags)
 
+    def _dispatch_tick(self, members: List[tuple]) -> None:
+        """Queue one decode tick over ``members`` on the device and start
+        its row's copy to the host; it joins the ticks in flight."""
+        from ray_tpu._private import metrics_defs as mdefs
+
+        if self._dirty or members != self._d_members:
+            self._upload_state(members)
+        w0 = time.time() if self._traced_live else None
+        t0 = time.perf_counter()
+        with _annotation("engine.tick.dispatch"):
+            row = self._run_tick()
+            for part in (row if isinstance(row, tuple) else (row,)):
+                part.copy_to_host_async()
+        if any(tick["wall"] is None for tick in self._inflight):
+            # Queued behind a tick whose row has not landed: the device
+            # goes into this one without waiting for the host. (After an
+            # admission the tick ahead has landed, before the prefill.)
+            mdefs.CB_TICK_OVERLAPPED.inc(tags=self._mtags)
+        self._inflight.append({"row": row, "members": members,
+                               "k": self._last_tick_k, "t0": t0,
+                               "w0": w0, "wall": None})
+
+    def _land(self, tick: Dict[str, Any]) -> None:
+        """Wait for ``tick``'s row to reach the host, once: 4 bytes a
+        slot (a routed model's expert row counts behind them; a spec
+        tick's committed window and counts). ``CB_TICK_MS`` gets the
+        time between two consecutive rows landing, which in steady state
+        is the device's tick (the next one is already queued behind it);
+        the clock starts at the dispatch instead where the device was
+        idle before it, or ran a prefill."""
+        from ray_tpu._private import metrics_defs as mdefs
+
+        if tick["wall"] is not None:
+            return
+        with _annotation("engine.tick.fetch"):
+            row = tick["row"]
+            tick["row"] = (tuple(np.asarray(part) for part in row)
+                           if isinstance(row, tuple) else np.asarray(row))
+        now = time.perf_counter()
+        tick["wall"] = now - max(self._row_landed, tick["t0"])
+        self._row_landed = now
+        mdefs.CB_TICK_MS.observe(tick["wall"] * 1e3, tags=self._mtags)
+
+    def _book_tick(self, tick: Dict[str, Any]) -> None:
+        """Apply a landed tick's tokens to the requests that were its
+        members when it was dispatched; a member that ended since (EOS,
+        cancel) drops its row."""
+        from ray_tpu._private import metrics_defs as mdefs
+
+        self._land(tick)
+        k = tick["k"]
+        # Spec ticks report against THEIR program (per-k instrumented
+        # jit) with the bytes hint priced for k draft passes + the wider
+        # verify window.
+        tick_fn = self._spec_ticks[k] if k else self._tick
+        with tracing.phase("engine.account", mdefs.CB_STEP_ACCOUNT_MS,
+                           self._mtags):
+            self._note_expert_rows([tick["row"]])
+            self._account_tick(tick_fn, tick["wall"], k)
+        self._apply_tokens(
+            [tick["row"]], tick["members"],
+            window=(tick["w0"], time.time())
+            if tick["w0"] is not None else None)
+
     def step(self) -> Dict[int, List[int]]:
-        """Admit waiting requests, run one decode tick over all active
-        slots, and return the requests that finished (with
-        ``sync_every > 1``, finish detection lags up to 2K ticks)."""
+        """Admit waiting requests, book ONE decode tick's tokens, and
+        return the requests that finished (with ``sync_every > 1``,
+        finish detection lags up to 2K ticks).
+
+        The step keeps one tick queued behind the one that runs: it
+        dispatches tick n+1 BEFORE it fetches tick n, so the device goes
+        from one tick straight into the next while this thread books
+        tokens, runs callbacks, admits and uploads (the first step after
+        an idle engine dispatches two). The device carries tokens,
+        positions, step counter and arena from tick to tick; what the
+        host re-uploads on a membership change it knows a tick ahead
+        (:meth:`_upload_state`). An admission's prefill queues behind
+        the tick in flight, so a slot freed by tick n is refilled for
+        tick n+2, one tick later than a step that waited would.
+
+        Running ahead costs at most one OVERRUN ROW, for an end the host
+        cannot foresee (EOS, ``cancel``): tick n+1 has run for a request
+        tick n ended. Its token is dropped (the row's rid no longer owns
+        the slot); its K/V write lands inside the slot's own reservation
+        or, past it, in the garbage block, never in a block the prefix
+        index holds (those are full PROMPT blocks); and the freed blocks
+        can only be refilled by a prefill the device runs after tick
+        n+1. An end by ``max_new`` is foreseen (:meth:`_next_members`).
+
+        A speculative tick (``spec_k`` rung above 0) advances each slot
+        by a count the device decides, so positions are not host-known a
+        tick ahead: it is fetched right after its dispatch, a depth of 1
+        in the same loop."""
         from ray_tpu._private import chaos
         from ray_tpu._private import metrics_defs as mdefs
 
@@ -2416,49 +2569,22 @@ class ContinuousBatcher:
             self._emit_gauges()
             if sync:
                 self._adapt_spec_k()
-        if sync:
-            self._admit()
-            if self._slots:
-                if self._dirty:
-                    self._upload_state()
-                w0 = time.time() if self._traced_live else None
-                # Per-tick sync: the fetch IS the device sync, so this is
-                # the honest tick latency (dispatch + compute + fetch) —
-                # also the denominator for the tick's achieved-FLOPs/
-                # bandwidth gauges.
-                with tracing.phase("engine.tick", mdefs.CB_TICK_MS,
-                                   self._mtags) as tick:
-                    with _annotation("engine.tick.dispatch"):
-                        nxt_dev = self._run_tick()
-                    with _annotation("engine.tick.emit"):
-                        self._emit_held()   # the previous tick's tokens
-                    with _annotation("engine.tick.fetch"):
-                        if isinstance(nxt_dev, tuple):
-                            nxt = (np.asarray(nxt_dev[0]),
-                                   np.asarray(nxt_dev[1]))
-                        else:
-                            nxt = np.asarray(nxt_dev)  # 4 bytes/slot
-                tick_wall = tick.ms / 1e3
-                # Spec ticks report against THEIR program (per-k
-                # instrumented jit) with the bytes hint priced for k
-                # draft passes + the wider verify window.
-                tick_fn = (self._spec_ticks[self._last_tick_k]
-                           if self._last_tick_k else self._tick)
-                with tracing.phase("engine.account",
-                                   mdefs.CB_STEP_ACCOUNT_MS, self._mtags):
-                    self._note_expert_rows([nxt])
-                    self._account_tick(tick_fn, tick_wall, self._last_tick_k)
-                if self._apply_tokens(
-                        [nxt], [(s, st["rid"])
-                                for s, st in self._slots.items()],
-                        window=(w0, time.time())
-                        if w0 is not None else None, hold=True):
-                    self._dirty = True
-            if self._finished:
-                self._emit_held()
-            out, self._finished = self._finished, {}
-            return out
-        return self._step_buffered()
+        if not sync:
+            return self._step_buffered()
+        self._admit()
+        depth = 1 if self.spec_k and self._spec_cur_k else 2
+        while len(self._inflight) >= depth:
+            # Only when the rung has just left 0 with a plain tick queued.
+            self._book_tick(self._inflight.popleft())
+        while len(self._inflight) < depth:
+            members = self._next_members()
+            if not members:
+                break
+            self._dispatch_tick(members)
+        if self._inflight:
+            self._book_tick(self._inflight.popleft())
+        out, self._finished = self._finished, {}
+        return out
 
     def _step_buffered(self) -> Dict[int, List[int]]:
         # Admission only at a clean boundary (no speculative ticks in

@@ -1347,6 +1347,10 @@ class DeploymentResponseGenerator:
                else self._gen._next_internal(self._timeout))
         return ray_tpu.get(ref, timeout=self._timeout)
 
+    def ready(self) -> bool:
+        """Whether ``next()`` would return an item without waiting."""
+        return self._gen.ready()
+
 
 # Process-wide in-flight request counts per deployment: the queue-depth
 # gauge must aggregate across every handle to a deployment (independent

@@ -7,7 +7,9 @@ routing state. This build keeps that shape with stdlib asyncio streams:
 * keep-alive HTTP/1.1 with pipelined request loop per connection;
 * chunked NDJSON streaming whose writes apply real backpressure
   (``await writer.drain()`` — a slow client throttles the generator pull
-  instead of buffering unboundedly);
+  instead of buffering unboundedly); a pull takes every item the
+  replica has already stored, so a stream that fell behind its producer
+  catches up in one write (each item keeps a chunk of its own);
 * a bounded executor bridging the blocking DeploymentHandle router calls,
   whose size caps in-flight requests (the asyncio analog of the
   reference's ``max_ongoing_requests`` admission);
@@ -36,6 +38,9 @@ logger = logging.getLogger(__name__)
 
 _MAX_BODY = 64 << 20
 _STREAM_END = object()
+# Most items one pull of a streamed response takes (``_pull_ready``): a
+# bound on one write, far above what a stream a few ticks behind holds.
+_PULL_MAX_ITEMS = 256
 
 
 def prefix_fingerprint(payload: Any) -> str:
@@ -306,6 +311,36 @@ class _Router:
                                  request_ctx=request_ctx)
         return RecoverableStream(self.handle(name), journal,
                                  per_item_timeout_s=60.0)
+
+
+def _pull_ready(items, held: List[Any]) -> Any:
+    """One pull of a streamed response: wait for the next item, then
+    take what else the replica has already stored (``items.ready()``),
+    as a list; ``_STREAM_END`` when the stream is over. A pull is a
+    round trip between the ingress loop and a pool thread, and under a
+    hundred open streams it can take longer than an engine's tick: at
+    one item a pull such a stream fell further behind with every token,
+    and its caller waited seconds for tokens the engine had long made.
+    An error (or the end) met after the first item is left in ``held``
+    for the next pull, so the items before it still reach the client."""
+    if held:
+        end = held.pop()
+        if end is _STREAM_END:
+            return end
+        raise end
+    try:
+        batch = [next(items)]
+    except StopIteration:
+        return _STREAM_END
+    more = getattr(items, "ready", None)
+    try:
+        while more is not None and len(batch) < _PULL_MAX_ITEMS and more():
+            batch.append(next(items))
+    except StopIteration:
+        held.append(_STREAM_END)
+    except Exception as e:  # noqa: BLE001 — raised by the next pull
+        held.append(e)
+    return batch
 
 
 def ingress_request_context(deployment: str, tenant: str = "",
@@ -587,11 +622,10 @@ class AsyncHttpProxy:
             _close_ingress_span(rctx, ing_t0, "error", path)
             raise
 
+        held: List[Any] = []
+
         def pull():
-            try:
-                return next(items)
-            except StopIteration:
-                return _STREAM_END
+            return _pull_ready(items, held)
 
         try:
             first = await loop.run_in_executor(self._pool, pull)
@@ -615,14 +649,18 @@ class AsyncHttpProxy:
                       f"Transfer-Encoding: chunked\r\n"
                       f"{extra}"
                       f"Connection: {conn}\r\n\r\n").encode())
-        item = first
+        batch = first
         try:
-            while item is not _STREAM_END:
-                chunk = json.dumps(item).encode() + b"\n"
-                writer.write(f"{len(chunk):x}\r\n".encode() + chunk
-                             + b"\r\n")
+            while batch is not _STREAM_END:
+                # One chunk an item, as ever; what one pull brought
+                # goes out in one write.
+                out = bytearray()
+                for item in batch:
+                    chunk = json.dumps(item).encode() + b"\n"
+                    out += f"{len(chunk):x}\r\n".encode() + chunk + b"\r\n"
+                writer.write(bytes(out))
                 await writer.drain()  # backpressure: slow client, slow pull
-                item = await loop.run_in_executor(self._pool, pull)
+                batch = await loop.run_in_executor(self._pool, pull)
             if journal is not None and journal.needs_marker \
                     and not marker_sent:
                 # The sampled resume happened MID-stream (headers long

@@ -393,6 +393,16 @@ class RecoverableStream:
             j.record(item)
             return item
 
+    def ready(self) -> bool:
+        """Whether the replica has already stored the next item, so
+        that ``next()`` returns it without waiting: the ingress takes
+        what is ready in one pull, and a stream that fell behind its
+        engine catches up instead of lagging one pull a token. Never
+        blocks and never recovers: a dead replica reads as not ready
+        and is met by the ``next()`` that waits."""
+        ready = getattr(self._inner, "ready", None)
+        return ready is not None and ready()
+
 
 class DisaggRecoverableStream(RecoverableStream):
     """Recoverable stream over a (prefill, decode) ROLE-GROUP pair —

@@ -4,16 +4,19 @@
 
 The engine thread wraps each stretch of its loop in a ``tracing.phase``
 (``engine.lock_wait``, ``engine.admit``, ``engine.prefill`` with its
-``.dispatch`` and ``.fetch``, ``engine.upload``, ``engine.tick`` with
-its ``.dispatch`` and ``.fetch``, ``engine.account``, ``engine.apply``),
-which a profiler session records as host events on the device events'
-clock. A gap is a stretch in which no instruction ran on the chip (the
+``.dispatch`` and ``.fetch``, ``engine.upload``, ``engine.tick.dispatch``
+of tick n+1 and ``engine.tick.fetch`` of tick n (the step keeps one tick
+queued behind the one that runs; traces older than PR 28 have an
+``engine.tick`` around both, of one tick), ``engine.account``,
+``engine.apply``), which a profiler session records as host events on
+the device events' clock. A gap is a stretch in which no instruction ran on the chip (the
 ``XLA Ops`` line of its ``/device:TPU:<n>`` plane). Each gap is split
 among the annotations that overlap it; where they nest, the innermost
 takes its part, and what no annotation covers is ``unattributed``. So a
 gap under ``engine.tick.dispatch`` is launch latency, one under
-``engine.tick.fetch`` the way back, one under ``engine.admit`` host
-bookkeeping while the device had nothing queued.
+``engine.tick.fetch`` a device that ran out of queued ticks before the
+host came back for the row, one under ``engine.admit`` host bookkeeping
+while the device had nothing queued.
 
 For a ``ray-tpu profile`` capture of a serving replica, or a
 ``benchmark/run.py --trace 1 --keep-trace DIR`` run. The profiler's host

@@ -204,6 +204,15 @@ class phase:
         self._t0 = time.perf_counter()
         return self
 
+    def elapsed_ms(self) -> float:
+        """Milliseconds since the block was entered."""
+        return (time.perf_counter() - self._t0) * 1e3
+
+    def exclude(self, ms: float) -> None:
+        """Take ``ms`` that passed inside the block out of its
+        observation: time another measurement already books."""
+        self._inner_ms += ms
+
     def __exit__(self, *exc) -> None:
         self.ms = (time.perf_counter() - self._t0) * 1e3
         self._annotation.__exit__(*exc)

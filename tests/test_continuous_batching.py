@@ -8,6 +8,7 @@ import pytest
 from ray_tpu.models import llama
 from ray_tpu.models.continuous_batching import ContinuousBatcher
 from ray_tpu.models.inference import LlamaGenerator
+from ray_tpu.models.paged_kv import GARBAGE_BLOCK
 
 
 @pytest.fixture(scope="module")
@@ -441,7 +442,8 @@ def test_live_rows_never_share_a_write_block(setup, pallas_interpret):
         def watched():
             ticks.append((np.asarray(eng._d_positions),
                           np.asarray(eng._d_tables),
-                          np.asarray(eng._d_limits), sorted(eng._slots)))
+                          np.asarray(eng._d_limits),
+                          sorted(s for s, _ in eng._d_members)))
             return run_tick()
 
         if use_kernel:
@@ -547,11 +549,16 @@ def test_paged_buffered_arena_wait_keeps_pipelining(setup):
     assert out[r2] == _reference(gen, [1, 2, 3], 3)
 
 
-def test_paged_overrun_write_lands_in_garbage_block():
-    """Speculative ticks past a slot's reservation must NOT write into
-    its last live block via the tail-repeated table (a rewind would then
-    replay over corrupted K/V): overrun writes redirect to the garbage
-    block, live blocks stay byte-identical."""
+@pytest.mark.parametrize("overrun", [33, 32, 64], ids=[
+    "past_the_reservation", "first_row_past_a_full_last_block",
+    "past_the_table"])
+def test_paged_overrun_write_lands_in_garbage_block(overrun):
+    """A tick that runs past a slot's reservation (the buffered path
+    detects an end up to 2K ticks late; a request whose ``prompt +
+    max_new`` fills its last block exactly has its very next row there)
+    must NOT write into its last live block via the tail-repeated table:
+    overrun writes redirect to the garbage block, live blocks stay
+    byte-identical."""
     import jax
 
     from ray_tpu.models.continuous_batching import _decode_tick_paged
@@ -567,11 +574,13 @@ def test_paged_overrun_write_lands_in_garbage_block():
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     _, _, new_cache, _ = _decode_tick_paged(
         params, jnp.asarray([3], jnp.int32),
-        jnp.asarray([33], jnp.int32),            # OVERRUN position
+        jnp.asarray([overrun], jnp.int32),       # OVERRUN position
         tables, limits, cache, jnp.int32(0), cfg)
     np.testing.assert_array_equal(
         np.asarray(new_cache.k[:, 2]),
         np.full_like(np.asarray(new_cache.k[:, 2]), 7.7))
+    np.testing.assert_array_equal(np.asarray(new_cache.k[:, 1]),
+                                  np.asarray(cache.k[:, 1]))
     # In-reservation writes still land in the mapped block.
     _, _, new_cache, _ = _decode_tick_paged(
         params, jnp.asarray([3], jnp.int32),
@@ -778,11 +787,11 @@ def test_buffered_admission_not_starved(setup):
 @pytest.mark.parametrize("sync_every", [1, 3])
 def test_token_callbacks_are_whole_and_in_order_when_a_request_ends(
         setup, sync_every):
-    """The per-tick-sync step holds a tick's token callbacks until it
-    has dispatched the next tick (the streams then run while the device
-    computes). Whatever it holds, by the time ``step`` REPORTS a request
-    finished the callbacks have delivered all its tokens, in order: a
-    stream's end-marker is put right after ``step`` returns."""
+    """Token callbacks are made as a tick's tokens are booked (the
+    next tick is queued on the device by then; the buffered path books
+    K ticks at a time). By the time ``step`` REPORTS a request finished
+    the callbacks have delivered all its tokens, in order: a stream's
+    end-marker is put right after ``step`` returns."""
     config, gen, _ = setup
     seen = {}
     eng = ContinuousBatcher(
@@ -799,4 +808,391 @@ def test_token_callbacks_are_whole_and_in_order_when_a_request_ends(
             assert seen[rid] == out and len(out) == want[rid], rid
         done.update(finished)
     assert set(done) == set(want)
-    assert not eng._held_tokens
+    assert set(seen) == set(want)
+
+
+# ------------------------------------------ one tick queued behind the one
+# that runs (PR 28): scripted runs against the unbatched reference
+
+def _pipelined(config, gen, **kw):
+    kw.setdefault("num_slots", 3)
+    kw.setdefault("max_len", 128)
+    kw.setdefault("block_size", 16)
+    return ContinuousBatcher(config, params=gen.params, **kw)
+
+
+def _prompts(seed, *lengths):
+    rng = np.random.default_rng(seed)
+    return [list(rng.integers(1, 250, size=n)) for n in lengths]
+
+
+def _drain(eng, done):
+    while eng.has_work():
+        done.update(eng.step())
+    assert not eng._inflight
+    return done
+
+
+def _eos_for(gen, prompt, n, at):
+    """(eos token, expected output) such that greedy decode of
+    ``prompt`` first emits the token at 0-based index ``at``."""
+    ref = _reference(gen, prompt, n)
+    eos = ref[at]
+    cut = ref.index(eos)
+    return eos, ref[:cut + 1]
+
+
+def _case_max_new_staggered(config, gen):
+    """Five requests over three slots that end on different ticks by
+    ``max_new``: every end is foreseen, every freed slot refilled."""
+    eng = _pipelined(config, gen)
+    prompts = _prompts(101, 5, 9, 17, 3, 12)
+    news = [6, 3, 9, 12, 2]
+    rids = [eng.submit(p, max_new_tokens=m) for p, m in zip(prompts, news)]
+    done = _drain(eng, {})
+    # No row ran for a request the host knew to be over.
+    assert eng.decoded_tokens == sum(m - 1 for m in news)
+    return [(done[r], _reference(gen, p, m))
+            for r, p, m in zip(rids, prompts, news)]
+
+
+def _case_eos_leaves_one_overrun_row(config, gen):
+    """An EOS ends a request a tick before the host can know: the tick
+    in flight ran one row for it, whose token is dropped; the stream
+    gets no token after its end, and its end after its last token."""
+    long_p, eos_p = _prompts(102, 7, 11)
+    eos, want = _eos_for(gen, eos_p, 20, at=4)
+    assert eos not in _reference(gen, long_p, 16)
+    seen, rows = {}, []
+    eng = _pipelined(
+        config, gen, eos_token=eos,
+        token_callback=lambda rid, tok: seen.setdefault(rid, []).append(tok))
+    dispatch = eng._dispatch_tick
+    eng._dispatch_tick = lambda members: (rows.append(len(members)),
+                                          dispatch(members))[1]
+    r_long = eng.submit(long_p, max_new_tokens=16)
+    r_eos = eng.submit(eos_p, max_new_tokens=20)
+    done = {}
+    while eng.has_work():
+        finished = eng.step()
+        for rid, out in finished.items():
+            assert seen[rid] == out, "tokens after (or missing at) the end"
+        done.update(finished)
+    assert len(want) < 20 and seen[r_eos] == want
+    assert sum(rows) - eng.decoded_tokens == 1      # the one overrun row
+    return [(done[r_eos], want), (done[r_long], _reference(gen, long_p, 16))]
+
+
+def _case_admission_with_a_tick_in_flight(config, gen):
+    eng = _pipelined(config, gen)
+    p1, p2, p3 = _prompts(103, 4, 21, 6)
+    r1 = eng.submit(p1, max_new_tokens=14)
+    done = dict(eng.step())
+    done.update(eng.step())
+    assert eng._inflight, "no tick queued behind the one that ran"
+    r2 = eng.submit(p2, max_new_tokens=5)
+    done.update(eng.step())
+    assert eng.active_count == 2 and eng._inflight
+    r3 = eng.submit(p3, max_new_tokens=7)
+    _drain(eng, done)
+    return [(done[r1], _reference(gen, p1, 14)),
+            (done[r2], _reference(gen, p2, 5)),
+            (done[r3], _reference(gen, p3, 7))]
+
+
+def _case_cancel_with_a_tick_in_flight(config, gen):
+    """A cancel between steps frees a slot the tick in flight still
+    decodes for: that row is dropped, and the request admitted into the
+    freed slot and blocks decodes as if alone."""
+    eng = _pipelined(config, gen, num_slots=2, num_blocks=9)
+    p1, p2, p3 = _prompts(104, 30, 5, 28)
+    r1 = eng.submit(p1, max_new_tokens=30)       # 4 of the 8 blocks
+    r2 = eng.submit(p2, max_new_tokens=11)
+    r3 = eng.submit(p3, max_new_tokens=9)        # waits for r1's slot
+    done = dict(eng.step())
+    done.update(eng.step())
+    assert eng._inflight and eng.cancel(r1)
+    _drain(eng, done)
+    assert r1 not in done
+    return [(done[r2], _reference(gen, p2, 11)),
+            (done[r3], _reference(gen, p3, 9))]
+
+
+def _case_reset_with_a_tick_in_flight(config, gen):
+    eng = _pipelined(config, gen)
+    p1, p2, p3 = _prompts(105, 8, 13, 19)
+    r1 = eng.submit(p1, max_new_tokens=20)
+    r2 = eng.submit(p2, max_new_tokens=20)
+    eng.step()
+    eng.step()
+    assert eng._inflight
+    assert sorted(eng.reset()) == [r1, r2]
+    assert not eng._inflight and not eng.has_work()
+    r3 = eng.submit(p3, max_new_tokens=6)
+    r4 = eng.submit(p1, max_new_tokens=4)
+    done = _drain(eng, {})
+    return [(done[r3], _reference(gen, p3, 6)),
+            (done[r4], _reference(gen, p1, 4))]
+
+
+def _case_import_into_a_running_decode_engine(config, gen):
+    """A decode-role engine admits by ``import_kv_payload``, whose first
+    token the host knows: with a tick in flight it is merged in on the
+    device like a prefill's."""
+    from ray_tpu.serve import kv_transfer
+
+    kw = dict(num_slots=3, num_blocks=40)
+    pre = _pipelined(config, gen, role="prefill", **kw)
+    dst = _pipelined(config, gen, role="decode", **kw)
+    prompts = _prompts(106, 33, 7, 18)
+    news = [12, 9, 6]
+    payloads = []
+    for p, m in zip(prompts, news):
+        rid = pre.submit(p, max_new_tokens=m)
+        pre.run_to_completion()
+        payloads.append(kv_transfer.export_kv(pre, rid))
+    assert not pre._inflight and pre.base_tick_count == 0
+    rids = [kv_transfer.import_kv(dst, payloads[0])]
+    done = dict(dst.step())
+    done.update(dst.step())
+    assert dst._inflight
+    rids.append(kv_transfer.import_kv(dst, payloads[1]))
+    done.update(dst.step())
+    rids.append(kv_transfer.import_kv(dst, payloads[2]))
+    _drain(dst, done)
+    return [(done[r], _reference(gen, p, m))
+            for r, p, m in zip(rids, prompts, news)]
+
+
+def _case_prefix_blocks_reused_after_an_overrun(config, gen):
+    """Prefix cache on: a request ended by EOS (so the tick in flight
+    wrote one more K/V row for it) parks its prompt blocks, the next
+    admission splices them in, and reads what the prefill wrote: the
+    overrun row fell behind the prompt, never in an indexed block."""
+    shared = _prompts(107, 32)[0]                # two full blocks
+    tail_a, tail_b, other = _prompts(108, 3, 5, 9)
+    eos, want_a = _eos_for(gen, shared + tail_a, 24, at=3)
+    want_b = _reference(gen, shared + tail_b, 8)
+    want_o = _reference(gen, other, 30)
+    assert eos not in want_b and eos not in want_o
+    eng = _pipelined(config, gen, num_slots=2, eos_token=eos,
+                     prefix_cache=True)
+    r_o = eng.submit(other, max_new_tokens=30)
+    r_a = eng.submit(shared + tail_a, max_new_tokens=24)
+    done = {}
+    while r_a not in done:
+        done.update(eng.step())
+    assert eng._inflight and eng.prefix_hit_tokens == 0
+    r_b = eng.submit(shared + tail_b, max_new_tokens=8)
+    _drain(eng, done)
+    assert eng.prefix_hit_tokens == 32
+    return [(done[r_a], want_a), (done[r_b], want_b), (done[r_o], want_o)]
+
+
+def _case_block_multiple_and_a_poisoned_garbage_block(config, gen):
+    """``prompt + max_new`` a multiple of the block size, so a slot's
+    last row sits at the end of its reservation, with ends foreseen
+    (``max_new``) and not (EOS), and the garbage block full of NaN: the
+    rows a tick computes for slots it left out, and every write past a
+    reservation, go there, and nothing a live row reads comes from it."""
+    p1, p2, p3, p4 = _prompts(109, 10, 20, 5, 9)
+    eos, want3 = _eos_for(gen, p3, 11, at=6)
+    wants = [_reference(gen, p1, 6), _reference(gen, p2, 12), want3,
+             _reference(gen, p4, 23)]
+    assert all(eos not in w for w in wants[:2] + wants[3:])
+    eng = _pipelined(config, gen, num_slots=2, eos_token=eos)
+    eng.cache = eng.cache._replace(
+        k=eng.cache.k.at[:, GARBAGE_BLOCK].set(jnp.nan),
+        v=eng.cache.v.at[:, GARBAGE_BLOCK].set(jnp.nan))
+    rids = [eng.submit(p, max_new_tokens=m)
+            for p, m in ((p1, 6), (p2, 12), (p3, 11), (p4, 23))]
+    done = _drain(eng, {})
+    return [(done[r], w) for r, w in zip(rids, wants)]
+
+
+_PIPELINE_CASES = [
+    _case_max_new_staggered, _case_eos_leaves_one_overrun_row,
+    _case_admission_with_a_tick_in_flight,
+    _case_cancel_with_a_tick_in_flight, _case_reset_with_a_tick_in_flight,
+    _case_import_into_a_running_decode_engine,
+    _case_prefix_blocks_reused_after_an_overrun,
+    _case_block_multiple_and_a_poisoned_garbage_block,
+]
+
+
+@pytest.mark.parametrize("case", _PIPELINE_CASES,
+                         ids=[c.__name__[6:] for c in _PIPELINE_CASES])
+def test_pipelined_engine_matches_the_reference(setup, case):
+    """With one tick always queued behind the one that runs, every
+    request still gets, token for token, the unbatched generator's
+    greedy answer."""
+    config, gen, _ = setup
+    for i, (got, want) in enumerate(case(config, gen)):
+        assert got == want, (case.__name__, i)
+
+
+@pytest.mark.parametrize("script", ["staggered", "mid_run_admissions"])
+def test_pipelined_sampled_decode_reproduces(setup, script):
+    """Sampled decode keys every tick off the device-carried step
+    counter, which a re-upload with a tick in flight sets to the applied
+    count plus the ticks in flight. Requests that end on different
+    ticks (a re-upload at each) draw the tokens the buffered engine
+    draws, whose re-uploads rewind to the applied count alone; with
+    admissions mid-run (a freed slot is refilled a tick later than the
+    buffered engine's, so the two schedules differ) a replay of the
+    same script draws the same tokens."""
+    from ray_tpu.models.sampling import SamplingParams
+
+    config, gen, _ = setup
+    sp = SamplingParams(temperature=0.9, top_p=0.95, seed=7)
+    prompts = _prompts(110, 5, 17, 9, 4, 11)
+    news = [9, 4, 13, 6, 8]
+
+    def run(n_requests, **kw):
+        eng = _pipelined(config, gen, sampling=sp, **kw)
+        rids = [eng.submit(p, max_new_tokens=m)
+                for p, m in list(zip(prompts, news))[:n_requests]]
+        done = _drain(eng, {})
+        return [done[r] for r in rids]
+
+    if script == "staggered":
+        out = run(3)
+        assert out == run(3, sync_every=4)
+        greedy = [_reference(gen, p, m) for p, m in zip(prompts, news)][:3]
+        assert out != greedy
+    else:
+        out = run(5)
+        assert out == run(5)
+    assert [len(o) for o in out] == news[:len(out)]
+
+
+def test_tick_is_dispatched_before_the_one_ahead_is_fetched(setup):
+    """The order, with the dispatch and the host fetch instrumented: in
+    a run of N ticks with A admissions after the first, tick n+1 is
+    dispatched before tick n's row reaches the host, except where an
+    admission's prefill ran between them (the prefill queues behind tick
+    n and lands it first). The counter reads N - 1 - A, ``CB_TICK_MS``
+    has N observations, and every step books exactly one tick."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    config, gen, _ = setup
+    eng = _pipelined(config, gen)
+    events = []
+    dispatch, land = eng._dispatch_tick, eng._land
+
+    def watched_dispatch(members):
+        events.append(("dispatch", eng.base_tick_count))
+        dispatch(members)
+        eng._inflight[-1]["n"] = eng.base_tick_count - 1
+
+    def watched_land(tick):
+        if tick["wall"] is None:
+            events.append(("land", tick["n"]))
+        return land(tick)
+
+    eng._dispatch_tick, eng._land = watched_dispatch, watched_land
+
+    def totals():
+        overlapped = sum(v for _, _, v in mdefs.CB_TICK_OVERLAPPED.samples())
+        ticks = sum(v for name, _, v in mdefs.CB_TICK_MS.samples()
+                    if name.endswith("_count"))
+        return overlapped, ticks
+
+    before = totals()
+    p1, p2, p3 = _prompts(111, 6, 9, 4)
+    eng.submit(p1, max_new_tokens=25)
+    admissions = {4: (p2, 8), 11: (p3, 5)}        # step -> request
+    steps = 0
+    while eng.has_work():
+        if steps in admissions:
+            p, m = admissions[steps]
+            eng.submit(p, max_new_tokens=m)
+        booked = eng.decoded_tokens
+        eng.step()
+        assert eng.decoded_tokens > booked         # one tick's tokens
+        steps += 1
+    n = eng.base_tick_count
+    assert n == steps == 24                        # 25 tokens, 1 prefilled
+    order = {ev: i for i, ev in enumerate(events)}
+    assert len(order) == 2 * n
+    behind_a_prefill = set()
+    for t in range(n - 1):
+        if order[("dispatch", t + 1)] > order[("land", t)]:
+            behind_a_prefill.add(t + 1)
+        assert order[("dispatch", t)] < order[("dispatch", t + 1)]
+        assert order[("land", t)] < order[("land", t + 1)]
+    assert len(behind_a_prefill) == len(admissions)
+    overlapped, ticks = (a - b for a, b in zip(totals(), before))
+    assert ticks == n
+    assert overlapped == n - 1 - len(admissions)
+
+
+def test_speculative_tick_never_runs_ahead(setup):
+    """A speculative tick advances each slot by a count the device
+    decides, so its row is fetched before anything else is dispatched:
+    no tick overlaps another, and the outputs are the reference's."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    config, gen, _ = setup
+    eng = _pipelined(config, gen, spec_k=2, spec_adaptive=False)
+    dispatch = eng._dispatch_tick
+
+    def watched(members):
+        assert not eng._inflight
+        return dispatch(members)
+
+    eng._dispatch_tick = watched
+    before = sum(v for _, _, v in mdefs.CB_TICK_OVERLAPPED.samples())
+    prompts = _prompts(112, 5, 12, 8, 3)
+    news = [9, 5, 12, 7]
+    rids = [eng.submit(p, max_new_tokens=m) for p, m in zip(prompts, news)]
+    done = {}
+    while eng.has_work():
+        done.update(eng.step())
+        assert not eng._inflight
+    assert eng.spec_tick_count > 0 and eng.base_tick_count == 0
+    assert sum(v for _, _, v in mdefs.CB_TICK_OVERLAPPED.samples()) == before
+    for r, p, m in zip(rids, prompts, news):
+        assert done[r] == _reference(gen, p, m)
+
+
+def test_tick_overlap_share_reads_the_engines_counter(setup):
+    """``benchmark/metrics/tick_overlap_share.json`` through its reader,
+    on registry snapshots around a run: the share of ticks that were
+    dispatched behind one still in flight; 0 where the program has no
+    such counter (the parent), nothing where no tick ran."""
+    import json
+    import os
+
+    from benchmark.readers import registry_delta
+    from ray_tpu._private import metrics_defs as mdefs
+
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "metrics", "tick_overlap_share.json")
+    with open(path) as fh:
+        spec = json.load(fh)
+    assert spec["reader"] == "registry_delta"
+
+    def snapshot():
+        out = {}
+        for metric in (mdefs.CB_TICK_OVERLAPPED, mdefs.CB_TICK_MS):
+            for name, _, v in metric.samples():
+                out[name] = out.get(name, 0.0) + v
+        return out
+
+    config, gen, _ = setup
+    eng = _pipelined(config, gen)
+    before = snapshot()
+    eng.submit(_prompts(113, 6)[0], max_new_tokens=11)     # 10 ticks
+    eng.run_to_completion()
+    after = snapshot()
+    ctx = {"registry_before": before, "registry_after": after}
+    assert registry_delta.read(ctx, **spec["args"]) == pytest.approx(90.0)
+    counter = mdefs.CB_TICK_OVERLAPPED.name
+    parent = {"registry_before": {k: v for k, v in before.items()
+                                  if k != counter},
+              "registry_after": {k: v for k, v in after.items()
+                                 if k != counter}}
+    assert registry_delta.read(parent, **spec["args"]) == 0.0
+    idle = {"registry_before": after, "registry_after": after}
+    assert registry_delta.read(idle, **spec["args"]) is None

@@ -88,6 +88,111 @@ def test_http_streaming_ndjson(ingress):
     conn.close()
 
 
+def test_http_stream_keeps_one_chunk_an_item_however_many_a_pull_takes(
+        ingress):
+    """A producer far ahead of the ingress: every item still arrives,
+    in order, each in a chunk of its own (more than ``_PULL_MAX_ITEMS``
+    of them, so one pull cannot take all)."""
+    import socket
+
+    from ray_tpu.serve import proxy
+
+    n = proxy._PULL_MAX_ITEMS * 2 + 44
+    http_port, _ = ingress
+    body = json.dumps({"n": n}).encode()
+    with socket.create_connection(("127.0.0.1", http_port)) as sock:
+        sock.sendall((f"POST /Echo/stream/counts HTTP/1.1\r\n"
+                      f"Host: x\r\nContent-Type: application/json\r\n"
+                      f"Content-Length: {len(body)}\r\n"
+                      f"Connection: close\r\n\r\n").encode() + body)
+        raw = b""
+        while chunk := sock.recv(1 << 16):
+            raw += chunk
+    head, _, rest = raw.partition(b"\r\n\r\n")
+    assert b" 200 " in head.split(b"\r\n")[0]
+    chunks = []
+    while True:
+        size, _, rest = rest.partition(b"\r\n")
+        size = int(size, 16)
+        if size == 0:
+            break
+        chunks.append(rest[:size])
+        assert rest[size:size + 2] == b"\r\n"
+        rest = rest[size + 2:]
+    assert [json.loads(c) for c in chunks] == [{"i": i} for i in range(n)]
+    assert all(c.count(b"\n") == 1 for c in chunks)
+
+
+class _Scripted:
+    """An item stream with a script: what ``next()`` gives in turn (an
+    exception instance is raised) and how many of them are stored."""
+
+    def __init__(self, script, stored):
+        self.script, self.stored, self.asked = list(script), stored, 0
+
+    def __next__(self):
+        if not self.script:
+            raise StopIteration
+        self.stored -= 1
+        item = self.script.pop(0)
+        if isinstance(item, BaseException):
+            raise item
+        return item
+
+    def ready(self):
+        self.asked += 1
+        return self.stored > 0
+
+
+@pytest.mark.parametrize("case", ["caught_up", "behind", "capped",
+                                  "ends_in_a_pull", "error_in_a_pull",
+                                  "no_ready"])
+def test_pull_takes_what_is_stored_and_never_waits_twice(case, monkeypatch):
+    from ray_tpu.serve import proxy
+
+    end, held = proxy._STREAM_END, []
+    if case == "caught_up":
+        # Nothing stored beyond the item waited for: one item a pull.
+        items = _Scripted([1, 2], stored=1)
+        assert proxy._pull_ready(items, held) == [1]
+        items.stored = 1
+        assert proxy._pull_ready(items, held) == [2]
+        assert proxy._pull_ready(items, held) is end
+    elif case == "behind":
+        # Four stored: one pull brings all four, then waits for the fifth.
+        items = _Scripted([1, 2, 3, 4, 5], stored=4)
+        assert proxy._pull_ready(items, held) == [1, 2, 3, 4]
+        assert items.asked == 4          # three yes, one no
+        assert proxy._pull_ready(items, held) == [5]
+    elif case == "capped":
+        monkeypatch.setattr(proxy, "_PULL_MAX_ITEMS", 3)
+        items = _Scripted(range(8), stored=8)
+        assert proxy._pull_ready(items, held) == [0, 1, 2]
+        assert proxy._pull_ready(items, held) == [3, 4, 5]
+        assert proxy._pull_ready(items, held) == [6, 7]
+    elif case == "ends_in_a_pull":
+        # "Stored" but gone: the end is kept for the next pull.
+        items = _Scripted([1, 2], stored=3)
+        assert proxy._pull_ready(items, held) == [1, 2]
+        assert held == [end]
+        assert proxy._pull_ready(items, held) is end
+        assert held == []
+    elif case == "error_in_a_pull":
+        # The items before the error reach the client; the error is
+        # raised by the pull after, not swallowed.
+        items = _Scripted([1, 2, ValueError("replica gone"), 4], stored=4)
+        assert proxy._pull_ready(items, held) == [1, 2]
+        with pytest.raises(ValueError, match="replica gone"):
+            proxy._pull_ready(items, held)
+        assert proxy._pull_ready(items, held) == [4]
+    else:
+        # A plain iterator (no ``ready``): one item a pull, as before.
+        items = iter([1, 2])
+        assert proxy._pull_ready(items, held) == [1]
+        assert proxy._pull_ready(items, held) == [2]
+        assert proxy._pull_ready(items, held) is end
+
+
 def test_http_error_does_not_kill_connection(ingress):
     http_port, _ = ingress
     conn = http.client.HTTPConnection("127.0.0.1", http_port)

@@ -179,40 +179,45 @@ def _hist_totals(*hists):
 
 
 def test_engine_phases_cover_the_steps_wall_time():
-    """Every stretch of ``step()`` is inside a ``tracing.phase``: over a
-    run with admissions, re-uploads, ticks and finishes, each phase
-    histogram gains observations, and their sums add up to the wall
-    time of the steps within 10% (no part of the engine thread's loop is
-    left untimed, and the prefill program is not booked twice)."""
+    """Every stretch of ``step()`` is timed: over a run with admissions,
+    re-uploads, ticks and finishes each phase histogram gains
+    observations, one tick a step and one prefill a batch. A tick's
+    clock runs from the previous row's landing to its own, so the ticks
+    overlap the host phases (that is the pipeline) but not each other
+    nor a prefill: the programs' sum stays inside the wall time of the
+    steps, the host phases' sum with the prefills' does too, and between
+    them no part of the loop is left untimed."""
     from ray_tpu._private import metrics_defs as mdefs
 
-    phases = (mdefs.CB_STEP_ADMIT_MS, mdefs.CB_PREFILL_MS,
-              mdefs.CB_STEP_UPLOAD_MS, mdefs.CB_TICK_MS,
-              mdefs.CB_STEP_ACCOUNT_MS, mdefs.CB_STEP_APPLY_MS)
+    host = (mdefs.CB_STEP_ADMIT_MS, mdefs.CB_STEP_UPLOAD_MS,
+            mdefs.CB_STEP_ACCOUNT_MS, mdefs.CB_STEP_APPLY_MS)
+    programs = (mdefs.CB_PREFILL_MS, mdefs.CB_TICK_MS)
     cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
     eng = ContinuousBatcher(cfg, num_slots=4, max_len=64, block_size=16)
     for i in range(4):                       # compile outside the clock
         eng.submit([1, 2, 3, i + 1], max_new_tokens=4)
     eng.run_to_completion()
-    before, batches_before = _hist_totals(*phases), eng.prefill_batches
+    before = _hist_totals(*host, *programs)
+    batches_before = eng.prefill_batches
     for i in range(9):                       # 9 requests over 4 slots
         eng.submit([1, 2, 3, i + 1], max_new_tokens=6 + i % 3)
-    wall_ms, steps = 0.0, 0
+    steps, t0 = 0, time.perf_counter()
     while eng.has_work():
-        t0 = time.perf_counter()
         eng.step()
-        wall_ms += (time.perf_counter() - t0) * 1e3
         steps += 1
-    after = _hist_totals(*phases)
+    wall_ms = (time.perf_counter() - t0) * 1e3
+    after = _hist_totals(*host, *programs)
     gained = {k: (after[k][0] - before[k][0], after[k][1] - before[k][1])
               for k in after}
     assert all(n > 0 for _, n in gained.values()), gained
     assert gained[mdefs.CB_TICK_MS.name][1] == steps
     assert (gained[mdefs.CB_PREFILL_MS.name][1]
             == eng.prefill_batches - batches_before)
-    covered = sum(ms for ms, _ in gained.values())
-    assert covered <= wall_ms
-    assert covered == pytest.approx(wall_ms, rel=0.10), (gained, wall_ms)
+    host_ms = sum(gained[h.name][0] for h in host)
+    prefill_ms, tick_ms = (gained[h.name][0] for h in programs)
+    assert prefill_ms + tick_ms <= wall_ms, (gained, wall_ms)
+    assert host_ms + prefill_ms <= wall_ms, (gained, wall_ms)
+    assert host_ms + prefill_ms + tick_ms >= 0.9 * wall_ms, (gained, wall_ms)
 
 
 def test_generate_under_a_held_lock_books_the_wait(ray_start_regular,

@@ -107,6 +107,36 @@ def test_local_async_actor_streaming(ray_start_regular):
     assert [ray_tpu.get(r, timeout=30) for r in it] == [7, 8]
 
 
+def test_local_stream_says_when_its_next_item_is_stored(ray_start_regular):
+    """``ready()`` is true exactly while ``next()`` would not wait, and
+    takes nothing from the stream."""
+    import threading
+
+    go = threading.Event()
+
+    @ray_tpu.remote(num_returns="streaming")
+    def gen():
+        yield 1
+        yield 2
+        go.wait(30)
+        yield 3
+
+    it = gen.remote()
+    assert ray_tpu.get(next(it), timeout=30) == 1
+    deadline = time.monotonic() + 30
+    while not it.ready():
+        assert time.monotonic() < deadline
+        time.sleep(0.01)
+    assert it.ready()                   # asking consumes nothing
+    assert ray_tpu.get(next(it), timeout=30) == 2
+    assert not it.ready()               # the producer is held at item 3
+    go.set()
+    assert ray_tpu.get(next(it), timeout=30) == 3
+    assert not it.ready()               # the end is not an item
+    with pytest.raises(StopIteration):
+        next(it)
+
+
 def test_local_abandoned_stream_tail_reaped(ray_start_regular):
     """Dropping an ObjectRefGenerator mid-stream must not pin the tail
     items in the store forever."""
@@ -182,6 +212,26 @@ def test_cluster_streaming_before_completion(stream_cluster):
     assert first_latency < 1.5, first_latency
     rest = [ray_tpu.get(r, timeout=30) for r in it]
     assert rest == [1, 2, 3]
+
+
+def test_cluster_stream_says_when_its_next_item_is_stored(stream_cluster):
+    @ray_tpu.remote(num_returns="streaming")
+    def gen():
+        yield 1
+        yield 2
+        time.sleep(1.0)
+        yield 3
+
+    it = gen.remote()
+    assert ray_tpu.get(next(it), timeout=60) == 1
+    deadline = time.monotonic() + 30
+    while not it.ready():
+        assert time.monotonic() < deadline
+        time.sleep(0.01)
+    assert ray_tpu.get(next(it), timeout=30) == 2
+    assert not it.ready()
+    assert [ray_tpu.get(r, timeout=30) for r in it] == [3]
+    assert not it.ready()
 
 
 def test_cluster_streaming_large_items(stream_cluster):

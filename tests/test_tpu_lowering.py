@@ -144,6 +144,7 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@functools.lru_cache(maxsize=None)
 def compile_paged_tick(sharding, kv_dtype, spec_k=0):
     """AOT-compile the paged decode tick at the sizes above for
     ``sharding``'s chip; with ``spec_k``, the speculative tick (that
@@ -229,6 +230,65 @@ def test_compiled_paged_tick_moves_no_arena_slab(v5e_chip, kv_dtype):
     assert [line.strip() for line in body.splitlines()
             if f"= s32[{visits}]" in line
             and " get-tuple-element(" not in line] == []
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_compiled_tick_keeps_its_operands_and_donates_the_arena_alone(
+        v5e_chip, kv_dtype):
+    """What the engine's pipeline leans on (PR 28 changed no program):
+    the tick takes the parameters, tokens, positions, tables, limits,
+    arena and step it took, and aliases onto its outputs the ARENA's
+    buffers only. The token vector it returns is a buffer of its own, so
+    tick n's row can still be fetched after tick n+1, which reads it,
+    has been dispatched."""
+    hlo = compile_paged_tick(v5e_chip, kv_dtype).as_text()
+    entry = hlo[hlo.index("\nENTRY "):]
+    operands = {int(n) for n in re.findall(r" parameter\((\d+)\)", entry)}
+    arenas = 4 if kv_dtype == "int8" else 2
+    # embed, final_norm, lm_head and nine stacked layer weights; tokens,
+    # positions, tables, limits; the arena; the step counter.
+    assert operands == set(range(12 + 4 + arenas + 1))
+    header = hlo[:hlo.index("\n")]
+    aliased = re.findall(r"\{[\d, ]*\}: \((\d+), ", header)
+    assert sorted(int(n) for n in aliased) == list(range(16, 16 + arenas))
+
+
+def test_admissions_after_warm_up_compile_nothing():
+    """The engine's own programs on the CPU, counted by ``xla_monitor``
+    as ``compiles_in_window`` counts them: once a warm-up has admitted
+    into a running batch (a re-upload with a tick in flight, which is
+    where ``cb_merge_tokens`` runs), a run with admissions, ends and a
+    cancel compiles nothing, and ``cb_tick`` keeps its one signature."""
+    from ray_tpu._private import xla_monitor
+    from ray_tpu.models.continuous_batching import ContinuousBatcher
+
+    def compiles():
+        return {s["name"]: s["compiles"]
+                for s in xla_monitor.all_program_stats()}
+
+    cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
+    cold = compiles()
+    eng = ContinuousBatcher(cfg, num_slots=4, max_len=64, block_size=16)
+    eng.submit([1, 2, 3], max_new_tokens=6)
+    eng.step()
+    eng.submit([4, 5, 6, 7], max_new_tokens=3)     # joins a running batch
+    eng.run_to_completion()
+    warm = compiles()
+    gained = {n: warm[n] - cold.get(n, 0) for n in warm}
+    assert gained["cb_tick"] == 1 and gained["cb_merge_tokens"] == 1
+    assert gained["cb_prefill"] == 1
+    rids = []
+    for step in range(40):
+        if step < 4:        # one admission a step: the one-row prefill
+            rids.append(eng.submit([step + 1, 2, 3, 4, 5],
+                                   max_new_tokens=4 + 3 * step))
+        if step == 6:
+            assert eng.cancel(rids[3])
+            rids.append(eng.submit([6, 6, 6, 6], max_new_tokens=5))
+        eng.step()
+    assert not eng.has_work() and eng.prefill_batches == 2 + 5
+    assert compiles() == warm
+    assert eng._tick._cache_size() == 1
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])

@@ -164,6 +164,24 @@ def test_phase_observes_its_histogram_and_takes_an_inner_phase_out():
     assert _sum_count(outer_h)[1] == 2
 
 
+def test_phase_excludes_time_another_measurement_books():
+    """``exclude`` takes a stretch that passed inside the block out of
+    its observation (the engine's prefill leaves out its wait for the
+    decode tick queued ahead of it, which that tick's own clock
+    books); ``ms`` and what an outer phase subtracts stay the whole
+    interval."""
+    outer_h, inner_h = _hist("t_excl_outer_ms"), _hist("t_excl_inner_ms")
+    with tracing.phase("t.outer", outer_h) as outer:
+        with tracing.phase("t.inner", inner_h, outer=outer) as inner:
+            time.sleep(0.02)
+            waited = inner.elapsed_ms()
+            inner.exclude(waited)
+            time.sleep(0.01)
+    assert 20 <= waited <= inner.ms - 10
+    assert _sum_count(inner_h) == (pytest.approx(inner.ms - waited), 1)
+    assert _sum_count(outer_h)[0] == pytest.approx(outer.ms - inner.ms)
+
+
 def test_phase_is_a_span_only_while_a_profiler_session_is_active(tmp_path):
     """The span half: a ``TraceAnnotation`` of the same interval, which
     reaches a trace only under a profiler session, at the host tracer
