@@ -21,6 +21,13 @@ seed), and checks what comes out by the repo's own means:
                 reference at the published widths, and three faults
                 (an expert dropped, weights renormalised, no QK-norm)
                 shown to land outside the benchmark's tolerances;
+* ``hybrid``    the Granite 4.0-H family at the published widths (two
+                Mamba-2 layers and an attention layer): a padded,
+                multi-chunk bf16 prefill, then 300 ticks through the
+                state cache, every position's logits against the float32
+                reference's full forward; six faults shown to land
+                outside the configuration file's tolerances, and a bf16
+                state measured beside them;
 * ``train``     ``ShardedTrainer`` on one device, batch 5 x 2048: loss
                 finite and falling, one compiled signature, the Mosaic
                 custom calls present in the compiled step;
@@ -56,13 +63,15 @@ import sys
 import threading
 import time
 
-PHASES = ("kernels", "moe", "train", "serve", "multichip")
+PHASES = ("kernels", "moe", "hybrid", "train", "serve", "multichip")
 # The multichip phase is two children: the trainer's state must be gone
 # from the chips before four serving replicas load theirs.
-CHILDREN = {"kernels": ("kernels",), "moe": ("moe",), "train": ("train",),
+CHILDREN = {"kernels": ("kernels",), "moe": ("moe",),
+            "hybrid": ("hybrid",), "train": ("train",),
             "serve": ("serve",),
             "multichip": ("multichip-train", "multichip-serve")}
-PHASE_TIMEOUT_S = {"kernels": 600, "moe": 600, "train": 480, "serve": 600,
+PHASE_TIMEOUT_S = {"kernels": 600, "moe": 600, "hybrid": 1500, "train": 480,
+                   "serve": 600,
                    "multichip-train": 900, "multichip-serve": 900}
 RESULT_TAG = "PHASE_RESULT "
 NO_ACCELERATOR_RC = 3    # a child found no TPU: no later phase can pass
@@ -677,6 +686,220 @@ def phase_moe(rehearse: bool) -> None:
     _finish(phase, info, faults=results)
 
 
+def phase_hybrid(rehearse: bool) -> None:
+    """The Granite 4.0-H family through the engine's two forwards, as
+    the served path runs them (bf16, every kernel), against
+    ``benchmark/reference_granite_hybrid.py`` (float32, a plain scan
+    over positions) at the published widths on the stack's first two
+    Mamba-2 layers and its attention layer. Two rows of teacher-forced
+    tokens: ONE right-padded prefill (300 and 200 tokens in the 512
+    bucket: four chunks of the scan, padding behind both rows) installs
+    each row's state in its slot, then 300 decode ticks advance the
+    state cache and the arena. EVERY position's logits are compared:
+    the largest difference at a position (judged by its MEDIAN over
+    positions: a router near-tie that bf16 flips moves one position's
+    logits by a step, so the worst position reads the flips and the
+    median the arithmetic), and how far the program's argmax lies under
+    the reference's maximum (judged by its worst, as the benchmark
+    does), both in standard deviations of the position's reference
+    logits.
+
+    Then the same comparison with one fault at a time, to show that the
+    tolerances of the benchmark's configuration file catch each: the
+    softmax scale ``head_dim ** -0.5`` where the family has 1/128; the
+    residual multiplier dropped; the gate applied AFTER the norm; ``D``
+    dropped; padding advancing the state; and the weights rounded to
+    float8, the nearest precision below the bf16 the configuration
+    states. The state rounded to bf16 after every tick is MEASURED
+    beside them and not required to fail: over 300 ticks it moves the
+    median difference by a tenth of itself (PERF.md section 6, PR 29),
+    inside any tolerance that passes the program as published."""
+    phase = "hybrid"
+    info = _open_device(phase, rehearse)
+    import dataclasses
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import manifest, reference_granite_hybrid
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models import llama, mamba2
+    from ray_tpu.models.paged_kv import PagedKVCache, StateCache
+
+    types = ("mamba", "mamba", "attention")
+    if rehearse:
+        config = llama.LlamaConfig.granite_4_0_h_small(
+            vocab_size=256, hidden_size=64, intermediate_size=32,
+            num_layers=3, layer_types=types, num_heads=4, num_kv_heads=2,
+            head_dim=16, attention_multiplier=1 / 16, num_experts=8,
+            num_experts_per_tok=2, shared_intermediate_size=48,
+            mamba_n_heads=8, mamba_d_head=8, mamba_d_state=16,
+            max_seq_len=64)
+        lengths, bucket, ticks, bs = (20, 13), 32, 12, 16
+        tolerance = {"serve_logit_gap_sd": 1.0,
+                     "smoke_median_logit_err_sd": 1.0}
+    else:
+        config = llama.LlamaConfig.granite_4_0_h_small(
+            num_layers=3, layer_types=types, max_seq_len=1024)
+        lengths, bucket, ticks, bs = (300, 200), 512, 300, 64
+        tolerance = manifest.load_json(os.path.join(
+            manifest.HERE, "configs", "granite-4.0-h-small-l6.json"))[
+                "tolerance"]
+    rows, max_blocks = len(lengths), -(-(bucket + ticks) // bs)
+    params = jax.jit(lambda k: llama.init_params(config, k))(
+        jax.random.PRNGKey(0))
+    rng = np.random.default_rng(29)
+    seqs = [rng.integers(1, config.vocab_size, n + ticks) for n in lengths]
+    # The reference's logits at each row's last prompt position and at
+    # every tick after it: [rows, ticks + 1, V].
+    want = jnp.stack([reference_granite_hybrid.logits(
+        params, seq.tolist(), config)[n - 1:] for seq, n in
+        zip(seqs, lengths)])
+    _say(phase, f"reference: {rows} rows of {lengths} + {ticks} positions")
+
+    prompt = np.zeros((rows, bucket), np.int32)
+    for i, (seq, n) in enumerate(zip(seqs, lengths)):
+        prompt[i, :n] = seq[:n]
+    fed = jnp.asarray(np.stack([seq[n:] for seq, n in zip(seqs, lengths)]))
+    tables = 1 + np.arange(rows * max_blocks, dtype=np.int32).reshape(
+        rows, max_blocks)
+    limits = jnp.full((rows,), max_blocks * bs, jnp.int32)
+    last_idx = jnp.asarray(lengths, jnp.int32) - 1
+
+    def compare(got, ref):
+        """got, ref [rows, V] -> (largest difference, gap of got's
+        argmax under ref's maximum), in ref's standard deviations."""
+        sd = jnp.std(ref, axis=-1)
+        picked = jnp.take_along_axis(
+            ref, jnp.argmax(got, -1)[:, None], axis=-1)[:, 0]
+        return (jnp.max(jnp.abs(got - ref), axis=-1) / sd,
+                (jnp.max(ref, axis=-1) - picked) / sd)
+
+    def run(cfg, weights, round_state=False):
+        use_kernel = cb._resolve_decode_kernel(cfg, None, bs)
+
+        @jax.jit
+        def program(weights, want):
+            cache = PagedKVCache.create(cfg, rows * max_blocks + 1, bs)
+            state = StateCache.create(cfg, rows)
+            empty = jnp.zeros((cfg.attn_layers, rows, 0, cfg.num_kv_heads,
+                               cfg.head_dim), cfg.dtype)
+            logits, (k, v), state = cb._prefill_forward_paged(
+                weights, jnp.asarray(prompt), jnp.arange(bucket), empty,
+                empty, cfg, False, last_idx, use_kernel, state,
+                jnp.arange(rows))
+            flat = jnp.asarray(tables[:, :bucket // bs]).reshape(-1)
+            to_blocks = functools.partial(cb._ctx_to_blocks, bs=bs)
+            cache = PagedKVCache(k=cache.k.at[:, flat].set(to_blocks(k)),
+                                 v=cache.v.at[:, flat].set(to_blocks(v)))
+            first = compare(logits[:, 0], want[:, 0])
+
+            def tick(carry, inputs):
+                caches, positions = carry
+                tokens, ref = inputs
+                logits, (cache, state), _ = cb._forward_paged(
+                    weights, tokens[:, None], positions[:, None],
+                    jnp.asarray(tables), limits, caches, cfg, use_kernel)
+                if round_state:
+                    # Not astype there and back: XLA may keep the excess
+                    # precision of a convert pair.
+                    state = state._replace(ssm=jax.lax.reduce_precision(
+                        state.ssm, exponent_bits=8, mantissa_bits=7))
+                return ((cache, state), positions + 1), compare(
+                    logits[:, 0], ref)
+
+            _, rest = jax.lax.scan(
+                tick, ((cache, state), last_idx + 1),
+                (fed.T, jnp.swapaxes(want[:, 1:], 0, 1)))
+            return jax.tree.map(
+                lambda a, b: jnp.concatenate([a[None], b]), first, rest)
+
+        err, gap = (np.asarray(a) for a in program(weights, want))
+        return {"prefill_err_sd": float(err[0].max()),
+                "median_err_sd": float(np.median(err)),
+                "p90_err_sd": float(np.percentile(err, 90)),
+                "worst_err_sd": float(err.max()),
+                "late_median_err_sd": float(np.median(err[-ticks // 4:])),
+                "worst_gap_sd": float(gap.max()),
+                "mean_gap_sd": float(gap.mean())}
+
+    def swap(name, fn):
+        """``mamba2.<name>`` replaced for one run."""
+        return lambda: setattr(mamba2, name, fn)
+
+    real_gate, real_prefill = mamba2._gate_out, mamba2.mixer_prefill
+
+    def gate_after_norm(y, x, z, layer, c):
+        y = y + layer["ssm_d"].astype(jnp.float32)[:, None] * x.astype(
+            jnp.float32)
+        y = y.reshape(*z.shape)
+        y = y * jax.lax.rsqrt(
+            jnp.mean(jnp.square(y), axis=-1, keepdims=True) + c.rms_eps)
+        y = (y * layer["ssm_norm"].astype(jnp.float32)
+             * jax.nn.silu(z.astype(jnp.float32))).astype(c.dtype)
+        return jnp.einsum("bsf,fe->bse", y, layer["ssm_out"].astype(c.dtype))
+
+    def no_d(tree):
+        return dict(tree, runs=[
+            dict(run, ssm_d=jnp.zeros_like(run["ssm_d"]))
+            if "ssm_d" in run else run for run in tree["runs"]])
+
+    def float8(tree):
+        return jax.tree.map(
+            lambda a: a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+            if a.dtype == jnp.bfloat16 else a, tree)
+
+    measured_only = "state rounded to bf16 every tick"
+    cases = [
+        ("as published", config, lambda: params, {}, None),
+        (measured_only, config, lambda: params, {"round_state": True},
+         None),
+        ("softmax scale head_dim ** -0.5", dataclasses.replace(
+            config, attention_multiplier=None), lambda: params, {}, None),
+        ("residual multiplier dropped", dataclasses.replace(
+            config, residual_multiplier=1.0), lambda: params, {}, None),
+        ("gate after the norm", config, lambda: params, {},
+         swap("_gate_out", gate_after_norm)),
+        ("D dropped", config, lambda: no_d(params), {}, None),
+        ("padding advances the state", config, lambda: params, {},
+         swap("mixer_prefill", lambda h, layer, c, n: real_prefill(
+             h, layer, c, jnp.full_like(n, h.shape[1])))),
+        ("weights rounded to float8_e4m3", config, lambda: float8(params),
+         {}, None),
+    ]
+    results = {}
+    for name, cfg, weights, options, patch in cases:
+        if patch:
+            patch()
+        try:
+            results[name] = run(cfg, weights(), **options)
+        finally:
+            mamba2._gate_out, mamba2.mixer_prefill = real_gate, real_prefill
+        r = results[name]
+        _say(phase, f"{name}: a position's largest logit difference, "
+                    f"median {r['median_err_sd']:.4f} sd (p90 "
+                    f"{r['p90_err_sd']:.4f}, worst {r['worst_err_sd']:.4f}, "
+                    f"prefill {r['prefill_err_sd']:.4f}, median of the last "
+                    f"quarter of the ticks {r['late_median_err_sd']:.4f}); "
+                    f"chosen-token gap worst {r['worst_gap_sd']:.4f} sd, "
+                    f"mean {r['mean_gap_sd']:.4f}")
+    gap_tol = tolerance["serve_logit_gap_sd"]
+    err_tol = tolerance["smoke_median_logit_err_sd"]
+    wrong = []
+    for name, r in results.items():
+        caught = (r["worst_gap_sd"] > gap_tol
+                  or max(r["median_err_sd"], r["late_median_err_sd"])
+                  > err_tol)
+        if name == measured_only or (rehearse and name != "as published"):
+            continue        # tiny sizes prove nothing about the faults
+        if caught != (name != "as published"):
+            wrong.append(name)
+    assert not wrong, f"{wrong}: {results} against {tolerance}"
+    _finish(phase, info, faults=results, tolerance=tolerance)
+
+
 def _train_once(phase, config, mesh, batch_size, seq_len, steps, rehearse):
     """Init + ``steps`` steps on one repeated batch. Returns (losses,
     trainer, state)."""
@@ -1075,7 +1298,7 @@ def _child(phase: str, rehearse: bool) -> int:
 
 
 CHILD_FNS = {"kernels": phase_kernels, "moe": phase_moe,
-             "train": phase_train,
+             "hybrid": phase_hybrid, "train": phase_train,
              "serve": phase_serve, "multichip-train": phase_multichip_train,
              "multichip-serve": phase_multichip_serve}
 

@@ -526,6 +526,17 @@ CB_SPEC_ACCEPT_RATE = Gauge(
     "last RAY_TPU_SPEC_WINDOW spec ticks) — the controller input that "
     "moves spec_k along its rung ladder",
     ("engine",))
+CB_STATE_CACHE_BYTES = Gauge(
+    "ray_tpu_cb_state_cache_bytes",
+    "Resident bytes of the per-slot state cache beside the K/V arena "
+    "(state-space layers: recurrent state and convolution tail of every "
+    "slot); fixed at construction, whatever the contexts",
+    ("engine",))
+CB_STATE_INSTALLS = Counter(
+    "ray_tpu_cb_state_installs_total",
+    "Prompts whose final recurrent state a prefill installed in a slot "
+    "of the state cache",
+    ("engine",))
 CB_SPEC_K = Gauge(
     "ray_tpu_cb_spec_k",
     "Live speculative draft depth k the engine is dispatching (0 = the "

@@ -56,15 +56,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import xla_monitor
-from ray_tpu.models import llama
+from ray_tpu.models import llama, mamba2
 from ray_tpu.models.inference import (ExternalLlamaDrafter, KVCache,
                                       SelfDrafter, _attend_cached,
                                       _forward_cached, lm_head_logits)
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.models.paged_kv import (GARBAGE_BLOCK, BlockAllocator,
                                      PagedKVCache, RadixBlockIndex,
-                                     prompt_chunks, quantize_kv,
-                                     resolve_kv_dtype)
+                                     StateCache, prompt_chunks,
+                                     quantize_kv, resolve_kv_dtype)
 from ray_tpu.models.sampling import (SPEC_DRAFT_SALT, SamplingParams,
                                      filtered_probs, sample_tokens,
                                      spec_commit, step_key)
@@ -219,25 +219,72 @@ def _layer_qkv(x, layer, cos, sin, c):
     spec-on/off parity tests pin down."""
     h = rms_norm(x, layer["attn_norm"], c.rms_eps)
     q, k, v = llama.project_qkv(h, layer, c)
+    if not c.rope:      # position_embedding_type "nope": no rotation
+        return q, k, v
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
-def _layer_finish(x, o, layer, c, experts=None, li=None, use_kernel=None):
-    """Every engine layer's second half: the attention output projection
-    of o [B, S, H, D], then ``x + MLP(norm(x))`` through the family's
-    one MLP function (:func:`llama.mlp_block`: dense SwiGLU, or the
-    routed block reading the stacked ``experts`` at layer ``li``).
+def _rope_tables(c, length, positions):
+    """cos/sin for ``positions``; None for a model without rope."""
+    if not c.rope:
+        return None, None
+    return rope_frequencies(c.head_dim, length, c.rope_theta,
+                            positions=positions)
+
+
+def _embed(params, tokens, c):
+    x = params["embed"].astype(c.dtype)[tokens]
+    if c.embedding_multiplier != 1.0:
+        x = x * c.embedding_multiplier
+    return x
+
+
+def _attn_out(o, layer, c):
+    """Attention's output projection of o [B, S, H, D] -> [B, S, E]."""
+    return jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
+
+
+def _residual(x, y, c):
+    """``x + residual_multiplier * y`` (GraniteMoeHybridDecoderLayer)."""
+    return x + y if c.residual_multiplier == 1.0 else (
+        x + y * c.residual_multiplier)
+
+
+def _layer_finish(x, mixed, layer, c, experts=None, li=None,
+                  use_kernel=None):
+    """Every engine layer's second half: ``mixed`` [B, S, E], the token
+    mixer's output (attention's :func:`_attn_out`, or the Mamba-2
+    mixer's), joins the residual, then ``x + MLP(norm(x))`` through the
+    family's one MLP function (:func:`llama.mlp_block`: dense SwiGLU, or
+    the routed block reading the stacked ``experts`` at layer ``li``).
     Returns (x, rows): the routed block's per-expert assignment counts,
     None for a dense model."""
-    x = x + jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
+    x = _residual(x, mixed, c)
     h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
     down, routed = llama.mlp_block(h, layer, c, experts, li,
                                    use_kernel=use_kernel)
-    return x + down, None if routed is None else routed.rows
+    return _residual(x, down, c), None if routed is None else routed.rows
+
+
+def _concat_runs(parts):
+    """Per-run scan outputs (trees stacked over each run's layers)
+    joined along the layer axis; one run's come back as they are."""
+    parts = [p for p in parts if p is not None]
+    if len(parts) <= 1:
+        return parts[0] if parts else None
+    return jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *parts)
+
+
+def _split_caches(caches):
+    """(arena, state cache) of an engine program's ``caches`` operand:
+    the arena alone for a model without state layers, else the pair."""
+    if isinstance(caches, PagedKVCache):
+        return caches, None
+    return caches
 
 
 def _forward_paged(params, tokens, positions, tables, limits,
-                   cache: PagedKVCache, config: llama.LlamaConfig,
+                   caches, config: llama.LlamaConfig,
                    use_kernel: bool, n_layers: Optional[int] = None):
     """The engine's ONE forward over the paged arena: each slot's window
     of S tokens [B, S] at per-slot absolute ``positions`` [B, S]
@@ -259,15 +306,24 @@ def _forward_paged(params, tokens, positions, tables, limits,
     speculative roofline lever — while attention runs per window
     position (:func:`_write_then_attend`).
 
+    The stack is scanned a RUN of equal layers at a time
+    (:func:`llama.layer_runs`; a homogeneous model is one run). A
+    "mamba" layer (S = 1 only) mixes through
+    :func:`mamba2.mixer_step`, which advances every slot's row of the
+    per-slot state cache beside the arena in place; the arena holds the
+    attention layers alone, so each kind indexes its own cache by its
+    index among layers of its kind. ``caches`` is the arena, or the pair
+    (arena, state cache) for a model with state layers.
+
     Returns (fp32 logits [B, S, V] through the final norm + lm_head,
-    the updated cache, and a routed model's per-layer per-expert row
-    counts [L, X], None for a dense model)."""
+    the updated ``caches``, and a routed model's per-layer per-expert
+    row counts [L, X], None for a dense model)."""
     c = config
+    cache, state = _split_caches(caches)
     bs = cache.block_size
-    cos, sin = rope_frequencies(c.head_dim, 0, c.rope_theta,
-                                positions=positions)      # [B, S, D//2]
-    x = params["embed"].astype(c.dtype)[tokens]               # [B, S, E]
-    scale = c.head_dim ** -0.5
+    cos, sin = _rope_tables(c, 0, positions)                  # [B, S, D//2]
+    x = _embed(params, tokens, c)                             # [B, S, E]
+    scale = c.attn_scale
     # Resolve each position's target block through the slot's table once
     # (shared by every layer's write). Speculative ticks can OVERRUN a
     # slot's reservation (the host detects finishes up to 2K ticks
@@ -281,28 +337,43 @@ def _forward_paged(params, tokens, positions, tables, limits,
     offset = positions % bs
     visits = _window_visits(tables, positions, limits, bs, use_kernel)
 
-    scanned, experts = llama.split_layers(params, n_layers)
+    runs, experts = llama.layer_runs(c, params, n_layers)
 
-    def layer_fn(carry, layer):
-        # The arena rides the CARRY, updated in place layer by layer,
-        # not scan xs/ys: as per-iteration inputs/outputs XLA
-        # materializes full cache copies every tick.
-        x, arenas, li = carry
-        q, k, v = _layer_qkv(x, layer, cos, sin, c)
-        o, arenas = _write_then_attend(
-            arenas, li, q, k, v, block_idx, offset, tables, positions,
-            visits, scale, use_kernel)
-        x, rows = _layer_finish(x, o.astype(x.dtype), layer, c, experts,
-                                li, use_kernel)
-        return (x, arenas, li + 1), rows
+    def layer_fn(carry, layer, kind, shift):
+        # The arena (and the state cache) ride the CARRY, updated in
+        # place layer by layer, not scan xs/ys: as per-iteration
+        # inputs/outputs XLA materializes full cache copies every tick.
+        x, arenas, held, li = carry
+        ki = li + shift if shift else li    # index among layers of its kind
+        if kind == "mamba":
+            h = rms_norm(x, layer["attn_norm"], c.rms_eps)
+            mixed, *held = mamba2.mixer_step(h, layer, c, *held, ki,
+                                             use_kernel)
+            held = tuple(held)
+        else:
+            q, k, v = _layer_qkv(x, layer, cos, sin, c)
+            o, arenas = _write_then_attend(
+                arenas, ki, q, k, v, block_idx, offset, tables, positions,
+                visits, scale, use_kernel)
+            mixed = _attn_out(o.astype(x.dtype), layer, c)
+        x, rows = _layer_finish(x, mixed, layer, c, experts, li,
+                                use_kernel)
+        return (x, arenas, held, li + 1), rows
 
-    (x, arenas, _), rows = jax.lax.scan(
-        layer_fn, (x, tuple(cache), jnp.int32(0)), scanned)
+    arenas, held = tuple(cache), tuple(state or ())
+    rows = []
+    for (kind, start, _, kind_start), tree in runs:
+        (x, arenas, held, _), run_rows = jax.lax.scan(
+            functools.partial(layer_fn, kind=kind, shift=kind_start - start),
+            (x, arenas, held, jnp.int32(start)), tree)
+        rows.append(run_rows)
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     # lm_head in the params' storage dtype with fp32 accumulation
     # (shared with prefill): bf16 params are never upcast in HBM.
     logits = lm_head_logits(x, params, c)
-    return logits, PagedKVCache(*arenas), rows
+    cache = PagedKVCache(*arenas)
+    return (logits, (cache, StateCache(*held)) if held else cache,
+            _concat_runs(rows))
 
 
 def _draft_forward_dense(dparams, tokens, positions, dcache: KVCache,
@@ -312,10 +383,9 @@ def _draft_forward_dense(dparams, tokens, positions, dcache: KVCache,
     target; reference attention — the drafter is small by construction).
     tokens/positions [B]. Returns (logits [B, V], updated cache)."""
     c = dconfig
-    cos, sin = rope_frequencies(c.head_dim, 0, c.rope_theta,
-                                positions=positions[:, None])
-    x = dparams["embed"].astype(c.dtype)[tokens][:, None, :]
-    scale = c.head_dim ** -0.5
+    cos, sin = _rope_tables(c, 0, positions[:, None])
+    x = _embed(dparams, tokens, c)[:, None, :]
+    scale = c.attn_scale
 
     def layer_fn(carry, layer):
         x, ck_all, cv_all, li = carry
@@ -327,7 +397,8 @@ def _draft_forward_dense(dparams, tokens, positions, dcache: KVCache,
         o = decode_attention_reference(q[:, 0], ck, cv, positions, scale)
         ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
         cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-        x, _ = _layer_finish(x, o[:, None], layer, c, experts, li)
+        x, _ = _layer_finish(x, _attn_out(o[:, None], layer, c), layer, c,
+                             experts, li)
         return (x, ck_all, cv_all, li + 1), None
 
     scanned, experts = llama.split_layers(dparams)
@@ -405,20 +476,21 @@ def _spec_tick_paged(params, tokens, positions, tables, limits,
 
 
 def _decode_tick_paged(params, tokens, positions, tables, limits,
-                       cache: PagedKVCache, step,
+                       caches, step,
                        config: llama.LlamaConfig, use_kernel: bool = False,
                        sampling: SamplingParams = SamplingParams()):
     """One decode step for every slot, the S = 1 case of
     :func:`_forward_paged`: tokens [B] at per-slot absolute
-    ``positions`` [B]. Returns (next_tokens [B], positions+1, cache,
-    step+1) — ``step`` is the device-resident sampling counter. Token
-    selection stays ON DEVICE: the host needs 4 bytes per slot, not the
-    [B, V] logits."""
-    logits, cache, rows = _forward_paged(
+    ``positions`` [B]. ``caches`` is the K/V arena, or, for a model with
+    state layers, the pair (arena, state cache). Returns (next_tokens
+    [B], positions+1, caches, step+1) — ``step`` is the device-resident
+    sampling counter. Token selection stays ON DEVICE: the host needs 4
+    bytes per slot, not the [B, V] logits."""
+    logits, caches, rows = _forward_paged(
         params, tokens[:, None], positions[:, None], tables, limits,
-        cache, config, use_kernel)
+        caches, config, use_kernel)
     next_tokens = _next_tokens(logits, step, sampling)
-    state = (next_tokens, positions + 1, cache, step + 1)
+    state = (next_tokens, positions + 1, caches, step + 1)
     if rows is None:
         return state
     # A routed model's tick also reports each layer's per-expert row
@@ -428,52 +500,86 @@ def _decode_tick_paged(params, tokens, positions, tables, limits,
 
 
 def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
-                           quantized, use_kernel=None):
+                           quantized, last_idx, use_kernel=None,
+                           state: Optional[StateCache] = None, slots=None):
     """Prefill forward over ``[shared prefix ++ suffix]``.
 
     ``tokens`` [N, S] are the suffix at absolute ``positions`` [S]
     (= P + arange(S), shared by the group — admission groups rows by
-    matched-prefix length); ``pk``/``pv`` [L, N, P, KVH, D] hold the
+    matched-prefix length); ``pk``/``pv`` [L_attn, N, P, KVH, D] hold the
     prefix K/V exactly as attention must read them (the dequantized
-    arena storage). Returns ``(logits [N, S, V], stored)`` where
+    arena storage); ``last_idx`` [N] is each row's last real position.
+    Returns ``(logits [N, 1, V], stored, state)``. The head runs on
+    the hidden state at ``last_idx`` ONLY: the one position a prefill
+    samples from (all-position float32 logits are N x S x V x 4 bytes,
+    2.5 GB for 48 x 128 rows of a 100k vocabulary).
     ``stored`` is the suffix K/V in ARENA form — int8 arenas quantize
     IN-LOOP and attention reads the dequantized values, so what a later
     prefix-sharer gathers back from the arena is bit-identical to what
-    this prefill attended: the prefix-cache on/off parity contract."""
+    this prefill attended: the prefix-cache on/off parity contract.
+    ``state`` (None without state layers) is the state cache with, in
+    row ``slots[i]`` of every state layer, prompt i's recurrent state
+    and conv tail after its last real token: a right-padded row's
+    padding must not advance them (K/V past the end are merely never
+    attended; a state has no mask), so :func:`mamba2.mixer_prefill`
+    takes the rows' lengths. Each layer writes its rows into the cache
+    as the layer loop's CARRY (the five layers' states of a 48-row
+    batch, stacked as the loop's output, would be a second gigabyte);
+    a repeated padding row writes the same bytes twice."""
     c = config
-    cos, sin = rope_frequencies(c.head_dim, tokens.shape[1], c.rope_theta,
-                                positions=positions)
-    x = params["embed"].astype(c.dtype)[tokens]
-    scale = c.head_dim ** -0.5
+    cos, sin = _rope_tables(c, tokens.shape[1], positions)
+    x = _embed(params, tokens, c)
+    scale = c.attn_scale
 
-    scanned, experts = llama.split_layers(params)
+    runs, experts = llama.layer_runs(c, params)
 
-    def layer_fn(carry, inputs):
-        x, li = carry
-        layer, pk_l, pv_l = inputs
-        q, k, v = _layer_qkv(x, layer, cos, sin, c)
-        if quantized:
-            kq, ksc = quantize_kv(k)
-            vq, vsc = quantize_kv(v)
-            k_att = (kq.astype(jnp.float32)
-                     * ksc[..., None]).astype(c.dtype)
-            v_att = (vq.astype(jnp.float32)
-                     * vsc[..., None]).astype(c.dtype)
-            stored = (kq, vq, ksc, vsc)
+    def layer_fn(carry, inputs, kind, shift):
+        x, held, li = carry
+        kept = ()
+        if kind == "mamba":
+            layer, = inputs
+            h = rms_norm(x, layer["attn_norm"], c.rms_eps)
+            mixed, *new = mamba2.mixer_prefill(h, layer, c, last_idx + 1)
+            held = tuple(a.at[li + shift, slots].set(n.astype(a.dtype))
+                         for a, n in zip(held, new))
         else:
-            k_att, v_att = k, v
-            stored = (k, v)
-        ck = jnp.concatenate([pk_l, k_att], axis=1)   # [N, P+S, KVH, D]
-        cv = jnp.concatenate([pv_l, v_att], axis=1)
-        o = _attend_cached(q, ck, cv, positions, scale)
-        x, _ = _layer_finish(x, o, layer, c, experts, li, use_kernel)
-        return (x, li + 1), stored
+            layer, pk_l, pv_l = inputs
+            q, k, v = _layer_qkv(x, layer, cos, sin, c)
+            if quantized:
+                kq, ksc = quantize_kv(k)
+                vq, vsc = quantize_kv(v)
+                k_att = (kq.astype(jnp.float32)
+                         * ksc[..., None]).astype(c.dtype)
+                v_att = (vq.astype(jnp.float32)
+                         * vsc[..., None]).astype(c.dtype)
+                kept = (kq, vq, ksc, vsc)
+            else:
+                k_att, v_att = k, v
+                kept = (k, v)
+            ck = jnp.concatenate([pk_l, k_att], axis=1)   # [N, P+S, KVH, D]
+            cv = jnp.concatenate([pv_l, v_att], axis=1)
+            mixed = _attn_out(_attend_cached(q, ck, cv, positions, scale),
+                              layer, c)
+        x, _ = _layer_finish(x, mixed, layer, c, experts, li, use_kernel)
+        return (x, held, li + 1), kept
 
-    (x, _), stored = jax.lax.scan(layer_fn, (x, jnp.int32(0)),
-                                  (scanned, pk, pv))
+    stored, held = [], tuple(state or ())
+    for (kind, start, count, kind_start), tree in runs:
+        inputs = (tree,)
+        if kind == "attention":
+            whole = count == pk.shape[0]
+            inputs += tuple(a if whole else a[kind_start:kind_start + count]
+                            for a in (pk, pv))
+        (x, held, _), kept = jax.lax.scan(
+            functools.partial(layer_fn, kind=kind,
+                              shift=kind_start - start),
+            (x, held, jnp.int32(start)), inputs)
+        stored.append(kept or None)
+    x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)  # [N, 1, E]
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     logits = lm_head_logits(x, params, c)
-    return logits, stored
+    return (logits, _concat_runs(stored),
+            StateCache(*held) if held else None)
 
 
 def _bucket(n: int, floor: int = 16) -> int:
@@ -719,6 +825,9 @@ class ContinuousBatcher:
                 f"block_size must be a power of two >= 8, "
                 f"got {self.block_size}")
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
+        if config.state_layers:
+            self._refuse_for_state_layers(prefix_cache, spec_k, drafter)
+            prefix_cache = False    # nothing to share: off unless asked
         self.prefix_cache = _resolve_prefix_cache(prefix_cache)
         self.use_decode_kernel = _resolve_decode_kernel(
             config, use_decode_kernel, self.block_size)
@@ -805,7 +914,8 @@ class ContinuousBatcher:
         # spec-aware tick_bytes_estimate prices drafts from these.
         self._head_param_bytes = sum(
             self.params[k].nbytes
-            for k in ("embed", "final_norm", "lm_head"))
+            for k in ("embed", "final_norm", "lm_head")
+            if k in self.params)    # a tied head is the embedding
         self._layer_param_bytes = self.param_bytes - self._head_param_bytes
         self._expert_param_bytes = sum(
             self.params["layers"][k].nbytes for k in llama.EXPERT_KEYS
@@ -826,6 +936,10 @@ class ContinuousBatcher:
             num_blocks if num_blocks is not None
             else num_slots * self.max_blocks + 1)
         self.cache = self._new_cache()
+        # The per-slot state cache of a model with state-space layers
+        # (None otherwise): owned, donated and rebuilt with the arena.
+        self.state = self._new_state()
+        self.state_installs = 0         # prompts whose state was installed
         self.allocator = BlockAllocator(self.num_blocks)
         self._slot_blocks: Dict[int, List[int]] = {}
         # Radix index over block-aligned prompt chunks -> resident
@@ -920,8 +1034,8 @@ class ContinuousBatcher:
                                 shape_policy="bucketed",
                                 allowed_dims=prefill_dims,
                                 donate_argnums=(2,))
-        def prefill(params, tokens, cache, ptables, tables_w,
-                    last_idx, pstep):
+        def prefill(params, tokens, caches, ptables, tables_w,
+                    last_idx, pstep, slots=None):
             # BATCHED BUCKETED PREFILL, paged + prefix-aware: tokens
             # [N, S] holds N same-group SUFFIXES (prompt tokens not
             # covered by matched prefix blocks; the whole prompt
@@ -931,6 +1045,10 @@ class ContinuousBatcher:
             # written); ``tables_w`` [N, S // bs] names the blocks
             # the suffix K/V land in (overflow entries point at the
             # garbage block). Only N first tokens leave the device.
+            # ``caches`` is the arena or, with state layers, (arena,
+            # state cache); ``slots`` [N] then names the state-cache row
+            # each prompt's final state is installed in.
+            cache, held = _split_caches(caches)
             n, s_pad = tokens.shape
             m = ptables.shape[1]
             positions = m * block_size_c + jnp.arange(s_pad)
@@ -945,11 +1063,11 @@ class ContinuousBatcher:
                       * cache.v_scale[:, flat_p][..., None]
                       ).astype(cfg.dtype)
 
-            logits, stored = _prefill_forward_paged(
+            logits, stored, held = _prefill_forward_paged(
                 params, tokens, positions,
                 _blocks_to_ctx(pk.astype(cfg.dtype), n),
                 _blocks_to_ctx(pv.astype(cfg.dtype), n),
-                cfg, cache.quantized, use_kernel)
+                cfg, cache.quantized, last_idx, use_kernel, held, slots)
             flat_tables = tables_w.reshape(-1)           # [N * npb]
 
             to_blocks = functools.partial(_ctx_to_blocks,
@@ -972,17 +1090,16 @@ class ContinuousBatcher:
                         to_blocks(k_s.astype(dt))),
                     v=cache.v.at[:, flat_tables].set(
                         to_blocks(v_s.astype(dt))))
-            last = jnp.take_along_axis(
-                logits, last_idx[:, None, None], axis=1)  # [N, 1, V]
-            first = _next_tokens(last, pstep, sampling_cfg,
+            first = _next_tokens(logits, pstep, sampling_cfg,
                                  salt=_PREFILL_SALT)
-            return first, new_cache
+            return first, (new_cache if held is None
+                           else (new_cache, held))
 
         @xla_monitor.instrument(name="cb_tick", donate_argnums=(5,))
-        def tick(params, tokens, positions, tables, limits, cache,
+        def tick(params, tokens, positions, tables, limits, caches,
                  step):
             return _decode_tick_paged(params, tokens, positions,
-                                      tables, limits, cache, step,
+                                      tables, limits, caches, step,
                                       cfg, use_kernel=use_kernel,
                                       sampling=sampling_cfg)
 
@@ -1024,6 +1141,32 @@ class ContinuousBatcher:
         else:
             self._draft_prefill = None
 
+    def _refuse_for_state_layers(self, prefix_cache, spec_k, drafter):
+        """A model with state-space layers keeps, beside its K/V, a
+        recurrent state a slot that only moves FORWARD and belongs to
+        one request: whatever rests on rewinding, sharing or shipping
+        K/V alone is refused by name."""
+        def refuse(what, why):
+            raise ValueError(
+                f"{what} is not supported for a model with state-space "
+                f"layers (layer_types has 'mamba'): {why}")
+
+        if _resolve_spec_k(spec_k) or drafter is not None:
+            refuse("speculative decoding (spec_k > 0)",
+                   "a rejected draft cannot rewind a recurrent state")
+        if self.sync_every > 1:
+            refuse("buffered decode (sync_every > 1)",
+                   "its rewind after a finish replays ticks, which would "
+                   "advance a recurrent state twice")
+        if prefix_cache or (prefix_cache is None
+                            and env_flag("RAY_TPU_PREFIX_CACHE")):
+            refuse("the prefix cache (prefix_cache=True)",
+                   "a cached prefix restores K/V blocks, not the state "
+                   "after them")
+        if self.role != "both":
+            refuse(f"role={self.role!r}",
+                   "the KV handoff carries no recurrent state")
+
     def _place(self, tree):
         """Commit a pytree (host or device values) to this engine's chip;
         with no chip named, host values go to JAX's default device."""
@@ -1034,6 +1177,18 @@ class ContinuousBatcher:
             return self._place(PagedKVCache.create(
                 self.config, self.num_blocks, self.block_size,
                 self.kv_dtype))
+
+    def _new_state(self) -> Optional[StateCache]:
+        if not self.config.state_layers:
+            return None
+        with jax.default_device(self.device):
+            return self._place(StateCache.create(self.config,
+                                                 self.num_slots))
+
+    def _caches(self):
+        """The ``caches`` operand of ``cb_prefill`` and ``cb_tick``."""
+        return (self.cache if self.state is None
+                else (self.cache, self.state))
 
     def _new_draft_cache(self):
         with jax.default_device(self.device):
@@ -1265,6 +1420,10 @@ class ContinuousBatcher:
             "kv_blocks_importable": free_blocks + cached,
             "handoff_ready": len(self._handoff_ready),
             "import_reservations": len(self._import_reservations),
+            # Resident bytes of the per-slot state cache (state-space
+            # layers): fixed at construction, whatever the contexts.
+            "state_cache_bytes": (self.state.nbytes
+                                  if self.state is not None else 0),
         }
 
     # ---------------------------------------------------------------- api
@@ -1454,6 +1613,7 @@ class ContinuousBatcher:
         # failure the old buffers may already be deleted, so rebuild the
         # pool or every later step would raise "Array has been deleted".
         self.cache = self._new_cache()
+        self.state = self._new_state()
         self.allocator.reset()
         self._slot_blocks.clear()
         self._slot_nodes.clear()
@@ -1543,6 +1703,12 @@ class ContinuousBatcher:
         self._release_handoff_blocks(entry)
         return True
 
+    def _refuse_handoff(self, what: str) -> None:
+        if self.state is not None:
+            raise ValueError(
+                f"{what} is not supported for a model with state-space "
+                f"layers: the KV handoff carries no recurrent state")
+
     def export_kv_payload(self, rid: int) -> Dict[str, Any]:
         """Materialize a parked request's KV handoff: gather its
         prompt-covering arena blocks (K/V plus int8 scale sidecars) to
@@ -1556,6 +1722,7 @@ class ContinuousBatcher:
         Call through ``ray_tpu.serve.kv_transfer`` — the journal-gated
         helper every cross-replica transfer must ride (a source lint
         pins this)."""
+        self._refuse_handoff("export_kv_payload")
         if self.role == "decode":
             raise ValueError("decode-role engines do not export KV")
         entry = self._handoff_ready.pop(rid, None)
@@ -1654,6 +1821,7 @@ class ContinuousBatcher:
         this engine's id stream). Call through
         ``ray_tpu.serve.kv_transfer`` — the journal-gated helper every
         cross-replica transfer must ride (a source lint pins this)."""
+        self._refuse_handoff("import_kv_payload")
         if self.role == "prefill":
             raise ValueError("prefill-role engines do not import KV")
         if payload.get("version") != HANDOFF_MANIFEST_VERSION:
@@ -1829,6 +1997,10 @@ class ContinuousBatcher:
                             0.0) if c.num_experts else 0.0)
         total = (self.param_bytes + live_bytes
                  - int(self._expert_param_bytes * idle_experts))
+        if self.state is not None:
+            # Every slot's state and conv tail, live or not, read and
+            # written once a tick.
+            total += 2 * self.state.nbytes
         if spec_k:
             if self._draft_cache is not None:
                 dcfg = self.drafter.config
@@ -2048,10 +2220,14 @@ class ContinuousBatcher:
                 with _annotation("engine.prefill.dispatch"):
                     pstep = self._place(np.int32(self._prefill_count))
                     self._prefill_count += 1
-                    first, self.cache = self._prefill(
-                        self.params, self._place(tokens), self.cache,
+                    slots = (None if self.state is None else self._place(
+                        np.asarray([group[min(i, n - 1)][1]
+                                    for i in range(n_pad)], np.int32)))
+                    first, caches = self._prefill(
+                        self.params, self._place(tokens), self._caches(),
                         self._place(ptables), self._place(tables_w),
-                        self._place(last_idx), pstep)
+                        self._place(last_idx), pstep, slots)
+                    self.cache, self.state = _split_caches(caches)
                 # The program queues behind the tick in flight, whose
                 # row reaches the host first: land it here, on its own
                 # clock. The device starts the prefill at that moment,
@@ -2080,6 +2256,9 @@ class ContinuousBatcher:
             self.prefill_tokens += true_tokens
             mdefs.CB_PREFILL_REQUESTS.inc(n, tags=self._mtags)
             mdefs.CB_PREFILL_TOKENS.inc(true_tokens, tags=self._mtags)
+            if self.state is not None:
+                self.state_installs += n
+                mdefs.CB_STATE_INSTALLS.inc(n, tags=self._mtags)
             first_ts = time.time()  # the fetch above synced the device
             for (req, slot, blocks, matched, _sfx, chunks), tok in \
                     zip(group, first):
@@ -2259,10 +2438,11 @@ class ContinuousBatcher:
             return (committed, counts)
         # A routed model's tick has a fifth output: the row to fetch
         # (tokens with the expert row counts packed behind them).
-        (self._d_tokens, self._d_positions, self.cache,
+        (self._d_tokens, self._d_positions, caches,
          self._d_step, *fetch) = self._tick(
             self.params, self._d_tokens, self._d_positions,
-            self._d_tables, self._d_limits, self.cache, self._d_step)
+            self._d_tables, self._d_limits, self._caches(), self._d_step)
+        self.cache, self.state = _split_caches(caches)
         self.base_tick_count += 1
         self._last_tick_k = 0
         return fetch[0] if fetch else self._d_tokens
@@ -2456,6 +2636,9 @@ class ContinuousBatcher:
         if self._prefix is not None:
             mdefs.CB_KV_BLOCKS_CACHED.set(kv["cached"], tags=self._mtags)
             mdefs.CB_KV_BLOCKS_SHARED.set(kv["shared"], tags=self._mtags)
+        if self.state is not None:
+            mdefs.CB_STATE_CACHE_BYTES.set(self.state.nbytes,
+                                           tags=self._mtags)
         if self.spec_k:
             mdefs.CB_SPEC_ACCEPT_RATE.set(self.spec_accept_rate,
                                           tags=self._mtags)

@@ -130,9 +130,13 @@ def lm_head_logits(x, params, config: llama.LlamaConfig):
     (tests/test_continuous_batching.py::test_bf16_lm_head_argmax_parity).
     """
     c = config
-    return jnp.einsum("bse,ev->bsv", x.astype(c.dtype),
-                      params["lm_head"].astype(c.dtype),
-                      preferred_element_type=jnp.float32)
+    # A tied head is contracted against the [V, E] embedding where it
+    # lies: a transposed copy would be a second embedding in HBM.
+    spec, head = (("bse,ve->bsv", params["embed"]) if c.tie_word_embeddings
+                  else ("bse,ev->bsv", params["lm_head"]))
+    logits = jnp.einsum(spec, x.astype(c.dtype), head.astype(c.dtype),
+                        preferred_element_type=jnp.float32)
+    return logits if c.logits_scaling == 1.0 else logits / c.logits_scaling
 
 
 def _forward_cached(params, tokens, positions, cache: KVCache,

@@ -74,6 +74,53 @@ class LlamaConfig:
     # A learned RMSNorm over the WHOLE projected q and k vectors, before
     # the split into heads and before rope (OLMoE's modeling code).
     qk_norm: bool = False
+    # The Granite 4.0-H family (``transformers`` ``granitemoehybrid``;
+    # SERVING ONLY: the engine runs it, :func:`forward` refuses it).
+    # ``layer_types`` names each layer's token mixer, "mamba" (a Mamba-2
+    # state-space mixer, ``models/mamba2.py``) or "attention"; empty =
+    # attention everywhere. Its parameters live in ``params["runs"]``,
+    # one stacked tree a run of equal layers (:func:`layer_runs`).
+    layer_types: Tuple[str, ...] = ()
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    # ``position_embedding_type`` "nope": no rotation of q and k.
+    rope: bool = True
+    # Softmax scale of attention; None = head_dim ** -0.5.
+    attention_multiplier: Optional[float] = None
+    embedding_multiplier: float = 1.0    # x = embed[tokens] * this
+    residual_multiplier: float = 1.0     # x += this * sublayer(norm(x))
+    logits_scaling: float = 1.0          # logits = x @ head / this
+    # A dense SwiGLU every token takes beside the routed experts, added
+    # unweighted; 0 = none.
+    shared_intermediate_size: int = 0
+    # The head is the embedding: contracted against ``embed [V, E]`` in
+    # place, no ``lm_head`` in the tree.
+    tie_word_embeddings: bool = False
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep a recurrent state a request (Mamba-2)."""
+        return sum(t == "mamba" for t in self.layer_types)
+
+    @property
+    def attn_layers(self) -> int:
+        """Layers that keep K/V: what the arena holds."""
+        return self.num_layers - self.state_layers
+
+    @property
+    def mamba_dims(self) -> Tuple[int, int]:
+        """(d_inner, conv_dim) of a Mamba-2 mixer: the channels of x, and
+        of x, B and C together, which the convolution runs over."""
+        inner = self.mamba_n_heads * self.mamba_d_head
+        return inner, inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def attn_scale(self) -> float:
+        return (self.head_dim ** -0.5 if self.attention_multiplier is None
+                else self.attention_multiplier)
 
     @staticmethod
     def llama2_7b(**kw) -> "LlamaConfig":
@@ -101,6 +148,26 @@ class LlamaConfig:
             max_seq_len=4096, rope_theta=10000.0, rms_eps=1e-5,
             num_experts=64, num_experts_per_tok=8, norm_topk_prob=False,
             qk_norm=True), **kw})
+
+    @staticmethod
+    def granite_4_0_h_small(**kw) -> "LlamaConfig":
+        """ibm-granite/granite-4.0-h-small (32B-A9B): 40 layers, 36
+        Mamba-2 mixers (128 heads x 64, state 128) and 4 GQA 32/8
+        attention layers without positions; every MLP 72 experts of 768,
+        top 10, beside a shared SwiGLU of 1536; tied 100k embedding."""
+        pattern = ("mamba",) * 5 + ("attention",) + (
+            ("mamba",) * 9 + ("attention",)) * 3 + ("mamba",) * 4
+        return LlamaConfig(**{**dict(
+            vocab_size=100352, hidden_size=4096, intermediate_size=768,
+            num_layers=40, num_heads=32, num_kv_heads=8, head_dim=128,
+            max_seq_len=131072, rms_eps=1e-5, num_experts=72,
+            num_experts_per_tok=10, norm_topk_prob=True,
+            layer_types=pattern, mamba_n_heads=128, mamba_d_head=64,
+            mamba_d_state=128, mamba_n_groups=1, mamba_d_conv=4,
+            rope=False, attention_multiplier=0.0078125,
+            embedding_multiplier=12.0, residual_multiplier=0.22,
+            logits_scaling=16.0, shared_intermediate_size=1536,
+            tie_word_embeddings=True), **kw})
 
     @staticmethod
     def tiny(**kw) -> "LlamaConfig":
@@ -153,6 +220,8 @@ def logical_axes(config: LlamaConfig) -> Params:
 def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     """Random init (normal / scaled), stacked over layers for lax.scan."""
     c = config
+    if c.layer_types:
+        return _init_hybrid_params(c, key)
     k_embed, k_head, k_layers = jax.random.split(key, 3)
 
     def norm_init(*shape):
@@ -209,6 +278,86 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     }
 
 
+def _init_hybrid_params(c: LlamaConfig, key: jax.Array) -> Params:
+    """The Granite 4.0-H family's tree: ``embed`` (also the head),
+    ``final_norm``, ``layers`` = the stacked experts ``[L, X, ...]``
+    alone (read in place at a GLOBAL layer index), and ``runs``: for
+    each run of equal layers (:func:`layer_runs`) one tree stacked over
+    the run's layers, holding everything else a layer has. A scan takes
+    a run's tree as it is: nothing is sliced, at any depth.
+
+    Seeded so that dropping a term shows: ``a_log`` gives decays
+    ``-exp(a_log)`` in -1..-16, ``dt_bias`` time steps of 0.001..0.1
+    (the ranges of ``mamba_ssm``'s own initialiser), ``ssm_d`` and the
+    gate norm's weight are uniform in 0.5..1.5, not ones."""
+    L, E, M, X = c.num_layers, c.hidden_size, c.intermediate_size, c.num_experts
+    H, KV, D = c.num_heads, c.num_kv_heads, c.head_dim
+    inner, conv_dim = c.mamba_dims
+    k_embed, k_experts, k_runs = jax.random.split(key, 3)
+
+    def dense(key, fan_in, *shape):
+        out = jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
+        return out.astype(c.dtype)
+
+    def uniform(key, lo, hi, *shape):
+        return jax.random.uniform(key, shape, jnp.float32, lo, hi)
+
+    ke = jax.random.split(k_experts, 3)
+    runs = []
+    for r, (kind, _, n, _) in enumerate(layer_runs(c)):
+        k = jax.random.split(jax.random.fold_in(k_runs, r), 12)
+        tree = {
+            "attn_norm": jnp.ones((n, E), c.dtype),
+            "mlp_norm": jnp.ones((n, E), c.dtype),
+            "w_router": jax.random.normal(k[0], (n, E, X), jnp.float32)
+            * E ** -0.5,
+            "shared_gate": dense(k[1], E, n, E, c.shared_intermediate_size),
+            "shared_up": dense(k[2], E, n, E, c.shared_intermediate_size),
+            "shared_down": dense(k[3], c.shared_intermediate_size, n,
+                                 c.shared_intermediate_size, E),
+        }
+        if kind == "mamba":
+            dt = jnp.exp(uniform(k[7], jnp.log(1e-3), jnp.log(1e-1),
+                                 n, c.mamba_n_heads))
+            tree.update({
+                "ssm_in": dense(k[4], E, n, E,
+                                inner + conv_dim + c.mamba_n_heads),
+                "conv_w": dense(k[5], c.mamba_d_conv, n, c.mamba_d_conv,
+                                conv_dim),
+                "conv_b": uniform(k[6], -0.5, 0.5, n, conv_dim).astype(c.dtype),
+                # softplus(dt_bias) = dt
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "a_log": jnp.log(uniform(k[8], 1.0, 16.0, n, c.mamba_n_heads)),
+                "ssm_d": uniform(k[9], 0.5, 1.5, n, c.mamba_n_heads),
+                "ssm_norm": uniform(k[10], 0.5, 1.5, n, inner).astype(c.dtype),
+                "ssm_out": dense(k[11], inner, n, inner, E),
+            })
+        else:
+            tree.update({
+                "wq": dense(k[4], E, n, E, H, D),
+                "wk": dense(k[5], E, n, E, KV, D),
+                "wv": dense(k[6], E, n, E, KV, D),
+                "wo": dense(k[7], H * D, n, H, D, E),
+            })
+        runs.append(tree)
+    return {
+        # The head is this matrix too: at unit scale a token's own
+        # embedding, still the largest part of x after six scaled
+        # residuals, would win every argmax and no check on chosen
+        # tokens could see a fault in a layer. At this scale its logit
+        # is one standard deviation of the others.
+        "embed": (jax.random.normal(k_embed, (c.vocab_size, E), jnp.float32)
+                  / (c.embedding_multiplier * E ** 0.5)).astype(c.dtype),
+        "final_norm": jnp.ones((E,), c.dtype),
+        "layers": {
+            "moe_gate": dense(ke[0], E, L, X, E, M),
+            "moe_up": dense(ke[1], E, L, X, E, M),
+            "moe_down": dense(ke[2], M, L, X, M, E),
+        },
+        "runs": runs,
+    }
+
+
 def truncated(config: LlamaConfig, params: Params,
               num_layers: int) -> Tuple[LlamaConfig, Params]:
     """First-``num_layers`` view of a model: (config, params) where the
@@ -249,6 +398,43 @@ def split_layers(params: Params, num_layers: Optional[int] = None):
     return scanned, experts
 
 
+def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
+               num_layers: Optional[int] = None):
+    """The layer stack as RUNS of equal layers, in order: a list of
+    ``(kind, start, count, kind_start)`` where ``kind`` is "attention"
+    or "mamba", ``start`` the run's first GLOBAL layer (the stacked
+    experts' index) and ``kind_start`` its first index among layers of
+    its kind (the K/V arena's layer for attention, the state cache's for
+    mamba). A model without ``layer_types`` is one attention run.
+
+    With ``params``: ``(runs, experts)``, each run followed by the tree
+    a ``lax.scan`` over its layers takes (``params["runs"][i]``; for a
+    homogeneous model :func:`split_layers`' ``scanned``, cut to the first
+    ``num_layers``)."""
+    c = config
+    types = c.layer_types or ("attention",) * c.num_layers
+    if len(types) != c.num_layers:
+        raise ValueError(f"layer_types names {len(types)} layers, "
+                         f"num_layers is {c.num_layers}")
+    runs, seen = [], {"attention": 0, "mamba": 0}
+    for i, kind in enumerate(types):
+        if kind not in seen:
+            raise ValueError(f"layer {i}: unknown layer type {kind!r}")
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, i, 1, seen[kind]])
+        seen[kind] += 1
+    runs = [tuple(r) for r in runs]
+    if params is None:
+        return runs
+    scanned, experts = split_layers(params, num_layers)
+    if not c.layer_types:
+        return [(("attention", 0, num_layers or c.num_layers, 0), scanned)
+                ], experts
+    return list(zip(runs, params["runs"])), experts
+
+
 def project_qkv(h, layer, c: LlamaConfig):
     """The layer's q, k, v projections of normed ``h [B, S, E]``, before
     rope: ``q [B, S, H, D]``, ``k``/``v [B, S, KVH, D]``. Every forward
@@ -264,6 +450,17 @@ def project_qkv(h, layer, c: LlamaConfig):
 
         q, k = whole(q, layer["q_norm"]), whole(k, layer["k_norm"])
     return q, k, v
+
+
+def _swiglu(h, w_gate, w_up, w_down, c: LlamaConfig,
+            mesh: Optional[Mesh] = None):
+    """``down(silu(gate h) * up h)`` on h [B, S, E]."""
+    gate = jnp.einsum("bse,em->bsm", h, w_gate.astype(c.dtype))
+    up = jnp.einsum("bse,em->bsm", h, w_up.astype(c.dtype))
+    act = jax.nn.silu(gate) * up
+    if mesh is not None:
+        act = constrain(act, mesh, "batch", "seq", "act_mlp")
+    return jnp.einsum("bsm,me->bse", act, w_down.astype(c.dtype))
 
 
 def mlp_block(h, layer, c: LlamaConfig, experts=None, li=None,
@@ -282,14 +479,17 @@ def mlp_block(h, layer, c: LlamaConfig, experts=None, li=None,
             h.reshape(b * s, e), layer["w_router"], experts, li,
             top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob,
             use_kernel=use_kernel)
-        return out.reshape(b, s, e), routed
-    gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(c.dtype))
-    up = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(c.dtype))
-    act = jax.nn.silu(gate) * up
-    if mesh is not None:
-        act = constrain(act, mesh, "batch", "seq", "act_mlp")
-    return jnp.einsum("bsm,me->bse", act,
-                      layer["w_down"].astype(c.dtype)), None
+        out = out.reshape(b, s, e)
+        if c.shared_intermediate_size:
+            # GraniteMoeHybridDecoderLayer.forward: moe(h) + shared_mlp(h),
+            # one norm output feeding both, the shared one unweighted.
+            with jax.named_scope("shared_mlp"):
+                out = out + _swiglu(h, layer["shared_gate"],
+                                    layer["shared_up"],
+                                    layer["shared_down"], c)
+        return out, routed
+    return _swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"], c,
+                   mesh), None
 
 
 def _select_attention(config: LlamaConfig, mesh: Optional[Mesh]):
@@ -358,6 +558,11 @@ def forward(
     otherwise just the logits array.
     """
     c = config
+    if c.layer_types:
+        raise NotImplementedError(
+            "a config with layer_types (state-space layers) is served by "
+            "the continuous-batching engine only: llama.forward, loss_fn "
+            "and LlamaGenerator do not run it")
     seq_len = tokens.shape[1]
     cos, sin = rope_frequencies(c.head_dim, seq_len, c.rope_theta)
 
@@ -539,6 +744,19 @@ def loss_fn(
 
 def num_params(config: LlamaConfig) -> int:
     c = config
+    if c.layer_types:
+        inner, conv_dim = c.mamba_dims
+        common = (2 * c.hidden_size + c.hidden_size * c.num_experts
+                  + 3 * c.hidden_size * (c.intermediate_size * c.num_experts
+                                         + c.shared_intermediate_size))
+        attention = c.hidden_size * c.head_dim * 2 * (
+            c.num_heads + c.num_kv_heads)
+        mamba = (c.hidden_size * (inner + conv_dim + c.mamba_n_heads)
+                 + conv_dim * (c.mamba_d_conv + 1) + 3 * c.mamba_n_heads
+                 + inner + inner * c.hidden_size)
+        return (c.vocab_size * c.hidden_size + c.hidden_size
+                + c.num_layers * common + c.attn_layers * attention
+                + c.state_layers * mamba)
     per_layer = (
         2 * c.hidden_size
         + c.hidden_size * c.num_heads * c.head_dim * 2

@@ -103,7 +103,8 @@ class PagedKVCache(NamedTuple):
     def create(cls, config: llama.LlamaConfig, num_blocks: int,
                block_size: int, kv_dtype: str = "bf16") -> "PagedKVCache":
         kv_dtype = resolve_kv_dtype(kv_dtype)
-        shape = (config.num_layers, num_blocks, config.num_kv_heads,
+        # Attention layers only: a state-space layer keeps no K/V.
+        shape = (config.attn_layers, num_blocks, config.num_kv_heads,
                  block_size, config.head_dim)
         if kv_dtype == "int8":
             return cls(k=jnp.zeros(shape, jnp.int8),
@@ -169,6 +170,38 @@ class PagedKVCache(NamedTuple):
             fields[name] = arr.at[:, idx].set(
                 jnp.asarray(src, dtype=arr.dtype))
         return PagedKVCache(**fields)
+
+
+class StateCache(NamedTuple):
+    """What the state-space layers of a hybrid model keep for each slot,
+    beside the K/V arena: ``ssm [L_ssm, slots, H, N / f, f * P]``
+    float32, the recurrent state in the layout the tick's kernel
+    updates in place (``ops/ssm.py::packed_shape``), and ``conv
+    [L_ssm, slots, K - 1, conv_dim]``, the convolution's last inputs,
+    oldest first, in the model's dtype. Neither is paged nor shareable
+    by prefix: a slot's row is installed whole by its prefill, advanced
+    by every tick, and simply overwritten by the slot's next prefill (a
+    freed slot's row computes garbage that nothing reads)."""
+
+    ssm: jnp.ndarray
+    conv: jnp.ndarray
+
+    @classmethod
+    def create(cls, config: llama.LlamaConfig,
+               num_slots: int) -> "StateCache":
+        from ray_tpu.ops.ssm import packed_shape
+
+        c = config
+        return cls(
+            ssm=jnp.zeros((c.state_layers, num_slots) + packed_shape(
+                c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state),
+                jnp.float32),
+            conv=jnp.zeros((c.state_layers, num_slots, c.mamba_d_conv - 1,
+                            c.mamba_dims[1]), c.dtype))
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.ssm.nbytes + self.conv.nbytes)
 
 
 class BlockAllocator:

@@ -176,10 +176,14 @@ def _gmm_tiles(m: int, k: int, n: int, num_groups: int, itemsize: int):
     """(tm, tn). A group that straddles a row tile is visited, and its
     weights read, once per tile, so tiles are tall where groups are
     (about twice the mean group, 128 to 512 rows); ``tn`` keeps one
-    ``[K, tn]`` weight tile at or under 2 MiB."""
+    ``[K, tn]`` weight tile at or under 2 MiB where halving allows."""
     tm = 128
     while tm < 512 and tm * num_groups < 2 * m:
         tm *= 2
+    # ... and one [tm, K] row tile too (K = 4096, Granite 4.0-H: with
+    # 512 rows the two tiles, double-buffered, pass the 16 MiB of VMEM).
+    while tm > 128 and tm * k * itemsize > (2 << 20):
+        tm //= 2
     tn = n
     while tn % 256 == 0 and k * tn * itemsize > (2 << 20):
         tn //= 2

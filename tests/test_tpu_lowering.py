@@ -356,3 +356,82 @@ def test_compiled_olmoe_tick_copies_no_expert_weights(v5e_chip):
     assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == 2
     # One expert matrix is 4 MB: the program's scratch is far below it.
     assert compiled.memory_analysis().temp_size_in_bytes < 2048 * 1024 * 2
+
+
+# ------------------------------------------- the state-space family (PR 29)
+
+@pytest.mark.parametrize("heads,p,n", [(128, 64, 128), (32, 64, 128)])
+def test_ssm_step_lowers(heads, p, n):
+    """The tick's in-place state update at Granite 4.0-H's widths."""
+    from ray_tpu.ops import ssm
+
+    f32 = jnp.float32
+    fn = functools.partial(ssm.ssm_step, use_kernel=True)
+    assert ssm.ssm_applicable(heads, p, n, 1)
+    assert _mosaic_calls(
+        fn, S((5, 48) + ssm.packed_shape(heads, p, n), f32),
+        S((), jnp.int32), S((48, heads, p), f32), S((48, heads), f32),
+        S((heads,), f32), S((48, 1, n), BF16), S((48, 1, n), BF16)) == 1
+
+
+def test_compiled_hybrid_tick_holds_no_copy_of_the_state_cache(v5e_chip):
+    """serve_hybrid_decode's tick (published widths, the cell's 5 Mamba-2
+    layers and 1 attention layer, 48 slots, 512 blocks): each run's layer
+    loop calls ``ssm_step`` once on the WHOLE state cache
+    ``[5, 48, 128, 64, 128]`` float32 (1.0 GB), aliased in to out, and no
+    other instruction has the cache's or one layer's slab's shape: an XLA
+    update in the loop would slice 201 MB out and put it back a layer a
+    tick. The conv tails ``[5, 48, 3, 8448]`` move only as one layer's
+    slab (2.4 MB). Experts are read in place by both runs' ``moe_gmm``
+    (3 calls a run), and the program's scratch is a few MB."""
+    import dataclasses
+
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models.paged_kv import PagedKVCache, StateCache
+
+    full = llama.LlamaConfig.granite_4_0_h_small()
+    cfg = dataclasses.replace(full, num_layers=6,
+                              layer_types=full.layer_types[:6],
+                              max_seq_len=1024)
+
+    def spec(a):
+        return S(a.shape, a.dtype, sharding=v5e_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        functools.partial(llama.init_params, cfg), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        PagedKVCache.create, cfg, 512, 64, kv_dtype="bf16")))
+    state = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        StateCache.create, cfg, 48)))
+    assert cache.k.shape[0] == 1 and state.ssm.shape == (5, 48, 128, 64, 128)
+    row = S((48,), jnp.int32, sharding=v5e_chip)
+    tables = S((48, 16), jnp.int32, sharding=v5e_chip)
+    step = S((), jnp.int32, sharding=v5e_chip)
+    tick = functools.partial(cb._decode_tick_paged, config=cfg,
+                             use_kernel=True)
+    compiled = jax.jit(tick, donate_argnums=(5,)).lower(
+        params, row, row, tables, row, (cache, state), step).compile()
+    hlo = compiled.as_text()
+    free = ("custom_call_target=\"tpu_custom_call\"", " parameter(",
+            " get-tuple-element(", " tuple(", " while(", " bitcast(")
+
+    def moves(shape):
+        shaped = re.compile(rf"= \(?\w+\[(\d+,)?{shape}\]")
+        return [line.strip() for line in hlo.splitlines()
+                if shaped.search(line) and not any(f in line for f in free)]
+
+    assert moves("48,128,64,128") == []
+    # ... written back in place: a dynamic-update-slice on the loop's
+    # carry (alone or as a loop fusion's root), never a copy.
+    assert all(" dynamic-update-slice(" in line or " fusion(" in line
+               for line in moves("5,48,3,8448"))
+    assert len(re.findall(r"%ssm_step[.\d]* = ", hlo)) == 1
+    assert len(re.findall(r"%moe_gmm[.\d]* = ", hlo)) == 6
+    assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 1
+    assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == 2
+    header = hlo[:hlo.index("\n")]
+    aliased = re.findall(r"\{[\d, ]*\}: \((\d+), ", header)
+    assert len(aliased) == 4        # K, V, the state, the conv tails
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 5 * 48 * 128 * 64 * 128 * 4
+    assert memory.temp_size_in_bytes < 8 << 20     # the tails are 12 MB
