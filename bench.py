@@ -242,8 +242,10 @@ def main() -> None:
             n = sched.collect([[1 + r, 2, 3]] * rl_prompts, rl_new,
                               lambda p, t: float(len(t)))
             tokens_between.append(n * rl_new)
+            # The publisher ships the canonical tree; the engine's own
+            # holds the q/k/v projections heads-major.
             faked = jax.tree.map(lambda a: (a * 0.999).astype(a.dtype),
-                                 eng.params)
+                                 llama.canonical_layout(eng.params))
             t0 = time.perf_counter()
             manifest = pub.publish(faked, step=r)
             got = sub.poll(timeout=5.0)

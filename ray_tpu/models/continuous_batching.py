@@ -209,6 +209,17 @@ def _next_tokens(logits, step, sampling: SamplingParams, salt: int = 0):
 _PREFILL_SALT = 1  # prefill sampling stream, distinct from decode's
 
 
+def init_engine_params(config: llama.LlamaConfig, key):
+    """:func:`llama.init_params`' seeded weights in the engine's layout
+    (:func:`llama.heads_major`): what ``cb_init`` runs, and the tree
+    every engine program is traced against."""
+    return llama.heads_major(llama.init_params(config, key))
+
+
+_relay_heads = xla_monitor.instrument(llama.swap_heads, name="cb_relay",
+                                      shape_policy="free")
+
+
 def _layer_qkv(x, layer, cos, sin, c):
     """Every engine layer's first half: attn-norm, Q/K/V projections,
     RoPE on Q and K (V unrotated). x [B, S, E]; cos/sin [S, D//2] where
@@ -895,13 +906,18 @@ class ContinuousBatcher:
         if params is None:
             # ONE program, not one per tensor: on the chip each eager op
             # is its own compile, and a replica must finish constructing
-            # inside the serve controller's start-up grace.
+            # inside the serve controller's start-up grace. It seeds the
+            # weights in the engine's layout, so no second program runs.
             with jax.default_device(device):
                 params = xla_monitor.instrument(
-                    functools.partial(llama.init_params, config),
+                    functools.partial(init_engine_params, config),
                     name="cb_init", shape_policy="free")(
                     jax.random.PRNGKey(seed))
-        self.params = self._place(params)
+        self.params = self._install(params)
+        # What swap_params holds a caller's tree to: the CANONICAL
+        # signature of the tree this engine was built with.
+        self._canonical_sig = jax.tree_util.tree_flatten(
+            jax.eval_shape(llama.canonical_layout, self.params))
         # Weight-sync plane (ray_tpu/rl): monotone version of the live
         # params. 0 = the cold-start weights; every swap_params bumps it
         # and each request records the version that admitted it.
@@ -920,7 +936,7 @@ class ContinuousBatcher:
         self._expert_param_bytes = sum(
             self.params["layers"][k].nbytes for k in llama.EXPERT_KEYS
             if k in self.params["layers"])
-        self._draft_params = (self._place(self.drafter.params)
+        self._draft_params = (self._install(self.drafter.params)
                               if self.spec_k and self.drafter.external
                               else None)
         self._draft_param_bytes = sum(
@@ -1171,6 +1187,14 @@ class ContinuousBatcher:
         """Commit a pytree (host or device values) to this engine's chip;
         with no chip named, host values go to JAX's default device."""
         return jax.device_put(tree, self.device)
+
+    def _install(self, tree):
+        """A parameter tree (canonical, or already the engine's) on this
+        engine's chip in the ENGINE's layout (:func:`llama.heads_major`):
+        every tree the engine takes in comes through here. One program
+        transposes the q/k/v projections and nothing else, so the other
+        leaves stay the arrays they were and a model never exists twice."""
+        return llama.heads_major(self._place(tree), relay=_relay_heads)
 
     def _new_cache(self):
         with jax.default_device(self.device):
@@ -1443,14 +1467,16 @@ class ContinuousBatcher:
         every in-flight request's device state are untouched: in-flight
         generations continue un-dropped under the new weights.
 
-        The new tree must match the old one structurally (same treedef,
-        same leaf shapes/dtypes) — the compiled tick programs were traced
-        against that signature and a silent mismatch would either retrace
-        per swap or miscompute. Returns the new weight version
-        (``version`` or the previous one + 1)."""
-        import jax
-
-        old_leaves, old_treedef = jax.tree_util.tree_flatten(self.params)
+        ``params`` is a CANONICAL tree (:func:`llama.init_params`'
+        layout: what the trainer, a checkpoint and ``rl/weight_sync``
+        hold); the engine re-lays it on the way in (:meth:`_install`),
+        as it did the tree it was built with. It must match that tree's
+        canonical form structurally (same treedef, same leaf
+        shapes/dtypes): the compiled tick programs were traced against
+        that signature and a silent mismatch would either retrace per
+        swap or miscompute. Returns the new weight version (``version``
+        or the previous one + 1)."""
+        old_leaves, old_treedef = self._canonical_sig
         new_leaves, new_treedef = jax.tree_util.tree_flatten(params)
         if new_treedef != old_treedef:
             raise ValueError(
@@ -1462,7 +1488,7 @@ class ContinuousBatcher:
                     f"swap_params leaf {i} mismatch: engine has "
                     f"{old.shape}/{old.dtype}, swap brought "
                     f"{new.shape}/{new.dtype}")
-        self.params = self._place(params)
+        self.params = self._install(params)
         self._weight_version = (int(version) if version is not None
                                 else self._weight_version + 1)
         return self._weight_version

@@ -389,7 +389,14 @@ def split_layers(params: Params, num_layers: Optional[int] = None):
     ``[L, X, ...]``, which it must NOT slice: a scan's slice of a
     custom call's operand is a copy (805 MB a layer at OLMoE's sizes),
     so the routed block reads them in place at a layer index. ``experts``
-    is None for a dense model."""
+    is None for a dense model.
+
+    ``scanned`` carries the q/k/v projections in whichever layout
+    ``params`` holds them: canonical ``wq``/``wk``/``wv`` ``[L, E, H, D]``
+    (:func:`init_params`' tree: the trainer's, a checkpoint's) or the
+    engine's ``wq_heads``/... ``[L, H, E, D]`` (:func:`heads_major`).
+    Layers stay axis 0 in both, so the cut and the scan's slice are the
+    same; :func:`project_qkv` contracts whichever it is handed."""
     layers = params["layers"]
     experts = {k: layers[k] for k in EXPERT_KEYS if k in layers} or None
     scanned = {k: v for k, v in layers.items() if k not in EXPERT_KEYS}
@@ -435,14 +442,85 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
     return list(zip(runs, params["runs"])), experts
 
 
+# Canonical name -> the name the same projection has in the engine's
+# heads-major tree (:func:`heads_major`).
+HEADS_MAJOR = {"wq": "wq_heads", "wk": "wk_heads", "wv": "wv_heads"}
+
+
+def swap_heads(leaves):
+    """Each stacked projection ``[L, E, H, D]`` as ``[L, H, E, D]``, or
+    back: one transpose, its own inverse."""
+    return [jnp.swapaxes(a, 1, 2) for a in leaves]
+
+
+def _relaid(params: Params, names: Dict[str, str], relay) -> Params:
+    def one(tree):
+        found = [k for k in names if k in tree]
+        if not found:
+            return tree
+        out = {k: v for k, v in tree.items() if k not in names}
+        out.update(zip((names[k] for k in found),
+                       relay([tree[k] for k in found])))
+        return out
+
+    out = dict(params, layers=one(params["layers"]))
+    if "runs" in params:
+        out["runs"] = [one(run) for run in params["runs"]]
+    return out
+
+
+def heads_major(params: Params, relay=swap_heads) -> Params:
+    """``params`` in the ENGINE's layout: every attention layer's
+    ``wq``/``wk``/``wv`` ``[L, E, H, D]`` held as ``wq_heads``/
+    ``wk_heads``/``wv_heads`` ``[L, H, E, D]`` (``KVH`` for k and v), in
+    both homes of the weights: ``params["layers"]`` and each attention
+    run of ``params["runs"]``. The same numbers transposed; every other
+    leaf is the same array. A tree already re-laid comes back as it is.
+
+    Why the engine holds them so: a decode tick's projection wants, per
+    head, an ``[E, D]`` panel with ``E`` on a tiled axis. Canonical tiles
+    span ``(H, D)``, so XLA first copied each layer's three slices out
+    of the stacked weights (33.5 + 8.4 + 8.4 MB a layer at Mistral's
+    widths, 9% of the tick); heads-major, the matmul reads the stacked
+    weight in place at the layer index, as ``wo`` and the MLP do.
+
+    The CANONICAL layout is a contract and does not change:
+    :func:`init_params`, :func:`logical_axes`, ``ShardedTrainer``,
+    checkpoints, weight sync and the benchmark's references all hold
+    ``[L, E, H, D]``. ``relay`` maps a list of projection leaves to
+    their transposes; the engine passes one jitted program, so a tree
+    already on the chip copies its three projections and nothing else."""
+    return _relaid(params, HEADS_MAJOR, relay)
+
+
+def canonical_layout(params: Params) -> Params:
+    """:func:`heads_major`'s inverse: an engine's tree as
+    :func:`init_params` lays it out (a canonical tree comes back as it
+    is). What ``swap_params`` validates against, and what to hand to
+    anything that reads ``wq`` by name."""
+    return _relaid(params, {v: k for k, v in HEADS_MAJOR.items()},
+                   swap_heads)
+
+
 def project_qkv(h, layer, c: LlamaConfig):
     """The layer's q, k, v projections of normed ``h [B, S, E]``, before
     rope: ``q [B, S, H, D]``, ``k``/``v [B, S, KVH, D]``. Every forward
     of the family (training, the dense-cache generator, each engine
-    program) projects here, so QK-norm reaches all of them at once."""
-    q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(c.dtype))
-    k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(c.dtype))
-    v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(c.dtype))
+    program) projects here, so QK-norm reaches all of them at once.
+
+    It contracts the layout it is given, read from the tree itself:
+    canonical ``wq [E, H, D]`` (the trainer's and ``init_params``' tree)
+    or the engine's ``wq_heads [H, E, D]`` (:func:`heads_major`). The
+    same products summed over the same ``E``; no flag selects it, so
+    ``forward`` on an engine's tree (``score_logprobs``), the self-draft,
+    verify and prefill all follow."""
+    def project(name):
+        if HEADS_MAJOR[name] in layer:
+            return jnp.einsum("bse,hed->bshd", h,
+                              layer[HEADS_MAJOR[name]].astype(c.dtype))
+        return jnp.einsum("bse,ehd->bshd", h, layer[name].astype(c.dtype))
+
+    q, k, v = project("wq"), project("wk"), project("wv")
     if c.qk_norm:
         def whole(x, w):
             flat = x.reshape(*x.shape[:2], -1)

@@ -128,6 +128,12 @@ def test_sharded_train_step_lowers_with_flash(fsdp):
 # serve_chat's engine sizes; two layers are enough for a layer loop.
 _TICK_LAYERS, _TICK_SLOTS, _TICK_BLOCKS, _TICK_BS, _TICK_LEN = (
     2, 48, 1000, 64, 2048)
+# The cell's own depth, for what depends on how the compiler places the
+# WEIGHTS: at two layers a weight's whole stack fits the chip's fast
+# memory and is prefetched there (slice-start/slice-done), which no
+# served model's program does; at 16 the compile names the instructions
+# the chip's trace names (PR 30).
+_CELL_LAYERS = 16
 
 
 @pytest.fixture(scope="module")
@@ -145,16 +151,17 @@ def v5e_chip():
 
 
 @functools.lru_cache(maxsize=None)
-def compile_paged_tick(sharding, kv_dtype, spec_k=0):
+def compile_paged_tick(sharding, kv_dtype, spec_k=0, layers=_TICK_LAYERS):
     """AOT-compile the paged decode tick at the sizes above for
     ``sharding``'s chip; with ``spec_k``, the speculative tick (that
-    many one-layer self-drafts, then a verify window of ``spec_k + 1``)."""
+    many one-layer self-drafts, then a verify window of ``spec_k + 1``).
+    The parameters are the ENGINE's tree (``cb.init_engine_params``)."""
     from ray_tpu.models import continuous_batching as cb
     from ray_tpu.models.paged_kv import PagedKVCache
 
     cfg = llama.LlamaConfig(
         vocab_size=32768, hidden_size=4096, intermediate_size=14336,
-        num_layers=_TICK_LAYERS, num_heads=32, num_kv_heads=8,
+        num_layers=layers, num_heads=32, num_kv_heads=8,
         head_dim=128, max_seq_len=_TICK_LEN, rope_theta=1e6,
         rms_eps=1e-5)
 
@@ -162,7 +169,8 @@ def compile_paged_tick(sharding, kv_dtype, spec_k=0):
         return S(a.shape, a.dtype, sharding=sharding)
 
     params = jax.tree.map(spec, jax.eval_shape(
-        functools.partial(llama.init_params, cfg), jax.random.PRNGKey(0)))
+        functools.partial(cb.init_engine_params, cfg),
+        jax.random.PRNGKey(0)))
     cache = jax.tree.map(spec, jax.eval_shape(functools.partial(
         PagedKVCache.create, cfg, _TICK_BLOCKS, _TICK_BS,
         kv_dtype=kv_dtype)))
@@ -183,6 +191,11 @@ def compile_paged_tick(sharding, kv_dtype, spec_k=0):
         params, row, row, tables, row, cache, step).compile()
 
 
+# Instructions that move no bytes of their own.
+_FREE = ("custom_call_target=\"tpu_custom_call\"", " parameter(",
+         " get-tuple-element(", " tuple(", " while(", " bitcast(")
+
+
 def arena_moves(hlo_text: str, trailing: str):
     """Instructions of compiled HLO whose result is one layer's slab or
     the whole arena (``[L?, NB, KVH, bs`` + ``trailing`` + ``]``) and
@@ -191,10 +204,8 @@ def arena_moves(hlo_text: str, trailing: str):
     parameter, a loop's tuple plumbing and a bitcast move no bytes."""
     shaped = re.compile(rf"= \(?\w+\[(\d+,)?{_TICK_BLOCKS},8,{_TICK_BS}"
                         rf"{trailing}\]")
-    free = ("custom_call_target=\"tpu_custom_call\"", " parameter(",
-            " get-tuple-element(", " tuple(", " while(", " bitcast(")
     return [line.strip() for line in hlo_text.splitlines()
-            if shaped.search(line) and not any(f in line for f in free)]
+            if shaped.search(line) and not any(f in line for f in _FREE)]
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
@@ -251,6 +262,53 @@ def test_compiled_tick_keeps_its_operands_and_donates_the_arena_alone(
     header = hlo[:hlo.index("\n")]
     aliased = re.findall(r"\{[\d, ]*\}: \((\d+), ", header)
     assert sorted(int(n) for n in aliased) == list(range(16, 16 + arenas))
+
+
+def projection_weight_moves(hlo_text: str, heads: int, kv_heads: int,
+                            embed: int, head_dim: int = 128):
+    """Instructions of compiled HLO whose result is ONE LAYER's ``wq``,
+    ``wk`` or ``wv`` in either axis order and that are not reads inside
+    a fusion: the per-layer slice copies (``constant_dynamic-slice_
+    fusion bf16[1,4096,32,128]``: 9% of serve_chat's tick before PR 30),
+    relayout copies and asynchronous slices. A ``slice`` or
+    ``dynamic-slice`` INSIDE a fused computation is the fusion's own read
+    of its operand (the matmul taking the stacked weight at the layer
+    index) and writes nothing back."""
+    dims = "|".join(f"{a},{b}" for h in {heads, kv_heads}
+                    for a, b in ((h, embed), (embed, h)))
+    shaped = re.compile(rf"= \(?\w+\[1,({dims}),{head_dim}\]")
+    moves = []
+    for computation in hlo_text.split("\n\n"):
+        fused = "fused_computation" in computation.lstrip().split("(")[0]
+        for line in computation.splitlines():
+            if not shaped.search(line) or any(f in line for f in _FREE):
+                continue
+            if fused and (" dynamic-slice(" in line or " slice(" in line):
+                continue
+            moves.append(line.strip())
+    return moves
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("spec_k", [0, 4])
+def test_compiled_tick_copies_no_projection_weights(v5e_chip, kv_dtype,
+                                                    spec_k):
+    """The engine holds ``wq``/``wk``/``wv`` heads-major (``[L, H, E,
+    D]``, :func:`llama.heads_major`), so the tick's projections read the
+    STACKED weights in place at the layer index, as ``wo`` and the MLP
+    do. In the canonical ``[L, E, H, D]`` every layer first copied its
+    three slices out (50 MB a layer written and read back: 1.1 ms of an
+    11.5 ms tick on the chip), and the speculative tick held 18 such
+    slices and copies."""
+    hlo = compile_paged_tick(v5e_chip, kv_dtype, spec_k,
+                             _CELL_LAYERS).as_text()
+    assert projection_weight_moves(hlo, 32, 8, 4096) == []
+    # The guard sees what it guards against: the parent's instruction.
+    assert projection_weight_moves(
+        "%body (p: bf16[16,4096,32,128]) -> bf16[1,4096,32,128] {\n"
+        "  %constant_dynamic-slice_fusion.6 = bf16[1,4096,32,128]"
+        "{3,2,1,0:T(8,128)(2,1)S(1)} fusion(%p, %i), kind=kLoop\n}",
+        32, 8, 4096) != []
 
 
 def test_admissions_after_warm_up_compile_nothing():
@@ -320,42 +378,61 @@ def test_moe_gmm_lowers(rows, k, n):
                          S((64,), jnp.int32), S((), jnp.int32)) == 1
 
 
-def test_compiled_olmoe_tick_copies_no_expert_weights(v5e_chip):
-    """serve_moe_decode's tick (published widths, 48 slots, 512 blocks,
-    two layers): each layer calls ``moe_gmm`` three times on the
-    STACKED expert weights, and no other instruction has a layer's
-    expert-weight shape: a per-layer slice would copy 805 MB a layer a
-    tick. MHA: both paged kernels compile at 16 KV heads, group 1."""
+@functools.lru_cache(maxsize=None)
+def compile_olmoe_tick(sharding, layers):
+    """serve_moe_decode's tick for ``sharding``'s chip: published widths,
+    48 slots, 512 blocks, the ENGINE's parameter tree."""
     from ray_tpu.models import continuous_batching as cb
     from ray_tpu.models.paged_kv import PagedKVCache
 
-    cfg = llama.LlamaConfig.olmoe_1b_7b(num_layers=2, max_seq_len=1024)
+    cfg = llama.LlamaConfig.olmoe_1b_7b(num_layers=layers, max_seq_len=1024)
 
     def spec(a):
-        return S(a.shape, a.dtype, sharding=v5e_chip)
+        return S(a.shape, a.dtype, sharding=sharding)
 
     params = jax.tree.map(spec, jax.eval_shape(
-        functools.partial(llama.init_params, cfg), jax.random.PRNGKey(0)))
+        functools.partial(cb.init_engine_params, cfg),
+        jax.random.PRNGKey(0)))
     cache = jax.tree.map(spec, jax.eval_shape(functools.partial(
         PagedKVCache.create, cfg, 512, 64, kv_dtype="bf16")))
-    row = S((48,), jnp.int32, sharding=v5e_chip)
-    tables = S((48, 16), jnp.int32, sharding=v5e_chip)
-    step = S((), jnp.int32, sharding=v5e_chip)
+    row = S((48,), jnp.int32, sharding=sharding)
+    tables = S((48, 16), jnp.int32, sharding=sharding)
+    step = S((), jnp.int32, sharding=sharding)
     tick = functools.partial(cb._decode_tick_paged, config=cfg,
                              use_kernel=True)
-    compiled = jax.jit(tick, donate_argnums=(5,)).lower(
+    return jax.jit(tick, donate_argnums=(5,)).lower(
         params, row, row, tables, row, cache, step).compile()
+
+
+def test_compiled_olmoe_tick_copies_no_expert_weights(v5e_chip):
+    """serve_moe_decode's tick (two layers): each layer calls ``moe_gmm``
+    three times on the STACKED expert weights, and no other instruction
+    has a layer's expert-weight shape: a per-layer slice would copy
+    805 MB a layer a tick. MHA: both paged kernels compile at 16 KV
+    heads, group 1."""
+    compiled = compile_olmoe_tick(v5e_chip, 2)
     hlo = compiled.as_text()
     shaped = re.compile(r"= \(?\w+\[(\d+,)?64,(2048,1024|1024,2048)\]")
-    free = ("custom_call_target=\"tpu_custom_call\"", " parameter(",
-            " get-tuple-element(", " tuple(", " while(", " bitcast(")
     assert [line.strip() for line in hlo.splitlines()
-            if shaped.search(line) and not any(f in line for f in free)] == []
+            if shaped.search(line) and not any(f in line for f in _FREE)] == []
     assert len(re.findall(r"%moe_gmm[.\d]* = ", hlo)) == 3
     assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 1
     assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == 2
     # One expert matrix is 4 MB: the program's scratch is far below it.
     assert compiled.memory_analysis().temp_size_in_bytes < 2048 * 1024 * 2
+
+
+def test_compiled_olmoe_tick_copies_no_more_projection_weights(v5e_chip):
+    """Under QK-norm (a whole-vector norm takes the projection flat
+    across heads) the heads-major layout does not free the tick of its
+    projection-weight copies as it does Mistral's: at the cell's 12
+    layers it holds two slices and two transposing copies where the
+    canonical layout held three slices and a relayout. Four such
+    instructions by this file's count on either side (+1% of the tick's
+    estimated bytes; -0.3% and -0.8% ``tokens_per_s`` on the chip, PR
+    30), and never more."""
+    hlo = compile_olmoe_tick(v5e_chip, 12).as_text()
+    assert len(projection_weight_moves(hlo, 16, 16, 2048)) <= 4
 
 
 # ------------------------------------------- the state-space family (PR 29)
@@ -398,7 +475,8 @@ def test_compiled_hybrid_tick_holds_no_copy_of_the_state_cache(v5e_chip):
         return S(a.shape, a.dtype, sharding=v5e_chip)
 
     params = jax.tree.map(spec, jax.eval_shape(
-        functools.partial(llama.init_params, cfg), jax.random.PRNGKey(0)))
+        functools.partial(cb.init_engine_params, cfg),
+        jax.random.PRNGKey(0)))
     cache = jax.tree.map(spec, jax.eval_shape(functools.partial(
         PagedKVCache.create, cfg, 512, 64, kv_dtype="bf16")))
     state = jax.tree.map(spec, jax.eval_shape(functools.partial(
@@ -412,13 +490,11 @@ def test_compiled_hybrid_tick_holds_no_copy_of_the_state_cache(v5e_chip):
     compiled = jax.jit(tick, donate_argnums=(5,)).lower(
         params, row, row, tables, row, (cache, state), step).compile()
     hlo = compiled.as_text()
-    free = ("custom_call_target=\"tpu_custom_call\"", " parameter(",
-            " get-tuple-element(", " tuple(", " while(", " bitcast(")
 
     def moves(shape):
         shaped = re.compile(rf"= \(?\w+\[(\d+,)?{shape}\]")
         return [line.strip() for line in hlo.splitlines()
-                if shaped.search(line) and not any(f in line for f in free)]
+                if shaped.search(line) and not any(f in line for f in _FREE)]
 
     assert moves("48,128,64,128") == []
     # ... written back in place: a dynamic-update-slice on the loop's
