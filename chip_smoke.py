@@ -28,6 +28,14 @@ seed), and checks what comes out by the repo's own means:
                 reference's full forward; six faults shown to land
                 outside the configuration file's tolerances, and a bf16
                 state measured beside them;
+* ``window``    the cell ``serve_window_decode``'s comparison at its
+                own sizes (Trinity's share at the published widths, five
+                layers, through ``ContinuousBatcher``): the cell's check
+                prompts, chosen tokens and kept routes against the
+                float32 reference under the configuration file's two
+                limits; the program as published passes, and seven
+                faults (bf16 router scores and a dropped selection bias
+                among them) each fail;
 * ``train``     ``ShardedTrainer`` on one device, batch 5 x 2048: loss
                 finite and falling, one compiled signature, the Mosaic
                 custom calls present in the compiled step;
@@ -63,14 +71,17 @@ import sys
 import threading
 import time
 
-PHASES = ("kernels", "moe", "hybrid", "train", "serve", "multichip")
+PHASES = ("kernels", "moe", "hybrid", "window", "train", "serve",
+          "multichip")
 # The multichip phase is two children: the trainer's state must be gone
 # from the chips before four serving replicas load theirs.
 CHILDREN = {"kernels": ("kernels",), "moe": ("moe",),
-            "hybrid": ("hybrid",), "train": ("train",),
+            "hybrid": ("hybrid",), "window": ("window",),
+            "train": ("train",),
             "serve": ("serve",),
             "multichip": ("multichip-train", "multichip-serve")}
-PHASE_TIMEOUT_S = {"kernels": 600, "moe": 600, "hybrid": 1500, "train": 480,
+PHASE_TIMEOUT_S = {"kernels": 600, "moe": 600, "hybrid": 1500,
+                   "window": 2700, "train": 480,
                    "serve": 600,
                    "multichip-train": 900, "multichip-serve": 900}
 RESULT_TAG = "PHASE_RESULT "
@@ -900,6 +911,153 @@ def phase_hybrid(rehearse: bool) -> None:
     _finish(phase, info, faults=results, tolerance=tolerance)
 
 
+def phase_window(rehearse: bool) -> None:
+    """The cell ``serve_window_decode``'s comparison with its reference,
+    and the faults it has to catch, AT THE CELL'S OWN SIZES: the
+    configuration as the cell runs it (Trinity's published widths, five
+    layers, experts 0-31 of 256) in the engine the served path builds
+    (``ContinuousBatcher``: chunked prefill, ring and arena, every
+    kernel; four slots are enough here), the cell's check prompts and
+    answer length, one request after another, greedy, each keeping its
+    routes; held to ``benchmark/reference_afmoe.py`` by the runner's own
+    ``hold_to_reference`` under the configuration file's limits.
+
+    First the program as published, which has to pass. Then one fault at
+    a time, each of which has to FAIL one of the two limits: the
+    router's scores in bf16; ``expert_bias`` dropped; the output gate
+    dropped; rope on the full-attention layer; a window one block too
+    long; the post-norms dropped; and the weights rounded to float8, the
+    nearest precision below the bf16 the configuration states."""
+    phase = "window"
+    info = _open_device(phase, rehearse)
+    import dataclasses
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import manifest
+    from benchmark.runners import serve_window
+    from benchmark.runners.serve import _prompts
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models import llama
+    from ray_tpu.ops import moe
+
+    cell = manifest.cell("serve_window_decode")
+    if rehearse:
+        cell = manifest.rehearsal(cell)
+    work, tolerance = cell["workload"], cell["config"]["tolerance"]
+    engine = dict(work["engine"], num_slots=4)
+    config = serve_window.afmoe_config(cell["config"],
+                                       max_seq_len=engine["max_len"])
+    # Four sets of check prompts for the program as published and for
+    # the fault nearest to it (the two readings the routes' limit lies
+    # between); the first set alone for the other faults.
+    sets = [_prompts(np.random.default_rng(seed), config.vocab_size,
+                     work["check"]["prompt_tokens"],
+                     work["check"]["max_tokens"])
+            for seed in ((32,) if rehearse else (32, 33, 34, 35))]
+    nearest = "router scores in bf16"
+
+    def published():
+        return jax.jit(lambda k: llama.init_params(config, k))(
+            jax.random.PRNGKey(0))
+
+    def answers(cfg, weights, sets):
+        """One engine; per set of requests, (request, record) pairs."""
+        eng = cb.ContinuousBatcher(cfg, params=weights, **engine)
+        out = []
+        for reqs in sets:
+            recs = []
+            for req in reqs:
+                rid = eng.submit(req["prompt"], req["max_tokens"],
+                                 keep_routes=True)
+                recs.append({"tokens": eng.run_to_completion()[rid],
+                             "routes": eng.take_routes(rid)})
+            out.append(list(zip(reqs, recs)))
+        return out
+
+    real_route, real_finish = moe.route_sigmoid_topk, cb._layer_finish
+
+    def bf16_route(h, w_router, k, renormalise=True, *, bias, scale=1.0):
+        scores = jax.nn.sigmoid(jnp.dot(
+            h.astype(jnp.bfloat16), w_router.astype(jnp.bfloat16)))
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.bfloat16), k)
+        weights = jnp.take_along_axis(scores, idx, -1).astype(jnp.float32)
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+        return weights * scale, idx.astype(jnp.int32)
+
+    def no_post_norms(x, mixed, layer, c, *args, **kw):
+        return real_finish(x, mixed, layer,
+                           dataclasses.replace(c, sandwich_norms=False),
+                           *args, **kw)
+
+    def no_bias(tree):
+        return dict(tree, runs=[
+            dict(run, expert_bias=jnp.zeros_like(run["expert_bias"]))
+            if "expert_bias" in run else run for run in tree["runs"]])
+
+    def float8(tree):
+        """Leaf by leaf, in place (two copies of the weights do not fit
+        the chip), and op by op: inside one program the compiler drops
+        a rounding whose float8 result it never has to store."""
+        def rounded(a):
+            if a.dtype != jnp.bfloat16:
+                return a
+            out = a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+            a.delete()
+            return out
+        return jax.tree.map(rounded, tree)
+
+    replace = dataclasses.replace
+    cases = [
+        ("as published", config, lambda p: p, None),
+        (nearest, config, lambda p: p,
+         lambda: setattr(moe, "route_sigmoid_topk", bf16_route)),
+        ("expert_bias dropped", config, no_bias, None),
+        ("output gate dropped", replace(config, attn_gate=False),
+         lambda p: p, None),
+        ("rope on the full-attention layer",
+         replace(config, rope_full_attention=True), lambda p: p, None),
+        ("window one block too long",
+         replace(config, sliding_window=config.sliding_window
+                 + engine["block_size"]), lambda p: p, None),
+        ("post-norms dropped", config, lambda p: p,
+         lambda: setattr(cb, "_layer_finish", no_post_norms)),
+        # Last: it eats the published weights.
+        ("weights rounded to float8_e4m3", config, float8, None),
+    ]
+    if rehearse:
+        cases = cases[:1]       # tiny sizes prove nothing about the faults
+    params = published()
+    answered = {}
+    for name, cfg, weights, patch in cases:
+        if patch:
+            patch()
+        try:
+            answered[name] = answers(
+                cfg, weights(params),
+                sets if name in ("as published", nearest) else sets[:1])
+        finally:
+            moe.route_sigmoid_topk, cb._layer_finish = real_route, real_finish
+        gc.collect()            # the engine's caches and relaid weights
+        _say(phase, f"{name}: {len(answered[name])} x {len(sets[0])} check "
+                    f"requests answered")
+    if len(cases) > 1:
+        del params
+        params = published()
+    results = {}
+    for name, checked in answered.items():
+        _say(phase, name)
+        results[name] = [serve_window.hold_to_reference(
+            params, config, checks, tolerance) for checks in checked]
+    wrong = [name for name, rs in results.items()
+             if any(r["ok"] != (name == "as published") for r in rs)]
+    assert not wrong, f"{wrong}: {results} against {tolerance}"
+    _finish(phase, info, faults=results, tolerance=tolerance)
+
+
 def _train_once(phase, config, mesh, batch_size, seq_len, steps, rehearse):
     """Init + ``steps`` steps on one repeated batch. Returns (losses,
     trainer, state)."""
@@ -1298,7 +1456,8 @@ def _child(phase: str, rehearse: bool) -> int:
 
 
 CHILD_FNS = {"kernels": phase_kernels, "moe": phase_moe,
-             "hybrid": phase_hybrid, "train": phase_train,
+             "hybrid": phase_hybrid, "window": phase_window,
+             "train": phase_train,
              "serve": phase_serve, "multichip-train": phase_multichip_train,
              "multichip-serve": phase_multichip_serve}
 

@@ -401,6 +401,28 @@ CB_MOE_LOAD_IMBALANCE = Histogram(
     "averaged over layers (1 = perfectly even)",
     boundaries=(1.0, 1.25, 1.5, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0, 16.0, 64.0),
     tag_keys=("engine",))
+CB_MOE_LOCAL_ASSIGNMENTS = Counter(
+    "ray_tpu_cb_moe_local_assignments_total",
+    "Of ray_tpu_cb_moe_assignments_total, those that fell on an expert "
+    "this chip holds (a model told which experts it holds; the others "
+    "are another chip's part of the sum)",
+    ("engine",))
+CB_WINDOW_LIVE_BLOCK_SHARE = Histogram(
+    "ray_tpu_cb_window_live_block_share",
+    "Per decode tick: blocks a sliding-window layer's kernel visits (those "
+    "that hold one of a query's last sliding_window keys) over the blocks "
+    "a table of every position would have it visit",
+    boundaries=_SHARE_BOUNDS, tag_keys=("engine",))
+CB_WINDOW_KV_BYTES = Gauge(
+    "ray_tpu_cb_window_kv_bytes",
+    "Resident bytes of the sliding-window layers' per-slot rings; fixed "
+    "at construction, whatever the contexts",
+    ("engine",))
+CB_FULL_KV_BYTES = Gauge(
+    "ray_tpu_cb_full_kv_bytes",
+    "Resident bytes of the arena of a model with sliding-window layers: "
+    "the K/V of its full-attention layers alone",
+    ("engine",))
 CB_PAGED_LIVE_BLOCK_SHARE = Histogram(
     "ray_tpu_cb_paged_live_block_share",
     "Per decode tick: block-table entries that hold a key a query may "
@@ -443,6 +465,16 @@ CB_STEP_ADMIT_MS = Histogram(
     "taken out: queue scan, block allocation, prefix match, building "
     "the batch, first-token bookkeeping (span engine.admit)",
     boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
+CB_PREFILL_CHUNK_MS = Histogram(
+    "ray_tpu_cb_prefill_chunk_ms",
+    "Milliseconds per prefill PROGRAM call (span engine.prefill.chunk): "
+    "a prompt over the engine's prefill_chunk runs as several, back to "
+    "back, and each one's first tokens land when it ends, so this is the "
+    "time between two landings; the first call's clock starts where "
+    "ray_tpu_cb_prefill_ms's does",
+    boundaries=(1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0, 500.0,
+                1000.0, 5000.0),
+    tag_keys=("engine",))
 CB_PREFILL_MS = Histogram(
     "ray_tpu_cb_prefill_ms",
     "Wall milliseconds per prefill BATCH: uploads, dispatch, compute, "

@@ -107,7 +107,8 @@ class ContinuousLlamaDeployment:
                  spec_draft_layers: Optional[int] = None,
                  spec_adaptive: Optional[bool] = None,
                  checkpoint_path: Optional[str] = None,
-                 role: Optional[str] = None):
+                 role: Optional[str] = None,
+                 prefill_chunk: Optional[int] = None):
         """Engine knobs (``num_slots``, ``max_len``, ``sync_every``,
         ``use_decode_kernel``, and the paged-KV plane's
         ``block_size`` / ``kv_dtype`` / ``num_blocks`` / ``sampling``)
@@ -171,7 +172,10 @@ class ContinuousLlamaDeployment:
             num_blocks=num_blocks, prefix_cache=prefix_cache,
             sampling=sampling, spec_k=spec_k,
             spec_draft_layers=spec_draft_layers,
-            spec_adaptive=spec_adaptive, role=role, device=self.device)
+            spec_adaptive=spec_adaptive, role=role, device=self.device,
+            # None leaves the engine's own default.
+            **({} if prefill_chunk is None
+               else {"prefill_chunk": prefill_chunk}))
         # Reservation tickets are engine-local ids; the nonce scopes a
         # ticket to THIS replica so a router whose reserve and
         # decode_from calls landed on different replicas cannot spend
@@ -214,7 +218,10 @@ class ContinuousLlamaDeployment:
                                    tags):
                     for rid in finished:
                         q = self._queues.get(rid)
+                        routes = self.batcher.take_routes(rid)
                         if q is not None:
+                            if routes is not None:
+                                q.put({"routes": routes})
                             q.put(None)  # end-of-stream
             except Exception as e:  # noqa: BLE001
                 # Engine error (OOM, bad request reaching the kernel):
@@ -421,15 +428,23 @@ class ContinuousLlamaDeployment:
         (``phase=decode,token=N`` — mid-decode, N tokens already
         streamed). The raised ``SimulatedProcessDeath`` unwinds through
         the replica actor's task machinery into genuine actor death —
-        exactly what the ingress journal recovers from."""
+        exactly what the ingress journal recovers from.
+
+        ``"return_routes": true`` in the payload (a held expert share
+        alone): after the last token the stream carries one control
+        object, ``{"routes": [position][routed layer][k]}``, the experts
+        each decoded position routed to
+        (``ContinuousBatcher.take_routes``)."""
         from ray_tpu._private import chaos
 
         entered = time.time()
         resumed_tokens = 0
+        keep_routes = False
         if isinstance(prompt_token_ids, dict):
             payload = prompt_token_ids
             prompt_token_ids = payload["prompt_token_ids"]
             max_tokens = payload.get("max_tokens", max_tokens)
+            keep_routes = bool(payload.get("return_routes", False))
             resumed_tokens = int(payload.get("resumed_tokens", 0) or 0)
         if resumed_tokens and self.batcher.eos_token is not None \
                 and prompt_token_ids \
@@ -449,7 +464,7 @@ class ContinuousLlamaDeployment:
         with self._submitting(entered, trace) as locked:
             rid = self.batcher.submit(list(prompt_token_ids),
                                       max_new_tokens=int(max_tokens),
-                                      trace=trace)
+                                      trace=trace, keep_routes=keep_routes)
             self.batcher.note_submit_wait(rid, entered, locked)
             self._queues[rid] = q
         self._work.set()
@@ -464,6 +479,9 @@ class ContinuousLlamaDeployment:
                 if isinstance(token, Exception):
                     done = True
                     raise token
+                if isinstance(token, dict):     # a control object
+                    yield token
+                    continue
                 if chaos.enabled():
                     # Fires BEFORE the yield: a rule with token=N dies
                     # with exactly N tokens delivered downstream.
@@ -661,7 +679,8 @@ def build_continuous_llama_app(config: Optional[llama.LlamaConfig] = None,
                                spec_k: Optional[int] = None,
                                spec_draft_layers: Optional[int] = None,
                                spec_adaptive: Optional[bool] = None,
-                               checkpoint_path: Optional[str] = None):
+                               checkpoint_path: Optional[str] = None,
+                               prefill_chunk: Optional[int] = None):
     dep = ContinuousLlamaDeployment.options(
         num_replicas=num_replicas, ray_actor_options=_chip_per_replica())
     # Keyword bind so per-deploy ``init_kwargs`` overrides (serve config
@@ -674,7 +693,8 @@ def build_continuous_llama_app(config: Optional[llama.LlamaConfig] = None,
                     sampling=sampling, spec_k=spec_k,
                     spec_draft_layers=spec_draft_layers,
                     spec_adaptive=spec_adaptive,
-                    checkpoint_path=checkpoint_path)
+                    checkpoint_path=checkpoint_path,
+                    prefill_chunk=prefill_chunk)
 
 
 def build_disagg_llama_apps(name: str = "llm",

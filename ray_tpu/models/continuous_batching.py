@@ -43,13 +43,14 @@ arena back attends exactly what the original prefill attended.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
 import os
 import time
 import zlib
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,17 +58,19 @@ import numpy as np
 
 from ray_tpu._private import xla_monitor
 from ray_tpu.models import llama, mamba2
+from ray_tpu.models import paged_kv
 from ray_tpu.models.inference import (ExternalLlamaDrafter, KVCache,
                                       SelfDrafter, _attend_cached,
                                       _forward_cached, lm_head_logits)
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.models.paged_kv import (GARBAGE_BLOCK, BlockAllocator,
                                      PagedKVCache, RadixBlockIndex,
-                                     StateCache, prompt_chunks,
+                                     RingKVCache, StateCache, prompt_chunks,
                                      quantize_kv, resolve_kv_dtype)
 from ray_tpu.models.sampling import (SPEC_DRAFT_SALT, SamplingParams,
                                      filtered_probs, sample_tokens,
                                      spec_commit, step_key)
+from ray_tpu.ops.attention import paged_chunk_attention
 from ray_tpu.ops.dispatch import env_flag
 from ray_tpu.ops.paged_decode_attention import (decode_attention_reference,
                                                 paged_applicable,
@@ -98,8 +101,21 @@ def _scatter_arena(arena, new, block_idx, offset):
     return arena.at[block_idx, :, offset].set(new.astype(arena.dtype))
 
 
+# A prefill program whose queries see at most this many keys (prefix +
+# suffix) scores them all at once in float32 (:func:`_attend_cached`:
+# every program compiled before chunked prefill existed); one that sees
+# more, or whose model has sliding-window layers, runs the blockwise
+# softmax over the arena's blocks (``ops.attention.paged_chunk_attention``).
+PREFILL_DENSE_KEYS = 1024
+
+# The most padded tokens (rows x padded length) one ``cb_prefill`` call
+# takes: what bounds a prefill program's temporaries whatever waits in
+# the queue. No batch within one chunk at 8 rows is split by it.
+PREFILL_BATCH_TOKENS = 8192
+
+
 def _window_visits(tables, positions, limits, block_size: int,
-                   use_kernel: bool):
+                   use_kernel: bool, window: int = 0):
     """The attention kernel's schedule for each of a window's S
     positions (``positions`` [B, S]): made ONCE a program, before the
     layer loop, because XLA leaves it inside the loop otherwise. A freed
@@ -107,12 +123,13 @@ def _window_visits(tables, positions, limits, block_size: int,
     if not use_kernel:
         return None
     return [paged_visits(tables, positions[:, j], limits,
-                         block_size=block_size)
+                         block_size=block_size, window=window)
             for j in range(positions.shape[1])]
 
 
 def _write_then_attend(arenas, li, q, k_new, v_new, block_idx, offset,
-                       tables, positions, visits, scale, use_kernel: bool):
+                       tables, positions, visits, scale, use_kernel: bool,
+                       window: int = 0):
     """The attention half of :func:`_forward_paged`'s layer: write each
     slot's S new tokens' K/V into layer ``li`` (S = 1 for a tick, k+1
     for verify), then attend every window position over the slot's
@@ -122,7 +139,9 @@ def _write_then_attend(arenas, li, q, k_new, v_new, block_idx, offset,
     block_idx/offset/positions [B, S]; ``visits`` from
     :func:`_window_visits`. All S writes land before any
     query attends, which position masking makes safe (query j sees
-    [0..p+j] only). Returns (o [B, S, Hq, D], arenas').
+    [0..p+j] only). With ``window`` the arenas are the RING of the
+    sliding-window layers and ``tables`` each slot's ring
+    (``paged_kv.RingKVCache``). Returns (o [B, S, Hq, D], arenas').
 
     With the kernels the write is a Mosaic call aliased onto the carry
     and the read takes the layer as a scalar, so no slab ever exists.
@@ -167,7 +186,7 @@ def _write_then_attend(arenas, li, q, k_new, v_new, block_idx, offset,
                                    positions[:, j], scale, layer=layer,
                                    visits=visits and visits[j],
                                    k_scale=ks, v_scale=vs,
-                                   use_kernel=use_kernel)
+                                   use_kernel=use_kernel, window=window)
             for j in range(q.shape[1])]   # unrolled: S = k+1, small
     return jnp.stack(outs, axis=1), arenas
 
@@ -227,12 +246,14 @@ def _layer_qkv(x, layer, cos, sin, c):
     position). S is 1 for a tick and k+1 for a verify window: the window
     rides the batch dims and the E-axis accumulation is untouched, so
     position j of a window gets the bits S = 1 gives it, which the
-    spec-on/off parity tests pin down."""
+    spec-on/off parity tests pin down. Also returns the output gate
+    (``llama.attn_gate``; None for a model without one)."""
     h = rms_norm(x, layer["attn_norm"], c.rms_eps)
     q, k, v = llama.project_qkv(h, layer, c)
-    if not c.rope:      # position_embedding_type "nope": no rotation
-        return q, k, v
-    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+    gate = llama.attn_gate(h, layer, c)    # None without an output gate
+    if cos is None:     # no positions: "nope", or a layer kind without
+        return q, k, v, gate
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v, gate
 
 
 def _rope_tables(c, length, positions):
@@ -250,9 +271,31 @@ def _embed(params, tokens, c):
     return x
 
 
-def _attn_out(o, layer, c):
-    """Attention's output projection of o [B, S, H, D] -> [B, S, E]."""
+def _attn_out(o, layer, c, gate=None):
+    """Attention's output projection of o [B, S, H, D] -> [B, S, E],
+    after the output gate where the model has one."""
+    if gate is not None:
+        o = o * gate
     return jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
+
+
+_KIND_SCOPES = {"sliding_attention": "attn/window",
+                "full_attention": "attn/full"}
+
+
+def _kind_scope(kind):
+    """The profiler scope of the two attention kinds of a windowed
+    model; a homogeneous model's layers keep the names they had."""
+    name = _KIND_SCOPES.get(kind)
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
+def _kind_rope(c, kind, cos, sin):
+    """The rope tables layer ``kind`` takes: none for a full-attention
+    layer of a model that gives those no positions."""
+    if kind == "full_attention" and not c.rope_full_attention:
+        return None, None
+    return cos, sin
 
 
 def _residual(x, y, c):
@@ -269,12 +312,22 @@ def _layer_finish(x, mixed, layer, c, experts=None, li=None,
     family's one MLP function (:func:`llama.mlp_block`: dense SwiGLU, or
     the routed block reading the stacked ``experts`` at layer ``li``).
     Returns (x, rows): the routed block's per-expert assignment counts,
-    None for a dense model."""
+    None for a dense model. A HELD SHARE's rows carry each token's chosen
+    experts ``[T * k]`` behind the counts: its output leaves out what the
+    absent experts add, so its routing shows nowhere else
+    (:meth:`ContinuousBatcher.take_routes`)."""
+    if c.sandwich_norms:
+        mixed = rms_norm(mixed, layer["post_attn_norm"], c.rms_eps)
     x = _residual(x, mixed, c)
     h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
     down, routed = llama.mlp_block(h, layer, c, experts, li,
                                    use_kernel=use_kernel)
-    return _residual(x, down, c), None if routed is None else routed.rows
+    if c.sandwich_norms:
+        down = rms_norm(down, layer["post_mlp_norm"], c.rms_eps)
+    rows = None if routed is None else routed.rows
+    if rows is not None and c.experts_held:
+        rows = jnp.concatenate([rows, routed.experts.reshape(-1)])
+    return _residual(x, down, c), rows
 
 
 def _concat_runs(parts):
@@ -287,8 +340,10 @@ def _concat_runs(parts):
 
 
 def _split_caches(caches):
-    """(arena, state cache) of an engine program's ``caches`` operand:
-    the arena alone for a model without state layers, else the pair."""
+    """(arena, second cache) of an engine program's ``caches`` operand:
+    the arena alone for a model whose every layer keeps all its K/V,
+    else the pair: with the state cache (state-space layers) or the
+    ring (sliding-window layers)."""
     if isinstance(caches, PagedKVCache):
         return caches, None
     return caches
@@ -347,6 +402,17 @@ def _forward_paged(params, tokens, positions, tables, limits,
                           GARBAGE_BLOCK)                      # [B, S]
     offset = positions % bs
     visits = _window_visits(tables, positions, limits, bs, use_kernel)
+    if isinstance(state, RingKVCache):
+        # Sliding-window layers: every slot's fixed ring of blocks, its
+        # table an iota; position p lands in entry (p // bs) % ring.
+        ring = paged_kv.ring_blocks(c.sliding_window, bs)
+        ring_tables = RingKVCache.tables(jnp.arange(tokens.shape[0]), ring)
+        ring_idx = jnp.where(
+            positions < limits[:, None],
+            jnp.take_along_axis(ring_tables, (positions // bs) % ring,
+                                axis=1), GARBAGE_BLOCK)
+        ring_visits = _window_visits(ring_tables, positions, limits, bs,
+                                     use_kernel, c.sliding_window)
 
     runs, experts = llama.layer_runs(c, params, n_layers)
 
@@ -362,11 +428,20 @@ def _forward_paged(params, tokens, positions, tables, limits,
                                              use_kernel)
             held = tuple(held)
         else:
-            q, k, v = _layer_qkv(x, layer, cos, sin, c)
-            o, arenas = _write_then_attend(
-                arenas, ki, q, k, v, block_idx, offset, tables, positions,
-                visits, scale, use_kernel)
-            mixed = _attn_out(o.astype(x.dtype), layer, c)
+            with _kind_scope(kind):
+                q, k, v, gate = _layer_qkv(
+                    x, layer, *_kind_rope(c, kind, cos, sin), c)
+                if kind == "sliding_attention":
+                    o, ring_kv = _write_then_attend(
+                        held + (None, None), ki, q, k, v, ring_idx, offset,
+                        ring_tables, positions, ring_visits, scale,
+                        use_kernel, c.sliding_window)
+                    held = ring_kv[:2]
+                else:
+                    o, arenas = _write_then_attend(
+                        arenas, ki, q, k, v, block_idx, offset, tables,
+                        positions, visits, scale, use_kernel)
+                mixed = _attn_out(o.astype(x.dtype), layer, c, gate)
         x, rows = _layer_finish(x, mixed, layer, c, experts, li,
                                 use_kernel)
         return (x, arenas, held, li + 1), rows
@@ -383,7 +458,7 @@ def _forward_paged(params, tokens, positions, tables, limits,
     # (shared with prefill): bf16 params are never upcast in HBM.
     logits = lm_head_logits(x, params, c)
     cache = PagedKVCache(*arenas)
-    return (logits, (cache, StateCache(*held)) if held else cache,
+    return (logits, (cache, type(state)(*held)) if held else cache,
             _concat_runs(rows))
 
 
@@ -402,7 +477,7 @@ def _draft_forward_dense(dparams, tokens, positions, dcache: KVCache,
         x, ck_all, cv_all, li = carry
         ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
         cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
-        q, k, v = _layer_qkv(x, layer, cos, sin, c)
+        q, k, v, _ = _layer_qkv(x, layer, cos, sin, c)
         ck = _scatter_slot(ck, k[:, 0].astype(ck.dtype), positions)
         cv = _scatter_slot(cv, v[:, 0].astype(cv.dtype), positions)
         o = decode_attention_reference(q[:, 0], ck, cv, positions, scale)
@@ -510,9 +585,24 @@ def _decode_tick_paged(params, tokens, positions, tables, limits,
     return state + (jnp.concatenate([next_tokens, rows.reshape(-1)]),)
 
 
+class _PagedPrefix(NamedTuple):
+    """Where a prefill chunk's queries find the prompt's EARLIER keys
+    without anyone gathering them first: the caches themselves and, for
+    each row, the blocks that hold them in order. ``tables [N, m]``
+    names arena blocks (positions ``0 .. m * bs``); ``ring_tables [N,
+    mw]`` the ring entries of the last ``mw`` of those ``m`` logical
+    blocks, the only ones a sliding-window layer's queries still see
+    (None without such layers)."""
+    cache: PagedKVCache
+    tables: jnp.ndarray
+    ring: Optional[RingKVCache] = None
+    ring_tables: Optional[jnp.ndarray] = None
+
+
 def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                            quantized, last_idx, use_kernel=None,
-                           state: Optional[StateCache] = None, slots=None):
+                           state: Optional[StateCache] = None, slots=None,
+                           paged: Optional[_PagedPrefix] = None):
     """Prefill forward over ``[shared prefix ++ suffix]``.
 
     ``tokens`` [N, S] are the suffix at absolute ``positions`` [S]
@@ -536,7 +626,16 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     takes the rows' lengths. Each layer writes its rows into the cache
     as the layer loop's CARRY (the five layers' states of a 48-row
     batch, stacked as the loop's output, would be a second gigabyte);
-    a repeated padding row writes the same bytes twice."""
+    a repeated padding row writes the same bytes twice.
+
+    With ``paged`` (a long prompt's chunk, or a model with
+    sliding-window layers; ``pk``/``pv`` are then not read) attention is
+    :func:`~ray_tpu.ops.attention.paged_chunk_attention`: blockwise over
+    the earlier keys where they lie, in the arena or the ring, then over
+    the chunk's own, so no ``[S, P + S]`` float32 score exists; and
+    ``stored`` comes back as ``{cache kind: K/V of the layers that keep
+    theirs there}`` (``"attention"``: the arena; ``"sliding_attention"``:
+    the ring)."""
     c = config
     cos, sin = _rope_tables(c, tokens.shape[1], positions)
     x = _embed(params, tokens, c)
@@ -553,9 +652,26 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
             mixed, *new = mamba2.mixer_prefill(h, layer, c, last_idx + 1)
             held = tuple(a.at[li + shift, slots].set(n.astype(a.dtype))
                          for a, n in zip(held, new))
+        elif paged is not None:
+            layer, = inputs
+            with _kind_scope(kind):
+                q, k, v, gate = _layer_qkv(
+                    x, layer, *_kind_rope(c, kind, cos, sin), c)
+                kept = (k, v)
+                if kind == "sliding_attention":
+                    source, tables = paged.ring, paged.ring_tables
+                    window = c.sliding_window
+                else:
+                    source, tables, window = paged.cache, paged.tables, 0
+                chunk_pos = paged.tables.shape[1] * source.k.shape[3]
+                o = paged_chunk_attention(
+                    q, k, v, source.k, source.v, li + shift, tables,
+                    chunk_pos - tables.shape[1] * source.k.shape[3],
+                    chunk_pos, scale, window=window)
+                mixed = _attn_out(o, layer, c, gate)
         else:
             layer, pk_l, pv_l = inputs
-            q, k, v = _layer_qkv(x, layer, cos, sin, c)
+            q, k, v, gate = _layer_qkv(x, layer, cos, sin, c)
             if quantized:
                 kq, ksc = quantize_kv(k)
                 vq, vsc = quantize_kv(v)
@@ -570,14 +686,15 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
             ck = jnp.concatenate([pk_l, k_att], axis=1)   # [N, P+S, KVH, D]
             cv = jnp.concatenate([pv_l, v_att], axis=1)
             mixed = _attn_out(_attend_cached(q, ck, cv, positions, scale),
-                              layer, c)
+                              layer, c, gate)
         x, _ = _layer_finish(x, mixed, layer, c, experts, li, use_kernel)
         return (x, held, li + 1), kept
 
     stored, held = [], tuple(state or ())
+    by_kind: Dict[str, list] = {"attention": [], "sliding_attention": []}
     for (kind, start, count, kind_start), tree in runs:
         inputs = (tree,)
-        if kind == "attention":
+        if kind == "attention" and paged is None:
             whole = count == pk.shape[0]
             inputs += tuple(a if whole else a[kind_start:kind_start + count]
                             for a in (pk, pv))
@@ -586,11 +703,56 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                               shift=kind_start - start),
             (x, held, jnp.int32(start)), inputs)
         stored.append(kept or None)
+        if kind != "mamba":
+            by_kind["sliding_attention" if kind == "sliding_attention"
+                    else "attention"].append(kept)
     x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)  # [N, 1, E]
     x = rms_norm(x, params["final_norm"], c.rms_eps)
     logits = lm_head_logits(x, params, c)
+    if paged is not None:
+        return logits, {k: _concat_runs(v) for k, v in by_kind.items()}, None
     return (logits, _concat_runs(stored),
             StateCache(*held) if held else None)
+
+
+def _prefill_chunk_paged(params, tokens, positions, cache, ring, ptables,
+                         tables_w, last_idx, slots, config, use_kernel):
+    """One prefill CHUNK through the paged caches (``cb_prefill``'s body
+    for a chunk of a long prompt, and for every prefill of a model with
+    sliding-window layers): attends the earlier chunks where they lie
+    (:class:`_PagedPrefix`), then lands its own K/V: in the arena
+    through ``tables_w``, and in each row's ring at the entries of its
+    logical blocks. A block that holds PADDING ONLY goes to the garbage
+    block instead: in a ring it would overwrite a block the row's first
+    decode queries still see. Returns (logits [N, 1, V], arena, ring)."""
+    bs = cache.block_size
+    npb = tokens.shape[1] // bs
+    m = ptables.shape[1]
+    ring_tables = ring_write = None
+    if ring is not None:
+        n_ring = paged_kv.ring_blocks(config.sliding_window, bs)
+        own = RingKVCache.tables(slots, n_ring)               # [N, ring]
+        first = max(m * bs - config.sliding_window + 1, 0) // bs
+        ring_tables = jnp.take(own, jnp.arange(first, m) % n_ring, axis=1)
+        real = jnp.arange(npb)[None, :] * bs <= last_idx[:, None]
+        ring_write = jnp.where(
+            real, jnp.take(own, (m + jnp.arange(npb)) % n_ring, axis=1),
+            GARBAGE_BLOCK)
+    logits, stored, _ = _prefill_forward_paged(
+        params, tokens, positions, None, None, config, False, last_idx,
+        use_kernel, paged=_PagedPrefix(cache, ptables, ring, ring_tables))
+
+    def land(into, kv, tables):
+        k, v = (a.at[:, tables.reshape(-1)].set(
+            _ctx_to_blocks(new.astype(a.dtype), bs))
+            for a, new in zip((into.k, into.v), kv))
+        return type(into)(k=k, v=v)
+
+    if stored["attention"] is not None:
+        cache = land(cache, stored["attention"], tables_w)
+    if ring is not None:
+        ring = land(ring, stored["sliding_attention"], ring_write)
+    return logits, cache, ring
 
 
 def _bucket(n: int, floor: int = 16) -> int:
@@ -722,7 +884,8 @@ class ContinuousBatcher:
                  spec_adaptive: Optional[bool] = None,
                  drafter=None,
                  role: Optional[str] = None,
-                 device: Optional[jax.Device] = None):
+                 device: Optional[jax.Device] = None,
+                 prefill_chunk: int = 1024):
         """``token_callback(rid, token)`` fires for every generated token
         as it is produced (serving streams ride this).
 
@@ -818,7 +981,24 @@ class ContinuousBatcher:
         default) is the colocated engine. Greedy outputs are
         bit-identical split vs colocated: the exported bytes are the
         exact arena blocks (int8 scales included) the colocated decode
-        would have attended."""
+        would have attended.
+
+        CHUNKED PREFILL: a prompt (less its matched prefix) longer than
+        ``prefill_chunk`` tokens (a power of two) runs as that many
+        tokens a ``cb_prefill`` call, each chunk attending the earlier
+        ones out of the arena, back to back inside one admission; a
+        shorter one is ONE call padded to its power-of-two bucket, as
+        ever. ``PREFILL_BATCH_TOKENS`` caps rows x padded tokens of one
+        call (a wave of long prompts is split into calls of fewer rows).
+        A model with state-space layers is never chunked (its state
+        would have to be carried between chunks).
+
+        SLIDING-WINDOW LAYERS (``layer_types`` with "sliding_attention"):
+        their K/V live in a per-slot ring of ``window / block_size + 2``
+        blocks beside the arena (``paged_kv.RingKVCache``), which holds
+        the full-attention layers alone. Whatever needs K/V a ring has
+        overwritten is refused by name: the prefix cache, speculation,
+        buffered decode, the KV handoff."""
         self.config = config
         self.device = device
         self.num_slots = num_slots
@@ -839,6 +1019,19 @@ class ContinuousBatcher:
         if config.state_layers:
             self._refuse_for_state_layers(prefix_cache, spec_k, drafter)
             prefix_cache = False    # nothing to share: off unless asked
+        if config.window_layers:
+            self._refuse_for_window_layers(prefix_cache, spec_k, drafter)
+            prefix_cache = False
+        chunk = _bucket_floor(int(prefill_chunk))
+        if config.window_layers:
+            # A chunk's blocks must be distinct entries of a ring.
+            chunk = min(chunk, _bucket_floor(self.block_size * paged_kv.
+                        ring_blocks(config.sliding_window, self.block_size)))
+        if chunk < self.block_size:
+            raise ValueError(f"prefill_chunk {prefill_chunk} is under one "
+                             f"block of {self.block_size}")
+        # None: a prompt is never split (state-space layers).
+        self.prefill_chunk = None if config.state_layers else chunk
         self.prefix_cache = _resolve_prefix_cache(prefix_cache)
         self.use_decode_kernel = _resolve_decode_kernel(
             config, use_decode_kernel, self.block_size)
@@ -991,6 +1184,7 @@ class ContinuousBatcher:
         self._waiting: deque = deque()
         self._rid = itertools.count()
         self._finished: Dict[int, List[int]] = {}
+        self._routes: Dict[int, list] = {}     # take_routes
         # Disaggregation state: prefill-role engines park each request's
         # retained arena blocks here between its first token and the
         # export call; decode-role engines hold pre-reserved import
@@ -1045,6 +1239,14 @@ class ContinuousBatcher:
             m *= 2
         prefill_dims = (max_len, num_slots, 0) + tuple(
             self.block_size * (self.max_blocks - v) for v in sorted(ms))
+        if self.prefill_chunk:
+            # A later chunk's prefix table: its matched blocks plus the
+            # whole chunks before it.
+            per = self.prefill_chunk // self.block_size
+            prefill_dims += tuple(
+                v + i * per for v in sorted(ms)
+                for i in range(1, self.max_blocks // per + 1)
+                if v + i * per <= self.max_blocks)
 
         @xla_monitor.instrument(name="cb_prefill",
                                 shape_policy="bucketed",
@@ -1068,6 +1270,20 @@ class ContinuousBatcher:
             n, s_pad = tokens.shape
             m = ptables.shape[1]
             positions = m * block_size_c + jnp.arange(s_pad)
+            ring = held if isinstance(held, RingKVCache) else None
+            if not cache.quantized and (ring is not None or (
+                    held is None
+                    and m * block_size_c + s_pad > PREFILL_DENSE_KEYS)):
+                # A long prompt's chunk, or sliding-window layers: the
+                # earlier keys are read where they lie, blockwise. (Not
+                # a model with state layers, whose prefill installs a
+                # state; nor an int8 arena: both keep the path they had.)
+                logits, cache, ring = _prefill_chunk_paged(
+                    params, tokens, positions, cache, ring, ptables,
+                    tables_w, last_idx, slots, cfg, use_kernel)
+                first = _next_tokens(logits, pstep, sampling_cfg,
+                                     salt=_PREFILL_SALT)
+                return first, (cache if held is None else (cache, ring))
             flat_p = ptables.reshape(-1)                 # [N * m]
             pk = cache.k[:, flat_p]
             pv = cache.v[:, flat_p]
@@ -1183,6 +1399,39 @@ class ContinuousBatcher:
             refuse(f"role={self.role!r}",
                    "the KV handoff carries no recurrent state")
 
+    def _refuse_for_window_layers(self, prefix_cache, spec_k, drafter):
+        """A sliding-window layer keeps the last ``sliding_window`` keys
+        of a slot in a ring and overwrites the rest: whatever needs K/V
+        from further back, or K/V that outlive their request, is refused
+        by name."""
+        def refuse(what, why):
+            raise ValueError(
+                f"{what} is not supported for a model with sliding-window "
+                f"layers (layer_types has 'sliding_attention'): {why}")
+
+        if self.config.state_layers:
+            refuse("a state-space layer in the same stack",
+                   "the engine keeps one second cache beside the arena")
+        if self.kv_dtype != "bf16":
+            refuse(f"kv_dtype={self.kv_dtype!r}",
+                   "the ring has no scale sidecar")
+        if _resolve_spec_k(spec_k) or drafter is not None:
+            refuse("speculative decoding (spec_k > 0)",
+                   "a rejected draft's writes may have overwritten ring "
+                   "entries the rewound position still sees")
+        if self.sync_every > 1:
+            refuse("buffered decode (sync_every > 1)",
+                   "its rewind after a finish replays ticks over ring "
+                   "entries they have already overwritten")
+        if prefix_cache or (prefix_cache is None
+                            and env_flag("RAY_TPU_PREFIX_CACHE")):
+            refuse("the prefix cache (prefix_cache=True)",
+                   "a hit restores the arena's blocks, not the window "
+                   "layers' keys, which a ring has overwritten")
+        if self.role != "both":
+            refuse(f"role={self.role!r}",
+                   "the KV handoff carries the arena's blocks only")
+
     def _place(self, tree):
         """Commit a pytree (host or device values) to this engine's chip;
         with no chip named, host values go to JAX's default device."""
@@ -1202,12 +1451,22 @@ class ContinuousBatcher:
                 self.config, self.num_blocks, self.block_size,
                 self.kv_dtype))
 
-    def _new_state(self) -> Optional[StateCache]:
-        if not self.config.state_layers:
+    def _new_state(self):
+        """The second cache beside the arena: the state cache of a model
+        with state-space layers, the ring of one with sliding-window
+        layers, None for any other."""
+        c = self.config
+        if not (c.state_layers or c.window_layers):
             return None
         with jax.default_device(self.device):
-            return self._place(StateCache.create(self.config,
-                                                 self.num_slots))
+            if c.window_layers:
+                return self._place(RingKVCache.create(
+                    c, self.num_slots, self.block_size))
+            return self._place(StateCache.create(c, self.num_slots))
+
+    @property
+    def _ring(self) -> Optional[RingKVCache]:
+        return self.state if isinstance(self.state, RingKVCache) else None
 
     def _caches(self):
         """The ``caches`` operand of ``cb_prefill`` and ``cb_tick``."""
@@ -1447,7 +1706,7 @@ class ContinuousBatcher:
             # Resident bytes of the per-slot state cache (state-space
             # layers): fixed at construction, whatever the contexts.
             "state_cache_bytes": (self.state.nbytes
-                                  if self.state is not None else 0),
+                                  if self.config.state_layers else 0),
         }
 
     # ---------------------------------------------------------------- api
@@ -1530,9 +1789,14 @@ class ContinuousBatcher:
 
     def submit(self, prompt_tokens: List[int],
                max_new_tokens: int = 32,
-               trace: Optional[Dict[str, Any]] = None) -> int:
+               trace: Optional[Dict[str, Any]] = None,
+               keep_routes: bool = False) -> int:
         """Queue a request; returns its id. It joins the next tick with a
         free slot — no waiting for the current batch to drain.
+
+        ``keep_routes``: keep the experts each decoded position routed
+        to, for :meth:`take_routes` (a held expert share alone: only its
+        tick rows carry them).
 
         ``trace`` carries the serve request context
         (``request_id``/``trace_id``/``parent_span_id``/``deployment``/
@@ -1541,6 +1805,10 @@ class ContinuousBatcher:
         and the TTFT/TPOT histograms are tagged with its
         deployment/tenant either way."""
         assert len(prompt_tokens) + max_new_tokens <= self.max_len
+        if keep_routes and not self.config.experts_held:
+            raise ValueError(
+                "keep_routes: only a held expert share's tick rows carry "
+                "each slot's chosen experts (config.experts_held)")
         if max_new_tokens <= 0:
             # Nothing to generate: finish immediately — no slot, no
             # blocks, so arena capacity is irrelevant.
@@ -1568,7 +1836,8 @@ class ContinuousBatcher:
             self._traced_live += 1
         self._waiting.append({"rid": rid,
                               "prompt": list(prompt_tokens),
-                              "max_new": max_new_tokens})
+                              "max_new": max_new_tokens,
+                              "routes": [] if keep_routes else None})
         return rid
 
     def _release_slot(self, slot: int) -> None:
@@ -1605,6 +1874,7 @@ class ContinuousBatcher:
         # A parked handoff's retained blocks must not outlive the
         # request (the first token already sits in _finished).
         self.abandon_handoff(rid)
+        self._routes.pop(rid, None)
         return self._finished.pop(rid, None) is not None
 
     def reset(self) -> List[int]:
@@ -1624,6 +1894,7 @@ class ContinuousBatcher:
         self._waiting.clear()
         self._free = list(range(self.num_slots))
         self._finished.clear()
+        self._routes.clear()
         self._buf = []
         self._pending = None
         # A tick in flight is dropped unfetched, and with it the device's
@@ -1730,10 +2001,15 @@ class ContinuousBatcher:
         return True
 
     def _refuse_handoff(self, what: str) -> None:
-        if self.state is not None:
+        if self.config.state_layers:
             raise ValueError(
                 f"{what} is not supported for a model with state-space "
                 f"layers: the KV handoff carries no recurrent state")
+        if self.config.window_layers:
+            raise ValueError(
+                f"{what} is not supported for a model with sliding-window "
+                f"layers: the KV handoff carries the arena's blocks, not "
+                f"a ring's")
 
     def export_kv_payload(self, rid: int) -> Dict[str, Any]:
         """Materialize a parked request's KV handoff: gather its
@@ -1935,7 +2211,7 @@ class ContinuousBatcher:
             self.token_callback(rid, first)
         self._slots[slot] = {
             "rid": rid, "out": [first], "max_new": max_new,
-            "pos": plen, "last": first,
+            "pos": plen, "last": first, "routes": None,
             "la_blocks": self._lookahead_blocks(plen, max_new),
         }
         self._maybe_finish(slot)
@@ -1976,6 +2252,16 @@ class ContinuousBatcher:
         bs = self.block_size
         return sum(st["pos"] // bs + 1 for st in self._slots.values())
 
+    def _window_blocks(self) -> tuple:
+        """(blocks a sliding-window layer's kernel visits for the next
+        tick's queries, blocks a table of every position would have it
+        visit): over the live slots."""
+        bs, w = self.block_size, self.config.sliding_window
+        last = [st["pos"] // bs for st in self._slots.values()]
+        first = [max(st["pos"] - w + 1, 0) // bs
+                 for st in self._slots.values()]
+        return sum(last) - sum(first) + len(last), sum(last) + len(last)
+
     def _account_tick(self, tick_fn, wall_s: float, spec_k: int) -> None:
         """Feed one tick (or a buffered window's mean tick) to the XLA
         monitor, with the live-byte hint, because the compiled cost
@@ -1986,6 +2272,9 @@ class ContinuousBatcher:
         live = self._live_blocks()
         mdefs.CB_PAGED_LIVE_BLOCK_SHARE.observe(
             live / (self.num_slots * self.max_blocks), tags=self._mtags)
+        if self._ring is not None and live:
+            mdefs.CB_WINDOW_LIVE_BLOCK_SHARE.observe(
+                self._window_blocks()[0] / live, tags=self._mtags)
         tick_fn.note_execution(wall_s, bytes_hint=self.tick_bytes_estimate(
             spec_k=spec_k, live_blocks=live))
 
@@ -2018,15 +2307,20 @@ class ContinuousBatcher:
         # most rows x top-k of each layer's X (every slot routes,
         # live or not), over each position of a spec window.
         c = self.config
+        # (A held share gets its share of the assignments, so the same
+        # ratio holds for the experts held here.)
         idle_experts = (max(1.0 - self.num_slots * (1 + spec_k)
                             * c.num_experts_per_tok / c.num_experts,
                             0.0) if c.num_experts else 0.0)
         total = (self.param_bytes + live_bytes
                  - int(self._expert_param_bytes * idle_experts))
-        if self.state is not None:
+        if c.state_layers:
             # Every slot's state and conv tail, live or not, read and
             # written once a tick.
             total += 2 * self.state.nbytes
+        if self._ring is not None:
+            total += (self._window_blocks()[0] * self.block_size
+                      * self._ring.token_bytes())
         if spec_k:
             if self._draft_cache is not None:
                 dcfg = self.drafter.config
@@ -2125,6 +2419,16 @@ class ContinuousBatcher:
         tail = blocks[-1] if blocks else GARBAGE_BLOCK
         return blocks + [tail] * (self.max_blocks - len(blocks))
 
+    def _prefill_batches(self, groups):
+        """``_admit_waiting``'s groups, each cut into batches of at most
+        ``PREFILL_BATCH_TOKENS`` padded tokens a program call (a power
+        of two of rows, at least one): what bounds a prefill program's
+        temporaries whatever waits in the queue."""
+        for key, group in groups.items():
+            rows = max(_bucket_floor(PREFILL_BATCH_TOKENS // key[0]), 1)
+            for at in range(0, len(group), rows):
+                yield key, group[at:at + rows]
+
     def _admit(self) -> None:
         if self._import_reservations:
             # Stale import tickets (handoff never arrived) must not
@@ -2192,9 +2496,15 @@ class ContinuousBatcher:
                 break
             blocks = [nd.block for nd in matched] + got
             suffix = req["prompt"][m * bs:]
-            padded_len = min(_bucket(len(suffix)),
-                             padded_cap - m * bs)
-            padded_len = max(padded_len, bs)  # at least one block
+            chunk = self.prefill_chunk
+            if chunk and len(suffix) > chunk:
+                # A long prompt: whole chunks, the last one padded.
+                padded_len, n_chunks = chunk, -(-len(suffix) // chunk)
+            else:
+                padded_len = min(_bucket(len(suffix)),
+                                 padded_cap - m * bs)
+                padded_len = max(padded_len, bs)  # at least one block
+                n_chunks = 1
             if self._prefix is not None:
                 self.prefix_hit_tokens += m * bs
                 self.prefix_miss_tokens += len(suffix)
@@ -2211,9 +2521,10 @@ class ContinuousBatcher:
                 meta["prefix_tokens"] = m * bs
             slot = self._free.pop()
             self._slot_blocks[slot] = blocks
-            groups.setdefault((padded_len, m), []).append(
+            groups.setdefault((padded_len, m, n_chunks), []).append(
                 (req, slot, blocks, matched, suffix, chunks))
-        for (padded_len, m), group in groups.items():
+        for (padded_len, m, n_chunks), group in self._prefill_batches(
+                groups):
             n = len(group)
             # The batch dim buckets to a power of two as well, so the
             # compiled prefill program count stays log(N) x log(L).
@@ -2222,39 +2533,49 @@ class ContinuousBatcher:
             # is well-defined; the duplicate's first token is dropped.
             # (Duplicated prefix gathers are reads — trivially safe.)
             n_pad = min(_bucket(n, floor=1), self.num_slots)
-            tokens = np.zeros((n_pad, padded_len), np.int32)
-            last_idx = np.zeros(n_pad, np.int32)
             npb_w = padded_len // bs
-            tables_w = np.full((n_pad, npb_w), GARBAGE_BLOCK, np.int32)
-            ptables = np.full((n_pad, m), GARBAGE_BLOCK, np.int32)
-            for i in range(n_pad):
-                req, slot, blocks, matched, suffix, chunks = \
-                    group[min(i, n - 1)]
-                tokens[i, :len(suffix)] = suffix
-                last_idx[i] = len(suffix) - 1
-                # Suffix K/V land in the slot's NEW blocks (the
-                # matched prefix is read-only); bucket-padding
-                # overflow past the reservation writes masked
-                # garbage to block 0.
-                new_blocks = blocks[m:]
-                k = min(len(new_blocks), npb_w)
-                tables_w[i, :k] = new_blocks[:k]
-                ptables[i, :m] = blocks[:m]
+            rows = [group[min(i, n - 1)] for i in range(n_pad)]
             pt0 = time.time()  # wall-clock anchor for the prefill span
             with tracing.phase("engine.prefill", mdefs.CB_PREFILL_MS,
                                self._mtags, outer=admit) as prefill:
                 with _annotation("engine.prefill.dispatch"):
-                    pstep = self._place(np.int32(self._prefill_count))
-                    self._prefill_count += 1
                     slots = (None if self.state is None else self._place(
-                        np.asarray([group[min(i, n - 1)][1]
-                                    for i in range(n_pad)], np.int32)))
-                    first, caches = self._prefill(
-                        self.params, self._place(tokens), self._caches(),
-                        self._place(ptables), self._place(tables_w),
-                        self._place(last_idx), pstep, slots)
-                    self.cache, self.state = _split_caches(caches)
-                # The program queues behind the tick in flight, whose
+                        np.asarray([row[1] for row in rows], np.int32)))
+                    firsts = []
+                    # One program call a chunk (a prompt within the chunk
+                    # length is one), back to back: chunk i reads what
+                    # the chunks before it wrote through the arena.
+                    for ci in range(n_chunks):
+                        mc = m + ci * npb_w
+                        tokens = np.zeros((n_pad, padded_len), np.int32)
+                        last_idx = np.zeros(n_pad, np.int32)
+                        tables_w = np.full((n_pad, npb_w), GARBAGE_BLOCK,
+                                           np.int32)
+                        ptables = np.full((n_pad, mc), GARBAGE_BLOCK,
+                                          np.int32)
+                        for i, (_, _, blocks, _, suffix, _) in enumerate(rows):
+                            part = suffix[ci * padded_len:
+                                          (ci + 1) * padded_len]
+                            tokens[i, :len(part)] = part
+                            last_idx[i] = len(part) - 1
+                            # Suffix K/V land in the slot's NEW blocks
+                            # (the matched prefix is read-only);
+                            # bucket-padding overflow past the
+                            # reservation writes masked garbage to block 0.
+                            new_blocks = blocks[mc:]
+                            k = min(len(new_blocks), npb_w)
+                            tables_w[i, :k] = new_blocks[:k]
+                            ptables[i, :mc] = blocks[:mc]
+                        pstep = self._place(np.int32(self._prefill_count))
+                        self._prefill_count += 1
+                        first, caches = self._prefill(
+                            self.params, self._place(tokens),
+                            self._caches(), self._place(ptables),
+                            self._place(tables_w), self._place(last_idx),
+                            pstep, slots)
+                        self.cache, self.state = _split_caches(caches)
+                        firsts.append(first)
+                # The programs queue behind the tick in flight, whose
                 # row reaches the host first: land it here, on its own
                 # clock. The device starts the prefill at that moment,
                 # so the prefill's clock does too.
@@ -2264,7 +2585,18 @@ class ContinuousBatcher:
                 behind = prefill.elapsed_ms() if queued else 0.0
                 prefill.exclude(behind)
                 with _annotation("engine.prefill.fetch"):
-                    first = np.asarray(first)    # N ints, one transfer
+                    # Only the last chunk's N ints are first tokens and
+                    # fetched; the chunks before it are waited for, not
+                    # fetched. Each is ready when its program ends, so
+                    # the time between two is a chunk's device time.
+                    for first in firsts:
+                        with tracing.phase("engine.prefill.chunk",
+                                           mdefs.CB_PREFILL_CHUNK_MS,
+                                           self._mtags):
+                            if first is firsts[-1]:
+                                first = np.asarray(first)
+                            else:
+                                first.block_until_ready()
             # The fetch syncs the dispatch, so this interval is the real
             # prefill cost — bench_serve derives prefill tokens/s from
             # it without decode/queueing time polluting the denominator,
@@ -2274,15 +2606,15 @@ class ContinuousBatcher:
             self.prefill_seconds += prefill_wall
             with tracing.phase("engine.account", mdefs.CB_STEP_ACCOUNT_MS,
                                self._mtags, outer=admit):
-                self._prefill.note_execution(prefill_wall)
+                self._prefill.note_execution(prefill_wall / n_chunks)
             self._prefill_shapes.add((n_pad, padded_len))
-            true_tokens = int(last_idx[:n].sum()) + n
+            true_tokens = sum(len(row[4]) for row in group)
             self.prefill_batches += 1
             self.prefill_requests += n
             self.prefill_tokens += true_tokens
             mdefs.CB_PREFILL_REQUESTS.inc(n, tags=self._mtags)
             mdefs.CB_PREFILL_TOKENS.inc(true_tokens, tags=self._mtags)
-            if self.state is not None:
+            if self.config.state_layers:
                 self.state_installs += n
                 mdefs.CB_STATE_INSTALLS.inc(n, tags=self._mtags)
             first_ts = time.time()  # the fetch above synced the device
@@ -2310,7 +2642,7 @@ class ContinuousBatcher:
                     "rid": req["rid"], "out": [tok],
                     "max_new": req["max_new"],
                     "pos": len(req["prompt"]),   # next decode writes here
-                    "last": tok,
+                    "last": tok, "routes": req["routes"],
                     # Reserved-but-speculative block head-room, reported
                     # by pressure_snapshot (router congestion must see
                     # it as occupied, not free).
@@ -2365,6 +2697,8 @@ class ContinuousBatcher:
             self.eos_token is not None and st["out"][-1] == self.eos_token)
         if done:
             self._finished[st["rid"]] = st["out"]
+            if st["routes"] is not None:
+                self._routes[st["rid"]] = st["routes"]
             del self._slots[slot]
             self._release_slot(slot)
             self._finish_request(st["rid"], "finished",
@@ -2534,11 +2868,16 @@ class ContinuousBatcher:
                     toks, counts = row   # spec tick: ([B, k+1], [B]) committed
                 else:
                     toks, counts = row, None
+                routes = None
                 for slot, rid in membership:
                     st = self._slots.get(slot)
                     if st is None or st["rid"] != rid:
                         continue  # finished earlier in this batch: skip tail
                     n = 1 if counts is None else int(counts[slot])
+                    if st["routes"] is not None and counts is None:
+                        if routes is None:
+                            routes = self._split_row(row)[1]
+                        st["routes"].append(routes[:, slot].tolist())
                     if counts is not None:
                         drafted += toks.shape[1] - 1
                         accepted += n - 1
@@ -2623,6 +2962,29 @@ class ContinuousBatcher:
             self._spec_cur_k = self._spec_ladder_ks[idx + 1]
             self._spec_window.clear()
 
+    def _split_row(self, row):
+        """A plain tick row's routed part: (assignment counts ``[L, X]``
+        over the experts held here, each slot's chosen experts ``[L,
+        num_slots, k]`` over the router's whole width; None but for a
+        held share)."""
+        c = self.config
+        ids = self.num_slots * c.num_experts_per_tok if c.experts_held else 0
+        per_layer = row[self.num_slots:].reshape(-1, c.experts_here + ids)
+        if not ids:
+            return per_layer, None
+        return (per_layer[:, :c.experts_here],
+                per_layer[:, c.experts_here:].reshape(
+                    len(per_layer), self.num_slots, -1))
+
+    def take_routes(self, rid: int) -> Optional[List[List[List[int]]]]:
+        """The experts a finished request's DECODED positions routed to,
+        ``[position][routed layer][k]`` over the router's whole width,
+        if it was submitted with ``keep_routes``; else None. Position j
+        is the one generated token j was fed at (the tick that chose
+        token j + 1), so there is one entry fewer than tokens. Held
+        until taken."""
+        return self._routes.pop(rid, None)
+
     def _note_expert_rows(self, rows) -> None:
         """Feed the routed block's registry metrics from fetched plain
         tick rows: behind its ``num_slots`` tokens each carries the
@@ -2637,15 +2999,28 @@ class ContinuousBatcher:
         for row in rows:
             if isinstance(row, tuple):
                 continue
-            counts = row[self.num_slots:].reshape(-1, c.num_experts)
-            mdefs.CB_MOE_ASSIGNMENTS.inc(int(counts.sum()),
-                                         tags=self._mtags)
+            counts = self._split_row(row)[0]
+            if c.experts_held:
+                # Every slot routes top-k in every routed layer; the rows
+                # count what fell on the experts held here.
+                mdefs.CB_MOE_ASSIGNMENTS.inc(
+                    self.num_slots * c.num_experts_per_tok * len(counts),
+                    tags=self._mtags)
+                mdefs.CB_MOE_LOCAL_ASSIGNMENTS.inc(int(counts.sum()),
+                                                   tags=self._mtags)
+            else:
+                mdefs.CB_MOE_ASSIGNMENTS.inc(int(counts.sum()),
+                                             tags=self._mtags)
             mdefs.CB_MOE_TOUCHED_SHARE.observe(
                 float(np.count_nonzero(counts)) / counts.size,
                 tags=self._mtags)
-            mdefs.CB_MOE_LOAD_IMBALANCE.observe(
-                float(np.mean(counts.max(axis=1) / counts.mean(axis=1))),
-                tags=self._mtags)
+            # (A layer none of whose held experts got a row has no mean
+            # to be uneven about; without a held share there is none.)
+            busy = counts[counts.any(axis=1)]
+            if len(busy):
+                mdefs.CB_MOE_LOAD_IMBALANCE.observe(
+                    float(np.mean(busy.max(axis=1) / busy.mean(axis=1))),
+                    tags=self._mtags)
 
     def _emit_gauges(self) -> None:
         from ray_tpu._private import metrics_defs as mdefs
@@ -2662,9 +3037,13 @@ class ContinuousBatcher:
         if self._prefix is not None:
             mdefs.CB_KV_BLOCKS_CACHED.set(kv["cached"], tags=self._mtags)
             mdefs.CB_KV_BLOCKS_SHARED.set(kv["shared"], tags=self._mtags)
-        if self.state is not None:
+        if self.config.state_layers:
             mdefs.CB_STATE_CACHE_BYTES.set(self.state.nbytes,
                                            tags=self._mtags)
+        if self._ring is not None:
+            mdefs.CB_WINDOW_KV_BYTES.set(self._ring.nbytes, tags=self._mtags)
+            mdefs.CB_FULL_KV_BYTES.set(
+                self.cache.k.nbytes + self.cache.v.nbytes, tags=self._mtags)
         if self.spec_k:
             mdefs.CB_SPEC_ACCEPT_RATE.set(self.spec_accept_rate,
                                           tags=self._mtags)

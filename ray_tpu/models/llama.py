@@ -99,6 +99,34 @@ class LlamaConfig:
     # The head is the embedding: contracted against ``embed [V, E]`` in
     # place, no ``lm_head`` in the tree.
     tie_word_embeddings: bool = False
+    # The afmoe family (Trinity; SERVING ONLY like the hybrids above).
+    # ``layer_types`` may name "sliding_attention" (a query sees the last
+    # ``sliding_window`` keys, itself included; the engine keeps them in
+    # a per-slot ring, ``paged_kv.RingKVCache``) and "full_attention"
+    # (the growing arena; without positions when ``rope_full_attention``
+    # is off). Every field below defaults to the program without it.
+    sliding_window: int = 0
+    rope_full_attention: bool = True
+    # RMSNorm over each head's ``head_dim`` of q and k, weight ``[D]``.
+    qk_norm_per_head: bool = False
+    # ``a * sigmoid(h Wg)`` on attention's output, ``Wg`` shaped as ``wq``.
+    attn_gate: bool = False
+    # Four norms a layer: each sublayer's OUTPUT is normed as well
+    # (``post_attn_norm``, ``post_mlp_norm``) before it joins the residual.
+    sandwich_norms: bool = False
+    # The first layers' MLP is a dense SwiGLU of ``dense_intermediate_size``;
+    # the routed block starts after them.
+    num_dense_layers: int = 0
+    dense_intermediate_size: int = 0
+    # "softmax" (OLMoE, Granite) or "sigmoid": scores ``sigmoid(h Wr)``,
+    # the top k of ``score + expert_bias``, weights the chosen scores
+    # over their sum, times ``route_scale``.
+    router_score: str = "softmax"
+    route_scale: float = 1.0
+    # ``(first, count)``: of the router's ``num_experts`` this chip holds
+    # ``[first, first + count)`` and computes their part of the result;
+    # None = all of them.
+    experts_held: Optional[Tuple[int, int]] = None
 
     @property
     def state_layers(self) -> int:
@@ -106,9 +134,25 @@ class LlamaConfig:
         return sum(t == "mamba" for t in self.layer_types)
 
     @property
+    def window_layers(self) -> int:
+        """Layers that keep the last ``sliding_window`` keys only."""
+        return sum(t == "sliding_attention" for t in self.layer_types)
+
+    @property
     def attn_layers(self) -> int:
-        """Layers that keep K/V: what the arena holds."""
-        return self.num_layers - self.state_layers
+        """Layers that keep ALL their K/V: what the arena holds."""
+        return self.num_layers - self.state_layers - self.window_layers
+
+    @property
+    def moe_layers(self) -> int:
+        """Layers whose MLP is the routed block (0 for a dense model)."""
+        return (self.num_layers - self.num_dense_layers
+                if self.num_experts else 0)
+
+    @property
+    def experts_here(self) -> int:
+        """Experts whose weights the tree holds."""
+        return self.experts_held[1] if self.experts_held else self.num_experts
 
     @property
     def mamba_dims(self) -> Tuple[int, int]:
@@ -170,6 +214,28 @@ class LlamaConfig:
             tie_word_embeddings=True), **kw})
 
     @staticmethod
+    def trinity_large_preview(**kw) -> "LlamaConfig":
+        """arcee-ai/Trinity-Large-Preview (``afmoe``, 400B-A13B): 60
+        layers, (sliding x3, full) x15 with a window of 4096 and no
+        positions on the full layers; GQA 48/8 of 128 with per-head
+        QK-norm and a gated output; four norms a layer; 6 dense layers of
+        12288, then 256 experts of 3072, top 4 of a sigmoid score, beside
+        one shared expert; untied 200k vocabulary; muP embedding scale."""
+        pattern = ("sliding_attention",) * 3 + ("full_attention",)
+        return LlamaConfig(**{**dict(
+            vocab_size=200192, hidden_size=3072, intermediate_size=3072,
+            num_layers=60, num_heads=48, num_kv_heads=8, head_dim=128,
+            max_seq_len=262144, rope_theta=10000.0, rms_eps=1e-5,
+            layer_types=pattern * 15, sliding_window=4096,
+            rope_full_attention=False, qk_norm_per_head=True,
+            attn_gate=True, sandwich_norms=True, num_dense_layers=6,
+            dense_intermediate_size=12288, num_experts=256,
+            num_experts_per_tok=4, norm_topk_prob=True,
+            router_score="sigmoid", route_scale=2.448,
+            shared_intermediate_size=3072,
+            embedding_multiplier=3072 ** 0.5), **kw})
+
+    @staticmethod
     def tiny(**kw) -> "LlamaConfig":
         """CPU-runnable config for tests (BASELINE.md config #1 analog)."""
         kw.setdefault("vocab_size", 256)
@@ -221,7 +287,8 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     """Random init (normal / scaled), stacked over layers for lax.scan."""
     c = config
     if c.layer_types:
-        return _init_hybrid_params(c, key)
+        return (_init_hybrid_params(c, key) if c.state_layers
+                else _init_windowed_params(c, key))
     k_embed, k_head, k_layers = jax.random.split(key, 3)
 
     def norm_init(*shape):
@@ -358,6 +425,88 @@ def _init_hybrid_params(c: LlamaConfig, key: jax.Array) -> Params:
     }
 
 
+def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
+    """The afmoe family's tree, laid out as the hybrids' is: ``embed``,
+    ``lm_head``, ``final_norm``, ``layers`` = the stacked experts
+    ``[L_moe, held, ...]`` alone (read in place at the layer's index
+    among ROUTED layers: the leading dense layers have none), and
+    ``runs``: one stacked tree a run of equal layers
+    (:func:`layer_runs`: a run ends where the attention kind or the MLP
+    changes).
+
+    Seeded so that dropping a term shows: the four norms and the
+    per-head q/k norms are uniform in 0.5..1.5, not ones; ``expert_bias``
+    is normal at 0.01, the spacing of the TOP scores of 256 (which a
+    sigmoid packs under 1): without it half the tokens pick another
+    fourth expert, and with it the busiest expert gets about twice the
+    mean load, a trained router's unevenness (at 0.2, the spread of all
+    the scores, a dozen experts would take every token); ``wg`` is a
+    projection like ``wq``, so its sigmoid ranges over (0, 1)."""
+    E, M, X = c.hidden_size, c.intermediate_size, c.num_experts
+    H, KV, D = c.num_heads, c.num_kv_heads, c.head_dim
+    k_embed, k_head, k_experts, k_runs = jax.random.split(key, 4)
+
+    def dense(key, fan_in, *shape):
+        out = jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
+        return out.astype(c.dtype)
+
+    def norm(key, *shape):
+        return jax.random.uniform(key, shape, jnp.float32, 0.5,
+                                  1.5).astype(c.dtype)
+
+    runs = []
+    for r, (_, start, n, _) in enumerate(layer_runs(c)):
+        k = jax.random.split(jax.random.fold_in(k_runs, r), 20)
+        tree = {
+            "attn_norm": norm(k[0], n, E), "mlp_norm": norm(k[1], n, E),
+            "wq": dense(k[2], E, n, E, H, D),
+            "wk": dense(k[3], E, n, E, KV, D),
+            "wv": dense(k[4], E, n, E, KV, D),
+            "wo": dense(k[5], H * D, n, H, D, E),
+        }
+        if c.sandwich_norms:
+            tree["post_attn_norm"] = norm(k[6], n, E)
+            tree["post_mlp_norm"] = norm(k[7], n, E)
+        if c.qk_norm_per_head:
+            tree["q_norm"] = norm(k[8], n, D)
+            tree["k_norm"] = norm(k[9], n, D)
+        if c.attn_gate:
+            tree["wg"] = dense(k[10], E, n, E, H, D)
+        if start < c.num_dense_layers or not X:
+            Md = c.dense_intermediate_size or M
+            tree.update({"w_gate": dense(k[11], E, n, E, Md),
+                         "w_up": dense(k[12], E, n, E, Md),
+                         "w_down": dense(k[13], Md, n, Md, E)})
+        else:
+            Ms = c.shared_intermediate_size
+            tree["w_router"] = jax.random.normal(
+                k[14], (n, E, X), jnp.float32) * E ** -0.5
+            if c.router_score == "sigmoid":
+                tree["expert_bias"] = 0.01 * jax.random.normal(
+                    k[15], (n, X), jnp.float32)
+            if Ms:
+                tree.update({"shared_gate": dense(k[16], E, n, E, Ms),
+                             "shared_up": dense(k[17], E, n, E, Ms),
+                             "shared_down": dense(k[18], Ms, n, Ms, E)})
+        runs.append(tree)
+    out = {
+        # x0 = embed[t] * embedding_multiplier has unit entries.
+        "embed": (jax.random.normal(k_embed, (c.vocab_size, E), jnp.float32)
+                  / c.embedding_multiplier).astype(c.dtype),
+        "final_norm": norm(jax.random.fold_in(k_head, 1), E),
+        "lm_head": dense(k_head, E, E, c.vocab_size),
+        "layers": {},
+        "runs": runs,
+    }
+    if X:
+        Lm, Xh = c.moe_layers, c.experts_here
+        ke = jax.random.split(k_experts, 3)
+        out["layers"] = {"moe_gate": dense(ke[0], E, Lm, Xh, E, M),
+                         "moe_up": dense(ke[1], E, Lm, Xh, E, M),
+                         "moe_down": dense(ke[2], M, Lm, Xh, M, E)}
+    return out
+
+
 def truncated(config: LlamaConfig, params: Params,
               num_layers: int) -> Tuple[LlamaConfig, Params]:
     """First-``num_layers`` view of a model: (config, params) where the
@@ -408,11 +557,12 @@ def split_layers(params: Params, num_layers: Optional[int] = None):
 def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
                num_layers: Optional[int] = None):
     """The layer stack as RUNS of equal layers, in order: a list of
-    ``(kind, start, count, kind_start)`` where ``kind`` is "attention"
-    or "mamba", ``start`` the run's first GLOBAL layer (the stacked
-    experts' index) and ``kind_start`` its first index among layers of
-    its kind (the K/V arena's layer for attention, the state cache's for
-    mamba). A model without ``layer_types`` is one attention run.
+    ``(kind, start, count, kind_start)`` where ``kind`` is "attention",
+    "mamba", "sliding_attention" or "full_attention", ``start`` the
+    run's first GLOBAL layer and ``kind_start`` its first index among
+    layers that share its cache (the K/V arena's layer for attention and
+    full attention, the state cache's for mamba, the ring's for sliding
+    attention). A model without ``layer_types`` is one attention run.
 
     With ``params``: ``(runs, experts)``, each run followed by the tree
     a ``lax.scan`` over its layers takes (``params["runs"][i]``; for a
@@ -423,15 +573,20 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
     if len(types) != c.num_layers:
         raise ValueError(f"layer_types names {len(types)} layers, "
                          f"num_layers is {c.num_layers}")
-    runs, seen = [], {"attention": 0, "mamba": 0}
+    runs, seen = [], {"attention": 0, "mamba": 0, "sliding_attention": 0}
     for i, kind in enumerate(types):
-        if kind not in seen:
+        # "full_attention" keeps all its K/V in the arena, as "attention"
+        # does: they count as one kind of cache.
+        cache = "attention" if kind == "full_attention" else kind
+        if cache not in seen:
             raise ValueError(f"layer {i}: unknown layer type {kind!r}")
-        if runs and runs[-1][0] == kind:
+        # A run also ends where the MLP turns from dense to routed.
+        if (runs and runs[-1][0] == kind
+                and not (c.num_experts and i == c.num_dense_layers)):
             runs[-1][2] += 1
         else:
-            runs.append([kind, i, 1, seen[kind]])
-        seen[kind] += 1
+            runs.append([kind, i, 1, seen[cache]])
+        seen[cache] += 1
     runs = [tuple(r) for r in runs]
     if params is None:
         return runs
@@ -445,6 +600,9 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
 # Canonical name -> the name the same projection has in the engine's
 # heads-major tree (:func:`heads_major`).
 HEADS_MAJOR = {"wq": "wq_heads", "wk": "wk_heads", "wv": "wv_heads"}
+# ... and with the output gate of a model that has one (``attn_gate``),
+# a projection of ``wq``'s shape: every leaf the engine re-lays.
+_ENGINE_LAYOUT = {**HEADS_MAJOR, "wg": "wg_heads"}
 
 
 def swap_heads(leaves):
@@ -490,7 +648,7 @@ def heads_major(params: Params, relay=swap_heads) -> Params:
     ``[L, E, H, D]``. ``relay`` maps a list of projection leaves to
     their transposes; the engine passes one jitted program, so a tree
     already on the chip copies its three projections and nothing else."""
-    return _relaid(params, HEADS_MAJOR, relay)
+    return _relaid(params, _ENGINE_LAYOUT, relay)
 
 
 def canonical_layout(params: Params) -> Params:
@@ -498,8 +656,25 @@ def canonical_layout(params: Params) -> Params:
     :func:`init_params` lays it out (a canonical tree comes back as it
     is). What ``swap_params`` validates against, and what to hand to
     anything that reads ``wq`` by name."""
-    return _relaid(params, {v: k for k, v in HEADS_MAJOR.items()},
+    return _relaid(params, {v: k for k, v in _ENGINE_LAYOUT.items()},
                    swap_heads)
+
+
+def _project_heads(h, layer, c: LlamaConfig, name: str):
+    """``h [B, S, E]`` through the layer's projection ``name`` in
+    whichever layout the tree holds it: ``[B, S, H, D]``."""
+    if _ENGINE_LAYOUT[name] in layer:
+        return jnp.einsum("bse,hed->bshd", h,
+                          layer[_ENGINE_LAYOUT[name]].astype(c.dtype))
+    return jnp.einsum("bse,ehd->bshd", h, layer[name].astype(c.dtype))
+
+
+def attn_gate(h, layer, c: LlamaConfig):
+    """``sigmoid(h Wg) [B, S, H, D]``, which multiplies attention's
+    output before ``wo`` (``attn_gate`` configs); None otherwise."""
+    if not c.attn_gate:
+        return None
+    return jax.nn.sigmoid(_project_heads(h, layer, c, "wg"))
 
 
 def project_qkv(h, layer, c: LlamaConfig):
@@ -514,13 +689,11 @@ def project_qkv(h, layer, c: LlamaConfig):
     same products summed over the same ``E``; no flag selects it, so
     ``forward`` on an engine's tree (``score_logprobs``), the self-draft,
     verify and prefill all follow."""
-    def project(name):
-        if HEADS_MAJOR[name] in layer:
-            return jnp.einsum("bse,hed->bshd", h,
-                              layer[HEADS_MAJOR[name]].astype(c.dtype))
-        return jnp.einsum("bse,ehd->bshd", h, layer[name].astype(c.dtype))
-
+    project = functools.partial(_project_heads, h, layer, c)
     q, k, v = project("wq"), project("wk"), project("wv")
+    if c.qk_norm_per_head:
+        q = rms_norm(q, layer["q_norm"], c.rms_eps)
+        k = rms_norm(k, layer["k_norm"], c.rms_eps)
     if c.qk_norm:
         def whole(x, w):
             flat = x.reshape(*x.shape[:2], -1)
@@ -548,15 +721,26 @@ def mlp_block(h, layer, c: LlamaConfig, experts=None, li=None,
     experts, the dropless routed block over ``experts`` (the stacked
     ``[L, X, ...]`` tree of :func:`split_layers`) read at layer ``li``;
     ``routed`` is its :class:`~ray_tpu.ops.moe.Routed`: per-expert
-    assignment counts ``[X]`` and each token's experts ``[B * S, k]``."""
-    if c.num_experts:
+    assignment counts ``[X]`` and each token's experts ``[B * S, k]``.
+    A layer whose tree has no router (the leading dense layers of a
+    config with ``num_dense_layers``) takes the dense branch."""
+    if c.num_experts and "w_router" in layer:
         from ray_tpu.ops import moe
 
         b, s, e = h.shape
+        extra = {}
+        if c.router_score == "sigmoid":
+            extra["route"] = functools.partial(
+                moe.route_sigmoid_topk, bias=layer["expert_bias"],
+                scale=c.route_scale)
+        if c.experts_held:
+            extra["held"] = c.experts_held
+        if c.num_dense_layers:
+            li = li - c.num_dense_layers    # its index among routed layers
         out, routed = moe.routed_block(
             h.reshape(b * s, e), layer["w_router"], experts, li,
             top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob,
-            use_kernel=use_kernel)
+            use_kernel=use_kernel, **extra)
         out = out.reshape(b, s, e)
         if c.shared_intermediate_size:
             # GraniteMoeHybridDecoderLayer.forward: moe(h) + shared_mlp(h),
@@ -638,9 +822,9 @@ def forward(
     c = config
     if c.layer_types:
         raise NotImplementedError(
-            "a config with layer_types (state-space layers) is served by "
-            "the continuous-batching engine only: llama.forward, loss_fn "
-            "and LlamaGenerator do not run it")
+            "a config with layer_types (state-space or sliding-window "
+            "layers) is served by the continuous-batching engine only: "
+            "llama.forward, loss_fn and LlamaGenerator do not run it")
     seq_len = tokens.shape[1]
     cos, sin = rope_frequencies(c.head_dim, seq_len, c.rope_theta)
 
@@ -822,6 +1006,10 @@ def loss_fn(
 
 def num_params(config: LlamaConfig) -> int:
     c = config
+    if c.layer_types and not c.state_layers:
+        shapes = jax.eval_shape(functools.partial(init_params, c),
+                                jax.random.PRNGKey(0))
+        return sum(a.size for a in jax.tree_util.tree_leaves(shapes))
     if c.layer_types:
         inner, conv_dim = c.mamba_dims
         common = (2 * c.hidden_size + c.hidden_size * c.num_experts

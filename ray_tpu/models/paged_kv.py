@@ -204,6 +204,58 @@ class StateCache(NamedTuple):
         return int(self.ssm.nbytes + self.conv.nbytes)
 
 
+def ring_blocks(window: int, block_size: int) -> int:
+    """Entries of a slot's ring for a window of ``window`` keys: the
+    ``window // bs + 1`` blocks a query's keys can span, and one more,
+    so that the block a prefill chunk or a tick writes next never holds
+    a key some query of the same program still sees."""
+    return -(-window // block_size) + 2
+
+
+class RingKVCache(NamedTuple):
+    """What the SLIDING-WINDOW attention layers keep, beside the arena:
+    k/v ``[L_win, 1 + slots * ring, KVH, bs, D]``, the arena's block
+    layout (so ``paged_kv_write`` and ``paged_decode_attn`` take it as
+    they take the arena), but every slot owns a fixed RING of ``ring``
+    blocks and logical block ``b`` of its context lives in entry ``b %
+    ring``: a block is overwritten once every key in it is more than
+    ``sliding_window`` positions behind the slot's query, so a slot's
+    window layers hold ``ring x bs`` tokens whatever its context
+    (:func:`ring_blocks`). Block 0 is the garbage block, as in the
+    arena. No allocator and no sharing: slot ``s`` owns blocks ``1 + s *
+    ring ...``, from construction; nothing in it outlives its request."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+
+    @classmethod
+    def create(cls, config: llama.LlamaConfig, num_slots: int,
+               block_size: int) -> "RingKVCache":
+        ring = ring_blocks(config.sliding_window, block_size)
+        shape = (config.window_layers, 1 + num_slots * ring,
+                 config.num_kv_heads, block_size, config.head_dim)
+        return cls(k=jnp.zeros(shape, config.dtype),
+                   v=jnp.zeros(shape, config.dtype))
+
+    @staticmethod
+    def tables(slots, ring: int):
+        """Each of ``slots``' ring as a block table ``[len(slots), ring]``
+        (entry r = the block that holds logical blocks ``b % ring == r``):
+        a function of the slot alone, so programs make it from an iota
+        and the host uploads nothing."""
+        return (1 + slots.astype(jnp.int32)[:, None] * ring
+                + jnp.arange(ring, dtype=jnp.int32)[None, :])
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.k.nbytes + self.v.nbytes)
+
+    def token_bytes(self) -> int:
+        """Bytes one token in the window occupies across the window layers."""
+        layers, _, kvh, _, d = self.k.shape
+        return 2 * layers * kvh * d * jnp.dtype(self.k.dtype).itemsize
+
+
 class BlockAllocator:
     """Host-side free-list over arena block ids. Block 0 (GARBAGE_BLOCK)
     is never handed out: freed slots keep scattering their masked-lane

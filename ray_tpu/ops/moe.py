@@ -172,6 +172,27 @@ def route_softmax_topk(h: jnp.ndarray, w_router: jnp.ndarray, k: int,
     return weights, idx.astype(jnp.int32)
 
 
+def route_sigmoid_topk(h: jnp.ndarray, w_router: jnp.ndarray, k: int,
+                       renormalise: bool = True, *, bias: jnp.ndarray,
+                       scale: float = 1.0
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The afmoe router: scores ``sigmoid(h Wr)`` over ALL experts in
+    float32; the top ``k`` of ``score + bias`` (``bias [X]`` steers the
+    SELECTION only: the load balancer's handle); weights the chosen
+    experts' own scores, over their sum when ``renormalise`` (the
+    published ``route_norm``), times ``scale`` (``route_scale``).
+    Returns (weights [T, k] float32, idx [T, k] int32)."""
+    logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    if renormalise:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + 1e-20)
+    return weights * scale, idx.astype(jnp.int32)
+
+
 def _gmm_tiles(m: int, k: int, n: int, num_groups: int, itemsize: int):
     """(tm, tn). A group that straddles a row tile is visited, and its
     weights read, once per tile, so tiles are tall where groups are
@@ -308,14 +329,16 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
 
 class Routed(NamedTuple):
     """What :func:`routed_block` did besides its output."""
-    rows: jnp.ndarray      # [X] int32: assignments each expert computed
+    rows: jnp.ndarray      # [X] int32: assignments each HELD expert computed
     experts: jnp.ndarray   # [T, k] int32: each token's experts, best first
 
 
 def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
                  experts: Dict[str, jnp.ndarray], layer=None, *,
                  top_k: int, norm_topk: bool = False,
-                 use_kernel: Optional[bool] = None
+                 use_kernel: Optional[bool] = None,
+                 route=route_softmax_topk,
+                 held: Optional[Tuple[int, int]] = None
                  ) -> Tuple[jnp.ndarray, Routed]:
     """The dropless SwiGLU expert block on normed tokens x [T, E]:
     ``sum_e p_e * down_e(silu(gate_e x) * up_e x)`` over each token's
@@ -323,6 +346,18 @@ def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
     and ``moe_down`` [L, X, M, E] read at ``layer``, or one layer's
     ``[X, ...]`` with ``layer`` None. No capacity: every assignment is
     computed, and a token's result does not depend on the other rows.
+    ``route(x, w_router, top_k, norm_topk)`` is the router.
+
+    ``held = (first, count)``: of the router's X experts, ``experts``
+    holds ``[first, first + count)`` (``[L, count, ...]``): the chip's
+    share under expert parallelism. The router still scores all X; only
+    assignments to a held expert are computed (they sort ahead of the
+    rest, and the grouped multiplication's schedule is made from the
+    held groups' sizes, so an absent assignment costs no visit), and the
+    others add nothing: their rows are never written, so they are
+    selected away, not multiplied by zero. ``rows`` then counts the held
+    experts' ``[count]``. What the absent experts would add is some
+    other chip's part of the sum.
     Returns (out [T, E], :class:`Routed`)."""
     t, _ = x.shape
     num_experts = w_router.shape[-1]
@@ -330,11 +365,20 @@ def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
                             use_kernel=use_kernel)
     with jax.named_scope("moe"):
         with jax.named_scope("route"):
-            weights, idx = route_softmax_topk(x, w_router, top_k, norm_topk)
+            weights, idx = route(x, w_router, top_k, norm_topk)
         with jax.named_scope("sort"):
             flat = idx.reshape(-1)                       # [T * k]
+            if held is not None:
+                first, num_experts = held
+                here = (flat >= first) & (flat < first + num_experts)
+                # Absent assignments sort behind every held group.
+                flat = jnp.where(here, flat - first, num_experts)
+                here = here.reshape(t, top_k)
             order = jnp.argsort(flat, stable=True)       # sorted -> flat
-            rows = jnp.zeros(num_experts, jnp.int32).at[flat].add(1)
+            rows = jnp.zeros(num_experts + (held is not None),
+                             jnp.int32).at[flat].add(1)
+            if held is not None:
+                rows = rows[:num_experts]
             xs = x[order // top_k]                       # [T * k, E]
         with jax.named_scope("experts"):
             act = (jax.nn.silu(gmm(xs, experts["moe_gate"], rows))
@@ -346,7 +390,8 @@ def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
             # else is in the batch.
             place = jnp.zeros_like(order).at[order].set(
                 jnp.arange(order.shape[0], dtype=order.dtype))
-            out = jnp.einsum("tk,tke->te", weights,
-                             ys[place].reshape(t, top_k, -1).astype(
-                                 jnp.float32))
+            mine = ys[place].reshape(t, top_k, -1).astype(jnp.float32)
+            if held is not None:
+                mine = jnp.where(here[..., None], mine, 0.0)
+            out = jnp.einsum("tk,tke->te", weights, mine)
     return out.astype(x.dtype), Routed(rows, idx)

@@ -81,14 +81,18 @@ MASK_VALUE = -1e30
 
 
 def decode_attention_reference(q, cache_k, cache_v, positions,
-                               scale: Optional[float] = None):
+                               scale: Optional[float] = None, *,
+                               key_positions=None, window: int = 0):
     """Single-token attention with per-slot positions over a dense
     per-slot context: what :func:`paged_attention_reference` attends
     once it has gathered a slot's blocks, and what the external drafter
     attends over its private cache.
 
     q [B, H, D]; cache [B, S_max, KVH, D]; positions [B] (the absolute
-    position each slot's query occupies).
+    position each slot's query occupies). ``key_positions`` [B, S_max]:
+    the absolute position of each cache entry where that is not its
+    index (a ring's; negative = holds nothing); with ``window`` a query
+    sees only the last ``window`` keys, itself included.
     """
     b, hq, d = q.shape
     s_max, hkv = cache_k.shape[1], cache_k.shape[2]
@@ -97,8 +101,13 @@ def decode_attention_reference(q, cache_k, cache_v, positions,
     qg = q.reshape(b, hkv, group, d).astype(jnp.float32)
     logits = jnp.einsum("bhgd,bkhd->bhgk", qg,
                         cache_k.astype(jnp.float32)) * scale
-    slots = jnp.arange(s_max)
-    mask = positions[:, None] >= slots[None, :]             # [B, S_max]
+    slots = (jnp.arange(s_max)[None, :] if key_positions is None
+             else key_positions)
+    mask = positions[:, None] >= slots                      # [B, S_max]
+    if key_positions is not None:
+        mask &= slots >= 0
+    if window:
+        mask &= positions[:, None] - slots < window
     logits = jnp.where(mask[:, None, None, :], logits, MASK_VALUE)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgk,bkhd->bhgd", probs,
@@ -130,16 +139,33 @@ def _layer_slab(a, layer):
     return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
 
 
+def ring_key_positions(positions, ring: int, block_size: int):
+    """The absolute position each entry of a slot's ring holds when the
+    slot's query sits at ``positions`` [B]: ``[B, ring * bs]``. Logical
+    block ``b`` lives in ring entry ``b % ring``, so entry ``r`` holds
+    the newest block ``b <= pos // bs`` with ``b % ring == r`` (negative:
+    not written yet)."""
+    last = positions.astype(jnp.int32)[:, None] // block_size    # [B, 1]
+    r = jnp.arange(ring, dtype=jnp.int32)[None, :]
+    block = last - (last - r) % ring                             # [B, ring]
+    return (block[:, :, None] * block_size
+            + jnp.arange(block_size, dtype=jnp.int32)).reshape(
+                positions.shape[0], ring * block_size)
+
+
 def paged_attention_reference(q, arena_k, arena_v, tables, positions,
                               scale: Optional[float] = None, *,
-                              layer=None, k_scale=None, v_scale=None):
+                              layer=None, k_scale=None, v_scale=None,
+                              window: int = 0):
     """XLA reference: gather blocks into dense layout, dequantize when the
     arena is quantized, then run the positional-mask softmax attention.
 
     q [B, Hq, D]; arena [NB, KVH, bs, D], or the whole [L, NB, KVH, bs,
     D] with ``layer``; tables [B, nb] (row j = slot's j-th logical
     block; dead entries may repeat blocks — masked out by
-    ``positions``); positions [B].
+    ``positions``); positions [B]. With ``window`` the table is a RING
+    (logical block ``b`` in entry ``b % nb``) and a query sees the last
+    ``window`` keys.
     """
     arena_k, arena_v, k_scale, v_scale = (
         _layer_slab(a, layer) for a in (arena_k, arena_v, k_scale, v_scale))
@@ -148,8 +174,11 @@ def paged_attention_reference(q, arena_k, arena_v, tables, positions,
     if k_scale is not None:
         ck = dequantize_block(ck, gather_kv(k_scale, tables))
         cv = dequantize_block(cv, gather_kv(v_scale, tables))
-    return decode_attention_reference(q, ck, cv, positions,
-                                      scale).astype(q.dtype)
+    ring = ({"key_positions": ring_key_positions(
+        positions, tables.shape[1], arena_k.shape[2]), "window": window}
+        if window else {})
+    return decode_attention_reference(q, ck, cv, positions, scale,
+                                      **ring).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -163,7 +192,7 @@ def _init_state(acc_ref, m_ref, l_ref):
 
 
 def _attend_block(q, k, v, pos, first_col, acc_ref, m_ref, l_ref, *, scale,
-                  k_scale=None, v_scale=None):
+                  k_scale=None, v_scale=None, window: int = 0):
     """One online-softmax step over a K/V block, all kv heads at once.
 
     q [KVH, G, D]; k/v [KVH, T, D] in storage dtype (upcast here);
@@ -179,7 +208,10 @@ def _attend_block(q, k, v, pos, first_col, acc_ref, m_ref, l_ref, *, scale,
     if k_scale is not None:
         s = s * k_scale[:, None, :]
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(pos >= first_col + cols, s, MASK_VALUE)
+    seen = pos >= first_col + cols
+    if window:      # the lower bound: inside the FIRST live block only
+        seen &= pos - (first_col + cols) < window
+    s = jnp.where(seen, s, MASK_VALUE)
 
     m_prev = m_ref[:, :, :1]                                 # [KVH, G, 1]
     l_prev = l_ref[:, :, :1]
@@ -216,7 +248,14 @@ def _layer_operand(layer):
     return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
-def paged_visits(tables, positions, limits=None, *, block_size: int):
+def _first_live(pos, window: int, block_size: int):
+    """The first logical block that holds a key a query at ``pos`` may
+    see: 0 without a window."""
+    return jnp.maximum(pos - (window - 1), 0) // block_size if window else 0
+
+
+def paged_visits(tables, positions, limits=None, *, block_size: int,
+                 window: int = 0):
     """The kernel's schedule: one VISIT per (slot, logical block) that
     holds a key the slot's query may see, slot-major, and nothing else.
     A slot's query sits at absolute position ``pos``, so its blocks
@@ -225,13 +264,23 @@ def paged_visits(tables, positions, limits=None, *, block_size: int):
     Returns (slot of visit, logical block of visit, both ``[B * nb]``
     and valid past the end, visits ``[1]``).
 
+    With ``window`` the table is a slot's RING of ``nb`` entries and a
+    query sees the last ``window`` keys: its live blocks are ``[(pos -
+    window + 1) // bs, pos // bs]``, LOGICAL blocks that the kernel maps
+    to ring entries itself (``block % nb``); they number at most
+    ``window // bs + 1``, which the ring must exceed.
+
     A dozen small XLA ops, which the compiler leaves inside a layer
     loop although nothing in them depends on the layer: a caller with
     such a loop makes the schedule once, before it, and hands it to
     :func:`paged_decode_attention` as ``visits``."""
     b, nb = tables.shape
     positions = positions.astype(jnp.int32)
-    n_live = jnp.minimum(positions // block_size + 1, nb)
+    first = _first_live(positions, window, block_size)
+    if window:
+        n_live = positions // block_size + 1 - first
+    else:
+        n_live = jnp.minimum(positions // block_size + 1, nb)
     if limits is not None:
         n_live = jnp.where(limits > 0, n_live, 0)
     ends = jnp.cumsum(n_live)
@@ -240,14 +289,17 @@ def paged_visits(tables, positions, limits=None, *, block_size: int):
     before = ends[None, :] <= v[:, None]                     # [V, B]
     slot = jnp.minimum(jnp.sum(before, axis=1), b - 1)
     start = jnp.sum(jnp.where(before, n_live[None, :], 0), axis=1)
-    block = jnp.clip(v - start, 0, nb - 1)
+    if window:
+        block = first[slot] + jnp.maximum(v - start, 0)
+    else:
+        block = jnp.clip(v - start, 0, nb - 1)
     return (slot.astype(jnp.int32), block.astype(jnp.int32),
             ends[-1:].astype(jnp.int32))
 
 
 def _paged_kernel(layer_ref, tables_ref, pos_ref, slot_ref, block_ref,
                   q_ref, k_ref, v_ref, *rest, scale, block_size, num_blocks,
-                  quantized):
+                  quantized, window=0):
     if quantized:
         ks_ref, vs_ref, _, o_ref, acc_ref, m_ref, l_ref = rest
     else:
@@ -256,22 +308,25 @@ def _paged_kernel(layer_ref, tables_ref, pos_ref, slot_ref, block_ref,
     pos = pos_ref[slot_ref[visit]]
     j = block_ref[visit]
 
-    @pl.when(j == 0)
+    @pl.when(j == _first_live(pos, window, block_size))
     def _init():
         _init_state(acc_ref, m_ref, l_ref)
 
     _attend_block(q_ref[0], k_ref[0, 0], v_ref[0, 0], pos, j * block_size,
                   acc_ref, m_ref, l_ref, scale=scale,
                   k_scale=ks_ref[0, 0] if quantized else None,
-                  v_scale=vs_ref[0, 0] if quantized else None)
+                  v_scale=vs_ref[0, 0] if quantized else None,
+                  window=window)
 
-    @pl.when(j == jnp.minimum(pos // block_size, num_blocks - 1))
+    # A ring's logical blocks run past its width; a table's do not.
+    last = pos // block_size
+    @pl.when(j == (last if window else jnp.minimum(last, num_blocks - 1)))
     def _fin():
         _finalize(o_ref, acc_ref, l_ref)
 
 
 def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
-                 k_scale, v_scale, scale, interpret):
+                 k_scale, v_scale, scale, interpret, window=0):
     b, hq, d = q.shape
     _, _, hkv, block_size, _ = arena_k.shape
     nb = tables.shape[1]
@@ -286,15 +341,19 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
     # The table gather IS the index_map: the scalar-prefetched layer,
     # schedule and block tables choose which arena block each visit
     # streams into VMEM.
+    # (A ring's entry for logical block b is b % nb.)
+    entry = (lambda bl, v: bl[v] % nb) if window else (lambda bl, v: bl[v])
     kv_spec = pl.BlockSpec(
         (1, 1, hkv, block_size, d),
-        lambda v, ly, tab, po, sl, bl: (ly[0], tab[sl[v], bl[v]], 0, 0, 0))
+        lambda v, ly, tab, po, sl, bl: (
+            ly[0], tab[sl[v], entry(bl, v)], 0, 0, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     inputs = [qg, arena_k, arena_v]
     if quantized:
         sc_spec = pl.BlockSpec(
             (1, 1, hkv, block_size),
-            lambda v, ly, tab, po, sl, bl: (ly[0], tab[sl[v], bl[v]], 0, 0))
+            lambda v, ly, tab, po, sl, bl: (
+                ly[0], tab[sl[v], entry(bl, v)], 0, 0))
         in_specs += [sc_spec, sc_spec]
         inputs += [k_scale, v_scale]
     # The output starts as zeros and only visited slots are written, so
@@ -310,7 +369,7 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
     )
     kernel = functools.partial(
         _paged_kernel, scale=scale, block_size=block_size, num_blocks=nb,
-        quantized=quantized)
+        quantized=quantized, window=window)
     itemsize = jnp.dtype(arena_k.dtype).itemsize
     kv_bytes = 2 * b * nb * hkv * block_size * d * itemsize
     if quantized:
@@ -440,6 +499,7 @@ def paged_decode_attention(
     v_scale: Optional[jnp.ndarray] = None,
     use_kernel: Optional[bool] = None,
     interpret: Optional[bool] = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Decode-step attention over a paged KV arena.
 
@@ -458,6 +518,13 @@ def paged_decode_attention(
     :func:`paged_visits` on the same tables, positions and limits, for
     a caller that makes it once for many layers; ``limits`` is then not
     read.
+
+    ``window``: ``tables`` [B, nb] is each slot's RING (logical block
+    ``b`` in entry ``b % nb``; ``nb > window // bs + 1``) and a query
+    sees its last ``window`` keys, itself included: the kernel visits
+    the blocks that hold one and masks inside the first of them.
+    ``visits`` must then come from :func:`paged_visits` with the same
+    ``window``.
 
     ``use_kernel``: None = auto (fused kernel on TPU when the shapes
     tile, XLA reference elsewhere); True forces the kernel (interpret
@@ -478,7 +545,8 @@ def paged_decode_attention(
     if not use_kernel:
         return paged_attention_reference(q, arena_k, arena_v, tables,
                                          positions, scale, layer=layer,
-                                         k_scale=k_scale, v_scale=v_scale)
+                                         k_scale=k_scale, v_scale=v_scale,
+                                         window=window)
     if interpret is None:
         interpret = interpret_default()
     if layer is None:
@@ -489,7 +557,7 @@ def paged_decode_attention(
             k_scale, v_scale = k_scale[None], v_scale[None]
     if visits is None:
         visits = paged_visits(tables, positions, limits,
-                              block_size=block_size)
+                              block_size=block_size, window=window)
     return _paged_fused(q, arena_k, arena_v, tables, positions, visits,
                         layer=layer, k_scale=k_scale, v_scale=v_scale,
-                        scale=scale, interpret=interpret)
+                        scale=scale, interpret=interpret, window=window)

@@ -511,3 +511,94 @@ def test_compiled_hybrid_tick_holds_no_copy_of_the_state_cache(v5e_chip):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 5 * 48 * 128 * 64 * 128 * 4
     assert memory.temp_size_in_bytes < 8 << 20     # the tails are 12 MB
+
+
+def test_compiled_window_tick_moves_no_ring_slab_and_no_expert_weight(
+        v5e_chip):
+    """serve_window_decode's tick (Trinity's published widths; the cell's
+    1 dense + 4 routed layers holding 32 of 256 experts, 3 sliding : 1
+    full attention, 48 slots, max_len 7168): the four sliding-window
+    layers read and write their RING ``[4, 1 + 48 x 66, 8, 64, 128]``
+    (3.32 GB) and the full-attention layer the arena ``[1, 1 + 48 x 112,
+    ...]`` through the two paged kernels alone, both aliased in to out:
+    no other instruction has a ring's, the arena's or one layer's slab's
+    shape (PR 24's guard, for the new storage). The held experts ``[4,
+    32, 3072, 3072]`` are read in place by ``moe_gmm`` (PR 25's guard):
+    three calls a routed run, three runs. Four runs of attention layers
+    (the MLP changes after layer 0, the kind at layers 3 and 4): one
+    ``paged_decode_attn`` and two ``paged_kv_write`` call sites each."""
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models.paged_kv import PagedKVCache, RingKVCache
+
+    slots, table = 48, 7168 // 64
+    cfg = llama.LlamaConfig.trinity_large_preview(
+        num_layers=5, num_dense_layers=1, experts_held=(0, 32),
+        layer_types=("sliding_attention",) * 3 + ("full_attention",
+                                                  "sliding_attention"),
+        vocab_size=25024, max_seq_len=7168)
+
+    def spec(a):
+        return S(a.shape, a.dtype, sharding=v5e_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        functools.partial(cb.init_engine_params, cfg),
+        jax.random.PRNGKey(0)))
+    cache = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        PagedKVCache.create, cfg, slots * table + 1, 64)))
+    ring = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        RingKVCache.create, cfg, slots, 64)))
+    assert ring.k.shape == (4, 1 + slots * 66, 8, 64, 128)
+    assert cache.k.shape == (1, 1 + slots * table, 8, 64, 128)
+    assert params["layers"]["moe_gate"].shape == (4, 32, 3072, 3072)
+    row = S((slots,), jnp.int32, sharding=v5e_chip)
+    tables = S((slots, table), jnp.int32, sharding=v5e_chip)
+    step = S((), jnp.int32, sharding=v5e_chip)
+    tick = functools.partial(cb._decode_tick_paged, config=cfg,
+                             use_kernel=True)
+    compiled = jax.jit(tick, donate_argnums=(5,)).lower(
+        params, row, row, tables, row, (cache, ring), step).compile()
+    hlo = compiled.as_text()
+
+    def moves(shape):
+        shaped = re.compile(rf"= \(?\w+\[(\d+,)?{shape}\]")
+        return [line.strip() for line in hlo.splitlines()
+                if shaped.search(line) and not any(f in line for f in _FREE)]
+
+    assert moves(f"{1 + slots * 66},8,64,128") == []        # the ring
+    assert moves(f"{1 + slots * table},8,64,128") == []     # the arena
+    assert moves("32,3072,3072") == []                      # held experts
+    assert len(re.findall(r"%moe_gmm[.\d]* = ", hlo)) == 9
+    assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 4
+    assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == 8
+    header = hlo[:hlo.index("\n")]
+    aliased = re.findall(r"\{[\d, ]*\}: \((\d+), ", header)
+    assert len(aliased) == 4        # arena K, V; ring K, V
+    memory = compiled.memory_analysis()
+    cache_bytes = 2 * (ring.k.size + cache.k.size) * 2
+    assert memory.alias_size_in_bytes >= cache_bytes
+    assert abs(cache_bytes / 1e9 - 4.73) < 0.01
+    # Weights 8.64 GB + caches 4.73 GB resident; scratch a few MB.
+    assert abs(memory.argument_size_in_bytes / 1e9 - 13.38) < 0.02
+    assert memory.temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("window", [0, 4096])
+def test_paged_decode_attn_lowers_with_a_window(window):
+    """The decode kernel at the window cell's head shape (48 query / 8
+    KV heads of 128) over a ring of 66 blocks: the lower bound in the
+    mask and the modulo in the index map lower for the TPU."""
+    from ray_tpu.ops.paged_decode_attention import paged_decode_attention
+
+    q = S((48, 48, 128), jnp.bfloat16)
+    arena = S((4, 1 + 48 * 66, 8, 64, 128), jnp.bfloat16)
+    tables = S((48, 66), jnp.int32)
+    pos = S((48,), jnp.int32)
+
+    def attend(q, k, v, tables, pos, layer):
+        return paged_decode_attention(q, k, v, tables, pos, layer=layer,
+                                      limits=pos, use_kernel=True,
+                                      interpret=False, window=window)
+
+    exported = jax.export.export(jax.jit(attend), platforms=["tpu"])(
+        q, arena, arena, tables, pos, S((), jnp.int32))
+    assert "tpu_custom_call" in exported.mlir_module()
