@@ -263,22 +263,24 @@ def _same(phase: str, name: str, got, ref) -> None:
 
 
 def walk_every_entry(q, arena_k, arena_v, tables, positions, *, layer=None,
-                     k_scale=None, v_scale=None):
+                     k_scale=None, v_scale=None, window=0):
     """``paged_decode_attn`` as it was before PR 26, kept as the
     yardstick: grid ``(slots, table entries)``, every entry a grid step
     and a dead one skipped by ``pl.when``. A live slot's blocks go
     through the same ``_attend_block`` in the same order as in
     ``paged_decode_attention``, so its row must come out the same bits;
-    a freed slot attends the garbage block at position 0. Arguments as
-    ``paged_decode_attention``'s."""
+    a freed slot attends the garbage block at position 0. With ``window``
+    the table is a ring and entry ``j`` of the walk is the slot's ``j``-th
+    block from its first live one (ring entry ``block % nb``), the ones
+    past its position skipped. Arguments as ``paged_decode_attention``'s."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     from ray_tpu.ops.dispatch import interpret_default
     from ray_tpu.ops.paged_decode_attention import (_attend_block, _finalize,
-                                                    _init_state, _scratch,
-                                                    pltpu)
+                                                    _first_live, _init_state,
+                                                    _scratch, pltpu)
 
     if layer is None:
         layer = 0
@@ -295,10 +297,10 @@ def walk_every_entry(q, arena_k, arena_v, tables, positions, *, layer=None,
             ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
         else:
             o_ref, acc_ref, m_ref, l_ref = rest
-        j = pl.program_id(1)
         pos = pos_ref[pl.program_id(0)]
+        j = _first_live(pos, window, bs) + pl.program_id(1)
 
-        @pl.when(j == 0)
+        @pl.when(pl.program_id(1) == 0)
         def _init():
             _init_state(acc_ref, m_ref, l_ref)
 
@@ -307,19 +309,24 @@ def walk_every_entry(q, arena_k, arena_v, tables, positions, *, layer=None,
             _attend_block(q_ref[0], k_ref[0, 0], v_ref[0, 0], pos, j * bs,
                           acc_ref, m_ref, l_ref, scale=d ** -0.5,
                           k_scale=ks_ref[0, 0] if quantized else None,
-                          v_scale=vs_ref[0, 0] if quantized else None)
+                          v_scale=vs_ref[0, 0] if quantized else None,
+                          window=window)
 
-        @pl.when(j == nb - 1)
+        @pl.when(pl.program_id(1) == nb - 1)
         def _fin():
             _finalize(o_ref, acc_ref, l_ref)
+
+    def entry(b_, j, tab, po):
+        return tab[b_, (_first_live(po[b_], window, bs) + j) % nb]
 
     q_spec = pl.BlockSpec((1, hkv, group, d),
                           lambda b_, j, ly, tab, po: (b_, 0, 0, 0))
     kv_spec = pl.BlockSpec(
         (1, 1, hkv, bs, d),
-        lambda b_, j, ly, tab, po: (ly[0], tab[b_, j], 0, 0, 0))
+        lambda b_, j, ly, tab, po: (ly[0], entry(b_, j, tab, po), 0, 0, 0))
     sc_spec = pl.BlockSpec(
-        (1, 1, hkv, bs), lambda b_, j, ly, tab, po: (ly[0], tab[b_, j], 0, 0))
+        (1, 1, hkv, bs),
+        lambda b_, j, ly, tab, po: (ly[0], entry(b_, j, tab, po), 0, 0))
     scales = [k_scale, v_scale] if quantized else []
     out = pl.pallas_call(
         kernel,
@@ -337,25 +344,40 @@ def walk_every_entry(q, arena_k, arena_v, tables, positions, *, layer=None,
 
 # The paged kernel alone at each serve cell's shapes and fill: (cell,
 # slots, table entries, q heads, kv heads, layers, arena blocks, live
-# blocks of each live slot, us a call of the every-entry walk in that
-# cell's trace (PERF.md section 5, PR 24 and PR 25)). Slots without
-# blocks are freed, as most of serve_chat's are at its arrival rate.
+# blocks of each live slot, the layers' sliding window (0: a table), us
+# a call of the every-entry walk in that cell's trace (PERF.md section
+# 5, PR 24 and PR 25; the window cell's are the one-block visits' of PR
+# 32's trace). Slots without blocks are freed, as most of serve_chat's
+# are at its arrival rate. The window cell's two shapes: a window
+# layer's 66-entry rings with 65 blocks in the window, and the full
+# layer's 112-entry tables at 4.9k-6.2k keys (two layers where the cell
+# has one: a call at a constant layer index is hoisted out of the loop
+# that times it).
 PAGED_CELLS = (
     ("serve_chat", 48, 32, 32, 8, 16, 1000, {6 * i: 6 for i in range(8)},
-     209),
+     0, 209),
     ("serve_prefill_heavy", 8, 18, 32, 8, 16, 145,
-     {i: 13 for i in range(8)}, 71),
+     {i: 13 for i in range(8)}, 0, 71),
     ("serve_moe_decode", 48, 16, 16, 16, 12, 512,
-     {i: 4 + i % 2 for i in range(48)}, 292),
+     {i: 4 + i % 2 for i in range(48)}, 0, 292),
+    ("serve_window_decode ring", 48, 66, 48, 8, 4, 1 + 48 * 66,
+     {i: 65 for i in range(48)}, 4096, 1960),
+    ("serve_window_decode table", 48, 112, 48, 8, 2, 1 + 48 * 112,
+     {i: 77 + (7 * i) % 20 for i in range(48)}, 0, 2570),
 )
 PAGED_REHEARSAL_CELLS = (
-    ("tiny", 4, 4, 4, 2, 2, 17, {0: 2, 2: 4}, None),)
+    ("tiny", 4, 4, 4, 2, 2, 17, {0: 2, 2: 4}, 0, None),
+    ("tiny ring", 4, 5, 4, 2, 2, 21, {i: 4 for i in range(4)}, 100, None))
+# Blocks a grid step, timed side by side (the module ships one).
+PAGED_VISIT_BLOCKS = (1, 2, 3, 4)
 
 
-def _paged_cell_inputs(slots, nb, hq, hkv, layers, blocks, live, bs, d):
+def _paged_cell_inputs(slots, nb, hq, hkv, layers, blocks, live, window,
+                       bs, d):
     """One cell's kernel inputs: (q, [arena_k, arena_v], tables,
     positions, limits). Live slots own their blocks alone and stand
-    near the end of their last one; the others are freed."""
+    near the end of their last one (over a ring: where ``live`` blocks
+    hold a key of the window, some wraps in); the others are freed."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -369,6 +391,18 @@ def _paged_cell_inputs(slots, nb, hq, hkv, layers, blocks, live, bs, d):
     limits = np.zeros(slots, np.int32)
     ids = iter(range(1, blocks))
     for slot, n in live.items():
+        if window:
+            # The whole ring is the slot's, and the query stands some
+            # wraps in, where ``n`` = window // bs + 1 blocks hold a key
+            # of its window: the newest block from a row at which the
+            # window's first key has not left block ``first``.
+            tables[slot] = [next(ids) for _ in range(nb)]
+            assert n == window // bs + 1, (n, window, bs)
+            first, lo = nb + slot % nb, max(window % bs - 1, 0)
+            positions[slot] = ((first + n - 1) * bs + lo
+                               + slot % (bs - 1 - lo))
+            limits[slot] = nb * bs
+            continue
         # A dead tail repeats the last live block (`_table_row`).
         row = [next(ids) for _ in range(n)]
         tables[slot] = row + [row[-1]] * (nb - n)
@@ -398,13 +432,19 @@ def _time_us(call, q, arena, layers: int, reps: int) -> float:
 
 
 def _time_paged_cells(phase: str, device_kind: str, rehearse: bool) -> None:
-    """Time ``paged_decode_attn`` and the walk it replaced, each alone,
-    beside the time the live blocks' bytes take at the device's HBM
-    peak. The schedule is made once, outside the loop, as the engine
-    makes it."""
+    """Time ``paged_decode_attn`` at each count of blocks a grid step
+    (``PAGED_VISIT_BLOCKS``; ``visit_blocks`` of the arena is the one
+    shipped) and the walk it replaced, each alone, beside the time the
+    live blocks' bytes take at the device's HBM peak; every row of each
+    must be the walk's, bit for bit. The schedule is made once, outside
+    the loop, as the engine makes it."""
+    import jax
+    import jax.numpy as jnp
+
     from benchmark import peaks
     from ray_tpu.ops.paged_decode_attention import (paged_decode_attention,
-                                                    paged_visits)
+                                                    paged_visits,
+                                                    visit_blocks)
 
     bs, d, reps = (32, 128, 4) if rehearse else (64, 128, 1024)
     # The rehearsal's CPU has no peak on record, and no time to compare.
@@ -412,27 +452,48 @@ def _time_paged_cells(phase: str, device_kind: str, rehearse: bool) -> None:
         "hbm_bytes_per_s"]
     for cell, *shape, was_us in (PAGED_REHEARSAL_CELLS if rehearse
                                  else PAGED_CELLS):
-        slots, nb, _, hkv, layers, _, live = shape
+        slots, nb, _, hkv, layers, _, live, window = shape
         q, arena, tables, positions, limits = _paged_cell_inputs(
             *shape, bs, d)
-        visits = paged_visits(tables, positions, limits, block_size=bs)
-        new_us = _time_us(lambda q, k, v, li: paged_decode_attention(
-            q, k, v, tables, positions, layer=li, visits=visits,
-            use_kernel=True), q, arena, layers, reps)
-        old_us = _time_us(lambda q, k, v, li: walk_every_entry(
-            q, k, v, tables, positions, layer=li), q, arena, layers, reps)
         n_live = sum(live.values())
         what = (f"paged_decode_attn alone, {cell} ({slots} x {nb} entries, "
                 f"{n_live} live, {slots - len(live)} slots freed)")
+
+        def walk(q, k, v, li):
+            return walk_every_entry(q, k, v, tables, positions, layer=li,
+                                    window=window)
+
+        want = jnp.where((limits == 0)[:, None, None], 0,
+                         jax.jit(walk)(q, *arena, layers - 1))
+        took = {}
+        for per in PAGED_VISIT_BLOCKS:
+            visits = paged_visits(tables, positions, limits, block_size=bs,
+                                  per_visit=per, window=window)
+            assert int(visits[3][0]) == sum(
+                -(-n // per) for n in live.values()), (cell, per)
+
+            def call(q, k, v, li):
+                return paged_decode_attention(
+                    q, k, v, tables, positions, layer=li, visits=visits,
+                    use_kernel=True, window=window)
+
+            _same(phase, f"{what}, {per} block(s) a step against the "
+                         "every-entry walk",
+                  jax.jit(call)(q, *arena, layers - 1), want)
+            took[per] = _time_us(call, q, arena, layers, reps)
+        old_us = _time_us(walk, q, arena, layers, reps)
         if rehearse:
             _say(phase, f"{what}: both kernels ran; a rehearsal times "
                         "nothing")
             continue
         live_bytes = n_live * 2 * hkv * bs * d * 2    # K and V, bf16
-        _say(phase, f"{what}: {new_us:.1f} us a call; every-entry walk "
-                    f"{old_us:.1f} us here, {was_us} us in the cell's "
-                    f"trace; live bytes / {hbm / 1e9:.0f} GB/s = "
-                    f"{live_bytes / hbm * 1e6:.1f} us")
+        each = ", ".join(f"{us:.1f} us at {per}" + (
+            " (shipped)" if per == visit_blocks(arena[0]) else "")
+            for per, us in took.items())
+        _say(phase, f"{what}: a call by blocks a grid step: {each}; "
+                    f"every-entry walk {old_us:.1f} us here, {was_us} us "
+                    f"in the cell's trace; live bytes / {hbm / 1e9:.0f} GB/s "
+                    f"= {live_bytes / hbm * 1e6:.1f} us")
 
 
 def phase_kernels(rehearse: bool) -> None:

@@ -429,6 +429,13 @@ CB_PAGED_LIVE_BLOCK_SHARE = Histogram(
     "see, over num_slots x max_blocks; the paged attention kernel "
     "visits those entries and no others",
     boundaries=_SHARE_BOUNDS, tag_keys=("engine",))
+CB_PAGED_VISIT_FILL_SHARE = Histogram(
+    "ray_tpu_cb_paged_visit_fill_share",
+    "Per decode tick: blocks the paged attention kernel reads over the "
+    "blocks its grid steps could hold (a step covers a few consecutive "
+    "blocks of one slot, about 1 MB of K and V; a slot's last step may "
+    "be short), over every attention layer",
+    boundaries=_SHARE_BOUNDS, tag_keys=("engine",))
 CB_TICK_OVERLAPPED = Counter(
     "ray_tpu_cb_tick_overlapped_total",
     "Decode ticks dispatched while another tick was still in flight: "

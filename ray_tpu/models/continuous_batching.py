@@ -76,7 +76,7 @@ from ray_tpu.ops.paged_decode_attention import (decode_attention_reference,
                                                 paged_applicable,
                                                 paged_decode_attention,
                                                 paged_kv_write,
-                                                paged_visits)
+                                                paged_visits, visit_blocks)
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.util import tracing
 
@@ -114,16 +114,18 @@ PREFILL_DENSE_KEYS = 1024
 PREFILL_BATCH_TOKENS = 8192
 
 
-def _window_visits(tables, positions, limits, block_size: int,
-                   use_kernel: bool, window: int = 0):
-    """The attention kernel's schedule for each of a window's S
+def _window_visits(tables, positions, limits, arena_k, use_kernel: bool,
+                   window: int = 0):
+    """The attention kernel's schedule over the arena ``arena_k`` (K of
+    the table's arena or of the rings) for each of a window's S
     positions (``positions`` [B, S]): made ONCE a program, before the
     layer loop, because XLA leaves it inside the loop otherwise. A freed
     slot (limit 0) is never visited. None without the kernel."""
     if not use_kernel:
         return None
     return [paged_visits(tables, positions[:, j], limits,
-                         block_size=block_size, window=window)
+                         block_size=arena_k.shape[-2],
+                         per_visit=visit_blocks(arena_k), window=window)
             for j in range(positions.shape[1])]
 
 
@@ -401,7 +403,7 @@ def _forward_paged(params, tokens, positions, tables, limits,
     block_idx = jnp.where(positions < limits[:, None], gathered,
                           GARBAGE_BLOCK)                      # [B, S]
     offset = positions % bs
-    visits = _window_visits(tables, positions, limits, bs, use_kernel)
+    visits = _window_visits(tables, positions, limits, cache.k, use_kernel)
     if isinstance(state, RingKVCache):
         # Sliding-window layers: every slot's fixed ring of blocks, its
         # table an iota; position p lands in entry (p // bs) % ring.
@@ -411,8 +413,8 @@ def _forward_paged(params, tokens, positions, tables, limits,
             positions < limits[:, None],
             jnp.take_along_axis(ring_tables, (positions // bs) % ring,
                                 axis=1), GARBAGE_BLOCK)
-        ring_visits = _window_visits(ring_tables, positions, limits, bs,
-                                     use_kernel, c.sliding_window)
+        ring_visits = _window_visits(ring_tables, positions, limits,
+                                     state.k, use_kernel, c.sliding_window)
 
     runs, experts = llama.layer_runs(c, params, n_layers)
 
@@ -2246,35 +2248,61 @@ class ContinuousBatcher:
                 "live_tokens": live,
                 "frag_ratio": max(1.0 - live / cap, 0.0) if cap else 0.0}
 
+    def _attended_blocks(self) -> tuple:
+        """One pass over the live slots for the next tick's queries:
+        (each slot's block-table entries that hold a key its query may
+        see, each slot's blocks that hold one of its last
+        ``sliding_window`` keys; the second empty without window
+        layers): what ``paged_decode_attn`` reads a full-attention and
+        a sliding-window layer."""
+        bs, w = self.block_size, self.config.sliding_window
+        pos = [st["pos"] for st in self._slots.values()]
+        table = [p // bs + 1 for p in pos]
+        if self._ring is None:
+            return table, []
+        return table, [n - max(p - w + 1, 0) // bs
+                       for n, p in zip(table, pos)]
+
     def _live_blocks(self) -> int:
         """Block-table entries that hold a key the next tick's queries
-        may see: the visits ``paged_decode_attn`` makes a layer."""
-        bs = self.block_size
-        return sum(st["pos"] // bs + 1 for st in self._slots.values())
+        may see: the blocks ``paged_decode_attn`` reads a layer."""
+        return sum(self._attended_blocks()[0])
 
     def _window_blocks(self) -> tuple:
-        """(blocks a sliding-window layer's kernel visits for the next
+        """(blocks a sliding-window layer's kernel reads for the next
         tick's queries, blocks a table of every position would have it
-        visit): over the live slots."""
-        bs, w = self.block_size, self.config.sliding_window
-        last = [st["pos"] // bs for st in self._slots.values()]
-        first = [max(st["pos"] - w + 1, 0) // bs
-                 for st in self._slots.values()]
-        return sum(last) - sum(first) + len(last), sum(last) + len(last)
+        read): over the live slots."""
+        table, ring = self._attended_blocks()
+        return sum(ring), sum(table)
 
     def _account_tick(self, tick_fn, wall_s: float, spec_k: int) -> None:
         """Feed one tick (or a buffered window's mean tick) to the XLA
         monitor, with the live-byte hint, because the compiled cost
         prices every table entry as live, and book the share of entries
-        that were: both from one pass over the slots."""
+        that were, and how full the kernel's grid steps ran (a step
+        covers up to ``visit_blocks`` blocks of one slot; every
+        attention layer, rings and table by their layer counts): all
+        from one pass over the slots."""
         from ray_tpu._private import metrics_defs as mdefs
 
-        live = self._live_blocks()
+        table, ring = self._attended_blocks()
+        live = sum(table)
         mdefs.CB_PAGED_LIVE_BLOCK_SHARE.observe(
             live / (self.num_slots * self.max_blocks), tags=self._mtags)
-        if self._ring is not None and live:
+        if ring and live:
             mdefs.CB_WINDOW_LIVE_BLOCK_SHARE.observe(
-                self._window_blocks()[0] / live, tags=self._mtags)
+                sum(ring) / live, tags=self._mtags)
+        runs = [(self.cache.k, table)]
+        if ring:
+            runs.append((self._ring.k, ring))
+        read = held = 0
+        for arena_k, blocks in runs:        # each by its layer count
+            per, layers = visit_blocks(arena_k), arena_k.shape[0]
+            read += layers * sum(blocks)
+            held += layers * per * sum(-(-n // per) for n in blocks)
+        if held:
+            mdefs.CB_PAGED_VISIT_FILL_SHARE.observe(read / held,
+                                                    tags=self._mtags)
         tick_fn.note_execution(wall_s, bytes_hint=self.tick_bytes_estimate(
             spec_k=spec_k, live_blocks=live))
 

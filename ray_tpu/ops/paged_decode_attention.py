@@ -16,22 +16,31 @@ tiles exactly for bf16 and int8 at any head count, where a trailing
 Two bandwidth levers stack:
 
 * **Paging** — a 1-D grid over the VISITS a tick needs and no others:
-  one step per (slot, logical block) that holds a key the slot's query
-  may see, one whole block (every kv head) per step, slot-major so a
-  slot's running softmax state stays in VMEM between its steps. The
-  schedule (:func:`paged_visits`) rides scalar prefetch and its length
-  is the grid's bound, a traced scalar. A freed slot has no visit and
-  its output row stays zero; a dead table entry is never stepped over.
-  Blocks still arrive through BlockSpecs, which pipeline across slot
+  one step per run of consecutive logical blocks of one slot that hold
+  a key the slot's query may see (as many as :func:`visit_blocks` says
+  of the arena's shape: about 1 MB of K and V a step), whole blocks
+  (every kv head), slot-major so a slot's running softmax state stays in
+  VMEM between its steps. The schedule (:func:`paged_visits`) rides
+  scalar prefetch and its length is the grid's bound, a traced scalar.
+  A freed slot has no visit and its output row stays zero; a dead table
+  entry is never stepped over, and a slot's last visit may be short: a
+  dead sub-block is neither fetched nor attended.
+  Blocks still arrive through BlockSpecs (one operand a sub-block, each
+  one whole block chosen by the schedule), which pipeline across slot
   boundaries; a kernel that copies them itself out of ``pl.ANY``
   operands cannot read an int8 arena, because Mosaic refuses any slice
   of an HBM array whose minor axis is under 128 lanes, and the scale
   sidecar's is ``block_size``.
   A grid step is not free: on the v5e one that ``pl.when`` skips costs
-  0.11-0.2 us with no HBM traffic at all, a live one 0.35 us plus its
-  block's bytes, so a ``(batch, table_blocks)`` grid over 48 slots x 32
-  entries with 48 of them live spends five sixths of its time on
-  entries that hold nothing (224 us against 34, PERF.md section 6).
+  0.11-0.2 us with no HBM traffic at all, so a ``(batch, table_blocks)``
+  grid over 48 slots x 32 entries with 48 of them live spends five
+  sixths of its time on entries that hold nothing (224 us against 34,
+  PERF.md section 6). A live one of one block costs 0.3 us beyond its
+  bytes, and that is the softmax chain's LATENCY (scores, max, exp,
+  sum, weighted values: each waits for the one before), not its work:
+  a step of several blocks whose chains the scheduler may interleave
+  (no branch between them, the state in registers) reads four blocks
+  in 1.4 us where four steps took 2.5 (PERF.md section 6, PR 33).
 * **int8 KV quantization** — the arena stores K/V as int8 with
   per-token/per-kv-head fp32 scales kept in block-shaped sidecars
   (``[num_blocks, KVH, block_size]``), gathered by the same table;
@@ -191,16 +200,16 @@ def _init_state(acc_ref, m_ref, l_ref):
     acc_ref[:] = jnp.zeros_like(acc_ref)
 
 
-def _attend_block(q, k, v, pos, first_col, acc_ref, m_ref, l_ref, *, scale,
-                  k_scale=None, v_scale=None, window: int = 0):
-    """One online-softmax step over a K/V block, all kv heads at once.
+def _block_scores(q, k, pos, first_col, *, scale, k_scale=None,
+                  window: int = 0):
+    """A K block's masked scores, all kv heads at once: [KVH, G, T].
 
-    q [KVH, G, D]; k/v [KVH, T, D] in storage dtype (upcast here);
+    q [KVH, G, D]; k [KVH, T, D] in storage dtype (upcast here);
     ``pos`` the slot's absolute query position, ``first_col`` the
-    absolute position of the block's first key. ``k_scale``/``v_scale``
-    [KVH, T] dequantize an int8 block: they scale the score and
-    probability COLUMNS (keys ride the lane axis of both), which equals
-    scaling K/V rows without relayouting the scales onto sublanes."""
+    absolute position of the block's first key. ``k_scale`` [KVH, T]
+    dequantizes an int8 block: it scales the score COLUMNS (keys ride
+    the lane axis), which equals scaling K rows without relayouting the
+    scales onto sublanes."""
     q = q.astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k.astype(jnp.float32), (((2,), (2,)), ((0,), (0,))),
@@ -211,10 +220,16 @@ def _attend_block(q, k, v, pos, first_col, acc_ref, m_ref, l_ref, *, scale,
     seen = pos >= first_col + cols
     if window:      # the lower bound: inside the FIRST live block only
         seen &= pos - (first_col + cols) < window
-    s = jnp.where(seen, s, MASK_VALUE)
+    return jnp.where(seen, s, MASK_VALUE)
 
-    m_prev = m_ref[:, :, :1]                                 # [KVH, G, 1]
-    l_prev = l_ref[:, :, :1]
+
+def _fold_block(s, m_prev, l_prev, acc, v, v_scale=None):
+    """One online-softmax step: fold a block's scores ``s`` [KVH, G, T]
+    and values ``v`` [KVH, T, D] into the running max and sum [KVH, G,
+    1] and the accumulator [KVH, G, D]; returns the three. ``v_scale``
+    [KVH, T] scales the probability columns, as ``k_scale`` the
+    scores'. A block no key of which is seen leaves all three as they
+    were: its probabilities are exp(MASK - m) = 0."""
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)                                   # [KVH, G, T]
     alpha = jnp.exp(m_prev - m_new)
@@ -224,7 +239,18 @@ def _attend_block(q, k, v, pos, first_col, acc_ref, m_ref, l_ref, *, scale,
     pv = jax.lax.dot_general(
         p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)                  # [KVH, G, D]
-    acc_ref[:] = acc_ref[:] * alpha + pv
+    return m_new, l_new, acc * alpha + pv
+
+
+def _attend_block(q, k, v, pos, first_col, acc_ref, m_ref, l_ref, *, scale,
+                  k_scale=None, v_scale=None, window: int = 0):
+    """:func:`_block_scores` then :func:`_fold_block` on the state in
+    VMEM scratch (the max and the sum broadcast along lanes)."""
+    s = _block_scores(q, k, pos, first_col, scale=scale, k_scale=k_scale,
+                      window=window)
+    m_new, l_new, acc = _fold_block(s, m_ref[:, :, :1], l_ref[:, :, :1],
+                                    acc_ref[:], v, v_scale)
+    acc_ref[:] = acc
     m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
@@ -254,73 +280,168 @@ def _first_live(pos, window: int, block_size: int):
     return jnp.maximum(pos - (window - 1), 0) // block_size if window else 0
 
 
+# K and V bytes a grid step takes, about: a step covers as many
+# consecutive blocks of its slot as fit, at most ``MAX_VISIT_BLOCKS``
+# (nothing above was timed). One block a step spends half its time on
+# steps and on the softmax chain's latency at 65-86 live blocks a slot;
+# on the v5e four 262 KB blocks a step (8 kv heads, bf16) run at 1.14 ms
+# where one a step takes 1.92 and the bytes 1.00, and two 524 KB blocks
+# (16 kv heads) at 166 us against 188 and 138, three or four slower
+# again (`chip_smoke.py kernels` times 1 to 4; PERF.md section 6, PR 33).
+VISIT_BYTES = 1 << 20
+MAX_VISIT_BLOCKS = 4
+
+
+def visit_blocks(arena_k) -> int:
+    """Blocks a grid step of :func:`paged_decode_attention` covers over
+    this arena (a slab or the whole ``[L, NB, KVH, bs, D]``): a rule on
+    the block's bytes, which every caller can see."""
+    block = 2 * math.prod(arena_k.shape[-3:]) * jnp.dtype(
+        arena_k.dtype).itemsize
+    return max(1, min(VISIT_BYTES // block, MAX_VISIT_BLOCKS))
+
+
 def paged_visits(tables, positions, limits=None, *, block_size: int,
-                 window: int = 0):
-    """The kernel's schedule: one VISIT per (slot, logical block) that
-    holds a key the slot's query may see, slot-major, and nothing else.
-    A slot's query sits at absolute position ``pos``, so its blocks
-    ``[0, pos // bs]`` are live (clamped to the table); a freed slot
-    (``limits`` 0; every slot is live without ``limits``) owns none.
-    Returns (slot of visit, logical block of visit, both ``[B * nb]``
-    and valid past the end, visits ``[1]``).
+                 per_visit: int, window: int = 0):
+    """The kernel's schedule: one VISIT per run of up to ``per_visit``
+    (:func:`visit_blocks` of the arena the schedule is for)
+    consecutive logical blocks of ONE slot that hold a key the slot's
+    query may see, slot-major, and nothing else. A slot's query sits at
+    absolute position ``pos``, so its blocks ``[0, pos // bs]`` are live
+    (clamped to the table); its visits start at its first live block and
+    the last may be short; a freed slot (``limits`` 0; every slot is
+    live without ``limits``) owns none.
+    Returns (slot of visit ``[V]``, first logical block of visit ``[V]``,
+    table entry of each of the visit's sub-blocks ``[P * V]`` (sub-block
+    ``p`` of visit ``v`` at ``p * V + v``, an index into the flattened
+    ``tables``), visits ``[1]``), the lists valid past the end. A DEAD
+    sub-block (past the slot's last live block) names the entry its
+    operand read at the step before, so the pipeline fetches nothing for
+    it, and the kernel skips it.
 
     With ``window`` the table is a slot's RING of ``nb`` entries and a
     query sees the last ``window`` keys: its live blocks are ``[(pos -
-    window + 1) // bs, pos // bs]``, LOGICAL blocks that the kernel maps
-    to ring entries itself (``block % nb``); they number at most
-    ``window // bs + 1``, which the ring must exceed.
+    window + 1) // bs, pos // bs]``, LOGICAL blocks that live in ring
+    entries ``block % nb``; they number at most ``window // bs + 1``,
+    which the ring must exceed.
 
-    A dozen small XLA ops, which the compiler leaves inside a layer
-    loop although nothing in them depends on the layer: a caller with
-    such a loop makes the schedule once, before it, and hands it to
-    :func:`paged_decode_attention` as ``visits``."""
+    Some dozen small XLA fusions and no gather (a gather's index vectors
+    pad to 128 lanes: 0.4 MB of scratch each at 768 visits), which the
+    compiler leaves inside a layer loop although nothing in them depends
+    on the layer: a caller with such a loop makes the schedule once,
+    before it, and hands it to :func:`paged_decode_attention` as
+    ``visits``."""
     b, nb = tables.shape
+    per = per_visit
     positions = positions.astype(jnp.int32)
-    first = _first_live(positions, window, block_size)
-    if window:
-        n_live = positions // block_size + 1 - first
-    else:
-        n_live = jnp.minimum(positions // block_size + 1, nb)
+    first = (_first_live(positions, window, block_size)
+             + jnp.zeros_like(positions))
+    last = positions // block_size
+    if not window:
+        last = jnp.minimum(last, nb - 1)
+    n_live = last + 1 - first
     if limits is not None:
         n_live = jnp.where(limits > 0, n_live, 0)
-    ends = jnp.cumsum(n_live)
-    v = jnp.arange(b * nb, dtype=jnp.int32)
+    n_visits = -(-n_live // per)
+    ends = jnp.cumsum(n_visits)
+    # One entry to spare: the pipeline works out the step after the last.
+    v = jnp.arange(b * -(-nb // per) + 1, dtype=jnp.int32)
+    slots = jnp.arange(b, dtype=jnp.int32)
     # Compare-all, not a binary search: one fusion, no loop on device.
     before = ends[None, :] <= v[:, None]                     # [V, B]
     slot = jnp.minimum(jnp.sum(before, axis=1), b - 1)
-    start = jnp.sum(jnp.where(before, n_live[None, :], 0), axis=1)
-    if window:
-        block = first[slot] + jnp.maximum(v - start, 0)
-    else:
-        block = jnp.clip(v - start, 0, nb - 1)
+    mine = slot[:, None] == slots[None, :]                   # [V, B]
+
+    def of_slot(x):
+        """``x[..., slot]`` for ``x`` [..., B], as a masked sum."""
+        return jnp.sum(jnp.where(mine, x[..., None, :], 0), axis=-1)
+
+    def entry(s, j):
+        """Logical block ``j`` of slot ``s`` in the flattened tables.
+        (A ring's entry for logical block j is j % nb.)"""
+        return s * nb + (j % nb if window else jnp.minimum(j, nb - 1))
+
+    nth = jnp.maximum(v - of_slot(ends - n_visits), 0)   # visit of its slot
+    block = of_slot(first) + nth * per                       # [V]
+    p = jnp.arange(per, dtype=jnp.int32)[:, None]
+    sub = block[None, :] + p                                 # [P, V]
+    live = (sub <= of_slot(last)[None, :]) & (v < ends[-1])[None, :]
+    # What operand p read at the step before a dead sub-block: within a
+    # slot its sub-block of the visit before, which was live; at a
+    # slot's first visit, what the newest earlier slot with more than p
+    # live blocks read last (a freed slot hands on what it was handed).
+    has = n_live[None, :] > p                                # [P, B]
+    left = entry(slots[None, :], first[None, :] + p
+                 + (n_live[None, :] - 1 - p) // per * per)
+    newest = jax.lax.cummax(jnp.where(has, slots[None, :], -1), axis=1)
+    newest = jnp.concatenate(                # of the slots BEFORE each
+        [jnp.full((per, 1), -1, jnp.int32), newest[:, :-1]], axis=1)
+    handed = jnp.sum(jnp.where(              # (none before: entry 0)
+        newest[:, :, None] == slots[None, None, :], left[:, None, :], 0),
+        axis=-1)                                             # [P, B]
+    where = jnp.where(live, entry(slot[None, :], sub), jnp.where(
+        nth[None, :] > 0, entry(slot[None, :], sub - per), of_slot(handed)))
     return (slot.astype(jnp.int32), block.astype(jnp.int32),
-            ends[-1:].astype(jnp.int32))
+            where.astype(jnp.int32).reshape(-1), ends[-1:].astype(jnp.int32))
 
 
 def _paged_kernel(layer_ref, tables_ref, pos_ref, slot_ref, block_ref,
-                  q_ref, k_ref, v_ref, *rest, scale, block_size, num_blocks,
-                  quantized, window=0):
+                  where_ref, q_ref, *rest, scale, block_size, num_blocks,
+                  per_visit, quantized, window=0):
+    n = per_visit
+    k_refs, v_refs, rest = rest[:n], rest[n:2 * n], rest[2 * n:]
     if quantized:
-        ks_ref, vs_ref, _, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        _, o_ref, acc_ref, m_ref, l_ref = rest
+        ks_refs, vs_refs, rest = rest[:n], rest[n:2 * n], rest[2 * n:]
+    _, o_ref, acc_ref, m_ref, l_ref = rest
     visit = pl.program_id(0)
     pos = pos_ref[slot_ref[visit]]
     j = block_ref[visit]
+    # A ring's logical blocks run past its width; a table's do not.
+    last = pos // block_size
+    if not window:
+        last = jnp.minimum(last, num_blocks - 1)
 
     @pl.when(j == _first_live(pos, window, block_size))
     def _init():
         _init_state(acc_ref, m_ref, l_ref)
 
-    _attend_block(q_ref[0], k_ref[0, 0], v_ref[0, 0], pos, j * block_size,
-                  acc_ref, m_ref, l_ref, scale=scale,
-                  k_scale=ks_ref[0, 0] if quantized else None,
-                  v_scale=vs_ref[0, 0] if quantized else None,
-                  window=window)
+    # The visit's blocks in logical order, one online-softmax step each:
+    # the arithmetic of one block a step, whatever a step covers.
+    def keys(p):
+        return dict(k=k_refs[p][0, 0], first_col=(j + p) * block_size,
+                    k_scale=ks_refs[p][0, 0] if quantized else None)
 
-    # A ring's logical blocks run past its width; a table's do not.
-    last = pos // block_size
-    @pl.when(j == (last if window else jnp.minimum(last, num_blocks - 1)))
+    def values(p):
+        return dict(v=v_refs[p][0, 0],
+                    v_scale=vs_refs[p][0, 0] if quantized else None)
+
+    @pl.when(j + n - 1 <= last)
+    def _full():
+        # No branch between the blocks and the state in registers, so
+        # the scheduler overlaps one block's scores with the fold of the
+        # block before: the softmax chain's latency, not its work, is
+        # what a block costs beyond its bytes.
+        q = q_ref[0]
+        scores = [_block_scores(q, pos=pos, scale=scale, window=window,
+                                **keys(p)) for p in range(n)]
+        state = (m_ref[:, :, :1], l_ref[:, :, :1], acc_ref[:])
+        for p in range(n):
+            state = _fold_block(scores[p], *state, **values(p))
+        m_new, l_new, acc = state
+        acc_ref[:] = acc
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    # A slot's last visit, short: block by block, the dead ones (past
+    # the slot's last block) neither read nor attended.
+    for p in range(n - 1):
+        @pl.when((j + n - 1 > last) & (j + p <= last))
+        def _short(p=p):
+            _attend_block(q_ref[0], pos=pos, acc_ref=acc_ref, m_ref=m_ref,
+                          l_ref=l_ref, scale=scale, window=window,
+                          **keys(p), **values(p))
+
+    @pl.when(last < j + n)
     def _fin():
         _finalize(o_ref, acc_ref, l_ref)
 
@@ -332,36 +453,46 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
     nb = tables.shape[1]
     group = hq // hkv
     quantized = k_scale is not None
-    slot_of, block_of, count = visits
+    slot_of, block_of, where_of, count = visits
+    n_visits = slot_of.shape[0]
+    per = where_of.shape[0] // n_visits
+
+    # The pipeline also works out block indices for steps it never runs
+    # (the one after the last, at least): a step past the lists reads
+    # their last entry, which the schedule keeps valid.
+    def listed(v):
+        return jnp.minimum(v, n_visits - 1)
 
     qg = q.reshape(b, hkv, group, d)
     q_spec = pl.BlockSpec(
         (1, hkv, group, d),
-        lambda v, ly, tab, po, sl, bl: (sl[v], 0, 0, 0))
+        lambda v, ly, tab, po, sl, bl, wh: (sl[listed(v)], 0, 0, 0))
+
     # The table gather IS the index_map: the scalar-prefetched layer,
-    # schedule and block tables choose which arena block each visit
-    # streams into VMEM.
-    # (A ring's entry for logical block b is b % nb.)
-    entry = (lambda bl, v: bl[v] % nb) if window else (lambda bl, v: bl[v])
-    kv_spec = pl.BlockSpec(
-        (1, 1, hkv, block_size, d),
-        lambda v, ly, tab, po, sl, bl: (
-            ly[0], tab[sl[v], entry(bl, v)], 0, 0, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
-    inputs = [qg, arena_k, arena_v]
+    # schedule and block tables choose which arena block each of a
+    # visit's operands streams into VMEM. One operand a sub-block, so
+    # the blocks still arrive through the BlockSpec pipeline, and one
+    # whose block does not change between two steps is not fetched again.
+    def specs(shape):
+        zeros = (0,) * (len(shape) - 2)
+        return [pl.BlockSpec(
+            shape, lambda v, ly, tab, po, sl, bl, wh, p=p: (
+                ly[0], tab[wh[p * n_visits + listed(v)]], *zeros))
+            for p in range(per)]
+
+    kv_specs = specs((1, 1, hkv, block_size, d))
+    in_specs = [q_spec] + kv_specs + kv_specs
+    inputs = [qg] + [arena_k] * per + [arena_v] * per
     if quantized:
-        sc_spec = pl.BlockSpec(
-            (1, 1, hkv, block_size),
-            lambda v, ly, tab, po, sl, bl: (
-                ly[0], tab[sl[v], entry(bl, v)], 0, 0))
-        in_specs += [sc_spec, sc_spec]
-        inputs += [k_scale, v_scale]
+        sc_specs = specs((1, 1, hkv, block_size))
+        in_specs += sc_specs + sc_specs
+        inputs += [k_scale] * per + [v_scale] * per
     # The output starts as zeros and only visited slots are written, so
     # a freed slot's row comes back zero at no grid step of its own.
     in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     inputs.append(jnp.zeros_like(qg))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(count[0],),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -369,7 +500,7 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
     )
     kernel = functools.partial(
         _paged_kernel, scale=scale, block_size=block_size, num_blocks=nb,
-        quantized=quantized, window=window)
+        per_visit=per, quantized=quantized, window=window)
     itemsize = jnp.dtype(arena_k.dtype).itemsize
     kv_bytes = 2 * b * nb * hkv * block_size * d * itemsize
     if quantized:
@@ -378,8 +509,8 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        # Operand index counts the five scalar-prefetch arrays.
-        input_output_aliases={5 + len(inputs) - 1: 0},
+        # Operand index counts the six scalar-prefetch arrays.
+        input_output_aliases={6 + len(inputs) - 1: 0},
         interpret=interpret,
         name="paged_decode_attn",
         cost_estimate=pl.CostEstimate(
@@ -390,8 +521,8 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
             + q.size * jnp.dtype(q.dtype).itemsize,
             transcendentals=b * hq * nb * block_size,
         ),
-    )(_layer_operand(layer), tables.astype(jnp.int32),
-      positions.astype(jnp.int32), slot_of, block_of, *inputs)
+    )(_layer_operand(layer), tables.astype(jnp.int32).reshape(-1),
+      positions.astype(jnp.int32), slot_of, block_of, where_of, *inputs)
     return out.reshape(b, hq, d)
 
 
@@ -515,9 +646,9 @@ def paged_decode_attention(
     does not visit it and its row comes back zero (the reference attends
     whatever its table names; nothing reads that row). Without it every
     slot is live. ``visits``: the kernel's schedule, from
-    :func:`paged_visits` on the same tables, positions and limits, for
-    a caller that makes it once for many layers; ``limits`` is then not
-    read.
+    :func:`paged_visits` on the same tables, positions and limits (and
+    :func:`visit_blocks` of this arena), for a caller that makes it once
+    for many layers; ``limits`` is then not read.
 
     ``window``: ``tables`` [B, nb] is each slot's RING (logical block
     ``b`` in entry ``b % nb``; ``nb > window // bs + 1``) and a query
@@ -557,7 +688,8 @@ def paged_decode_attention(
             k_scale, v_scale = k_scale[None], v_scale[None]
     if visits is None:
         visits = paged_visits(tables, positions, limits,
-                              block_size=block_size, window=window)
+                              block_size=block_size,
+                              per_visit=visit_blocks(arena_k), window=window)
     return _paged_fused(q, arena_k, arena_v, tables, positions, visits,
                         layer=layer, k_scale=k_scale, v_scale=v_scale,
                         scale=scale, interpret=interpret, window=window)

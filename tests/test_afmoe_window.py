@@ -33,7 +33,7 @@ from ray_tpu.models.paged_kv import (PagedKVCache, RingKVCache,  # noqa: E402
 from ray_tpu.ops import moe  # noqa: E402
 from ray_tpu.ops.attention import paged_chunk_attention  # noqa: E402
 from ray_tpu.ops.paged_decode_attention import (  # noqa: E402
-    paged_decode_attention, paged_visits)
+    MAX_VISIT_BLOCKS, paged_decode_attention, paged_visits)
 
 TYPES = ("sliding_attention", "sliding_attention", "sliding_attention",
          "full_attention", "sliding_attention")
@@ -268,13 +268,19 @@ def test_window_decode_attention_over_a_ring(use_kernel, positions,
 def test_window_visits_start_at_the_first_live_block():
     pos = jnp.asarray([3, 40, 100], jnp.int32)
     tables = RingKVCache.tables(jnp.arange(3), 5)
-    slot, block, count = paged_visits(tables, pos, jnp.asarray([8, 8, 0]),
-                                      block_size=BS, window=WINDOW)
+    per = 2
+    slot, block, where, count = paged_visits(
+        tables, pos, jnp.asarray([8, 8, 0]), block_size=BS, per_visit=per,
+        window=WINDOW)
     n = int(count[0])
-    # Slot 0: block 0; slot 1: keys 17..40 = blocks 2..5; slot 2 freed.
-    assert n == 5
-    assert list(map(int, slot[:n])) == [0, 1, 1, 1, 1]
-    assert list(map(int, block[:n])) == [0, 2, 3, 4, 5]
+    # Slot 0: block 0; slot 1: keys 17..40 = blocks 2..5, in runs of
+    # ``per`` from block 2; slot 2 freed.
+    starts = list(range(2, 6, per))
+    assert n == 1 + len(starts)
+    assert list(map(int, slot[:n])) == [0] + [1] * len(starts)
+    assert list(map(int, block[:n])) == [0] + starts
+    # Each visit's first sub-block is its ring entry, block % ring.
+    assert list(map(int, where[:n])) == [0] + [5 + b % 5 for b in starts]
 
 
 @pytest.mark.parametrize("window", [0, 20])
@@ -528,6 +534,8 @@ def test_window_and_expert_metrics_are_booked(model):
                                     mdefs.CB_MOE_LOCAL_ASSIGNMENTS)}
     chunks = total(mdefs.CB_PREFILL_CHUNK_MS, "_count")
     shares = total(mdefs.CB_WINDOW_LIVE_BLOCK_SHARE, "_count")
+    fills = {end: total(mdefs.CB_PAGED_VISIT_FILL_SHARE, end)
+             for end in ("_sum", "_count")}
     outs, eng = _serve(config, params, _prompts((70,)), max_new=10)
     ticks = eng.base_tick_count
     asked = total(mdefs.CB_MOE_ASSIGNMENTS) - before[mdefs.CB_MOE_ASSIGNMENTS]
@@ -538,6 +546,11 @@ def test_window_and_expert_metrics_are_booked(model):
     assert 0 < local < asked
     assert total(mdefs.CB_PREFILL_CHUNK_MS, "_count") - chunks == 5  # 70/16
     assert total(mdefs.CB_WINDOW_LIVE_BLOCK_SHARE, "_count") - shares == ticks
+    # Rings and table, each by its layer count: a share in (0, 1] a tick.
+    fills = {end: total(mdefs.CB_PAGED_VISIT_FILL_SHARE, end) - was
+             for end, was in fills.items()}
+    assert fills["_count"] == ticks
+    assert 1 / MAX_VISIT_BLOCKS <= fills["_sum"] / ticks <= 1
     assert eng._window_blocks()[1] == 0         # nothing live any more
     gauges = {n: v for m in (mdefs.CB_WINDOW_KV_BYTES, mdefs.CB_FULL_KV_BYTES)
               for n, tags, v in m.samples()

@@ -414,6 +414,38 @@ def test_tick_books_the_share_of_table_entries_it_visits(setup):
     assert total - sum0 == pytest.approx(ticks * 2 / 16)
 
 
+def test_tick_books_how_full_the_kernels_grid_steps_run(setup):
+    """Every tick with a live slot observes
+    ``ray_tpu_cb_paged_visit_fill_share``: blocks the attention kernel
+    reads over ``visit_blocks`` x the grid steps it takes, a slot's
+    blocks in runs of ``visit_blocks`` and its last run short. Two
+    requests at positions 40..44 and 70..74 of 32-token blocks hold 2
+    and 3 blocks in every tick; the two free slots take no step."""
+    from ray_tpu._private import metrics_defs as mdefs
+    from ray_tpu.ops.paged_decode_attention import visit_blocks
+
+    config, gen, _ = setup
+    eng = ContinuousBatcher(config, params=gen.params, num_slots=4,
+                            max_len=128, block_size=32)
+
+    def read():
+        samples = mdefs.CB_PAGED_VISIT_FILL_SHARE.samples()
+        return tuple(sum(v for name, _, v in samples if name.endswith(end))
+                     for end in ("_sum", "_count"))
+
+    sum0, count0 = read()
+    eng.submit(list(range(1, 41)), max_new_tokens=5)
+    eng.submit(list(range(1, 71)), max_new_tokens=5)
+    eng.run_to_completion()
+    ticks = eng.base_tick_count
+    total, count = read()
+    assert ticks >= 4 and count - count0 == ticks
+    per = visit_blocks(eng.cache.k)
+    steps = -(-2 // per) + -(-3 // per)
+    assert total - sum0 == pytest.approx(ticks * 5 / (per * steps))
+    assert eng._attended_blocks() == ([], [])       # nothing live any more
+
+
 def test_live_rows_never_share_a_write_block(setup, pallas_interpret):
     """``paged_kv_write`` merges each row into the block as fetched, so
     two rows of one tick may name the same block only if nothing reads

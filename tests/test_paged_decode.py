@@ -14,11 +14,14 @@ import pytest
 from ray_tpu.models.paged_kv import (GARBAGE_BLOCK, BlockAllocator,
                                      PagedKVCache, quantize_kv,
                                      resolve_kv_dtype)
-from ray_tpu.ops.paged_decode_attention import (decode_attention_reference,
+from ray_tpu.ops.paged_decode_attention import (MAX_VISIT_BLOCKS,
+                                                VISIT_BYTES,
+                                                decode_attention_reference,
                                                 paged_applicable,
                                                 paged_attention_reference,
                                                 paged_decode_attention,
-                                                paged_kv_write, paged_visits)
+                                                paged_kv_write, paged_visits,
+                                                visit_blocks)
 
 
 def _paged_inputs(b=3, hq=4, hkv=2, d=16, bs=32, nb_slot=4, seed=0,
@@ -244,29 +247,274 @@ def test_limits_decide_which_slots_are_visited(pallas_interpret, kv_dtype,
             use_kernel=True, **kw), np.float32))
 
 
-def test_visit_schedule_lists_live_blocks_slot_major(pallas_interpret):
+def _expected_visits(tables, pos, limits, bs, per, window=0):
+    """The schedule in plain Python: [(slot, first logical block, [arena
+    block of each LIVE sub-block])], runs of ``per`` from each slot's
+    first live block."""
+    tables, nb = np.asarray(tables), tables.shape[1]
+    want = []
+    for s, (p, lim) in enumerate(zip(pos, limits)):
+        if not lim:
+            continue
+        first = max(p - window + 1, 0) // bs if window else 0
+        last = p // bs if window else min(p // bs, nb - 1)
+        for j in range(first, last + 1, per):
+            run = range(j, min(j + per, last + 1))
+            want.append((s, j, [int(tables[s, b % nb]) for b in run]))
+    return want
+
+
+def _check_schedule(visits, want, tables, per):
+    """``visits`` lists ``want`` and nothing else; a dead sub-block names
+    the table entry its operand read the step before (nothing to fetch),
+    and every entry past the end (one at least, which the pipeline works
+    out and never runs) still names a slot and a table entry."""
+    slot, block, where, count = (np.asarray(a) for a in visits)
+    n = int(count[0])
+    assert n == len(want) < slot.size
+    assert slot.shape == block.shape == (where.size // per,)
+    assert (slot < tables.shape[0]).all() and (slot >= 0).all()
+    assert (where < tables.size).all() and (where >= 0).all()
+    where = where.reshape(per, -1)
+    phys = np.asarray(tables).reshape(-1)[where]
+    for v, (s, j, blocks) in enumerate(want):
+        assert (slot[v], block[v]) == (s, j)
+        assert phys[:len(blocks), v].tolist() == blocks
+        if v:
+            np.testing.assert_array_equal(where[len(blocks):, v],
+                                          where[len(blocks):, v - 1])
+
+
+@pytest.mark.parametrize("per", [1, 2, 3, 4])
+def test_visit_schedule_lists_live_blocks_slot_major(pallas_interpret, per):
     """``paged_visits``: slot i contributes blocks 0..pos // bs (clamped
-    to the table) and a freed slot none; a schedule made ahead of the
-    call, as the engine makes it before its layer loop, gives the same
-    bits as one made inside it."""
+    to the table) in runs of ``per`` and a freed slot none; a schedule
+    made ahead of the call, as the engine makes it before its layer
+    loop, gives the same bits as one made inside it, whatever a step
+    covers."""
     q, (ak, av, tables), _ = _walk_case(4, 2, "bf16", layered=False)
-    pos = jnp.asarray([31, 32, 500, 7, 127], jnp.int32)
-    limits = jnp.asarray([128, 128, 128, 0, 128], jnp.int32)
-    slot, block, count = paged_visits(tables, pos, limits, block_size=32)
-    want = [(0, 0), (1, 0), (1, 1), (2, 0), (2, 1), (2, 2), (2, 3),
-            (4, 0), (4, 1), (4, 2), (4, 3)]
-    assert int(count[0]) == len(want)
-    assert list(zip(np.asarray(slot)[:len(want)].tolist(),
-                    np.asarray(block)[:len(want)].tolist())) == want
-    # Past the end the lists still name a slot and a block of the table.
-    assert slot.shape == block.shape == (5 * 4,)
-    assert (np.asarray(slot) < 5).all() and (np.asarray(block) < 4).all()
+    pos = [31, 32, 500, 7, 127]
+    limits = [128, 128, 128, 0, 128]
+    visits = paged_visits(tables, jnp.asarray(pos, jnp.int32),
+                          jnp.asarray(limits, jnp.int32), block_size=32,
+                          per_visit=per)
+    want = _expected_visits(tables, pos, limits, 32, per)
+    assert sum(len(blocks) for _, _, blocks in want) == 1 + 2 + 4 + 4
+    _check_schedule(visits, want, tables, per)
+    pos, limits = jnp.asarray(pos, jnp.int32), jnp.asarray(limits, jnp.int32)
     np.testing.assert_array_equal(
         np.asarray(paged_decode_attention(
-            q, ak, av, tables, pos, visits=(slot, block, count),
-            use_kernel=True)),
+            q, ak, av, tables, pos, visits=visits, use_kernel=True)),
         np.asarray(paged_decode_attention(
             q, ak, av, tables, pos, limits=limits, use_kernel=True)))
+
+
+@pytest.mark.parametrize("per", [2, 4])
+def test_visit_schedule_of_a_short_slot_is_one_step_of_one_block(per):
+    """A slot with one live block makes one visit whose other
+    sub-blocks are dead, whatever its neighbours hold: sixteen such
+    slots are sixteen steps, and no operand but the first ever changes
+    its block."""
+    tables = jnp.arange(1, 16 * 4 + 1, dtype=jnp.int32).reshape(16, 4)
+    pos = jnp.arange(16, dtype=jnp.int32)        # every slot in block 0
+    slot, block, where, count = (np.asarray(a) for a in paged_visits(
+        tables, pos, block_size=32, per_visit=per))
+    assert int(count[0]) == 16 and slot[:16].tolist() == list(range(16))
+    assert not block[:16].any()
+    where = where.reshape(per, -1)[:, :16]
+    np.testing.assert_array_equal(where[0], np.arange(16) * 4)
+    assert (where[1:] == where[1:, :1]).all()
+
+
+def test_blocks_a_visit_follow_the_blocks_bytes():
+    """``visit_blocks``: about ``VISIT_BYTES`` of K and V a step, from the
+    arena's shape alone. The served shapes at 64-token blocks of 128:
+    8 kv heads in bf16 (262 KB a block) take four a step, 16 kv heads
+    (524 KB) two, a block over the budget one, and no shape more than
+    ``MAX_VISIT_BLOCKS``; a slab and the whole arena agree."""
+    def arena(hkv, dtype, bs=64, layers=None):
+        shape = (9, hkv, bs, 128) if layers is None else (layers, 9, hkv,
+                                                          bs, 128)
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    assert VISIT_BYTES == 1 << 20 and MAX_VISIT_BLOCKS == 4
+    assert visit_blocks(arena(8, jnp.bfloat16)) == 4
+    assert visit_blocks(arena(8, jnp.bfloat16, layers=5)) == 4
+    assert visit_blocks(arena(16, jnp.bfloat16)) == 2
+    assert visit_blocks(arena(16, jnp.bfloat16, layers=12)) == 2
+    assert visit_blocks(arena(8, jnp.int8)) == 4            # capped
+    assert visit_blocks(arena(16, jnp.float32)) == 1
+    assert visit_blocks(arena(32, jnp.float32, bs=128)) == 1
+
+
+# Live blocks a slot: 1, P-1, P, P+1, then an odd and an even count over
+# two visits and more; ``block_size`` 32.
+def _live_counts(per):
+    return (1, max(per - 1, 1), per, per + 1, 2 * per + 1, 2 * per + 2)
+
+
+def _positions_for(counts, bs=32):
+    """A position inside each slot's ``count``-th block: its last row,
+    its first, then rows in between."""
+    rows = [bs - 1, 0, 5, 17, bs - 2, 9]
+    return [(n - 1) * bs + rows[i % len(rows)] for i, n in enumerate(counts)]
+
+
+def _visits_for(ak, tables, pos, per, limits=None, window=0):
+    return paged_visits(tables, pos, limits, block_size=ak.shape[-2],
+                        per_visit=per, window=window)
+
+
+@pytest.mark.parametrize("per", [2, 4])
+@pytest.mark.parametrize("hq,hkv", [(32, 8), (16, 16)])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("layered", [False, True])
+def test_visits_of_several_blocks_equal_every_entry_walk(
+        pallas_interpret, hq, hkv, kv_dtype, layered, per):
+    """A grid step covers up to ``per`` consecutive blocks of a slot and
+    attends them one after the other: the one-block walk's blocks in
+    the walk's order, so its bits, whether a slot's live count is
+    under, at or over a whole number of visits (a full visit and a
+    short one are two bodies of the kernel)."""
+    from chip_smoke import walk_every_entry
+
+    counts = _live_counts(per)
+    q, _, _, ak, av, tables, _ = _paged_inputs(
+        b=len(counts), hq=hq, hkv=hkv, nb_slot=max(counts),
+        seed=13, dtype=jnp.bfloat16)
+    kw = {}
+    if kv_dtype == "int8":
+        ak, kw["k_scale"] = quantize_kv(ak)
+        av, kw["v_scale"] = quantize_kv(av)
+    if layered:
+        ak, av = jnp.stack([av, ak, av]), jnp.stack([ak, av, ak])
+        kw = {n: jnp.stack([a * 2, a, a * 3]) for n, a in kw.items()}
+        kw["layer"] = jnp.int32(1)
+    pos = jnp.asarray(_positions_for(counts), jnp.int32)
+    out = paged_decode_attention(
+        q, ak, av, tables, pos, use_kernel=True,
+        visits=_visits_for(ak, tables, pos, per), **kw)
+    np.testing.assert_array_equal(
+        np.asarray(out),
+        np.asarray(walk_every_entry(q, ak, av, tables, pos, **kw)))
+    ref = paged_attention_reference(q, ak, av, tables, pos, **kw)
+    np.testing.assert_allclose(np.asarray(out, jnp.float32),
+                               np.asarray(ref, jnp.float32), atol=3e-2)
+
+
+@pytest.mark.parametrize("per", [2, 4])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("freed", ["odd", "even"])
+def test_short_visits_between_freed_slots(pallas_interpret, kv_dtype, freed,
+                                          per):
+    """Live slots of every count interleaved with freed ones, the
+    garbage block NaN: a freed slot has no visit and a zero row, a short
+    last visit's dead sub-blocks are never read into the arithmetic, and
+    every live row is the walk's, bit for bit."""
+    from chip_smoke import walk_every_entry
+
+    counts = [n for n in _live_counts(per) for _ in (0, 1)]
+    live = np.arange(len(counts)) % 2 == (freed == "odd")
+    q, _, _, ak, av, tables, _ = _paged_inputs(
+        b=len(counts), hq=32, hkv=8, nb_slot=max(counts), seed=17,
+        dtype=jnp.bfloat16)
+    kw = {}
+    if kv_dtype == "int8":
+        ak, kw["k_scale"] = quantize_kv(ak)
+        av, kw["v_scale"] = quantize_kv(av)
+        kw = {n: a.at[GARBAGE_BLOCK].set(jnp.nan) for n, a in kw.items()}
+    else:
+        ak, av = (a.at[GARBAGE_BLOCK].set(jnp.nan) for a in (ak, av))
+    # A live slot's dead tail repeats its last live block (`_table_row`);
+    # a freed slot's row is the garbage block throughout, position 0.
+    t = np.asarray(tables).copy()
+    for i, n in enumerate(counts):
+        t[i, n:] = t[i, n - 1]
+    tables = jnp.where(live[:, None], jnp.asarray(t), GARBAGE_BLOCK)
+    pos = jnp.where(live, jnp.asarray(_positions_for(counts), jnp.int32), 0)
+    limits = jnp.asarray(live * max(counts) * 32, jnp.int32)
+    out = np.asarray(paged_decode_attention(
+        q, ak, av, tables, pos, use_kernel=True,
+        visits=_visits_for(ak, tables, pos, per, limits), **kw), np.float32)
+    want = np.asarray(walk_every_entry(q, ak, av, tables, pos, **kw),
+                      np.float32)
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out[live], want[live])
+    np.testing.assert_array_equal(out[~live], 0.0)
+
+
+# A ring of 6 entries of 32 under a window of 4 blocks and 7 keys: a
+# query sees 5 blocks, or 6 where the window's first key lies deep
+# enough in its block.
+_RING_WINDOW, _RING = 4 * 32 + 7, 6
+
+
+def _ring_positions(per):
+    """Query positions over a ring: under the window (first live block
+    0); first live block 1, P+1 and 2P+1 (not multiples of P for P > 1)
+    before the ring's first wrap, at it and several wraps on; six live
+    blocks and five."""
+    bs, w = 32, _RING_WINDOW
+    # First live block f <=> pos - w + 1 in [f * bs, (f + 1) * bs).
+    firsts = [1, per + 1, 2 * per + 1, 7 * _RING + per + 1]
+    return [5, w - 1] + [f * bs + w - 1 + r
+                         for f, r in zip(firsts, (0, 30, 12, 31))]
+
+
+@pytest.mark.parametrize("per", [2, 4])
+@pytest.mark.parametrize("hq,hkv", [(32, 8), (16, 16)])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("layered", [False, True])
+def test_ring_visits_equal_the_walk_from_the_first_live_block(
+        pallas_interpret, hq, hkv, kv_dtype, layered, per):
+    """Over a ring a slot's visits start at its FIRST live block, which
+    no multiple of ``per`` need be, and a visit's blocks lie in ring
+    entries ``block % nb``, so a visit may straddle the ring's end:
+    still the one-block walk's bits, before the first wrap and after,
+    with a window that is no multiple of the block."""
+    from chip_smoke import walk_every_entry
+
+    pos = _ring_positions(per)
+    q, _, _, ak, av, tables, _ = _paged_inputs(
+        b=len(pos), hq=hq, hkv=hkv, nb_slot=_RING, seed=19,
+        dtype=jnp.bfloat16)
+    kw = {"window": _RING_WINDOW}
+    if kv_dtype == "int8":
+        ak, kw["k_scale"] = quantize_kv(ak)
+        av, kw["v_scale"] = quantize_kv(av)
+    if layered:
+        ak, av = jnp.stack([av, ak, av]), jnp.stack([ak, av, ak])
+        kw.update({n: jnp.stack([kw[n] * 2, kw[n], kw[n] * 3])
+                   for n in ("k_scale", "v_scale") if n in kw})
+        kw["layer"] = jnp.int32(1)
+    firsts = [max(p - _RING_WINDOW + 1, 0) // 32 for p in pos]
+    assert any(f % per for f in firsts)
+    assert any(p // 32 < _RING for p in pos[2:])        # before a wrap
+    assert any(f >= _RING for f in firsts)              # and after one
+    pos = jnp.asarray(pos, jnp.int32)
+    out = paged_decode_attention(
+        q, ak, av, tables, pos, use_kernel=True,
+        visits=_visits_for(ak, tables, pos, per, window=_RING_WINDOW), **kw)
+    np.testing.assert_array_equal(
+        np.asarray(out),
+        np.asarray(walk_every_entry(q, ak, av, tables, pos, **kw)))
+    ref = paged_attention_reference(q, ak, av, tables, pos, **kw)
+    np.testing.assert_allclose(np.asarray(out, jnp.float32),
+                               np.asarray(ref, jnp.float32), atol=3e-2)
+
+
+@pytest.mark.parametrize("per", [1, 2, 3, 4])
+def test_ring_visit_schedule_runs_from_the_first_live_block(per):
+    pos = _ring_positions(per) + [40]
+    limits = [1] * (len(pos) - 1) + [0]                 # the last freed
+    tables = jnp.arange(1, len(pos) * _RING + 1, dtype=jnp.int32).reshape(
+        len(pos), _RING)
+    visits = paged_visits(tables, jnp.asarray(pos, jnp.int32),
+                          jnp.asarray(limits, jnp.int32), block_size=32,
+                          per_visit=per, window=_RING_WINDOW)
+    want = _expected_visits(tables, pos, limits, 32, per, _RING_WINDOW)
+    assert {len(b) for _, _, b in want} >= {1, min(per, 2)}
+    _check_schedule(visits, want, tables, per)
 
 
 # ------------------------------------- whole arena: layer index, in-place write

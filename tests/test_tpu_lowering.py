@@ -227,9 +227,11 @@ def test_compiled_paged_tick_moves_no_arena_slab(v5e_chip, kv_dtype):
     assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == writes
     assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 1
     # Scratch: the kernel's schedule, made once before the layer loop
-    # (48 x 32 visits against 48 slot ends), and the zeroed output it
-    # fills in, 0.7 MB together since PR 26; an int8 arena adds the
-    # relayout of its scale sidecars. Not one block-row of a slab more
+    # (48 x 8 visits of four blocks against 48 slot ends, in fusions
+    # and no gather: a gather's index vectors pad to 128 lanes, 0.4 MB
+    # each), and the zeroed output it fills in, 0.7-0.8 MB together
+    # since PR 26 (782,848 and 879,616 bytes at PR 33); an int8 arena
+    # adds the relayout of its scale sidecars. Not one block-row of a slab more
     # (0 and 1,408,512 bytes at PR 25, whose kernel had no schedule).
     scratch = compiled.memory_analysis().temp_size_in_bytes
     assert scratch <= (1 << 20) + (1_408_512 if kv_dtype == "int8" else 0)
@@ -237,10 +239,15 @@ def test_compiled_paged_tick_moves_no_arena_slab(v5e_chip, kv_dtype):
     # hoist it, so nothing there may compute a visit list.
     body = next(c for c in hlo.split("\n\n")
                 if re.search(r"%paged_decode_attn[.\d]* = ", c))
-    visits = _TICK_SLOTS * (_TICK_LEN // _TICK_BS)
-    assert [line.strip() for line in body.splitlines()
-            if f"= s32[{visits}]" in line
-            and " get-tuple-element(" not in line] == []
+    # (Slot and first block a visit; a table entry a visit and sub-block,
+    # which is also the flattened tables' length.)
+    per = 4         # 8 kv heads x 64 x 128 in bf16, and int8 at the cap
+    visits = _TICK_SLOTS * -(-(_TICK_LEN // _TICK_BS) // per) + 1
+    listed = [line.strip() for line in body.splitlines()
+              if re.search(rf"= s32\[({visits}|{visits * per})\]", line)]
+    assert len(listed) >= 3
+    assert [line for line in listed
+            if " get-tuple-element(" not in line] == []
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
@@ -570,6 +577,21 @@ def test_compiled_window_tick_moves_no_ring_slab_and_no_expert_weight(
     assert len(re.findall(r"%moe_gmm[.\d]* = ", hlo)) == 9
     assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 4
     assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == 8
+    # Both schedules (the rings' and the table's) are made once, in the
+    # entry computation (which also holds the runs of one layer): the
+    # layer loop's body takes them as loop state, and at most moves a
+    # list between memory spaces or flattens the rings' iota table.
+    lists = "|".join(str((slots * -(-n // 4) + 1) * per)
+                     for n in (66, table) for per in (1, 4))
+    loops = [c for c in hlo[:hlo.index("\nENTRY ")].split("\n\n")
+             if re.search(r"%paged_decode_attn[.\d]* = ", c)]
+    assert len(loops) == 1          # the three sliding routed layers
+    listed = [line.strip() for line in loops[0].splitlines()
+              if re.search(rf"= s32\[({lists})\]", line)]
+    assert len(listed) >= 3
+    assert [line for line in listed if not any(
+        op in line for op in (" get-tuple-element(", " copy-start(",
+                              " copy-done(", " reshape("))] == []
     header = hlo[:hlo.index("\n")]
     aliased = re.findall(r"\{[\d, ]*\}: \((\d+), ", header)
     assert len(aliased) == 4        # arena K, V; ring K, V
