@@ -233,12 +233,56 @@ SERVE_REQ_TPOT = Histogram(
     boundaries=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                 0.25, 0.5, 1.0),
     tag_keys=_REQ_TAGS)
+SERVE_REQ_DECODE_STALL = Histogram(
+    "ray_tpu_serve_request_decode_stall_seconds",
+    "Seconds of a request's life after its first token that went to "
+    "other requests' prefill batches, during which its stream stood "
+    "still (one observation a request; stalled_s in request_breakdowns)",
+    boundaries=(0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0),
+    tag_keys=_REQ_TAGS)
 SERVE_REQ_OUTCOMES = Counter(
     "ray_tpu_serve_request_outcomes_total",
     "Engine request terminations by outcome "
     "(finished/evicted/aborted/prefilled — prefilled is a prefill-role "
     "engine parking the request for KV handoff at its first token)",
     _REQ_TAGS + ("outcome",))
+
+# A streamed token's way back, hop by hop, on ``time.time()`` (the request
+# chain's clock). Each stream sums on locals and flushes here at its end
+# and every 64 items: never once a token or once a pull. The replica's
+# three are tagged with its engine, the ingress's with the deployment.
+SERVE_STREAM_HANDOFF_SECONDS = Counter(
+    "ray_tpu_serve_stream_handoff_seconds_total",
+    "Streamed tokens' seconds from landing on the host (the engine's "
+    "stamp of the tick or prefill fetch) to the replica's generator "
+    "thread taking them off the request's queue: tick thread -> "
+    "generator thread",
+    ("engine",))
+SERVE_STREAM_STORE_SECONDS = Counter(
+    "ray_tpu_serve_stream_store_seconds_total",
+    "Streamed tokens' seconds from the replica generator's yield to its "
+    "resumption: the runtime stored and announced the item",
+    ("engine",))
+SERVE_STREAM_REPLICA_ITEMS = Counter(
+    "ray_tpu_serve_stream_replica_items_total",
+    "Tokens the replica's generators yielded (the items the handoff and "
+    "store seconds are over)",
+    ("engine",))
+SERVE_STREAM_LOOP_SECONDS = Counter(
+    "ray_tpu_serve_stream_loop_seconds_total",
+    "Ingress seconds in a pull's two thread hops, neither of which waits "
+    "for the engine: run_in_executor called -> the pull starts on a pool "
+    "thread, and the pull returned -> the loop resumes",
+    ("deployment",))
+SERVE_STREAM_PULLS = Counter(
+    "ray_tpu_serve_stream_pulls_total",
+    "Pulls of streamed responses that brought items (one write each)",
+    ("deployment",))
+SERVE_STREAM_ITEMS = Counter(
+    "ray_tpu_serve_stream_items_total",
+    "Items streamed responses wrote; over the pulls it is the burst: 1.0 "
+    "is token by token",
+    ("deployment",))
 
 # ------------------------------- disaggregated prefill/decode handoff (L6)
 # The KV-block transfer plane between prefill and decode replicas: every
@@ -507,6 +551,61 @@ CB_STEP_APPLY_MS = Histogram(
     "into the stream queues, finish detection, end-of-stream puts "
     "(span engine.apply)",
     boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
+# The same thread's timeline seen from the DEVICE (sync_every == 1): from
+# the moment a landing leaves nothing queued on the device to the next
+# dispatch, the device waits for this thread. One histogram a cause, one
+# observation an interval, on ``time.perf_counter()`` like the tick's
+# clock; with ``ray_tpu_cb_tick_ms`` and ``ray_tpu_cb_prefill_ms`` the
+# four sums partition the thread's wall time.
+CB_STARVED_AFTER_PREFILL_MS = Histogram(
+    "ray_tpu_cb_starved_after_prefill_ms",
+    "Device-empty milliseconds from a prefill's first tokens landing to "
+    "the dispatch of the next decode tick: the slot-state uploads, the "
+    "token merge and the dispatch itself",
+    boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
+CB_STARVED_TICK_LATE_MS = Histogram(
+    "ray_tpu_cb_starved_tick_late_ms",
+    "Device-empty milliseconds from a decode tick's row landing with "
+    "nothing queued behind it to the dispatch of the next tick: the "
+    "engine lock, booking, admission's host part, the interpreter lock",
+    boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
+CB_STARVED_BEFORE_PREFILL_MS = Histogram(
+    "ray_tpu_cb_starved_before_prefill_ms",
+    "Device-empty milliseconds up to the dispatch of a prefill's first "
+    "program: waking, admission and building and uploading its arguments "
+    "with nothing queued on the device",
+    boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
+CB_IDLE_NO_WORK_MS = Histogram(
+    "ray_tpu_cb_idle_no_work_ms",
+    "Device-empty milliseconds with no live slot and nothing waiting, up "
+    "to the next request's arrival: not starved, booked so that the "
+    "thread's timeline adds up",
+    boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
+# The same timeline seen from a LIVE SLOT (a request past its first
+# token): every millisecond of it is one or the other.
+CB_SLOT_ADVANCING_MS = Counter(
+    "ray_tpu_cb_slot_advancing_ms_total",
+    "Slot-milliseconds inside a decode tick that advances the slot: each "
+    "tick's ray_tpu_cb_tick_ms times its members",
+    ("engine",))
+CB_SLOT_STALLED_MS = Counter(
+    "ray_tpu_cb_slot_stalled_ms_total",
+    "Slot-milliseconds a live slot stood still: each prefill batch's "
+    "ray_tpu_cb_prefill_ms times the slots live before it, and each "
+    "device-starved interval times the slots live in it",
+    ("engine",))
+CB_PREFILL_PADDED_ROWS = Counter(
+    "ray_tpu_cb_prefill_padded_rows_total",
+    "Rows the prefill programs ran, a batch counted once: the batch's "
+    "requests padded to a power of two (beside "
+    "ray_tpu_cb_prefill_requests_total, the real rows)",
+    ("engine",))
+CB_PREFILL_PADDED_TOKENS = Counter(
+    "ray_tpu_cb_prefill_padded_tokens_total",
+    "Token positions the prefill programs ran: padded rows x padded "
+    "length x chunks (beside ray_tpu_cb_prefill_tokens_total, the real "
+    "tokens)",
+    ("engine",))
 CB_PREFILL_REQUESTS = Counter(
     "ray_tpu_cb_prefill_requests_total",
     "Requests admitted into KV slots via (batched bucketed) prefill",

@@ -81,6 +81,82 @@ def build_llama_app(config: Optional[llama.LlamaConfig] = None,
 __all__ = ["LlamaDeployment", "build_llama_app"]
 
 
+class _StreamLag:
+    """One stream's tokens on their way out of the replica, on
+    ``time.time()`` (the request chain's clock): ``handoff`` from a
+    token's landing on the host (the engine's stamp) to the generator
+    thread taking it off the request's queue, ``store`` from the
+    generator's yield to its resumption (the runtime stored and
+    announced the item). A token costs two clock reads and a few float
+    adds on this object; the metrics registry is touched at the stream's
+    end and every ``FLUSH_EVERY`` items, never once a token."""
+
+    FLUSH_EVERY = 64
+    __slots__ = ("items", "handoff_s", "handoff_max_s", "store_s",
+                 "first_landed", "last_landed", "last_got", "_tags",
+                 "_flushed")
+
+    def __init__(self, tags: Dict[str, str]):
+        self._tags = tags
+        self.items = 0
+        self.handoff_s = self.handoff_max_s = self.store_s = 0.0
+        self.first_landed = self.last_landed = self.last_got = 0.0
+        self._flushed = (0, 0.0, 0.0)   # items, handoff_s, store_s
+
+    def note(self, landed: float, got: float, resumed: float) -> None:
+        """A token that landed at ``landed`` left the queue at ``got``
+        and the generator was resumed after its yield at ``resumed``."""
+        handoff = got - landed
+        self.handoff_s += handoff
+        if handoff > self.handoff_max_s:
+            self.handoff_max_s = handoff
+        self.store_s += resumed - got
+        if not self.items:
+            self.first_landed = landed
+        self.last_landed, self.last_got = landed, got
+        self.items += 1
+        if self.items % self.FLUSH_EVERY == 0:
+            self.flush()
+
+    @property
+    def handoff_mean_s(self) -> float:
+        return self.handoff_s / self.items if self.items else 0.0
+
+    def flush(self) -> None:
+        from ray_tpu._private import metrics_defs as mdefs
+
+        items, handoff_s, store_s = self._flushed
+        if self.items == items:
+            return
+        mdefs.SERVE_STREAM_REPLICA_ITEMS.inc(self.items - items,
+                                             tags=self._tags)
+        mdefs.SERVE_STREAM_HANDOFF_SECONDS.inc(self.handoff_s - handoff_s,
+                                               tags=self._tags)
+        mdefs.SERVE_STREAM_STORE_SECONDS.inc(self.store_s - store_s,
+                                             tags=self._tags)
+        self._flushed = (self.items, self.handoff_s, self.store_s)
+
+    def close(self, trace: Optional[Dict[str, Any]]) -> None:
+        """The stream is over: flush, and for a traced request close its
+        chain on the replica's side with one summary span,
+        ``engine.stream`` (first landing to last dequeue)."""
+        self.flush()
+        if trace is None or not self.items:
+            return
+        from ray_tpu.util import tracing
+
+        tracing.emit_span(
+            "engine.stream", trace_id=trace.get("trace_id", ""),
+            parent_span_id=trace.get("parent_span_id", ""),
+            ts=self.first_landed, dur=self.last_got - self.first_landed,
+            kind="engine", request_id=trace.get("request_id", ""),
+            tokens=self.items, handoff_mean_s=self.handoff_mean_s,
+            handoff_max_s=self.handoff_max_s,
+            store_mean_s=self.store_s / self.items,
+            landed_first_ts=self.first_landed,
+            landed_last_ts=self.last_landed)
+
+
 @serve.deployment
 class ContinuousLlamaDeployment:
     """Continuous-batching completion replica (reference: the vLLM engine
@@ -185,9 +261,12 @@ class ContinuousLlamaDeployment:
                          name="llm-ticks").start()
 
     def _on_token(self, rid: int, token: int) -> None:
+        # Called on the tick thread as a landing's tokens are booked:
+        # the token rides with that landing's stamp, for the stream's
+        # handoff clock (``_StreamLag``).
         q = self._queues.get(rid)
         if q is not None:
-            q.put(token)
+            q.put((token, self.batcher.landed_ts))
 
     def _tick_loop(self) -> None:
         import logging
@@ -305,6 +384,18 @@ class ContinuousLlamaDeployment:
         mutates."""
         with self._lock:
             return self.batcher.pressure_snapshot()
+
+    def request_breakdowns(self, n: int = 100) -> List[Dict[str, Any]]:
+        """The newest ``n`` ended requests' records, oldest first: why
+        was a request slow? Before its first token ``lock_wait_s``,
+        ``queue_s``, ``arena_wait_s``, ``prefill_s`` (``ttft_s``); after
+        it ``tpot_s``, ``stalled_s`` / ``stall_count`` (other requests'
+        prefill batches it stood still through) and ``handoff_mean_s``
+        (its tokens' mean lag from landing to leaving this replica's
+        queue); with ``request_id`` and ``trace_id`` to find its spans."""
+        with self._lock:
+            recs = list(self.batcher.request_breakdowns)
+        return [dict(rec) for rec in recs[max(len(recs) - int(n), 0):]]
 
     # ---------------------------------------- RL weight-sync plane (rl/)
     def weight_version(self) -> int:
@@ -470,6 +561,7 @@ class ContinuousLlamaDeployment:
         self._work.set()
         done = False
         emitted = 0
+        lag = _StreamLag(self.batcher._mtags)
         try:
             while True:
                 token = q.get(timeout=300)
@@ -482,6 +574,8 @@ class ContinuousLlamaDeployment:
                 if isinstance(token, dict):     # a control object
                     yield token
                     continue
+                token, landed = token
+                got = time.time()
                 if chaos.enabled():
                     # Fires BEFORE the yield: a rule with token=N dies
                     # with exactly N tokens delivered downstream.
@@ -489,14 +583,24 @@ class ContinuousLlamaDeployment:
                                  token=emitted)
                 emitted += 1
                 yield token
+                lag.note(landed, got, time.time())
         finally:
-            self._queues.pop(rid, None)
+            self._stream_ended(rid, lag, trace, done)
+
+    def _stream_ended(self, rid: int, lag: _StreamLag,
+                      trace: Optional[Dict[str, Any]], done: bool) -> None:
+        """A token stream's generator is closing: book its lag, and
+        either keep it beside the request's record or, for a stream
+        abandoned before its end (client disconnect, simulated process
+        death), free the slot so the ghost request stops burning decode
+        ticks."""
+        self._queues.pop(rid, None)
+        lag.close(trace)
+        with self._lock:
             if not done:
-                # Abandoned stream (client disconnect or simulated
-                # process death): free the slot so the ghost request
-                # stops burning decode ticks.
-                with self._lock:
-                    self.batcher.cancel(rid)
+                self.batcher.cancel(rid)
+            elif lag.items:
+                self.batcher.note_stream(rid, lag.handoff_mean_s)
 
     # ------------------------------------ disaggregated prefill/decode
     def _req_deployment(self) -> str:
@@ -553,7 +657,7 @@ class ContinuousLlamaDeployment:
                     break
                 if isinstance(item, Exception):
                     raise item
-                tokens.append(item)
+                tokens.append(item[0])
         finally:
             self._queues.pop(rid, None)
         with self._lock:
@@ -623,6 +727,7 @@ class ContinuousLlamaDeployment:
         self._work.set()
         done = False
         emitted = 0
+        lag = _StreamLag(self.batcher._mtags)
         try:
             if chaos.enabled():
                 chaos.inject("serve_replica", phase="decode", token=0)
@@ -636,16 +741,16 @@ class ContinuousLlamaDeployment:
                 if isinstance(token, Exception):
                     done = True
                     raise token
+                token, landed = token
+                got = time.time()
                 if chaos.enabled():
                     chaos.inject("serve_replica", phase="decode",
                                  token=emitted)
                 emitted += 1
                 yield token
+                lag.note(landed, got, time.time())
         finally:
-            self._queues.pop(rid, None)
-            if not done:
-                with self._lock:
-                    self.batcher.cancel(rid)
+            self._stream_ended(rid, lag, trace, done)
 
     def __call__(self, request: Dict[str, Any]) -> Dict[str, Any]:
         """Non-streaming completion."""

@@ -1088,13 +1088,11 @@ class ContinuousBatcher:
         # counters mirror them into the TSDB). With the prefix cache on,
         # ``prefill_tokens`` counts only NOVEL (suffix) tokens; the
         # hit/miss counters below split total prompt traffic.
-        self.prefill_batches = 0
         self.prefill_requests = 0
         self.prefill_tokens = 0
         self.prefix_hit_tokens = 0      # prompt tokens served from cache
         self.prefix_miss_tokens = 0     # prompt tokens actually prefilled
         self.prefix_hit_requests = 0    # requests with >=1 matched block
-        self.prefill_seconds = 0.0          # dispatch->first-token sync
         self._prefill_shapes: set = set()   # (N_pad, L_pad) compiled
         self._buf: List[Any] = []       # unstacked device token vectors
         self._pending: Optional[tuple] = None  # (stacked, [(slot, rid)])
@@ -1177,6 +1175,26 @@ class ContinuousBatcher:
         # the last row that reached the host.
         self._inflight: deque = deque()
         self._row_landed = 0.0
+        # This thread's timeline seen from the device (``sync_every`` 1
+        # alone: a buffered fetch does not say when the device ran dry).
+        # ``_empty_since``: the ``perf_counter`` moment a landing left
+        # nothing queued on the device, None while something is;
+        # ``_empty_after``: what landed then ("tick" or "prefill");
+        # ``_no_work``: no slot was live and nothing waited, so the time
+        # up to the next arrival is idle, not starved. The next dispatch
+        # books the interval by cause (:meth:`_book_empty`).
+        self._empty_since: Optional[float] = (
+            time.perf_counter() if self.sync_every == 1 else None)
+        self._empty_after = "tick"
+        self._no_work = True
+        # Running totals of the prefill batches' booked seconds and their
+        # count: a request notes both at its first token and takes the
+        # difference when it ends (``stalled_s``, ``stall_count``).
+        self._stall_s = 0.0
+        self._stall_n = 0
+        # ``time.time()`` of the landing whose tokens are being booked:
+        # what a token callback may stamp its token with.
+        self.landed_ts = 0.0
         self._prefill_count = 0   # per-dispatch prefill sampling stream
         # Buffered-mode achieved-bandwidth window: wall time and tick
         # count between consecutive fetch syncs.
@@ -1195,8 +1213,6 @@ class ContinuousBatcher:
         self._handoff_ready: Dict[int, Dict[str, Any]] = {}
         self._import_reservations: Dict[int, Dict[str, Any]] = {}
         self._reservation_ids = itertools.count()
-        self.handoff_exports = 0    # payloads exported (bench/tests)
-        self.handoff_imports = 0    # payloads imported (bench/tests)
         # Request-path telemetry: one lifecycle record per live request
         # (submit/admit/prefill/first-token/finish timestamps + the
         # caller's trace context). TTFT decomposition histograms are
@@ -1581,6 +1597,7 @@ class ContinuousBatcher:
                           rec.get("admit", rec["submit"]))
         admit = rec.get("admit", blocked)
         rec["first_token"] = first_tok_ts
+        rec["stall0"] = (self._stall_s, self._stall_n)
         rec["queue_s"] = max(blocked - rec["submit"], 0.0)
         rec["arena_wait_s"] = max(admit - blocked, 0.0)
         rec["prefill_s"] = max(first_tok_ts - prefill_t0, 0.0)
@@ -1625,6 +1642,13 @@ class ContinuousBatcher:
             # 3, so TPOT stays honest under multi-token ticks.
             tpot = max(now - first, 0.0) / (tokens - 1)
             mdefs.SERVE_REQ_TPOT.observe(tpot, tags=tags)
+        stalled_s = stall_count = None
+        if first is not None:
+            # Other requests' prefill batches since this one's first
+            # token: its stream stood still through each.
+            stalled_s = self._stall_s - rec["stall0"][0]
+            stall_count = self._stall_n - rec["stall0"][1]
+            mdefs.SERVE_REQ_DECODE_STALL.observe(stalled_s, tags=tags)
         trace = rec.get("trace") or {}
         self.request_breakdowns.append({
             "rid": rid, "outcome": outcome, "tokens": tokens,
@@ -1633,6 +1657,10 @@ class ContinuousBatcher:
             "arena_wait_s": rec.get("arena_wait_s"),
             "prefill_s": rec.get("prefill_s"),
             "ttft_s": rec.get("ttft_s"), "tpot_s": tpot,
+            "stalled_s": stalled_s, "stall_count": stall_count,
+            # Filled in by the replica when the request's stream ends
+            # (:meth:`note_stream`); None for a request no stream read.
+            "handoff_mean_s": None,
             "prefix_tokens": rec.get("prefix_tokens", 0),
             "prompt_tokens": rec.get("prompt_len", 0),
             "weight_version": rec.get("weight_version"),
@@ -1662,7 +1690,18 @@ class ContinuousBatcher:
                               dur=max(tail[1] - tail[0], 0.0),
                               tokens=tail[2], windows=tail[3], **common)
         tracing.emit_span(f"engine.{outcome}", ts=now, dur=0.0,
-                          tokens=tokens, **common)
+                          tokens=tokens, stalled_s=stalled_s,
+                          stall_count=stall_count, **common)
+
+    def note_stream(self, rid: int, handoff_mean_s: float) -> None:
+        """The replica's generator read ``rid``'s stream to its end:
+        keep the mean lag of its tokens from landing to leaving the
+        request's queue beside the request's breakdown record, if the
+        request has ended (a stream cut short has no record yet)."""
+        for rec in reversed(self.request_breakdowns):
+            if rec["rid"] == rid:
+                rec["handoff_mean_s"] = handoff_mean_s
+                return
 
     def pressure_snapshot(self) -> Dict[str, Any]:
         """Live engine pressure — the router/autoscaler input: queue
@@ -1840,7 +1879,36 @@ class ContinuousBatcher:
                               "prompt": list(prompt_tokens),
                               "max_new": max_new_tokens,
                               "routes": [] if keep_routes else None})
+        self._work_arrived()
         return rid
+
+    def _work_arrived(self) -> None:
+        """A request reached an engine that had none: the device's idle
+        time up to now was for want of work, from now on it waits for
+        this thread."""
+        if not self._no_work:
+            return
+        self._no_work = False
+        if self._empty_since is not None:
+            from ray_tpu._private import metrics_defs as mdefs
+
+            now = time.perf_counter()
+            self._book_empty(now, mdefs.CB_IDLE_NO_WORK_MS)
+            self._empty_since = now
+
+    def _book_empty(self, now: float, hist) -> None:
+        """The device had nothing queued from ``_empty_since`` to
+        ``now``: one observation in the cause's histogram, and the
+        interval once for each live slot, which stood still through it.
+        The caller says what follows (``_empty_since``: None when it
+        dispatches a program)."""
+        from ray_tpu._private import metrics_defs as mdefs
+
+        ms = max(now - self._empty_since, 0.0) * 1e3
+        hist.observe(ms, tags=self._mtags)
+        if self._slots:
+            mdefs.CB_SLOT_STALLED_MS.inc(ms * len(self._slots),
+                                         tags=self._mtags)
 
     def _release_slot(self, slot: int) -> None:
         self._free.append(slot)
@@ -1903,6 +1971,8 @@ class ContinuousBatcher:
         # copy of the decode state (it may be the output of the program
         # that failed).
         self._inflight.clear()
+        self._device_empty(time.perf_counter(), "tick")
+        self._no_work = True
         self._d_tokens = self._d_members = None
         # Parked handoffs and import reservations die with the arena
         # (allocator.reset below reclaims their blocks wholesale).
@@ -2057,7 +2127,6 @@ class ContinuousBatcher:
             "crc32": zlib.crc32(staging),
         }
         self._release_handoff_blocks(entry)
-        self.handoff_exports += 1
         return payload
 
     def reserve_import(self, prompt_len: int,
@@ -2205,7 +2274,8 @@ class ContinuousBatcher:
             if created:
                 self._slot_nodes[slot] = created
         first = int(payload["first_token"])
-        now = time.time()
+        self._work_arrived()
+        now = self.landed_ts = time.time()
         self._note_first_token(meta, t0, now)
         if meta.get("handoff") is not None:
             meta["handoff"]["import_s"] = now - t0
@@ -2222,7 +2292,6 @@ class ContinuousBatcher:
             # re-prefills the full prompt locally (cheap vs the target).
             self._run_draft_prefill([(slot, prompt)])
         self._dirty = True
-        self.handoff_imports += 1
         return rid
 
     # ------------------------------------------------------------ paged kv
@@ -2563,6 +2632,7 @@ class ContinuousBatcher:
             n_pad = min(_bucket(n, floor=1), self.num_slots)
             npb_w = padded_len // bs
             rows = [group[min(i, n - 1)] for i in range(n_pad)]
+            live_before = len(self._slots)  # streams that stand still now
             pt0 = time.time()  # wall-clock anchor for the prefill span
             with tracing.phase("engine.prefill", mdefs.CB_PREFILL_MS,
                                self._mtags, outer=admit) as prefill:
@@ -2596,21 +2666,33 @@ class ContinuousBatcher:
                             ptables[i, :mc] = blocks[:mc]
                         pstep = self._place(np.int32(self._prefill_count))
                         self._prefill_count += 1
-                        first, caches = self._prefill(
-                            self.params, self._place(tokens),
-                            self._caches(), self._place(ptables),
-                            self._place(tables_w), self._place(last_idx),
-                            pstep, slots)
+                        args = (self._place(tokens), self._caches(),
+                                self._place(ptables), self._place(tables_w),
+                                self._place(last_idx), pstep, slots)
+                        if ci == 0:
+                            # The arguments are on the device: from here
+                            # it has a program to run.
+                            dispatched = time.perf_counter()
+                        first, caches = self._prefill(self.params, *args)
                         self.cache, self.state = _split_caches(caches)
                         firsts.append(first)
                 # The programs queue behind the tick in flight, whose
                 # row reaches the host first: land it here, on its own
                 # clock. The device starts the prefill at that moment,
-                # so the prefill's clock does too.
+                # so the prefill's clock does too. With nothing in
+                # flight the device stood empty until the first program
+                # was dispatched: that wait has a clock of its own.
                 queued = [t for t in self._inflight if t["wall"] is None]
                 for tick in queued:
-                    self._land(tick)
-                behind = prefill.elapsed_ms() if queued else 0.0
+                    self._land(tick, prefill_behind=True)
+                behind = 0.0
+                if queued:
+                    behind = prefill.elapsed_ms()
+                elif self._empty_since is not None:
+                    self._book_empty(dispatched,
+                                     mdefs.CB_STARVED_BEFORE_PREFILL_MS)
+                    self._empty_since = None
+                    behind = (dispatched - prefill.t0) * 1e3
                 prefill.exclude(behind)
                 with _annotation("engine.prefill.fetch"):
                     # Only the last chunk's N ints are first tokens and
@@ -2625,27 +2707,37 @@ class ContinuousBatcher:
                                 first = np.asarray(first)
                             else:
                                 first.block_until_ready()
-            # The fetch syncs the dispatch, so this interval is the real
-            # prefill cost — bench_serve derives prefill tokens/s from
-            # it without decode/queueing time polluting the denominator,
-            # and the XLA monitor turns it into achieved-FLOPs/bandwidth
-            # gauges against this bucket's compiler cost analysis.
-            prefill_wall = (prefill.ms - behind) / 1e3
-            self.prefill_seconds += prefill_wall
+            # The fetch syncs the dispatch, so this interval (what
+            # ``CB_PREFILL_MS`` just booked) is the programs' device
+            # time: the XLA monitor turns it into achieved-FLOPs and
+            # bandwidth gauges against this bucket's cost analysis, and
+            # every stream that was live stood still through it.
+            self._device_empty(prefill.t1, "prefill")
+            prefill_ms = prefill.ms - behind
+            self._stall_s += prefill_ms / 1e3
+            self._stall_n += 1
+            if live_before:
+                mdefs.CB_SLOT_STALLED_MS.inc(prefill_ms * live_before,
+                                             tags=self._mtags)
             with tracing.phase("engine.account", mdefs.CB_STEP_ACCOUNT_MS,
                                self._mtags, outer=admit):
-                self._prefill.note_execution(prefill_wall / n_chunks)
+                self._prefill.note_execution(prefill_ms / 1e3 / n_chunks)
             self._prefill_shapes.add((n_pad, padded_len))
             true_tokens = sum(len(row[4]) for row in group)
-            self.prefill_batches += 1
             self.prefill_requests += n
             self.prefill_tokens += true_tokens
             mdefs.CB_PREFILL_REQUESTS.inc(n, tags=self._mtags)
             mdefs.CB_PREFILL_TOKENS.inc(true_tokens, tags=self._mtags)
+            # What the programs ran beside those: rows padded to a power
+            # of two, lengths to the bucket or the chunk.
+            mdefs.CB_PREFILL_PADDED_ROWS.inc(n_pad, tags=self._mtags)
+            mdefs.CB_PREFILL_PADDED_TOKENS.inc(
+                n_pad * padded_len * n_chunks, tags=self._mtags)
             if self.config.state_layers:
                 self.state_installs += n
                 mdefs.CB_STATE_INSTALLS.inc(n, tags=self._mtags)
-            first_ts = time.time()  # the fetch above synced the device
+            # The fetch above synced the device: the first tokens landed.
+            first_ts = self.landed_ts = time.time()
             for (req, slot, blocks, matched, _sfx, chunks), tok in \
                     zip(group, first):
                 tok = int(tok)
@@ -3086,6 +3178,13 @@ class ContinuousBatcher:
             self._upload_state(members)
         w0 = time.time() if self._traced_live else None
         t0 = time.perf_counter()
+        if self._empty_since is not None:
+            # The device had nothing queued: it waited for this thread,
+            # through the restart after a prefill or after its last tick.
+            self._book_empty(t0, mdefs.CB_STARVED_AFTER_PREFILL_MS
+                             if self._empty_after == "prefill"
+                             else mdefs.CB_STARVED_TICK_LATE_MS)
+            self._empty_since = None
         with _annotation("engine.tick.dispatch"):
             row = self._run_tick()
             for part in (row if isinstance(row, tuple) else (row,)):
@@ -3099,14 +3198,24 @@ class ContinuousBatcher:
                                "k": self._last_tick_k, "t0": t0,
                                "w0": w0, "wall": None})
 
-    def _land(self, tick: Dict[str, Any]) -> None:
+    def _device_empty(self, now: float, after: str) -> None:
+        """A landing at ``now`` (``after``: "tick" or "prefill") left
+        nothing queued on the device: until the next dispatch it waits
+        for this thread."""
+        if self.sync_every == 1:
+            self._empty_since, self._empty_after = now, after
+
+    def _land(self, tick: Dict[str, Any],
+              prefill_behind: bool = False) -> None:
         """Wait for ``tick``'s row to reach the host, once: 4 bytes a
         slot (a routed model's expert row counts behind them; a spec
         tick's committed window and counts). ``CB_TICK_MS`` gets the
         time between two consecutive rows landing, which in steady state
         is the device's tick (the next one is already queued behind it);
         the clock starts at the dispatch instead where the device was
-        idle before it, or ran a prefill."""
+        idle before it, or ran a prefill. Every member advanced through
+        that time. ``prefill_behind``: a prefill's programs are queued
+        behind this tick, so the device does not run dry when it ends."""
         from ray_tpu._private import metrics_defs as mdefs
 
         if tick["wall"] is not None:
@@ -3116,9 +3225,16 @@ class ContinuousBatcher:
             tick["row"] = (tuple(np.asarray(part) for part in row)
                            if isinstance(row, tuple) else np.asarray(row))
         now = time.perf_counter()
+        tick["landed_ts"] = time.time()
         tick["wall"] = now - max(self._row_landed, tick["t0"])
         self._row_landed = now
-        mdefs.CB_TICK_MS.observe(tick["wall"] * 1e3, tags=self._mtags)
+        wall_ms = tick["wall"] * 1e3
+        mdefs.CB_TICK_MS.observe(wall_ms, tags=self._mtags)
+        mdefs.CB_SLOT_ADVANCING_MS.inc(wall_ms * len(tick["members"]),
+                                       tags=self._mtags)
+        if not prefill_behind and all(
+                t["wall"] is not None for t in self._inflight):
+            self._device_empty(now, "tick")
 
     def _book_tick(self, tick: Dict[str, Any]) -> None:
         """Apply a landed tick's tokens to the requests that were its
@@ -3136,6 +3252,7 @@ class ContinuousBatcher:
                            self._mtags):
             self._note_expert_rows([tick["row"]])
             self._account_tick(tick_fn, tick["wall"], k)
+        self.landed_ts = tick["landed_ts"]
         self._apply_tokens(
             [tick["row"]], tick["members"],
             window=(tick["w0"], time.time())
@@ -3199,6 +3316,9 @@ class ContinuousBatcher:
             self._dispatch_tick(members)
         if self._inflight:
             self._book_tick(self._inflight.popleft())
+        if self._empty_since is not None and not (
+                self._slots or self._waiting):
+            self._no_work = True
         out, self._finished = self._finished, {}
         return out
 

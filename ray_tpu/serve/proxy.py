@@ -343,6 +343,76 @@ def _pull_ready(items, held: List[Any]) -> Any:
     return batch
 
 
+class _StreamPulls:
+    """One streamed response's pulls as the ingress loop sees them, on
+    ``time.time()`` (the request chain's clock): ``loop`` is a pull's
+    two thread hops, neither of which waits for the engine
+    (``run_in_executor`` called -> the pull starts on a pool thread, the
+    pull returned -> the loop resumes); items over pulls is the burst.
+    A pull costs clock reads and float adds on this object; the metrics
+    registry is touched at the stream's end and about every
+    ``FLUSH_EVERY`` items, never once a pull."""
+
+    FLUSH_EVERY = 64
+    __slots__ = ("started", "returned", "pulls", "items", "loop_s",
+                 "max_items", "first_write", "last_write", "_hops_s",
+                 "_tags", "_flushed")
+
+    def __init__(self, deployment: str):
+        self._tags = {"deployment": deployment}
+        self.started = self.returned = 0.0      # set on the pool thread
+        self.pulls = self.items = self.max_items = 0
+        self.loop_s = self._hops_s = 0.0
+        self.first_write = self.last_write = 0.0
+        self._flushed = (0, 0, 0.0)             # pulls, items, loop_s
+
+    def resumed(self, called: float, now: float) -> None:
+        """The loop, which called ``run_in_executor`` at ``called``, has
+        the pull's result at ``now``."""
+        self._hops_s = (self.started - called) + (now - self.returned)
+
+    def wrote(self, n: int, now: float) -> None:
+        """What the last pull brought, ``n`` items, went out at ``now``."""
+        self.pulls += 1
+        self.items += n
+        self.loop_s += self._hops_s
+        if n > self.max_items:
+            self.max_items = n
+        if not self.first_write:
+            self.first_write = now
+        self.last_write = now
+        if self.items - self._flushed[1] >= self.FLUSH_EVERY:
+            self.flush()
+
+    def flush(self) -> None:
+        from ray_tpu._private import metrics_defs as mdefs
+
+        pulls, items, loop_s = self._flushed
+        if self.pulls == pulls:
+            return
+        mdefs.SERVE_STREAM_PULLS.inc(self.pulls - pulls, tags=self._tags)
+        mdefs.SERVE_STREAM_ITEMS.inc(self.items - items, tags=self._tags)
+        mdefs.SERVE_STREAM_LOOP_SECONDS.inc(self.loop_s - loop_s,
+                                            tags=self._tags)
+        self._flushed = (self.pulls, self.items, self.loop_s)
+
+    def close(self, rctx: Optional[Dict[str, Any]]) -> None:
+        """The stream is over: flush, and for a traced request close its
+        chain on the ingress's side with one summary span,
+        ``serve.stream`` (first write to last)."""
+        self.flush()
+        if rctx is None or not self.pulls:
+            return
+        tracing.emit_span(
+            "serve.stream", trace_id=rctx["trace_id"],
+            parent_span_id=rctx["parent_span_id"], ts=self.first_write,
+            dur=self.last_write - self.first_write, kind="ingress",
+            request_id=rctx["request_id"],
+            deployment=rctx.get("deployment", ""), pulls=self.pulls,
+            items=self.items, max_items_per_pull=self.max_items,
+            first_write_ts=self.first_write, last_write_ts=self.last_write)
+
+
 def ingress_request_context(deployment: str, tenant: str = "",
                             request_id: str = "") -> Optional[Dict[str, Any]]:
     """Mint the serve request context at an INGRESS: a fresh trace id
@@ -623,12 +693,23 @@ class AsyncHttpProxy:
             raise
 
         held: List[Any] = []
+        pulls = _StreamPulls(name)
 
         def pull():
-            return _pull_ready(items, held)
+            pulls.started = time.time()
+            try:
+                return _pull_ready(items, held)
+            finally:
+                pulls.returned = time.time()
+
+        async def next_batch():
+            called = time.time()
+            batch = await loop.run_in_executor(self._pool, pull)
+            pulls.resumed(called, time.time())
+            return batch
 
         try:
-            first = await loop.run_in_executor(self._pool, pull)
+            first = await next_batch()
         except Exception:
             _close_ingress_span(rctx, ing_t0, "error", path)
             raise
@@ -660,7 +741,8 @@ class AsyncHttpProxy:
                     out += f"{len(chunk):x}\r\n".encode() + chunk + b"\r\n"
                 writer.write(bytes(out))
                 await writer.drain()  # backpressure: slow client, slow pull
-                batch = await loop.run_in_executor(self._pool, pull)
+                pulls.wrote(len(batch), time.time())
+                batch = await next_batch()
             if journal is not None and journal.needs_marker \
                     and not marker_sent:
                 # The sampled resume happened MID-stream (headers long
@@ -672,6 +754,7 @@ class AsyncHttpProxy:
                     {RESUMED_MARKER: journal.resumes}).encode() + b"\n"
                 writer.write(f"{len(chunk):x}\r\n".encode() + chunk
                              + b"\r\n")
+            pulls.close(rctx)       # booked before the client sees the end
             writer.write(b"0\r\n\r\n")
             await writer.drain()
             _close_ingress_span(rctx, ing_t0, 200, path)
@@ -680,6 +763,7 @@ class AsyncHttpProxy:
             # connection so the client sees truncation, not completion.
             logger.exception("streaming response for %s failed mid-stream",
                              name)
+            pulls.close(rctx)
             _close_ingress_span(rctx, ing_t0, "aborted", path)
             return False
 

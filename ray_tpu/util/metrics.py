@@ -179,6 +179,18 @@ class Histogram(Metric):
                 total += self._totals[key]
         return self._bounds, merged, total
 
+    def totals(self, tags: Optional[Dict[str, str]] = None
+               ) -> Tuple[float, int]:
+        """``(sum, count)`` of the observations, merged across every
+        label set matching ``tags`` (a subset filter; ``None`` = all):
+        what an in-process reader diffs for a mean over a stretch."""
+        want = tuple(sorted((tags or {}).items()))
+        with self._lock:
+            keys = [key for key in self._totals
+                    if all(dict(key).get(k) == v for k, v in want)]
+            return (sum(self._sums[key] for key in keys),
+                    sum(self._totals[key] for key in keys))
+
     @staticmethod
     def percentile_from(bounds: Sequence[float], counts: Sequence[int],
                         q: float) -> Optional[float]:

@@ -179,13 +179,14 @@ class phase:
     device's idle gaps against these. A phase opened with
     ``outer=<the phase around it>`` takes its time out of that one's
     observation, so the two histograms add up to the outer interval.
-    ``ms`` holds the whole elapsed time after the block. ``RAY_TPU_TRACING``
+    ``ms`` holds the whole elapsed time after the block; ``t0`` and ``t1``
+    are the ``time.perf_counter()`` it began and ended at. ``RAY_TPU_TRACING``
     does not switch it: that gates the request spans, this is a metric.
     ``jax`` is imported on first use, so the control plane can import
     this module without it."""
 
-    __slots__ = ("ms", "_hist", "_tags", "_outer", "_inner_ms",
-                 "_annotation", "_t0")
+    __slots__ = ("ms", "t1", "_hist", "_tags", "_outer", "_inner_ms",
+                 "_annotation", "t0")
     _annotate = None    # jax.profiler.TraceAnnotation, once imported
 
     def __init__(self, name: str, hist, tags: Optional[Dict[str, str]] = None,
@@ -201,12 +202,12 @@ class phase:
 
     def __enter__(self) -> "phase":
         self._annotation.__enter__()
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         return self
 
     def elapsed_ms(self) -> float:
         """Milliseconds since the block was entered."""
-        return (time.perf_counter() - self._t0) * 1e3
+        return (time.perf_counter() - self.t0) * 1e3
 
     def exclude(self, ms: float) -> None:
         """Take ``ms`` that passed inside the block out of its
@@ -214,7 +215,8 @@ class phase:
         self._inner_ms += ms
 
     def __exit__(self, *exc) -> None:
-        self.ms = (time.perf_counter() - self._t0) * 1e3
+        self.t1 = time.perf_counter()
+        self.ms = (self.t1 - self.t0) * 1e3
         self._annotation.__exit__(*exc)
         self._hist.observe(self.ms - self._inner_ms, tags=self._tags)
         if self._outer is not None:

@@ -39,6 +39,13 @@ TYPES = ("sliding_attention", "sliding_attention", "sliding_attention",
          "full_attention", "sliding_attention")
 WINDOW, BS, CHUNK = 24, 8, 16       # ring: 24 / 8 + 2 = 5 blocks = 40 tokens
 
+def _prefill_batches(eng):
+    """Prefill batches ``eng`` ran: ``CB_PREFILL_MS`` books one each."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    return mdefs.CB_PREFILL_MS.totals(eng._mtags)[1]
+
+
 
 def tiny(**kw):
     return llama.LlamaConfig.trinity_large_preview(**{**dict(
@@ -333,7 +340,7 @@ def test_engine_tokens_are_the_references_argmax(model, engine,
     outs, eng = _serve(config, params, prompts, max_new=12, **engine)
     for prompt, out in zip(prompts, outs):
         assert out == _reference_tokens(params, config, prompt, out)
-    assert eng.prefill_batches == 4     # four chunk counts, four groups
+    assert _prefill_batches(eng) == 4     # four chunk counts, four groups
 
 
 def test_kept_routes_are_the_references_choices(model):

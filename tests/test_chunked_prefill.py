@@ -44,6 +44,13 @@ GRANITE = lambda: llama.LlamaConfig.granite_4_0_h_small(  # noqa: E731
     dtype=jnp.float32)
 LENGTHS = (9, 33, 47, 64, 100)
 
+def _prefill_batches(eng):
+    """Prefill batches ``eng`` ran: ``CB_PREFILL_MS`` books one each."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    return mdefs.CB_PREFILL_MS.totals(eng._mtags)[1]
+
+
 
 @pytest.fixture(scope="module", params=list(FAMILIES))
 def family(request):
@@ -84,7 +91,7 @@ def test_multi_chunk_prefill_is_the_one_chunk_prefill(family, blockwise,
     assert got == want
     # 9 -> 1 call; 33 and 47 -> 3; 64 -> 4; 100 -> 7: five groups, no two
     # prompts share a chunk count but 33 and 47.
-    assert eng.prefill_batches == 4
+    assert _prefill_batches(eng) == 4
     assert eng.prefill_tokens == sum(LENGTHS)
 
 
@@ -154,13 +161,13 @@ def test_a_wave_over_the_token_cap_is_split_into_calls_of_fewer_rows(
     monkeypatch.setattr(cb, "PREFILL_BATCH_TOKENS", 64)
     got, capped = _serve(config, params, prompts)
     assert got == want
-    assert (whole.prefill_batches, capped.prefill_batches) == (1, 2)
+    assert (_prefill_batches(whole), _prefill_batches(capped)) == (1, 2)
     assert (4, 32) in whole._prefill_shapes
     assert capped._prefill_shapes == {(2, 32)}
     # A cap under one row's tokens still admits a row at a time.
     monkeypatch.setattr(cb, "PREFILL_BATCH_TOKENS", 8)
     got, single = _serve(config, params, prompts)
-    assert got == want and single.prefill_batches == 4
+    assert got == want and _prefill_batches(single) == 4
 
 
 def test_the_default_cap_and_chunk_split_no_batch_of_the_existing_cells():

@@ -10,6 +10,13 @@ from ray_tpu.models.continuous_batching import ContinuousBatcher
 from ray_tpu.models.inference import LlamaGenerator
 from ray_tpu.models.paged_kv import GARBAGE_BLOCK
 
+def _prefill_batches(eng):
+    """Prefill batches ``eng`` ran: ``CB_PREFILL_MS`` books one each."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    return mdefs.CB_PREFILL_MS.totals(eng._mtags)[1]
+
+
 
 @pytest.fixture(scope="module")
 def setup():
@@ -267,9 +274,9 @@ def test_burst_admission_is_one_prefill_program(setup):
     eng = ContinuousBatcher(config, params=gen.params, num_slots=4,
                             max_len=128)
     rids = [eng.submit(p, max_new_tokens=3) for p in prompts]  # one bucket
-    assert eng.prefill_batches == 0
+    assert _prefill_batches(eng) == 0
     eng.step()
-    assert eng.prefill_batches == 1, "burst took >1 prefill dispatch"
+    assert _prefill_batches(eng) == 1, "burst took >1 prefill dispatch"
     assert eng.prefill_requests == 4
     assert eng.prefill_tokens == sum(len(p) for p in prompts)
     assert eng.prefill_cache_misses() == 1
@@ -280,7 +287,7 @@ def test_burst_admission_is_one_prefill_program(setup):
     for p in prompts[:3]:
         eng.submit(p, max_new_tokens=2)
     eng.step()
-    assert eng.prefill_batches == 2
+    assert _prefill_batches(eng) == 2
     assert eng.prefill_cache_misses() == 1, "N-bucketing failed to reuse"
     burst_out.update(eng.run_to_completion())
 
@@ -317,7 +324,7 @@ def test_mixed_bucket_burst_admits_per_bucket(setup):
                             max_len=128, block_size=16)
     rids = [eng.submit(p, max_new_tokens=3) for p in short + long]
     eng.step()
-    assert eng.prefill_batches == 2
+    assert _prefill_batches(eng) == 2
     assert eng.prefill_requests == 4
     out = eng.run_to_completion()
     for p, rid in zip(short + long, rids):
@@ -1117,10 +1124,10 @@ def test_tick_is_dispatched_before_the_one_ahead_is_fetched(setup):
         dispatch(members)
         eng._inflight[-1]["n"] = eng.base_tick_count - 1
 
-    def watched_land(tick):
+    def watched_land(tick, **kwargs):
         if tick["wall"] is None:
             events.append(("land", tick["n"]))
-        return land(tick)
+        return land(tick, **kwargs)
 
     eng._dispatch_tick, eng._land = watched_dispatch, watched_land
 

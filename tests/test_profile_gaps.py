@@ -120,3 +120,47 @@ def test_cli_prints_seconds_and_share_by_name(capsys):
     assert out.startswith("/device:TPU:0: window ")
     assert "engine.tick.fetch" in out and "% of idle" in out
     assert profile_gaps.main([]) == 2
+
+
+def test_longest_gaps_lists_each_with_its_neighbours_and_annotations():
+    """``--gaps N``: the N longest gaps one by one, longest first, each
+    with the programs on either side and the annotations over it."""
+    ops = _ops((0, 10), (20, 30), (31, 40), (65, 70))
+    programs = [_ev("jit_tick(1)", 0, 10), _ev("jit_prefill(2)", 20, 30),
+                _ev("jit_merge_tokens(3)", 31, 40), _ev("jit_tick(4)", 65, 70)]
+    host = [[_ev("engine.admit", 8, 22), _ev("engine.upload", 40, 60)]]
+    got = profile_gaps.longest_gaps(ops, host, 2, programs)
+    assert [(g["at_s"], g["ms"]) for g in got] == [
+        (pytest.approx(0.040), pytest.approx(25.0)),
+        (pytest.approx(0.010), pytest.approx(10.0))]
+    assert (got[0]["after"], got[0]["before"]) == (
+        "jit_merge_tokens(3)", "jit_tick(4)")
+    assert got[0]["host"] == [["engine.upload", pytest.approx(20.0)],
+                              ["unattributed", pytest.approx(5.0)]]
+    assert (got[1]["after"], got[1]["before"]) == (
+        "jit_tick(1)", "jit_prefill(2)")
+    assert got[1]["host"] == [["engine.admit", pytest.approx(10.0)]]
+    # Without the programs' line the instructions on either side do.
+    (only,) = profile_gaps.longest_gaps(ops, host, 1)
+    assert (only["after"], only["before"]) == ("op2", "op3")
+    assert len(profile_gaps.longest_gaps(ops, host, 9)) == 3
+    assert profile_gaps.longest_gaps(ops, host, 0) == []
+
+
+def test_cli_lists_the_recorded_traces_longest_gaps(capsys):
+    """On the v5e recording every listed gap lies between two programs
+    and under the engine's annotations, longest first; the listing adds
+    up to no more than the idle time above it."""
+    assert profile_gaps.main([RECORDED, "--gaps", "5"]) == 0
+    out = capsys.readouterr().out
+    rows = [line.split() for line in out.splitlines()
+            if line.startswith("  gap at ")]
+    assert len(rows) == 5
+    lengths = [float(row[4]) for row in rows]
+    assert lengths == sorted(lengths, reverse=True) and lengths[-1] > 0
+    idle_s = float(out.split("idle ")[1].split(" s")[0])
+    assert sum(lengths) / 1e3 <= idle_s + 1e-6
+    for row in rows:
+        assert "->" in row and "jit_" in " ".join(row), row
+    assert "engine." in out.split("  gap at ", 1)[1]
+    assert profile_gaps.main([RECORDED, "--gaps"]) == 2

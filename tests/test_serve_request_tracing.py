@@ -14,6 +14,13 @@ from ray_tpu.models import llama
 from ray_tpu.models.continuous_batching import ContinuousBatcher
 from ray_tpu.util import tracing
 
+def _prefill_batches(eng):
+    """Prefill batches ``eng`` ran: ``CB_PREFILL_MS`` books one each."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    return mdefs.CB_PREFILL_MS.totals(eng._mtags)[1]
+
+
 
 class _FakeReporter:
     """Captures span records in-process (engine-level tests don't need a
@@ -198,7 +205,7 @@ def test_engine_phases_cover_the_steps_wall_time():
         eng.submit([1, 2, 3, i + 1], max_new_tokens=4)
     eng.run_to_completion()
     before = _hist_totals(*host, *programs)
-    batches_before = eng.prefill_batches
+    batches_before = _prefill_batches(eng)
     for i in range(9):                       # 9 requests over 4 slots
         eng.submit([1, 2, 3, i + 1], max_new_tokens=6 + i % 3)
     steps, t0 = 0, time.perf_counter()
@@ -212,7 +219,7 @@ def test_engine_phases_cover_the_steps_wall_time():
     assert all(n > 0 for _, n in gained.values()), gained
     assert gained[mdefs.CB_TICK_MS.name][1] == steps
     assert (gained[mdefs.CB_PREFILL_MS.name][1]
-            == eng.prefill_batches - batches_before)
+            == _prefill_batches(eng) - batches_before)
     host_ms = sum(gained[h.name][0] for h in host)
     prefill_ms, tick_ms = (gained[h.name][0] for h in programs)
     assert prefill_ms + tick_ms <= wall_ms, (gained, wall_ms)

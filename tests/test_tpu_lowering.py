@@ -31,6 +31,13 @@ from ray_tpu.parallel import MeshConfig, make_mesh
 S = jax.ShapeDtypeStruct
 BF16 = jnp.bfloat16
 
+def _prefill_batches(eng):
+    """Prefill batches ``eng`` ran: ``CB_PREFILL_MS`` books one each."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    return mdefs.CB_PREFILL_MS.totals(eng._mtags)[1]
+
+
 
 @pytest.fixture(autouse=True)
 def _real_kernels(monkeypatch):
@@ -351,7 +358,7 @@ def test_admissions_after_warm_up_compile_nothing():
             assert eng.cancel(rids[3])
             rids.append(eng.submit([6, 6, 6, 6], max_new_tokens=5))
         eng.step()
-    assert not eng.has_work() and eng.prefill_batches == 2 + 5
+    assert not eng.has_work() and _prefill_batches(eng) == 2 + 5
     assert compiles() == warm
     assert eng._tick._cache_size() == 1
 
