@@ -26,8 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops.attention import (flash_applicable, flash_attention,
-                                   mha_reference)
+from ray_tpu.ops.attention import (FLASH_RESIDUAL_NAMES, flash_applicable,
+                                   flash_attention, mha_reference)
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -56,11 +56,15 @@ class LlamaConfig:
     rms_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     remat: bool = True
-    # remat_policy: "full" recomputes the whole layer body in the backward
-    # (the measured-best default at the bench shape); "attn_out" saves the
-    # attention outputs only; "mlp_only" additionally saves q/k/v (the
-    # least recompute, the most memory). See forward() for the exact
-    # save-lists and measured tradeoffs.
+    # remat_policy: what the backward of a checkpointed layer finds saved.
+    # "full" keeps every matmul output (some 350 MB a layer at the train
+    # cells' shapes) and recomputes the elementwise work between them: the
+    # most memory, the least recompute, the default of every cell;
+    # "attn_out" keeps the attention block's output alone and recomputes
+    # the rest; "mlp_only" keeps q/k/v beside it and recomputes the MLP.
+    # All three keep the flash kernel's output and log-sum-exp (B x S x H x
+    # (2 D + 4) bytes a layer, 34 MB at 4 x 2048 x 16 x 128), so no policy's
+    # backward runs the flash forward a second time. See forward().
     remat_policy: str = "full"
     # attention: "auto" | "flash" | "ring" | "reference"
     attention: str = "auto"
@@ -876,22 +880,25 @@ def forward(
 
     body = layer_fn
     if c.remat:
+        # flash_attention's backward needs (q, k, v, out, lse). out and
+        # lse leave a pallas_call, which no dots policy saves, so every
+        # policy keeps them by name: q, k, v come back from the saved
+        # projections through the rope, and the backward holds the two
+        # backward kernels and no second flash forward.
+        policies = jax.checkpoint_policies
         if c.remat_policy == "mlp_only":
-            policy = jax.checkpoint_policies.save_only_these_names(
-                "q", "k", "v", "attn_out"
-            )
+            policy = policies.save_only_these_names(
+                "q", "k", "v", "attn_out", *FLASH_RESIDUAL_NAMES)
         elif c.remat_policy == "attn_out":
-            # Save ONLY the attention outputs (~33MB/layer at the bench
-            # shape). NOTE: flash_attention is a custom_vjp whose bwd
-            # needs (q, k, v, out, lse) residuals, so the remat backward
-            # STILL replays the flash forward — this only spares the
-            # wo-projection backward's input recompute. Measured slightly
-            # WORSE than "full" on v5e at the bench shape; kept as a
-            # tuning point for other shapes.
-            policy = jax.checkpoint_policies.save_only_these_names(
-                "attn_out")
+            # The attention block's output and nothing of the matmuls:
+            # the backward recomputes norms, projections, rope and the
+            # whole MLP. Not timed on the chip.
+            policy = policies.save_only_these_names(
+                "attn_out", *FLASH_RESIDUAL_NAMES)
         elif c.remat_policy == "full":
-            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+            policy = policies.save_from_both_policies(
+                policies.dots_with_no_batch_dims_saveable,
+                policies.save_only_these_names(*FLASH_RESIDUAL_NAMES))
         else:
             raise ValueError(
                 f"unknown remat_policy {c.remat_policy!r}; "

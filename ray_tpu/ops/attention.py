@@ -26,6 +26,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -67,6 +68,24 @@ def mha_reference(
 # ---------------------------------------------------------------------------
 # Pallas flash attention (TPU)
 # ---------------------------------------------------------------------------
+
+# Per-query statistics (the log-sum-exp, the backward's delta) cross HBM as
+# lane-dense ROWS ``[B, H, 1, S]``. As columns ``[B, H, S, 1]`` every float
+# is padded to a 128-lane tile: 67 MB where 0.5 MB are data at 4 x 16 x
+# 2048, which the kernels write and read block by block and which XLA
+# reads or writes whole wherever it reshapes one (a saved residual, PR 35).
+# Inside a kernel the softmax wants a column beside its [bq, bk] scores;
+# the turn is a transpose of a 128-wide tile, once a grid step.
+
+def _row(col):
+    """``[n, 1]`` -> ``[1, n]``."""
+    return jnp.broadcast_to(col, (col.shape[0], 128)).T[:1]
+
+
+def _column(row):
+    """``[1, n]`` -> ``[n, 1]``."""
+    return jnp.broadcast_to(row, (128, row.shape[1])).T[:, :1]
+
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, scale, causal, block_q, block_k, num_k_blocks, offs):
@@ -117,7 +136,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         # Fully-masked rows (possible with padding) have l == 0; emit zeros.
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[:, :1] + jnp.log(safe_l)
+        lse_ref[0, 0] = _row(m_ref[:, :1] + jnp.log(safe_l))
 
 
 def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
@@ -131,9 +150,10 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
     kv_spec = pl.BlockSpec((1, 1, block_k, d),
                            lambda b_, h, i, j: (b_, h // group, j, 0))
     out_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j: (b_, h, i, 0))
-    # lse kept as [B, H, S, 1]: block last-two dims (block_q, 1) satisfy the
-    # TPU tiling rule (sublane multiple of 8, lane == full array dim).
-    lse_spec = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, i, j: (b_, h, i, 0))
+    # lse leaves as rows [B, H, 1, S]: block last-two dims (1, block_q)
+    # satisfy the TPU tiling rule (sublane == full array dim, lane a
+    # multiple of 128 or the full dim).
+    lse_spec = pl.BlockSpec((1, 1, 1, block_q), lambda b_, h, i, j: (b_, h, 0, i))
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
@@ -151,7 +171,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
         out_specs=[out_spec, lse_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, 1, sq), jnp.float32),
         ],
         scratch_shapes=scratch,
         interpret=interpret,
@@ -181,8 +201,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)                 # [bq, d]
-        lse = lse_ref[0, 0]                                   # [bq, 1]
-        delta = delta_ref[0, 0]
+        lse = _column(lse_ref[0, 0])                          # [bq, 1]
+        delta = _column(delta_ref[0, 0])
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -220,8 +240,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)                   # [bk, d]
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
+        lse = _column(lse_ref[0, 0])                          # [bq, 1]
+        delta = _column(delta_ref[0, 0])
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -257,13 +277,15 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
     group = hq // hkv
     nq, nk = pl.cdiv(sq, block_q), pl.cdiv(sk, block_k)
 
+    # Rows [B, H, 1, S], as the forward kernel wrote lse.
+    lse = lse[:, :, None, :]
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
-                    axis=-1, keepdims=True)
+                    axis=-1)[:, :, None, :]
 
     q_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j: (b_, h, i, 0))
     kv_spec_dq = pl.BlockSpec((1, 1, block_k, d),
                               lambda b_, h, i, j: (b_, h // group, j, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, i, j: (b_, h, i, 0))
+    row_spec = pl.BlockSpec((1, 1, 1, block_q), lambda b_, h, i, j: (b_, h, 0, i))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -286,7 +308,7 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
                             lambda b_, h, j, i: (b_, h // group, j, 0))
     kv_out_spec = pl.BlockSpec((1, 1, block_k, d),
                                lambda b_, h, j, i: (b_, h, j, 0))
-    row_spec2 = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, j, i: (b_, h, i, 0))
+    row_spec2 = pl.BlockSpec((1, 1, 1, block_q), lambda b_, h, j, i: (b_, h, 0, i))
 
     dk_ph, dv_ph = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
@@ -324,6 +346,15 @@ def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
 def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret):
     out, lse = _fwd(q, k, v, scale=scale, causal=causal,
                     block_q=block_q, block_k=block_k, interpret=interpret)
+    # Named so a remat policy can KEEP the kernel's two outputs
+    # (FLASH_RESIDUAL_NAMES): they come out of a pallas_call, not a dot, so
+    # a dots-saveable policy alone drops them and the backward of a
+    # checkpointed layer runs this whole kernel a second time to get them
+    # back. Outside a jax.checkpoint a name is the identity. lse is kept
+    # as [B, H, S], the kernel's rows less their unit axis: 0.5 MB a layer
+    # at 4 x 16 x 2048, and no relayout on the way in or out.
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse[:, :, 0, :], "flash_lse")
     return out, (q, k, v, out, lse)
 
 
@@ -333,6 +364,11 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, g):
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+# What _flash_fwd_rule names: a jax.checkpoint policy that saves these
+# (``save_only_these_names(*FLASH_RESIDUAL_NAMES)``) has no flash forward in
+# its backward, for B x S x H x (2 D + 4) bytes a call.
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def flash_applicable(

@@ -11,6 +11,7 @@ from ray_tpu.models.training import (
     default_optimizer,
     synthetic_batch,
 )
+from ray_tpu.ops.attention import FLASH_RESIDUAL_NAMES, flash_attention
 from ray_tpu.parallel import MeshConfig, make_mesh, mesh_shape
 
 
@@ -95,3 +96,131 @@ def test_params_actually_sharded():
     assert shard.data.size == w.size // 8
     mesh = trainer.mesh
     assert mesh_shape(mesh)["fsdp"] == 8
+
+
+# --------------------------------------------- what a rematted backward keeps
+
+REMAT_POLICIES = ["full", "attn_out", "mlp_only"]
+# The smallest shape the flash KERNEL takes (head_dim a multiple of 128, a
+# sequence that tiles): interpreted on CPU, GQA 2/1, float32 throughout.
+_B, _S, _H, _KVH, _D = 3, 32, 2, 1, 128
+
+
+def _flash_model(**kw):
+    cfg = llama.LlamaConfig.tiny(
+        hidden_size=64, num_heads=_H, num_kv_heads=_KVH, head_dim=_D,
+        max_seq_len=_S, dtype=jnp.float32, **kw)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.random.randint(
+        jax.random.PRNGKey(1), (_B, _S), 0, cfg.vocab_size)}
+    return cfg, params, batch
+
+
+def _loss_grads(cfg, params, batch):
+    return jax.jit(jax.grad(
+        lambda p: llama.loss_fn(p, batch, cfg)[0]))(params)
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        v = getattr(v, "jaxpr", v)
+        if hasattr(v, "eqns"):
+            yield v
+
+
+def _eqns(jaxpr, primitive):
+    """Every equation of ``primitive`` in ``jaxpr`` and below it."""
+    for e in jaxpr.eqns:
+        if e.primitive.name == primitive:
+            yield e
+        for sub in _sub_jaxprs(e):
+            yield from _eqns(sub, primitive)
+
+
+def _kernels(jaxpr):
+    return sorted(e.params["name"] for e in _eqns(jaxpr, "pallas_call"))
+
+
+@pytest.mark.parametrize("policy", REMAT_POLICIES)
+def test_remat_gradients_match_unrematted(pallas_interpret, policy):
+    """A checkpointed layer changes what the backward RECOMPUTES, never
+    what it computes. The flash kernel's out and lse are kept, not
+    recomputed, so attention adds nothing to the difference; the norms,
+    rope, SwiGLU and (under "attn_out"/"mlp_only") the matmuls are
+    recomputed and may fuse in another order than the forward's. In
+    float32 that is rounding in the last bits: 1e-5 of each leaf's largest
+    gradient is 25 times what this shape reads (4.1e-7 under every policy)
+    and far under what a dropped or stale residual would show."""
+    cfg, params, batch = _flash_model(remat=False)
+    want = _loss_grads(cfg, params, batch)
+    cfg_r, _, _ = _flash_model(remat=True, remat_policy=policy)
+    got = _loss_grads(cfg_r, params, batch)
+    for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want),
+                            jax.tree.leaves(got)):
+        w, g = np.asarray(w), np.asarray(g)
+        assert np.abs(w).max() > 0, path
+        assert np.abs(g - w).max() <= 1e-5 * np.abs(w).max(), path
+
+
+@pytest.mark.parametrize("policy", REMAT_POLICIES)
+def test_remat_keeps_flash_residuals(pallas_interpret, policy):
+    """The forward layer scan hands the backward the kernel's output
+    ``flash_out`` (the kernel's layout, ``[B, H, S, D]``) and
+    ``flash_lse`` as ``[B, H, S]``, one of each a layer; nothing of shape
+    ``[B, H, S, 1]`` is saved (a minor dimension of 1 pads to 128 lanes on
+    the chip); and the backward scan runs the two backward kernels and NO
+    second flash forward."""
+    cfg, params, batch = _flash_model(remat=True, remat_policy=policy)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: llama.loss_fn(p, batch, cfg)[0]))(params).jaxpr
+    fwd, bwd = [e for e in jaxpr.eqns if e.primitive.name == "scan"
+                and e.params["length"] == cfg.num_layers]
+    body = fwd.params["jaxpr"].jaxpr
+    named = {e.params["name"]: e.outvars[0] for e in _eqns(body, "name")}
+    for name, shape in (("flash_out", (_B, _H, _S, _D)),
+                        ("flash_lse", (_B, _H, _S))):
+        assert named[name].aval.shape == shape
+    # What the scan stacks for the backward: each named buffer once a layer.
+    saved = [v.aval.shape for v in fwd.outvars]
+    assert saved.count((cfg.num_layers, _B, _H, _S, _D)) == 1
+    assert saved.count((cfg.num_layers, _B, _H, _S)) == 1
+    assert (cfg.num_layers, _B, _H, _S, 1) not in saved
+    assert _kernels(body) == ["flash_fwd"]
+    assert _kernels(bwd.params["jaxpr"].jaxpr) == [
+        "flash_bwd_dkv", "flash_bwd_dq"]
+
+
+def _flash_grads(wrap):
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(ks[0], (_B, _S, _H, _D), jnp.float32)
+    k, v = (jax.random.normal(key, (_B, _S, _KVH, _D), jnp.float32)
+            for key in ks[1:])
+    attend = wrap(lambda q, k, v: flash_attention(q, k, v, causal=True))
+    grad = jax.grad(lambda *a: jnp.sum(jnp.sin(attend(*a))),
+                    argnums=(0, 1, 2))
+    return jax.make_jaxpr(grad)(q, k, v).jaxpr, jax.jit(grad)(q, k, v)
+
+
+@pytest.mark.parametrize("how", ["bare", "kept", "replayed"])
+def test_flash_residual_names_are_inert(pallas_interpret, how):
+    """Outside a ``jax.checkpoint`` the names change nothing: a bare
+    gradient is the forward kernel once and the two backward kernels, as
+    before they were named. Under a checkpoint that keeps the names the
+    program is those same three kernels; under one that saves nothing it
+    is four, the forward run again. All three give the SAME BITS: the
+    backward kernels read the same out and lse either way."""
+    policies = jax.checkpoint_policies
+    wrap = {
+        "bare": lambda f: f,
+        "kept": lambda f: jax.checkpoint(
+            f, policy=policies.save_only_these_names(*FLASH_RESIDUAL_NAMES)),
+        "replayed": lambda f: jax.checkpoint(
+            f, policy=policies.nothing_saveable),
+    }[how]
+    jaxpr, got = _flash_grads(wrap)
+    assert _kernels(jaxpr) == (
+        ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+        + ["flash_fwd"] * (how == "replayed"))
+    _, want = _flash_grads(lambda f: f)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -49,8 +49,19 @@ def _mosaic_calls(fn, *specs) -> int:
     return exported.mlir_module().count("tpu_custom_call")
 
 
+def _kernel_names(module: str) -> list:
+    """The Pallas ``name=`` of every Mosaic call in ``module``, sorted."""
+    names = sorted(re.findall(r'kernel_name = "([^"]+)"', module))
+    assert len(names) == module.count("tpu_custom_call")
+    return names
+
+
 # (q heads, kv heads): the 953M config's MHA, and one GQA shape.
 HEADS = [(16, 16), (32, 8)]
+
+
+def _flash_sum(q, k, v):
+    return jnp.sum(flash_attention(q, k, v, causal=True).astype(jnp.float32))
 
 
 @pytest.mark.parametrize("hq,hkv", HEADS)
@@ -60,11 +71,26 @@ def test_flash_forward_and_gradient_lower(hq, hkv):
     fwd = functools.partial(flash_attention, causal=True)
     assert _mosaic_calls(fwd, q, kv, kv) == 1
 
-    def loss(q, k, v):
-        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+    # forward (once: it hands out and lse to the backward as residuals; a
+    # bare jax.grad has no checkpoint, so nothing to replay) + dq + dk/dv
+    assert _mosaic_calls(
+        jax.grad(_flash_sum, argnums=(0, 1, 2)), q, kv, kv) == 3
 
-    # forward (for residuals) + dq + dk/dv
-    assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
+
+@pytest.mark.parametrize("hq,hkv", HEADS)
+def test_flash_statistics_cross_hbm_as_rows(hq, hkv):
+    """The log-sum-exp and the backward's delta pass between the kernels
+    (and into a checkpoint's saved residuals) as ``[B, H, 1, S]`` rows. A
+    ``[B, H, S, 1]`` float32 column is padded to 128 lanes a value on the
+    chip: reshaping the saved lse through it cost 7.3 ms of a 909 ms
+    ``train_fsdp4`` step (PR 35)."""
+    q = S((2, 2048, hq, 128), BF16)
+    kv = S((2, 2048, hkv, 128), BF16)
+    module = jax.export.export(
+        jax.jit(jax.grad(_flash_sum, argnums=(0, 1, 2))),
+        platforms=["tpu"])(q, kv, kv).mlir_module()
+    assert f"tensor<2x{hq}x1x2048xf32>" in module
+    assert "x2048x1xf32>" not in module
 
 
 @pytest.mark.parametrize("hq,hkv", HEADS)
@@ -109,15 +135,22 @@ def test_paged_kv_write_lowers(hkv, kind, width):
                          where, where) == 1
 
 
+@pytest.mark.parametrize("remat_policy", ["full", "attn_out", "mlp_only"])
 @pytest.mark.parametrize("fsdp", [1, 4])
-def test_sharded_train_step_lowers_with_flash(fsdp):
+def test_sharded_train_step_lowers_with_flash(fsdp, remat_policy):
     """GSPMD cannot partition a Mosaic kernel: on a mesh of more than one
     device the flash call must sit inside a shard_map, or this raises
-    ``Mosaic kernels cannot be automatically partitioned``."""
+    ``Mosaic kernels cannot be automatically partitioned``.
+
+    And the checkpointed layer's backward holds NO second flash forward:
+    every remat policy keeps the kernel's output and log-sum-exp by name
+    (through the shard_map too), so the step is one forward kernel and the
+    two backward kernels inside the layer scans (PR 35; the replay was
+    2.4-2.7% of a step on the chip)."""
     config = llama.LlamaConfig.tiny(
         vocab_size=512, hidden_size=256, intermediate_size=512,
         num_layers=2, num_heads=2, num_kv_heads=2, head_dim=128,
-        max_seq_len=128, remat=True)
+        max_seq_len=128, remat=True, remat_policy=remat_policy)
     mesh = make_mesh(MeshConfig(fsdp=fsdp), devices=jax.devices()[:fsdp])
     trainer = ShardedTrainer(config, mesh)
     state = jax.eval_shape(trainer._init._jitted, jax.random.PRNGKey(0))
@@ -125,8 +158,8 @@ def test_sharded_train_step_lowers_with_flash(fsdp):
     with mesh:
         exported = jax.export.export(
             trainer._step._jitted, platforms=["tpu"])(state, batch)
-    # flash forward, its remat replay, dq, dk/dv — inside the layer scan.
-    assert exported.mlir_module().count("tpu_custom_call") >= 3
+    assert _kernel_names(exported.mlir_module()) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
 
 
 # ------------------------------------------- the compiled tick's arena moves
