@@ -71,17 +71,17 @@ import sys
 import threading
 import time
 
-PHASES = ("kernels", "moe", "hybrid", "window", "train", "serve",
+PHASES = ("kernels", "moe", "hybrid", "window", "mla", "train", "serve",
           "multichip")
 # The multichip phase is two children: the trainer's state must be gone
 # from the chips before four serving replicas load theirs.
 CHILDREN = {"kernels": ("kernels",), "moe": ("moe",),
-            "hybrid": ("hybrid",), "window": ("window",),
+            "hybrid": ("hybrid",), "window": ("window",), "mla": ("mla",),
             "train": ("train",),
             "serve": ("serve",),
             "multichip": ("multichip-train", "multichip-serve")}
 PHASE_TIMEOUT_S = {"kernels": 600, "moe": 600, "hybrid": 1500,
-                   "window": 2700, "train": 480,
+                   "window": 2700, "mla": 2700, "train": 480,
                    "serve": 600,
                    "multichip-train": 900, "multichip-serve": 900}
 RESULT_TAG = "PHASE_RESULT "
@@ -98,7 +98,8 @@ REHEARSAL_BANNER = (
 # through two more bf16 roundings (dO, and the recomputed P) than the
 # forward. The chip run prints what was measured next to each bound.
 TOLERANCE = {"flash_fwd": 1e-2, "flash_bwd": 2e-2,
-             "decode_bf16": 1e-2, "decode_int8": 1e-2, "moe_gmm": 1e-2}
+             "decode_bf16": 1e-2, "decode_int8": 1e-2, "moe_gmm": 1e-2,
+             "latent_decode": 1e-2}
 # fsdp=4 vs one-chip first-step loss: the same bf16 model, sums reduced
 # across four devices in another order.
 LOSS_RTOL = 5e-3
@@ -496,6 +497,86 @@ def _time_paged_cells(phase: str, device_kind: str, rehearse: bool) -> None:
                     f"= {live_bytes / hbm * 1e6:.1f} us")
 
 
+def _latent_kernel(phase: str, device_kind: str, rehearse: bool) -> None:
+    """``latent_decode_attn`` at Kimi K2's head shape (64 absorbed
+    queries a slot of 640 lanes = 512 latent + 64 rope + 64 pad, values
+    the first 512; blocks of 64) against the ``jax.numpy`` gather:
+    contexts of 1, 63, 64, 4097 and 10240 rows and a freed slot, in a
+    whole ``[L, ...]`` cache read at a layer. Then its time at the cell's
+    load (96 slots x about 7000 rows) beside the time the live rows'
+    bytes take at the device's HBM peak."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import peaks
+    from ray_tpu.ops.latent_decode_attention import (
+        latent_attention_reference, latent_decode_attention,
+        latent_visit_blocks)
+    from ray_tpu.ops.paged_decode_attention import paged_visits
+
+    h, w, rank, bs = (4, 128, 32, 8) if rehearse else (64, 640, 512, 64)
+    contexts = [1, 7, 8, 33, 80] if rehearse else [1, 63, 64, 4097, 10240]
+    nb = -(-max(contexts) // bs)
+    key = jax.random.PRNGKey(7)
+    scale = 0.14468
+
+    def inputs(slots, lengths, layers):
+        k1, k2 = jax.random.split(jax.random.fold_in(key, slots))
+        blocks = 1 + slots * nb
+        arena = jax.random.normal(k1, (layers, blocks, 1, bs, w),
+                                  jnp.float32).astype(jnp.bfloat16)
+        q = jax.random.normal(k2, (slots, h, w), jnp.float32
+                              ).astype(jnp.bfloat16)
+        tables = np.zeros((slots, nb), np.int32)
+        for s_, n in enumerate(lengths):
+            live = -(-n // bs)
+            row = list(range(1 + s_ * nb, 1 + s_ * nb + live))
+            tables[s_] = (row + [row[-1]] * (nb - live)) if live else 0
+        positions = jnp.asarray([max(n - 1, 0) for n in lengths], jnp.int32)
+        limits = jnp.asarray([nb * bs if n else 0 for n in lengths],
+                             jnp.int32)
+        return q, arena, jnp.asarray(tables), positions, limits
+
+    lengths = contexts + [0]                  # the last slot is freed
+    q, arena, tables, positions, limits = inputs(len(lengths), lengths, 2)
+    got = jax.jit(lambda q, a: latent_decode_attention(
+        q, a, tables, positions, scale, rank=rank, layer=jnp.int32(1),
+        limits=limits, use_kernel=True))(q, arena)
+    want = jax.jit(lambda q, a: latent_attention_reference(
+        q, a, tables, positions, scale, rank=rank, layer=1))(q, arena)
+    assert not np.asarray(got[-1], np.float32).any(), "freed slot not zero"
+    _check(phase, f"latent_decode_attn {h} heads x {w} lanes, values "
+                  f"{rank}, block {bs}, contexts {contexts}",
+           got[:-1], want[:-1], TOLERANCE["latent_decode"])
+    if rehearse:
+        return
+    slots, rows = 96, 7000
+    rng = np.random.default_rng(0)
+    lengths = [int(n) for n in rng.integers(rows - 1500, rows + 1500, slots)]
+    q, arena, tables, positions, limits = inputs(slots, lengths, 1)
+    hbm = peaks.for_device(device_kind)["hbm_bytes_per_s"]
+    live = sum(lengths)
+    for per in (4, 8, 12, 16):
+        visits = paged_visits(tables, positions, limits, block_size=bs,
+                              per_visit=per)
+
+        def call(q, a, _v, li, visits=visits):
+            out = latent_decode_attention(
+                q, a, tables, positions, scale, rank=rank, layer=li,
+                visits=visits, use_kernel=True)
+            return jnp.pad(out, ((0, 0), (0, 0), (0, w - rank)))
+
+        us = _time_us(call, q, (arena, arena), 1, 64)
+        _say(phase, f"latent_decode_attn alone, {slots} slots x ~{rows} rows "
+                    f"({live} live), {per} block(s) a step"
+                    + (" (shipped)" if per == latent_visit_blocks(arena)
+                       else "")
+                    + f": {us:.0f} us a call; live rows x 1152 B / "
+                    f"{hbm / 1e9:.0f} GB/s = {live * 1152 / hbm * 1e6:.0f} us"
+                    f" (x 1280 B as stored: {live * 1280 / hbm * 1e6:.0f})")
+
+
 def phase_kernels(rehearse: bool) -> None:
     phase = "kernels"
     info = _open_device(phase, rehearse)
@@ -695,6 +776,7 @@ def phase_kernels(rehearse: bool) -> None:
             _check(phase, f"moe_gmm [{m},{kk}] x [{x_},{kk},{nn}] bf16",
                    gmm(a, w, g), masked_loop(a, w, g), TOLERANCE["moe_gmm"])
     _time_paged_cells(phase, info["kind"], rehearse)
+    _latent_kernel(phase, info["kind"], rehearse)
     _finish(phase, info)
 
 
@@ -1119,6 +1201,144 @@ def phase_window(rehearse: bool) -> None:
     _finish(phase, info, faults=results, tolerance=tolerance)
 
 
+def phase_mla(rehearse: bool) -> None:
+    """The cell ``serve_mla_decode``'s comparison with its reference, and
+    the faults it has to catch, AT THE CELL'S OWN SIZES: the
+    configuration as the cell runs it (Kimi K2's published widths, five
+    layers, experts 0-11 of 384) in the engine the served path builds
+    (``ContinuousBatcher``: chunked prefill in expanded form, the latent
+    cache, absorbed ticks, every kernel; four slots are enough here), the
+    cell's check prompts and answer length, one request after another,
+    greedy, each keeping its routes; held to
+    ``benchmark/reference_kimi_k2.py`` by the runner's own
+    ``hold_to_reference`` under the configuration file's limits.
+
+    First the program as published, which has to pass. Then one fault at
+    a time, each of which has to FAIL one of the two limits: the cache
+    rows kept in 8 bits; the rope term of the score dropped; and the
+    weights rounded to float8, the nearest precision below the bf16 the
+    configuration states. The tick's scores in bf16 are read as well and
+    held to nothing: no limit that passes the published program sees
+    them at these sizes."""
+    phase = "mla"
+    info = _open_device(phase, rehearse)
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import manifest
+    from benchmark.runners import serve_mla
+    from benchmark.runners.serve import _prompts
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models import llama, mla
+
+    cell = manifest.cell("serve_mla_decode")
+    if rehearse:
+        cell = manifest.rehearsal(cell)
+    work, tolerance = cell["workload"], cell["config"]["tolerance"]
+    engine = dict(work["engine"], num_slots=4)
+    config = serve_mla.kimi_config(cell["config"],
+                                   max_seq_len=engine["max_len"])
+    sets = [_prompts(np.random.default_rng(seed), config.vocab_size,
+                     work["check"]["prompt_tokens"],
+                     work["check"]["max_tokens"])
+            for seed in ((36,) if rehearse else (36, 37))]
+
+    def published():
+        return jax.jit(lambda k: llama.init_params(config, k))(
+            jax.random.PRNGKey(0))
+
+    def answers(weights, sets):
+        eng = cb.ContinuousBatcher(config, params=weights, **engine)
+        out = []
+        for reqs in sets:
+            recs = []
+            for req in reqs:
+                rid = eng.submit(req["prompt"], req["max_tokens"],
+                                 keep_routes=True)
+                recs.append({"tokens": eng.run_to_completion()[rid],
+                             "routes": eng.take_routes(rid)})
+            out.append(list(zip(reqs, recs)))
+        return out
+
+    real = {name: getattr(mla, name) for name in
+            ("_latents", "_queries", "latent_decode_attention")}
+
+    def rows_in_8_bits(*args):
+        row = real["_latents"](*args)
+        return row.astype(jnp.float8_e4m3fn).astype(row.dtype)
+
+    def no_rope_term(*args):
+        q_nope, q_rope = real["_queries"](*args)
+        return q_nope, jnp.zeros_like(q_rope)
+
+    def bf16_scores(q, arena, tables, positions, scale, *, rank, layer=None,
+                    **_):
+        slab = arena if layer is None else arena[layer]
+        b, nb = tables.shape
+        rows = slab[tables][:, :, 0].reshape(b, -1, slab.shape[-1])
+        s = jnp.einsum("bhw,bkw->bhk", q, rows) * jnp.asarray(scale, q.dtype)
+        seen = positions[:, None] >= jnp.arange(rows.shape[1])[None, :]
+        p = jax.nn.softmax(jnp.where(seen[:, None], s.astype(jnp.float32),
+                                     -1e30), axis=-1)
+        return jnp.einsum("bhk,bkc->bhc", p.astype(q.dtype),
+                          rows[..., :rank]).astype(q.dtype)
+
+    def float8(tree):
+        """Leaf by leaf, in place, and op by op (two copies of the
+        weights do not fit the chip)."""
+        def rounded(a):
+            if a.dtype != jnp.bfloat16:
+                return a
+            out = a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+            a.delete()
+            return out
+        return jax.tree.map(rounded, tree)
+
+    cases = [
+        ("as published", lambda p: p, {}),
+        ("cache rows in 8 bits", lambda p: p, {"_latents": rows_in_8_bits}),
+        ("tick scores in bf16", lambda p: p,
+         {"latent_decode_attention": bf16_scores}),
+        ("rope term dropped", lambda p: p, {"_queries": no_rope_term}),
+        # Last: it eats the published weights.
+        ("weights rounded to float8_e4m3", float8, {}),
+    ]
+    if rehearse:
+        cases = cases[:1]       # tiny sizes prove nothing about the faults
+    params = published()
+    answered = {}
+    for name, weights, patch in cases:
+        for attr, fn in patch.items():
+            setattr(mla, attr, fn)
+        try:
+            answered[name] = answers(
+                weights(params), sets if name == "as published" else sets[:1])
+        finally:
+            for attr, fn in real.items():
+                setattr(mla, attr, fn)
+        gc.collect()
+        _say(phase, f"{name}: {len(answered[name])} x {len(sets[0])} check "
+                    f"requests answered")
+    if len(cases) > 1:
+        del params
+        params = published()
+    results = {}
+    for name, checked in answered.items():
+        _say(phase, name)
+        results[name] = [serve_mla.hold_to_reference(
+            params, config, checks, tolerance) for checks in checked]
+    _finish(phase, info, faults=results, tolerance=tolerance)
+    # bf16 scores in the tick are READ, not held: they moved neither
+    # number out of the published program's own range (PERF.md, PR 36).
+    wrong = [name for name, rs in results.items()
+             if name != "tick scores in bf16"
+             and any(r["ok"] != (name == "as published") for r in rs)]
+    assert not wrong, f"{wrong}: {results} against {tolerance}"
+
+
 def _train_once(phase, config, mesh, batch_size, seq_len, steps, rehearse):
     """Init + ``steps`` steps on one repeated batch. Returns (losses,
     trainer, state)."""
@@ -1518,7 +1738,7 @@ def _child(phase: str, rehearse: bool) -> int:
 
 CHILD_FNS = {"kernels": phase_kernels, "moe": phase_moe,
              "hybrid": phase_hybrid, "window": phase_window,
-             "train": phase_train,
+             "mla": phase_mla, "train": phase_train,
              "serve": phase_serve, "multichip-train": phase_multichip_train,
              "multichip-serve": phase_multichip_serve}
 

@@ -457,6 +457,19 @@ CB_WINDOW_LIVE_BLOCK_SHARE = Histogram(
     "that hold one of a query's last sliding_window keys) over the blocks "
     "a table of every position would have it visit",
     boundaries=_SHARE_BOUNDS, tag_keys=("engine",))
+CB_LATENT_KV_BYTES = Gauge(
+    "ray_tpu_cb_latent_kv_bytes",
+    "Resident bytes of the latent cache of a model with latent-attention "
+    "(MLA) layers: one padded row a token a layer, in the arena's place; "
+    "fixed at construction",
+    ("engine",))
+CB_MLA_LIVE_TOKENS = Histogram(
+    "ray_tpu_cb_mla_live_tokens",
+    "Per decode tick of a model with latent-attention layers: the cache "
+    "rows the tick's queries attended, summed over the live slots (each "
+    "slot's position + 1); its sum over its count is the mean a tick",
+    boundaries=[1e3, 1e4, 1e5, 2e5, 4e5, 6e5, 8e5, 1e6, 2e6],
+    tag_keys=("engine",))
 CB_WINDOW_KV_BYTES = Gauge(
     "ray_tpu_cb_window_kv_bytes",
     "Resident bytes of the sliding-window layers' per-slot rings; fixed "

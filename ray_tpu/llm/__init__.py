@@ -20,6 +20,7 @@ import numpy as np
 from ray_tpu import serve
 from ray_tpu.models import llama
 from ray_tpu.models.inference import LlamaGenerator
+from ray_tpu.serve.recovery import STREAM_ITEM_TIMEOUT_S
 
 
 @serve.deployment
@@ -564,7 +565,7 @@ class ContinuousLlamaDeployment:
         lag = _StreamLag(self.batcher._mtags)
         try:
             while True:
-                token = q.get(timeout=300)
+                token = q.get(timeout=STREAM_ITEM_TIMEOUT_S)
                 if token is None:
                     done = True
                     return
@@ -652,7 +653,7 @@ class ContinuousLlamaDeployment:
         tokens: List[int] = []
         try:
             while True:
-                item = q.get(timeout=300)
+                item = q.get(timeout=STREAM_ITEM_TIMEOUT_S)
                 if item is None:
                     break
                 if isinstance(item, Exception):
@@ -734,7 +735,7 @@ class ContinuousLlamaDeployment:
             emitted = 1
             yield int(manifest["first_token"])
             while True:
-                token = q.get(timeout=300)
+                token = q.get(timeout=STREAM_ITEM_TIMEOUT_S)
                 if token is None:
                     done = True
                     return

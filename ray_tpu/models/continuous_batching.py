@@ -64,7 +64,8 @@ from ray_tpu.models.inference import (ExternalLlamaDrafter, KVCache,
                                       _forward_cached, lm_head_logits)
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.models.paged_kv import (GARBAGE_BLOCK, BlockAllocator,
-                                     PagedKVCache, RadixBlockIndex,
+                                     LatentKVCache, PagedKVCache,
+                                     RadixBlockIndex,
                                      RingKVCache, StateCache, prompt_chunks,
                                      quantize_kv, resolve_kv_dtype)
 from ray_tpu.models.sampling import (SPEC_DRAFT_SALT, SamplingParams,
@@ -115,7 +116,7 @@ PREFILL_BATCH_TOKENS = 8192
 
 
 def _window_visits(tables, positions, limits, arena_k, use_kernel: bool,
-                   window: int = 0):
+                   window: int = 0, per_visit=visit_blocks):
     """The attention kernel's schedule over the arena ``arena_k`` (K of
     the table's arena or of the rings) for each of a window's S
     positions (``positions`` [B, S]): made ONCE a program, before the
@@ -125,7 +126,7 @@ def _window_visits(tables, positions, limits, arena_k, use_kernel: bool,
         return None
     return [paged_visits(tables, positions[:, j], limits,
                          block_size=arena_k.shape[-2],
-                         per_visit=visit_blocks(arena_k), window=window)
+                         per_visit=per_visit(arena_k), window=window)
             for j in range(positions.shape[1])]
 
 
@@ -262,8 +263,28 @@ def _rope_tables(c, length, positions):
     """cos/sin for ``positions``; None for a model without rope."""
     if not c.rope:
         return None, None
+    if c.latent_layers:     # the rotated dims alone, YaRN frequencies
+        return _mla().rope_tables(c, positions)
     return rope_frequencies(c.head_dim, length, c.rope_theta,
                             positions=positions)
+
+
+def _mla():
+    """``models/mla.py``, imported where a latent-attention layer is met:
+    no other family's process loads it or the kernel behind it."""
+    from ray_tpu.models import mla
+
+    return mla
+
+
+def _visit_rule(cache):
+    """Blocks a grid step of the attention kernel covers, as a function
+    of the cache's ``k``: the latent kernel's rule for a latent cache."""
+    if isinstance(cache, LatentKVCache):
+        from ray_tpu.ops.latent_decode_attention import latent_visit_blocks
+
+        return latent_visit_blocks
+    return visit_blocks
 
 
 def _embed(params, tokens, c):
@@ -343,10 +364,10 @@ def _concat_runs(parts):
 
 def _split_caches(caches):
     """(arena, second cache) of an engine program's ``caches`` operand:
-    the arena alone for a model whose every layer keeps all its K/V,
-    else the pair: with the state cache (state-space layers) or the
-    ring (sliding-window layers)."""
-    if isinstance(caches, PagedKVCache):
+    the arena alone for a model whose every layer keeps all its K/V (or
+    the latent cache in its place), else the pair: with the state cache
+    (state-space layers) or the ring (sliding-window layers)."""
+    if isinstance(caches, (PagedKVCache, LatentKVCache)):
         return caches, None
     return caches
 
@@ -403,7 +424,8 @@ def _forward_paged(params, tokens, positions, tables, limits,
     block_idx = jnp.where(positions < limits[:, None], gathered,
                           GARBAGE_BLOCK)                      # [B, S]
     offset = positions % bs
-    visits = _window_visits(tables, positions, limits, cache.k, use_kernel)
+    visits = _window_visits(tables, positions, limits, cache.k, use_kernel,
+                            per_visit=_visit_rule(cache))
     if isinstance(state, RingKVCache):
         # Sliding-window layers: every slot's fixed ring of blocks, its
         # table an iota; position p lands in entry (p // bs) % ring.
@@ -429,6 +451,11 @@ def _forward_paged(params, tokens, positions, tables, limits,
             mixed, *held = mamba2.mixer_step(h, layer, c, *held, ki,
                                              use_kernel)
             held = tuple(held)
+        elif kind == "latent_attention":
+            mixed, latents = _mla().tick_layer(
+                x, layer, c, arenas[0], ki, cos, sin, block_idx, offset,
+                tables, positions, visits, use_kernel)
+            arenas = (latents,)
         else:
             with _kind_scope(kind):
                 q, k, v, gate = _layer_qkv(
@@ -459,7 +486,7 @@ def _forward_paged(params, tokens, positions, tables, limits,
     # lm_head in the params' storage dtype with fp32 accumulation
     # (shared with prefill): bf16 params are never upcast in HBM.
     logits = lm_head_logits(x, params, c)
-    cache = PagedKVCache(*arenas)
+    cache = type(cache)(*arenas)
     return (logits, (cache, type(state)(*held)) if held else cache,
             _concat_runs(rows))
 
@@ -654,6 +681,16 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
             mixed, *new = mamba2.mixer_prefill(h, layer, c, last_idx + 1)
             held = tuple(a.at[li + shift, slots].set(n.astype(a.dtype))
                          for a, n in zip(held, new))
+        elif kind == "latent_attention":
+            layer, = inputs
+            # Always the paged chunk form: the chunk's own keys and
+            # values expanded per head, the earlier chunks' read as
+            # latents where they lie.
+            mixed, row = _mla().prefill_layer(
+                x, layer, c, paged.cache.k, li + shift, cos, sin,
+                paged.tables, paged.tables.shape[1] * paged.cache.block_size,
+                bool(use_kernel))
+            kept = (row,)
         elif paged is not None:
             layer, = inputs
             with _kind_scope(kind):
@@ -745,10 +782,10 @@ def _prefill_chunk_paged(params, tokens, positions, cache, ring, ptables,
         use_kernel, paged=_PagedPrefix(cache, ptables, ring, ring_tables))
 
     def land(into, kv, tables):
-        k, v = (a.at[:, tables.reshape(-1)].set(
+        # (k, v) of an arena or a ring; the one plane of a latent cache.
+        return type(into)(*(a.at[:, tables.reshape(-1)].set(
             _ctx_to_blocks(new.astype(a.dtype), bs))
-            for a, new in zip((into.k, into.v), kv))
-        return type(into)(k=k, v=v)
+            for a, new in zip(into, kv)))
 
     if stored["attention"] is not None:
         cache = land(cache, stored["attention"], tables_w)
@@ -816,6 +853,11 @@ def _resolve_decode_kernel(config: llama.LlamaConfig,
     argument forces either way (CPU tests pass True and run them
     interpreted); None means on a TPU when the shapes tile, the XLA
     reference elsewhere."""
+    if use_decode_kernel is None and config.latent_layers:
+        from ray_tpu.ops.latent_decode_attention import latent_applicable
+
+        return (jax.default_backend() == "tpu" and latent_applicable(
+            block_size, _mla().row_width(config), config.kv_lora_rank))
     if use_decode_kernel is None:
         return (jax.default_backend() == "tpu"
                 and paged_applicable(block_size, config.head_dim,
@@ -1024,6 +1066,8 @@ class ContinuousBatcher:
         if config.window_layers:
             self._refuse_for_window_layers(prefix_cache, spec_k, drafter)
             prefix_cache = False
+        if config.latent_layers:
+            self._refuse_for_latent_layers(spec_k, drafter)
         chunk = _bucket_floor(int(prefill_chunk))
         if config.window_layers:
             # A chunk's blocks must be distinct entries of a ring.
@@ -1289,9 +1333,10 @@ class ContinuousBatcher:
             m = ptables.shape[1]
             positions = m * block_size_c + jnp.arange(s_pad)
             ring = held if isinstance(held, RingKVCache) else None
-            if not cache.quantized and (ring is not None or (
-                    held is None
-                    and m * block_size_c + s_pad > PREFILL_DENSE_KEYS)):
+            if not cache.quantized and (
+                    ring is not None or isinstance(cache, LatentKVCache)
+                    or (held is None
+                        and m * block_size_c + s_pad > PREFILL_DENSE_KEYS)):
                 # A long prompt's chunk, or sliding-window layers: the
                 # earlier keys are read where they lie, blockwise. (Not
                 # a model with state layers, whose prefill installs a
@@ -1450,6 +1495,46 @@ class ContinuousBatcher:
             refuse(f"role={self.role!r}",
                    "the KV handoff carries the arena's blocks only")
 
+    def _refuse_for_latent_layers(self, spec_k=None, drafter=None,
+                                  what: Optional[str] = None):
+        """A model of latent-attention layers keeps one row a token in
+        ``paged_kv.LatentKVCache`` and its weights in ``params["runs"]``:
+        whatever is written against per-head K/V planes, or cuts the
+        layer stack out of ``params["layers"]``, is refused by name,
+        here and nowhere else. ``what``: a method the family refuses
+        when called (the constructor checks its own arguments)."""
+        def refuse(what, why):
+            raise ValueError(
+                f"{what} is not supported for a model with latent-attention "
+                f"layers (layer_types has 'latent_attention'): {why}")
+
+        handoff = ("the KV handoff gathers and scatters K and V planes, "
+                   "and a latent cache has one plane")
+        if what is not None:
+            refuse(what, "it runs llama.forward, the training forward, "
+                   "which this serving-only family has none of"
+                   if what == "score_logprobs" else handoff)
+        c = self.config
+        if c.latent_layers != c.num_layers:
+            refuse("another layer kind in the same stack",
+                   "the latent cache takes the arena's place, so every "
+                   "layer must keep its keys there")
+        if self.kv_dtype != "bf16":
+            refuse(f"kv_dtype={self.kv_dtype!r}",
+                   "8-bit latents are a different model output, not a "
+                   "storage option: the cache has no scale sidecar")
+        if _resolve_spec_k(spec_k) or drafter is not None:
+            refuse("speculative decoding (spec_k > 0)",
+                   "the self-draft cuts params['layers'], which holds "
+                   "this family's experts alone, and the verify programs "
+                   "take K and V planes")
+        if self.sync_every > 1:
+            refuse("buffered decode (sync_every > 1)",
+                   "its rewind is not run over a latent cache (the "
+                   "per-tick step keeps the device as busy: ROADMAP D3)")
+        if self.role != "both":
+            refuse(f"role={self.role!r}", handoff)
+
     def _place(self, tree):
         """Commit a pytree (host or device values) to this engine's chip;
         with no chip named, host values go to JAX's default device."""
@@ -1465,6 +1550,9 @@ class ContinuousBatcher:
 
     def _new_cache(self):
         with jax.default_device(self.device):
+            if self.config.latent_layers:
+                return self._place(LatentKVCache.create(
+                    self.config, self.num_blocks, self.block_size))
             return self._place(PagedKVCache.create(
                 self.config, self.num_blocks, self.block_size,
                 self.kv_dtype))
@@ -1801,6 +1889,8 @@ class ContinuousBatcher:
         run, so the RL experience path's importance ratios are priced
         against the true generating policy. Returns ``[len(out_tokens)]``
         float32."""
+        if self.config.latent_layers:
+            self._refuse_for_latent_layers(what="score_logprobs")
         if not out_tokens:
             return np.zeros((0,), np.float32)
         if self._score_fn is None:
@@ -2073,6 +2163,8 @@ class ContinuousBatcher:
         return True
 
     def _refuse_handoff(self, what: str) -> None:
+        if self.config.latent_layers:
+            self._refuse_for_latent_layers(what=what)
         if self.config.state_layers:
             raise ValueError(
                 f"{what} is not supported for a model with state-space "
@@ -2361,12 +2453,19 @@ class ContinuousBatcher:
         if ring and live:
             mdefs.CB_WINDOW_LIVE_BLOCK_SHARE.observe(
                 sum(ring) / live, tags=self._mtags)
+        if self.config.latent_layers:
+            # The positions the tick's queries attended, summed over the
+            # slots: what the latent kernel must read a layer, in tokens.
+            mdefs.CB_MLA_LIVE_TOKENS.observe(
+                sum(st["pos"] + 1 for st in self._slots.values()),
+                tags=self._mtags)
         runs = [(self.cache.k, table)]
         if ring:
             runs.append((self._ring.k, ring))
         read = held = 0
+        rule = _visit_rule(self.cache)
         for arena_k, blocks in runs:        # each by its layer count
-            per, layers = visit_blocks(arena_k), arena_k.shape[0]
+            per, layers = rule(arena_k), arena_k.shape[0]
             read += layers * sum(blocks)
             held += layers * per * sum(-(-n // per) for n in blocks)
         if held:
@@ -3160,6 +3259,8 @@ class ContinuousBatcher:
         if self.config.state_layers:
             mdefs.CB_STATE_CACHE_BYTES.set(self.state.nbytes,
                                            tags=self._mtags)
+        if self.config.latent_layers:
+            mdefs.CB_LATENT_KV_BYTES.set(self.cache.nbytes, tags=self._mtags)
         if self._ring is not None:
             mdefs.CB_WINDOW_KV_BYTES.set(self._ring.nbytes, tags=self._mtags)
             mdefs.CB_FULL_KV_BYTES.set(

@@ -131,6 +131,30 @@ class LlamaConfig:
     # ``[first, first + count)`` and computes their part of the result;
     # None = all of them.
     experts_held: Optional[Tuple[int, int]] = None
+    # The DeepSeek-V3 family (``kimi_k2``; SERVING ONLY like the two
+    # above): ``layer_types`` names "latent_attention", multi-head LATENT
+    # attention (``models/mla.py``). q is projected through a normed
+    # bottleneck of ``q_lora_rank``; keys and values through one of
+    # ``kv_lora_rank`` beside ``qk_rope_head_dim`` rotated dims that all
+    # heads share; a head's q and k have ``qk_nope_head_dim +
+    # qk_rope_head_dim`` dims, its v ``v_head_dim``. The engine keeps the
+    # ``kv_lora_rank + qk_rope_head_dim`` values a token in
+    # ``paged_kv.LatentKVCache``, no per-head K/V. ``rope_scaling``: the
+    # published YaRN group as sorted ``(key, value)`` pairs
+    # (:func:`scaling_pairs`), None = plain rope.
+    # ``first_k_dense_replace`` is ``num_dense_layers``,
+    # ``routed_scaling_factor`` ``route_scale``.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[Tuple[Tuple[str, Any], ...]] = None
+
+    @property
+    def latent_layers(self) -> int:
+        """Layers whose keys and values are one latent row a token."""
+        return sum(t == "latent_attention" for t in self.layer_types)
 
     @property
     def state_layers(self) -> int:
@@ -145,7 +169,8 @@ class LlamaConfig:
     @property
     def attn_layers(self) -> int:
         """Layers that keep ALL their K/V: what the arena holds."""
-        return self.num_layers - self.state_layers - self.window_layers
+        return (self.num_layers - self.state_layers - self.window_layers
+                - self.latent_layers)
 
     @property
     def moe_layers(self) -> int:
@@ -167,6 +192,10 @@ class LlamaConfig:
 
     @property
     def attn_scale(self) -> float:
+        if self.latent_layers and self.attention_multiplier is None:
+            from ray_tpu.models import mla
+
+            return mla.softmax_scale(self)
         return (self.head_dim ** -0.5 if self.attention_multiplier is None
                 else self.attention_multiplier)
 
@@ -240,6 +269,30 @@ class LlamaConfig:
             embedding_multiplier=3072 ** 0.5), **kw})
 
     @staticmethod
+    def kimi_k2_7_code(**kw) -> "LlamaConfig":
+        """moonshotai/Kimi-K2.7-Code (``kimi_k2``: the DeepSeek-V3
+        decoder): 61 layers of latent attention, 64 heads of 128 + 64
+        rotated dims (values of 128) through ranks 1536 (q) and 512
+        (k/v), YaRN x64 over 4096; one dense layer of 18432, then 384
+        experts of 2048, top 8 of a sigmoid score times 2.827, beside one
+        shared expert; untied 164k vocabulary."""
+        return LlamaConfig(**{**dict(
+            vocab_size=163840, hidden_size=7168, intermediate_size=2048,
+            num_layers=61, num_heads=64, num_kv_heads=64, head_dim=192,
+            max_seq_len=262144, rope_theta=50000.0, rms_eps=1e-5,
+            layer_types=("latent_attention",) * 61,
+            q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128,
+            rope_scaling=scaling_pairs(dict(
+                type="yarn", factor=64.0,
+                original_max_position_embeddings=4096, beta_fast=32.0,
+                beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0)),
+            num_dense_layers=1, dense_intermediate_size=18432,
+            num_experts=384, num_experts_per_tok=8, norm_topk_prob=True,
+            router_score="sigmoid", route_scale=2.827,
+            shared_intermediate_size=2048), **kw})
+
+    @staticmethod
     def tiny(**kw) -> "LlamaConfig":
         """CPU-runnable config for tests (BASELINE.md config #1 analog)."""
         kw.setdefault("vocab_size", 256)
@@ -252,6 +305,12 @@ class LlamaConfig:
         kw.setdefault("max_seq_len", 128)
         kw.setdefault("remat", False)
         return LlamaConfig(**kw)
+
+
+def scaling_pairs(group: Optional[Dict[str, Any]]):
+    """A published ``rope_scaling`` group as ``LlamaConfig.rope_scaling``
+    holds it (hashable: sorted pairs); None stays None."""
+    return None if group is None else tuple(sorted(group.items()))
 
 
 def logical_axes(config: LlamaConfig) -> Params:
@@ -430,7 +489,9 @@ def _init_hybrid_params(c: LlamaConfig, key: jax.Array) -> Params:
 
 
 def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
-    """The afmoe family's tree, laid out as the hybrids' is: ``embed``,
+    """The afmoe family's tree (and the latent-attention family's, whose
+    attention weights are :func:`mla.init_attention`'s), laid out as the
+    hybrids' is: ``embed``,
     ``lm_head``, ``final_norm``, ``layers`` = the stacked experts
     ``[L_moe, held, ...]`` alone (read in place at the layer's index
     among ROUTED layers: the leading dense layers have none), and
@@ -461,13 +522,16 @@ def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
     runs = []
     for r, (_, start, n, _) in enumerate(layer_runs(c)):
         k = jax.random.split(jax.random.fold_in(k_runs, r), 20)
-        tree = {
-            "attn_norm": norm(k[0], n, E), "mlp_norm": norm(k[1], n, E),
-            "wq": dense(k[2], E, n, E, H, D),
-            "wk": dense(k[3], E, n, E, KV, D),
-            "wv": dense(k[4], E, n, E, KV, D),
-            "wo": dense(k[5], H * D, n, H, D, E),
-        }
+        tree = {"attn_norm": norm(k[0], n, E), "mlp_norm": norm(k[1], n, E)}
+        if c.latent_layers:
+            from ray_tpu.models import mla
+
+            tree.update(mla.init_attention(c, k[2], n))
+        else:
+            tree.update({"wq": dense(k[2], E, n, E, H, D),
+                         "wk": dense(k[3], E, n, E, KV, D),
+                         "wv": dense(k[4], E, n, E, KV, D),
+                         "wo": dense(k[5], H * D, n, H, D, E)})
         if c.sandwich_norms:
             tree["post_attn_norm"] = norm(k[6], n, E)
             tree["post_mlp_norm"] = norm(k[7], n, E)
@@ -562,10 +626,11 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
                num_layers: Optional[int] = None):
     """The layer stack as RUNS of equal layers, in order: a list of
     ``(kind, start, count, kind_start)`` where ``kind`` is "attention",
-    "mamba", "sliding_attention" or "full_attention", ``start`` the
-    run's first GLOBAL layer and ``kind_start`` its first index among
-    layers that share its cache (the K/V arena's layer for attention and
-    full attention, the state cache's for mamba, the ring's for sliding
+    "mamba", "sliding_attention", "full_attention" or "latent_attention",
+    ``start`` the run's first GLOBAL layer and ``kind_start`` its first
+    index among layers that share its cache (the K/V arena's layer for
+    attention and full attention, the state cache's for mamba, the
+    ring's for sliding attention, the latent cache's for latent
     attention). A model without ``layer_types`` is one attention run.
 
     With ``params``: ``(runs, experts)``, each run followed by the tree
@@ -577,7 +642,8 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
     if len(types) != c.num_layers:
         raise ValueError(f"layer_types names {len(types)} layers, "
                          f"num_layers is {c.num_layers}")
-    runs, seen = [], {"attention": 0, "mamba": 0, "sliding_attention": 0}
+    runs, seen = [], {"attention": 0, "mamba": 0, "sliding_attention": 0,
+                      "latent_attention": 0}
     for i, kind in enumerate(types):
         # "full_attention" keeps all its K/V in the arena, as "attention"
         # does: they count as one kind of cache.
@@ -718,6 +784,25 @@ def _swiglu(h, w_gate, w_up, w_down, c: LlamaConfig,
     return jnp.einsum("bsm,me->bse", act, w_down.astype(c.dtype))
 
 
+# The most bytes the routed block's sorted rows ``[T * k, E]`` may take
+# for a HELD SHARE, whose rows are mostly assignments to absent experts
+# that are gathered, never multiplied, and thrown away: a longer batch
+# runs the block a piece of its tokens at a time. (8 x 1024 tokens of
+# Kimi K2's 7168 at top 8 are 940 MB, beside as much again for the
+# results and twice that for their float32 sum; Trinity's 201 MB stay one
+# piece.)
+ROUTED_SORT_BYTES = 256 << 20
+
+
+def _routed_pieces(c: LlamaConfig, tokens: int, width: int, dtype) -> int:
+    pieces = 1
+    while (c.experts_held and tokens % (2 * pieces) == 0
+           and tokens // pieces * c.num_experts_per_tok * width
+           * jnp.dtype(dtype).itemsize > ROUTED_SORT_BYTES):
+        pieces *= 2
+    return pieces
+
+
 def mlp_block(h, layer, c: LlamaConfig, experts=None, li=None,
               mesh: Optional[Mesh] = None, use_kernel=None):
     """The layer's MLP sublayer on normed ``h [B, S, E]``: ``(out,
@@ -741,10 +826,19 @@ def mlp_block(h, layer, c: LlamaConfig, experts=None, li=None,
             extra["held"] = c.experts_held
         if c.num_dense_layers:
             li = li - c.num_dense_layers    # its index among routed layers
-        out, routed = moe.routed_block(
-            h.reshape(b * s, e), layer["w_router"], experts, li,
-            top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob,
-            use_kernel=use_kernel, **extra)
+        block = functools.partial(
+            moe.routed_block, w_router=layer["w_router"], experts=experts,
+            layer=li, top_k=c.num_experts_per_tok,
+            norm_topk=c.norm_topk_prob, use_kernel=use_kernel, **extra)
+        pieces = _routed_pieces(c, b * s, e, h.dtype)
+        if pieces == 1:
+            out, routed = block(h.reshape(b * s, e))
+        else:
+            # No token's result depends on the other rows, so the pieces'
+            # results are the whole batch's.
+            out, routed = jax.lax.map(block, h.reshape(pieces, -1, e))
+            routed = moe.Routed(routed.rows.sum(axis=0),
+                                routed.experts.reshape(b * s, -1))
         out = out.reshape(b, s, e)
         if c.shared_intermediate_size:
             # GraniteMoeHybridDecoderLayer.forward: moe(h) + shared_mlp(h),
@@ -826,8 +920,9 @@ def forward(
     c = config
     if c.layer_types:
         raise NotImplementedError(
-            "a config with layer_types (state-space or sliding-window "
-            "layers) is served by the continuous-batching engine only: "
+            "a config with layer_types (state-space, sliding-window or "
+            "latent-attention layers) is served by the continuous-batching "
+            "engine only: "
             "llama.forward, loss_fn and LlamaGenerator do not run it")
     seq_len = tokens.shape[1]
     cos, sin = rope_frequencies(c.head_dim, seq_len, c.rope_theta)

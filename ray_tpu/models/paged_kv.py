@@ -172,6 +172,49 @@ class PagedKVCache(NamedTuple):
         return PagedKVCache(**fields)
 
 
+class LatentKVCache(NamedTuple):
+    """The cache of a model whose every layer is LATENT attention
+    (``models/mla.py``), in the arena's place: ``k [L, NB, 1, bs, W]``,
+    one row a token a layer, ``[c_kv | k_rope | 0]`` padded to whole
+    lane tiles (``mla.row_width``), key and value at once and shared by
+    every head; no V plane, no scales. The arena's block layout with one
+    "kv head", so ONE block table a slot serves every layer, the
+    :class:`BlockAllocator` and the :class:`RadixBlockIndex` hand its
+    blocks out as they do the arena's, and ``paged_kv_write`` and the
+    ``paged_visits`` schedule take it as they take the arena."""
+
+    k: jnp.ndarray
+
+    @classmethod
+    def create(cls, config: llama.LlamaConfig, num_blocks: int,
+               block_size: int) -> "LatentKVCache":
+        from ray_tpu.models import mla
+
+        return cls(k=jnp.zeros(
+            (config.latent_layers, num_blocks, 1, block_size,
+             mla.row_width(config)), config.dtype))
+
+    @property
+    def block_size(self) -> int:
+        return self.k.shape[3]
+
+    @property
+    def num_blocks(self) -> int:
+        return self.k.shape[1]
+
+    quantized = False
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.k.nbytes)
+
+    def token_bytes(self) -> int:
+        """Bytes one live token occupies across all layers (the pad to
+        whole lanes included: the kernel reads it)."""
+        layers, _, _, _, w = self.k.shape
+        return layers * w * jnp.dtype(self.k.dtype).itemsize
+
+
 class StateCache(NamedTuple):
     """What the state-space layers of a hybrid model keep for each slot,
     beside the K/V arena: ``ssm [L_ssm, slots, H, N / f, f * P]``

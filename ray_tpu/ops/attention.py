@@ -434,7 +434,7 @@ def flash_attention(
 def paged_chunk_attention(q, k_new, v_new, arena_k, arena_v, layer, tables,
                           first_pos: int, chunk_pos: int, scale: float, *,
                           window: int = 0, key_blocks: int = 4,
-                          key_step: int = 256):
+                          key_step: int = 256, expand=None):
     """Causal attention of ONE CHUNK of a prompt: queries ``q [N, S, Hq,
     D]`` at absolute positions ``chunk_pos + arange(S)`` over the
     prompt's earlier keys, which lie in a paged cache, and then over the
@@ -451,9 +451,17 @@ def paged_chunk_attention(q, k_new, v_new, arena_k, arena_v, layer, tables,
     first chunk). With ``window`` a query sees only its last ``window``
     keys, itself included; the caller then lists only blocks that hold
     one (a ring's live entries), so blocks out of range cost nothing.
-    Returns ``[N, S, Hq, D]`` in ``q``'s dtype."""
+
+    ``expand`` (a LATENT cache, ``models/mla.py``): the cache holds no
+    per-head K/V; ``expand(arena_k[layer, idx]) -> (keys, values)`` makes
+    them from a step's gathered blocks ``[N, g, ...]``, ``[N, g * bs,
+    KVH, D]`` and ``[N, g * bs, KVH, Dv]``, so they exist for one step's
+    keys at a time (``arena_v`` is then not read). The values' width may
+    differ from the keys'.
+    Returns ``[N, S, Hq, Dv]`` in ``q``'s dtype."""
     n, s, hq, d = q.shape
     hkv = k_new.shape[2]
+    dv = v_new.shape[-1]
     bs = arena_k.shape[3]
     qg = q.reshape(n, s, hkv, hq // hkv, d)
     q_pos = chunk_pos + jnp.arange(s)
@@ -483,7 +491,7 @@ def paged_chunk_attention(q, k_new, v_new, arena_k, arena_v, layer, tables,
     lead = qg.shape[:-1]
     carry = (jnp.full(lead, DEFAULT_MASK_VALUE, jnp.float32),
              jnp.zeros(lead, jnp.float32),
-             jnp.zeros(lead + (d,), jnp.float32))
+             jnp.zeros(lead + (dv,), jnp.float32))
 
     m_blocks = tables.shape[1]
     if m_blocks:
@@ -497,8 +505,9 @@ def paged_chunk_attention(q, k_new, v_new, arena_k, arena_v, layer, tables,
         def from_cache(carry, step):
             i, idx = step
             k_pos = first_pos + i * (g * bs) + jnp.arange(g * bs)
-            return attend(carry, blocks(arena_k, idx), blocks(arena_v, idx),
-                          k_pos), None
+            kb, vb = (expand(arena_k[layer, idx]) if expand else
+                      (blocks(arena_k, idx), blocks(arena_v, idx)))
+            return attend(carry, kb, vb, k_pos), None
 
         steps = m_blocks // g
         carry, _ = jax.lax.scan(
@@ -513,9 +522,9 @@ def paged_chunk_attention(q, k_new, v_new, arena_k, arena_v, layer, tables,
         return attend(carry, kb, vb, chunk_pos + i * t + jnp.arange(t)), None
 
     def stepped(a):
-        return jnp.swapaxes(a.reshape(n, s // t, t, hkv, d), 0, 1)
+        return jnp.swapaxes(a.reshape(n, s // t, t, hkv, a.shape[-1]), 0, 1)
 
     (_, l, acc), _ = jax.lax.scan(
         from_chunk, carry, (jnp.arange(s // t), stepped(k_new),
                             stepped(v_new)))
-    return (acc / l[..., None]).reshape(n, s, hq, d).astype(q.dtype)
+    return (acc / l[..., None]).reshape(n, s, hq, dv).astype(q.dtype)

@@ -158,6 +158,19 @@ def unregister_role_group(name: str) -> bool:
         return _ROLE_GROUPS.pop(name, None) is not None
 
 
+# The replica actor's concurrency group for what the CONTROLLER asks of
+# it (health, metrics, pressure, node id, drain). Requests run in the
+# default group, capped at the deployment's ``max_ongoing_requests``; a
+# caller pool larger than that cap keeps every one of those places taken
+# for as long as the pool lasts, with more requests queued behind them.
+# A probe in the same queue would go unanswered for as long, and the
+# controller would kill a healthy, full replica after
+# ``REPLICA_STARTUP_GRACE_S``; in a group of its own it is answered
+# whatever the requests do.
+CONTROL_GROUP = "control"
+CONTROL_CONCURRENCY = 8
+
+
 class Replica:
     """Hosts one copy of the user callable.
 
@@ -293,12 +306,14 @@ class Replica:
             with self._m_lock:
                 self._ongoing -= 1
 
+    @ray_tpu.method(concurrency_group=CONTROL_GROUP)
     def metrics(self):
         """Ongoing-request count the autoscaler averages (reference:
         replica metrics pushed to the controller, autoscaling_policy.py)."""
         with self._m_lock:
             return {"ongoing": self._ongoing, "total": self._total}
 
+    @ray_tpu.method(concurrency_group=CONTROL_GROUP)
     def pressure(self):
         """Pressure snapshot for the serve pressure endpoint: router
         in-flight counts plus whatever the hosted callable reports (the
@@ -315,9 +330,11 @@ class Replica:
                     pass           # fail requests' host process
         return out
 
+    @ray_tpu.method(concurrency_group=CONTROL_GROUP)
     def health(self):
         return True
 
+    @ray_tpu.method(concurrency_group=CONTROL_GROUP)
     def node_id(self):
         """The node hosting this replica — the controller's key for
         preemption-notice targeting (a notice naming a node drains that
@@ -327,6 +344,7 @@ class Replica:
         except Exception:  # noqa: BLE001 — no runtime context: untargetable
             return ""
 
+    @ray_tpu.method(concurrency_group=CONTROL_GROUP)
     async def drain(self, deadline_s: Optional[float] = None):
         """Controller-initiated graceful drain: stop admitting (new
         requests get a clean :class:`ReplicaDrainingError` reject and
@@ -1085,6 +1103,7 @@ class ServeController:
         current = live
         opts: Dict[str, Any] = dict(spec.get("actor_options") or {})
         opts["max_concurrency"] = spec["max_concurrency"]
+        opts["concurrency_groups"] = {CONTROL_GROUP: CONTROL_CONCURRENCY}
         placement = spec.get("placement")
         if placement == "COMPACT":
             strategy, regrown = self._compact_group_strategy(name, spec)

@@ -304,13 +304,12 @@ class _Router:
                 return DisaggRecoverableStream(
                     self.handle(group["prefill"]),
                     self.handle(group["decode"]),
-                    journal, per_item_timeout_s=60.0)
+                    journal)
             name = group["decode"]
         journal = RequestJournal(name, method, payload,
                                  model_id=model_id,
                                  request_ctx=request_ctx)
-        return RecoverableStream(self.handle(name), journal,
-                                 per_item_timeout_s=60.0)
+        return RecoverableStream(self.handle(name), journal)
 
 
 def _pull_ready(items, held: List[Any]) -> Any:

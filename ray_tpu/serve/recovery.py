@@ -240,6 +240,17 @@ class RequestJournal:
                 "max_tokens": remaining, "resumed_tokens": len(toks)}
 
 
+# How long a stream's consumer waits for its NEXT item, at the ingress
+# and on the replica alike (``llm.generate`` waits as long on its
+# engine). A request that waits for a slot behind a full engine has no
+# item to give: a worker pool of twice the engine's slots on minute-long
+# answers waits over a minute for its first token, so an ingress less
+# patient than the replica it fronts would fail requests the replica
+# goes on to serve. A dead replica is not found by this clock
+# (``ActorDiedError`` is), only a hung one.
+STREAM_ITEM_TIMEOUT_S = 300.0
+
+
 class RecoverableStream:
     """Iterator over a streaming deployment call that survives replica
     death and drain. Wraps the handle dispatch: every pull that raises
@@ -249,7 +260,7 @@ class RecoverableStream:
     router path handles ``ActorDiedError`` (source-linted)."""
 
     def __init__(self, handle, journal: RequestJournal,
-                 per_item_timeout_s: Optional[float] = 60.0):
+                 per_item_timeout_s: Optional[float] = STREAM_ITEM_TIMEOUT_S):
         self._handle = handle
         self.journal = journal
         self._timeout = per_item_timeout_s
@@ -434,7 +445,7 @@ class DisaggRecoverableStream(RecoverableStream):
 
     def __init__(self, prefill_handle, decode_handle,
                  journal: RequestJournal,
-                 per_item_timeout_s: Optional[float] = 60.0):
+                 per_item_timeout_s: Optional[float] = STREAM_ITEM_TIMEOUT_S):
         super().__init__(decode_handle, journal, per_item_timeout_s)
         self._prefill_handle = prefill_handle
         # True between note_handoff and clean stream end: a death in

@@ -216,3 +216,30 @@ def test_http_proxy():
     assert out == {"got": {"a": 1}}
     serve.stop_http()
     serve.delete("echo")
+
+
+def test_a_replica_full_of_requests_still_answers_the_controller():
+    """More callers than ``max_ongoing_requests`` keep every request
+    place of the replica taken and more calls queued behind them; the
+    controller's probes run in a concurrency group of their own
+    (``CONTROL_GROUP``), so they are answered at once. In the default
+    group they waited behind the requests, and the controller killed the
+    full, healthy replica after its grace."""
+    @serve.deployment(max_ongoing_requests=2)
+    class Slow:
+        def __call__(self, x):
+            time.sleep(1.5)
+            return x
+
+    handle = serve.run(Slow.bind())
+    responses = [handle.remote(i) for i in range(6)]   # 2 run, 4 wait
+    time.sleep(0.3)
+    controller = ray_tpu.get_actor("__serve_controller__")
+    replica, = ray_tpu.get(controller.get_replicas.remote("Slow"))
+    t0 = time.monotonic()
+    assert ray_tpu.get(replica.health.remote(), timeout=1.0) is True
+    assert ray_tpu.get(replica.metrics.remote(), timeout=1.0)["ongoing"] >= 2
+    assert "ongoing" in ray_tpu.get(replica.pressure.remote(), timeout=1.0)
+    assert time.monotonic() - t0 < 1.0
+    assert [r.result() for r in responses] == list(range(6))
+    serve.delete("Slow")

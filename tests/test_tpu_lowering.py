@@ -664,3 +664,88 @@ def test_paged_decode_attn_lowers_with_a_window(window):
     exported = jax.export.export(jax.jit(attend), platforms=["tpu"])(
         q, arena, arena, tables, pos, S((), jnp.int32))
     assert "tpu_custom_call" in exported.mlir_module()
+
+
+# ------------------------------------------------- latent attention (MLA)
+
+_MLA_SLOTS, _MLA_LEN, _MLA_BS = 64, 10240, 64
+
+
+def test_latent_decode_kernel_lowers():
+    """Kimi K2's head shape: 64 absorbed queries of 640 lanes (512 latent
+    + 64 rope + 64 pad) a slot, values the first 512, blocks of 64."""
+    from ray_tpu.ops.latent_decode_attention import latent_decode_attention
+
+    nb = _MLA_LEN // _MLA_BS
+    q = S((8, 64, 640), BF16)
+    arena = S((5, 1 + 8 * nb, 1, _MLA_BS, 640), BF16)
+    fn = functools.partial(latent_decode_attention, scale=0.14, rank=512,
+                           use_kernel=True)
+    exported = jax.export.export(jax.jit(
+        lambda q, a, t, p, li: fn(q, a, t, p, layer=li)), platforms=["tpu"])(
+        q, arena, S((8, nb), jnp.int32), S((8,), jnp.int32),
+        S((), jnp.int32))
+    assert _kernel_names(exported.mlir_module()) == ["latent_decode_attn"]
+
+
+def test_latent_prefill_kernel_lowers():
+    """A chunk of 1024 queries of 64 heads against a run of 1024 keys
+    256 lanes wide (192 padded) and values 128 wide, causal and not."""
+    from ray_tpu.ops.latent_prefill_attention import attend_run
+
+    q = S((2, 64, 1024, 256), BF16)
+    v = S((2, 64, 1024, 128), BF16)
+    for causal in (True, False):
+        exported = jax.export.export(jax.jit(functools.partial(
+            attend_run, scale=0.14, causal=causal)), platforms=["tpu"])(
+            q, q, v)
+        assert _kernel_names(exported.mlir_module()) == [
+            "latent_prefill_attn"]
+
+
+def test_compiled_latent_tick_reads_the_cache_through_its_kernels(v5e_chip):
+    """The cell ``serve_mla_decode``'s tick, compiled for a described
+    v5e at the cell's own sizes (Kimi K2's widths, 5 layers, 12 of 384
+    experts, 64 slots x 10240 over 10,241 blocks): each of the two runs'
+    layer bodies touches the latent cache through ``paged_kv_write`` and
+    ``latent_decode_attn`` and nothing else (no slab of ``[10241, 1, 64,
+    640]`` is sliced, copied or scattered), the routed run calls
+    ``moe_gmm`` three times, and beside the 11.2 GB of arguments (7.0 GB
+    of weights, 4.2 GB of cache, donated) the program needs under 64 MB."""
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models.paged_kv import LatentKVCache
+
+    sharding = v5e_chip
+    cfg = llama.LlamaConfig.kimi_k2_7_code(
+        vocab_size=20480, num_layers=5,
+        layer_types=("latent_attention",) * 5, experts_held=(0, 12),
+        max_seq_len=_MLA_LEN)
+
+    def spec(a):
+        return S(a.shape, a.dtype, sharding=sharding)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        functools.partial(cb.init_engine_params, cfg),
+        jax.random.PRNGKey(0)))
+    blocks = 1 + _MLA_SLOTS * (_MLA_LEN // _MLA_BS)
+    cache = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        LatentKVCache.create, cfg, blocks, _MLA_BS)))
+    row = S((_MLA_SLOTS,), jnp.int32, sharding=sharding)
+    tables = S((_MLA_SLOTS, _MLA_LEN // _MLA_BS), jnp.int32,
+               sharding=sharding)
+    step = S((), jnp.int32, sharding=sharding)
+    tick = functools.partial(cb._decode_tick_paged, config=cfg,
+                             use_kernel=True)
+    compiled = jax.jit(tick, donate_argnums=(5,)).lower(
+        params, row, row, tables, row, cache, step).compile()
+    hlo = compiled.as_text()
+    for name, calls in (("latent_decode_attn", 2), ("paged_kv_write", 2),
+                        ("moe_gmm", 3)):
+        assert len(re.findall(rf"%{name}[.\d]* = ", hlo)) == calls, name
+    shaped = re.compile(rf"= \(?\w+\[(\d+,)?{blocks},1,{_MLA_BS},640\]")
+    moved = [line.strip() for line in hlo.splitlines()
+             if shaped.search(line) and not any(f in line for f in _FREE)]
+    assert moved == []
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 << 20
+    assert memory.alias_size_in_bytes == cache.k.size * 2
