@@ -741,7 +741,7 @@ def phase_kernels(rehearse: bool) -> None:
     # Stacked [3, X, K, N] weights read at layer 1, against a masked loop
     # over the experts in XLA, at OLMoE's widths: decode (48 slots x top
     # 8), a one-row and an eight-row prefill of 128 tokens.
-    from ray_tpu.ops.moe import grouped_matmul
+    from ray_tpu.ops.moe import grouped_matmul, grouped_swiglu
 
     x_, widths, row_counts = ((8, [(64, 32)], [24, 300]) if rehearse else
                               (64, [(2048, 1024), (1024, 2048)],
@@ -749,8 +749,13 @@ def phase_kernels(rehearse: bool) -> None:
     gmm = jax.jit(lambda a, w, g: grouped_matmul(a, w, g, jnp.int32(1),
                                                  use_kernel=True))
 
+    # Gate and up in one call (PR 37), against the same loop's two
+    # float32 products: the activation is rounded once.
+    swiglu = jax.jit(lambda a, w, u, g: grouped_swiglu(
+        a, w, u, g, jnp.int32(1), use_kernel=True))
+
     @jax.jit
-    def masked_loop(a, w, g):
+    def masked_loop(a, w, g):                   # float32
         ends = jnp.cumsum(g)
         rows = jnp.arange(a.shape[0])
         out = jnp.zeros((a.shape[0], w.shape[-1]), jnp.float32)
@@ -758,7 +763,7 @@ def phase_kernels(rehearse: bool) -> None:
             mine = (rows >= ends[e] - g[e]) & (rows < ends[e])
             out = jnp.where(mine[:, None], jnp.dot(
                 a, w[1, e], preferred_element_type=jnp.float32), out)
-        return out.astype(a.dtype)
+        return out
 
     for kk, nn in widths:
         w = (jax.random.normal(keys[9], (3, x_, kk, nn), jnp.float32)
@@ -774,7 +779,18 @@ def phase_kernels(rehearse: bool) -> None:
             if not rehearse:
                 assert _mosaic_calls(gmm.lower(a, w, g).compile()) == 1
             _check(phase, f"moe_gmm [{m},{kk}] x [{x_},{kk},{nn}] bf16",
-                   gmm(a, w, g), masked_loop(a, w, g), TOLERANCE["moe_gmm"])
+                   gmm(a, w, g), masked_loop(a, w, g).astype(bf16),
+                   TOLERANCE["moe_gmm"])
+            if kk > nn:                 # gate's and up's shape
+                u = jnp.flip(w, axis=1)
+                if not rehearse:
+                    assert _mosaic_calls(
+                        swiglu.lower(a, w, u, g).compile()) == 1
+                want = (jax.nn.silu(masked_loop(a, w, g))
+                        * masked_loop(a, u, g)).astype(bf16)
+                _check(phase, f"moe_gmm silu(gate) * up [{m},{kk}] x 2 x "
+                              f"[{x_},{kk},{nn}] bf16",
+                       swiglu(a, w, u, g), want, TOLERANCE["moe_gmm"])
     _time_paged_cells(phase, info["kind"], rehearse)
     _latent_kernel(phase, info["kind"], rehearse)
     _finish(phase, info)

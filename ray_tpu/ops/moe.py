@@ -16,10 +16,11 @@ the ``expert`` mesh axis). Serving routes through :func:`routed_block`
 instead, which is DROPLESS: in a served batch a dropped token would make
 one user's answer depend on who else shares the batch. It sorts the
 ``T x k`` assignments by expert and runs a grouped matrix multiplication
-over the sorted rows (:func:`grouped_matmul`: the ``moe_gmm`` Mosaic
-kernel on the TPU, ``jax.lax.ragged_dot`` elsewhere), so no ``[T, E, C]``
-tensor exists, an untouched expert's weights are never read, and every
-routed token is computed. The kernel takes the STACKED ``[L, X, K, N]``
+over the sorted rows (:func:`grouped_swiglu` for gate and up in one
+call, :func:`grouped_matmul` for down: the ``moe_gmm`` Mosaic kernel on
+the TPU, ``jax.lax.ragged_dot`` elsewhere), so no ``[T, E, C]`` tensor
+exists, an untouched expert's weights are never read, and every routed
+token is computed. The kernel takes the STACKED ``[L, X, K, N]``
 weights with the layer as a scalar-prefetch operand (as the paged
 attention kernels take the arena), so a layer scan never slices, and so
 never copies, a layer's experts.
@@ -193,21 +194,50 @@ def route_sigmoid_topk(h: jnp.ndarray, w_router: jnp.ndarray, k: int,
     return weights * scale, idx.astype(jnp.int32)
 
 
+# What one ``moe_gmm`` call may hold in VMEM, declared to the compiler
+# (``vmem_limit_bytes``; Mosaic's own default is 16 MiB of a v5e's 128).
+GMM_VMEM_BYTES = 40 << 20
+# An expert's ``[K, N]`` matrix up to this size is one window, fetched
+# whole. A step holds one (down) or two (gate beside up), each
+# double-buffered: 16 MiB of the budget at most, the rest the row tiles
+# and the float32 accumulators.
+GMM_WHOLE_BYTES = 4 << 20
+# A larger matrix is fetched in windows ``[K, tn]`` of at most this.
+GMM_WINDOW_BYTES = 2 << 20
+
+
 def _gmm_tiles(m: int, k: int, n: int, num_groups: int, itemsize: int):
-    """(tm, tn). A group that straddles a row tile is visited, and its
+    """(tm, tn), from the shapes alone.
+
+    ``tn``: an expert's matrix of at most :data:`GMM_WHOLE_BYTES` is
+    fetched WHOLE, one contiguous read and one grid step a visit; a
+    larger one in the widest ``N / 2^i`` of whole 128-lane tiles whose
+    ``[K, tn]`` window is at most :data:`GMM_WINDOW_BYTES`. A call is
+    its weight DMAs (timed on the chip on each configuration's own
+    stack, PR 37, PERF.md section 6: 730-740 GB/s), and a window that
+    is half of a 2 KiB-wide matrix (``[2048, 512]``: 16 KiB runs at a
+    32 KiB stride) read 633 GB/s from OLMoE's 12-layer stack, which
+    cost its tick a tenth. Wider windows of the larger experts were
+    timed too: 4.5 MiB of a ``[3072, 3072]`` reads 4% faster than 2.25,
+    3.5 MiB of a ``[7168, 2048]`` 3% SLOWER than 1.75, 3-6 MiB of a
+    ``[4096, 768]`` the same: they keep the 2 MiB rule.
+
+    ``tm``: a group that straddles a row tile is visited, and its
     weights read, once per tile, so tiles are tall where groups are
-    (about twice the mean group, 128 to 512 rows); ``tn`` keeps one
-    ``[K, tn]`` weight tile at or under 2 MiB where halving allows."""
+    (about twice the mean group, 128 to 512 rows), and ``[tm, K]`` stays
+    at or under 2 MiB (K = 4096, Granite 4.0-H). Tiles of 64, 32 and 16
+    rows were timed at 6 rows a group and lose 1-6%: a step costs what
+    its window's bytes cost whatever the rows, and shorter tiles are
+    more visits."""
     tm = 128
     while tm < 512 and tm * num_groups < 2 * m:
         tm *= 2
-    # ... and one [tm, K] row tile too (K = 4096, Granite 4.0-H: with
-    # 512 rows the two tiles, double-buffered, pass the 16 MiB of VMEM).
     while tm > 128 and tm * k * itemsize > (2 << 20):
         tm //= 2
     tn = n
-    while tn % 256 == 0 and k * tn * itemsize > (2 << 20):
-        tn //= 2
+    if k * n * itemsize > GMM_WHOLE_BYTES:
+        while tn % 256 == 0 and k * tn * itemsize > GMM_WINDOW_BYTES:
+            tn //= 2
     return tm, tn
 
 
@@ -240,14 +270,21 @@ def _visits(group_sizes, tm: int, tiles_m: int):
 
 
 def _gmm_kernel(layer_ref, gid_ref, tid_ref, bounds_ref, total_ref,
-                lhs_ref, rhs_ref, out_ref, *, tm):
+                lhs_ref, *refs, tm):
+    *rhs_refs, out_ref = refs
     v = pl.program_id(1)
 
     @pl.when(v < total_ref[0])
     def _visit():
         g = gid_ref[v]
-        acc = jnp.dot(lhs_ref[...], rhs_ref[0, 0],
+        lhs = lhs_ref[...]
+        acc = jnp.dot(lhs, rhs_refs[0][0, 0],
                       preferred_element_type=jnp.float32)
+        if len(rhs_refs) == 2:
+            # Gate's window beside up's: the activation from the two
+            # float32 accumulators, rounded once on its way out.
+            acc = jax.nn.silu(acc) * jnp.dot(
+                lhs, rhs_refs[1][0, 0], preferred_element_type=jnp.float32)
         rows = tid_ref[v] * tm + jax.lax.broadcasted_iota(
             jnp.int32, acc.shape, 0)
         mine = (rows >= bounds_ref[g]) & (rows < bounds_ref[g + 1])
@@ -257,24 +294,30 @@ def _gmm_kernel(layer_ref, gid_ref, tid_ref, bounds_ref, total_ref,
             mine, acc, out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 
-def _gmm_fused(lhs, rhs, group_sizes, layer, *, interpret):
+def _gmm_call(lhs, weights, group_sizes, layer, *, interpret, tiles=None):
+    """One ``moe_gmm`` Pallas call over ``weights``, one stacked
+    ``[L, X, K, N]`` array (``lhs @ w``) or two (``silu(lhs @ w0) *
+    (lhs @ w1)``, both windows of a visit fetched in the same grid
+    step). ``tiles`` overrides :func:`_gmm_tiles` (tests that walk
+    several tiles at small sizes; timing scripts)."""
     m, k = lhs.shape
-    _, x, _, n = rhs.shape
-    itemsize = jnp.dtype(rhs.dtype).itemsize
-    tm, tn = _gmm_tiles(m, k, n, x, itemsize)
+    _, x, _, n = weights[0].shape
+    itemsize = jnp.dtype(weights[0].dtype).itemsize
+    tm, tn = tiles or _gmm_tiles(m, k, n, x, itemsize)
     tiles_m = -(-m // tm)
     if tiles_m * tm != m:
         lhs = jnp.pad(lhs, ((0, tiles_m * tm - m), (0, 0)))
     gid, tid, bounds, total = _visits(group_sizes, tm, tiles_m)
+    window = pl.BlockSpec((1, 1, k, tn),
+                          lambda j, v, ly, gid, tid, bnd, tot:
+                          (ly[0], gid[v], 0, j))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(n // tn, tiles_m + x - 1),
         in_specs=[
             pl.BlockSpec((tm, k),
                          lambda j, v, ly, gid, tid, bnd, tot: (tid[v], 0)),
-            pl.BlockSpec((1, 1, k, tn),
-                         lambda j, v, ly, gid, tid, bnd, tot:
-                         (ly[0], gid[v], 0, j)),
+            *[window] * len(weights),
         ],
         out_specs=pl.BlockSpec(
             (tm, tn), lambda j, v, ly, gid, tid, bnd, tot: (tid[v], j)),
@@ -286,14 +329,42 @@ def _gmm_fused(lhs, rhs, group_sizes, layer, *, interpret):
         interpret=interpret,
         name="moe_gmm",
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=GMM_VMEM_BYTES),
         cost_estimate=pl.CostEstimate(
             # Static worst case: every expert touched.
-            flops=2 * m * k * n, transcendentals=0,
-            bytes_accessed=(x * k * n + m * k + m * n) * itemsize),
+            flops=2 * m * k * n * len(weights),
+            transcendentals=m * n * (len(weights) - 1),
+            bytes_accessed=(len(weights) * x * k * n + m * k + m * n)
+            * itemsize),
     )(jnp.asarray(layer, jnp.int32).reshape(1), gid, tid, bounds, total,
-      lhs, rhs)
+      lhs, *weights)
     return out[:m]
+
+
+def _grouped(lhs, weights, group_sizes, layer, use_kernel, ragged):
+    """The dispatch both grouped multiplications share: the ``moe_gmm``
+    call over ``weights`` (one layer's ``[X, K, N]`` given a unit layer
+    axis), or ``ragged(lhs, weights, sizes)`` over the layer's own
+    ``[X, K, N]`` (the stacked ones indexed at ``layer``)."""
+    stacked = weights[0].ndim == 4
+    if (layer is None) == stacked:
+        raise ValueError("stacked weights need `layer`; one layer's take none")
+    interpret = interpret_default()
+    tiles = gmm_applicable(weights[0].shape[-2], weights[0].shape[-1],
+                           jnp.dtype(weights[0].dtype).itemsize)
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu" and tiles
+    weights = [w.astype(lhs.dtype) for w in weights]
+    if use_kernel and (interpret or tiles):
+        if not stacked:
+            layer, weights = 0, [w[None] for w in weights]
+        return _gmm_call(lhs, weights, group_sizes, layer,
+                         interpret=interpret)
+    if stacked:
+        weights = [jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+                   for w in weights]
+    return ragged(lhs, weights, group_sizes.astype(jnp.int32))
 
 
 def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
@@ -308,23 +379,35 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
     ``moe_gmm`` kernel on the TPU when the widths tile,
     ``jax.lax.ragged_dot`` elsewhere; True forces the kernel (interpret
     mode off the TPU: the CPU tier-1 path); False forces ``ragged_dot``,
-    which needs the layer's experts as an array of their own."""
-    if (layer is None) != (rhs.ndim == 3):
-        raise ValueError("stacked weights need `layer`; one layer's take none")
-    interpret = interpret_default()
-    tiles = gmm_applicable(rhs.shape[-2], rhs.shape[-1],
-                           jnp.dtype(rhs.dtype).itemsize)
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu" and tiles
-    if use_kernel and (interpret or tiles):
-        if layer is None:
-            layer, rhs = 0, rhs[None]
-        return _gmm_fused(lhs, rhs.astype(lhs.dtype), group_sizes, layer,
-                          interpret=interpret)
-    if layer is not None:
-        rhs = jax.lax.dynamic_index_in_dim(rhs, layer, 0, keepdims=False)
-    return jax.lax.ragged_dot(lhs, rhs.astype(lhs.dtype),
-                              group_sizes.astype(jnp.int32))
+    which needs the layer's experts as an array of their own. The
+    kernel's tiles are :func:`_gmm_tiles`' and its VMEM budget
+    :data:`GMM_VMEM_BYTES`."""
+    return _grouped(lhs, [rhs], group_sizes, layer, use_kernel,
+                    lambda lhs, ws, sizes: jax.lax.ragged_dot(lhs, ws[0],
+                                                              sizes))
+
+
+def _ragged_swiglu(lhs, weights, sizes):
+    gate, up = (jax.lax.ragged_dot(lhs, w, sizes,
+                                   preferred_element_type=jnp.float32)
+                for w in weights)
+    return (jax.nn.silu(gate) * up).astype(lhs.dtype)
+
+
+def grouped_swiglu(lhs: jnp.ndarray, gate: jnp.ndarray, up: jnp.ndarray,
+                   group_sizes: jnp.ndarray, layer=None, *,
+                   use_kernel: Optional[bool] = None) -> jnp.ndarray:
+    """``out[r] = silu(lhs[r] @ gate[layer, g(r)]) * (lhs[r] @ up[layer,
+    g(r)])`` for rows sorted by group: :func:`grouped_matmul`'s operands
+    and dispatch, with two weight arrays of one shape. ONE ``moe_gmm``
+    call: a visit fetches its gate window and its up window, multiplies
+    the one row tile by both, and writes the activation; no ``[M, N]``
+    gate or up output exists. Both products are accumulated in float32
+    and the activation is taken from the accumulators and ROUNDED ONCE,
+    to ``lhs.dtype``, on every path (kernel, interpreted kernel,
+    ``ragged_dot``)."""
+    return _grouped(lhs, [gate, up], group_sizes, layer, use_kernel,
+                    _ragged_swiglu)
 
 
 class Routed(NamedTuple):
@@ -346,7 +429,11 @@ def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
     and ``moe_down`` [L, X, M, E] read at ``layer``, or one layer's
     ``[X, ...]`` with ``layer`` None. No capacity: every assignment is
     computed, and a token's result does not depend on the other rows.
-    ``route(x, w_router, top_k, norm_topk)`` is the router.
+    ``route(x, w_router, top_k, norm_topk)`` is the router. The experts
+    are TWO grouped calls on one schedule: :func:`grouped_swiglu` (gate
+    and up: the activation from float32 accumulators, rounded once to
+    ``x.dtype``; no ``[T * k, M]`` gate or up array exists), then
+    :func:`grouped_matmul` (down), at :func:`_gmm_tiles`' tiles.
 
     ``held = (first, count)``: of the router's X experts, ``experts``
     holds ``[first, first + count)`` (``[L, count, ...]``): the chip's
@@ -361,8 +448,6 @@ def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
     Returns (out [T, E], :class:`Routed`)."""
     t, _ = x.shape
     num_experts = w_router.shape[-1]
-    gmm = functools.partial(grouped_matmul, layer=layer,
-                            use_kernel=use_kernel)
     with jax.named_scope("moe"):
         with jax.named_scope("route"):
             weights, idx = route(x, w_router, top_k, norm_topk)
@@ -381,9 +466,10 @@ def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
                 rows = rows[:num_experts]
             xs = x[order // top_k]                       # [T * k, E]
         with jax.named_scope("experts"):
-            act = (jax.nn.silu(gmm(xs, experts["moe_gate"], rows))
-                   * gmm(xs, experts["moe_up"], rows))
-            ys = gmm(act, experts["moe_down"], rows)
+            act = grouped_swiglu(xs, experts["moe_gate"], experts["moe_up"],
+                                 rows, layer, use_kernel=use_kernel)
+            ys = grouped_matmul(act, experts["moe_down"], rows, layer,
+                                use_kernel=use_kernel)
         with jax.named_scope("combine"):
             # Back to [T, k, E] in each token's own top-k order, so the
             # weighted sum adds the same terms in the same order whoever

@@ -125,18 +125,159 @@ def test_routed_block_computes_every_assignment(tokens, use_kernel):
         np.bincount(np.asarray(routed.experts).ravel(), minlength=8))
 
 
-def test_gmm_schedule_visits_every_group_tile_pair_once():
+# The fused gate-and-up call: the four configurations' (K, N) an eighth
+# (Kimi: a sixteenth) as wide, with what a schedule can meet. ``sizes``
+# are the groups' rows in order; ``rows`` the row count (past the
+# groups' sum: absent assignments, as a held share sorts them); ``tiles``
+# an explicit (tm, tn) so that the small case still walks several row
+# tiles and column windows.
+SWIGLU_CASES = {
+    # OLMoE's tick in small: every group a few rows, one row tile.
+    "olmoe_every_group_small": dict(
+        k=256, n=128, sizes=[6, 3, 9, 1, 7, 5, 8, 9], rows=48,
+        tiles=(16, 128)),
+    # Granite: K over N; empty groups; rows not a multiple of tm.
+    "granite_empty_groups_ragged_rows": dict(
+        k=512, n=96, sizes=[0, 13, 0, 0, 20, 4, 0, 0, 0], rows=37,
+        tiles=(16, 96)),
+    # Trinity's held share: 3 of 24 rows absent behind the held groups,
+    # two column windows, a group (40 rows) straddling three row tiles.
+    "trinity_held_absent_rows_straddling_group": dict(
+        k=384, n=384, sizes=[2, 40, 0, 3], rows=48, tiles=(16, 128)),
+    # Kimi's held share: most rows absent, whole row tiles never visited.
+    "kimi_held_mostly_absent": dict(
+        k=448, n=128, sizes=[2, 0, 3, 1], rows=96, tiles=(32, 128)),
+    # No group has a row: nothing is visited, nothing defined is read.
+    "no_rows_at_all": dict(
+        k=256, n=128, sizes=[0, 0, 0], rows=32, tiles=(16, 128)),
+    # The tile rule's own choice (None) at a prefill's row count.
+    "prefill_rows_default_tiles": dict(
+        k=256, n=256, sizes=[150, 0, 90, 260, 12], rows=512, tiles=None),
+}
+
+
+def _swiglu_operands(case, dtype):
+    c = SWIGLU_CASES[case]
+    x = len(c["sizes"])
+    keys = jax.random.split(jax.random.PRNGKey(c["k"] + c["rows"]), 3)
+    lhs = jax.random.normal(keys[0], (c["rows"], c["k"])).astype(dtype)
+    gate, up = (
+        (jax.random.normal(key, (2, x, c["k"], c["n"])) / c["k"] ** 0.5
+         ).astype(dtype) for key in keys[1:])
+    return lhs, gate, up, jnp.asarray(c["sizes"], jnp.int32)
+
+
+def _swiglu_reference(lhs, gate, up, sizes, layer):
+    """float64 on the operands as given, the groups' rows alone."""
+    lhs, gate, up = (np.asarray(a, np.float64) for a in (lhs, gate, up))
+    out, r = [], 0
+    for g, size in enumerate(np.asarray(sizes)):
+        rows = lhs[r:r + size]
+        a = rows @ gate[layer, g]
+        out.append(a / (1 + np.exp(-a)) * (rows @ up[layer, g]))
+        r += size
+    return np.concatenate(out) if out else np.zeros((0, gate.shape[-1]))
+
+
+@pytest.mark.parametrize("path", ["ragged_dot", "moe_gmm_interpreted",
+                                  "moe_gmm_interpreted_bf16"])
+@pytest.mark.parametrize("case", list(SWIGLU_CASES))
+def test_grouped_swiglu_is_silu_gate_times_up_rounded_once(case, path):
+    """One call for gate and up, against the float64 reference: in
+    float32 to operation order; in bfloat16 to ONE rounding of the
+    activation (half an ulp: at most 2^-8 of the value; gate and up each
+    rounded first, as the block did before PR 37, is three roundings)."""
+    dtype = jnp.bfloat16 if path.endswith("bf16") else jnp.float32
+    lhs, gate, up, sizes = _swiglu_operands(case, dtype)
+    real = int(sizes.sum())
+    want = _swiglu_reference(lhs, gate, up, sizes, 1)
+    if path == "ragged_dot":
+        got = moe.grouped_swiglu(lhs, gate[1], up[1], sizes,
+                                 use_kernel=False)
+    else:
+        got = jax.jit(lambda *a: moe._gmm_call(
+            *a, interpret=True, tiles=SWIGLU_CASES[case]["tiles"]))(
+                lhs, [gate, up], sizes, jnp.int32(1))
+    assert got.shape == (lhs.shape[0], gate.shape[-1])
+    assert got.dtype == dtype
+    got = np.asarray(got[:real], np.float64)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, atol=2e-5)
+    else:
+        half_ulp = 2.0 ** -8 * 1.01 * np.abs(want)
+        assert np.all(np.abs(got - want) <= half_ulp + 1e-6)
+
+
+@pytest.mark.parametrize("case", list(SWIGLU_CASES))
+def test_grouped_swiglu_kernel_and_ragged_dot_agree(case):
+    """The two paths of the dispatch compute one function (float32: to
+    operation order), through the public call and its own tile rule."""
+    lhs, gate, up, sizes = _swiglu_operands(case, jnp.float32)
+    real = int(sizes.sum())
+    kernel = moe.grouped_swiglu(lhs, gate, up, sizes, jnp.int32(0),
+                                use_kernel=True)
+    ragged = moe.grouped_swiglu(lhs, gate, up, sizes, jnp.int32(0),
+                                use_kernel=False)
+    np.testing.assert_allclose(np.asarray(kernel[:real]),
+                               np.asarray(ragged[:real]), atol=2e-5)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            if hasattr(value, "jaxpr"):
+                yield from _pallas_calls(value.jaxpr)
+
+
+@pytest.mark.parametrize("tm,tiles_m", [(128, 5), (16, 35)])
+def test_gmm_schedule_visits_every_group_tile_pair_once(tm, tiles_m):
     sizes = jnp.asarray([0, 130, 0, 5, 121, 0, 300, 0], jnp.int32)
-    gid, tid, bounds, total = moe._visits(sizes, 128, 5)
+    gid, tid, bounds, total = moe._visits(sizes, tm, tiles_m)
     n = int(total[0])
     pairs = list(zip(np.asarray(gid)[:n].tolist(),
                      np.asarray(tid)[:n].tolist()))
-    assert pairs == [(1, 0), (1, 1), (3, 1), (4, 1), (6, 2), (6, 3), (6, 4)]
+    # every (group, row tile) pair that shares a row, once, in row order
+    owner = np.repeat(np.arange(8), np.asarray(sizes))
+    want = sorted({(int(g), r // tm) for r, g in enumerate(owner)},
+                  key=lambda p: (p[1], p[0]))
+    assert pairs == want and len(set(pairs)) == n
+    if tm == 128:
+        assert pairs == [(1, 0), (1, 1), (3, 1), (4, 1), (6, 2), (6, 3),
+                         (6, 4)]
+    assert n <= tiles_m + 8 - 1 == gid.shape[0]
     # the tail repeats the last real visit: nothing new to fetch
     assert set(zip(np.asarray(gid)[n:].tolist(),
-                   np.asarray(tid)[n:].tolist())) == {(6, 4)}
+                   np.asarray(tid)[n:].tolist())) == {pairs[-1]}
     assert np.asarray(bounds).tolist() == [0, 0, 130, 130, 135, 256, 256,
                                            556, 556]
+
+
+def test_routed_block_is_two_moe_gmm_calls_on_one_schedule_grid():
+    """The block's ``experts`` scope: gate-and-up in ONE call (three
+    operands behind the five scalars: the rows and two weight arrays),
+    then down; both named ``moe_gmm`` (the benchmark's readers match the
+    name), both over ``(N / tn, row tiles + X - 1)`` grid steps, both
+    declaring the module's VMEM budget."""
+    experts = {"moe_gate": jnp.zeros((2, 8, 64, 32)),
+               "moe_up": jnp.zeros((2, 8, 64, 32)),
+               "moe_down": jnp.zeros((2, 8, 32, 64))}
+    jaxpr = jax.make_jaxpr(lambda x: moe.routed_block(
+        x, jnp.zeros((64, 8)), experts, jnp.int32(1), top_k=2,
+        use_kernel=True)[0])(jnp.zeros((300, 64)))
+    calls = list(_pallas_calls(jaxpr.jaxpr))
+    assert [c.params["name"] for c in calls] == ["moe_gmm", "moe_gmm"]
+    tm, _ = moe._gmm_tiles(600, 64, 32, 8, 4)
+    steps = -(-600 // tm) + 8 - 1
+    assert [c.params["grid_mapping"].grid for c in calls] == [
+        (1, steps), (1, steps)]
+    operands = [len(c.params["grid_mapping"].block_mappings) - 1
+                for c in calls]             # less the output
+    assert operands == [3, 2]
+    for call in calls:
+        assert (call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+                == moe.GMM_VMEM_BYTES)
 
 
 # ------------------------------------------- system against the reference
