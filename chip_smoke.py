@@ -16,7 +16,8 @@ seed), and checks what comes out by the repo's own means:
                 masked loop over the experts; ``paged_decode_attn``
                 bit for bit against the walk over every table entry it
                 replaced (PR 26), and both timed alone at the three
-                serve cells' shapes and fill;
+                serve cells' shapes and fill; ``gdn_step`` against the
+                ``jax.numpy`` step at Qwen3-Next's head shape, timed;
 * ``moe``       the OLMoE family's bf16 forward against the float32
                 reference at the published widths, and three faults
                 (an expert dropped, weights renormalised, no QK-norm)
@@ -36,6 +37,15 @@ seed), and checks what comes out by the repo's own means:
                 limits; the program as published passes, and seven
                 faults (bf16 router scores and a dropped selection bias
                 among them) each fail;
+* ``linear``    the cell ``serve_linear_decode``'s comparison at its
+                own sizes (Qwen3-Next's share at the published widths,
+                eight layers, through ``ContinuousBatcher``): check
+                prompts that cross the prefill chunk boundary and do
+                not, chosen tokens and kept routes against the float32
+                reference under the configuration file's two limits;
+                the program as published passes, eleven faults (state
+                or conv tail not carried between chunks among them)
+                each fail, and a bf16 recurrent state is read;
 * ``train``     ``ShardedTrainer`` on one device, batch 5 x 2048: loss
                 finite and falling, one compiled signature, the Mosaic
                 custom calls present in the compiled step;
@@ -63,6 +73,7 @@ spending chip time.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import signal
@@ -71,17 +82,17 @@ import sys
 import threading
 import time
 
-PHASES = ("kernels", "moe", "hybrid", "window", "mla", "train", "serve",
-          "multichip")
+PHASES = ("kernels", "moe", "hybrid", "window", "mla", "linear", "train",
+          "serve", "multichip")
 # The multichip phase is two children: the trainer's state must be gone
 # from the chips before four serving replicas load theirs.
 CHILDREN = {"kernels": ("kernels",), "moe": ("moe",),
             "hybrid": ("hybrid",), "window": ("window",), "mla": ("mla",),
-            "train": ("train",),
+            "linear": ("linear",), "train": ("train",),
             "serve": ("serve",),
             "multichip": ("multichip-train", "multichip-serve")}
 PHASE_TIMEOUT_S = {"kernels": 600, "moe": 600, "hybrid": 1500,
-                   "window": 2700, "mla": 2700, "train": 480,
+                   "window": 2700, "mla": 2700, "linear": 3300, "train": 480,
                    "serve": 600,
                    "multichip-train": 900, "multichip-serve": 900}
 RESULT_TAG = "PHASE_RESULT "
@@ -99,7 +110,7 @@ REHEARSAL_BANNER = (
 # forward. The chip run prints what was measured next to each bound.
 TOLERANCE = {"flash_fwd": 1e-2, "flash_bwd": 2e-2,
              "decode_bf16": 1e-2, "decode_int8": 1e-2, "moe_gmm": 1e-2,
-             "latent_decode": 1e-2}
+             "latent_decode": 1e-2, "gdn_step": 1e-5}
 # fsdp=4 vs one-chip first-step loss: the same bf16 model, sums reduced
 # across four devices in another order.
 LOSS_RTOL = 5e-3
@@ -577,6 +588,77 @@ def _latent_kernel(phase: str, device_kind: str, rehearse: bool) -> None:
                     f" (x 1280 B as stored: {live * 1280 / hbm * 1e6:.0f})")
 
 
+def _gdn_kernel(phase: str, device_kind: str, rehearse: bool) -> None:
+    """``gdn_step`` at Qwen3-Next's head shape (32 value heads of a
+    ``[128, 128]`` float32 state) against the ``jax.numpy`` step, in a
+    whole ``[L, slots, ...]`` cache updated at a layer, for 1, 48 and 256
+    slots: the outputs, the layer's new states, and the other layer
+    untouched. Then its time a call beside the time the bytes of
+    ``benchmark/flops_gdn.py`` take at the device's HBM peak."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import flops_gdn, peaks
+    from ray_tpu.ops import gated_delta
+
+    h, dk, dv = (4, 16, 128) if rehearse else (32, 128, 128)
+    shape = {"linear_num_value_heads": h, "linear_num_key_heads": h // 2,
+             "linear_key_head_dim": dk, "linear_value_head_dim": dv}
+    f32 = jnp.float32
+    step = jax.jit(lambda st, *a: gated_delta.gdn_step(
+        st, jnp.int32(1), *a, use_kernel=True), donate_argnums=(0,))
+    for slots in ((1, 3) if rehearse else (1, 48, 256)):
+        k = jax.random.split(jax.random.fold_in(jax.random.PRNGKey(11),
+                                                slots), 6)
+        state = jax.random.normal(k[0], (2, slots, h, dk, dv), f32)
+        def unit(key):      # unit rows, as the mixer's L2 norm leaves q, k
+            x = jax.random.normal(key, (slots, h, dk), f32)
+            return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+        q, kk = unit(k[1]) * dk ** -0.5, unit(k[2])
+        v = jax.random.normal(k[3], (slots, h, dv), f32)
+        g = -jax.random.uniform(k[4], (slots, h), f32, 0.001, 1.6)
+        beta = jax.random.uniform(k[5], (slots, h), f32)
+        want_o, want = jax.jit(gated_delta.gdn_step_reference)(
+            state[1], q, kk, v, g, beta)
+        other = np.asarray(state[0])
+        if not rehearse:
+            assert _mosaic_calls(step.lower(state, q, kk, v, g,
+                                            beta).compile()) == 1
+        got_o, got = step(state, q, kk, v, g, beta)
+        tag = f"gdn_step {slots} slot(s) x {h} heads x [{dk}, {dv}] float32"
+        _check(phase, tag + ", outputs", got_o, want_o, TOLERANCE["gdn_step"])
+        _check(phase, tag + ", states", got[1], want, TOLERANCE["gdn_step"])
+        assert np.array_equal(np.asarray(got[0]), other), "another layer moved"
+        if rehearse:
+            continue
+        reps = 50
+
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def many(st, q, kk, v, g, beta):
+            def body(_, carry):
+                st, acc = carry
+                o, st = gated_delta.gdn_step(st, jnp.int32(1), q, kk, v, g,
+                                             beta, use_kernel=True)
+                return st, acc + o
+            return jax.lax.fori_loop(0, reps, body, (st, jnp.zeros_like(v)))
+
+        st = got
+        st, acc = many(st, q, kk, v, g, beta)       # compiles
+        acc.block_until_ready()
+        t0 = time.perf_counter()
+        st, acc = many(st, q, kk, v, g, beta)
+        acc.block_until_ready()
+        us = (time.perf_counter() - t0) / reps * 1e6
+        hbm = peaks.for_device(device_kind)["hbm_bytes_per_s"]
+        least = flops_gdn.step_bytes(shape, slots) / hbm * 1e6
+        _say(phase, f"{tag}: {us:.0f} us a call; state read and written once "
+                    f"and the rows = {flops_gdn.step_bytes(shape, slots) / 1e6:.1f}"
+                    f" MB / {hbm / 1e9:.0f} GB/s = {least:.0f} us "
+                    f"({100 * least / us:.0f}% of the roofline)")
+
+
 def phase_kernels(rehearse: bool) -> None:
     phase = "kernels"
     info = _open_device(phase, rehearse)
@@ -793,6 +875,7 @@ def phase_kernels(rehearse: bool) -> None:
                        swiglu(a, w, u, g), want, TOLERANCE["moe_gmm"])
     _time_paged_cells(phase, info["kind"], rehearse)
     _latent_kernel(phase, info["kind"], rehearse)
+    _gdn_kernel(phase, info["kind"], rehearse)
     _finish(phase, info)
 
 
@@ -887,7 +970,6 @@ def phase_hybrid(rehearse: bool) -> None:
     phase = "hybrid"
     info = _open_device(phase, rehearse)
     import dataclasses
-    import functools
 
     import jax
     import jax.numpy as jnp
@@ -1355,6 +1437,181 @@ def phase_mla(rehearse: bool) -> None:
     assert not wrong, f"{wrong}: {results} against {tolerance}"
 
 
+def phase_linear(rehearse: bool) -> None:
+    """The cell ``serve_linear_decode``'s comparison with its reference,
+    and the faults it has to catch, AT THE CELL'S OWN SIZES: the
+    configuration as the cell runs it (Qwen3-Next's published widths,
+    eight layers, experts 0-63 of 512) in the engine the served path
+    builds (``ContinuousBatcher``: chunked prefill that carries each
+    linear layer's state and conv tail, the state cache, ``gdn_step``
+    ticks, every kernel; four slots are enough here), the cell's check
+    prompts and answer length, one request after another, greedy, each
+    keeping its routes; held to ``benchmark/reference_qwen3_next.py`` by
+    the runner's own ``hold_to_reference`` under the configuration file's
+    limits.
+
+    First the program as published, which has to pass. Then one fault at
+    a time, each of which has to FAIL one of the two limits (ISSUE 38,
+    Tentpole 5b). A bfloat16 recurrent state is read as well and held to
+    nothing."""
+    phase = "linear"
+    info = _open_device(phase, rehearse)
+    import dataclasses
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import manifest
+    from benchmark.runners import serve_linear
+    from benchmark.runners.serve import _prompts
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models import gated_delta, llama
+    from ray_tpu.ops.norms import rms_norm
+
+    cell = manifest.cell("serve_linear_decode")
+    if rehearse:
+        cell = manifest.rehearsal(cell)
+    work, tolerance = cell["workload"], cell["config"]["tolerance"]
+    engine = dict(work["engine"], num_slots=4)
+    config = serve_linear.qwen3_next_config(cell["config"],
+                                            max_seq_len=engine["max_len"])
+    sets = [_prompts(np.random.default_rng(seed), config.vocab_size,
+                     work["check"]["prompt_tokens"],
+                     work["check"]["max_tokens"])
+            for seed in ((38,) if rehearse else (38, 39))]
+
+    def published():
+        return jax.jit(lambda k: llama.init_params(config, k))(
+            jax.random.PRNGKey(0))
+
+    def answers(weights, sets, config):
+        eng = cb.ContinuousBatcher(config, params=weights, **engine)
+        out = []
+        for reqs in sets:
+            recs = []
+            for req in reqs:
+                rid = eng.submit(req["prompt"], req["max_tokens"],
+                                 keep_routes=True)
+                recs.append({"tokens": eng.run_to_completion()[rid],
+                             "routes": eng.take_routes(rid)})
+            out.append(list(zip(reqs, recs)))
+        return out
+
+    real = {(mod, name): getattr(mod, name) for mod, name in (
+        (gated_delta, "_gates"), (gated_delta, "_l2norm"),
+        (gated_delta, "mixer_prefill"), (gated_delta, "mixer_step"),
+        (llama, "norm"), (llama, "attn_gate"))}
+    gates, prefill, tick = (real[gated_delta, "_gates"],
+                            real[gated_delta, "mixer_prefill"],
+                            real[gated_delta, "mixer_step"])
+
+    def beta_one(b, a, layer):
+        g, beta = gates(b, a, layer)
+        return g, jnp.ones_like(beta)
+
+    def no_decay(b, a, layer):
+        g, beta = gates(b, a, layer)
+        return jnp.zeros_like(g), beta
+
+    def carrying(keep_state: bool, keep_tail: bool):
+        def mixer_prefill(h, layer, c, lengths, carried=None):
+            if carried is not None:
+                state, tail = carried
+                carried = (state if keep_state else jnp.zeros_like(state),
+                           tail if keep_tail else jnp.zeros_like(tail))
+            return prefill(h, layer, c, lengths, carried)
+        return mixer_prefill
+
+    def padding_advances(h, layer, c, lengths, carried=None):
+        return prefill(h, layer, c, jnp.full_like(lengths, h.shape[1]),
+                       carried)
+
+    def rounded(x):
+        return x.astype(jnp.bfloat16).astype(x.dtype)
+
+    def bf16_state_prefill(h, layer, c, lengths, carried=None):
+        out, state, tail = prefill(h, layer, c, lengths, carried)
+        return out, rounded(state), tail
+
+    def bf16_state_tick(h, layer, c, state_all, conv_all, index,
+                        use_kernel=None):
+        out, state_all, conv_all = tick(h, layer, c, state_all, conv_all,
+                                        index, use_kernel)
+        return out, rounded(state_all), conv_all
+
+    def float8(tree):
+        """Leaf by leaf, in place, and op by op."""
+        def low(a):
+            if a.dtype != jnp.bfloat16:
+                return a
+            out = a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+            a.delete()
+            return out
+        return jax.tree.map(low, tree)
+
+    same = lambda p: p
+    replace = dataclasses.replace
+    G, L = gated_delta, llama
+    cases = [
+        ("as published", same, {}, config),
+        ("beta fixed at 1", same, {(G, "_gates"): beta_one}, config),
+        ("decay dropped (g = 0)", same, {(G, "_gates"): no_decay}, config),
+        ("q and k not L2-normalised", same,
+         {(G, "_l2norm"): lambda x: x.astype(jnp.float32)}, config),
+        ("(1 + w) read as w", same,
+         {(L, "norm"): lambda x, w, c: rms_norm(x, w, c.rms_eps)}, config),
+        ("rope over all 256 dims", same, {},
+         replace(config, partial_rotary_factor=1.0)),
+        ("attention gate dropped", same,
+         {(L, "attn_gate"): lambda h, layer, c: None}, config),
+        ("shared expert ungated", same, {},
+         replace(config, shared_expert_gate=False)),
+        ("state not carried into chunk 1", same,
+         {(G, "mixer_prefill"): carrying(False, True)}, config),
+        ("conv tail not carried into chunk 1", same,
+         {(G, "mixer_prefill"): carrying(True, False)}, config),
+        ("padding advancing the state", same,
+         {(G, "mixer_prefill"): padding_advances}, config),
+        ("recurrent state in bf16", same,
+         {(G, "mixer_prefill"): bf16_state_prefill,
+          (G, "mixer_step"): bf16_state_tick}, config),
+        # Last: it eats the published weights.
+        ("weights rounded to float8_e4m3", float8, {}, config),
+    ]
+    read_only = {"recurrent state in bf16"}
+    if rehearse:
+        cases = cases[:1]       # tiny sizes prove nothing about the faults
+    params = published()
+    answered = {}
+    for name, weights, patch, case_config in cases:
+        for (mod, attr), fn in patch.items():
+            setattr(mod, attr, fn)
+        try:
+            answered[name] = answers(
+                weights(params), sets if name == "as published" else sets[:1],
+                case_config)
+        finally:
+            for (mod, attr), fn in real.items():
+                setattr(mod, attr, fn)
+        gc.collect()
+        _say(phase, f"{name}: {len(answered[name])} x {len(sets[0])} check "
+                    f"requests answered")
+    if len(cases) > 1:
+        del params
+        params = published()
+    results = {}
+    for name, checked in answered.items():
+        _say(phase, name)
+        results[name] = [serve_linear.hold_to_reference(
+            params, config, checks, tolerance) for checks in checked]
+    _finish(phase, info, faults=results, tolerance=tolerance)
+    wrong = [name for name, rs in results.items() if name not in read_only
+             and any(r["ok"] != (name == "as published") for r in rs)]
+    assert not wrong, f"{wrong}: {results} against {tolerance}"
+
+
 def _train_once(phase, config, mesh, batch_size, seq_len, steps, rehearse):
     """Init + ``steps`` steps on one repeated batch. Returns (losses,
     trainer, state)."""
@@ -1754,7 +2011,7 @@ def _child(phase: str, rehearse: bool) -> int:
 
 CHILD_FNS = {"kernels": phase_kernels, "moe": phase_moe,
              "hybrid": phase_hybrid, "window": phase_window,
-             "mla": phase_mla, "train": phase_train,
+             "mla": phase_mla, "linear": phase_linear, "train": phase_train,
              "serve": phase_serve, "multichip-train": phase_multichip_train,
              "multichip-serve": phase_multichip_serve}
 

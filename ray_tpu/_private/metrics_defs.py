@@ -680,8 +680,24 @@ CB_SPEC_ACCEPT_RATE = Gauge(
 CB_STATE_CACHE_BYTES = Gauge(
     "ray_tpu_cb_state_cache_bytes",
     "Resident bytes of the per-slot state cache beside the K/V arena "
-    "(state-space layers: recurrent state and convolution tail of every "
-    "slot); fixed at construction, whatever the contexts",
+    "(Mamba-2 or Gated DeltaNet layers: recurrent state and convolution "
+    "tail of every slot); fixed at construction, whatever the contexts",
+    ("engine",))
+CB_STATE_LIVE_SLOTS = Histogram(
+    "ray_tpu_cb_state_live_slots",
+    "Per decode tick of a model with recurrent layers: the slots that "
+    "held a live request, whose states the tick HAD to advance (the "
+    "kernels advance every slot's); its sum over its count is the mean "
+    "a tick",
+    boundaries=[1, 2, 4, 8, 16, 32, 48, 64, 96, 128, 192, 256, 512],
+    tag_keys=("engine",))
+CB_PREFILL_STATE_CARRIES = Counter(
+    "ray_tpu_cb_prefill_state_carries_total",
+    "Prefill chunks of real prompts (a request's chunk counted once, "
+    "padding rows not at all) that started from the recurrent state and "
+    "conv tail the chunk before them installed in the slot's row of the "
+    "state cache: every chunk of a linear-attention model's prompt but "
+    "its first (beside ray_tpu_cb_state_installs_total, the prompts)",
     ("engine",))
 CB_STATE_INSTALLS = Counter(
     "ray_tpu_cb_state_installs_total",

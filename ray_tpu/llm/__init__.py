@@ -238,6 +238,7 @@ class ContinuousLlamaDeployment:
         # seconds (PR 28, serve_moe_decode).
         self._queues: Dict[int, "queue.SimpleQueue"] = {}
         self._lock = threading.Lock()
+        self._pressure: Dict[str, Any] = {}     # the last snapshot read
         self._work = threading.Event()
         self._queue_mod = queue
         self.batcher = ContinuousBatcher(
@@ -383,8 +384,19 @@ class ContinuousLlamaDeployment:
         prefix/KV-pressure router's input). Under the engine lock: the
         snapshot iterates the waiting queue, which the tick thread
         mutates."""
-        with self._lock:
-            return self.batcher.pressure_snapshot()
+        # ... but never WAITING for it: the tick thread holds that lock
+        # for most of every step and every waiting submit queues on it,
+        # so under a full engine a snapshot could wait for seconds, on
+        # one of the replica's eight control threads; a few of those and
+        # the controller's health probes, which share them, went
+        # unanswered for a minute and it killed a replica serving 256
+        # streams (PR 38). A busy lock returns the last snapshot read.
+        if self._lock.acquire(timeout=0.05):
+            try:
+                self._pressure = self.batcher.pressure_snapshot()
+            finally:
+                self._lock.release()
+        return self._pressure
 
     def request_breakdowns(self, n: int = 100) -> List[Dict[str, Any]]:
         """The newest ``n`` ended requests' records, oldest first: why
@@ -773,6 +785,19 @@ def _chip_per_replica() -> Dict[str, Any]:
     return {"num_tpus": 1} if chips >= 1 else {}
 
 
+def _ongoing_for(num_slots: int) -> int:
+    """A replica's ``max_ongoing_requests`` for an engine of ``num_slots``:
+    a stream holds one of those places for as long as it holds a slot
+    (and while it waits for one), so under ``num_slots`` places the
+    engine's slots cannot all be taken, and with none to spare no request
+    waits in the engine's queue for the slot the next ending frees. The
+    deployment default of 100 where it leaves an engine four to spare
+    (up to 96 slots: those engines keep the places they had), twice the
+    slots beyond."""
+    default = 100
+    return default if num_slots + 4 <= default else 2 * num_slots
+
+
 def build_continuous_llama_app(config: Optional[llama.LlamaConfig] = None,
                                num_replicas: int = 1, num_slots: int = 8,
                                max_len: int = 512, sync_every: int = 1,
@@ -788,7 +813,8 @@ def build_continuous_llama_app(config: Optional[llama.LlamaConfig] = None,
                                checkpoint_path: Optional[str] = None,
                                prefill_chunk: Optional[int] = None):
     dep = ContinuousLlamaDeployment.options(
-        num_replicas=num_replicas, ray_actor_options=_chip_per_replica())
+        num_replicas=num_replicas, ray_actor_options=_chip_per_replica(),
+        max_ongoing_requests=_ongoing_for(num_slots))
     # Keyword bind so per-deploy ``init_kwargs`` overrides (serve config
     # files) can retarget any engine knob without positional conflicts.
     return dep.bind(config=config, num_slots=num_slots, max_len=max_len,

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import xla_monitor
-from ray_tpu.models import llama, mamba2
+from ray_tpu.models import gated_delta, llama, mamba2
 from ray_tpu.models import paged_kv
 from ray_tpu.models.inference import (ExternalLlamaDrafter, KVCache,
                                       SelfDrafter, _attend_cached,
@@ -251,12 +251,23 @@ def _layer_qkv(x, layer, cos, sin, c):
     position j of a window gets the bits S = 1 gives it, which the
     spec-on/off parity tests pin down. Also returns the output gate
     (``llama.attn_gate``; None for a model without one)."""
-    h = rms_norm(x, layer["attn_norm"], c.rms_eps)
+    h = llama.norm(x, layer["attn_norm"], c)
     q, k, v = llama.project_qkv(h, layer, c)
     gate = llama.attn_gate(h, layer, c)    # None without an output gate
     if cos is None:     # no positions: "nope", or a layer kind without
         return q, k, v, gate
-    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v, gate
+    return _rotate(q, cos, sin), _rotate(k, cos, sin), v, gate
+
+
+def _rotate(x, cos, sin):
+    """:func:`apply_rope` over the dims the tables cover: all of a head,
+    or its first ``2 * cos.shape[-1]`` (``partial_rotary_factor``); the
+    rest pass through."""
+    turned = 2 * cos.shape[-1]
+    if turned == x.shape[-1]:
+        return apply_rope(x, cos, sin)
+    return jnp.concatenate(
+        [apply_rope(x[..., :turned], cos, sin), x[..., turned:]], axis=-1)
 
 
 def _rope_tables(c, length, positions):
@@ -265,7 +276,7 @@ def _rope_tables(c, length, positions):
         return None, None
     if c.latent_layers:     # the rotated dims alone, YaRN frequencies
         return _mla().rope_tables(c, positions)
-    return rope_frequencies(c.head_dim, length, c.rope_theta,
+    return rope_frequencies(c.rotary_dim, length, c.rope_theta,
                             positions=positions)
 
 
@@ -302,6 +313,8 @@ def _attn_out(o, layer, c, gate=None):
     return jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
 
 
+_STATE_KIND_NAMES = {"mamba": "state-space",
+                     "linear_attention": "linear-attention"}
 _KIND_SCOPES = {"sliding_attention": "attn/window",
                 "full_attention": "attn/full"}
 
@@ -340,13 +353,13 @@ def _layer_finish(x, mixed, layer, c, experts=None, li=None,
     absent experts add, so its routing shows nowhere else
     (:meth:`ContinuousBatcher.take_routes`)."""
     if c.sandwich_norms:
-        mixed = rms_norm(mixed, layer["post_attn_norm"], c.rms_eps)
+        mixed = llama.norm(mixed, layer["post_attn_norm"], c)
     x = _residual(x, mixed, c)
-    h = rms_norm(x, layer["mlp_norm"], c.rms_eps)
+    h = llama.norm(x, layer["mlp_norm"], c)
     down, routed = llama.mlp_block(h, layer, c, experts, li,
                                    use_kernel=use_kernel)
     if c.sandwich_norms:
-        down = rms_norm(down, layer["post_mlp_norm"], c.rms_eps)
+        down = llama.norm(down, layer["post_mlp_norm"], c)
     rows = None if routed is None else routed.rows
     if rows is not None and c.experts_held:
         rows = jnp.concatenate([rows, routed.experts.reshape(-1)])
@@ -398,8 +411,9 @@ def _forward_paged(params, tokens, positions, tables, limits,
     The stack is scanned a RUN of equal layers at a time
     (:func:`llama.layer_runs`; a homogeneous model is one run). A
     "mamba" layer (S = 1 only) mixes through
-    :func:`mamba2.mixer_step`, which advances every slot's row of the
-    per-slot state cache beside the arena in place; the arena holds the
+    :func:`mamba2.mixer_step`, a "linear_attention" layer through
+    :func:`gated_delta.mixer_step`; either advances every slot's row of
+    the per-slot state cache beside the arena in place; the arena holds the
     attention layers alone, so each kind indexes its own cache by its
     index among layers of its kind. ``caches`` is the arena, or the pair
     (arena, state cache) for a model with state layers.
@@ -451,6 +465,11 @@ def _forward_paged(params, tokens, positions, tables, limits,
             mixed, *held = mamba2.mixer_step(h, layer, c, *held, ki,
                                              use_kernel)
             held = tuple(held)
+        elif kind == "linear_attention":
+            h = llama.norm(x, layer["attn_norm"], c)
+            mixed, *held = gated_delta.mixer_step(h, layer, c, *held, ki,
+                                                  use_kernel)
+            held = tuple(held)
         elif kind == "latent_attention":
             mixed, latents = _mla().tick_layer(
                 x, layer, c, arenas[0], ki, cos, sin, block_idx, offset,
@@ -482,7 +501,7 @@ def _forward_paged(params, tokens, positions, tables, limits,
             functools.partial(layer_fn, kind=kind, shift=kind_start - start),
             (x, arenas, held, jnp.int32(start)), tree)
         rows.append(run_rows)
-    x = rms_norm(x, params["final_norm"], c.rms_eps)
+    x = llama.norm(x, params["final_norm"], c)
     # lm_head in the params' storage dtype with fp32 accumulation
     # (shared with prefill): bf16 params are never upcast in HBM.
     logits = lm_head_logits(x, params, c)
@@ -661,7 +680,11 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     sliding-window layers; ``pk``/``pv`` are then not read) attention is
     :func:`~ray_tpu.ops.attention.paged_chunk_attention`: blockwise over
     the earlier keys where they lie, in the arena or the ring, then over
-    the chunk's own, so no ``[S, P + S]`` float32 score exists; and
+    the chunk's own, so no ``[S, P + S]`` float32 score exists; a
+    "linear_attention" layer (always under ``paged``) starts from the
+    state and conv tail in rows ``slots`` of ``state`` when the chunk has
+    earlier ones (``paged.tables`` is not empty), from an empty history
+    when it is the prompt's first, and installs what it leaves there; and
     ``stored`` comes back as ``{cache kind: K/V of the layers that keep
     theirs there}`` (``"attention"``: the arena; ``"sliding_attention"``:
     the ring)."""
@@ -679,6 +702,17 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
             layer, = inputs
             h = rms_norm(x, layer["attn_norm"], c.rms_eps)
             mixed, *new = mamba2.mixer_prefill(h, layer, c, last_idx + 1)
+            held = tuple(a.at[li + shift, slots].set(n.astype(a.dtype))
+                         for a, n in zip(held, new))
+        elif kind == "linear_attention":
+            layer, = inputs
+            h = llama.norm(x, layer["attn_norm"], c)
+            # A chunk that is not its prompt's first goes on from the
+            # rows the chunk before it installed.
+            carried = (tuple(a[li + shift, slots] for a in held)
+                       if paged.tables.shape[1] else None)
+            mixed, *new = gated_delta.mixer_prefill(
+                h, layer, c, last_idx + 1, carried)
             held = tuple(a.at[li + shift, slots].set(n.astype(a.dtype))
                          for a, n in zip(held, new))
         elif kind == "latent_attention":
@@ -742,19 +776,19 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                               shift=kind_start - start),
             (x, held, jnp.int32(start)), inputs)
         stored.append(kept or None)
-        if kind != "mamba":
+        if kind not in llama.STATE_KINDS:
             by_kind["sliding_attention" if kind == "sliding_attention"
                     else "attention"].append(kept)
     x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)  # [N, 1, E]
-    x = rms_norm(x, params["final_norm"], c.rms_eps)
+    x = llama.norm(x, params["final_norm"], c)
     logits = lm_head_logits(x, params, c)
+    held = StateCache(*held) if held else None
     if paged is not None:
-        return logits, {k: _concat_runs(v) for k, v in by_kind.items()}, None
-    return (logits, _concat_runs(stored),
-            StateCache(*held) if held else None)
+        return logits, {k: _concat_runs(v) for k, v in by_kind.items()}, held
+    return logits, _concat_runs(stored), held
 
 
-def _prefill_chunk_paged(params, tokens, positions, cache, ring, ptables,
+def _prefill_chunk_paged(params, tokens, positions, cache, second, ptables,
                          tables_w, last_idx, slots, config, use_kernel):
     """One prefill CHUNK through the paged caches (``cb_prefill``'s body
     for a chunk of a long prompt, and for every prefill of a model with
@@ -763,7 +797,12 @@ def _prefill_chunk_paged(params, tokens, positions, cache, ring, ptables,
     through ``tables_w``, and in each row's ring at the entries of its
     logical blocks. A block that holds PADDING ONLY goes to the garbage
     block instead: in a ring it would overwrite a block the row's first
-    decode queries still see. Returns (logits [N, 1, V], arena, ring)."""
+    decode queries still see. ``second`` is the cache beside the arena:
+    the ring, the state cache of a model with linear-attention layers
+    (each row's state and conv tail are read from and written to row
+    ``slots[i]``), or None. Returns (logits [N, 1, V], arena, second)."""
+    ring = second if isinstance(second, RingKVCache) else None
+    state = second if isinstance(second, StateCache) else None
     bs = cache.block_size
     npb = tokens.shape[1] // bs
     m = ptables.shape[1]
@@ -777,9 +816,10 @@ def _prefill_chunk_paged(params, tokens, positions, cache, ring, ptables,
         ring_write = jnp.where(
             real, jnp.take(own, (m + jnp.arange(npb)) % n_ring, axis=1),
             GARBAGE_BLOCK)
-    logits, stored, _ = _prefill_forward_paged(
+    logits, stored, state = _prefill_forward_paged(
         params, tokens, positions, None, None, config, False, last_idx,
-        use_kernel, paged=_PagedPrefix(cache, ptables, ring, ring_tables))
+        use_kernel, state, slots,
+        paged=_PagedPrefix(cache, ptables, ring, ring_tables))
 
     def land(into, kv, tables):
         # (k, v) of an arena or a ring; the one plane of a latent cache.
@@ -791,7 +831,7 @@ def _prefill_chunk_paged(params, tokens, positions, cache, ring, ptables,
         cache = land(cache, stored["attention"], tables_w)
     if ring is not None:
         ring = land(ring, stored["sliding_attention"], ring_write)
-    return logits, cache, ring
+    return logits, cache, state if ring is None else ring
 
 
 def _bucket(n: int, floor: int = 16) -> int:
@@ -1034,8 +1074,10 @@ class ContinuousBatcher:
         shorter one is ONE call padded to its power-of-two bucket, as
         ever. ``PREFILL_BATCH_TOKENS`` caps rows x padded tokens of one
         call (a wave of long prompts is split into calls of fewer rows).
-        A model with state-space layers is never chunked (its state
-        would have to be carried between chunks).
+        A model with Mamba-2 layers is never chunked (its scan is not
+        given a carried state); a linear-attention layer's chunk starts
+        from the state and conv tail the chunk before it left in the
+        slot's row of the state cache.
 
         SLIDING-WINDOW LAYERS (``layer_types`` with "sliding_attention"):
         their K/V live in a per-slot ring of ``window / block_size + 2``
@@ -1076,8 +1118,10 @@ class ContinuousBatcher:
         if chunk < self.block_size:
             raise ValueError(f"prefill_chunk {prefill_chunk} is under one "
                              f"block of {self.block_size}")
-        # None: a prompt is never split (state-space layers).
-        self.prefill_chunk = None if config.state_layers else chunk
+        # None: a prompt is never split (Mamba-2 layers, whose scan is not
+        # given a carried state: ROADMAP R7). A linear-attention layer's
+        # chunk starts from the state and conv tail the one before left.
+        self.prefill_chunk = None if "mamba" in config.layer_types else chunk
         self.prefix_cache = _resolve_prefix_cache(prefix_cache)
         self.use_decode_kernel = _resolve_decode_kernel(
             config, use_decode_kernel, self.block_size)
@@ -1193,6 +1237,7 @@ class ContinuousBatcher:
         # (None otherwise): owned, donated and rebuilt with the arena.
         self.state = self._new_state()
         self.state_installs = 0         # prompts whose state was installed
+        self.state_carries = 0          # chunks that started from a row
         self.allocator = BlockAllocator(self.num_blocks)
         self._slot_blocks: Dict[int, List[int]] = {}
         # Radix index over block-aligned prompt chunks -> resident
@@ -1279,6 +1324,7 @@ class ContinuousBatcher:
         use_kernel = self.use_decode_kernel
         sampling_cfg = self.sampling
         block_size_c = self.block_size
+        chunks_state = "linear_attention" in cfg.layer_types
 
         # The XLA monitor dispatches per signature and audits shape
         # growth: prefill's signatures are pow-2 bucketed in N and L by
@@ -1332,21 +1378,22 @@ class ContinuousBatcher:
             n, s_pad = tokens.shape
             m = ptables.shape[1]
             positions = m * block_size_c + jnp.arange(s_pad)
-            ring = held if isinstance(held, RingKVCache) else None
             if not cache.quantized and (
-                    ring is not None or isinstance(cache, LatentKVCache)
+                    isinstance(held, RingKVCache) or chunks_state
+                    or isinstance(cache, LatentKVCache)
                     or (held is None
                         and m * block_size_c + s_pad > PREFILL_DENSE_KEYS)):
-                # A long prompt's chunk, or sliding-window layers: the
-                # earlier keys are read where they lie, blockwise. (Not
-                # a model with state layers, whose prefill installs a
-                # state; nor an int8 arena: both keep the path they had.)
-                logits, cache, ring = _prefill_chunk_paged(
-                    params, tokens, positions, cache, ring, ptables,
+                # A long prompt's chunk, sliding-window layers, or
+                # linear-attention layers (whose chunks carry a state):
+                # the earlier keys are read where they lie, blockwise.
+                # (Not a model with Mamba-2 layers, whose prompt is one
+                # piece; nor an int8 arena: both keep the path they had.)
+                logits, cache, held = _prefill_chunk_paged(
+                    params, tokens, positions, cache, held, ptables,
                     tables_w, last_idx, slots, cfg, use_kernel)
                 first = _next_tokens(logits, pstep, sampling_cfg,
                                      salt=_PREFILL_SALT)
-                return first, (cache if held is None else (cache, ring))
+                return first, (cache if held is None else (cache, held))
             flat_p = ptables.reshape(-1)                 # [N * m]
             pk = cache.k[:, flat_p]
             pv = cache.v[:, flat_p]
@@ -1437,14 +1484,21 @@ class ContinuousBatcher:
             self._draft_prefill = None
 
     def _refuse_for_state_layers(self, prefix_cache, spec_k, drafter):
-        """A model with state-space layers keeps, beside its K/V, a
-        recurrent state a slot that only moves FORWARD and belongs to
-        one request: whatever rests on rewinding, sharing or shipping
-        K/V alone is refused by name."""
+        """A model with recurrent layers (Mamba-2 or Gated DeltaNet)
+        keeps, beside its K/V, a recurrent state a slot that only moves
+        FORWARD and belongs to one request: whatever rests on rewinding,
+        sharing or shipping K/V alone is refused by name."""
+        kinds = [k for k in llama.STATE_KINDS if k in self.config.layer_types]
+
         def refuse(what, why):
             raise ValueError(
-                f"{what} is not supported for a model with state-space "
-                f"layers (layer_types has 'mamba'): {why}")
+                f"{what} is not supported for a model with "
+                f"{_STATE_KIND_NAMES[kinds[0]]} layers (layer_types has "
+                f"{kinds[0]!r}): {why}")
+
+        if len(kinds) > 1:
+            refuse("a second kind of recurrent layer in the same stack",
+                   "the state cache holds one mixer's shapes")
 
         if _resolve_spec_k(spec_k) or drafter is not None:
             refuse("speculative decoding (spec_k > 0)",
@@ -2167,8 +2221,9 @@ class ContinuousBatcher:
             self._refuse_for_latent_layers(what=what)
         if self.config.state_layers:
             raise ValueError(
-                f"{what} is not supported for a model with state-space "
-                f"layers: the KV handoff carries no recurrent state")
+                f"{what} is not supported for a model with recurrent "
+                f"(state-space or linear-attention) layers: the KV handoff "
+                f"carries no recurrent state")
         if self.config.window_layers:
             raise ValueError(
                 f"{what} is not supported for a model with sliding-window "
@@ -2453,6 +2508,9 @@ class ContinuousBatcher:
         if ring and live:
             mdefs.CB_WINDOW_LIVE_BLOCK_SHARE.observe(
                 sum(ring) / live, tags=self._mtags)
+        if self.config.state_layers:
+            mdefs.CB_STATE_LIVE_SLOTS.observe(len(self._slots),
+                                              tags=self._mtags)
         if self.config.latent_layers:
             # The positions the tick's queries attended, summed over the
             # slots: what the latent kernel must read a layer, in tokens.
@@ -2835,6 +2893,10 @@ class ContinuousBatcher:
             if self.config.state_layers:
                 self.state_installs += n
                 mdefs.CB_STATE_INSTALLS.inc(n, tags=self._mtags)
+                if n_chunks > 1:
+                    self.state_carries += n * (n_chunks - 1)
+                    mdefs.CB_PREFILL_STATE_CARRIES.inc(
+                        n * (n_chunks - 1), tags=self._mtags)
             # The fetch above synced the device: the first tokens landed.
             first_ts = self.landed_ts = time.time()
             for (req, slot, blocks, matched, _sfx, chunks), tok in \

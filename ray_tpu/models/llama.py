@@ -41,6 +41,9 @@ compile_cache.ensure()
 
 Params = Dict[str, Any]
 
+# Layer kinds whose mixer keeps a recurrent state and a conv tail a slot.
+STATE_KINDS = ("mamba", "linear_attention")
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -97,9 +100,11 @@ class LlamaConfig:
     embedding_multiplier: float = 1.0    # x = embed[tokens] * this
     residual_multiplier: float = 1.0     # x += this * sublayer(norm(x))
     logits_scaling: float = 1.0          # logits = x @ head / this
-    # A dense SwiGLU every token takes beside the routed experts, added
-    # unweighted; 0 = none.
+    # A dense SwiGLU every token takes beside the routed experts; 0 =
+    # none. Added unweighted, or, with ``shared_expert_gate``, times
+    # ``sigmoid(h w_sg)``, one scalar a token (``shared_gate_w [E]``).
     shared_intermediate_size: int = 0
+    shared_expert_gate: bool = False
     # The head is the embedding: contracted against ``embed [V, E]`` in
     # place, no ``lm_head`` in the tree.
     tie_word_embeddings: bool = False
@@ -150,6 +155,29 @@ class LlamaConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_scaling: Optional[Tuple[Tuple[str, Any], ...]] = None
+    # The Qwen3-Next family (``qwen3_next``; SERVING ONLY like the three
+    # above): ``layer_types`` names "linear_attention", a Gated DeltaNet
+    # mixer (``models/gated_delta.py``: ``linear_num_key_heads`` heads of
+    # ``linear_key_head_dim`` shared by ``linear_num_value_heads`` of
+    # ``linear_value_head_dim``, behind a causal depthwise conv of
+    # ``linear_conv_kernel_dim`` taps), whose recurrent state and conv
+    # tail a slot keeps in ``paged_kv.StateCache``, beside
+    # "full_attention" layers with ``attn_gate`` and ``qk_norm_per_head``.
+    # ``partial_rotary_factor``: rope turns the first ``rotary_dim`` dims
+    # of a head only. ``zero_centered_norms``: every RMSNorm but the
+    # linear mixer's own scales by ``1 + w`` (``w`` initialised at 0).
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    partial_rotary_factor: float = 1.0
+    zero_centered_norms: bool = False
+
+    @property
+    def rotary_dim(self) -> int:
+        """Dims of a head that rope turns (the first ones)."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def latent_layers(self) -> int:
@@ -158,8 +186,9 @@ class LlamaConfig:
 
     @property
     def state_layers(self) -> int:
-        """Layers that keep a recurrent state a request (Mamba-2)."""
-        return sum(t == "mamba" for t in self.layer_types)
+        """Layers that keep a recurrent state a request (Mamba-2 or
+        Gated DeltaNet: ``paged_kv.StateCache`` holds either's)."""
+        return sum(t in STATE_KINDS for t in self.layer_types)
 
     @property
     def window_layers(self) -> int:
@@ -293,6 +322,28 @@ class LlamaConfig:
             shared_intermediate_size=2048), **kw})
 
     @staticmethod
+    def qwen3_next_80b_a3b(**kw) -> "LlamaConfig":
+        """Qwen/Qwen3-Next-80B-A3B-Instruct (``qwen3_next``): 48 layers,
+        (Gated DeltaNet x3, full attention) x12; the linear mixer 16 key
+        heads and 32 value heads of 128 behind a conv of 4; attention GQA
+        16/2 of 256 with a gated output, zero-centred per-head QK-norm and
+        rope on the first 64 dims; every MLP 512 experts of 512, top 10 of
+        a softmax, renormalised, beside a sigmoid-gated shared expert of
+        512; zero-centred norms; untied 152k vocabulary."""
+        pattern = ("linear_attention",) * 3 + ("full_attention",)
+        return LlamaConfig(**{**dict(
+            vocab_size=151936, hidden_size=2048, intermediate_size=512,
+            num_layers=48, num_heads=16, num_kv_heads=2, head_dim=256,
+            max_seq_len=262144, rope_theta=1e7, rms_eps=1e-6,
+            layer_types=pattern * 12, linear_num_key_heads=16,
+            linear_num_value_heads=32, linear_key_head_dim=128,
+            linear_value_head_dim=128, linear_conv_kernel_dim=4,
+            partial_rotary_factor=0.25, zero_centered_norms=True,
+            qk_norm_per_head=True, attn_gate=True, num_experts=512,
+            num_experts_per_tok=10, norm_topk_prob=True,
+            shared_intermediate_size=512, shared_expert_gate=True), **kw})
+
+    @staticmethod
     def tiny(**kw) -> "LlamaConfig":
         """CPU-runnable config for tests (BASELINE.md config #1 analog)."""
         kw.setdefault("vocab_size", 256)
@@ -350,7 +401,7 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     """Random init (normal / scaled), stacked over layers for lax.scan."""
     c = config
     if c.layer_types:
-        return (_init_hybrid_params(c, key) if c.state_layers
+        return (_init_hybrid_params(c, key) if "mamba" in c.layer_types
                 else _init_windowed_params(c, key))
     k_embed, k_head, k_layers = jax.random.split(key, 3)
 
@@ -516,29 +567,36 @@ def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
         return out.astype(c.dtype)
 
     def norm(key, *shape):
-        return jax.random.uniform(key, shape, jnp.float32, 0.5,
-                                  1.5).astype(c.dtype)
+        # A zero-centred norm scales by 1 + w: the same 0.5..1.5.
+        low = -0.5 if c.zero_centered_norms else 0.5
+        return jax.random.uniform(key, shape, jnp.float32, low,
+                                  low + 1.0).astype(c.dtype)
 
     runs = []
-    for r, (_, start, n, _) in enumerate(layer_runs(c)):
+    for r, (kind, start, n, _) in enumerate(layer_runs(c)):
         k = jax.random.split(jax.random.fold_in(k_runs, r), 20)
         tree = {"attn_norm": norm(k[0], n, E), "mlp_norm": norm(k[1], n, E)}
+        attention = kind != "linear_attention"
         if c.latent_layers:
             from ray_tpu.models import mla
 
             tree.update(mla.init_attention(c, k[2], n))
-        else:
+        elif attention:
             tree.update({"wq": dense(k[2], E, n, E, H, D),
                          "wk": dense(k[3], E, n, E, KV, D),
                          "wv": dense(k[4], E, n, E, KV, D),
                          "wo": dense(k[5], H * D, n, H, D, E)})
+        else:
+            from ray_tpu.models import gated_delta
+
+            tree.update(gated_delta.init_mixer(c, k[2], n))
         if c.sandwich_norms:
             tree["post_attn_norm"] = norm(k[6], n, E)
             tree["post_mlp_norm"] = norm(k[7], n, E)
-        if c.qk_norm_per_head:
+        if c.qk_norm_per_head and attention:
             tree["q_norm"] = norm(k[8], n, D)
             tree["k_norm"] = norm(k[9], n, D)
-        if c.attn_gate:
+        if c.attn_gate and attention:
             tree["wg"] = dense(k[10], E, n, E, H, D)
         if start < c.num_dense_layers or not X:
             Md = c.dense_intermediate_size or M
@@ -556,6 +614,8 @@ def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
                 tree.update({"shared_gate": dense(k[16], E, n, E, Ms),
                              "shared_up": dense(k[17], E, n, E, Ms),
                              "shared_down": dense(k[18], Ms, n, Ms, E)})
+            if c.shared_expert_gate:
+                tree["shared_gate_w"] = dense(k[19], E, n, E)
         runs.append(tree)
     out = {
         # x0 = embed[t] * embedding_multiplier has unit entries.
@@ -626,12 +686,12 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
                num_layers: Optional[int] = None):
     """The layer stack as RUNS of equal layers, in order: a list of
     ``(kind, start, count, kind_start)`` where ``kind`` is "attention",
-    "mamba", "sliding_attention", "full_attention" or "latent_attention",
-    ``start`` the run's first GLOBAL layer and ``kind_start`` its first
-    index among layers that share its cache (the K/V arena's layer for
-    attention and full attention, the state cache's for mamba, the
-    ring's for sliding attention, the latent cache's for latent
-    attention). A model without ``layer_types`` is one attention run.
+    "mamba", "linear_attention", "sliding_attention", "full_attention" or
+    "latent_attention", ``start`` the run's first GLOBAL layer and
+    ``kind_start`` its first index among layers that share its cache
+    (the K/V arena's layer for attention and full attention, the state
+    cache's for mamba and for linear attention, the ring's for sliding
+    attention, the latent cache's for latent attention). A model without ``layer_types`` is one attention run.
 
     With ``params``: ``(runs, experts)``, each run followed by the tree
     a ``lax.scan`` over its layers takes (``params["runs"][i]``; for a
@@ -643,7 +703,7 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
         raise ValueError(f"layer_types names {len(types)} layers, "
                          f"num_layers is {c.num_layers}")
     runs, seen = [], {"attention": 0, "mamba": 0, "sliding_attention": 0,
-                      "latent_attention": 0}
+                      "latent_attention": 0, "linear_attention": 0}
     for i, kind in enumerate(types):
         # "full_attention" keeps all its K/V in the arena, as "attention"
         # does: they count as one kind of cache.
@@ -730,6 +790,14 @@ def canonical_layout(params: Params) -> Params:
                    swap_heads)
 
 
+def norm(x, weight, c: LlamaConfig):
+    """The family's RMSNorm of ``x`` over its last axis: scaled by
+    ``weight``, or by ``1 + weight`` for ``zero_centered_norms``."""
+    if c.zero_centered_norms:
+        weight = 1.0 + weight.astype(jnp.float32)
+    return rms_norm(x, weight, c.rms_eps)
+
+
 def _project_heads(h, layer, c: LlamaConfig, name: str):
     """``h [B, S, E]`` through the layer's projection ``name`` in
     whichever layout the tree holds it: ``[B, S, H, D]``."""
@@ -762,8 +830,8 @@ def project_qkv(h, layer, c: LlamaConfig):
     project = functools.partial(_project_heads, h, layer, c)
     q, k, v = project("wq"), project("wk"), project("wv")
     if c.qk_norm_per_head:
-        q = rms_norm(q, layer["q_norm"], c.rms_eps)
-        k = rms_norm(k, layer["k_norm"], c.rms_eps)
+        q = norm(q, layer["q_norm"], c)
+        k = norm(k, layer["k_norm"], c)
     if c.qk_norm:
         def whole(x, w):
             flat = x.reshape(*x.shape[:2], -1)
@@ -842,11 +910,16 @@ def mlp_block(h, layer, c: LlamaConfig, experts=None, li=None,
         out = out.reshape(b, s, e)
         if c.shared_intermediate_size:
             # GraniteMoeHybridDecoderLayer.forward: moe(h) + shared_mlp(h),
-            # one norm output feeding both, the shared one unweighted.
+            # one norm output feeding both, the shared one unweighted;
+            # Qwen3NextSparseMoeBlock's times sigmoid(h w_sg).
             with jax.named_scope("shared_mlp"):
-                out = out + _swiglu(h, layer["shared_gate"],
-                                    layer["shared_up"],
-                                    layer["shared_down"], c)
+                shared = _swiglu(h, layer["shared_gate"], layer["shared_up"],
+                                 layer["shared_down"], c)
+                if c.shared_expert_gate:
+                    shared = shared * jax.nn.sigmoid(jnp.einsum(
+                        "bse,e->bs", h, layer["shared_gate_w"].astype(c.dtype)
+                    ))[..., None]
+                out = out + shared
         return out, routed
     return _swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"], c,
                    mesh), None
@@ -920,8 +993,9 @@ def forward(
     c = config
     if c.layer_types:
         raise NotImplementedError(
-            "a config with layer_types (state-space, sliding-window or "
-            "latent-attention layers) is served by the continuous-batching "
+            "a config with layer_types (state-space, linear-attention, "
+            "sliding-window or latent-attention layers) is served by the "
+            "continuous-batching "
             "engine only: "
             "llama.forward, loss_fn and LlamaGenerator do not run it")
     seq_len = tokens.shape[1]
@@ -1108,7 +1182,7 @@ def loss_fn(
 
 def num_params(config: LlamaConfig) -> int:
     c = config
-    if c.layer_types and not c.state_layers:
+    if c.layer_types and "mamba" not in c.layer_types:
         shapes = jax.eval_shape(functools.partial(init_params, c),
                                 jax.random.PRNGKey(0))
         return sum(a.size for a in jax.tree_util.tree_leaves(shapes))

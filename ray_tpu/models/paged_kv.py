@@ -216,15 +216,20 @@ class LatentKVCache(NamedTuple):
 
 
 class StateCache(NamedTuple):
-    """What the state-space layers of a hybrid model keep for each slot,
-    beside the K/V arena: ``ssm [L_ssm, slots, H, N / f, f * P]``
-    float32, the recurrent state in the layout the tick's kernel
-    updates in place (``ops/ssm.py::packed_shape``), and ``conv
-    [L_ssm, slots, K - 1, conv_dim]``, the convolution's last inputs,
-    oldest first, in the model's dtype. Neither is paged nor shareable
-    by prefix: a slot's row is installed whole by its prefill, advanced
-    by every tick, and simply overwritten by the slot's next prefill (a
-    freed slot's row computes garbage that nothing reads)."""
+    """What the recurrent layers of a hybrid model keep for each slot,
+    beside the K/V arena: ``ssm [L_state, slots, *state]`` float32, the
+    recurrent state in the layout the tick's kernel updates in place,
+    and ``conv [L_state, slots, K - 1, conv_dim]``, the convolution's
+    last inputs, oldest first, in the model's dtype. The shapes are the
+    mixer's that the config names: Mamba-2 (``layer_types`` "mamba")
+    ``[H, N / f, f * P]`` (``ops/ssm.py::packed_shape``) over ``x | B |
+    C``; Gated DeltaNet ("linear_attention") ``[Hv, Dk, Dv]`` over ``q |
+    k | v`` (``models/gated_delta.py::state_shapes``). Neither is paged
+    nor shareable by prefix: a slot's row is installed by its prefill
+    (a later CHUNK of a linear-attention prompt reads the row the chunk
+    before it wrote, and overwrites it), advanced by every tick, and
+    simply overwritten by the slot's next prefill (a freed slot's row
+    computes garbage that nothing reads)."""
 
     ssm: jnp.ndarray
     conv: jnp.ndarray
@@ -232,15 +237,20 @@ class StateCache(NamedTuple):
     @classmethod
     def create(cls, config: llama.LlamaConfig,
                num_slots: int) -> "StateCache":
-        from ray_tpu.ops.ssm import packed_shape
-
         c = config
+        if "linear_attention" in c.layer_types:
+            from ray_tpu.models.gated_delta import state_shapes
+
+            state, tail = state_shapes(c)
+        else:
+            from ray_tpu.ops.ssm import packed_shape
+
+            state = packed_shape(c.mamba_n_heads, c.mamba_d_head,
+                                 c.mamba_d_state)
+            tail = (c.mamba_d_conv - 1, c.mamba_dims[1])
         return cls(
-            ssm=jnp.zeros((c.state_layers, num_slots) + packed_shape(
-                c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state),
-                jnp.float32),
-            conv=jnp.zeros((c.state_layers, num_slots, c.mamba_d_conv - 1,
-                            c.mamba_dims[1]), c.dtype))
+            ssm=jnp.zeros((c.state_layers, num_slots) + state, jnp.float32),
+            conv=jnp.zeros((c.state_layers, num_slots) + tail, c.dtype))
 
     @property
     def nbytes(self) -> int:

@@ -27,6 +27,7 @@ Condensed re-design of SURVEY.md §3.5's architecture:
 
 from __future__ import annotations
 
+import collections
 import json
 import logging
 import math
@@ -171,6 +172,25 @@ CONTROL_GROUP = "control"
 CONTROL_CONCURRENCY = 8
 
 
+class StreamBatch(list):
+    """What a replica's sync generator had yielded when the replica's
+    loop next turned, as ONE streamed object: a stream that keeps up
+    ships its items one at a time, as ever; one that has fallen behind
+    (hundreds of open streams share the replica's loop, the object
+    store and the interpreter lock) ships what has piled up, so the
+    cost of a turn is paid once for all of it and the stream catches
+    up. :class:`DeploymentResponseGenerator` hands the items out one by
+    one, so a consumer never sees a batch."""
+
+
+_STREAM_OVER = object()
+
+
+class _StreamRaised:
+    def __init__(self, error: BaseException):
+        self.error = error
+
+
 class Replica:
     """Hosts one copy of the user callable.
 
@@ -271,29 +291,8 @@ class Replica:
                 async for item in result:
                     yield item
             elif hasattr(result, "__next__"):
-                # Sync generator: pull each item off-loop so a slow
-                # producer (time.sleep between yields) can't stall the
-                # replica's other in-flight requests. The copied context
-                # carries the multiplexed-model-id ContextVar into the
-                # pool thread (same as the non-streaming sync path).
-                import asyncio
-                import contextvars
-
-                loop = asyncio.get_running_loop()
-                ctx = contextvars.copy_context()
-                sentinel = object()
-
-                def _next():
-                    try:
-                        return ctx.run(next, result)
-                    except StopIteration:
-                        return sentinel
-
-                while True:
-                    item = await loop.run_in_executor(self._sync_pool, _next)
-                    if item is sentinel:
-                        break
-                    yield item
+                async for batch in self._stream_sync_generator(result):
+                    yield batch
             else:
                 raise TypeError(
                     f"stream=True requires a generator; "
@@ -305,6 +304,73 @@ class Replica:
             multiplex._reset_model_id(token)
             with self._m_lock:
                 self._ongoing -= 1
+
+    async def _stream_sync_generator(self, result):
+        """A sync generator's items as :class:`StreamBatch` es: ONE pool
+        thread runs the generator for the stream's whole life (a slow
+        producer cannot stall the replica's other requests; the copied
+        context carries the multiplexed-model-id ContextVar into it, as
+        on the non-streaming sync path) and leaves each item in a
+        deque; the loop is woken once for however many lie there, and
+        ships them as one object. Before PR 38 every item was a hop to a
+        pool thread and back: at 256 open streams of 46 tokens a second
+        the items queued behind those hops for minutes."""
+        import asyncio
+        import contextvars
+
+        loop = asyncio.get_running_loop()
+        ctx = contextvars.copy_context()
+        made: collections.deque = collections.deque()
+        wake = asyncio.Event()
+        stop = threading.Event()
+        signalled = [False]
+
+        def produce():
+            try:
+                while not stop.is_set():
+                    try:
+                        item = ctx.run(next, result)
+                    except StopIteration:
+                        item = _STREAM_OVER
+                    except BaseException as e:  # noqa: BLE001 — re-raised
+                        item = _StreamRaised(e)     # on the loop, in order
+                    made.append(item)
+                    # Appended BEFORE the flag is read: a consumer that
+                    # has reset it drains after resetting, so it sees
+                    # this item or is woken for it.
+                    if not signalled[0]:
+                        signalled[0] = True
+                        loop.call_soon_threadsafe(wake.set)
+                    if item is _STREAM_OVER or isinstance(item,
+                                                          _StreamRaised):
+                        return
+            finally:
+                if stop.is_set():
+                    # The consumer left first (a client gone, a cancel):
+                    # closing the generator runs its ``finally``.
+                    result.close()
+
+        loop.run_in_executor(self._sync_pool, produce)
+        try:
+            while True:
+                await wake.wait()
+                signalled[0] = False
+                wake.clear()
+                batch = StreamBatch()
+                while made:
+                    item = made.popleft()
+                    if item is _STREAM_OVER or isinstance(item,
+                                                          _StreamRaised):
+                        if batch:
+                            yield batch
+                        if item is _STREAM_OVER:
+                            return
+                        raise item.error
+                    batch.append(item)
+                if batch:
+                    yield batch
+        finally:
+            stop.set()
 
     @ray_tpu.method(concurrency_group=CONTROL_GROUP)
     def metrics(self):
@@ -1066,7 +1132,8 @@ class ServeController:
         live = []
         for r in current:
             try:
-                ray_tpu.get(r.health.remote(), timeout=2)
+                ray_tpu.get(r.health.remote(),
+                            timeout=self.HEALTH_PROBE_TIMEOUT_S)
                 live.append(r)
                 self._replica_birth.pop(id(r), None)  # confirmed up
             except ray_tpu.exceptions.ActorDiedError:
@@ -1158,6 +1225,11 @@ class ServeController:
             self._routes_changed(name)
 
     REPLICA_STARTUP_GRACE_S = 60.0
+    # A probe crosses the replica's loop, which hundreds of open streams
+    # share: at 256 streams a turn of that loop took 1.3 s and more (PR
+    # 38), a 2 s probe missed for a minute on end, and the controller
+    # killed a replica that was serving 12,000 tokens a second.
+    HEALTH_PROBE_TIMEOUT_S = 10.0
 
     @staticmethod
     def _replica_bundle(actor_options: Dict[str, Any]) -> Dict[str, float]:
@@ -1357,18 +1429,25 @@ class DeploymentResponseGenerator:
         self._gen = obj_ref_gen
         self._timeout = per_item_timeout_s
         self._replica = replica
+        # Items of a StreamBatch not yet handed out.
+        self._held: collections.deque = collections.deque()
 
     def __iter__(self):
         return self
 
     def __next__(self):
-        ref = (next(self._gen) if self._timeout is None
-               else self._gen._next_internal(self._timeout))
-        return ray_tpu.get(ref, timeout=self._timeout)
+        while not self._held:
+            ref = (next(self._gen) if self._timeout is None
+                   else self._gen._next_internal(self._timeout))
+            item = ray_tpu.get(ref, timeout=self._timeout)
+            if not isinstance(item, StreamBatch):
+                return item
+            self._held.extend(item)
+        return self._held.popleft()
 
     def ready(self) -> bool:
         """Whether ``next()`` would return an item without waiting."""
-        return self._gen.ready()
+        return bool(self._held) or self._gen.ready()
 
 
 # Process-wide in-flight request counts per deployment: the queue-depth

@@ -447,7 +447,7 @@ class AsyncHttpProxy:
     """Asyncio HTTP/1.1 ingress (keep-alive, streaming, backpressure)."""
 
     def __init__(self, host: str = "127.0.0.1", port: int = 8000,
-                 max_concurrency: int = 256, router: Optional[_Router] = None):
+                 max_concurrency: int = 1024, router: Optional[_Router] = None):
         self.router = router or _Router()
         # The executor bounds concurrent blocking router calls: requests
         # beyond it queue in asyncio (cheap futures), not in threads.
@@ -456,7 +456,9 @@ class AsyncHttpProxy:
         # the engine's whole queue), so this is also the cap on open
         # streams: under it new submits queue behind blocked pulls and
         # the engine's slots run empty while callers wait (at 64, 96
-        # callers over a 48-slot engine lost up to half their rate).
+        # callers over a 48-slot engine lost up to half their rate; at
+        # 256, 512 callers over a 256-slot engine would leave no request
+        # waiting in the engine for the slot an ending frees).
         # Threads start on demand, so a quiet ingress pays nothing.
         self._pool = ThreadPoolExecutor(max_workers=max_concurrency,
                                         thread_name_prefix="serve-http")

@@ -112,6 +112,10 @@ def test_replica_dropped_at_startup_grace_gives_its_chip_back(
 
     monkeypatch.setattr(serve_api.ServeController,
                         "REPLICA_STARTUP_GRACE_S", 1.0)
+    # A probe shorter than the slow start, so it is missed (the default
+    # waits 10 s for a replica whose loop hundreds of streams share).
+    monkeypatch.setattr(serve_api.ServeController,
+                        "HEALTH_PROBE_TIMEOUT_S", 2.0)
     ray_tpu.init(num_cpus=4, num_tpus=1)
     dep = serve.deployment(_SlowFirstStart).options(
         ray_actor_options={"num_tpus": 1})

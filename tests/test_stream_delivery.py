@@ -257,3 +257,59 @@ def test_replica_returns_its_newest_request_breakdowns():
         assert 0 <= rec["handoff_mean_s"] < 5
     recs[0]["tokens"] = -1                          # a copy, not the book
     assert dep.request_breakdowns()[0]["tokens"] == 3
+
+
+def test_a_stream_that_falls_behind_ships_what_piled_up(ray_start_regular):
+    """A sync generator's items reach the consumer one by one, in order
+    and once each, whatever the replica shipped them as: a generator
+    that outruns its consumer (200 items made at once, the consumer
+    starting late) crosses the object store in fewer objects than
+    items (``serve.api.StreamBatch``), one that is slower than its
+    consumer in one object an item; an error raised after items were
+    made arrives after them."""
+    from ray_tpu import serve
+    from ray_tpu.serve import api
+
+    @serve.deployment
+    class Source:
+        def fast(self, n):
+            yield from range(n)
+
+        def slow(self, n):
+            for i in range(n):
+                time.sleep(0.05)
+                yield i
+
+        def broken(self, n):
+            yield from range(n)
+            raise RuntimeError("after the items")
+
+    handle = serve.run(Source.bind(), name="batches")
+    shipped = []
+    real_get = api.ray_tpu.get
+
+    def counting_get(ref, **kw):
+        item = real_get(ref, **kw)
+        shipped.append(item)
+        return item
+
+    stream = handle.options(stream=True)
+    gen = stream.fast.remote(200)
+    time.sleep(0.5)                     # every item is made by now
+    api.ray_tpu.get, before = counting_get, api.ray_tpu.get
+    try:
+        assert list(gen) == list(range(200))
+        batches = [s for s in shipped if isinstance(s, api.StreamBatch)]
+        assert sum(len(b) for b in batches) == 200
+        assert len(batches) < 200 and max(len(b) for b in batches) > 1
+        del shipped[:]
+        assert list(stream.slow.remote(5)) == list(range(5))
+        assert [list(s) for s in shipped] == [[i] for i in range(5)]
+        gen = stream.broken.remote(3)
+        got = []
+        with pytest.raises(Exception, match="after the items"):
+            for item in gen:
+                got.append(item)
+        assert got == [0, 1, 2]
+    finally:
+        api.ray_tpu.get = before

@@ -791,3 +791,77 @@ def test_compiled_latent_tick_reads_the_cache_through_its_kernels(v5e_chip):
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 64 << 20
     assert memory.alias_size_in_bytes == cache.k.size * 2
+
+
+@pytest.mark.parametrize("slots", [1, 48, 256])
+def test_gdn_step_lowers(slots):
+    """The tick's in-place delta-rule update at Qwen3-Next's widths (32
+    value heads of a ``[128, 128]`` float32 state), on the whole
+    ``[L, slots, ...]`` cache at a traced layer."""
+    from ray_tpu.ops import gated_delta
+
+    f32 = jnp.float32
+    fn = functools.partial(gated_delta.gdn_step, use_kernel=True)
+    assert gated_delta.gdn_applicable(32, 128, 128)
+    row = S((slots, 32, 128), f32)
+    gate = S((slots, 32), f32)
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        S((6, slots, 32, 128, 128), f32), S((), jnp.int32), row, row, row,
+        gate, gate)
+    assert _kernel_names(exported.mlir_module()) == ["gdn_step"]
+
+
+def test_compiled_linear_tick_holds_no_copy_of_the_state_cache(v5e_chip):
+    """The cell ``serve_linear_decode``'s tick, compiled for a described
+    v5e at the cell's own sizes (Qwen3-Next's widths, 8 layers = (3
+    Gated DeltaNet, 1 full attention) x 2, experts 0-63 of 512, 256 slots
+    x 3072 over 12,289 blocks): each linear run's layer loop calls
+    ``gdn_step`` once on the WHOLE state cache ``[6, 256, 32, 128, 128]``
+    float32 (3.2 GB), aliased in to out, and no other instruction has the
+    cache's or one layer's slab's shape (an XLA update in the loop would
+    slice 537 MB out and put it back a layer a tick); every run calls
+    ``moe_gmm`` twice; the two full-attention runs touch the arena
+    through ``paged_kv_write`` and ``paged_decode_attn``; and beside the
+    10.5 GB of arguments (4.0 GB of weights, 3.2 GB of arena, 3.3 GB of
+    state and conv tails, the last two donated) the program needs under
+    32 MB."""
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models.paged_kv import PagedKVCache, StateCache
+
+    slots, bs, width = 256, 64, 48
+    cfg = llama.LlamaConfig.qwen3_next_80b_a3b(
+        num_layers=8, layer_types=(("linear_attention",) * 3
+                                   + ("full_attention",)) * 2,
+        vocab_size=18992, experts_held=(0, 64), max_seq_len=bs * width)
+
+    def spec(a):
+        return S(a.shape, a.dtype, sharding=v5e_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        functools.partial(cb.init_engine_params, cfg),
+        jax.random.PRNGKey(0)))
+    cache = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        PagedKVCache.create, cfg, 1 + slots * width, bs, kv_dtype="bf16")))
+    state = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        StateCache.create, cfg, slots)))
+    assert cache.k.shape == (2, 12289, 2, 64, 256)
+    assert state.ssm.shape == (6, 256, 32, 128, 128)
+    assert state.conv.shape == (6, 256, 3, 8192)
+    row = S((slots,), jnp.int32, sharding=v5e_chip)
+    tables = S((slots, width), jnp.int32, sharding=v5e_chip)
+    step = S((), jnp.int32, sharding=v5e_chip)
+    tick = functools.partial(cb._decode_tick_paged, config=cfg,
+                             use_kernel=True)
+    compiled = jax.jit(tick, donate_argnums=(5,)).lower(
+        params, row, row, tables, row, (cache, state), step).compile()
+    hlo = compiled.as_text()
+    for name, calls in (("gdn_step", 2), ("moe_gmm", 8),
+                        ("paged_decode_attn", 2), ("paged_kv_write", 4)):
+        assert len(re.findall(rf"%{name}[.\d]* = ", hlo)) == calls, name
+    shaped = re.compile(r"= \(?\w+\[(\d+,)?256,32,128,128\]")
+    moved = [line.strip() for line in hlo.splitlines()
+             if shaped.search(line) and not any(f in line for f in _FREE)]
+    assert moved == []
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 32 << 20
+    assert memory.alias_size_in_bytes >= state.ssm.size * 4
