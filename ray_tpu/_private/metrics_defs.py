@@ -607,6 +607,19 @@ CB_SLOT_STALLED_MS = Counter(
     "ray_tpu_cb_prefill_ms times the slots live before it, and each "
     "device-starved interval times the slots live in it",
     ("engine",))
+# A third part since the gathered admission: a slot a hold keeps empty is
+# neither (``ContinuousBatcher._holds_admission``).
+CB_ADMIT_HELD_SLOT_MS = Counter(
+    "ray_tpu_cb_admit_held_slot_ms_total",
+    "Slot-milliseconds a free slot stood empty because the engine held "
+    "its admission back for the slots that free next to join the same "
+    "prefill batch: each tick's ray_tpu_cb_tick_ms times the slots the "
+    "hold kept empty through it",
+    ("engine",))
+CB_ADMIT_HELD_TICKS = Counter(
+    "ray_tpu_cb_admit_held_ticks_total",
+    "Decode ticks that ran while a held admission kept free slots empty",
+    ("engine",))
 CB_PREFILL_PADDED_ROWS = Counter(
     "ray_tpu_cb_prefill_padded_rows_total",
     "Rows the prefill programs ran, a batch counted once: the batch's "

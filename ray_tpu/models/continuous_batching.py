@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import heapq
 import itertools
 import os
 import time
@@ -1281,6 +1282,20 @@ class ContinuousBatcher:
         # difference when it ends (``stalled_s``, ``stall_count``).
         self._stall_s = 0.0
         self._stall_n = 0
+        # What a gathered admission weighs (:meth:`_gather`), all of it
+        # measured by this engine on its own clocks. ``_batch_ms``: a
+        # prefill batch's shape ``(padded rows, padded length, matched
+        # blocks, chunks)`` -> its two newest ``CB_PREFILL_MS`` readings,
+        # the first ever left out (an empty list); ``_restart_ms``: the
+        # same of the ``CB_STARVED_AFTER_PREFILL_MS`` interval that
+        # followed it (owed to the last admission's ``_restart_owed``);
+        # ``_tick_ms``: ``CB_TICK_MS``'s clock, smoothed. ``_hold``: the
+        # hold in progress, None when the free slots are not being kept.
+        self._batch_ms: Dict[tuple, List[float]] = {}
+        self._restart_ms: Dict[tuple, List[float]] = {}
+        self._restart_owed: List[tuple] = []
+        self._tick_ms = 0.0
+        self._hold: Optional[Dict[str, Any]] = None
         # ``time.time()`` of the landing whose tokens are being booked:
         # what a token callback may stamp its token with.
         self.landed_ts = 0.0
@@ -1976,8 +1991,13 @@ class ContinuousBatcher:
                max_new_tokens: int = 32,
                trace: Optional[Dict[str, Any]] = None,
                keep_routes: bool = False) -> int:
-        """Queue a request; returns its id. It joins the next tick with a
-        free slot — no waiting for the current batch to drain.
+        """Queue a request; returns its id. It is admitted as soon as a
+        slot is free — no waiting for the current batch to drain — unless
+        the engine is saturated (more requests wait than slots are free,
+        with streams decoding): then a free slot may stay empty for a few
+        ticks so that the slots that free next are refilled by the same
+        prefill call, for no longer than the request had already waited
+        (:meth:`_holds_admission`).
 
         ``keep_routes``: keep the experts each decoded position routed
         to, for :meth:`take_routes` (a held expert share alone: only its
@@ -2040,12 +2060,12 @@ class ContinuousBatcher:
             self._book_empty(now, mdefs.CB_IDLE_NO_WORK_MS)
             self._empty_since = now
 
-    def _book_empty(self, now: float, hist) -> None:
+    def _book_empty(self, now: float, hist) -> float:
         """The device had nothing queued from ``_empty_since`` to
         ``now``: one observation in the cause's histogram, and the
         interval once for each live slot, which stood still through it.
         The caller says what follows (``_empty_since``: None when it
-        dispatches a program)."""
+        dispatches a program). Returns the interval, in ms."""
         from ray_tpu._private import metrics_defs as mdefs
 
         ms = max(now - self._empty_since, 0.0) * 1e3
@@ -2053,6 +2073,7 @@ class ContinuousBatcher:
         if self._slots:
             mdefs.CB_SLOT_STALLED_MS.inc(ms * len(self._slots),
                                          tags=self._mtags)
+        return ms
 
     def _release_slot(self, slot: int) -> None:
         self._free.append(slot)
@@ -2117,6 +2138,7 @@ class ContinuousBatcher:
         self._inflight.clear()
         self._device_empty(time.perf_counter(), "tick")
         self._no_work = True
+        self._hold = None
         self._d_tokens = self._d_members = None
         # Parked handoffs and import reservations die with the arena
         # (allocator.reset below reclaims their blocks wholesale).
@@ -2607,13 +2629,20 @@ class ContinuousBatcher:
                 - -(-(prompt_len + max_new) // self.block_size))
 
     def _can_admit_head(self) -> bool:
+        """True when the FIFO head WOULD admit right now: it could (a
+        free slot and the arena's blocks, :meth:`_head_fits`) and
+        :meth:`_admit` would not hold it back for a larger batch. The
+        buffered engine uses this to decide whether forcing a sync
+        boundary is worth it — an arena-blocked or held head must not
+        collapse speculative pipelining to one tick per sync while it
+        waits."""
+        return self._head_fits() and not self._holds_admission()
+
+    def _head_fits(self) -> bool:
         """True when the FIFO head could admit RIGHT NOW (free slot and
         enough free arena blocks — counting LRU-cached blocks the
         allocator can reclaim and prefix blocks a radix match would
-        cover). The buffered engine uses this to decide
-        whether forcing a sync boundary is worth it — an arena-blocked
-        head must not collapse speculative pipelining to one tick per
-        sync while it waits for blocks."""
+        cover)."""
         if not (self._waiting and self._free):
             return False
         req = self._waiting[0]
@@ -2689,12 +2718,198 @@ class ContinuousBatcher:
             # starve local admission out of the same arena.
             self.sweep_reservations()
         if not (self._waiting and self._free):
+            self._hold = None
+            return
+        if self._holds_admission():
             return
         from ray_tpu._private import metrics_defs as mdefs
 
         with tracing.phase("engine.admit", mdefs.CB_STEP_ADMIT_MS,
                            self._mtags) as admit:
             self._admit_waiting(admit)
+        self._hold = None
+
+    def _group(self, prompt_len: int, m: int) -> tuple:
+        """The key under which :meth:`_admit_waiting` batches a prompt of
+        ``prompt_len`` tokens behind ``m`` matched blocks: (padded
+        length, ``m``, program calls). A prompt past the chunk length is
+        whole chunks, the last one padded; a shorter one is one call at
+        its power-of-two bucket, at least one block and never beyond the
+        table. Requests of one key share their prefill calls."""
+        chunk, bs = self.prefill_chunk, self.block_size
+        suffix_len = prompt_len - m * bs
+        if chunk and suffix_len > chunk:
+            return chunk, m, -(-suffix_len // chunk)
+        padded_len = min(_bucket(suffix_len), (self.max_blocks - m) * bs)
+        return max(padded_len, bs), m, 1
+
+    def _group_of(self, req: Dict[str, Any]) -> tuple:
+        """:meth:`_group` of a request still in the queue: what its
+        admission would find in the prefix index now, with nothing
+        pinned."""
+        m = 0
+        if self._prefix is not None:
+            m = _bucket_floor(len(self._prefix.match_nodes(
+                self._req_chunks(req)[:self._match_cap(req)])))
+        return self._group(len(req["prompt"]), m)
+
+    def _batch_cost(self, shape: tuple) -> Optional[float]:
+        """Device ms an admission of one prefill batch of ``shape``
+        costs, by this engine's own readings: the batch's time plus the
+        restart that followed it, each the LOWER of its two newest
+        readings (so one slow reading, a host stall inside the fetch,
+        prices nothing until the next confirms it). None for a shape not
+        measured yet."""
+        seen = self._batch_ms.get(shape)
+        if not seen:
+            return None
+        return min(seen) + min(self._restart_ms.get(shape) or (0.0,))
+
+    @staticmethod
+    def _note_reading(table: Dict[tuple, List[float]], shape: tuple,
+                      ms: float) -> None:
+        """Keep ``ms`` among ``shape``'s two newest readings, but not
+        its first in this engine: that one holds a program's compilation
+        or its load from the cache, and a shape priced too high is never
+        chosen, so never measured again."""
+        seen = table.get(shape)
+        if seen is None:
+            table[shape] = []
+        else:
+            seen.append(ms)
+            del seen[:-2]
+
+    def _note_tick_ms(self, ms: float) -> None:
+        """Keep the tick's cadence (``CB_TICK_MS``'s clock) for
+        :meth:`_gather`: what a row of a tick is worth."""
+        self._tick_ms += (ms - self._tick_ms) / 8 if self._tick_ms else ms
+
+    @staticmethod
+    def _hold_room_s(meta: Dict[str, Any], now: float) -> float:
+        """Seconds a waiting request may still be held: no longer than
+        it had waited when its hold began, so a hold at most doubles a
+        wait that saturation already caused."""
+        since = meta.get("held", now)
+        return (since - meta["submit"]) - (now - since)
+
+    def _gather(self) -> Optional[Dict[str, Any]]:
+        """The admission that costs a request the least device time, if
+        it is larger than the one the free slots allow now; None when
+        admitting now is best, or when nothing may be held at all.
+
+        Only a saturated engine gathers: requests would still wait after
+        this admission, a stream is decoding (its ticks are what frees
+        the next slots), and the head fits the arena. It weighs, for
+        each number of slots ``n`` one admission could fill (up to the
+        rows ``_prefill_batches`` gives one call of the head's group),
+
+            (batch + restart) / rows  +  forgone decode / n
+
+        ``batch + restart``: :meth:`_batch_cost` of the head's group at
+        the padded row count, ``rows`` the requests of that group among
+        the first ``n`` waiting (another group is another call). A
+        bucket never measured is not waited for. ``forgone decode``: a
+        slot that stands empty through a tick forgoes one row of it,
+        ``_tick_ms / num_slots`` of device time, and how long each
+        stands empty is KNOWN: a live slot ends after at most ``max_new
+        - len(out)`` more booked ticks (an EOS, or a speculative tick's
+        extra tokens, only bring that forward, so this is an upper bound
+        and a hold on it ends early, never late). A wait the head has
+        no room for (:meth:`_hold_room_s`) is not considered."""
+        free, live = len(self._free), len(self._slots)
+        if not (live and self._tick_ms and len(self._waiting) > free
+                and self._head_fits()):
+            return None
+        head = self._waiting[0]
+        key = self._group_of(head)
+        most = min(max(_bucket_floor(PREFILL_BATCH_TOKENS // key[0]), 1),
+                   len(self._waiting), free + live)
+        if most <= free:
+            return None
+        ends = heapq.nsmallest(most - free, (
+            st["max_new"] - len(st["out"]) for st in self._slots.values()))
+        meta = self._req_meta.get(head["rid"])
+        room_ticks = (self._hold_room_s(meta, time.time()) * 1e3
+                      / self._tick_ms if meta is not None else 0.0)
+        slot_tick_ms = self._tick_ms / self.num_slots
+        rows, ended, now_row_ms, best = 0, 0, None, None
+        for n, req in enumerate(itertools.islice(self._waiting, most), 1):
+            rows += req is head or self._group_of(req) == key
+            if n < free:
+                continue
+            wait = 0
+            if n > free:
+                wait = ends[n - free - 1]
+                ended += wait
+                if wait > room_ticks:
+                    break
+            cost = self._batch_cost(
+                (min(_bucket(rows, floor=1), self.num_slots),) + key)
+            if cost is None:
+                if n == free:
+                    return None   # admitting now has no price to beat
+                continue
+            # Slot-ticks left empty by then: the free slots through all
+            # of the wait, each later one from its own end.
+            empty = n * wait - ended
+            row_ms = cost / rows
+            if n == free:
+                now_row_ms = row_ms
+            total = row_ms + empty * slot_tick_ms / n
+            if best is None or total < best[0]:
+                best = (total, n, rows, row_ms)
+        if best[1] == free:
+            return None
+        return {"slots": best[1], "rows": best[2], "row_ms": best[3],
+                "now_row_ms": now_row_ms}
+
+    def _holds_admission(self) -> bool:
+        """True while :meth:`_admit` leaves the free slots empty so that
+        the slots that free next join the same prefill call
+        (:meth:`_gather`). Two bounds end a hold whatever the readings
+        say, and it then stays ended until the admission has run: the
+        slot-time it has left empty, as device time, reaches what the
+        gathered batch saves over admitting as the hold began (a wrong
+        reading costs at most what a right one gains), and no request is
+        held longer than it had already waited (:meth:`_hold_room_s`)."""
+        hold = self._hold
+        if hold is not None and hold["over"]:
+            return False
+        plan = self._gather()
+        if plan is None:
+            self._hold = None
+            return False
+        if hold is None:
+            hold = self._hold = {"row_ms": plan["now_row_ms"],
+                                 "slot_ms": 0.0, "over": False}
+        saving_ms = plan["rows"] * (hold["row_ms"] - plan["row_ms"])
+        over = hold["slot_ms"] / self.num_slots >= saving_ms
+        now = time.time()
+        for req in itertools.islice(self._waiting, len(self._free)):
+            meta = self._req_meta.get(req["rid"])
+            if meta is not None:
+                meta.setdefault("held", now)
+                over = over or self._hold_room_s(meta, now) <= 0
+        hold["over"] = over
+        return not over
+
+    def _book_held(self, tick_ms: float, hold: Optional[Dict[str, Any]],
+                   slots: int) -> None:
+        """A tick of ``tick_ms`` ran while ``hold`` kept ``slots`` free
+        slots empty: neither stalled nor advancing, the third part of
+        the slots' time."""
+        if hold is None:
+            return
+        from ray_tpu._private import metrics_defs as mdefs
+
+        hold["slot_ms"] += tick_ms * slots
+        mdefs.CB_ADMIT_HELD_SLOT_MS.inc(tick_ms * slots, tags=self._mtags)
+        mdefs.CB_ADMIT_HELD_TICKS.inc(tags=self._mtags)
+
+    def _held(self) -> Optional[Dict[str, Any]]:
+        """The hold that keeps the free slots empty now, or None."""
+        hold = self._hold
+        return hold if hold is not None and not hold["over"] else None
 
     def _admit_waiting(self, admit: "tracing.phase") -> None:
         """``_admit``'s work, inside its ``engine.admit`` phase: each
@@ -2714,7 +2929,7 @@ class ContinuousBatcher:
         # allocated, so prefill cost and arena demand both scale with
         # novel tokens.
         bs = self.block_size
-        padded_cap = self.max_blocks * bs
+        self._restart_owed = []   # by the batches of THIS admission
         groups: Dict[tuple, List] = {}
         draft_pending: List = []   # (slot, prompt) for the ext. drafter
         while self._waiting and self._free:
@@ -2750,15 +2965,6 @@ class ContinuousBatcher:
                 break
             blocks = [nd.block for nd in matched] + got
             suffix = req["prompt"][m * bs:]
-            chunk = self.prefill_chunk
-            if chunk and len(suffix) > chunk:
-                # A long prompt: whole chunks, the last one padded.
-                padded_len, n_chunks = chunk, -(-len(suffix) // chunk)
-            else:
-                padded_len = min(_bucket(len(suffix)),
-                                 padded_cap - m * bs)
-                padded_len = max(padded_len, bs)  # at least one block
-                n_chunks = 1
             if self._prefix is not None:
                 self.prefix_hit_tokens += m * bs
                 self.prefix_miss_tokens += len(suffix)
@@ -2775,7 +2981,8 @@ class ContinuousBatcher:
                 meta["prefix_tokens"] = m * bs
             slot = self._free.pop()
             self._slot_blocks[slot] = blocks
-            groups.setdefault((padded_len, m, n_chunks), []).append(
+            groups.setdefault(
+                self._group(len(req["prompt"]), m), []).append(
                 (req, slot, blocks, matched, suffix, chunks))
         for (padded_len, m, n_chunks), group in self._prefill_batches(
                 groups):
@@ -2873,6 +3080,9 @@ class ContinuousBatcher:
             prefill_ms = prefill.ms - behind
             self._stall_s += prefill_ms / 1e3
             self._stall_n += 1
+            shape = (n_pad, padded_len, m, n_chunks)
+            self._note_reading(self._batch_ms, shape, prefill_ms)
+            self._restart_owed.append(shape)
             if live_before:
                 mdefs.CB_SLOT_STALLED_MS.inc(prefill_ms * live_before,
                                              tags=self._mtags)
@@ -3344,9 +3554,14 @@ class ContinuousBatcher:
         if self._empty_since is not None:
             # The device had nothing queued: it waited for this thread,
             # through the restart after a prefill or after its last tick.
-            self._book_empty(t0, mdefs.CB_STARVED_AFTER_PREFILL_MS
-                             if self._empty_after == "prefill"
-                             else mdefs.CB_STARVED_TICK_LATE_MS)
+            if self._empty_after == "prefill":
+                restart_ms = self._book_empty(
+                    t0, mdefs.CB_STARVED_AFTER_PREFILL_MS)
+                # The restart the admission's batches brought with them.
+                for shape in self._restart_owed:
+                    self._note_reading(self._restart_ms, shape, restart_ms)
+            else:
+                self._book_empty(t0, mdefs.CB_STARVED_TICK_LATE_MS)
             self._empty_since = None
         with _annotation("engine.tick.dispatch"):
             row = self._run_tick()
@@ -3359,7 +3574,9 @@ class ContinuousBatcher:
             mdefs.CB_TICK_OVERLAPPED.inc(tags=self._mtags)
         self._inflight.append({"row": row, "members": members,
                                "k": self._last_tick_k, "t0": t0,
-                               "w0": w0, "wall": None})
+                               "w0": w0, "wall": None,
+                               "hold": self._held(),
+                               "held": len(self._free)})
 
     def _device_empty(self, now: float, after: str) -> None:
         """A landing at ``now`` (``after``: "tick" or "prefill") left
@@ -3395,6 +3612,8 @@ class ContinuousBatcher:
         mdefs.CB_TICK_MS.observe(wall_ms, tags=self._mtags)
         mdefs.CB_SLOT_ADVANCING_MS.inc(wall_ms * len(tick["members"]),
                                        tags=self._mtags)
+        self._book_held(wall_ms, tick["hold"], tick["held"])
+        self._note_tick_ms(wall_ms)
         if not prefill_behind and all(
                 t["wall"] is not None for t in self._inflight):
             self._device_empty(now, "tick")
@@ -3435,7 +3654,13 @@ class ContinuousBatcher:
         host re-uploads on a membership change it knows a tick ahead
         (:meth:`_upload_state`). An admission's prefill queues behind
         the tick in flight, so a slot freed by tick n is refilled for
-        tick n+2, one tick later than a step that waited would.
+        tick n+2 at the earliest, one tick later than a step that waited
+        would. A saturated engine refills it later still, on purpose:
+        :meth:`_admit` leaves it empty while the slots that free over
+        the next few ticks are worth waiting for, and then refills them
+        all with one prefill batch (:meth:`_gather`, which weighs the
+        batch's measured time a row against the decode rows the empty
+        slots forgo; the ticks go on through a hold).
 
         Running ahead costs at most one OVERRUN ROW, for an end the host
         cannot foresee (EOS, ``cancel``): tick n+1 has run for a request
@@ -3519,8 +3744,9 @@ class ContinuousBatcher:
             # Buffered mode overlaps fetches with compute, so this is
             # dispatch time only; steady-state backpressure still makes
             # the histogram track the real tick cadence.
-            mdefs.CB_TICK_MS.observe(
-                (time.perf_counter() - t0) * 1e3, tags=self._mtags)
+            dispatch_ms = (time.perf_counter() - t0) * 1e3
+            mdefs.CB_TICK_MS.observe(dispatch_ms, tags=self._mtags)
+            self._book_held(dispatch_ms, self._held(), len(self._free))
             self._bw_window_ticks += 1
             if not self._buf:
                 # k is frozen for the whole buffered window (adaptation
@@ -3582,9 +3808,9 @@ class ContinuousBatcher:
             now = time.perf_counter()
             if self._bw_window_t0 is not None and self._bw_window_ticks:
                 tick_fn = self._spec_ticks[wk] if wk else self._tick
-                self._account_tick(
-                    tick_fn,
-                    (now - self._bw_window_t0) / self._bw_window_ticks, wk)
+                tick_s = (now - self._bw_window_t0) / self._bw_window_ticks
+                self._account_tick(tick_fn, tick_s, wk)
+                self._note_tick_ms(tick_s * 1e3)
             self._bw_window_t0 = now
             self._bw_window_ticks = 0
             self._note_expert_rows(rows)

@@ -1235,3 +1235,305 @@ def test_tick_overlap_share_reads_the_engines_counter(setup):
     assert registry_delta.read(parent, **spec["args"]) == 0.0
     idle = {"registry_before": after, "registry_after": after}
     assert registry_delta.read(idle, **spec["args"]) is None
+
+
+# ------------------------------------------------- gathered admission
+# A saturated engine may leave free slots empty for a few ticks so that
+# the slots that free next join one prefill call (ISSUE 40). The
+# decision reads the engine's own clocks; these tests give it readings.
+
+def _read_counter(eng, counter):
+    key = tuple(sorted(eng._mtags.items()))
+    return sum(v for _, k, v in counter.samples() if k == key)
+
+
+def _price(eng, batch_ms, restart_ms=0.0, tick_ms=None, length=32):
+    """Give ``eng`` a table: ``batch_ms(padded rows)`` and ``restart_ms``
+    for the one-call shape of prompts that pad to ``length``, and the
+    tick's cadence; from here on it keeps them (its own CPU readings
+    would say nothing about the rule)."""
+    for rows in (1, 2, 4, 8, 16):
+        shape = (min(rows, eng.num_slots), length, 0, 1)
+        eng._batch_ms[shape] = [float(batch_ms(shape[0]))]
+        eng._restart_ms[shape] = [float(restart_ms)]
+    eng._note_reading = lambda table, shape, ms: None
+    if tick_ms is not None:
+        eng._tick_ms = float(tick_ms)
+        eng._note_tick_ms = lambda ms: None
+
+
+def _closed_loop(eng, seed, total, first, lengths=(17, 32), new=(4, 24)):
+    """``first`` requests at once, then one more for each that ends, up
+    to ``total``: a queue always waits. Returns every request's tokens
+    and the order of first tokens."""
+    rng = np.random.default_rng(seed)
+    order, done = [], {}
+    eng.token_callback = lambda rid, tok: (
+        order.append(rid) if rid not in order else None)
+
+    def submit():
+        prompt = list(rng.integers(1, 250, size=int(rng.integers(*lengths))))
+        return eng.submit(prompt, max_new_tokens=int(rng.integers(*new)))
+
+    rids = [submit() for _ in range(first)]
+    while eng.has_work():
+        out = eng.step()
+        done.update(out)
+        for _ in out:
+            if len(rids) < total:
+                rids.append(submit())
+    assert sorted(done) == sorted(rids)
+    return done, order
+
+
+@pytest.mark.parametrize("sync_every", [1, 3])
+def test_gathered_admission_changes_no_token_and_no_order(setup, sync_every):
+    """The same seeded closed loop with holds and with none: every
+    request's tokens and the order of first tokens are the same, in
+    fewer prefill batches, and the held slots' time is booked."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    config, gen, _ = setup
+    runs = {}
+    for holds in (True, False):
+        eng = _pipelined(config, gen, num_slots=4, prefix_cache=False,
+                         sync_every=sync_every)
+        _price(eng, lambda rows: 10.0, restart_ms=2.0, tick_ms=0.5)
+        if not holds:
+            eng._holds_admission = lambda: False
+        runs[holds] = (*_closed_loop(eng, 7, total=40, first=12),
+                       _prefill_batches(eng),
+                       _read_counter(eng, mdefs.CB_ADMIT_HELD_TICKS),
+                       _read_counter(eng, mdefs.CB_ADMIT_HELD_SLOT_MS))
+    held, plain = runs[True], runs[False]
+    assert held[0] == plain[0] and held[1] == plain[1]
+    assert held[2] < plain[2]
+    assert held[3] > 0 and held[4] > 0
+    assert plain[3] == 0 and plain[4] == 0
+
+
+def _saturated(config, gen, remaining, free=1, waiting=12, slots=8, **kw):
+    """An engine's books as a saturated loop leaves them, without a
+    device: ``free`` empty slots, a live slot for each entry of
+    ``remaining`` (tokens still to book), ``waiting`` one-bucket
+    requests that have waited a minute."""
+    import time
+
+    eng = _pipelined(config, gen, num_slots=slots, prefix_cache=False, **kw)
+    eng._free = list(range(slots - free, slots))
+    eng._slots = {i: {"rid": 1000 + i, "out": [1] * 3, "max_new": 3 + left}
+                  for i, left in enumerate(remaining)}
+    for rid in range(waiting):
+        eng._waiting.append({"rid": rid, "prompt": [1] * 20, "max_new": 4,
+                             "routes": None})
+        eng._req_meta[rid] = {"rid": rid, "submit": time.time() - 60.0}
+    eng._tick_ms = 1.0
+    return eng
+
+
+def _cheapest(free, ends, most, batch_ms, restart_ms, tick_ms, slots):
+    """The rule by hand: slots to fill for the least device time a
+    request, among ``free``..``most``."""
+    def cost(n):
+        wait = ends[n - free - 1] if n > free else 0
+        empty = free * wait + sum(wait - e for e in ends[:n - free])
+        pad = 1 << (n - 1).bit_length()
+        return ((batch_ms(min(pad, slots)) + restart_ms) / n
+                + empty * tick_ms / slots / n)
+    return min(range(free, most + 1), key=lambda n: (cost(n), n))
+
+
+_TABLES = {
+    # A call's time is fixed cost (weights read once whatever the rows):
+    # gather as many as one call takes.
+    "bytes_like": (lambda rows: 300.0, 5.0, 2.0, 8),
+    # A second row costs what the first does and nothing is shared:
+    # never worth an empty slot.
+    "compute_like": (lambda rows: 30.0 * rows, 0.0, 2.0, 1),
+    # Between: the fixed part amortises until the empty slots cost more.
+    "between": (lambda rows: 20.0 + 12.0 * rows, 4.0, 16.0, 4),
+}
+
+
+@pytest.mark.parametrize("table", sorted(_TABLES))
+def test_gathered_size_is_the_costs_minimum(setup, table):
+    config, gen, _ = setup
+    batch_ms, restart_ms, tick_ms, want = _TABLES[table]
+    ends = [2, 3, 5, 9, 14, 20, 27]
+    eng = _saturated(config, gen, remaining=ends)
+    _price(eng, batch_ms, restart_ms, tick_ms)
+    assert want == _cheapest(1, ends, 8, batch_ms, restart_ms, tick_ms, 8)
+    plan = eng._gather()
+    if want == 1:
+        assert plan is None and not eng._holds_admission()
+    else:
+        assert (plan["slots"], plan["rows"]) == (want, want)
+        assert eng._holds_admission() and not eng._can_admit_head()
+
+
+def _case_a_slot_for_everyone(config, gen):
+    return _saturated(config, gen, remaining=[4, 5, 6], free=5, waiting=5)
+
+
+def _case_no_stream_live(config, gen):
+    return _saturated(config, gen, remaining=[], free=8, waiting=12)
+
+
+def _case_a_bucket_never_measured(config, gen):
+    eng = _saturated(config, gen, remaining=[2] * 7)
+    for rows in (2, 4, 8):
+        eng._batch_ms[(rows, 32, 0, 1)] = []   # seen once: not kept
+    return eng
+
+
+def _case_admitting_now_has_no_price(config, gen):
+    eng = _saturated(config, gen, remaining=[2] * 7)
+    eng._batch_ms[(1, 32, 0, 1)] = []
+    return eng
+
+
+def _case_another_group_waits_behind_the_head(config, gen):
+    eng = _saturated(config, gen, remaining=[2] * 7)
+    for req in list(eng._waiting)[1:]:
+        req["prompt"] = [1] * 40      # pads to 64: another call
+    return eng
+
+
+def _case_the_head_is_blocked_on_the_arena(config, gen):
+    eng = _saturated(config, gen, remaining=[2] * 7)
+    eng.allocator.alloc(eng.allocator.free_count)
+    return eng
+
+
+def _case_no_tick_has_been_timed(config, gen):
+    eng = _saturated(config, gen, remaining=[2] * 7)
+    eng._tick_ms = 0.0
+    return eng
+
+
+_NO_HOLD = [_case_a_slot_for_everyone, _case_no_stream_live,
+            _case_a_bucket_never_measured, _case_admitting_now_has_no_price,
+            _case_another_group_waits_behind_the_head,
+            _case_the_head_is_blocked_on_the_arena,
+            _case_no_tick_has_been_timed]
+
+
+@pytest.mark.parametrize("case", _NO_HOLD,
+                         ids=lambda f: f.__name__[len("_case_"):])
+def test_nothing_is_held_when(setup, case):
+    """Under a table that makes every gathered batch look free, these
+    engines still admit as they always did."""
+    config, gen, _ = setup
+    eng = case(config, gen)
+    for rows in (1, 2, 4, 8):
+        for length in (32, 64):
+            eng._batch_ms.setdefault((rows, length, 0, 1), [50.0])
+    assert eng._gather() is None
+    assert not eng._holds_admission() and eng._hold is None
+
+
+def test_a_prefill_role_engine_never_holds(setup):
+    """It parks every request at its first token, so no stream is ever
+    live: a queue longer than its slots is admitted as the slots free."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    config, gen, _ = setup
+    eng = _pipelined(config, gen, num_slots=2, prefix_cache=False,
+                     role="prefill")
+    _price(eng, lambda rows: 50.0, tick_ms=1e-3)
+    rids = [eng.submit(p, max_new_tokens=6) for p in _prompts(5, *[20] * 7)]
+    done = {}
+    for _ in range(10):
+        done.update(eng.step())
+        for rid in eng.handoff_ready():
+            eng.abandon_handoff(rid)
+    assert sorted(done) == rids and eng._hold is None
+    assert _read_counter(eng, mdefs.CB_ADMIT_HELD_TICKS) == 0
+
+
+@pytest.mark.parametrize("bound", ["slot_time", "queue_age"])
+def test_a_hold_ends_at_its_bound_under_readings_that_lie(setup, bound):
+    """Three streams with 60 tokens to go, one free slot, a queue. The
+    table says a second row is free and the tick clock says an empty
+    slot forgoes nothing, so the rule would wait 60 ticks for the pair.
+    ``slot_time``: the pair saves 0.2 ms, which the empty slot has cost
+    after a tick or two. ``queue_age``: it saves a second, but the
+    head has waited only as long as a few ticks take."""
+    import time
+
+    from ray_tpu._private import metrics_defs as mdefs
+
+    config, gen, _ = setup
+    eng = _pipelined(config, gen, num_slots=4, prefix_cache=False)
+    _price(eng, lambda rows: 0.2 if bound == "slot_time" else 1000.0,
+           tick_ms=1e-9)
+    live = [eng.submit(p, max_new_tokens=64) for p in _prompts(9, 20, 21, 22)]
+    for _ in range(4):
+        eng.step()
+    assert len(eng._slots) == 3 and len(eng._free) == 1
+    late = [eng.submit(p, max_new_tokens=3) for p in _prompts(10, 20, 21)]
+    if bound == "slot_time":
+        for rid in late:
+            eng._req_meta[rid]["submit"] -= 60.0
+    submitted, batches = time.time(), _prefill_batches(eng)
+    assert eng._gather()["slots"] == 2
+    steps = 0
+    while _prefill_batches(eng) == batches:
+        eng.step()
+        steps += 1
+        assert steps < 40, "the hold outlived its bound"
+    waited = time.time() - submitted
+    held_ticks = _read_counter(eng, mdefs.CB_ADMIT_HELD_TICKS)
+    assert 1 <= held_ticks <= steps < 40
+    assert len(eng._slots) == 4 and eng._hold is None
+    if bound == "queue_age":
+        # Held no longer than it had waited, give or take a step.
+        rec = eng._req_meta[late[0]]
+        assert rec["admit"] - rec["held"] <= (
+            rec["held"] - rec["submit"] + waited / steps + 0.05)
+    done = _drain(eng, {})
+    assert sorted(done) == sorted(live + late)
+
+
+def test_run_to_completion_leaves_no_request_behind_a_hold(setup):
+    """A batch job: the queue only shrinks. Holds engage while more wait
+    than are free, end as the streams end, and the last requests are
+    admitted by an engine whose streams have all ended."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    config, gen, _ = setup
+    eng = _pipelined(config, gen, num_slots=4, prefix_cache=False)
+    _price(eng, lambda rows: 10.0, restart_ms=2.0, tick_ms=0.5)
+    prompts = _prompts(21, *range(17, 32))
+    rids = [eng.submit(p, max_new_tokens=5 + i % 7)
+            for i, p in enumerate(prompts)]
+    done = eng.run_to_completion()
+    assert sorted(done) == rids
+    assert _read_counter(eng, mdefs.CB_ADMIT_HELD_TICKS) > 0
+    assert not (eng._waiting or eng._slots or eng._hold)
+    for rid, prompt in list(zip(rids, prompts))[::5]:
+        assert done[rid] == _reference(gen, prompt, 5 + rids.index(rid) % 7)
+
+
+@pytest.mark.parametrize("sync_every", [1, 3])
+def test_can_admit_head_is_false_while_admit_holds(setup, sync_every):
+    """The buffered engine's boundary probe agrees with ``_admit``: a
+    held head forces no boundary, and the step that holds runs no
+    prefill."""
+    config, gen, _ = setup
+    eng = _pipelined(config, gen, num_slots=4, prefix_cache=False,
+                     sync_every=sync_every)
+    _price(eng, lambda rows: 10.0, restart_ms=2.0, tick_ms=0.5)
+    for i, p in enumerate(_prompts(3, 20, 21, 22, 23)):
+        eng.submit(p, max_new_tokens=8 + 8 * i)
+    for p in _prompts(4, 20, 21, 22):
+        eng.submit(p, max_new_tokens=4)
+    while len(eng._free) != 1:
+        eng.step()
+    assert len(eng._waiting) == 3 and eng._head_fits()
+    batches = _prefill_batches(eng)
+    assert eng._holds_admission() and not eng._can_admit_head()
+    eng.step()
+    assert _prefill_batches(eng) == batches and len(eng._waiting) == 3
+    _drain(eng, {})
+    assert _prefill_batches(eng) == batches + 1   # the three, in one call

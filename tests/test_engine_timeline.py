@@ -230,3 +230,73 @@ def test_padded_rows_and_tokens_beside_the_real_ones(config, monkeypatch):
     assert got[mdefs.CB_PREFILL_PADDED_TOKENS.name] == 1 * 16 * 3
     assert (1, 16) in chunked._prefill_shapes
     assert cb.PREFILL_BATCH_TOKENS >= 16
+
+
+HELD = (mdefs.CB_ADMIT_HELD_SLOT_MS, mdefs.CB_ADMIT_HELD_TICKS)
+
+
+@pytest.mark.parametrize("holds", [True, False])
+def test_a_held_slot_is_the_third_part_of_the_slots_time(config, holds):
+    """ISSUE 40: a saturated engine keeps free slots empty for a few
+    ticks so that the next to free join their prefill call. Through each
+    such tick a slot is advancing, held, or ending in the tick ahead;
+    the held slot-milliseconds are each tick's wall time times the slots
+    its hold kept empty, beside the live slots' advancing and stalled
+    ones; and the thread's own identity is untouched, since the thread
+    ticks through a hold. With no hold the third part reads 0."""
+    eng = ContinuousBatcher(config, num_slots=4, max_len=64, block_size=16,
+                            prefix_cache=False)
+    _warm(eng)
+    # The readings a fixed-cost prefill call would leave: a second row
+    # is free, an empty slot forgoes an eighth of a millisecond a tick.
+    for rows in (1, 2, 4):
+        eng._batch_ms[(rows, 16, 0, 1)] = [10.0]
+    eng._note_reading = lambda table, shape, ms: None
+    eng._tick_ms, eng._note_tick_ms = 0.5, lambda ms: None
+    if not holds:
+        eng._holds_admission = lambda: False
+
+    def read():
+        key = tuple(sorted(eng._mtags.items()))
+        return dict(_read(eng), **{
+            c.name: sum(v for _, k, v in c.samples() if k == key)
+            for c in HELD})
+
+    landed, land, dispatch = [], eng._land, eng._dispatch_tick
+
+    def logged_dispatch(members):
+        dispatch(members)
+        eng._inflight[-1]["ending"] = len(eng._slots) - len(members)
+
+    def logged_land(tick, **kwargs):
+        fresh = tick["wall"] is None
+        land(tick, **kwargs)
+        if fresh:
+            landed.append((tick["wall"] * 1e3, len(tick["members"]),
+                           tick["held"] if tick["hold"] else 0,
+                           tick["ending"]))
+
+    eng._dispatch_tick, eng._land = logged_dispatch, logged_land
+    begin, before = eng._empty_since, read()
+    for i in range(10):
+        eng.submit([1 + i, 2, 3, 4], max_new_tokens=4 + 3 * (i % 4))
+    eng.run_to_completion()
+    got = _gained(before, read())
+    wall_ms = (eng._empty_since - begin) * 1e3
+    assert _timeline_ms(got) == pytest.approx(wall_ms, rel=0.05)
+    assert got[mdefs.CB_SLOT_ADVANCING_MS.name] == pytest.approx(
+        sum(ms * members for ms, members, _, _ in landed), rel=1e-6)
+    assert got[mdefs.CB_ADMIT_HELD_SLOT_MS.name] == pytest.approx(
+        sum(ms * held for ms, _, held, _ in landed), rel=1e-6)
+    held_ticks = [tick for tick in landed if tick[2]]
+    assert got[mdefs.CB_ADMIT_HELD_TICKS.name] == len(held_ticks)
+    assert bool(held_ticks) == holds
+    for _, members, held, ending in held_ticks:
+        assert members + held + ending == eng.num_slots
+    # Every slot's millisecond has at most one name.
+    assert (got[mdefs.CB_SLOT_ADVANCING_MS.name]
+            + got[mdefs.CB_SLOT_STALLED_MS.name]
+            + got[mdefs.CB_ADMIT_HELD_SLOT_MS.name]
+            <= eng.num_slots * _timeline_ms(got))
+    assert got[mdefs.CB_PREFILL_REQUESTS.name] == 10
+    assert (got[mdefs.CB_PREFILL_MS.name][1] < 6) == holds
