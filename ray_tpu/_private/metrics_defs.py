@@ -254,19 +254,27 @@ SERVE_REQ_OUTCOMES = Counter(
 SERVE_STREAM_HANDOFF_SECONDS = Counter(
     "ray_tpu_serve_stream_handoff_seconds_total",
     "Streamed tokens' seconds from landing on the host (the engine's "
-    "stamp of the tick or prefill fetch) to the replica's generator "
-    "thread taking them off the request's queue: tick thread -> "
-    "generator thread",
+    "stamp of the tick or prefill fetch) to the stream's consumer taking "
+    "them out of the request's buffer: tick thread -> the replica's "
+    "event loop, which got the whole landing in one call and spread it "
+    "over the streams (a synchronous caller: -> its own thread)",
     ("engine",))
 SERVE_STREAM_STORE_SECONDS = Counter(
     "ray_tpu_serve_stream_store_seconds_total",
-    "Streamed tokens' seconds from the replica generator's yield to its "
-    "resumption: the runtime stored and announced the item",
+    "Streamed tokens' seconds from the stream handing them out to its "
+    "consumer asking for more: the runtime stored and announced the item",
     ("engine",))
 SERVE_STREAM_REPLICA_ITEMS = Counter(
     "ray_tpu_serve_stream_replica_items_total",
-    "Tokens the replica's generators yielded (the items the handoff and "
+    "Tokens the replica's streams handed out (the items the handoff and "
     "store seconds are over)",
+    ("engine",))
+SERVE_STREAM_HANDOFFS = Counter(
+    "ray_tpu_serve_stream_handoffs_total",
+    "Landings (a tick's row, a prefill batch's first tokens) whose tokens "
+    "the tick thread handed to the replica's streams: ONE call into the "
+    "replica's event loop each, whatever the number of open streams; the "
+    "replica's items over it are the tokens a call carried",
     ("engine",))
 SERVE_STREAM_LOOP_SECONDS = Counter(
     "ray_tpu_serve_stream_loop_seconds_total",
@@ -560,9 +568,9 @@ CB_STEP_ACCOUNT_MS = Histogram(
     boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
 CB_STEP_APPLY_MS = Histogram(
     "ray_tpu_cb_step_apply_ms",
-    "Host milliseconds booking fetched tokens: per-request callbacks "
-    "into the stream queues, finish detection, end-of-stream puts "
-    "(span engine.apply)",
+    "Host milliseconds booking fetched tokens: finish detection, the "
+    "landing's one hand-over to the replica's streams, the end-of-stream "
+    "hand-over (span engine.apply)",
     boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
 # The same thread's timeline seen from the DEVICE (sync_every == 1): from
 # the moment a landing leaves nothing queued on the device to the next

@@ -11,15 +11,20 @@ with ``@serve.batch`` merging concurrent requests into one batched decode
 
 from __future__ import annotations
 
-import contextlib
+import asyncio
+import collections
+import threading
 import time
-from typing import Any, Dict, List, Optional
+import weakref
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
 from ray_tpu import serve
+from ray_tpu._private import chaos
 from ray_tpu.models import llama
 from ray_tpu.models.inference import LlamaGenerator
+from ray_tpu.serve.api import StreamBatch
 from ray_tpu.serve.recovery import STREAM_ITEM_TIMEOUT_S
 
 
@@ -85,12 +90,14 @@ __all__ = ["LlamaDeployment", "build_llama_app"]
 class _StreamLag:
     """One stream's tokens on their way out of the replica, on
     ``time.time()`` (the request chain's clock): ``handoff`` from a
-    token's landing on the host (the engine's stamp) to the generator
-    thread taking it off the request's queue, ``store`` from the
-    generator's yield to its resumption (the runtime stored and
-    announced the item). A token costs two clock reads and a few float
-    adds on this object; the metrics registry is touched at the stream's
-    end and every ``FLUSH_EVERY`` items, never once a token."""
+    token's landing on the host (the engine's stamp) to the stream's
+    consumer taking it out of the request's buffer (the replica's loop,
+    which got the landing in one call), ``store`` from the stream
+    handing it out to its consumer coming back for more (the runtime
+    stored and announced the item). A token costs a share of two clock
+    reads and a few float adds on this object; the metrics registry is
+    touched at the stream's end and every ``FLUSH_EVERY`` items, never
+    once a token."""
 
     FLUSH_EVERY = 64
     __slots__ = ("items", "handoff_s", "handoff_max_s", "store_s",
@@ -105,8 +112,8 @@ class _StreamLag:
         self._flushed = (0, 0.0, 0.0)   # items, handoff_s, store_s
 
     def note(self, landed: float, got: float, resumed: float) -> None:
-        """A token that landed at ``landed`` left the queue at ``got``
-        and the generator was resumed after its yield at ``resumed``."""
+        """A token that landed at ``landed`` left the buffer at ``got``
+        and the consumer came back for the next at ``resumed``."""
         handoff = got - landed
         self.handoff_s += handoff
         if handoff > self.handoff_max_s:
@@ -140,7 +147,8 @@ class _StreamLag:
     def close(self, trace: Optional[Dict[str, Any]]) -> None:
         """The stream is over: flush, and for a traced request close its
         chain on the replica's side with one summary span,
-        ``engine.stream`` (first landing to last dequeue)."""
+        ``engine.stream`` (first landing to the last token leaving the
+        buffer)."""
         self.flush()
         if trace is None or not self.items:
             return
@@ -156,6 +164,289 @@ class _StreamLag:
             store_mean_s=self.store_s / self.items,
             landed_first_ts=self.first_landed,
             landed_last_ts=self.last_landed)
+
+
+_STREAM_END = object()
+
+
+def _spread(entries) -> None:
+    """The consumers' side of a hand-over: each ``(stream, entry)`` into
+    its stream's buffer. On the replica's loop this is ONE callback a
+    landing, whatever the number of open streams."""
+    for stream, entry in entries:
+        stream._take(entry)
+
+
+class _TokenStream:
+    """What ``generate`` and ``decode_from`` return: ONE request's tokens
+    over ONE buffer, for either kind of consumer. The replica drives it
+    from its event loop (``async for``): no thread exists for the
+    stream, the tick thread's one call a landing appends to the buffer
+    and resolves the future the consumer awaits, and a stream the loop
+    reaches late hands out everything it holds as one
+    :class:`~ray_tpu.serve.api.StreamBatch` (token by token where it
+    keeps up). A direct caller iterates it (``for``, ``list``) and waits
+    on its own thread. Buffer entries: ``(token, landed_ts)``, a control
+    object (a dict), ``None`` for the end, an exception to raise in
+    their place (an engine error, the item timeout, a simulated death).
+
+    The engine lock (submit or import at the start; cancel or the
+    request's lag at the end) is never taken on the loop: a stream that
+    starts or ends leaves itself in one of the deployment's two queues
+    and a task with the deployment's one hop thread, where ONE hold of
+    the lock does everything either queue holds
+    (:meth:`ContinuousLlamaDeployment._turn`); what the engine refuses
+    comes back through the buffer like any error. A consumer that leaves
+    early calls ``close()`` (``aclose()`` on the loop), which frees the
+    slot; one that drops the stream without (a ``break`` out of a
+    ``for``) leaves that to ``__del__``: the deployment holds its
+    streams weakly."""
+
+    def __init__(self, dep: "ContinuousLlamaDeployment",
+                 open_request: Optional[Callable[[], int]],
+                 trace: Optional[Dict[str, Any]],
+                 chaos_tokens: Optional[int] = None,
+                 first: Optional[int] = None):
+        """``open_request`` submits or imports under the engine lock
+        and returns the engine's request id (None: a stream with nothing
+        to say); ``chaos_tokens`` is the prompt length of the
+        ``phase=prefill`` chaos site in front of it; ``first`` a token
+        the stream opens with (an imported handoff's)."""
+        self._dep = dep
+        self._open = open_request
+        self._trace = trace
+        self._chaos_tokens = chaos_tokens
+        self._first = first
+        self._entered = time.time()
+        self._buf: collections.deque = collections.deque()
+        self._lag = _StreamLag(dep.batcher._mtags)
+        self.rid: Optional[int] = None
+        self._started = self._done = self._ended = False
+        self._emitted = 0
+        # The tokens handed out at the last turn: their landing stamps,
+        # and when they left the buffer.
+        self._out: List[float] = []
+        self._got = 0.0
+        # The loop's consumer: its loop, the future it awaits, the timer
+        # of ``STREAM_ITEM_TIMEOUT_S``. A synchronous one: its event.
+        self._loop = self._waiter = self._timer = None
+        self._event: Optional[threading.Event] = None
+        if open_request is None:
+            self._started = True
+            self._buf.append(None)
+
+    # ------------------------------------------------ the engine's side
+    def _take(self, entry) -> None:
+        self._buf.append(entry)
+        waiter = self._waiter
+        if waiter is not None:
+            self._waiter = None
+            try:
+                if not waiter.done():
+                    waiter.set_result(None)
+            except RuntimeError:    # its loop is closed: nobody waits
+                pass
+        elif self._event is not None:
+            self._event.set()
+
+    def _start(self, locked: float) -> List[tuple]:
+        """Under the engine lock (held since ``locked``): submit or
+        import, and register the stream with the deployment (under the
+        same hold: no landing can fall between the two). Returns what
+        the stream opens with, as ``(stream, entry)`` pairs to ship
+        under that hold too, ahead of the next landing."""
+        if self._ended:         # the consumer left before it began
+            return []
+        dep = self._dep
+        rid = self._open()
+        # Registered and named BEFORE the check below: a consumer that
+        # leaves now either finds the id (and settles it) or has set
+        # ``_ended`` for this thread to find.
+        dep._streams[rid] = self
+        self.rid = rid
+        dep.batcher.note_submit_wait(rid, self._entered, locked)
+        if self._ended:
+            dep._streams.pop(rid, None)
+            dep.batcher.cancel(rid)
+            return []
+        if self._first is None:
+            return []
+        # The engine made its first-token hand-over during the import,
+        # before the stream could be registered under the fresh rid.
+        return [(self, (self._first, dep.batcher.landed_ts))]
+
+    # ---------------------------------------------- the consumer's side
+    def _begin(self) -> None:
+        """The consumer's first turn, in the request's own context, before
+        the engine lock: the ``phase=prefill`` chaos site, ``serve.hop``
+        (the router's ``remote()`` to the replica method's entry; only a
+        traced request carries ``route_ts``), and a place among the
+        streams that wait to start."""
+        self._started = True
+        if self._chaos_tokens is not None and chaos.enabled():
+            chaos.inject("serve_replica", phase="prefill",
+                         tokens=self._chaos_tokens)
+        trace = self._trace
+        if trace is not None and trace.get("route_ts") is not None:
+            from ray_tpu.util import tracing
+
+            tracing.emit_span(
+                "serve.hop", trace_id=trace.get("trace_id", ""),
+                parent_span_id=trace.get("parent_span_id", ""),
+                ts=trace["route_ts"],
+                dur=self._entered - trace["route_ts"],
+                kind="route", request_id=trace.get("request_id", ""),
+                deployment=trace.get("deployment", ""))
+        self._dep._starting.append(self)
+
+    def _pop(self):
+        """The buffer's next entry as the item to hand out."""
+        entry = self._buf.popleft()
+        if entry is None:
+            self._done = True
+            return _STREAM_END
+        if isinstance(entry, BaseException):
+            raise entry
+        if isinstance(entry, dict):     # a control object
+            return entry
+        token, landed = entry
+        if chaos.enabled():
+            # Fires BEFORE the token is handed out: a rule with token=N
+            # dies with exactly N tokens delivered downstream.
+            chaos.inject("serve_replica", phase="decode",
+                         token=self._emitted)
+        self._emitted += 1
+        self._out.append(landed)
+        return token
+
+    def _more(self) -> bool:
+        """Whether the buffer's next entry is an item: not the end of
+        the stream, not an error."""
+        return bool(self._buf) and self._buf[0] is not None \
+            and not isinstance(self._buf[0], BaseException)
+
+    def _resumed(self) -> None:
+        """The consumer is back for more: book the last turn's tokens."""
+        if self._out:
+            now = time.time()
+            for landed in self._out:
+                self._lag.note(landed, self._got, now)
+            del self._out[:]
+
+    def _finish(self, inline: bool = True) -> None:
+        """The stream is closing: book its lag, and either keep it
+        beside the request's record or, for a stream abandoned before
+        its end (client gone, cancel, simulated process death), free
+        the slot so the ghost request stops burning decode ticks. Both
+        need the engine lock: the stream leaves them in the deployment's
+        queue, for the hop thread or, a synchronous consumer
+        (``inline``), for its own."""
+        if self._ended:
+            return
+        self._ended = True
+        if self._timer is not None:
+            self._timer.cancel()
+            self._timer = None
+        if self.rid is None:    # not started: ``_start`` finds ``_ended``
+            return
+        dep = self._dep
+        dep._streams.pop(self.rid, None)
+        self._lag.close(self._trace)
+        dep._settling.append((self.rid, self._lag, self._done))
+        if inline and self._loop is None:
+            dep._turn()
+        else:
+            dep._hops.submit(dep._turn)
+
+    def __del__(self):
+        # Dropped without ``close()``. Never inline: the last reference
+        # may go on the tick thread, under the engine lock.
+        try:
+            self._finish(inline=False)
+        except Exception:  # noqa: BLE001 — the interpreter is going down
+            pass
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        if self._ended:
+            raise StopIteration
+        try:
+            self._resumed()
+            if not self._started:
+                self._event = threading.Event()
+                self._begin()
+                self._dep._turn()
+            while not self._buf:
+                self._event.clear()
+                if not self._buf and not self._event.wait(
+                        STREAM_ITEM_TIMEOUT_S):
+                    raise TimeoutError(
+                        f"no token in {STREAM_ITEM_TIMEOUT_S} s")
+            self._got = time.time()
+            item = self._pop()
+            if item is _STREAM_END:
+                raise StopIteration
+            return item
+        except BaseException:
+            self._finish()
+            raise
+
+    def close(self) -> None:
+        self._finish()
+
+    def __aiter__(self):
+        return self
+
+    async def __anext__(self):
+        if self._ended:
+            raise StopAsyncIteration
+        try:
+            self._resumed()
+            if not self._started:
+                self._loop = self._dep._loop = asyncio.get_running_loop()
+                self._begin()
+                self._dep._hops.submit(self._dep._turn)
+                self._got = time.time()
+                self._timer = self._loop.call_later(
+                    STREAM_ITEM_TIMEOUT_S, self._overdue)
+            while not self._buf:
+                self._waiter = self._loop.create_future()
+                await self._waiter
+            self._got = time.time()
+            item = self._pop()
+            if item is _STREAM_END:
+                raise StopAsyncIteration
+            if self._more():
+                # The loop reached this stream late: what piled up
+                # ships as one object, cut in front of the end and of
+                # a simulated death, which come a turn later.
+                item = StreamBatch((item,))
+                try:
+                    while self._more():
+                        item.append(self._pop())
+                except BaseException as e:  # noqa: BLE001 — raised next turn
+                    self._buf.appendleft(e)
+            return item
+        except BaseException:
+            self._finish()
+            raise
+
+    async def aclose(self) -> None:
+        self._finish()
+
+    def _overdue(self) -> None:
+        """``STREAM_ITEM_TIMEOUT_S`` on the loop, as ONE timer a stream
+        that re-arms itself for what is left while items keep leaving
+        (or lie there untaken): a token costs the loop no timer."""
+        left = STREAM_ITEM_TIMEOUT_S - (time.time() - self._got)
+        if left > 0 or self._buf:
+            self._timer = self._loop.call_later(
+                max(left, 1.0), self._overdue)
+            return
+        self._timer = None
+        self._take(TimeoutError(f"no token in {STREAM_ITEM_TIMEOUT_S} s"))
 
 
 @serve.deployment
@@ -214,11 +505,11 @@ class ContinuousLlamaDeployment:
         the handoff and run the decode ticks) — plus every colocated
         entry point. The default ``"both"`` is the ordinary colocated
         engine."""
-        import queue
-        import threading
         import uuid
+        from concurrent.futures import ThreadPoolExecutor
 
         import ray_tpu
+        from ray_tpu._private import metrics_defs as mdefs
         from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
         from ray_tpu.models.continuous_batching import ContinuousBatcher
 
@@ -230,21 +521,46 @@ class ContinuousLlamaDeployment:
         self.config = config or llama.LlamaConfig.tiny()
         if params is None and checkpoint_path:
             params = _params_from_checkpoint(checkpoint_path)
-        # One stream queue a request. A SimpleQueue's put takes no
-        # Python-level lock, so the tick thread's token callbacks never
-        # wait for a consumer: with a hundred stream threads sharing the
-        # interpreter lock, a consumer descheduled inside Queue.get()
-        # held the queue's mutex, and the tick thread with it, for
-        # seconds (PR 28, serve_moe_decode).
-        self._queues: Dict[int, "queue.SimpleQueue"] = {}
+        # The open streams by engine request id, each a buffer (a
+        # deque: its append takes no Python-level lock, so the hand-over
+        # never waits for a consumer). The tick thread hands a
+        # landing's tokens to ``_loop``, the event loop that drives the
+        # streams (the replica's; learned from the first stream driven
+        # that way), in ONE ``call_soon_threadsafe``, and the loop
+        # spreads them: no thread exists per open stream and none is
+        # woken per token. With a thread a stream (until PR 41) a tick
+        # over 256 rows made 256 sleeping threads runnable, and the tick
+        # thread queued for the interpreter lock behind them every time
+        # it let go of it. While no loop drives a stream (a direct
+        # caller iterating on its own thread) the tick thread spreads
+        # the landing itself.
+        # Held weakly: a stream its consumer dropped without closing
+        # (a ``break`` out of a ``for``) is collected, and frees its
+        # slot from ``__del__``.
+        self._streams: "weakref.WeakValueDictionary[int, _TokenStream]" \
+            = weakref.WeakValueDictionary()
+        self._loop: Optional[asyncio.AbstractEventLoop] = None
+        # Where a request takes the engine lock, off the loop: once at
+        # its start (submit), once at its end (cancel, or its lag). It
+        # leaves itself in ``_starting`` or ``_settling`` and a task
+        # with the ONE hop thread, whose every hold of the lock does
+        # everything both queues hold (``_turn``): a wave of requests
+        # that arrives during a prefill is admitted together, and a
+        # burst of endings keeps no start from the lock. A burst that
+        # is still arriving at an engine with nothing live is given
+        # room once before it starts (``_burst_room``).
+        self._hops = ThreadPoolExecutor(max_workers=1,
+                                        thread_name_prefix="llm-req")
+        self._starting: collections.deque = collections.deque()
+        self._settling: collections.deque = collections.deque()
+        self._handoffs = mdefs.SERVE_STREAM_HANDOFFS
         self._lock = threading.Lock()
         self._pressure: Dict[str, Any] = {}     # the last snapshot read
         self._work = threading.Event()
-        self._queue_mod = queue
         self.batcher = ContinuousBatcher(
             self.config, params=params, num_slots=num_slots,
             max_len=max_len, eos_token=eos_token,
-            token_callback=self._on_token, sync_every=sync_every,
+            landing_callback=self._on_landing, sync_every=sync_every,
             use_decode_kernel=use_decode_kernel,
             block_size=block_size, kv_dtype=kv_dtype,
             num_blocks=num_blocks, prefix_cache=prefix_cache,
@@ -262,13 +578,32 @@ class ContinuousLlamaDeployment:
         threading.Thread(target=self._tick_loop, daemon=True,
                          name="llm-ticks").start()
 
-    def _on_token(self, rid: int, token: int) -> None:
-        # Called on the tick thread as a landing's tokens are booked:
-        # the token rides with that landing's stamp, for the stream's
-        # handoff clock (``_StreamLag``).
-        q = self._queues.get(rid)
-        if q is not None:
-            q.put((token, self.batcher.landed_ts))
+    def _on_landing(self, tokens: List[tuple], landed: float) -> None:
+        """On the tick thread, once a landing, right after its tokens
+        are booked: each token of a request whose stream is open rides
+        with the landing's stamp (the stream's handoff clock,
+        ``_StreamLag``) to the streams' side in ONE call. A token of a
+        request with no stream registered NOW is dropped here, not on
+        the loop later (an import's first token, which ``decode_from``
+        delivers itself)."""
+        streams = self._streams
+        entries = [(stream, (token, landed)) for rid, token in tokens
+                   if (stream := streams.get(rid)) is not None]
+        if entries:
+            self._handoffs.inc(tags=self.batcher._mtags)
+            self._ship(entries)
+
+    def _ship(self, entries: List[tuple]) -> None:
+        """``(stream, entry)`` pairs to the streams' buffers: one call
+        into the loop that drives them, which spreads them there."""
+        loop = self._loop
+        if loop is not None:
+            try:
+                loop.call_soon_threadsafe(_spread, entries)
+                return
+            except RuntimeError:    # closed, and its streams with it
+                self._loop = None
+        _spread(entries)
 
     def _tick_loop(self) -> None:
         import logging
@@ -297,25 +632,29 @@ class ContinuousLlamaDeployment:
                     self._lock.release()
                 with tracing.phase("engine.apply", mdefs.CB_STEP_APPLY_MS,
                                    tags):
+                    ends = []
                     for rid in finished:
-                        q = self._queues.get(rid)
+                        stream = self._streams.get(rid)
                         routes = self.batcher.take_routes(rid)
-                        if q is not None:
+                        if stream is not None:
                             if routes is not None:
-                                q.put({"routes": routes})
-                            q.put(None)  # end-of-stream
+                                ends.append((stream, {"routes": routes}))
+                            ends.append((stream, None))  # end-of-stream
+                    if ends:
+                        # Behind the step's landings in the loop's
+                        # queue: after each request's last token.
+                        self._ship(ends)
             except Exception as e:  # noqa: BLE001
                 # Engine error (OOM, bad request reaching the kernel):
                 # fail every in-flight stream explicitly and reset the
                 # slot pool, instead of dying silently and leaving
-                # clients blocked on their queues.
+                # clients waiting on their streams.
                 log.exception("continuous-batching tick failed; "
                               "aborting in-flight requests")
                 with self._lock:
                     self.batcher.reset()
-                    queues = dict(self._queues)
-                for q in queues.values():
-                    q.put(e)
+                    streams = list(self._streams.values())
+                self._ship([(stream, e) for stream in streams])
 
     @staticmethod
     def _request_trace() -> Optional[Dict[str, Any]]:
@@ -335,28 +674,81 @@ class ContinuousLlamaDeployment:
         trace.setdefault("tenant", multiplex.get_request_tenant())
         return trace
 
-    @contextlib.contextmanager
-    def _submitting(self, entered: float,
-                    trace: Optional[Dict[str, Any]]):
-        """Hold the engine lock for a submit or import; yields when it
-        was got. With ``entered``, the replica method's entry, that is
-        what precedes the engine's own TTFT clock: ``serve.hop`` (the
-        router's ``remote()`` to ``entered``, emitted here; only a
-        traced request carries ``route_ts``) and the wait for the lock,
-        which the tick thread holds across each step and which the
-        caller hands to ``batcher.note_submit_wait`` with the request
-        id its submit returned."""
-        from ray_tpu.util import tracing
-
-        if trace is not None and trace.get("route_ts") is not None:
-            tracing.emit_span(
-                "serve.hop", trace_id=trace.get("trace_id", ""),
-                parent_span_id=trace.get("parent_span_id", ""),
-                ts=trace["route_ts"], dur=entered - trace["route_ts"],
-                kind="route", request_id=trace.get("request_id", ""),
-                deployment=trace.get("deployment", ""))
+    def _turn(self) -> None:
+        """Under ONE hold of the engine lock, which the tick thread holds
+        across each step: everything that waits for it. Every stream
+        that has closed is settled (one read to its end leaves its
+        tokens' mean lag beside the request's record; one abandoned
+        before it frees the slot), then every stream that waits to start
+        is submitted (or imported), in arrival order. What precedes the
+        engine's own TTFT clock is the wait for the lock, which each
+        stream hands to ``batcher.note_submit_wait`` with the request id
+        its submit returned; what the engine refuses goes to that
+        stream's consumer alone. A task that finds both queues empty was
+        served by an earlier hold and takes none. A burst still running
+        at an idle engine's door is given room once, without the lock
+        (:meth:`_burst_room`), and started under a second hold."""
+        if not (self._starting or self._settling):
+            return
         with self._lock:
-            yield time.time()
+            locked = time.time()
+            self._settle_waiting()
+            room = self._burst_room(locked)
+            if not room:
+                self._start_waiting(locked)
+        if room:
+            time.sleep(room)
+            with self._lock:
+                self._start_waiting(time.time())
+        self._work.set()
+
+    def _settle_waiting(self) -> None:
+        """Under the engine lock: every stream that has closed."""
+        while self._settling:
+            rid, lag, done = self._settling.popleft()
+            try:
+                if not done:
+                    self.batcher.cancel(rid)
+                elif lag.items:
+                    self.batcher.note_stream(rid, lag.handoff_mean_s)
+            except Exception:  # noqa: BLE001 — the others still settle
+                import logging
+
+                logging.getLogger(__name__).exception(
+                    "settling stream %s failed", rid)
+
+    def _start_waiting(self, locked: float) -> None:
+        """Under the engine lock (held since ``locked``): every stream
+        that waits to start, in arrival order."""
+        opened: List[tuple] = []
+        while self._starting:
+            stream = self._starting.popleft()
+            try:
+                opened.extend(stream._start(locked))
+            except BaseException as e:  # noqa: BLE001 — that stream's
+                # alone, a simulated death (``kv_transfer``'s chaos
+                # site) included: its consumer raises it.
+                opened.append((stream, e))
+        if opened:
+            self._ship(opened)
+
+    def _burst_room(self, now: float) -> float:
+        """Under the engine lock: how long to hold back the streams that
+        wait to start, or 0. Several streams at the door of an engine
+        with no request live or queued came in while its last step ran:
+        a burst. If its newest member came more recently than the burst
+        has lasted, the burst is likely still running, and an engine
+        that starts now spends the next step (a prefill batch, with the
+        lock) on a part of it while the rest waits a whole step for a
+        second, padded batch: the burst is given as long again as it
+        has lasted, once, and started together. One stream, a live
+        engine or a burst that has paused waits for nothing."""
+        if len(self._starting) < 2 or self.batcher._slots \
+                or self.batcher._waiting:
+            return 0.0
+        oldest, newest = self._starting[0], self._starting[-1]
+        lasted = newest._entered - oldest._entered
+        return lasted if now - newest._entered < lasted else 0.0
 
     def engine_info(self) -> Dict[str, Any]:
         """What this replica's engine actually runs and where: the
@@ -519,7 +911,9 @@ class ContinuousLlamaDeployment:
 
     def generate(self, prompt_token_ids,
                  max_tokens: int = 16):
-        """Streaming generator of token ids (serve stream=True surface).
+        """A stream of token ids (serve stream=True surface): a
+        :class:`_TokenStream`, which the replica drives from its loop
+        (``async for``) and a direct caller iterates (``for``, ``list``).
         Accepts either the token-id list directly or the ingress payload
         dict (``{"prompt_token_ids": [...], "max_tokens": N}``) — the
         HTTP/gRPC streaming routes (``POST /<name>/stream/generate``)
@@ -528,20 +922,18 @@ class ContinuousLlamaDeployment:
 
         Chaos sites (``_private/chaos.py`` ``kill_replica``): before the
         engine submit (``phase=prefill`` — the request is queued-or-
-        prefilling, nothing streamed) and before yielding the Nth token
-        (``phase=decode,token=N`` — mid-decode, N tokens already
-        streamed). The raised ``SimulatedProcessDeath`` unwinds through
-        the replica actor's task machinery into genuine actor death —
-        exactly what the ingress journal recovers from.
+        prefilling, nothing streamed) and before handing out the Nth
+        token (``phase=decode,token=N`` — mid-decode, N tokens already
+        streamed: what had piled up in front of it ships first). The
+        raised ``SimulatedProcessDeath`` unwinds through the replica
+        actor's task machinery into genuine actor death — exactly what
+        the ingress journal recovers from.
 
         ``"return_routes": true`` in the payload (a held expert share
         alone): after the last token the stream carries one control
         object, ``{"routes": [position][routed layer][k]}``, the experts
         each decoded position routed to
         (``ContinuousBatcher.take_routes``)."""
-        from ray_tpu._private import chaos
-
-        entered = time.time()
         resumed_tokens = 0
         keep_routes = False
         if isinstance(prompt_token_ids, dict):
@@ -550,6 +942,7 @@ class ContinuousLlamaDeployment:
             max_tokens = payload.get("max_tokens", max_tokens)
             keep_routes = bool(payload.get("return_routes", False))
             resumed_tokens = int(payload.get("resumed_tokens", 0) or 0)
+        trace = self._request_trace()
         if resumed_tokens and self.batcher.eos_token is not None \
                 and prompt_token_ids \
                 and prompt_token_ids[-1] == self.batcher.eos_token:
@@ -559,61 +952,15 @@ class ContinuousLlamaDeployment:
             # the leftover budget would append post-EOS garbage the
             # un-killed run never produced. (Only resumes check this:
             # an ORIGINAL prompt may legitimately end with EOS.)
-            return
-        q = self._queue_mod.SimpleQueue()
-        trace = self._request_trace()
-        if chaos.enabled():
-            chaos.inject("serve_replica", phase="prefill",
-                         tokens=len(prompt_token_ids))
-        with self._submitting(entered, trace) as locked:
-            rid = self.batcher.submit(list(prompt_token_ids),
-                                      max_new_tokens=int(max_tokens),
-                                      trace=trace, keep_routes=keep_routes)
-            self.batcher.note_submit_wait(rid, entered, locked)
-            self._queues[rid] = q
-        self._work.set()
-        done = False
-        emitted = 0
-        lag = _StreamLag(self.batcher._mtags)
-        try:
-            while True:
-                token = q.get(timeout=STREAM_ITEM_TIMEOUT_S)
-                if token is None:
-                    done = True
-                    return
-                if isinstance(token, Exception):
-                    done = True
-                    raise token
-                if isinstance(token, dict):     # a control object
-                    yield token
-                    continue
-                token, landed = token
-                got = time.time()
-                if chaos.enabled():
-                    # Fires BEFORE the yield: a rule with token=N dies
-                    # with exactly N tokens delivered downstream.
-                    chaos.inject("serve_replica", phase="decode",
-                                 token=emitted)
-                emitted += 1
-                yield token
-                lag.note(landed, got, time.time())
-        finally:
-            self._stream_ended(rid, lag, trace, done)
+            return _TokenStream(self, None, trace)
+        prompt = list(prompt_token_ids)
 
-    def _stream_ended(self, rid: int, lag: _StreamLag,
-                      trace: Optional[Dict[str, Any]], done: bool) -> None:
-        """A token stream's generator is closing: book its lag, and
-        either keep it beside the request's record or, for a stream
-        abandoned before its end (client disconnect, simulated process
-        death), free the slot so the ghost request stops burning decode
-        ticks."""
-        self._queues.pop(rid, None)
-        lag.close(trace)
-        with self._lock:
-            if not done:
-                self.batcher.cancel(rid)
-            elif lag.items:
-                self.batcher.note_stream(rid, lag.handoff_mean_s)
+        def submit() -> int:
+            return self.batcher.submit(prompt,
+                                       max_new_tokens=int(max_tokens),
+                                       trace=trace, keep_routes=keep_routes)
+
+        return _TokenStream(self, submit, trace, chaos_tokens=len(prompt))
 
     # ------------------------------------ disaggregated prefill/decode
     def _req_deployment(self) -> str:
@@ -637,10 +984,8 @@ class ContinuousLlamaDeployment:
         (nothing journaled — the router resubmits) and
         ``kv_transfer``/``stage=export`` inside the transfer helper
         (prefill death mid-export — same resubmit leg)."""
-        from ray_tpu._private import chaos
         from ray_tpu.serve import kv_transfer
 
-        entered = time.time()
         prompt = list(payload["prompt_token_ids"])
         max_tokens = int(payload.get("max_tokens", 16))
         resumed_tokens = int(payload.get("resumed_tokens", 0) or 0)
@@ -651,28 +996,12 @@ class ContinuousLlamaDeployment:
             # died with the replica (see generate()).
             return {"done": []}
         trace = self._request_trace()
-        if chaos.enabled():
-            chaos.inject("serve_replica", phase="prefill",
-                         tokens=len(prompt))
-        q = self._queue_mod.SimpleQueue()
-        with self._submitting(entered, trace) as locked:
-            rid = self.batcher.submit(prompt,
-                                      max_new_tokens=max_tokens,
-                                      trace=trace)
-            self.batcher.note_submit_wait(rid, entered, locked)
-            self._queues[rid] = q
-        self._work.set()
-        tokens: List[int] = []
-        try:
-            while True:
-                item = q.get(timeout=STREAM_ITEM_TIMEOUT_S)
-                if item is None:
-                    break
-                if isinstance(item, Exception):
-                    raise item
-                tokens.append(item[0])
-        finally:
-            self._queues.pop(rid, None)
+        stream = _TokenStream(
+            self, lambda: self.batcher.submit(
+                prompt, max_new_tokens=max_tokens, trace=trace),
+            trace, chaos_tokens=len(prompt))
+        tokens: List[int] = list(stream)
+        rid = stream.rid
         with self._lock:
             if rid not in self.batcher.handoff_ready():
                 # Finished entirely at prefill — a complete (short)
@@ -715,10 +1044,8 @@ class ContinuousLlamaDeployment:
         helper (decode death after the journaled handoff — the router
         replays as a fresh prefill, ``cause=resume``) and the usual
         ``serve_replica``/``phase=decode`` per-token site."""
-        from ray_tpu._private import chaos
         from ray_tpu.serve import kv_transfer
 
-        entered = time.time()
         manifest = request["manifest"]
         ticket = request.get("reservation")
         res_id = None
@@ -726,44 +1053,18 @@ class ContinuousLlamaDeployment:
                 ticket.get("nonce") == self._nonce:
             res_id = ticket.get("res_id")
         trace = self._request_trace()
-        q = self._queue_mod.SimpleQueue()
-        with self._submitting(entered, trace) as locked:
-            # The engine fires its first-token callback during the
-            # import, before any queue could be registered under the
-            # fresh rid — the manifest's first_token is delivered
-            # explicitly below instead.
-            rid = kv_transfer.receive_handoff(
+        deployment = self._req_deployment()
+
+        def receive() -> int:
+            return kv_transfer.receive_handoff(
                 self.batcher, manifest, reservation=res_id,
-                trace=trace, deployment=self._req_deployment())
-            self.batcher.note_submit_wait(rid, entered, locked)
-            self._queues[rid] = q
-        self._work.set()
-        done = False
-        emitted = 0
-        lag = _StreamLag(self.batcher._mtags)
-        try:
-            if chaos.enabled():
-                chaos.inject("serve_replica", phase="decode", token=0)
-            emitted = 1
-            yield int(manifest["first_token"])
-            while True:
-                token = q.get(timeout=STREAM_ITEM_TIMEOUT_S)
-                if token is None:
-                    done = True
-                    return
-                if isinstance(token, Exception):
-                    done = True
-                    raise token
-                token, landed = token
-                got = time.time()
-                if chaos.enabled():
-                    chaos.inject("serve_replica", phase="decode",
-                                 token=emitted)
-                emitted += 1
-                yield token
-                lag.note(landed, got, time.time())
-        finally:
-            self._stream_ended(rid, lag, trace, done)
+                trace=trace, deployment=deployment)
+
+        # The engine hands over the first token during the import,
+        # before a stream could be registered under the fresh rid: the
+        # stream opens with the manifest's instead (``_start``).
+        return _TokenStream(self, receive, trace,
+                            first=int(manifest["first_token"]))
 
     def __call__(self, request: Dict[str, Any]) -> Dict[str, Any]:
         """Non-streaming completion."""

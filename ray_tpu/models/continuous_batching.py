@@ -957,6 +957,7 @@ class ContinuousBatcher:
     def __init__(self, config: llama.LlamaConfig, params=None,
                  num_slots: int = 8, max_len: int = 512, seed: int = 0,
                  eos_token: Optional[int] = None, token_callback=None,
+                 landing_callback=None,
                  sync_every: int = 1,
                  use_decode_kernel: Optional[bool] = None,
                  block_size: int = 64,
@@ -971,8 +972,15 @@ class ContinuousBatcher:
                  role: Optional[str] = None,
                  device: Optional[jax.Device] = None,
                  prefill_chunk: int = 1024):
-        """``token_callback(rid, token)`` fires for every generated token
-        as it is produced (serving streams ride this).
+        """``landing_callback(tokens, landed_ts)`` fires ONCE a landing
+        (a tick's row, a prefill batch's first tokens, an imported
+        handoff's first token) with the tokens it booked, ``[(rid,
+        token), ...]`` in booking order, and the landing's
+        ``time.time()`` stamp: what a serving replica hands to its event
+        loop in one call (:meth:`_hand_over`). ``token_callback(rid,
+        token)`` is the same hand-over a token at a time, for callers
+        that count or collect (benchmarks, tests); either, both or
+        neither may be set, and reassigned on a live engine.
 
         ``device`` is the one chip this engine lives on: parameters, the
         KV arena and every per-tick upload are COMMITTED to it, so each
@@ -1225,6 +1233,7 @@ class ContinuousBatcher:
             x.nbytes for x in jax.tree_util.tree_leaves(self._draft_params))
         self._draft_cache = None
         self.token_callback = token_callback
+        self.landing_callback = landing_callback
         # Table width covers max_len PLUS the spec look-ahead: a spec
         # tick writes draft/verify K/V up to position p + spec_k, and
         # those writes must stay inside the slot's own reservation
@@ -1297,7 +1306,7 @@ class ContinuousBatcher:
         self._tick_ms = 0.0
         self._hold: Optional[Dict[str, Any]] = None
         # ``time.time()`` of the landing whose tokens are being booked:
-        # what a token callback may stamp its token with.
+        # the stamp ``_hand_over`` gives the landing callback.
         self.landed_ts = 0.0
         self._prefill_count = 0   # per-dispatch prefill sampling stream
         # Buffered-mode achieved-bandwidth window: wall time and tick
@@ -2448,8 +2457,7 @@ class ContinuousBatcher:
         self._note_first_token(meta, t0, now)
         if meta.get("handoff") is not None:
             meta["handoff"]["import_s"] = now - t0
-        if self.token_callback is not None:
-            self.token_callback(rid, first)
+        self._hand_over([(rid, first)])
         self._slots[slot] = {
             "rid": rid, "out": [first], "max_new": max_new,
             "pos": plen, "last": first, "routes": None,
@@ -3109,6 +3117,7 @@ class ContinuousBatcher:
                         n * (n_chunks - 1), tags=self._mtags)
             # The fetch above synced the device: the first tokens landed.
             first_ts = self.landed_ts = time.time()
+            landed = []
             for (req, slot, blocks, matched, _sfx, chunks), tok in \
                     zip(group, first):
                 tok = int(tok)
@@ -3127,8 +3136,7 @@ class ContinuousBatcher:
                                                   start=len(matched))
                     if matched or created:
                         self._slot_nodes[slot] = matched + created
-                if self.token_callback is not None:
-                    self.token_callback(req["rid"], tok)
+                landed.append((req["rid"], tok))
                 self._slots[slot] = {
                     "rid": req["rid"], "out": [tok],
                     "max_new": req["max_new"],
@@ -3151,6 +3159,9 @@ class ContinuousBatcher:
                 if (self._draft_prefill is not None
                         and slot in self._slots):
                     draft_pending.append((slot, req["prompt"]))
+            # The batch's first tokens leave now, not behind the fetch
+            # of the tick that follows them.
+            self._hand_over(landed)
         if self._draft_prefill is not None and draft_pending:
             self._run_draft_prefill(draft_pending)
         self._dirty = True  # device tokens/positions need re-upload
@@ -3326,19 +3337,33 @@ class ContinuousBatcher:
             return
         entries[rid] = ent
 
+    def _hand_over(self, tokens: List[tuple]) -> None:
+        """One landing's booked tokens, ``[(rid, token), ...]`` in
+        booking order, to whoever streams them: the landing callback
+        once, with ``landed_ts``; the per-token callback for each."""
+        if not tokens:
+            return
+        if self.landing_callback is not None:
+            self.landing_callback(tokens, self.landed_ts)
+        if self.token_callback is not None:
+            for rid, tok in tokens:
+                self.token_callback(rid, tok)
+
     def _apply_tokens(self, nxt_rows, membership, window=None) -> bool:
         """Book one or more fetched tick rows; returns True when any
         request finished (membership changed). ``window`` is the
         (wall_start, wall_end) of the sync window these rows cover —
         recorded per traced request for the decode-window spans (windows
         must attach BEFORE ``_maybe_finish`` pops the record, so this
-        rides the apply loop, not a post-pass). The token callbacks are
-        made here, as each token is booked: the per-tick step has the
-        next tick queued on the device by then, so the streams they
-        wake run while the device computes."""
+        rides the apply loop, not a post-pass). What was booked is
+        handed over in ONE call when the rows are done
+        (:meth:`_hand_over`): the per-tick step has the next tick queued
+        on the device by then, so the streams it feeds run while the
+        device computes, and a request the step goes on to report as
+        finished has all its tokens delivered."""
         from ray_tpu._private import metrics_defs as mdefs
 
-        callback = self.token_callback
+        landed: List[tuple] = []
         with tracing.phase("engine.apply", mdefs.CB_STEP_APPLY_MS,
                            self._mtags):
             finished_any = False
@@ -3375,8 +3400,7 @@ class ContinuousBatcher:
                     for j in range(n):
                         tok = int(toks[slot]) if counts is None else int(
                             toks[slot, j])
-                        if callback is not None:
-                            callback(rid, tok)
+                        landed.append((rid, tok))
                         st["out"].append(tok)
                         st["last"] = tok
                         st["pos"] += 1
@@ -3391,6 +3415,7 @@ class ContinuousBatcher:
                             # dirty re-upload the finish already forces).
                             finished_any = True
                             break
+            self._hand_over(landed)
             self.decoded_tokens += applied
             if applied:
                 mdefs.CB_DECODE_TOKENS.inc(applied, tags=self._mtags)
@@ -3648,7 +3673,7 @@ class ContinuousBatcher:
         The step keeps one tick queued behind the one that runs: it
         dispatches tick n+1 BEFORE it fetches tick n, so the device goes
         from one tick straight into the next while this thread books
-        tokens, runs callbacks, admits and uploads (the first step after
+        tokens, hands them over, admits and uploads (the first step after
         an idle engine dispatches two). The device carries tokens,
         positions, step counter and arena from tick to tick; what the
         host re-uploads on a membership change it knows a tick ahead
@@ -3814,8 +3839,9 @@ class ContinuousBatcher:
             self._bw_window_t0 = now
             self._bw_window_ticks = 0
             self._note_expert_rows(rows)
+            self.landed_ts = time.time()
             if self._apply_tokens(rows, membership,
-                                  window=(win0, time.time())):
+                                  window=(win0, self.landed_ts)):
                 self._buf = []
                 self._dirty = True
                 return
@@ -3827,8 +3853,9 @@ class ContinuousBatcher:
             membership = [(s, st["rid"]) for s, st in self._slots.items()]
             self._buf = []
             win0, self._window_t0 = self._window_t0, None
+            self.landed_ts = time.time()
             self._apply_tokens(rows, membership,
-                               window=(win0, time.time()))
+                               window=(win0, self.landed_ts))
             self._dirty = True
             return
         if not self._buf:

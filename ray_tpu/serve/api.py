@@ -173,13 +173,16 @@ CONTROL_CONCURRENCY = 8
 
 
 class StreamBatch(list):
-    """What a replica's sync generator had yielded when the replica's
-    loop next turned, as ONE streamed object: a stream that keeps up
-    ships its items one at a time, as ever; one that has fallen behind
-    (hundreds of open streams share the replica's loop, the object
-    store and the interpreter lock) ships what has piled up, so the
-    cost of a turn is paid once for all of it and the stream catches
-    up. :class:`DeploymentResponseGenerator` hands the items out one by
+    """What a stream held when the replica's loop next reached it, as
+    ONE streamed object: a stream that keeps up ships its items one at a
+    time, as ever; one that has fallen behind (hundreds of open streams
+    share the replica's loop, the object store and the interpreter
+    lock) ships what has piled up, so the cost of a turn is paid once
+    for all of it and the stream catches up. Made by
+    :meth:`Replica._stream_sync_generator` for a sync generator's items
+    and by the engine deployments' token streams
+    (``ray_tpu.llm._TokenStream``), which the loop drives itself.
+    :class:`DeploymentResponseGenerator` hands the items out one by
     one, so a consumer never sees a batch."""
 
 
@@ -277,7 +280,9 @@ class Replica:
         """Streaming variant: each yield of the user method becomes one
         streamed item when called with num_returns="streaming" (reference:
         DeploymentResponseGenerator / RayServeHandle stream=True). Accepts
-        sync and async generators."""
+        async iterators, which this loop drives itself (an async
+        generator; the engine deployments' token streams, which cost no
+        thread), and sync generators, which get a pool thread each."""
         from ray_tpu.serve import context as serve_context
         from ray_tpu.serve import multiplex
 
@@ -288,8 +293,15 @@ class Replica:
         try:
             result = self._target(method)(*args, **kwargs)
             if hasattr(result, "__aiter__"):
-                async for item in result:
-                    yield item
+                try:
+                    async for item in result:
+                        yield item
+                finally:
+                    # A consumer that left first (a client gone, a
+                    # cancel): closing the iterator frees what it holds.
+                    aclose = getattr(result, "aclose", None)
+                    if aclose is not None:
+                        await aclose()
             elif hasattr(result, "__next__"):
                 async for batch in self._stream_sync_generator(result):
                     yield batch
@@ -306,7 +318,9 @@ class Replica:
                 self._ongoing -= 1
 
     async def _stream_sync_generator(self, result):
-        """A sync generator's items as :class:`StreamBatch` es: ONE pool
+        """A sync generator's items as :class:`StreamBatch` es (any
+        deployment's sync generator method; the engine deployments
+        return an async iterator instead and take no thread): ONE pool
         thread runs the generator for the stream's whole life (a slow
         producer cannot stall the replica's other requests; the copied
         context carries the multiplexed-model-id ContextVar into it, as
