@@ -108,7 +108,7 @@ def _breakdown_pcts(breakdowns) -> dict:
     return out
 
 
-def _prefix_phase(config, params, num_slots, max_len, sync_every,
+def _prefix_phase(config, params, num_slots, max_len,
                   block_size, shared_blocks, tail_len, rounds,
                   shared_frac=0.75) -> dict:
     """Prefix-reuse churn: ``shared_frac`` of requests share one system
@@ -143,7 +143,6 @@ def _prefix_phase(config, params, num_slots, max_len, sync_every,
     for on in (True, False):
         eng = ContinuousBatcher(config, params=params,
                                 num_slots=num_slots, max_len=max_len,
-                                sync_every=sync_every,
                                 block_size=block_size, prefix_cache=on)
         # Warm-up = the steady state of a serving replica: the system
         # prompt is resident AND both prefill program shapes (cold full
@@ -272,7 +271,7 @@ def _spec_phase(config, params, num_slots, max_len, prompt_len, ticks,
         del eng  # release the previous point's arena first
         eng = ContinuousBatcher(config, params=pp,
                                 num_slots=num_slots, max_len=max_len,
-                                sync_every=1, spec_k=k, spec_draft_layers=dl,
+                                spec_k=k, spec_draft_layers=dl,
                                 spec_adaptive=False)
         tps, med, _ = _measure_decode(eng, num_slots, max_len,
                                       prompt_len, ticks)
@@ -375,7 +374,7 @@ def _disagg_phase(config, params, num_slots, max_len, block_size,
 
     colo = ContinuousBatcher(config, params=params, role="both",
                              num_slots=num_slots, max_len=max_len,
-                             sync_every=1, block_size=block_size,
+                             block_size=block_size,
                              token_callback=on_token)
     def _run_colo(reqs):
         t0 = time.perf_counter()
@@ -405,7 +404,7 @@ def _disagg_phase(config, params, num_slots, max_len, block_size,
     # on a free decode slot (production pre-reserves; the bench polls).
     pre = ContinuousBatcher(config, params=params, role="prefill",
                             num_slots=num_slots, max_len=max_len,
-                            sync_every=1, block_size=block_size)
+                            block_size=block_size)
     # Role-specific sizing is one of disaggregation's levers: a decode
     # slot costs arena blocks, not prefill compute, so a decode-role
     # engine runs more concurrent generations than a colocated engine
@@ -413,7 +412,7 @@ def _disagg_phase(config, params, num_slots, max_len, block_size,
     decode_slots = 2 * num_slots
     dec = ContinuousBatcher(config, params=params, role="decode",
                             num_slots=decode_slots, max_len=max_len,
-                            sync_every=1, block_size=block_size)
+                            block_size=block_size)
     submit_ts.clear()
     split_ttft = []
     handoff_walls = []
@@ -515,7 +514,6 @@ def main() -> None:
         num_layers=16, num_heads=16, num_kv_heads=16, head_dim=128,
         max_seq_len=2048)
     num_slots, max_len, prompt_len, ticks = 32, 512, 32, 120
-    sync_every = 32  # host syncs per K ticks
     sweep_grid = [(kv, bs) for kv in ("bf16", "int8")
                   for bs in (32, 64, 128)]
     sweep_ticks = 40
@@ -530,7 +528,7 @@ def main() -> None:
             ttft_s.append(time.perf_counter() - t0)
 
     eng = ContinuousBatcher(config, num_slots=num_slots, max_len=max_len,
-                            sync_every=sync_every, token_callback=on_token)
+                            token_callback=on_token)
     param_bytes = eng.param_bytes
 
     def top_up(max_new=None, stamp=False):
@@ -580,7 +578,7 @@ def main() -> None:
     # prefill tokens/s (or >=50% prefill_tokens_saved) at 75% shared
     # traffic.
     prefix_phase = _prefix_phase(config, eng.params, num_slots,
-                                 max_len, sync_every, block_size=64,
+                                 max_len, block_size=64,
                                  shared_blocks=4, tail_len=16, rounds=4)
 
     # Phase 2d — speculative-decoding ladder (ISSUE-17 tentpole):
@@ -635,7 +633,7 @@ def main() -> None:
     for kv_dtype, bs in sweep_grid:
         del s_eng  # release the previous config's arena before allocating
         s_eng = ContinuousBatcher(config, num_slots=num_slots,
-                                  max_len=max_len, sync_every=sync_every,
+                                  max_len=max_len,
                                   block_size=bs,
                                   kv_dtype=kv_dtype, params=eng.params)
         tps, _, lb = _measure_decode(s_eng, num_slots, max_len,
@@ -678,7 +676,6 @@ def main() -> None:
         "kv_dtype": eng.kv_dtype,
         "sweep": sweep,
         "num_slots": num_slots,
-        "sync_every": sync_every,
         "param_bytes": param_bytes,
         "platform": jax.devices()[0].platform,
         "device_kind": jax.devices()[0].device_kind,

@@ -510,9 +510,7 @@ CB_TICK_MS = Histogram(
     "ray_tpu_cb_tick_ms",
     "Wall milliseconds per decode tick, one observation a tick: the "
     "time between two consecutive token rows reaching the host (from "
-    "the dispatch where the device was idle or prefilling before it); "
-    "dispatch only when speculative buffering (sync_every > 1) "
-    "overlaps the fetch",
+    "the dispatch where the device was idle or prefilling before it)",
     boundaries=(0.5, 1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0,
                 500.0, 1000.0),
     tag_keys=("engine",))
@@ -572,7 +570,7 @@ CB_STEP_APPLY_MS = Histogram(
     "landing's one hand-over to the replica's streams, the end-of-stream "
     "hand-over (span engine.apply)",
     boundaries=_STEP_MS_BOUNDS, tag_keys=("engine",))
-# The same thread's timeline seen from the DEVICE (sync_every == 1): from
+# The same thread's timeline seen from the DEVICE: from
 # the moment a landing leaves nothing queued on the device to the next
 # dispatch, the device waits for this thread. One histogram a cause, one
 # observation an interval, on ``time.perf_counter()`` like the tick's

@@ -464,7 +464,7 @@ class ContinuousLlamaDeployment:
 
     def __init__(self, config: Optional[llama.LlamaConfig] = None,
                  params=None, num_slots: int = 8, max_len: int = 512,
-                 eos_token: Optional[int] = None, sync_every: int = 1,
+                 eos_token: Optional[int] = None,
                  use_decode_kernel: Optional[bool] = None,
                  block_size: int = 64,
                  kv_dtype: Optional[str] = None,
@@ -477,7 +477,7 @@ class ContinuousLlamaDeployment:
                  checkpoint_path: Optional[str] = None,
                  role: Optional[str] = None,
                  prefill_chunk: Optional[int] = None):
-        """Engine knobs (``num_slots``, ``max_len``, ``sync_every``,
+        """Engine knobs (``num_slots``, ``max_len``,
         ``use_decode_kernel``, and the paged-KV plane's
         ``block_size`` / ``kv_dtype`` / ``num_blocks`` / ``sampling``)
         pass straight to the ContinuousBatcher and are overridable
@@ -560,7 +560,7 @@ class ContinuousLlamaDeployment:
         self.batcher = ContinuousBatcher(
             self.config, params=params, num_slots=num_slots,
             max_len=max_len, eos_token=eos_token,
-            landing_callback=self._on_landing, sync_every=sync_every,
+            landing_callback=self._on_landing,
             use_decode_kernel=use_decode_kernel,
             block_size=block_size, kv_dtype=kv_dtype,
             num_blocks=num_blocks, prefix_cache=prefix_cache,
@@ -1101,7 +1101,7 @@ def _ongoing_for(num_slots: int) -> int:
 
 def build_continuous_llama_app(config: Optional[llama.LlamaConfig] = None,
                                num_replicas: int = 1, num_slots: int = 8,
-                               max_len: int = 512, sync_every: int = 1,
+                               max_len: int = 512,
                                use_decode_kernel: Optional[bool] = None,
                                block_size: int = 64,
                                kv_dtype: Optional[str] = None,
@@ -1119,7 +1119,6 @@ def build_continuous_llama_app(config: Optional[llama.LlamaConfig] = None,
     # Keyword bind so per-deploy ``init_kwargs`` overrides (serve config
     # files) can retarget any engine knob without positional conflicts.
     return dep.bind(config=config, num_slots=num_slots, max_len=max_len,
-                    sync_every=sync_every,
                     use_decode_kernel=use_decode_kernel,
                     block_size=block_size, kv_dtype=kv_dtype,
                     num_blocks=num_blocks, prefix_cache=prefix_cache,

@@ -217,8 +217,8 @@ def _ctx_to_blocks(a, bs: int):
 def _next_tokens(logits, step, sampling: SamplingParams, salt: int = 0):
     """In-device token selection from tick/prefill logits [B, 1, V]:
     greedy argmax, or temperature/top-p sampling keyed off the
-    device-threaded ``step`` counter (deterministic under a fixed seed,
-    including speculative-rewind replays of the same step). ``salt``
+    device-threaded ``step`` counter (deterministic under a fixed
+    seed). ``salt``
     separates the prefill and decode key streams — their counters both
     start at 0, and an unsalted collision would correlate prefill
     first-token draws with the first decode tick's."""
@@ -314,8 +314,83 @@ def _attn_out(o, layer, c, gate=None):
     return jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
 
 
-_STATE_KIND_NAMES = {"mamba": "state-space",
-                     "linear_attention": "linear-attention"}
+_KIND_NAMES = {"mamba": "state-space",
+               "linear_attention": "linear-attention",
+               "sliding_attention": "sliding-window",
+               "latent_attention": "latent-attention"}
+# A recurrent layer (Mamba-2 or Gated DeltaNet) keeps, beside its K/V, a
+# state a slot that only moves FORWARD and belongs to one request.
+_RECURRENT_CANNOT = {
+    "second_kind": "the engine keeps one cache beside the arena, and the "
+                   "state cache holds one mixer's shapes: no second kind of "
+                   "recurrent layer, no ring",
+    "speculative": "a rejected draft cannot rewind a recurrent state",
+    "prefix_cache": "a cached prefix restores K/V blocks, not the state "
+                    "after them",
+    "handoff": "the KV handoff carries no recurrent state",
+}
+# What the engine offers that a layer kind's cache cannot have, and why:
+# the one place a kind's limits are written. A capability is refused, by
+# :func:`_refuse_unsupported`, for the first kind of the stack that names
+# it; "second_kind" is asked by the stack itself, the rest by the caller.
+_KIND_CANNOT = {
+    "mamba": _RECURRENT_CANNOT,
+    "linear_attention": _RECURRENT_CANNOT,
+    # The last ``sliding_window`` keys of a slot live in a ring beside
+    # the arena, which overwrites the rest.
+    "sliding_attention": {
+        "second_kind": "the engine keeps one cache beside the arena, and "
+                       "here that is the ring",
+        "kv_dtype": "the ring has no scale sidecar",
+        "speculative": "a rejected draft's writes may have overwritten ring "
+                       "entries the rewound position still sees",
+        "prefix_cache": "a hit restores the arena's blocks, not the window "
+                        "layers' keys, which a ring has overwritten",
+        "handoff": "the KV handoff carries the arena's blocks, not a ring's",
+    },
+    # One row a token in ``paged_kv.LatentKVCache``, weights in
+    # ``params["runs"]``: nothing written against per-head K/V planes, or
+    # that cuts the layer stack out of ``params["layers"]``.
+    "latent_attention": {
+        "second_kind": "the latent cache takes the arena's place, so every "
+                       "layer must keep its keys there",
+        "kv_dtype": "8-bit latents are a different model output, not a "
+                    "storage option: the cache has no scale sidecar",
+        "speculative": "the self-draft cuts params['layers'], which holds "
+                       "this family's experts alone, and the verify programs "
+                       "take K and V planes",
+        "handoff": "the KV handoff gathers and scatters K and V planes, "
+                   "and a latent cache has one plane",
+        "score_logprobs": "it runs llama.forward, the training forward, "
+                          "which this serving-only family has none of",
+    },
+}
+
+
+def _refuse_unsupported(config, asked: Dict[str, str]) -> None:
+    """Raise for the first capability in ``asked`` ({capability: what
+    the caller called it}) that a layer kind of ``config`` cannot have
+    (:data:`_KIND_CANNOT`)."""
+    kinds = set(config.layer_types)
+    for kind, cannot in _KIND_CANNOT.items():
+        if kind not in kinds:
+            continue
+        # The arena's own layers sit beside any cache but the latent
+        # one, which takes the arena's place.
+        beside = kinds - {kind}
+        if kind != "latent_attention":
+            beside -= {"attention", "full_attention"}
+        wants = dict(asked)
+        if beside:
+            wants["second_kind"] = "another layer kind in the same stack"
+        for capability, why in cannot.items():
+            if capability in wants:
+                raise ValueError(
+                    f"{wants[capability]} is not supported for a model "
+                    f"with {_KIND_NAMES[kind]} layers (layer_types has "
+                    f"{kind!r}): {why}")
+
+
 _KIND_SCOPES = {"sliding_attention": "attn/window",
                 "full_attention": "attn/full"}
 
@@ -429,11 +504,10 @@ def _forward_paged(params, tokens, positions, tables, limits,
     x = _embed(params, tokens, c)                             # [B, S, E]
     scale = c.attn_scale
     # Resolve each position's target block through the slot's table once
-    # (shared by every layer's write). Speculative ticks can OVERRUN a
-    # slot's reservation (the host detects finishes up to 2K ticks
-    # late): past ``limits`` the table tail would alias the write onto
-    # the slot's LAST LIVE block — and a later rewind would replay over
-    # the corrupted K/V. Redirect overrun writes, and a freed slot's, to
+    # (shared by every layer's write). The tick in flight can OVERRUN a
+    # slot's reservation (the host learns of an end one tick late): past
+    # ``limits`` the table tail would alias the write onto the slot's
+    # LAST LIVE block. Redirect overrun writes, and a freed slot's, to
     # the garbage block instead.
     gathered = jnp.take_along_axis(tables, positions // bs, axis=1)
     block_idx = jnp.where(positions < limits[:, None], gathered,
@@ -557,12 +631,10 @@ def _spec_tick_paged(params, tokens, positions, tables, limits,
     Returns ``(committed [B, k+1], counts [B], next_tokens [B],
     next_positions [B], cache, draft_cache, step + 1)`` — the device
     threads its own next-token/next-position state exactly like the
-    plain tick, so buffered mode runs spec ticks back-to-back without a
-    host sync. Rejected draft writes land past each slot's committed
+    plain tick. Rejected draft writes land past each slot's committed
     length inside its (k-lookahead-extended) reservation and are dead on
     arrival: every future decode overwrites a position before attending
-    it, and a buffered rewind simply re-uploads host counts — the
-    garbage-block redirect + replay machinery, unchanged."""
+    it."""
     external = draft_params is not None
     d_tokens: List[Any] = []
     d_probs: List[Any] = []
@@ -958,7 +1030,6 @@ class ContinuousBatcher:
                  num_slots: int = 8, max_len: int = 512, seed: int = 0,
                  eos_token: Optional[int] = None, token_callback=None,
                  landing_callback=None,
-                 sync_every: int = 1,
                  use_decode_kernel: Optional[bool] = None,
                  block_size: int = 64,
                  kv_dtype: Optional[str] = None,
@@ -988,31 +1059,11 @@ class ContinuousBatcher:
         process driving four chips holds four engines, one per chip.
         ``None`` leaves placement to JAX's default device.
 
-        ``sync_every=1`` (the default) books one tick's tokens a step
-        with ONE tick queued behind the one that runs: ``step`` dispatches
-        tick n+1 before it fetches tick n, so the device never waits for
-        the host between ticks, every stream still gets one token a tick,
-        and no tick is thrown away (see :meth:`step`).
-
-        ``sync_every=K > 1`` enables SPECULATIVE BUFFERED decode: host
-        syncs per K ticks. The engine runs K ticks per host
-        synchronization, fetching token batches double-buffered so the
-        transfer overlaps the next K ticks' compute: tokens reach a
-        stream K at a time, an end discards up to 2K ticks, and an
-        admission forces a boundary. Since the per-tick step keeps the
-        device as busy without any of that, nothing is left that this
-        mode does better (ROADMAP D3). Decode is
-        deterministic (greedy, and sampled decode is
-        keyed off a device-threaded step counter), so ticks run ahead of
-        host bookkeeping speculatively; when a request finishes, the
-        engine rewinds to host-known state and redoes ≤2K ticks (freed
-        slots need re-admission). Greedy outputs are bit-identical to
-        ``sync_every=1``; only finish *detection* lags. Sampled outputs
-        are bit-identical for a fixed submission schedule relative to
-        buffer boundaries (e.g. everything submitted up front): a
-        MID-RUN submission can admit at a different global tick than it
-        would under ``sync_every=1``, and sampling keys are derived from
-        that global step counter.
+        A step books one tick's tokens with ONE tick queued behind the
+        one that runs: ``step`` dispatches tick n+1 before it fetches
+        tick n, so the device never waits for the host between ticks,
+        every stream gets one token a tick, and no tick is thrown away
+        (see :meth:`step`).
 
         ``use_decode_kernel`` routes decode attention and the K/V write
         through the paged Pallas kernels; ``None`` is auto (TPU with
@@ -1059,8 +1110,7 @@ class ContinuousBatcher:
         ladders k from the windowed accept rate — down to 0, which
         dispatches the EXACT pre-spec tick program. Greedy outputs are
         bit-identical spec-on/off; sampled acceptance is rejection
-        sampling that preserves the target distribution and replays
-        deterministically across buffered rewinds.
+        sampling that preserves the target distribution.
 
         DISAGGREGATED ROLES (``role`` / ``RAY_TPU_SERVE_ROLE``):
         ``"prefill"`` runs admission + prefill and parks
@@ -1092,14 +1142,13 @@ class ContinuousBatcher:
         their K/V live in a per-slot ring of ``window / block_size + 2``
         blocks beside the arena (``paged_kv.RingKVCache``), which holds
         the full-attention layers alone. Whatever needs K/V a ring has
-        overwritten is refused by name: the prefix cache, speculation,
-        buffered decode, the KV handoff."""
+        overwritten is refused by name (:data:`_KIND_CANNOT`): the
+        prefix cache, speculation, the KV handoff."""
         self.config = config
         self.device = device
         self.num_slots = num_slots
         self.max_len = max_len
         self.eos_token = eos_token
-        self.sync_every = max(1, int(sync_every))
         self.sampling = SamplingParams.coerce(sampling)
         self.role = _resolve_role(role)
         self.block_size = int(block_size)
@@ -1111,14 +1160,20 @@ class ContinuousBatcher:
                 f"block_size must be a power of two >= 8, "
                 f"got {self.block_size}")
         self.kv_dtype = resolve_kv_dtype(kv_dtype)
-        if config.state_layers:
-            self._refuse_for_state_layers(prefix_cache, spec_k, drafter)
-            prefix_cache = False    # nothing to share: off unless asked
-        if config.window_layers:
-            self._refuse_for_window_layers(prefix_cache, spec_k, drafter)
-            prefix_cache = False
-        if config.latent_layers:
-            self._refuse_for_latent_layers(spec_k, drafter)
+        asked = {}
+        if self.kv_dtype != "bf16":
+            asked["kv_dtype"] = f"kv_dtype={self.kv_dtype!r}"
+        if _resolve_spec_k(spec_k) or drafter is not None:
+            asked["speculative"] = "speculative decoding (spec_k > 0)"
+        if prefix_cache or (prefix_cache is None
+                            and env_flag("RAY_TPU_PREFIX_CACHE")):
+            asked["prefix_cache"] = "the prefix cache (prefix_cache=True)"
+        if self.role != "both":
+            asked["handoff"] = f"role={self.role!r}"
+        _refuse_unsupported(config, asked)
+        if any("prefix_cache" in _KIND_CANNOT.get(kind, ())
+               for kind in config.layer_types):
+            prefix_cache = False    # nothing to share: off, not on
         chunk = _bucket_floor(int(prefill_chunk))
         if config.window_layers:
             # A chunk's blocks must be distinct entries of a ring.
@@ -1167,7 +1222,6 @@ class ContinuousBatcher:
         self._spec_cur_k = self.spec_k
         self._spec_ticks: Dict[int, Any] = {}   # ladder k -> compiled tick
         self._last_tick_k = 0                   # k the last tick ran with
-        self._window_k = 0                      # k of the buffered window
         # Windowed accept-rate telemetry: (drafted, accepted) per applied
         # fetch — the adaptive-k controller and the accept-rate gauge both
         # read it.
@@ -1191,8 +1245,6 @@ class ContinuousBatcher:
         self.prefix_miss_tokens = 0     # prompt tokens actually prefilled
         self.prefix_hit_requests = 0    # requests with >=1 matched block
         self._prefill_shapes: set = set()   # (N_pad, L_pad) compiled
-        self._buf: List[Any] = []       # unstacked device token vectors
-        self._pending: Optional[tuple] = None  # (stacked, [(slot, rid)])
         if params is None:
             # ONE program, not one per tensor: on the chip each eager op
             # is its own compile, and a replica must finish constructing
@@ -1274,16 +1326,14 @@ class ContinuousBatcher:
         # the last row that reached the host.
         self._inflight: deque = deque()
         self._row_landed = 0.0
-        # This thread's timeline seen from the device (``sync_every`` 1
-        # alone: a buffered fetch does not say when the device ran dry).
-        # ``_empty_since``: the ``perf_counter`` moment a landing left
-        # nothing queued on the device, None while something is;
+        # This thread's timeline seen from the device. ``_empty_since``:
+        # the ``perf_counter`` moment a landing left nothing queued on
+        # the device, None while something is;
         # ``_empty_after``: what landed then ("tick" or "prefill");
         # ``_no_work``: no slot was live and nothing waited, so the time
         # up to the next arrival is idle, not starved. The next dispatch
         # books the interval by cause (:meth:`_book_empty`).
-        self._empty_since: Optional[float] = (
-            time.perf_counter() if self.sync_every == 1 else None)
+        self._empty_since: Optional[float] = time.perf_counter()
         self._empty_after = "tick"
         self._no_work = True
         # Running totals of the prefill batches' booked seconds and their
@@ -1309,10 +1359,6 @@ class ContinuousBatcher:
         # the stamp ``_hand_over`` gives the landing callback.
         self.landed_ts = 0.0
         self._prefill_count = 0   # per-dispatch prefill sampling stream
-        # Buffered-mode achieved-bandwidth window: wall time and tick
-        # count between consecutive fetch syncs.
-        self._bw_window_t0 = None
-        self._bw_window_ticks = 0
         self._dirty = True
         self._waiting: deque = deque()
         self._rid = itertools.count()
@@ -1334,7 +1380,6 @@ class ContinuousBatcher:
         # the decode loop pays one integer check per fetch.
         self._req_meta: Dict[int, Dict[str, Any]] = {}
         self._traced_live = 0            # live requests carrying a trace
-        self._window_t0: Optional[float] = None  # decode-window start
         self.request_breakdowns: deque = deque(maxlen=4096)
         self._MAX_WINDOWS = 64           # per-request span cap (tail merges)
         # Observability: engine label for the slot-occupancy / decode-rate
@@ -1506,112 +1551,6 @@ class ContinuousBatcher:
             self._draft_prefill = draft_prefill
         else:
             self._draft_prefill = None
-
-    def _refuse_for_state_layers(self, prefix_cache, spec_k, drafter):
-        """A model with recurrent layers (Mamba-2 or Gated DeltaNet)
-        keeps, beside its K/V, a recurrent state a slot that only moves
-        FORWARD and belongs to one request: whatever rests on rewinding,
-        sharing or shipping K/V alone is refused by name."""
-        kinds = [k for k in llama.STATE_KINDS if k in self.config.layer_types]
-
-        def refuse(what, why):
-            raise ValueError(
-                f"{what} is not supported for a model with "
-                f"{_STATE_KIND_NAMES[kinds[0]]} layers (layer_types has "
-                f"{kinds[0]!r}): {why}")
-
-        if len(kinds) > 1:
-            refuse("a second kind of recurrent layer in the same stack",
-                   "the state cache holds one mixer's shapes")
-
-        if _resolve_spec_k(spec_k) or drafter is not None:
-            refuse("speculative decoding (spec_k > 0)",
-                   "a rejected draft cannot rewind a recurrent state")
-        if self.sync_every > 1:
-            refuse("buffered decode (sync_every > 1)",
-                   "its rewind after a finish replays ticks, which would "
-                   "advance a recurrent state twice")
-        if prefix_cache or (prefix_cache is None
-                            and env_flag("RAY_TPU_PREFIX_CACHE")):
-            refuse("the prefix cache (prefix_cache=True)",
-                   "a cached prefix restores K/V blocks, not the state "
-                   "after them")
-        if self.role != "both":
-            refuse(f"role={self.role!r}",
-                   "the KV handoff carries no recurrent state")
-
-    def _refuse_for_window_layers(self, prefix_cache, spec_k, drafter):
-        """A sliding-window layer keeps the last ``sliding_window`` keys
-        of a slot in a ring and overwrites the rest: whatever needs K/V
-        from further back, or K/V that outlive their request, is refused
-        by name."""
-        def refuse(what, why):
-            raise ValueError(
-                f"{what} is not supported for a model with sliding-window "
-                f"layers (layer_types has 'sliding_attention'): {why}")
-
-        if self.config.state_layers:
-            refuse("a state-space layer in the same stack",
-                   "the engine keeps one second cache beside the arena")
-        if self.kv_dtype != "bf16":
-            refuse(f"kv_dtype={self.kv_dtype!r}",
-                   "the ring has no scale sidecar")
-        if _resolve_spec_k(spec_k) or drafter is not None:
-            refuse("speculative decoding (spec_k > 0)",
-                   "a rejected draft's writes may have overwritten ring "
-                   "entries the rewound position still sees")
-        if self.sync_every > 1:
-            refuse("buffered decode (sync_every > 1)",
-                   "its rewind after a finish replays ticks over ring "
-                   "entries they have already overwritten")
-        if prefix_cache or (prefix_cache is None
-                            and env_flag("RAY_TPU_PREFIX_CACHE")):
-            refuse("the prefix cache (prefix_cache=True)",
-                   "a hit restores the arena's blocks, not the window "
-                   "layers' keys, which a ring has overwritten")
-        if self.role != "both":
-            refuse(f"role={self.role!r}",
-                   "the KV handoff carries the arena's blocks only")
-
-    def _refuse_for_latent_layers(self, spec_k=None, drafter=None,
-                                  what: Optional[str] = None):
-        """A model of latent-attention layers keeps one row a token in
-        ``paged_kv.LatentKVCache`` and its weights in ``params["runs"]``:
-        whatever is written against per-head K/V planes, or cuts the
-        layer stack out of ``params["layers"]``, is refused by name,
-        here and nowhere else. ``what``: a method the family refuses
-        when called (the constructor checks its own arguments)."""
-        def refuse(what, why):
-            raise ValueError(
-                f"{what} is not supported for a model with latent-attention "
-                f"layers (layer_types has 'latent_attention'): {why}")
-
-        handoff = ("the KV handoff gathers and scatters K and V planes, "
-                   "and a latent cache has one plane")
-        if what is not None:
-            refuse(what, "it runs llama.forward, the training forward, "
-                   "which this serving-only family has none of"
-                   if what == "score_logprobs" else handoff)
-        c = self.config
-        if c.latent_layers != c.num_layers:
-            refuse("another layer kind in the same stack",
-                   "the latent cache takes the arena's place, so every "
-                   "layer must keep its keys there")
-        if self.kv_dtype != "bf16":
-            refuse(f"kv_dtype={self.kv_dtype!r}",
-                   "8-bit latents are a different model output, not a "
-                   "storage option: the cache has no scale sidecar")
-        if _resolve_spec_k(spec_k) or drafter is not None:
-            refuse("speculative decoding (spec_k > 0)",
-                   "the self-draft cuts params['layers'], which holds "
-                   "this family's experts alone, and the verify programs "
-                   "take K and V planes")
-        if self.sync_every > 1:
-            refuse("buffered decode (sync_every > 1)",
-                   "its rewind is not run over a latent cache (the "
-                   "per-tick step keeps the device as busy: ROADMAP D3)")
-        if self.role != "both":
-            refuse(f"role={self.role!r}", handoff)
 
     def _place(self, tree):
         """Commit a pytree (host or device values) to this engine's chip;
@@ -1967,8 +1906,8 @@ class ContinuousBatcher:
         run, so the RL experience path's importance ratios are priced
         against the true generating policy. Returns ``[len(out_tokens)]``
         float32."""
-        if self.config.latent_layers:
-            self._refuse_for_latent_layers(what="score_logprobs")
+        _refuse_unsupported(self.config,
+                            {"score_logprobs": "score_logprobs"})
         if not out_tokens:
             return np.zeros((0,), np.float32)
         if self._score_fn is None:
@@ -2133,14 +2072,11 @@ class ContinuousBatcher:
                                  tokens=tokens_by_rid.get(rid, 0))
         self._req_meta.clear()
         self._traced_live = 0
-        self._window_t0 = None
         self._slots.clear()
         self._waiting.clear()
         self._free = list(range(self.num_slots))
         self._finished.clear()
         self._routes.clear()
-        self._buf = []
-        self._pending = None
         # A tick in flight is dropped unfetched, and with it the device's
         # copy of the decode state (it may be the output of the program
         # that failed).
@@ -2166,15 +2102,12 @@ class ContinuousBatcher:
             # entry would alias garbage, so the index restarts cold.
             self._prefix.clear()
         self._applied_steps = 0
-        self._bw_window_t0 = None
-        self._bw_window_ticks = 0
         # Spec state restarts with the engine: the controller re-enters at
         # the configured k and the external drafter's dense cache (donated
         # by the spec tick like the main arena) is rebuilt alongside it.
         self._spec_cur_k = self.spec_k
         self._spec_window.clear()
         self._spec_probe_countdown = self._spec_probe_after
-        self._window_k = 0
         if self._draft_cache is not None:
             self._draft_cache = self._new_draft_cache()
         self._dirty = True
@@ -2195,9 +2128,9 @@ class ContinuousBatcher:
         """True while a ``step`` has something to do: a live or waiting
         request, finishes not yet returned, or a tick dispatched whose
         tokens are not booked yet (one is always queued while requests
-        decode; the buffered path holds up to 2K)."""
+        decode)."""
         return bool(self._slots or self._waiting or self._finished
-                    or self._inflight or self._buf or self._pending)
+                    or self._inflight)
 
     # --------------------------------------------- disaggregated handoff
     def _park_for_handoff(self, slot: int, req: Dict[str, Any]) -> None:
@@ -2247,20 +2180,6 @@ class ContinuousBatcher:
         self._release_handoff_blocks(entry)
         return True
 
-    def _refuse_handoff(self, what: str) -> None:
-        if self.config.latent_layers:
-            self._refuse_for_latent_layers(what=what)
-        if self.config.state_layers:
-            raise ValueError(
-                f"{what} is not supported for a model with recurrent "
-                f"(state-space or linear-attention) layers: the KV handoff "
-                f"carries no recurrent state")
-        if self.config.window_layers:
-            raise ValueError(
-                f"{what} is not supported for a model with sliding-window "
-                f"layers: the KV handoff carries the arena's blocks, not "
-                f"a ring's")
-
     def export_kv_payload(self, rid: int) -> Dict[str, Any]:
         """Materialize a parked request's KV handoff: gather its
         prompt-covering arena blocks (K/V plus int8 scale sidecars) to
@@ -2274,7 +2193,7 @@ class ContinuousBatcher:
         Call through ``ray_tpu.serve.kv_transfer`` — the journal-gated
         helper every cross-replica transfer must ride (a source lint
         pins this)."""
-        self._refuse_handoff("export_kv_payload")
+        _refuse_unsupported(self.config, {"handoff": "export_kv_payload"})
         if self.role == "decode":
             raise ValueError("decode-role engines do not export KV")
         entry = self._handoff_ready.pop(rid, None)
@@ -2313,6 +2232,7 @@ class ContinuousBatcher:
         router reserves the decode slot BEFORE dispatching prefill, so
         the payload never races arena pressure on arrival). Returns a
         reservation id, or None when the arena cannot cover it."""
+        _refuse_unsupported(self.config, {"handoff": "reserve_import"})
         if self.role == "prefill":
             raise ValueError("prefill-role engines do not import KV")
         self.sweep_reservations()
@@ -2372,7 +2292,7 @@ class ContinuousBatcher:
         this engine's id stream). Call through
         ``ray_tpu.serve.kv_transfer`` — the journal-gated helper every
         cross-replica transfer must ride (a source lint pins this)."""
-        self._refuse_handoff("import_kv_payload")
+        _refuse_unsupported(self.config, {"handoff": "import_kv_payload"})
         if self.role == "prefill":
             raise ValueError("prefill-role engines do not import KV")
         if payload.get("version") != HANDOFF_MANIFEST_VERSION:
@@ -2522,9 +2442,9 @@ class ContinuousBatcher:
         return sum(ring), sum(table)
 
     def _account_tick(self, tick_fn, wall_s: float, spec_k: int) -> None:
-        """Feed one tick (or a buffered window's mean tick) to the XLA
-        monitor, with the live-byte hint, because the compiled cost
-        prices every table entry as live, and book the share of entries
+        """Feed one tick to the XLA monitor, with the live-byte hint,
+        because the compiled cost prices every table entry as live, and
+        book the share of entries
         that were, and how full the kernel's grid steps ran (a step
         covers up to ``visit_blocks`` blocks of one slot; every
         attention layer, rings and table by their layer counts): all
@@ -2636,16 +2556,6 @@ class ContinuousBatcher:
         return (self._blocks_needed(prompt_len, max_new)
                 - -(-(prompt_len + max_new) // self.block_size))
 
-    def _can_admit_head(self) -> bool:
-        """True when the FIFO head WOULD admit right now: it could (a
-        free slot and the arena's blocks, :meth:`_head_fits`) and
-        :meth:`_admit` would not hold it back for a larger batch. The
-        buffered engine uses this to decide whether forcing a sync
-        boundary is worth it — an arena-blocked or held head must not
-        collapse speculative pipelining to one tick per sync while it
-        waits."""
-        return self._head_fits() and not self._holds_admission()
-
     def _head_fits(self) -> bool:
         """True when the FIFO head could admit RIGHT NOW (free slot and
         enough free arena blocks — counting LRU-cached blocks the
@@ -2664,10 +2574,8 @@ class ContinuousBatcher:
             # A parked matched block must not count twice: the match
             # will revive it from the LRU (covering part of ``need``)
             # WITHOUT freeing anything, so it is no longer evictable
-            # for the novel blocks — an optimistic probe here makes the
-            # buffered engine force sync boundaries for an admission
-            # that then fails, exactly the pipelining collapse this
-            # probe exists to avoid.
+            # for the novel blocks — an optimistic probe here has
+            # ``_gather`` hold slots empty for an admission that fails.
             parked = sum(1 for nd in nodes[:m] if nd.refs == 0)
             avail += self._prefix.cached_count - parked
         return need <= avail
@@ -2681,9 +2589,9 @@ class ContinuousBatcher:
 
     def _req_chunks(self, req: Dict[str, Any]) -> List[tuple]:
         """Block-aligned chunk keys for a queued request, memoized on
-        the request: the buffered engine's per-tick admission probe and
-        the eventual admission itself would otherwise re-tuple the
-        whole prompt each time a request waits on the arena."""
+        the request: the admission probe (:meth:`_head_fits`) and the
+        eventual admission itself would otherwise re-tuple the whole
+        prompt each time a request waits on the arena."""
         chunks = req.get("chunks")
         if chunks is None:
             chunks = req["chunks"] = prompt_chunks(req["prompt"],
@@ -3225,9 +3133,9 @@ class ContinuousBatcher:
                 if len(st["out"]) + ahead.get((slot, st["rid"]), 0)
                 < st["max_new"]]
 
-    def _upload_state(self, members: Optional[List[tuple]] = None) -> None:
+    def _upload_state(self, members: List[tuple]) -> None:
         """Bring the device's decode state up to date for a tick over
-        ``members`` (default: every live slot): whatever the host knows
+        ``members``: whatever the host knows
         a tick ahead is rebuilt from its books. Blocks are reserved at
         admission, so tables and limits change only with membership; a
         slot's position is ``pos`` plus the ticks in flight that advance
@@ -3240,8 +3148,6 @@ class ContinuousBatcher:
 
         with tracing.phase("engine.upload", mdefs.CB_STEP_UPLOAD_MS,
                            self._mtags):
-            if members is None:
-                members = [(s, st["rid"]) for s, st in self._slots.items()]
             ahead = self._ahead()
             tokens = np.zeros(self.num_slots, np.int32)
             fresh = np.zeros(self.num_slots, bool)
@@ -3263,9 +3169,7 @@ class ContinuousBatcher:
                 tokens if self._d_tokens is None else self._d_tokens)
             self._d_positions = self._place(positions)
             # The device sampling-step counter is the host-applied count
-            # plus the ticks in flight. After a buffered rewind that is
-            # the applied count alone: the ticks it discarded replay the
-            # SAME step numbers, so sampled decode reproduces like greedy.
+            # plus the ticks in flight.
             self._d_step = self._place(
                 np.int32(self._applied_steps + len(self._inflight)))
             self._d_tables = self._place(tables)
@@ -3349,9 +3253,8 @@ class ContinuousBatcher:
             for rid, tok in tokens:
                 self.token_callback(rid, tok)
 
-    def _apply_tokens(self, nxt_rows, membership, window=None) -> bool:
-        """Book one or more fetched tick rows; returns True when any
-        request finished (membership changed). ``window`` is the
+    def _apply_tokens(self, nxt_rows, membership, window=None) -> None:
+        """Book one or more fetched tick rows. ``window`` is the
         (wall_start, wall_end) of the sync window these rows cover —
         recorded per traced request for the decode-window spans (windows
         must attach BEFORE ``_maybe_finish`` pops the record, so this
@@ -3366,18 +3269,16 @@ class ContinuousBatcher:
         landed: List[tuple] = []
         with tracing.phase("engine.apply", mdefs.CB_STEP_APPLY_MS,
                            self._mtags):
-            finished_any = False
             applied = 0
             drafted = 0
             accepted = 0
             track = window is not None and self._traced_live > 0
             if track:
-                w1 = window[1]
-                w0 = window[0] if window[0] is not None else w1
+                w0, w1 = window
                 entries: Dict[int, list] = {}
             # One device tick == one sampling step regardless of how many
             # tokens it committed (spec windows burn exactly one step number),
-            # so the rewind counter advances per ROW, not per token.
+            # so the step counter advances per ROW, not per token.
             self._applied_steps += len(nxt_rows)
             for row in nxt_rows:
                 if isinstance(row, tuple):
@@ -3411,9 +3312,7 @@ class ContinuousBatcher:
                         if slot not in self._slots:
                             # EOS / max_new mid-window: the rest of the
                             # committed window is past the request's end —
-                            # drop it (device-side overrun rewinds with the
-                            # dirty re-upload the finish already forces).
-                            finished_any = True
+                            # drop it (the finish forces a re-upload).
                             break
             self._hand_over(landed)
             self.decoded_tokens += applied
@@ -3426,7 +3325,6 @@ class ContinuousBatcher:
                 mdefs.CB_SPEC_DRAFT_TOKENS.inc(drafted, tags=self._mtags)
                 mdefs.CB_SPEC_ACCEPTED_TOKENS.inc(accepted,
                                                   tags=self._mtags)
-            return finished_any
 
     # Accept-rate controller thresholds: shrink k below LOW (drafts are
     # wasting verify bandwidth), grow above HIGH (more look-ahead pays),
@@ -3447,9 +3345,7 @@ class ContinuousBatcher:
 
     def _adapt_spec_k(self) -> None:
         """Move the live draft depth along the rung ladder from the
-        windowed accept rate. Called ONLY at clean boundaries (sync path
-        per step; buffered path when no ticks are in flight), so a rung
-        change never mixes row widths inside one stacked fetch. At rung 0
+        windowed accept rate, once a step. At rung 0
         the engine runs the exact pre-spec tick program; a probe
         re-enters the bottom rung after ``RAY_TPU_SPEC_PROBE_TICKS``
         base ticks so a workload whose accept rate recovers isn't parked
@@ -3607,8 +3503,7 @@ class ContinuousBatcher:
         """A landing at ``now`` (``after``: "tick" or "prefill") left
         nothing queued on the device: until the next dispatch it waits
         for this thread."""
-        if self.sync_every == 1:
-            self._empty_since, self._empty_after = now, after
+        self._empty_since, self._empty_after = now, after
 
     def _land(self, tick: Dict[str, Any],
               prefill_behind: bool = False) -> None:
@@ -3667,8 +3562,7 @@ class ContinuousBatcher:
 
     def step(self) -> Dict[int, List[int]]:
         """Admit waiting requests, book ONE decode tick's tokens, and
-        return the requests that finished (with ``sync_every > 1``,
-        finish detection lags up to 2K ticks).
+        return the requests that finished.
 
         The step keeps one tick queued behind the one that runs: it
         dispatches tick n+1 BEFORE it fetches tick n, so the device goes
@@ -3709,14 +3603,10 @@ class ContinuousBatcher:
             # request still alive. Drains under load and streaming
             # timeouts must ride it out.
             chaos.inject("serve_tick", engine=self._mtags["engine"])
-        sync = self.sync_every == 1
         with tracing.phase("engine.account", mdefs.CB_STEP_ACCOUNT_MS,
                            self._mtags):
             self._emit_gauges()
-            if sync:
-                self._adapt_spec_k()
-        if not sync:
-            return self._step_buffered()
+            self._adapt_spec_k()
         self._admit()
         depth = 1 if self.spec_k and self._spec_cur_k else 2
         while len(self._inflight) >= depth:
@@ -3734,143 +3624,6 @@ class ContinuousBatcher:
             self._no_work = True
         out, self._finished = self._finished, {}
         return out
-
-    def _step_buffered(self) -> Dict[int, List[int]]:
-        # Admission only at a clean boundary (no speculative ticks in
-        # flight): an upload mid-buffer would rewind the device sequence.
-        if not self._buf and self._pending is None:
-            # Spec-k changes only ever land here (clean boundary): a
-            # mid-buffer rung switch would mix row widths in one stacked
-            # fetch and desync the replayed device sequence on rewind.
-            self._adapt_spec_k()
-            self._admit()
-            # Clean boundary: restart the bandwidth window so idle gaps
-            # and admission prefill time never pollute the first
-            # buffered window's per-tick denominator (the achieved-BW
-            # gauges would otherwise report near-zero bandwidth after
-            # an idle period).
-            self._bw_window_t0 = None
-            self._bw_window_ticks = 0
-        if self._slots:
-            if self._dirty and not self._buf and self._pending is None:
-                self._upload_state()
-            from ray_tpu._private import metrics_defs as mdefs
-
-            if not self._buf and self._traced_live:
-                # A fresh speculative buffer starts: its ticks form ONE
-                # sync window for the decode-window spans (the host only
-                # observes tokens at the next fetch, so finer-grained
-                # timing would be fiction).
-                self._window_t0 = time.time()
-            if self._bw_window_t0 is None:
-                self._bw_window_t0 = time.perf_counter()
-            t0 = time.perf_counter()
-            nxt_dev = self._run_tick()
-            # Buffered mode overlaps fetches with compute, so this is
-            # dispatch time only; steady-state backpressure still makes
-            # the histogram track the real tick cadence.
-            dispatch_ms = (time.perf_counter() - t0) * 1e3
-            mdefs.CB_TICK_MS.observe(dispatch_ms, tags=self._mtags)
-            self._book_held(dispatch_ms, self._held(), len(self._free))
-            self._bw_window_ticks += 1
-            if not self._buf:
-                # k is frozen for the whole buffered window (adaptation
-                # happens at clean boundaries only) — remember which
-                # program produced these rows for the flush accounting.
-                self._window_k = self._last_tick_k
-            self._buf.append(nxt_dev)
-        want_admit = self._can_admit_head()
-        if len(self._buf) >= self.sync_every or want_admit or (
-                not self._slots and (self._buf or self._pending is not None)):
-            # Non-K arms drain in-flight state early: a waiting request
-            # with a free slot must not starve behind steady pipelining
-            # (time-to-first-token), and a cancel of the last request
-            # must not wedge admission.
-            self._flush_buffered(force_boundary=want_admit)
-        out, self._finished = self._finished, {}
-        return out
-
-    @staticmethod
-    def _stack_buffer(buf):
-        """Stack buffered tick rows into one fetchable device value.
-        Plain rows ([B] vectors) stack to [T, B]; spec rows stack
-        componentwise to ([T, B, k+1], [T, B]) — k is constant across a
-        window, so the stack is uniform."""
-        if isinstance(buf[0], tuple):
-            return (jnp.stack([r[0] for r in buf]),
-                    jnp.stack([r[1] for r in buf]))
-        return jnp.stack(buf)
-
-    @staticmethod
-    def _rows_from_stacked(stacked):
-        """Fetch a stacked buffer to host and split it back into per-tick
-        rows for ``_apply_tokens`` (spec rows become (toks, counts)
-        pairs)."""
-        if isinstance(stacked, tuple):
-            toks = np.asarray(stacked[0])
-            counts = np.asarray(stacked[1])
-            return [(toks[i], counts[i]) for i in range(toks.shape[0])]
-        rows = np.asarray(stacked)
-        return list(rows)
-
-    def _flush_buffered(self, force_boundary: bool = False) -> None:
-        # 1. Apply the PRIOR pending fetch first — its transfer has been
-        # overlapping the ticks just buffered. If it finished requests,
-        # the current buffer is stale speculation over freed slots:
-        # discard it and rewind (re-upload host state next step).
-        if self._pending is not None:
-            stacked, membership, win0, wk = self._pending
-            self._pending = None
-            rows = self._rows_from_stacked(stacked)  # overlapped fetch
-            # The fetch landing IS a device sync: backpressure makes the
-            # wall time since the last sync cover the ticks dispatched in
-            # between, so window/ticks is the steady-state per-tick cost.
-            # Feed it (with the live-byte hint) to the achieved-bandwidth
-            # gauges — without this the gauges would price the tick at the
-            # compiled worst case instead of live tokens. Spec
-            # windows report against their per-k program with the hint
-            # priced for the drafts + wider verify those ticks ran.
-            now = time.perf_counter()
-            if self._bw_window_t0 is not None and self._bw_window_ticks:
-                tick_fn = self._spec_ticks[wk] if wk else self._tick
-                tick_s = (now - self._bw_window_t0) / self._bw_window_ticks
-                self._account_tick(tick_fn, tick_s, wk)
-                self._note_tick_ms(tick_s * 1e3)
-            self._bw_window_t0 = now
-            self._bw_window_ticks = 0
-            self._note_expert_rows(rows)
-            self.landed_ts = time.time()
-            if self._apply_tokens(rows, membership,
-                                  window=(win0, self.landed_ts)):
-                self._buf = []
-                self._dirty = True
-                return
-        if force_boundary and self._buf:
-            # A waiting request needs a clean boundary to admit: apply the
-            # just-stacked-would-be buffer SYNCHRONOUSLY instead of
-            # pipelining it, then rewind so the next step re-admits.
-            rows = self._rows_from_stacked(self._stack_buffer(self._buf))
-            membership = [(s, st["rid"]) for s, st in self._slots.items()]
-            self._buf = []
-            win0, self._window_t0 = self._window_t0, None
-            self.landed_ts = time.time()
-            self._apply_tokens(rows, membership,
-                               window=(win0, self.landed_ts))
-            self._dirty = True
-            return
-        if not self._buf:
-            return
-        # 2. Stack this buffer into ONE transfer and start it async; it
-        # lands while the next K ticks run.
-        stacked = self._stack_buffer(self._buf)
-        self._buf = []
-        for part in (stacked if isinstance(stacked, tuple) else (stacked,)):
-            part.copy_to_host_async()
-        self._pending = (stacked,
-                         [(s, st["rid"])
-                          for s, st in self._slots.items()],
-                         self._window_t0, self._window_k)
-        self._window_t0 = None
 
     def run_to_completion(self) -> Dict[int, List[int]]:
         """Drive ticks until every submitted request finished."""

@@ -1943,7 +1943,7 @@ class Deployment:
         self.placement_strategy = placement_strategy
         # Constructor overrides merged over bind() kwargs at deploy time:
         # config-file deploys tune replica knobs (e.g. the LLM engine's
-        # num_slots / sync_every / use_decode_kernel) without editing the
+        # num_slots / max_len / use_decode_kernel) without editing the
         # application module.
         self.init_kwargs = dict(init_kwargs or {})
 

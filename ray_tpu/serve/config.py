@@ -19,7 +19,7 @@ Config shape::
             ray_actor_options: {num_cpus: 1}
             init_kwargs:             # constructor overrides, merged over
               num_slots: 16          # bind() kwargs (e.g. the continuous
-              sync_every: 8          # -batching engine knobs)
+              max_len: 1024          # -batching engine knobs)
               block_size: 64         # paged-KV plane knobs ride the same
               kv_dtype: int8         # path (paged / block_size / kv_dtype
               sampling:              # / num_blocks / sampling)

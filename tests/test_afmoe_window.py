@@ -500,7 +500,6 @@ def test_reset_rebuilds_the_ring(model):
 @pytest.mark.parametrize("kwargs,named", [
     (dict(prefix_cache=True), "prefix cache"),
     (dict(spec_k=2), "speculative"),
-    (dict(sync_every=4), "buffered decode"),
     (dict(role="prefill"), "role='prefill'"),
     (dict(kv_dtype="int8"), "kv_dtype='int8'"),
 ])

@@ -23,6 +23,11 @@ from ray_tpu.cluster_utils import Cluster
 
 @pytest.fixture(scope="module", autouse=True)
 def cluster():
+    # A runtime an earlier file of this worker left initialised (which
+    # file comes first depends on the other workers' pace) would make
+    # every test here an error at set-up.
+    if ray_tpu.is_initialized():
+        ray_tpu.shutdown()
     c = Cluster(head_node_args={"num_cpus": 8})
     c.wait_for_nodes()
     ray_tpu.init(address=c.address)

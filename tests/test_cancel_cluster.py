@@ -19,6 +19,8 @@ from ray_tpu.cluster_utils import Cluster
 
 @pytest.fixture(scope="module", autouse=True)
 def cluster():
+    if ray_tpu.is_initialized():    # left by an earlier file of this worker
+        ray_tpu.shutdown()
     c = Cluster(head_node_args={"num_cpus": 2})
     c.wait_for_nodes()
     ray_tpu.init(address=c.address)

@@ -182,41 +182,27 @@ def test_batch_generate_over_dataset():
     ray_tpu.shutdown()
 
 
-def test_buffered_sync_matches_per_tick(setup):
-    """sync_every>1 (speculative buffered decode for high-latency links)
-    produces bit-identical outputs to per-tick sync."""
-    config, gen, _ = setup
-    rng = np.random.default_rng(7)
-    reqs = []
-    for n_prompt, n_new in [(5, 9), (11, 4), (3, 14)]:
-        reqs.append((list(rng.integers(1, 250, size=n_prompt)), n_new))
-    buffered = ContinuousBatcher(config, params=gen.params, num_slots=2,
-                                 max_len=128, sync_every=4)
-    rids = [buffered.submit(p, max_new_tokens=n) for p, n in reqs]
-    results = buffered.run_to_completion()
-    assert set(results) == set(rids)
-    for rid, (prompt, n_new) in zip(rids, reqs):
-        assert results[rid] == _reference(gen, prompt, n_new), rid
-
-
-def test_buffered_cancel_last_request_does_not_wedge(setup):
-    """Cancelling the only active request while a fetch is pending must
-    drain the in-flight state, not wedge admission forever."""
+def test_cancel_of_the_last_request_with_a_tick_in_flight_does_not_wedge(
+        setup):
+    """Cancelling the only live request while its next tick is queued
+    on the device must drain the tick in flight, not wedge admission."""
     config, gen, _ = setup
     eng = ContinuousBatcher(config, params=gen.params, num_slots=2,
-                            max_len=128, sync_every=4)
+                            max_len=128)
     rid = eng.submit([1, 2, 3], max_new_tokens=50)
-    for _ in range(5):  # runs past one flush: a pending fetch exists
+    for _ in range(5):
         eng.step()
+    assert eng._inflight, "no tick queued behind the one that ran"
     eng.cancel(rid)
-    for _ in range(12):
+    for _ in range(3):
         eng.step()
         if not eng.has_work():
             break
     assert not eng.has_work(), "engine wedged after cancel"
     rid2 = eng.submit([4, 5], max_new_tokens=3)
     out = eng.run_to_completion()
-    assert rid2 in out and len(out[rid2]) == 3
+    assert rid not in out
+    assert out[rid2] == _reference(gen, [4, 5], 3)
 
 
 # ------------------------------------- fused decode kernel / batched prefill
@@ -242,23 +228,24 @@ def test_decode_kernel_on_off_bit_identical(setup, pallas_interpret):
         assert toks == _reference(gen, prompt, m)
 
 
-def test_decode_kernel_across_sync_every(setup, pallas_interpret):
-    """Kernel on, sync_every in {1, K}: speculative buffered decode must
-    stay bit-identical with the fused kernel in the tick."""
+def test_decode_kernel_on_against_off_with_a_tick_in_flight(
+        setup, pallas_interpret):
+    """Kernel on against kernel off over requests that end on different
+    ticks (a membership change with a tick in flight at each): the fused
+    kernel in the tick changes no token."""
     config, gen, _ = setup
     rng = np.random.default_rng(12)
     reqs = [(list(rng.integers(1, 250, size=n)), m)
             for n, m in [(4, 9), (12, 5)]]
     results = {}
-    for sync_every in (1, 4):
+    for use_kernel in (True, False):
         eng = ContinuousBatcher(config, params=gen.params, num_slots=2,
-                                max_len=128, sync_every=sync_every,
-                                use_decode_kernel=True)
+                                max_len=128, use_decode_kernel=use_kernel)
         rids = [eng.submit(p, max_new_tokens=m) for p, m in reqs]
         out = eng.run_to_completion()
-        results[sync_every] = [out[r] for r in rids]
-    assert results[1] == results[4]
-    for (prompt, m), toks in zip(reqs, results[1]):
+        results[use_kernel] = [out[r] for r in rids]
+    assert results[True] == results[False]
+    for (prompt, m), toks in zip(reqs, results[True]):
         assert toks == _reference(gen, prompt, m)
 
 
@@ -566,25 +553,23 @@ def test_paged_block_accounting_and_arena_exhaustion(setup):
     assert eng.run_to_completion()[r0] == []
 
 
-def test_paged_buffered_arena_wait_keeps_pipelining(setup):
-    """Buffered mode + arena-exhausted waiting request: the engine must
-    keep K-ticks-per-sync pipelining (no forced boundary every tick)
-    until blocks free, then admit and finish the waiter."""
+def test_an_arena_blocked_head_does_not_stop_the_live_slots_ticks(setup):
+    """A waiting request the arena has no blocks for (a slot is free):
+    the live slot's ticks go on, one queued behind the one that runs,
+    until blocks free; then the waiter is admitted and finishes."""
     config, gen, _ = setup
     eng = ContinuousBatcher(config, params=gen.params, num_slots=3,
-                            max_len=128, block_size=16,
-                            num_blocks=5, sync_every=4)
-    r1 = eng.submit(list(range(1, 40)), max_new_tokens=20)  # 4 blocks
+                            max_len=128, block_size=16, num_blocks=5)
+    p1 = list(range(1, 40))
+    r1 = eng.submit(p1, max_new_tokens=20)                  # 4 blocks
     r2 = eng.submit([1, 2, 3], max_new_tokens=3)            # waits: 0 free
     for _ in range(4):
         eng.step()
-    # r2 cannot admit (no blocks): the pipeline must still be buffering
-    # speculative ticks instead of syncing every step.
-    assert eng.active_count == 1
-    assert len(eng._buf) + (eng._pending is not None) > 0, \
-        "arena-blocked waiter collapsed speculative buffering"
+    assert eng.active_count == 1 and not eng._head_fits()
+    assert eng._inflight and eng.base_tick_count == 5, \
+        "an arena-blocked waiter stopped the tick queued ahead"
     out = eng.run_to_completion()
-    assert len(out[r1]) == 20
+    assert out[r1] == _reference(gen, p1, 20)
     assert out[r2] == _reference(gen, [1, 2, 3], 3)
 
 
@@ -592,9 +577,9 @@ def test_paged_buffered_arena_wait_keeps_pipelining(setup):
     "past_the_reservation", "first_row_past_a_full_last_block",
     "past_the_table"])
 def test_paged_overrun_write_lands_in_garbage_block(overrun):
-    """A tick that runs past a slot's reservation (the buffered path
-    detects an end up to 2K ticks late; a request whose ``prompt +
-    max_new`` fills its last block exactly has its very next row there)
+    """A tick that runs past a slot's reservation (a request whose
+    ``prompt + max_new`` fills its last block exactly has its very next
+    row there)
     must NOT write into its last live block via the tail-repeated table:
     overrun writes redirect to the garbage block, live blocks stay
     byte-identical."""
@@ -729,25 +714,35 @@ def test_removed_engine_switches_are_refused(setup):
         _deploy_application(None, app, {})
 
 
-def test_paged_buffered_overrun_heavy_parity(setup):
-    """sync_every>1 with requests whose reservations the device overruns
-    during speculation (finish detection lags 2K ticks): outputs stay
-    bit-identical to per-tick sync."""
+def test_overrun_by_the_tick_in_flight_matches_the_reference(setup):
+    """Reservations that fill their last block exactly, in an arena with
+    no block to spare: a request ended by EOS has the tick in flight run
+    one more row for it, and the waiter admitted into the blocks it
+    freed, like the neighbour that decoded beside it, gets the
+    reference's tokens."""
     config, gen, _ = setup
     rng = np.random.default_rng(99)
-    pa = list(rng.integers(1, 250, size=5))   # 2 blocks of 16, ends at 30
-    pc = list(rng.integers(1, 250, size=4))   # finishes late -> rewind
-    outs = {}
-    for k in (1, 8):
-        eng = ContinuousBatcher(config, params=gen.params, num_slots=3,
-                                max_len=64, block_size=16,
-                                sync_every=k)
-        ra = eng.submit(pa, max_new_tokens=26)
-        rc = eng.submit(pc, max_new_tokens=20)
-        o = eng.run_to_completion()
-        outs[k] = (o[ra], o[rc])
-    assert outs[1] == outs[8]
-    assert outs[1][0] == _reference(gen, pa, 26)
+    pa = list(rng.integers(1, 250, size=5))
+    pb = list(rng.integers(1, 250, size=4))
+    pc = list(rng.integers(1, 250, size=6))
+    ref_a = _reference(gen, pa, 27)              # 5 + 27: two full blocks
+    eos = ref_a[20]
+    want_a = ref_a[:ref_a.index(eos) + 1]
+    want_b = _reference(gen, pb, 28)             # 4 + 28: two full blocks
+    want_c = _reference(gen, pc, 26)             # 6 + 26: two full blocks
+    assert eos not in want_b and eos not in want_c
+    eng = ContinuousBatcher(config, params=gen.params, num_slots=2,
+                            max_len=64, block_size=16, num_blocks=5,
+                            eos_token=eos)
+    rows, dispatch = [], eng._dispatch_tick
+    eng._dispatch_tick = lambda members: (rows.append(len(members)),
+                                          dispatch(members))[1]
+    ra = eng.submit(pa, max_new_tokens=27)
+    rb = eng.submit(pb, max_new_tokens=28)
+    rc = eng.submit(pc, max_new_tokens=26)       # waits for ra's blocks
+    out = eng.run_to_completion()
+    assert (out[ra], out[rb], out[rc]) == (want_a, want_b, want_c)
+    assert sum(rows) - eng.decoded_tokens == 1      # the one overrun row
 
 
 def test_paged_rejects_non_pow2_block_size():
@@ -766,8 +761,7 @@ def test_paged_rejects_non_pow2_block_size():
 def test_sampling_deterministic_and_distinct():
     """temperature/top-p sampling inside the tick jit: a fixed seed
     replays bit-identically (fresh engine, same submissions), differs
-    from greedy, differs across seeds, and sync_every>1 speculative
-    buffering does not change sampled output."""
+    from greedy and differs across seeds."""
     from ray_tpu.models.sampling import SamplingParams
 
     config = llama.LlamaConfig.tiny(dtype=jnp.float32)
@@ -793,49 +787,43 @@ def test_sampling_deterministic_and_distinct():
     assert a != run(sampling=SamplingParams(temperature=0.8, top_p=0.9,
                                             seed=43)), \
         "seed does not steer sampling"
-    assert a == run(sampling=sp, sync_every=4), \
-        "speculative buffering changed sampled output"
     for toks, (_, m) in zip(a, reqs):
         assert len(toks) == m
         assert all(0 <= t < config.vocab_size for t in toks)
 
 
-def test_buffered_admission_not_starved(setup):
-    """A request submitted mid-pipeline with a free slot must join within
-    ~2K ticks, not wait for the running request to finish."""
+def test_admission_is_not_starved_behind_a_running_request(setup):
+    """A request submitted mid-run with a free slot joins behind the tick
+    in flight and finishes in its own few ticks, not when the running
+    request does."""
     config, gen, _ = setup
     eng = ContinuousBatcher(config, params=gen.params, num_slots=2,
-                            max_len=128, sync_every=4)
+                            max_len=128)
     r_long = eng.submit([1, 2, 3], max_new_tokens=100)
     for _ in range(6):
         eng.step()
     r_short = eng.submit([4, 5, 6], max_new_tokens=3)
     finished = {}
-    for i in range(30):  # << the ~100 ticks r_long needs
+    for i in range(5):  # << the ~100 ticks r_long needs
         finished.update(eng.step())
         if r_short in finished:
             break
-    assert r_short in finished, "waiting request starved behind pipeline"
+    assert r_short in finished, "waiting request starved behind the run"
     assert r_long not in finished
     out = eng.run_to_completion()
-    assert r_long in out and len(out[r_long]) == 100
-    # The long request's output is unaffected by the mid-flight rewinds.
+    # The long request's output is unaffected by the mid-run admission.
     assert out[r_long] == _reference(gen, [1, 2, 3], 100)
 
 
-@pytest.mark.parametrize("sync_every", [1, 3])
-def test_token_callbacks_are_whole_and_in_order_when_a_request_ends(
-        setup, sync_every):
+def test_token_callbacks_are_whole_and_in_order_when_a_request_ends(setup):
     """Token callbacks are made as a tick's tokens are booked (the
-    next tick is queued on the device by then; the buffered path books
-    K ticks at a time). By the time ``step`` REPORTS a request finished
+    next tick is queued on the device by then). By the time ``step`` REPORTS a request finished
     the callbacks have delivered all its tokens, in order: a stream's
     end-marker is put right after ``step`` returns."""
     config, gen, _ = setup
     seen = {}
     eng = ContinuousBatcher(
         config, params=gen.params, num_slots=2, max_len=64,
-        sync_every=sync_every,
         token_callback=lambda rid, tok: seen.setdefault(rid, []).append(tok))
     rng = np.random.default_rng(0)
     want = {eng.submit(rng.integers(1, config.vocab_size, n).tolist(), m): m
@@ -1074,12 +1062,9 @@ def test_pipelined_engine_matches_the_reference(setup, case):
 def test_pipelined_sampled_decode_reproduces(setup, script):
     """Sampled decode keys every tick off the device-carried step
     counter, which a re-upload with a tick in flight sets to the applied
-    count plus the ticks in flight. Requests that end on different
-    ticks (a re-upload at each) draw the tokens the buffered engine
-    draws, whose re-uploads rewind to the applied count alone; with
-    admissions mid-run (a freed slot is refilled a tick later than the
-    buffered engine's, so the two schedules differ) a replay of the
-    same script draws the same tokens."""
+    count plus the ticks in flight. With requests that end on different
+    ticks (a re-upload at each), and with admissions mid-run, a replay
+    of the same script draws the same tokens."""
     from ray_tpu.models.sampling import SamplingParams
 
     config, gen, _ = setup
@@ -1096,7 +1081,7 @@ def test_pipelined_sampled_decode_reproduces(setup, script):
 
     if script == "staggered":
         out = run(3)
-        assert out == run(3, sync_every=4)
+        assert out == run(3)
         greedy = [_reference(gen, p, m) for p, m in zip(prompts, news)][:3]
         assert out != greedy
     else:
@@ -1286,8 +1271,7 @@ def _closed_loop(eng, seed, total, first, lengths=(17, 32), new=(4, 24)):
     return done, order
 
 
-@pytest.mark.parametrize("sync_every", [1, 3])
-def test_gathered_admission_changes_no_token_and_no_order(setup, sync_every):
+def test_gathered_admission_changes_no_token_and_no_order(setup):
     """The same seeded closed loop with holds and with none: every
     request's tokens and the order of first tokens are the same, in
     fewer prefill batches, and the held slots' time is booked."""
@@ -1296,8 +1280,7 @@ def test_gathered_admission_changes_no_token_and_no_order(setup, sync_every):
     config, gen, _ = setup
     runs = {}
     for holds in (True, False):
-        eng = _pipelined(config, gen, num_slots=4, prefix_cache=False,
-                         sync_every=sync_every)
+        eng = _pipelined(config, gen, num_slots=4, prefix_cache=False)
         _price(eng, lambda rows: 10.0, restart_ms=2.0, tick_ms=0.5)
         if not holds:
             eng._holds_admission = lambda: False
@@ -1368,7 +1351,7 @@ def test_gathered_size_is_the_costs_minimum(setup, table):
         assert plan is None and not eng._holds_admission()
     else:
         assert (plan["slots"], plan["rows"]) == (want, want)
-        assert eng._holds_admission() and not eng._can_admit_head()
+        assert eng._head_fits() and eng._holds_admission()
 
 
 def _case_a_slot_for_everyone(config, gen):
@@ -1515,14 +1498,12 @@ def test_run_to_completion_leaves_no_request_behind_a_hold(setup):
         assert done[rid] == _reference(gen, prompt, 5 + rids.index(rid) % 7)
 
 
-@pytest.mark.parametrize("sync_every", [1, 3])
-def test_can_admit_head_is_false_while_admit_holds(setup, sync_every):
-    """The buffered engine's boundary probe agrees with ``_admit``: a
-    held head forces no boundary, and the step that holds runs no
-    prefill."""
+def test_a_head_that_fits_is_not_admitted_while_admit_holds(setup):
+    """``_holds_admission`` is what ``_admit`` obeys: a head the arena
+    and a free slot have room for stays in the queue through a hold, and
+    the step that holds runs no prefill."""
     config, gen, _ = setup
-    eng = _pipelined(config, gen, num_slots=4, prefix_cache=False,
-                     sync_every=sync_every)
+    eng = _pipelined(config, gen, num_slots=4, prefix_cache=False)
     _price(eng, lambda rows: 10.0, restart_ms=2.0, tick_ms=0.5)
     for i, p in enumerate(_prompts(3, 20, 21, 22, 23)):
         eng.submit(p, max_new_tokens=8 + 8 * i)
@@ -1532,7 +1513,7 @@ def test_can_admit_head_is_false_while_admit_holds(setup, sync_every):
         eng.step()
     assert len(eng._waiting) == 3 and eng._head_fits()
     batches = _prefill_batches(eng)
-    assert eng._holds_admission() and not eng._can_admit_head()
+    assert eng._holds_admission()
     eng.step()
     assert _prefill_batches(eng) == batches and len(eng._waiting) == 3
     _drain(eng, {})

@@ -3,8 +3,8 @@
 The KV-block transfer plane must be INVISIBLE to correctness: a request
 split across a prefill replica and a decode replica yields the
 bit-identical greedy completion the colocated engine produces, across
-the whole engine feature matrix (paged kernel, int8 arenas, buffered
-sync, prefix cache). The handoff is exactly-once under chaos — a
+the whole engine feature matrix (paged kernel, int8 arenas, prefix
+cache). The handoff is exactly-once under chaos — a
 replica killed mid-transfer on EITHER side recovers through the request
 journal without dropping, duplicating, or double-billing the transfer.
 """
@@ -284,22 +284,20 @@ def _split_parity_matrix(config, gen, use_kernel):
             (list(rng.integers(1, 250, size=17)), 7)]
     refs = [_reference(gen, p, m) for p, m in reqs]
     for kv_dtype in ("bf16", "int8"):
-        for sync_every in (1, 4):
-            for prefix in (False, True):
-                kw = dict(use_decode_kernel=use_kernel,
-                          kv_dtype=kv_dtype, sync_every=sync_every,
-                          prefix_cache=prefix)
-                colo = _run_colocated(config, gen.params, reqs, **kw)
-                split = _run_split(config, gen.params, reqs, **kw)
-                tag = (use_kernel, kv_dtype, sync_every, prefix)
-                assert split == colo, tag
-                if kv_dtype == "bf16":
-                    assert split == refs, tag
+        for prefix in (False, True):
+            kw = dict(use_decode_kernel=use_kernel,
+                      kv_dtype=kv_dtype, prefix_cache=prefix)
+            colo = _run_colocated(config, gen.params, reqs, **kw)
+            split = _run_split(config, gen.params, reqs, **kw)
+            tag = (use_kernel, kv_dtype, prefix)
+            assert split == colo, tag
+            if kv_dtype == "bf16":
+                assert split == refs, tag
 
 
 def test_split_parity_smoke(setup):
-    """Fast-tier parity anchor: the two most entangled legs — buffered
-    sync + prefix cache bf16, and int8 per-tick sync — split outputs
+    """Fast-tier parity anchor: the two most entangled legs — prefix
+    cache bf16, and int8 — split outputs
     bit-identical to colocated (bf16 also equal to the sequential
     generator). The full cross-product runs in the slow tier."""
     config, gen = setup
@@ -308,7 +306,7 @@ def test_split_parity_smoke(setup):
     reqs = [(shared + list(rng.integers(1, 250, size=4)), 6),
             (list(rng.integers(1, 250, size=17)), 5)]
     refs = [_reference(gen, p, m) for p, m in reqs]
-    kw = dict(sync_every=4, prefix_cache=True)
+    kw = dict(prefix_cache=True)
     assert _run_split(config, gen.params, reqs, **kw) == \
         _run_colocated(config, gen.params, reqs, **kw) == refs
     kw8 = dict(kv_dtype="int8")
@@ -319,7 +317,7 @@ def test_split_parity_smoke(setup):
 @pytest.mark.slow
 def test_split_parity_matrix(setup):
     """Colocated-vs-split greedy outputs bit-identical across bf16/int8
-    arenas × sync_every {1,4} × prefix-cache on/off (interpreter-path
+    arenas × prefix-cache on/off (interpreter-path
     attention)."""
     config, gen = setup
     _split_parity_matrix(config, gen, use_kernel=False)

@@ -397,7 +397,6 @@ def test_reset_rebuilds_the_state_cache(model):
     ({"prefix_cache": True}, "prefix cache"),
     ({"role": "prefill"}, "role='prefill'"),
     ({"role": "decode"}, "role='decode'"),
-    ({"sync_every": 4}, "buffered"),
 ])
 def test_refused_by_name_for_state_layers(model, kwargs, named):
     config, params = model

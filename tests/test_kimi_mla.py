@@ -487,7 +487,6 @@ def test_the_shares_and_the_shared_expert_once_are_the_layer():
 
 @pytest.mark.parametrize("kwargs,named", [
     (dict(spec_k=2), "speculative"),
-    (dict(sync_every=4), "buffered decode"),
     (dict(role="prefill"), "role='prefill'"),
     (dict(role="decode"), "role='decode'"),
     (dict(kv_dtype="int8"), "kv_dtype='int8'"),

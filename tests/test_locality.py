@@ -11,6 +11,8 @@ from ray_tpu.cluster_utils import Cluster
 
 @pytest.fixture(scope="module", autouse=True)
 def cluster():
+    if ray_tpu.is_initialized():    # left by an earlier file of this worker
+        ray_tpu.shutdown()
     c = Cluster(head_node_args={"num_cpus": 2})
     c.add_node(num_cpus=2, resources={"b": 1.0})
     c.wait_for_nodes()

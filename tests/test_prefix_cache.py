@@ -162,27 +162,6 @@ def test_prefix_cache_on_off_bit_identical_across_paths(
                     assert toks == _reference(gen, p, m)
 
 
-def test_prefix_cache_buffered_parity(setup):
-    """Speculative buffered decode (sync_every>1, the remote-chip mode)
-    + prefix reuse stays bit-identical to per-tick sync."""
-    config, gen = setup
-    rng = np.random.default_rng(7)
-    shared = list(map(int, rng.integers(1, 250, size=2 * BS + 1)))
-    reqs = [(shared + [7, 8], 9), (shared + [9], 6)]
-    results = {}
-    for k in (1, 4):
-        eng = _engine(config, gen, prefix_cache=True, sync_every=k)
-        outs = []
-        for p, m in reqs:
-            rid = eng.submit(list(p), max_new_tokens=m)
-            outs.append(eng.run_to_completion()[rid])
-        results[k] = outs
-        assert eng.prefix_hit_tokens > 0
-    assert results[1] == results[4]
-    for (p, m), toks in zip(reqs, results[1]):
-        assert toks == _reference(gen, p, m)
-
-
 def test_same_round_cold_twins_are_safe(setup):
     """Two identical prompts admitted in ONE admission round are both
     cold (matching sees only blocks whose prefill already dispatched):
@@ -256,12 +235,11 @@ def test_cached_blocks_reclaimed_before_admission_blocks(setup):
 
 
 def test_admission_probe_agrees_with_admission_under_shared_pressure(setup):
-    """_can_admit_head must not count a parked matched block twice —
+    """_head_fits must not count a parked matched block twice —
     once as covering the request's need (via the match) and once as
     evictable capacity (via the LRU): pinning the match revives the
-    block WITHOUT freeing anything. An optimistic probe makes the
-    buffered engine force sync boundaries for an admission that then
-    fails, the exact pipelining collapse the probe exists to avoid."""
+    block WITHOUT freeing anything. An optimistic probe has ``_gather``
+    hold slots empty for an admission that then fails."""
     config, gen = setup
     eng = _engine(config, gen, num_blocks=7, prefix_cache=True)
     p1 = list(range(1, 1 + 2 * BS + 2))
@@ -278,7 +256,7 @@ def test_admission_probe_agrees_with_admission_under_shared_pressure(setup):
     # the match revives the parked pair from the LRU, leaving NOTHING
     # evictable for the novel pair: the probe must say no.
     r2 = eng.submit(list(p1), max_new_tokens=2 * BS - 4)
-    assert eng._can_admit_head() is False
+    assert eng._head_fits() is False and not eng._holds_admission()
     eng.step()
     assert eng.active_count == 1, "admission should be arena-blocked"
     out = eng.run_to_completion()

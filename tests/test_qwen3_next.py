@@ -516,7 +516,6 @@ def test_the_shares_and_the_gated_shared_expert_once_are_the_layer():
 @pytest.mark.parametrize("kwargs,named", [
     (dict(spec_k=2), "speculative"),
     (dict(prefix_cache=True), "prefix cache"),
-    (dict(sync_every=4), "buffered decode"),
     (dict(role="prefill"), "role='prefill'"),
     (dict(role="decode"), "role='decode'"),
 ])

@@ -67,38 +67,28 @@ def test_ttft_components_sum_to_measured_ttft(span_capture):
     assert bd["tpot_s"] is not None and bd["tpot_s"] >= 0
 
 
-def test_engine_spans_share_trace_id_sync_and_buffered(span_capture):
+def test_engine_spans_share_trace_id(span_capture):
     """One submit yields queue + prefill + >=1 decode-window span, all on
-    the caller's trace id — including the buffered (sync_every>1)
-    engine, whose windows cover whole speculative buffers."""
+    the caller's trace id."""
     cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
-    for sync_every in (1, 4):
-        rep_before = len(span_capture.records)
-        eng = ContinuousBatcher(cfg, sync_every=sync_every, **TINY)
-        t = _trace(request_id=f"req-s{sync_every}",
-                   trace_id=f"{sync_every}" * 16)
-        rid = eng.submit([1, 2, 3], max_new_tokens=8, trace=t)
-        out = eng.run_to_completion()
-        assert len(out[rid]) == 8
-        spans = span_capture.records[rep_before:]
-        assert spans and all(
-            s["trace_id"] == t["trace_id"] for s in spans), sync_every
-        assert all(s["parent_span_id"] == t["parent_span_id"]
-                   for s in spans)
-        assert all(s.get("request_id") == t["request_id"] for s in spans)
-        names = [s["name"] for s in spans]
-        assert "engine.queue" in names
-        assert "engine.prefill" in names
-        windows = [s for s in spans if s["name"] == "engine.decode_window"]
-        assert windows, names
-        # Every generated token after the first is attributed to exactly
-        # one decode window.
-        assert sum(s["tokens"] for s in windows) == 8 - 1
-        if sync_every > 1:
-            # Buffered mode books whole speculative buffers per window:
-            # strictly fewer windows than decode ticks.
-            assert len(windows) < 8 - 1
-        assert names[-1] == "engine.finished"
+    eng = ContinuousBatcher(cfg, **TINY)
+    t = _trace(request_id="req-s1", trace_id="1" * 16)
+    rid = eng.submit([1, 2, 3], max_new_tokens=8, trace=t)
+    out = eng.run_to_completion()
+    assert len(out[rid]) == 8
+    spans = span_capture.records
+    assert spans and all(s["trace_id"] == t["trace_id"] for s in spans)
+    assert all(s["parent_span_id"] == t["parent_span_id"] for s in spans)
+    assert all(s.get("request_id") == t["request_id"] for s in spans)
+    names = [s["name"] for s in spans]
+    assert "engine.queue" in names
+    assert "engine.prefill" in names
+    windows = [s for s in spans if s["name"] == "engine.decode_window"]
+    assert windows, names
+    # Every generated token after the first is attributed to exactly
+    # one decode window.
+    assert sum(s["tokens"] for s in windows) == 8 - 1
+    assert names[-1] == "engine.finished"
 
 
 def test_eviction_path_emits_trace_and_outcome(span_capture):

@@ -2,9 +2,9 @@
 
 Draft-and-verify decode must be a pure THROUGHPUT change: greedy outputs
 bit-identical spec-on vs spec-off across the whole engine feature matrix
-(paged kernel, int8 arenas, buffered sync, prefix cache), sampled decode
+(paged kernel, int8 arenas, prefix cache), sampled decode
 still the target distribution (rejection sampling) and still
-deterministic under a fixed seed including buffered rewind replay, and
+deterministic under a fixed seed, and
 k=0 — configured or adapted-to — exactly the pre-spec tick program.
 """
 
@@ -52,32 +52,29 @@ def _parity_matrix(config, gen, use_kernel):
             (list(rng.integers(1, 250, size=7)), 7)]
     refs = [_reference(gen, p, m) for p, m in reqs]
     for kv_dtype in ("bf16", "int8"):
-        # One spec-off baseline per (kernel, kv_dtype): sync_every and
-        # prefix-cache bit-parity are already tier-1 guarantees of their
-        # own, so the baseline doesn't vary across them.
+        # One spec-off baseline per (kernel, kv_dtype): prefix-cache
+        # bit-parity is already a tier-1 guarantee of its own, so the
+        # baseline doesn't vary across it.
         base, _ = _run(config, gen.params, reqs, spec_k=0,
                        use_decode_kernel=use_kernel,
                        kv_dtype=kv_dtype, block_size=16)
-        for sync_every in (1, 4):
-            for prefix in (False, True):
-                spec, eng = _run(config, gen.params, reqs, spec_k=2,
-                                 spec_draft_layers=1,
-                                 spec_adaptive=False,
-                                 use_decode_kernel=use_kernel,
-                                 kv_dtype=kv_dtype,
-                                 sync_every=sync_every,
-                                 prefix_cache=prefix, block_size=16)
-                tag = (use_kernel, kv_dtype, sync_every, prefix)
-                assert spec == base, tag
-                assert eng.spec_tick_count > 0, tag
-                if kv_dtype == "bf16":
-                    assert spec == refs, tag
+        for prefix in (False, True):
+            spec, eng = _run(config, gen.params, reqs, spec_k=2,
+                             spec_draft_layers=1,
+                             spec_adaptive=False,
+                             use_decode_kernel=use_kernel,
+                             kv_dtype=kv_dtype,
+                             prefix_cache=prefix, block_size=16)
+            tag = (use_kernel, kv_dtype, prefix)
+            assert spec == base, tag
+            assert eng.spec_tick_count > 0, tag
+            if kv_dtype == "bf16":
+                assert spec == refs, tag
 
 
 def test_greedy_parity_smoke(setup):
     """Fast-tier parity anchor: the two most entangled legs of the
-    matrix — buffered (sync_every=4) + prefix-cache bf16, and int8 with
-    per-tick sync — bit-identical spec-on vs spec-off, with the bf16 leg
+    matrix — prefix-cache bf16, and int8 — bit-identical spec-on vs spec-off, with the bf16 leg
     also equal to the sequential generator. The full cross-product runs
     in the slow tier (`test_greedy_parity_matrix*`)."""
     config, gen = setup
@@ -87,7 +84,7 @@ def test_greedy_parity_smoke(setup):
             (list(rng.integers(1, 250, size=7)), 5)]
     refs = [_reference(gen, p, m) for p, m in reqs]
     spec_kw = dict(spec_k=2, spec_draft_layers=1, spec_adaptive=False)
-    spec, eng = _run(config, gen.params, reqs, sync_every=4,
+    spec, eng = _run(config, gen.params, reqs,
                      prefix_cache=True, block_size=16, **spec_kw)
     assert spec == refs
     assert eng.spec_tick_count > 0
@@ -101,7 +98,7 @@ def test_greedy_parity_smoke(setup):
 @pytest.mark.slow
 def test_greedy_parity_matrix(setup):
     """Greedy outputs are bit-identical spec-on vs spec-off across
-    bf16/int8 arenas × sync_every {1,4} × prefix-cache on/off — and
+    bf16/int8 arenas × prefix-cache on/off — and
     equal to the sequential generator wherever the arena stores full
     precision (int8 asserts spec-on == spec-off only; quantization
     perturbs logits either way)."""
@@ -213,24 +210,19 @@ def test_spec_commit_preserves_target_distribution():
     assert empirical[target == 0].sum() == 0
 
 
-def test_sampled_spec_deterministic_and_rewind_replay(setup):
+def test_sampled_spec_deterministic_across_staggered_finishes(setup):
     """Sampled spec decode replays bit-identically: same seed twice,
-    sync_every=1 vs 4 (up-front submission), and buffered runs whose
-    staggered finishes force rewinds mid-stream."""
+    with staggered finishes that change the membership mid-stream."""
     config, gen = setup
     rng = np.random.default_rng(43)
-    # Staggered max_new: the sync_every=4 run rewinds when the short
-    # request finishes mid-buffer.
     reqs = [(list(rng.integers(1, 250, size=6)), 4),
             (list(rng.integers(1, 250, size=10)), 9)]
     sampling = dict(temperature=0.8, top_p=0.9, seed=11)
     kw = dict(spec_k=2, spec_draft_layers=1, spec_adaptive=False,
               sampling=sampling)
-    a, _ = _run(config, gen.params, reqs, sync_every=1, **kw)
-    b, _ = _run(config, gen.params, reqs, sync_every=1, **kw)
+    a, eng = _run(config, gen.params, reqs, **kw)
+    b, _ = _run(config, gen.params, reqs, **kw)
     assert a == b, "same-seed sampled spec run not deterministic"
-    c, eng = _run(config, gen.params, reqs, sync_every=4, **kw)
-    assert c == a, "buffered sampled spec diverged from per-tick sync"
     assert eng.spec_tick_count > 0
 
 

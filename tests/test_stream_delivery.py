@@ -24,6 +24,18 @@ STREAM_COUNTERS = (mdefs.SERVE_STREAM_HANDOFF_SECONDS,
                    mdefs.SERVE_STREAM_PULLS, mdefs.SERVE_STREAM_ITEMS)
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _leave_no_runtime():
+    """A deployment built outside a runtime fixture (``_engine``) starts
+    an in-process runtime as it asks for its chip: shut it down with the
+    module, or the next file of this worker finds it initialised."""
+    import ray_tpu
+
+    yield
+    if ray_tpu.is_initialized():
+        ray_tpu.shutdown()
+
+
 class _Records:
     def __init__(self):
         self.records = []
@@ -582,7 +594,9 @@ def test_simulated_death_in_front_of_token_n_delivers_exactly_n():
         chaos.configure(None)
         chaos._clear_dying()
     assert _flat(shipped) == want[:5]
-    assert len(shipped) == 2 and len(shipped[1]) == 4
+    # What piled up in front of token 5 ships as ONE batch (the first
+    # pull may itself have found two tokens on a loaded machine).
+    assert len(shipped) == 2 and len(shipped[1]) >= 3
     assert not dep._streams
 
 
