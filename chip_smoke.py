@@ -46,6 +46,15 @@ seed), and checks what comes out by the repo's own means:
                 the program as published passes, eleven faults (state
                 or conv tail not carried between chunks among them)
                 each fail, and a bf16 recurrent state is read;
+* ``eva``       the cell ``serve_eva_decode``'s comparison at its own
+                sizes (EvaByte's stage at the published widths, eight
+                layers, through ``ContinuousBatcher``): check prompts
+                inside a window, on its last position, filling it, one
+                past it, closing one in a tick, and across three; chosen
+                bytes against the float32 reference under the
+                configuration file's limit; the program as published
+                passes, and five faults in the program and three in the
+                reference are read against it;
 * ``train``     ``ShardedTrainer`` on one device, batch 5 x 2048: loss
                 finite and falling, one compiled signature, the Mosaic
                 custom calls present in the compiled step;
@@ -82,17 +91,18 @@ import sys
 import threading
 import time
 
-PHASES = ("kernels", "moe", "hybrid", "window", "mla", "linear", "train",
-          "serve", "multichip")
+PHASES = ("kernels", "moe", "hybrid", "window", "mla", "linear", "eva",
+          "train", "serve", "multichip")
 # The multichip phase is two children: the trainer's state must be gone
 # from the chips before four serving replicas load theirs.
 CHILDREN = {"kernels": ("kernels",), "moe": ("moe",),
             "hybrid": ("hybrid",), "window": ("window",), "mla": ("mla",),
-            "linear": ("linear",), "train": ("train",),
+            "linear": ("linear",), "eva": ("eva",), "train": ("train",),
             "serve": ("serve",),
             "multichip": ("multichip-train", "multichip-serve")}
 PHASE_TIMEOUT_S = {"kernels": 600, "moe": 600, "hybrid": 1500,
-                   "window": 2700, "mla": 2700, "linear": 3300, "train": 480,
+                   "window": 2700, "mla": 2700, "linear": 3300, "eva": 3300,
+                   "train": 480,
                    "serve": 600,
                    "multichip-train": 900, "multichip-serve": 900}
 RESULT_TAG = "PHASE_RESULT "
@@ -1612,6 +1622,238 @@ def phase_linear(rehearse: bool) -> None:
     assert not wrong, f"{wrong}: {results} against {tolerance}"
 
 
+def phase_eva(rehearse: bool) -> None:
+    """The cell ``serve_eva_decode``'s comparison with its reference,
+    and the faults it has to catch, AT THE CELL'S OWN SIZES: the
+    configuration as the cell runs it (EvaByte's published widths, eight
+    layers) in the engine the served path builds (``ContinuousBatcher``:
+    a window a prefill chunk, summaries landed by the prefill, windows
+    closed inside the tick, blocks retired by the host; four slots are
+    enough here), the cell's check prompts and answer length, one request
+    after another, greedy; held to ``benchmark/reference_evabyte.py`` by
+    the runner's own ``hold_to_reference`` under the configuration file's
+    limit.
+
+    First the program as published, which has to pass. Then one fault at
+    a time, each of which has to FAIL the limit (ISSUE 43, Tentpole 4).
+    Five are put into the PROGRAM. Three change what a query sees, which
+    the program's kernels decide by one causal mask over a compressed
+    position, so they are put into the REFERENCE instead and the sound
+    program's bytes are held to it: the same distance read from the
+    other side."""
+    phase = "eva"
+    info = _open_device(phase, rehearse)
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import manifest, reference_evabyte
+    from benchmark.runners import serve_eva
+    from benchmark.runners.serve import _prompts
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models import eva, llama
+
+    cell = manifest.cell("serve_eva_decode")
+    if rehearse:
+        cell = manifest.rehearsal(cell)
+    work, tolerance = cell["workload"], cell["config"]["tolerance"]
+    config = serve_eva.eva_config(cell["config"],
+                                  max_seq_len=work["engine"]["max_len"])
+    bs, window = work["engine"]["block_size"], config.eva_window
+    engine = dict(work["engine"], num_slots=4, num_blocks=4 * eva.blocks_peak(
+        work["engine"]["max_len"], config, bs) + 1)
+    sets = [_prompts(np.random.default_rng(seed), config.vocab_size,
+                     work["check"]["prompt_tokens"],
+                     work["check"]["max_tokens"])
+            for seed in ((43,) if rehearse else (43, 44))]
+
+    def published():
+        return jax.jit(lambda k: llama.init_params(config, k))(
+            jax.random.PRNGKey(0))
+
+    def answers(weights, sets):
+        eng = cb.ContinuousBatcher(config, params=weights, **engine)
+        out = []
+        for reqs in sets:
+            recs = []
+            for req in reqs:
+                rid = eng.submit(req["prompt"], req["max_tokens"])
+                recs.append({"tokens": eng.run_to_completion()[rid]})
+            out.append(list(zip(reqs, recs)))
+        return out
+
+    Batcher = cb.ContinuousBatcher
+    real = {(mod, name): getattr(mod, name) for mod, name in (
+        (eva, "summarise"), (eva, "close_windows"),
+        (cb, "paged_chunk_attention"), (cb, "paged_decode_attention"),
+        (cb, "_rope_tables"), (Batcher, "_eva_step_blocks"),
+        (reference_evabyte, "seen"), (reference_evabyte, "_rope"),
+        (reference_evabyte, "summarise"))}
+    summarise = real[eva, "summarise"]
+
+    # The faults in the program.
+    def no_mu(k, v, phi, mu, chunk):
+        return summarise(k, v, phi, jnp.zeros_like(mu), chunk)
+
+    def uniform(k, v, phi, mu, chunk):
+        return summarise(k, v, jnp.zeros_like(phi), mu, chunk)
+
+    seen_at = {}            # the true positions of the program being traced
+
+    def rope_tables(c, length, positions):
+        seen_at["p"] = positions
+        return real[cb, "_rope_tables"](c, length, positions)
+
+    def chunk_sees_no_summary(q, k, v, ak, av, layer, tables, first,
+                              chunk_pos, scale, **kw):
+        return real[cb, "paged_chunk_attention"](
+            q, k, v, ak, av, layer, tables[:, :0], 0, chunk_pos, scale, **kw)
+
+    def tick_sees_no_summary(q, ck, cv, tables, positions, scale, *,
+                             layer=None, visits=None, **kw):
+        # The table turned so that the open window's first block leads,
+        # the position counted from there: the summaries fall behind it.
+        first = seen_at["p"][:, 0] // window * (eva.summaries(config) // bs)
+        turned = jnp.take_along_axis(
+            tables, (jnp.arange(tables.shape[1])[None] + first[:, None])
+            % tables.shape[1], axis=1)
+        return real[cb, "paged_decode_attention"](
+            q, ck, cv, turned, positions - first * bs, scale, layer=layer,
+            **kw)
+
+    def not_written(k, v, *rest):
+        return k, v
+
+    def retired_early(self, members, before):
+        real[Batcher, "_eva_step_blocks"](self, members, before)
+        if not before:
+            return
+        ahead = self._ahead()
+        for slot, rid in members:       # the tick about to go would fill
+            written = self._slots[slot]["pos"] + ahead.get((slot, rid), 0) + 1
+            if written % window == 0:
+                blocks = self._slot_blocks[slot]
+                keep = eva.blocks_held(written, config, bs)
+                self.allocator.free(blocks[keep:])
+                del blocks[keep:]
+                self._tables_stale = True
+
+    def float8(tree):
+        """Leaf by leaf, in place, and op by op."""
+        def low(a):
+            if a.dtype != jnp.bfloat16:
+                return a
+            out = a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+            a.delete()
+            return out
+        return jax.tree.map(low, tree)
+
+    # The faults in the reference (what a query sees).
+    R = reference_evabyte
+
+    def own_chunks_too(s, window, chunk):
+        i = jnp.arange(s)
+        ends = jnp.arange(s // chunk) * chunk + chunk - 1
+        extra = ((ends[None, :] // window == (i // window)[:, None])
+                 & (ends[None, :] <= i[:, None]))
+        return real[R, "seen"](s, window, chunk) | jnp.concatenate(
+            [jnp.zeros((s, s), bool), extra], axis=1)
+
+    def sliding(s, window, chunk):
+        i = jnp.arange(s)
+        ends = jnp.arange(s // chunk) * chunk + chunk - 1
+        raw = (i[None, :] <= i[:, None]) & (i[:, None] - i[None, :] < window)
+        return jnp.concatenate(
+            [raw, ends[None, :] <= i[:, None] - window], axis=1)
+
+    unrotated = []
+
+    def remember(x, theta):
+        unrotated.append(x)
+        return real[R, "_rope"](x, theta)
+
+    def pool_unrotated(k, v, phi, mu, chunk):
+        return real[R, "summarise"](unrotated[-1], v, phi, mu, chunk)
+
+    same = lambda p: p
+    E = eva
+    program_cases = [
+        ("as published", same, {}),
+        ("mu dropped", same, {(E, "summarise"): no_mu}),
+        ("uniform pooling (phi = 0)", same, {(E, "summarise"): uniform}),
+        ("summaries dropped (window only)", same,
+         {(cb, "_rope_tables"): rope_tables,
+          (cb, "paged_chunk_attention"): chunk_sees_no_summary,
+          (cb, "paged_decode_attention"): tick_sees_no_summary}),
+        ("a tick-closed window's summaries not written", same,
+         {(E, "close_windows"): not_written}),
+        ("a window's blocks retired one tick early", same,
+         {(Batcher, "_eva_step_blocks"): retired_early}),
+        # Last: it eats the published weights.
+        ("weights rounded to float8_e4m3", float8, {}),
+    ]
+    reference_cases = [
+        ("reference: own window's whole chunks also seen as summaries",
+         {(R, "seen"): own_chunks_too}),
+        ("reference: a sliding window in place of the block",
+         {(R, "seen"): sliding}),
+        ("reference: summaries pooled before the rotation",
+         {(R, "_rope"): remember, (R, "summarise"): pool_unrotated}),
+    ]
+    if rehearse:            # tiny sizes prove nothing about the faults
+        program_cases, reference_cases = program_cases[:1], []
+
+    def patched(patch):
+        for (mod, attr), fn in patch.items():
+            setattr(mod, attr, fn)
+
+    def restore():
+        for (mod, attr), fn in real.items():
+            setattr(mod, attr, fn)
+        R._gaps.clear_cache()
+
+    params = published()
+    answered = {}
+    for name, weights, patch in program_cases:
+        patched(patch)
+        try:
+            answered[name] = answers(
+                weights(params), sets if name == "as published" else sets[:1])
+        finally:
+            restore()
+        gc.collect()
+        _say(phase, f"{name}: {len(answered[name])} x {len(sets[0])} check "
+                    f"requests answered")
+    _print_memory(phase)
+    if len(program_cases) > 1:      # float8 ate the weights
+        del params
+        params = published()
+    results = {}
+    for name, checked in answered.items():
+        _say(phase, name)
+        results[name] = [serve_eva.hold_to_reference(
+            params, config, checks, tolerance) for checks in checked]
+    for name, patch in reference_cases:
+        _say(phase, name)
+        patched(patch)
+        R._gaps.clear_cache()
+        try:
+            results[name] = [serve_eva.hold_to_reference(
+                params, config, answered["as published"][0], tolerance)]
+        finally:
+            restore()
+    _finish(phase, info, faults=results, tolerance=tolerance)
+    # Read and held to nothing: at seeded weights these two move no
+    # chosen byte's rank (PERF.md section 7 keeps them, with readings).
+    read_only = {"uniform pooling (phi = 0)", reference_cases[0][0]
+                 } if reference_cases else set()
+    wrong = [name for name, rs in results.items() if name not in read_only
+             and any(r["ok"] != (name == "as published") for r in rs)]
+    assert not wrong, f"{wrong}: {results} against {tolerance}"
+
+
 def _train_once(phase, config, mesh, batch_size, seq_len, steps, rehearse):
     """Init + ``steps`` steps on one repeated batch. Returns (losses,
     trainer, state)."""
@@ -2011,7 +2253,8 @@ def _child(phase: str, rehearse: bool) -> int:
 
 CHILD_FNS = {"kernels": phase_kernels, "moe": phase_moe,
              "hybrid": phase_hybrid, "window": phase_window,
-             "mla": phase_mla, "linear": phase_linear, "train": phase_train,
+             "mla": phase_mla, "linear": phase_linear, "eva": phase_eva,
+             "train": phase_train,
              "serve": phase_serve, "multichip-train": phase_multichip_train,
              "multichip-serve": phase_multichip_serve}
 

@@ -718,6 +718,47 @@ CB_PREFILL_STATE_CARRIES = Counter(
     "state cache: every chunk of a linear-attention model's prompt but "
     "its first (beside ray_tpu_cb_state_installs_total, the prompts)",
     ("engine",))
+CB_EVA_WINDOWS_CLOSED = Counter(
+    "ray_tpu_cb_eva_windows_closed_total",
+    "Windows of an EVA-attention model's contexts that filled and were "
+    "pooled into their summaries, by the program that filled them: a "
+    "prefill chunk (a whole window of a prompt: its raw keys never reach "
+    "the arena) or a decode tick (the window's blocks rewritten in "
+    "place, inside the tick)",
+    ("engine", "phase"))
+CB_EVA_BLOCKS_RETIRED = Counter(
+    "ray_tpu_cb_eva_blocks_retired_total",
+    "Arena blocks that went back to the allocator because a decode tick "
+    "closed their window (all of the window's but its summaries'; a "
+    "prefill never holds them), in the step that dispatched that tick",
+    ("engine",))
+CB_EVA_SUMMARY_KEYS = Histogram(
+    "ray_tpu_cb_eva_summary_keys",
+    "Per decode tick of an EVA-attention model: the summaries of closed "
+    "windows its queries attended, summed over the live slots, a layer "
+    "(beside ray_tpu_cb_eva_window_keys: the two are a tick's attended "
+    "keys; two series because a label's values are summed where the "
+    "benchmark reads the registry)",
+    boundaries=[64, 256, 1024, 4096, 8192, 16384, 32768, 65536, 131072],
+    tag_keys=("engine",))
+CB_EVA_WINDOW_KEYS = Histogram(
+    "ray_tpu_cb_eva_window_keys",
+    "Per decode tick of an EVA-attention model: the raw keys of the open "
+    "windows its queries attended (each query's own included), summed "
+    "over the live slots, a layer",
+    boundaries=[64, 256, 1024, 4096, 8192, 16384, 32768, 65536, 131072],
+    tag_keys=("engine",))
+CB_EVA_CACHE_BYTES = Gauge(
+    "ray_tpu_cb_eva_cache_bytes",
+    "Bytes of the arena blocks the live slots of an EVA-attention model "
+    "hold now: their closed windows' summaries and their open windows as "
+    "far as they are filled",
+    ("engine",))
+CB_EVA_UNCOMPRESSED_BYTES = Gauge(
+    "ray_tpu_cb_eva_uncompressed_bytes",
+    "Bytes of the blocks the same live contexts would hold with every "
+    "key kept (what ray_tpu_cb_eva_cache_bytes is a share of)",
+    ("engine",))
 CB_STATE_INSTALLS = Counter(
     "ray_tpu_cb_state_installs_total",
     "Prompts whose final recurrent state a prefill installed in a slot "

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import xla_monitor
-from ray_tpu.models import gated_delta, llama, mamba2
+from ray_tpu.models import eva, gated_delta, llama, mamba2
 from ray_tpu.models import paged_kv
 from ray_tpu.models.inference import (ExternalLlamaDrafter, KVCache,
                                       SelfDrafter, _attend_cached,
@@ -252,7 +252,7 @@ def _layer_qkv(x, layer, cos, sin, c):
     position j of a window gets the bits S = 1 gives it, which the
     spec-on/off parity tests pin down. Also returns the output gate
     (``llama.attn_gate``; None for a model without one)."""
-    h = llama.norm(x, layer["attn_norm"], c)
+    h = llama.norm(x, layer["attn_norm"], c).astype(c.dtype)
     q, k, v = llama.project_qkv(h, layer, c)
     gate = llama.attn_gate(h, layer, c)    # None without an output gate
     if cos is None:     # no positions: "nope", or a layer kind without
@@ -303,7 +303,9 @@ def _embed(params, tokens, c):
     x = params["embed"].astype(c.dtype)[tokens]
     if c.embedding_multiplier != 1.0:
         x = x * c.embedding_multiplier
-    return x
+    # ``fp32_residual``: the stream between sublayers is float32; every
+    # sublayer still reads its norm's output in the model's dtype.
+    return x.astype(jnp.float32) if c.fp32_residual else x
 
 
 def _attn_out(o, layer, c, gate=None):
@@ -317,7 +319,8 @@ def _attn_out(o, layer, c, gate=None):
 _KIND_NAMES = {"mamba": "state-space",
                "linear_attention": "linear-attention",
                "sliding_attention": "sliding-window",
-               "latent_attention": "latent-attention"}
+               "latent_attention": "latent-attention",
+               "eva_attention": "eva-attention"}
 # A recurrent layer (Mamba-2 or Gated DeltaNet) keeps, beside its K/V, a
 # state a slot that only moves FORWARD and belongs to one request.
 _RECURRENT_CANNOT = {
@@ -364,6 +367,25 @@ _KIND_CANNOT = {
         "score_logprobs": "it runs llama.forward, the training forward, "
                           "which this serving-only family has none of",
     },
+    # The arena under a compressed position (``models/eva.py``): a closed
+    # window's blocks are REWRITTEN as its summaries, so a block no
+    # longer stands for the ``block_size`` tokens that filled it.
+    "eva_attention": {
+        "second_kind": "the compressed position is the whole table's: every "
+                       "layer must summarise the windows the others do",
+        "kv_dtype": "summaries are pooled from the keys as stored and "
+                    "written back over them: an 8-bit arena would quantize "
+                    "twice, and the pooling reads no scale sidecar",
+        "speculative": "a rejected draft that crossed a window's end has "
+                       "already overwritten the window with its summaries",
+        "prefix_cache": "the radix index names blocks by the block_size "
+                        "tokens that filled them; a closed window's blocks "
+                        "hold summaries, and its raw keys are gone",
+        "handoff": "the KV handoff counts a prompt's blocks from its "
+                   "length, not the compressed table's",
+        "score_logprobs": "it runs llama.forward, the training forward, "
+                          "which this serving-only family has none of",
+    },
 }
 
 
@@ -378,7 +400,7 @@ def _refuse_unsupported(config, asked: Dict[str, str]) -> None:
         # The arena's own layers sit beside any cache but the latent
         # one, which takes the arena's place.
         beside = kinds - {kind}
-        if kind != "latent_attention":
+        if kind not in ("latent_attention", "eva_attention"):
             beside -= {"attention", "full_attention"}
         wants = dict(asked)
         if beside:
@@ -431,7 +453,7 @@ def _layer_finish(x, mixed, layer, c, experts=None, li=None,
     if c.sandwich_norms:
         mixed = llama.norm(mixed, layer["post_attn_norm"], c)
     x = _residual(x, mixed, c)
-    h = llama.norm(x, layer["mlp_norm"], c)
+    h = llama.norm(x, layer["mlp_norm"], c).astype(c.dtype)
     down, routed = llama.mlp_block(h, layer, c, experts, li,
                                    use_kernel=use_kernel)
     if c.sandwich_norms:
@@ -503,6 +525,11 @@ def _forward_paged(params, tokens, positions, tables, limits,
     cos, sin = _rope_tables(c, 0, positions)                  # [B, S, D//2]
     x = _embed(params, tokens, c)                             # [B, S, E]
     scale = c.attn_scale
+    true_positions = positions
+    if c.eva_window:
+        # From here on ``positions`` are the ARENA's: a key lies behind
+        # its slot's summaries, not behind every key before it.
+        positions = eva.compressed(positions, c)
     # Resolve each position's target block through the slot's table once
     # (shared by every layer's write). The tick in flight can OVERRUN a
     # slot's reservation (the host learns of an end one tick late): past
@@ -550,6 +577,15 @@ def _forward_paged(params, tokens, positions, tables, limits,
                 x, layer, c, arenas[0], ki, cos, sin, block_idx, offset,
                 tables, positions, visits, use_kernel)
             arenas = (latents,)
+        elif kind == "eva_attention":
+            with jax.named_scope("eva/qkv"):
+                q, k, v, _ = _layer_qkv(x, layer, cos, sin, c)
+            with jax.named_scope("eva/attend"):
+                o, arenas = _write_then_attend(
+                    arenas, ki, q, k, v, block_idx, offset, tables,
+                    positions, visits, scale, use_kernel)
+            with jax.named_scope("eva/out_proj"):
+                mixed = _attn_out(o.astype(c.dtype), layer, c)
         else:
             with _kind_scope(kind):
                 q, k, v, gate = _layer_qkv(
@@ -576,6 +612,15 @@ def _forward_paged(params, tokens, positions, tables, limits,
             functools.partial(layer_fn, kind=kind, shift=kind_start - start),
             (x, arenas, held, jnp.int32(start)), tree)
         rows.append(run_rows)
+    if c.eva_window:
+        # The rows whose new key filled its window: pool the window into
+        # its summaries, in place (one run: no second kind beside EVA).
+        with jax.named_scope("eva/summarise"):
+            pooling = runs[0][1]
+            arenas = eva.close_windows(
+                *arenas[:2], tables, true_positions[:, 0],
+                positions[:, 0] < limits, pooling["eva_phi"],
+                pooling["eva_mu"], c) + arenas[2:]
     x = llama.norm(x, params["final_norm"], c)
     # lm_head in the params' storage dtype with fp32 accumulation
     # (shared with prefill): bf16 params are never upcast in HBM.
@@ -723,7 +768,8 @@ class _PagedPrefix(NamedTuple):
 def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                            quantized, last_idx, use_kernel=None,
                            state: Optional[StateCache] = None, slots=None,
-                           paged: Optional[_PagedPrefix] = None):
+                           paged: Optional[_PagedPrefix] = None,
+                           land=None):
     """Prefill forward over ``[shared prefix ++ suffix]``.
 
     ``tokens`` [N, S] are the suffix at absolute ``positions`` [S]
@@ -760,7 +806,12 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     when it is the prompt's first, and installs what it leaves there; and
     ``stored`` comes back as ``{cache kind: K/V of the layers that keep
     theirs there}`` (``"attention"``: the arena; ``"sliding_attention"``:
-    the ring)."""
+    the ring). An EVA-attention model's layers land their own blocks
+    in the arena through ``land [N, S / bs]`` as they go, the arena
+    riding the layer loop's carry (stacked as the loop's output, a
+    4-row chunk's raw keys of 8 layers would be a gigabyte that the
+    arena mostly never takes): the arena comes back in ``state``'s
+    place."""
     c = config
     cos, sin = _rope_tables(c, tokens.shape[1], positions)
     x = _embed(params, tokens, c)
@@ -798,6 +849,28 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                 paged.tables, paged.tables.shape[1] * paged.cache.block_size,
                 bool(use_kernel))
             kept = (row,)
+        elif kind == "eva_attention":
+            layer, = inputs
+            # A chunk is (at most) one window: causal over itself, dense
+            # over the earlier windows' summaries, which are all the
+            # arena holds of them; and it lands, for a row that fills
+            # the window, its own summaries and no raw key.
+            bs = paged.cache.block_size
+            with jax.named_scope("eva/qkv"):
+                q, k, v, _ = _layer_qkv(x, layer, cos, sin, c)
+            with jax.named_scope("eva/attend"):
+                o = paged_chunk_attention(
+                    q, k, v, *held, li + shift, paged.tables, 0,
+                    paged.tables.shape[1] * bs, scale)
+            with jax.named_scope("eva/summarise"):
+                blocks = eva.chunk_blocks(
+                    k, v, layer["eva_phi"], layer["eva_mu"],
+                    last_idx == c.eva_window - 1, c, bs)
+                held = tuple(
+                    a.at[li + shift, land.reshape(-1)].set(b.astype(a.dtype))
+                    for a, b in zip(held, blocks))
+            with jax.named_scope("eva/out_proj"):
+                mixed = _attn_out(o.astype(c.dtype), layer, c)
         elif paged is not None:
             layer, = inputs
             with _kind_scope(kind):
@@ -837,6 +910,8 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
         return (x, held, li + 1), kept
 
     stored, held = [], tuple(state or ())
+    if c.eva_window:
+        held = (paged.cache.k, paged.cache.v)
     by_kind: Dict[str, list] = {"attention": [], "sliding_attention": []}
     for (kind, start, count, kind_start), tree in runs:
         inputs = (tree,)
@@ -855,7 +930,10 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)  # [N, 1, E]
     x = llama.norm(x, params["final_norm"], c)
     logits = lm_head_logits(x, params, c)
-    held = StateCache(*held) if held else None
+    if c.eva_window:
+        held = PagedKVCache(*held)
+    else:
+        held = StateCache(*held) if held else None
     if paged is not None:
         return logits, {k: _concat_runs(v) for k, v in by_kind.items()}, held
     return logits, _concat_runs(stored), held
@@ -892,7 +970,10 @@ def _prefill_chunk_paged(params, tokens, positions, cache, second, ptables,
     logits, stored, state = _prefill_forward_paged(
         params, tokens, positions, None, None, config, False, last_idx,
         use_kernel, state, slots,
-        paged=_PagedPrefix(cache, ptables, ring, ring_tables))
+        paged=_PagedPrefix(cache, ptables, ring, ring_tables),
+        land=tables_w)
+    if config.eva_window:       # landed layer by layer: the arena itself
+        return logits, state, None
 
     def land(into, kv, tables):
         # (k, v) of an arena or a ring; the one plane of a latent cache.
@@ -1143,7 +1224,20 @@ class ContinuousBatcher:
         blocks beside the arena (``paged_kv.RingKVCache``), which holds
         the full-attention layers alone. Whatever needs K/V a ring has
         overwritten is refused by name (:data:`_KIND_CANNOT`): the
-        prefix cache, speculation, the KV handoff."""
+        prefix cache, speculation, the KV handoff.
+
+        EVA-ATTENTION LAYERS (``layer_types`` all "eva_attention",
+        ``models/eva.py``): the arena holds a slot's context under a
+        compressed position, ``eva_window / eva_chunk`` summaries for
+        each closed window and then the open window's raw keys, so the
+        paged kernels serve as they are. ``prefill_chunk`` is the window;
+        a chunk lands its summaries, the tick that fills a window pools
+        it in place, and the host allocates a slot's blocks as its open
+        window fills and frees a closed window's in the step that closed
+        it (:meth:`_eva_step_blocks`); admission promises each request
+        the most blocks it will ever hold (:func:`eva.blocks_peak`).
+        Whatever takes a block for the ``block_size`` tokens that filled
+        it is refused by name."""
         self.config = config
         self.device = device
         self.num_slots = num_slots
@@ -1179,6 +1273,11 @@ class ContinuousBatcher:
             # A chunk's blocks must be distinct entries of a ring.
             chunk = min(chunk, _bucket_floor(self.block_size * paged_kv.
                         ring_blocks(config.sliding_window, self.block_size)))
+        if config.eva_window:
+            # A chunk is a window: it attends the earlier ones through
+            # their summaries alone and leaves its own behind.
+            eva.check(config, self.block_size)
+            chunk = config.eva_window
         if chunk < self.block_size:
             raise ValueError(f"prefill_chunk {prefill_chunk} is under one "
                              f"block of {self.block_size}")
@@ -1290,7 +1389,10 @@ class ContinuousBatcher:
         # tick writes draft/verify K/V up to position p + spec_k, and
         # those writes must stay inside the slot's own reservation
         # (the garbage redirect is for overrun PAST it).
-        self.max_blocks = -(-(max_len + self.spec_k) // self.block_size)
+        self.max_blocks = (
+            eva.blocks_peak(max_len, config, self.block_size)
+            if config.eva_window
+            else -(-(max_len + self.spec_k) // self.block_size))
         self.num_blocks = int(
             num_blocks if num_blocks is not None
             else num_slots * self.max_blocks + 1)
@@ -1302,6 +1404,16 @@ class ContinuousBatcher:
         self.state_carries = 0          # chunks that started from a row
         self.allocator = BlockAllocator(self.num_blocks)
         self._slot_blocks: Dict[int, List[int]] = {}
+        # EVA attention: a slot's blocks are allocated as its open window
+        # fills and retired as it closes (``_slot_blocks`` is what it
+        # holds NOW); admission promises each slot the most it will ever
+        # hold (``_slot_peak``, summed in ``_promised``), so the arena
+        # never blocks a live slot. ``_tables_stale``: a table changed
+        # under unchanged membership.
+        self._slot_peak: Dict[int, int] = {}
+        self._promised = 0
+        self._tables_stale = False
+        self.eva_windows_closed = {"prefill": 0, "tick": 0}
         # Radix index over block-aligned prompt chunks -> resident
         # arena blocks (None with the prefix cache off). Slots track
         # their pinned index nodes so release can deref instead of
@@ -1394,6 +1506,8 @@ class ContinuousBatcher:
         sampling_cfg = self.sampling
         block_size_c = self.block_size
         chunks_state = "linear_attention" in cfg.layer_types
+        eva_sb = (eva.summaries(cfg) // self.block_size if cfg.eva_window
+                  else 0)     # blocks of summaries a closed window keeps
 
         # The XLA monitor dispatches per signature and audits shape
         # growth: prefill's signatures are pow-2 bucketed in N and L by
@@ -1419,7 +1533,7 @@ class ContinuousBatcher:
         if self.prefill_chunk:
             # A later chunk's prefix table: its matched blocks plus the
             # whole chunks before it.
-            per = self.prefill_chunk // self.block_size
+            per = eva_sb or self.prefill_chunk // self.block_size
             prefill_dims += tuple(
                 v + i * per for v in sorted(ms)
                 for i in range(1, self.max_blocks // per + 1)
@@ -1447,8 +1561,10 @@ class ContinuousBatcher:
             n, s_pad = tokens.shape
             m = ptables.shape[1]
             positions = m * block_size_c + jnp.arange(s_pad)
+            if eva_sb:      # true positions: the table's blocks are windows
+                positions = m // eva_sb * cfg.eva_window + jnp.arange(s_pad)
             if not cache.quantized and (
-                    isinstance(held, RingKVCache) or chunks_state
+                    isinstance(held, RingKVCache) or chunks_state or eva_sb
                     or isinstance(cache, LatentKVCache)
                     or (held is None
                         and m * block_size_c + s_pad > PREFILL_DENSE_KEYS)):
@@ -2026,6 +2142,7 @@ class ContinuousBatcher:
     def _release_slot(self, slot: int) -> None:
         self._free.append(slot)
         blocks = self._slot_blocks.pop(slot, None)
+        self._promised -= self._slot_peak.pop(slot, 0)
         nodes = self._slot_nodes.pop(slot, None)
         if nodes:
             # Indexed (shared/shareable) blocks: deref — refcount 0
@@ -2096,6 +2213,8 @@ class ContinuousBatcher:
         self.state = self._new_state()
         self.allocator.reset()
         self._slot_blocks.clear()
+        self._slot_peak.clear()
+        self._promised = 0
         self._slot_nodes.clear()
         if self._prefix is not None:
             # The rebuilt arena holds zeros: every cached prefix
@@ -2423,6 +2542,8 @@ class ContinuousBatcher:
         a sliding-window layer."""
         bs, w = self.block_size, self.config.sliding_window
         pos = [st["pos"] for st in self._slots.values()]
+        if self.config.eva_window:      # where the arena holds that key
+            pos = [eva.compressed(p, self.config) for p in pos]
         table = [p // bs + 1 for p in pos]
         if self._ring is None:
             return table, []
@@ -2461,6 +2582,18 @@ class ContinuousBatcher:
         if self.config.state_layers:
             mdefs.CB_STATE_LIVE_SLOTS.observe(len(self._slots),
                                               tags=self._mtags)
+        if self.config.eva_window:
+            # The keys the tick's queries attended, summed over the
+            # slots, by what they are: summaries of closed windows, raw
+            # keys of the open one (the query's own included).
+            c = self.config
+            closed = sum(st["pos"] // c.eva_window
+                         for st in self._slots.values())
+            mdefs.CB_EVA_SUMMARY_KEYS.observe(
+                closed * eva.summaries(c), tags=self._mtags)
+            mdefs.CB_EVA_WINDOW_KEYS.observe(
+                sum(st["pos"] % c.eva_window + 1
+                    for st in self._slots.values()), tags=self._mtags)
         if self.config.latent_layers:
             # The positions the tick's queries attended, summed over the
             # slots: what the latent kernel must read a layer, in tokens.
@@ -2548,11 +2681,17 @@ class ContinuousBatcher:
         # length: rejected draft/verify writes must land inside the
         # slot's own reservation, never a neighbor's block — reserved at
         # admission, all-or-nothing, so free counts stay honest.
+        if self.config.eva_window:
+            # The COMPRESSED worst case: closed windows are summaries.
+            return eva.blocks_peak(prompt_len + max_new, self.config,
+                                   self.block_size)
         return -(-(prompt_len + max_new + self.spec_k)
                  // self.block_size)
 
     def _lookahead_blocks(self, prompt_len: int, max_new: int) -> int:
         """Blocks of a reservation attributable to spec look-ahead."""
+        if not self.spec_k:
+            return 0
         return (self._blocks_needed(prompt_len, max_new)
                 - -(-(prompt_len + max_new) // self.block_size))
 
@@ -2566,6 +2705,10 @@ class ContinuousBatcher:
         req = self._waiting[0]
         need = self._blocks_needed(len(req["prompt"]), req["max_new"])
         avail = self.allocator.free_count
+        if self.config.eva_window:
+            # Against what is not promised yet, not what is free now: a
+            # live slot's open window has blocks still to come.
+            avail = self.num_blocks - 1 - self._promised
         if self._prefix is not None:
             nodes = self._prefix.match_nodes(
                 self._req_chunks(req)[:self._match_cap(req)])
@@ -2845,6 +2988,7 @@ class ContinuousBatcher:
         # allocated, so prefill cost and arena demand both scale with
         # novel tokens.
         bs = self.block_size
+        eva_c = self.config if self.config.eva_window else None
         self._restart_owed = []   # by the batches of THIS admission
         groups: Dict[tuple, List] = {}
         draft_pending: List = []   # (slot, prompt) for the ext. drafter
@@ -2869,7 +3013,14 @@ class ContinuousBatcher:
                     matched = matched[:m]
             need = self._blocks_needed(len(req["prompt"]),
                                        req["max_new"]) - m
-            got = self._alloc_blocks(need)
+            if eva_c:
+                # Promise the peak, take what the prompt leaves behind.
+                peak, need = need, eva.blocks_held(len(req["prompt"]),
+                                                   eva_c, bs)
+                got = (self._alloc_blocks(need) if peak <= self.num_blocks
+                       - 1 - self._promised else None)
+            else:
+                got = self._alloc_blocks(need)
             if got is None:
                 # Head blocked on arena space with a slot free: from
                 # here until admission the wait is ARENA wait, not
@@ -2897,6 +3048,9 @@ class ContinuousBatcher:
                 meta["prefix_tokens"] = m * bs
             slot = self._free.pop()
             self._slot_blocks[slot] = blocks
+            if eva_c:
+                self._slot_peak[slot] = peak
+                self._promised += peak
             groups.setdefault(
                 self._group(len(req["prompt"]), m), []).append(
                 (req, slot, blocks, matched, suffix, chunks))
@@ -2911,6 +3065,9 @@ class ContinuousBatcher:
             # (Duplicated prefix gathers are reads — trivially safe.)
             n_pad = min(_bucket(n, floor=1), self.num_slots)
             npb_w = padded_len // bs
+            # Table entries a chunk leaves behind it: its blocks, or, of
+            # an EVA window, its summaries'.
+            npb_c = eva.summaries(eva_c) // bs if eva_c else npb_w
             rows = [group[min(i, n - 1)] for i in range(n_pad)]
             live_before = len(self._slots)  # streams that stand still now
             pt0 = time.time()  # wall-clock anchor for the prefill span
@@ -2924,7 +3081,7 @@ class ContinuousBatcher:
                     # length is one), back to back: chunk i reads what
                     # the chunks before it wrote through the arena.
                     for ci in range(n_chunks):
-                        mc = m + ci * npb_w
+                        mc = m + ci * npb_c
                         tokens = np.zeros((n_pad, padded_len), np.int32)
                         last_idx = np.zeros(n_pad, np.int32)
                         tables_w = np.full((n_pad, npb_w), GARBAGE_BLOCK,
@@ -2942,6 +3099,8 @@ class ContinuousBatcher:
                             # reservation writes masked garbage to block 0.
                             new_blocks = blocks[mc:]
                             k = min(len(new_blocks), npb_w)
+                            if eva_c and len(part) == eva_c.eva_window:
+                                k = npb_c       # a whole window: summaries
                             tables_w[i, :k] = new_blocks[:k]
                             ptables[i, :mc] = blocks[:mc]
                         pstep = self._place(np.int32(self._prefill_count))
@@ -3016,6 +3175,11 @@ class ContinuousBatcher:
             mdefs.CB_PREFILL_PADDED_ROWS.inc(n_pad, tags=self._mtags)
             mdefs.CB_PREFILL_PADDED_TOKENS.inc(
                 n_pad * padded_len * n_chunks, tags=self._mtags)
+            if eva_c:
+                closed = sum(len(row[4]) // eva_c.eva_window for row in group)
+                self.eva_windows_closed["prefill"] += closed
+                mdefs.CB_EVA_WINDOWS_CLOSED.inc(
+                    closed, tags=dict(self._mtags, phase="prefill"))
             if self.config.state_layers:
                 self.state_installs += n
                 mdefs.CB_STATE_INSTALLS.inc(n, tags=self._mtags)
@@ -3137,7 +3301,9 @@ class ContinuousBatcher:
         """Bring the device's decode state up to date for a tick over
         ``members``: whatever the host knows
         a tick ahead is rebuilt from its books. Blocks are reserved at
-        admission, so tables and limits change only with membership; a
+        admission, so tables and limits change only with membership (an
+        EVA-attention model's also as windows fill and close:
+        :meth:`_upload_tables`); a
         slot's position is ``pos`` plus the ticks in flight that advance
         it; a row left out (freed, or finishing in flight) gets limit 0
         and the garbage table row, so the tick neither visits nor writes
@@ -3152,17 +3318,12 @@ class ContinuousBatcher:
             tokens = np.zeros(self.num_slots, np.int32)
             fresh = np.zeros(self.num_slots, bool)
             positions = np.zeros(self.num_slots, np.int32)
-            tables = np.zeros((self.num_slots, self.max_blocks), np.int32)
-            limits = np.zeros(self.num_slots, np.int32)
             for slot, rid in members:
                 st = self._slots[slot]
                 running = ahead.get((slot, rid), 0)
                 fresh[slot] = not running
                 tokens[slot] = st["last"]
                 positions[slot] = st["pos"] + running
-                blocks = self._slot_blocks[slot]
-                tables[slot] = self._table_row(blocks)
-                limits[slot] = len(blocks) * self.block_size
             tokens = self._place(tokens)
             self._d_tokens = self._merge_tokens(
                 self._place(fresh), tokens,
@@ -3172,10 +3333,59 @@ class ContinuousBatcher:
             # plus the ticks in flight.
             self._d_step = self._place(
                 np.int32(self._applied_steps + len(self._inflight)))
-            self._d_tables = self._place(tables)
-            self._d_limits = self._place(limits)
+            self._upload_tables(members)
             self._d_members = members
             self._dirty = False
+
+    def _upload_tables(self, members: List[tuple]) -> None:
+        """The device's block tables and limits from the host's books:
+        with the membership and, for a model whose slots' blocks come
+        and go while they decode (EVA attention), whenever one did."""
+        tables = np.zeros((self.num_slots, self.max_blocks), np.int32)
+        limits = np.zeros(self.num_slots, np.int32)
+        for slot, _ in members:
+            blocks = self._slot_blocks[slot]
+            tables[slot] = self._table_row(blocks)
+            limits[slot] = len(blocks) * self.block_size
+        self._d_tables = self._place(tables)
+        self._d_limits = self._place(limits)
+        self._tables_stale = False
+
+    def _eva_step_blocks(self, members: List[tuple], before: bool) -> None:
+        """The host's half of the tick about to be dispatched over
+        ``members`` (``before``) or just dispatched: a slot's next key
+        lies at a position the host knows (its booked position plus the
+        ticks in flight), so where that key opens a block the block is
+        allocated first, out of what admission promised the slot, and
+        where it fills the slot's window the tick pools the window and
+        its blocks, all but the summaries', go back to the allocator
+        here, in the same step (the device runs programs in order: no
+        later one can write them before this tick has read them)."""
+        from ray_tpu._private import metrics_defs as mdefs
+
+        c, bs = self.config, self.block_size
+        ahead = self._ahead()
+        closed = retired = 0
+        for slot, rid in members:
+            # ``before``: this tick is not in flight yet; after: it is.
+            written = (self._slots[slot]["pos"] + ahead.get((slot, rid), 0)
+                       + before)
+            blocks = self._slot_blocks[slot]
+            held = eva.blocks_held(written, c, bs)
+            if before and held > len(blocks):
+                blocks += self.allocator.alloc(held - len(blocks))
+                self._tables_stale = True
+            elif not before and written % c.eva_window == 0:
+                self.allocator.free(blocks[held:])
+                closed += 1
+                retired += len(blocks) - held
+                del blocks[held:]
+                self._tables_stale = True
+        if closed:
+            self.eva_windows_closed["tick"] += closed
+            mdefs.CB_EVA_WINDOWS_CLOSED.inc(
+                closed, tags=dict(self._mtags, phase="tick"))
+            mdefs.CB_EVA_BLOCKS_RETIRED.inc(retired, tags=self._mtags)
 
     def _run_tick(self):
         """Dispatch one decode tick. Returns the device row to fetch:
@@ -3452,6 +3662,17 @@ class ContinuousBatcher:
         if self.config.state_layers:
             mdefs.CB_STATE_CACHE_BYTES.set(self.state.nbytes,
                                            tags=self._mtags)
+        if self.config.eva_window:
+            # What the live slots hold, and what their contexts would
+            # hold with every key kept.
+            block_bytes = self.block_size * self.cache.token_bytes()
+            mdefs.CB_EVA_CACHE_BYTES.set(
+                block_bytes * sum(map(len, self._slot_blocks.values())),
+                tags=self._mtags)
+            mdefs.CB_EVA_UNCOMPRESSED_BYTES.set(
+                block_bytes * sum(-(-st["pos"] // self.block_size)
+                                  for st in self._slots.values()),
+                tags=self._mtags)
         if self.config.latent_layers:
             mdefs.CB_LATENT_KV_BYTES.set(self.cache.nbytes, tags=self._mtags)
         if self._ring is not None:
@@ -3468,8 +3689,12 @@ class ContinuousBatcher:
         its row's copy to the host; it joins the ticks in flight."""
         from ray_tpu._private import metrics_defs as mdefs
 
+        if self.config.eva_window:
+            self._eva_step_blocks(members, before=True)
         if self._dirty or members != self._d_members:
             self._upload_state(members)
+        elif self._tables_stale:
+            self._upload_tables(members)
         w0 = time.time() if self._traced_live else None
         t0 = time.perf_counter()
         if self._empty_since is not None:
@@ -3498,6 +3723,8 @@ class ContinuousBatcher:
                                "w0": w0, "wall": None,
                                "hold": self._held(),
                                "held": len(self._free)})
+        if self.config.eva_window:
+            self._eva_step_blocks(members, before=False)
 
     def _device_empty(self, now: float, after: str) -> None:
         """A landing at ``now`` (``after``: "tick" or "prefill") left

@@ -136,6 +136,10 @@ def lm_head_logits(x, params, config: llama.LlamaConfig):
                   else ("bse,ev->bsv", params["lm_head"]))
     logits = jnp.einsum(spec, x.astype(c.dtype), head.astype(c.dtype),
                         preferred_element_type=jnp.float32)
+    if c.num_pred_heads > 1:
+        # The head is [E, heads x V], head j for the token j + 1 ahead:
+        # head 0, the next token's, is the one a decode step samples.
+        logits = logits[..., :c.vocab_size]
     return logits if c.logits_scaling == 1.0 else logits / c.logits_scaling
 
 

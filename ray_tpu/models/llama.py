@@ -173,6 +173,21 @@ class LlamaConfig:
     linear_conv_kernel_dim: int = 4
     partial_rotary_factor: float = 1.0
     zero_centered_norms: bool = False
+    # The EvaByte family (``attention_class`` "eva"; SERVING ONLY): every
+    # layer is "eva_attention" (``models/eva.py``): a query sees the raw
+    # keys of its own block of ``eva_window`` positions, causally, and
+    # every EARLIER window only through one learned summary for each
+    # ``eva_chunk`` of its keys (``eva_phi``, ``eva_mu`` ``[KVH, D]`` a
+    # layer, float32). The arena holds both, under a compressed position
+    # (:func:`eva.compressed`): a closed window's blocks are rewritten as
+    # its summaries. ``num_pred_heads``: the head is ``[E, heads x V]``,
+    # head j for the token j + 1 ahead; the engine samples from head 0.
+    # ``fp32_residual`` (``fp32_skip_add``): the
+    # engine keeps the stream between sublayers in float32.
+    eva_window: int = 0
+    eva_chunk: int = 0
+    num_pred_heads: int = 1
+    fp32_residual: bool = False
 
     @property
     def rotary_dim(self) -> int:
@@ -344,6 +359,21 @@ class LlamaConfig:
             shared_intermediate_size=512, shared_expert_gate=True), **kw})
 
     @staticmethod
+    def evabyte_6_5b(**kw) -> "LlamaConfig":
+        """EvaByte/EvaByte (6.5B, tokenizer-free): 32 LLaMA layers of
+        hidden 4096, MHA 32/32 of 128, SwiGLU 11008, RMSNorm with a unit
+        offset, rope theta 1e5; every layer EVA attention over windows of
+        2048 bytes with one summary for 16; 320 byte ids, 8 prediction
+        heads."""
+        return LlamaConfig(**{**dict(
+            vocab_size=320, hidden_size=4096, intermediate_size=11008,
+            num_layers=32, num_heads=32, num_kv_heads=32, head_dim=128,
+            max_seq_len=32768, rope_theta=1e5, rms_eps=1e-5,
+            layer_types=("eva_attention",) * kw.get("num_layers", 32),
+            zero_centered_norms=True, eva_window=2048, eva_chunk=16,
+            num_pred_heads=8, fp32_residual=True), **kw})
+
+    @staticmethod
     def tiny(**kw) -> "LlamaConfig":
         """CPU-runnable config for tests (BASELINE.md config #1 analog)."""
         kw.setdefault("vocab_size", 256)
@@ -377,6 +407,9 @@ def logical_axes(config: LlamaConfig) -> Params:
         "w_up": ("layers", "embed", "mlp"),
         "w_down": ("layers", "mlp", "embed"),
     }
+    if "eva_attention" in config.layer_types:
+        layer["eva_phi"] = ("layers", "kv_heads", "head_dim")
+        layer["eva_mu"] = ("layers", "kv_heads", "head_dim")
     if config.qk_norm:
         layer["q_norm"] = ("layers", None)
         layer["k_norm"] = ("layers", None)
@@ -577,6 +610,10 @@ def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
         k = jax.random.split(jax.random.fold_in(k_runs, r), 20)
         tree = {"attn_norm": norm(k[0], n, E), "mlp_norm": norm(k[1], n, E)}
         attention = kind != "linear_attention"
+        if kind == "eva_attention":
+            from ray_tpu.models import eva
+
+            tree.update(eva.init_pooling(c, jax.random.fold_in(k[2], 7), n))
         if c.latent_layers:
             from ray_tpu.models import mla
 
@@ -622,7 +659,7 @@ def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
         "embed": (jax.random.normal(k_embed, (c.vocab_size, E), jnp.float32)
                   / c.embedding_multiplier).astype(c.dtype),
         "final_norm": norm(jax.random.fold_in(k_head, 1), E),
-        "lm_head": dense(k_head, E, E, c.vocab_size),
+        "lm_head": dense(k_head, E, E, c.num_pred_heads * c.vocab_size),
         "layers": {},
         "runs": runs,
     }
@@ -686,12 +723,15 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
                num_layers: Optional[int] = None):
     """The layer stack as RUNS of equal layers, in order: a list of
     ``(kind, start, count, kind_start)`` where ``kind`` is "attention",
-    "mamba", "linear_attention", "sliding_attention", "full_attention" or
-    "latent_attention", ``start`` the run's first GLOBAL layer and
-    ``kind_start`` its first index among layers that share its cache
-    (the K/V arena's layer for attention and full attention, the state
-    cache's for mamba and for linear attention, the ring's for sliding
-    attention, the latent cache's for latent attention). A model without ``layer_types`` is one attention run.
+    "mamba", "linear_attention", "sliding_attention", "full_attention",
+    "latent_attention" or "eva_attention", ``start`` the run's first
+    GLOBAL layer and ``kind_start`` its first index among layers that
+    share its cache (the K/V arena's layer for attention and full
+    attention, the state cache's for mamba and for linear attention, the
+    ring's for sliding attention, the latent cache's for latent
+    attention, the arena's again for EVA attention, which no other kind
+    shares it with). A model without ``layer_types`` is one attention
+    run.
 
     With ``params``: ``(runs, experts)``, each run followed by the tree
     a ``lax.scan`` over its layers takes (``params["runs"][i]``; for a
@@ -703,7 +743,8 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
         raise ValueError(f"layer_types names {len(types)} layers, "
                          f"num_layers is {c.num_layers}")
     runs, seen = [], {"attention": 0, "mamba": 0, "sliding_attention": 0,
-                      "latent_attention": 0, "linear_attention": 0}
+                      "latent_attention": 0, "linear_attention": 0,
+                      "eva_attention": 0}
     for i, kind in enumerate(types):
         # "full_attention" keeps all its K/V in the arena, as "attention"
         # does: they count as one kind of cache.
@@ -994,9 +1035,8 @@ def forward(
     if c.layer_types:
         raise NotImplementedError(
             "a config with layer_types (state-space, linear-attention, "
-            "sliding-window or latent-attention layers) is served by the "
-            "continuous-batching "
-            "engine only: "
+            "sliding-window, latent-attention or eva-attention layers) is "
+            "served by the continuous-batching engine only: "
             "llama.forward, loss_fn and LlamaGenerator do not run it")
     seq_len = tokens.shape[1]
     cos, sin = rope_frequencies(c.head_dim, seq_len, c.rope_theta)

@@ -54,6 +54,9 @@ CONFIGS = {
         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
         dense_intermediate_size=96, num_experts=16, num_experts_per_tok=4,
         experts_held=(4, 4), shared_intermediate_size=32),
+    "eva_attention": lambda: llama.LlamaConfig.evabyte_6_5b(
+        **_SMALL, num_layers=2, num_kv_heads=4, head_dim=16, eva_window=32,
+        eva_chunk=4, num_pred_heads=2),
 }
 
 # How a caller asks the constructor for a capability, and what the
@@ -69,7 +72,8 @@ ASKED_OF_THE_CONSTRUCTOR = {
 A_SECOND_KIND = {
     "mamba": "linear_attention", "linear_attention": "sliding_attention",
     "sliding_attention": "latent_attention",
-    "latent_attention": "full_attention"}
+    "latent_attention": "full_attention",
+    "eva_attention": "full_attention"}
 # The methods that offer a capability on a live engine.
 ASKED_OF_A_METHOD = {
     "handoff": [("export_kv_payload", (0,)), ("import_kv_payload", ({},)),
@@ -97,7 +101,8 @@ def _names(err, kind, capability, called):
 
 def test_the_table_covers_the_kinds_the_engine_keeps_a_cache_for():
     assert set(_KIND_CANNOT) == set(_KIND_NAMES) == set(CONFIGS) == {
-        *llama.STATE_KINDS, "sliding_attention", "latent_attention"}
+        *llama.STATE_KINDS, "sliding_attention", "latent_attention",
+        "eva_attention"}
 
 
 @pytest.mark.parametrize("kind,capability", [

@@ -865,3 +865,88 @@ def test_compiled_linear_tick_holds_no_copy_of_the_state_cache(v5e_chip):
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 32 << 20
     assert memory.alias_size_in_bytes >= state.ssm.size * 4
+
+
+def _eva_cell(v5e_chip):
+    """The cell ``serve_eva_decode``'s sizes as shapes on a described
+    v5e: (config, params, arena, table width)."""
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models import eva
+    from ray_tpu.models.paged_kv import PagedKVCache
+
+    cfg = llama.LlamaConfig.evabyte_6_5b(num_layers=8, max_seq_len=16384)
+
+    def spec(a):
+        return S(a.shape, a.dtype, sharding=v5e_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        functools.partial(cb.init_engine_params, cfg),
+        jax.random.PRNGKey(0)))
+    cache = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        PagedKVCache.create, cfg, 32 * 42 + 1, 64)))
+    assert cache.k.shape == (8, 1345, 32, 64, 128)
+    return cfg, params, cache, eva.blocks_peak(16384, cfg, 64)
+
+
+def test_compiled_eva_tick_rewrites_the_arena_in_place(v5e_chip):
+    """The cell ``serve_eva_decode``'s tick for a described v5e at the
+    cell's own sizes (EvaByte's widths, 8 layers, 32 slots over 1,345
+    blocks of 8.4 MB): the layer loop touches the arena through
+    ``paged_kv_write`` and ``paged_decode_attn`` alone, unchanged; the
+    compression behind it is one more loop in the SAME program (a trip
+    a row that closes a window), which holds one window's blocks at a
+    time (0.4 GB of scratch beside 14.5 GB of arguments: no copy of the
+    11.3 GB arena, which is aliased in to out)."""
+    from ray_tpu.models import continuous_batching as cb
+
+    cfg, params, cache, width = _eva_cell(v5e_chip)
+    assert width == 2 * 7 + 32
+    row = S((32,), jnp.int32, sharding=v5e_chip)
+    tables = S((32, width), jnp.int32, sharding=v5e_chip)
+    step = S((), jnp.int32, sharding=v5e_chip)
+    tick = functools.partial(cb._decode_tick_paged, config=cfg,
+                             use_kernel=True)
+    compiled = jax.jit(tick, donate_argnums=(5,)).lower(
+        params, row, row, tables, row, cache, step).compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 1
+    assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == 2
+    # Two loops carry the tables: the layer loop, with the hidden state,
+    # and the loop over the rows that close, without it.
+    tabled = [line.split(" while(")[0] for line in hlo.splitlines()
+              if " while(" in line and "s32[32,46]" in line.split(" while(")[0]]
+    assert sorted("f32[32,1,4096]" in loop for loop in tabled) == [False,
+                                                                   True]
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 512 << 20
+    assert memory.alias_size_in_bytes >= 2 * cache.k.size * 2
+
+
+@pytest.mark.parametrize("earlier", [0, 5])
+def test_compiled_eva_prefill_chunk_lands_blocks_in_the_layer_loop(
+        v5e_chip, earlier):
+    """The cell's worst prefill chunk (4 rows x one 2048-byte window,
+    over 0 and over 5 earlier windows' summaries): each layer lands its
+    own blocks in the arena it carries, so the program needs under 1 GB
+    of scratch (2.6 GB when the layers' raw keys were stacked as the
+    loop's output) beside the arena, which is aliased in to out."""
+    from ray_tpu.models import continuous_batching as cb
+
+    cfg, params, cache, _ = _eva_cell(v5e_chip)
+
+    def prefill(params, tokens, cache, ptables, tables_w, last_idx):
+        positions = earlier * cfg.eva_window + jnp.arange(tokens.shape[1])
+        logits, cache, _ = cb._prefill_chunk_paged(
+            params, tokens, positions, cache, None, ptables, tables_w,
+            last_idx, None, cfg, True)
+        return logits, cache
+
+    def ints(*shape):
+        return S(shape, jnp.int32, sharding=v5e_chip)
+
+    compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
+        params, ints(4, 2048), cache, ints(4, 2 * earlier), ints(4, 32),
+        ints(4)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1 << 30
+    assert memory.alias_size_in_bytes >= 2 * cache.k.size * 2
