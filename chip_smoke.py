@@ -45,7 +45,10 @@ seed), and checks what comes out by the repo's own means:
                 reference under the configuration file's two limits;
                 the program as published passes, eleven faults (state
                 or conv tail not carried between chunks among them)
-                each fail, and a bf16 recurrent state is read;
+                each fail, and a bf16 recurrent state is read; before
+                them the prefill's ``gdn_chunk_scan`` kernel against the
+                ``jax.numpy`` scan at the published shape, its solve
+                against a float64 one, and both forms' time a layer;
 * ``eva``       the cell ``serve_eva_decode``'s comparison at its own
                 sizes (EvaByte's stage at the published widths, eight
                 layers, through ``ContinuousBatcher``): check prompts
@@ -120,7 +123,11 @@ REHEARSAL_BANNER = (
 # forward. The chip run prints what was measured next to each bound.
 TOLERANCE = {"flash_fwd": 1e-2, "flash_bwd": 2e-2,
              "decode_bf16": 1e-2, "decode_int8": 1e-2, "moe_gmm": 1e-2,
-             "latent_decode": 1e-2, "gdn_step": 1e-5}
+             "latent_decode": 1e-2, "gdn_step": 1e-5,
+             # Kernel and jax.numpy scan round the same bf16 operands; a
+             # float32 last bit (another exp, another order of addition)
+             # flips one rounding now and then: one bf16 ulp, 2^-8.
+             "gdn_scan": 2.0 ** -8, "gdn_scan_solve": 2e-5}
 # fsdp=4 vs one-chip first-step loss: the same bf16 model, sums reduced
 # across four devices in another order.
 LOSS_RTOL = 5e-3
@@ -667,6 +674,102 @@ def _gdn_kernel(phase: str, device_kind: str, rehearse: bool) -> None:
                     f"and the rows = {flops_gdn.step_bytes(shape, slots) / 1e6:.1f}"
                     f" MB / {hbm / 1e9:.0f} GB/s = {least:.0f} us "
                     f"({100 * least / us:.0f}% of the roofline)")
+
+
+def _gdn_scan_kernel(phase: str, rehearse: bool) -> None:
+    """The prefill's ``gdn_chunk_scan`` kernel at Qwen3-Next's head shape
+    (32 value heads over 16 key heads, ``[128, 128]``), bf16 operands, a
+    carried state, 1 and 2 rows x 1024: outputs and final states against
+    the ``jax.numpy`` scan. Its solve alone (a Mosaic kernel around
+    ``_solve_in_place``: float32 products INSIDE a kernel, which the
+    interpreter cannot show rounded to one bf16 pass) against a float64
+    solve, on random systems and on one whose keys are alike. Then both
+    forms' milliseconds a layer at 1, 2, 4 and 8 rows, on the HOST's
+    clock over 20 dispatches: each holds a dispatch's overhead (0.2-1
+    ms), so the kernel's device time is a trace's to give
+    (``gdn_scan_time_share``, PERF.md section 5)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import pallas as pl
+
+    from ray_tpu.models import gated_delta as mixer
+    from ray_tpu.ops import gated_delta as gdn
+    from ray_tpu.ops.dispatch import interpret_default
+
+    hk, h, s, qn = (2, 4, 128, 64) if rehearse else (16, 32, 1024, 64)
+    dk = dv = 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    assert gdn.gdn_scan_applicable(h, hk, dk, dv, qn)
+
+    def inputs(rows):
+        k = jax.random.split(jax.random.fold_in(jax.random.PRNGKey(44),
+                                                rows), 6)
+        q = mixer._l2norm(jax.random.normal(k[0], (rows, s, hk, dk)
+                                            ).astype(bf16))
+        kk = mixer._l2norm(jax.random.normal(k[1], (rows, s, hk, dk)
+                                             ).astype(bf16))
+        v = jax.random.normal(k[2], (rows, s, h, dv)).astype(bf16)
+        # A token's decay in 0.2..0.999, as the seeded mixer's.
+        g = jnp.log(jax.random.uniform(k[3], (rows, s, h), f32, 0.2, 0.999))
+        beta = jax.nn.sigmoid(jax.random.normal(k[4], (rows, s, h)))
+        return (q * dk ** -0.5, kk, v, g, beta,
+                jax.random.normal(k[5], (rows, h, dk, dv)))
+
+    scan = {kernel: jax.jit(lambda *a, kernel=kernel: gdn.gdn_chunked_scan(
+        *a, chunk=qn, dtype=bf16, use_kernel=kernel))
+        for kernel in (True, False)}
+
+    def solve(ls, rs):
+        def kernel(l_ref, r_ref, x_ref):
+            sides = [gdn._row_tiles(r_ref[...])]
+            gdn._solve_in_place([gdn._row_tiles(l_ref[...])], sides)
+            x_ref[...] = jnp.concatenate(sides[0], axis=0)
+        return pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct(rs.shape, f32),
+            interpret=interpret_default())(ls, rs)
+
+    k = jax.random.split(jax.random.PRNGKey(45), 2)
+    rhs = jax.random.normal(k[1], (qn, dv + dk), f32)
+    for name, strict in (
+            ("random", jnp.tril(jax.random.normal(k[0], (qn, qn), f32), -1)),
+            ("keys alike", 0.9 * jnp.tril(jnp.ones((qn, qn), f32), -1))):
+        want = np.linalg.solve(
+            np.asarray(strict, np.float64) + np.eye(qn),
+            np.asarray(rhs, np.float64)).astype(np.float32)
+        _check(phase, f"gdn_chunk_scan's solve, {name} [{qn}, {qn}] system "
+                      f"x [{qn}, {dv + dk}]", solve(strict, rhs),
+               jnp.asarray(want), TOLERANCE["gdn_scan_solve"])
+    for rows in ((1,) if rehearse else (1, 2, 4, 8)):
+        args = inputs(rows)
+        tag = (f"gdn_chunk_scan {rows} row(s) x {s} x {h} heads over {hk} "
+               f"x [{dk}, {dv}], bf16 operands, carried state")
+        if not rehearse:
+            assert _mosaic_calls(scan[True].lower(*args).compile()) == 1
+        outs = {kernel: scan[kernel](*args) for kernel in (True, False)}
+        if rows <= 2:
+            _check(phase, tag + ", outputs", outs[True][0], outs[False][0],
+                   TOLERANCE["gdn_scan"])
+            _check(phase, tag + ", states", outs[True][1], outs[False][1],
+                   TOLERANCE["gdn_scan"])
+        if rehearse:
+            continue
+        ms = {}
+        for kernel, fn in scan.items():
+            reps = 20
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            ms[kernel] = (time.perf_counter() - t0) / reps * 1e3
+        units = rows * h * (s // qn)
+        _say(phase, f"{tag}, host clock over 20 dispatches: kernel "
+                    f"{ms[True]:.3f} ms a layer "
+                    f"({ms[True] * 1e3 / units:.2f} us a head a chunk), "
+                    f"jax.numpy scan {ms[False]:.3f} ms "
+                    f"({ms[False] * 1e3 / units:.2f}): the kernel takes "
+                    f"{100 * (1 - ms[True] / ms[False]):.0f}% less")
 
 
 def phase_kernels(rehearse: bool) -> None:
@@ -1460,12 +1563,14 @@ def phase_linear(rehearse: bool) -> None:
     the runner's own ``hold_to_reference`` under the configuration file's
     limits.
 
-    First the program as published, which has to pass. Then one fault at
-    a time, each of which has to FAIL one of the two limits (ISSUE 38,
-    Tentpole 5b). A bfloat16 recurrent state is read as well and held to
-    nothing."""
+    First the prefill's scan kernel against the ``jax.numpy`` scan
+    (:func:`_gdn_scan_kernel`). Then the program as published, which has
+    to pass. Then one fault at a time, each of which has to FAIL one of
+    the two limits (ISSUE 38, Tentpole 5b). A bfloat16 recurrent state is
+    read as well and held to nothing."""
     phase = "linear"
     info = _open_device(phase, rehearse)
+    _gdn_scan_kernel(phase, rehearse)
     import dataclasses
     import gc
 

@@ -116,16 +116,14 @@ def _l2norm(x):
 
 
 def _heads(qkv, c):
-    """Convolved q|k|v [..., conv_dim] -> q, k [..., Hv, Dk] float32
-    (L2-normalised, each key head repeated for its value heads, q
-    scaled), v [..., Hv, Dv]."""
+    """Convolved q|k|v [..., conv_dim] -> q, k [..., Hk, Dk] float32 a
+    KEY head (L2-normalised, q scaled), v [..., Hv, Dv]."""
     key_dim, _, _ = dims(c)
     lead = qkv.shape[:-1]
     hk, dk = c.linear_num_key_heads, c.linear_key_head_dim
-    share = c.linear_num_value_heads // hk
 
     def keyed(x):
-        return jnp.repeat(_l2norm(x.reshape(*lead, hk, dk)), share, axis=-2)
+        return _l2norm(x.reshape(*lead, hk, dk))
 
     return (keyed(qkv[..., :key_dim]) * dk ** -0.5,
             keyed(qkv[..., key_dim:2 * key_dim]),
@@ -212,8 +210,12 @@ def mixer_step(h, layer, c, state_all, conv_all, index, use_kernel=None):
         conv_all = jax.lax.dynamic_update_index_in_dim(
             conv_all, tail, index, 0)
     g, beta = _gates(b[:, 0], a[:, 0], layer)
+    q, k, v = _heads(qkv, c)
+    share = c.linear_num_value_heads // c.linear_num_key_heads
     with jax.named_scope("gdn/delta_rule"):
+        # The tick's kernel reads q and k a VALUE head.
         o, state_all = gated_delta.gdn_step(
-            state_all, index, *_heads(qkv, c), g, beta,
+            state_all, index, jnp.repeat(q, share, axis=-2),
+            jnp.repeat(k, share, axis=-2), v, g, beta,
             use_kernel=use_kernel)
     return _gate_out(o[:, None], z, layer, c), state_all, conv_all

@@ -19,15 +19,32 @@ the write READS the decayed state first, so the tick's kernel cannot be
 ``ssm_step``: a head's tile is decayed, contracted with ``k``, updated
 and contracted with ``q`` inside one visit (one HBM read, one write).
 
-:func:`gdn_chunked_scan` (prefill) is the chunkwise form in
-``jax.numpy`` (``torch_chunk_gated_delta_rule``): inside a chunk of ``Q``
-positions the ``u`` of every position come out of ONE unit-lower-
-triangular solve (the WY representation), the outputs are masked
-``[Q, Q]`` products, and the state crosses chunks by a ``lax.scan``
-whose carry starts at ``state``: an engine chunk that is not a prompt's
-first starts from what the one before it left. ``g = 0`` and ``beta =
-0`` at a position make it the identity, which is how a right-padded row
-keeps the state of its last real token.
+:func:`gdn_chunked_scan` (prefill) is the chunkwise form
+(``torch_chunk_gated_delta_rule``): inside a chunk of ``Q`` positions
+the ``u`` of every position come out of ONE unit-lower-triangular solve
+(the WY representation), the outputs are masked ``[Q, Q]`` products, and
+the state crosses chunks from a carry that starts at ``state``: an
+engine chunk that is not a prompt's first starts from what the one
+before it left. ``g = 0`` and ``beta = 0`` at a position make it the
+identity, which is how a right-padded row keeps the state of its last
+real token. It has two bodies with the same rounding points (the large
+products take operands in the model's dtype and accumulate in float32;
+decays, the solve and the carry are float32):
+
+* the kernel (``name="gdn_chunk_scan"``, PR 44), taken on the TPU where
+  :func:`gdn_scan_applicable` holds: grid (rows, blocks of 4 value
+  heads, chunks), the chunk axis sequential; a head's state stays in
+  VMEM for the row's chunks (the output block, loaded at chunk 0 and
+  written back after the last); q and k are read by KEY head, a head a
+  lane-aligned slice of the ``[S, Hk Dk]`` rows, so no repeated or
+  transposed copy of q, k, v or o exists; the solve is forward
+  substitution inside 16-row diagonal blocks on the vector unit and one
+  float32 product at precision ``highest`` for what finished rows owe
+  the block below (:func:`_solve_in_place`). On the v5e it is bound by
+  the vector unit's issue rate, about 0.50 us a head a chunk at every
+  row count, against 1.3-1.6 (and 0.25 of copies around it) for
+* the ``jax.numpy`` body under ``lax.scan``: the CPU's path, the path of
+  heads narrower than a lane tile, and the kernel's reference.
 
 :func:`gdn_step` (the tick) updates EVERY slot's state by one token. The
 state cache ``[L_lin, slots, H, Dk, Dv]`` float32 is 2 MB a slot a layer
@@ -108,22 +125,38 @@ def _solve_unit_lower(system, rhs):
 
 
 def gdn_chunked_scan(q, k, v, g, beta, state=None, *, chunk: int = 64,
-                     dtype=F32) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The recurrence over whole sequences. q, k [B, S, H, Dk]; v
-    [B, S, H, Dv]; g, beta [B, S, H] float32 (``g = beta = 0``: the
-    position is skipped); ``state`` [B, H, Dk, Dv] float32, zeros when
-    None. Returns (o [B, S, H, Dv] float32, final state).
+                     dtype=F32, use_kernel: Optional[bool] = None
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The recurrence over whole sequences. q, k [B, S, Hk, Dk] with
+    ``Hk`` dividing ``H`` (value head ``h`` reads key head ``h // (H /
+    Hk)``); v [B, S, H, Dv]; g, beta [B, S, H] float32 (``g = beta =
+    0``: the position is skipped); ``state`` [B, H, Dk, Dv] float32,
+    zeros when None. Returns (o [B, S, H, Dv] float32, final state).
 
     The large products take operands in ``dtype`` (the model's dtype:
     bf16 rounds them as the published CUDA kernels do) and accumulate in
     float32; decays, cumulative sums, the triangular solve and the
-    carried state stay float32."""
-    bsz, s, h, dk = k.shape
-    dv = v.shape[-1]
+    carried state stay float32.
+
+    ``use_kernel`` None takes the ``gdn_chunk_scan`` kernel on the TPU
+    where :func:`gdn_scan_applicable` holds, and the ``jax.numpy`` body
+    below everywhere else (the CPU, heads narrower than a lane tile):
+    the kernel's reference and the one fallback."""
+    bsz, s, hk, dk = k.shape
+    h, dv = v.shape[-2:]
     qn = min(chunk, s)
     if s % qn:
         raise ValueError(f"sequence {s} is not a multiple of chunk {qn}")
     nc = s // qn
+    tiles = gdn_scan_applicable(h, hk, dk, dv, qn)
+    interpret = interpret_default()
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu" and tiles
+    if use_kernel and tiles:
+        return _gdn_chunk_scan_fused(q, k, v, g, beta, state, chunk=qn,
+                                     dtype=dtype, interpret=interpret)
+    if hk != h:
+        q, k = (jnp.repeat(a, h // hk, axis=2) for a in (q, k))
     if state is None:
         state = jnp.zeros((bsz, h, dk, dv), F32)
 
@@ -179,6 +212,213 @@ def gdn_chunked_scan(q, k, v, g, beta, state=None, *, chunk: int = 64,
         step, state.astype(F32),
         (chunks(q), chunks(k), chunks(v), chunks(g), chunks(beta)))
     return jnp.moveaxis(os, 0, 1).reshape(bsz, s, h, dv), state
+
+
+# ---------------------------------------------------------------------------
+# Prefill: the same chunks as one kernel
+# ---------------------------------------------------------------------------
+
+_SCAN_HEADS = 4
+_SUBLANES = 8
+
+
+def _scan_head_block(heads: int, key_heads: int) -> int:
+    """Value heads a grid step of ``gdn_chunk_scan`` covers: whole key
+    heads' shares, up to ``_SCAN_HEADS``. Their chains of substitution
+    steps are independent, so the scheduler interleaves them, and they
+    share the grid step's fixed cost; but the body is unrolled a head,
+    and every process traces and lowers it anew for each prefill
+    program: on the v5e 4, 8 and 16 heads a step read 0.69, 0.68 and
+    0.67 us a head a chunk, and 8 cost a run twice 4's seconds of
+    set-up (PR 44). 0 when no such block divides ``heads``."""
+    if key_heads <= 0 or heads % key_heads:
+        return 0
+    share = heads // key_heads
+    hb = min(heads, _SCAN_HEADS) // share * share
+    while hb and heads % hb:
+        hb -= share
+    return hb
+
+
+def gdn_scan_applicable(heads: int, key_heads: int, dk: int, dv: int,
+                        chunk: int) -> bool:
+    """True when auto-dispatch takes the ``gdn_chunk_scan`` kernel on
+    the TPU: key and value dims of whole lane tiles (a head is a lane-
+    aligned slice of the ``[S, H D]`` rows), a chunk of whole diagonal
+    blocks, and whole blocks of value heads over whole key heads."""
+    return (dk % LANES == 0 and dv % LANES == 0 and chunk % _DIAGONAL == 0
+            and _scan_head_block(heads, key_heads) > 0)
+
+
+def _row_tiles(a):
+    """``[Q, N]`` as its ``Q / 8`` sublane tiles ``[8, N]``."""
+    return [a[t:t + _SUBLANES] for t in range(0, a.shape[0], _SUBLANES)]
+
+
+def _solve_in_place(systems, sides):
+    """``(I + L)^-1 rhs`` for every head of a grid step, in place on
+    lists of row tiles: ``systems[h]`` the tiles of the strictly lower
+    ``L [Q, Q]``, ``sides[h]`` the tiles of the right-hand sides ``[Q,
+    N]``. The published kernels' shape, on this chip's units: diagonal
+    blocks of 16 rows by forward substitution on the vector unit (column
+    by column: once row ``j`` is final, ``L[:, j]`` times it leaves
+    every later row of the block; float32, no matmul rounds it and no
+    power of ``L`` is formed), and what the finished rows owe a block
+    below them as ONE float32 product at precision ``highest`` (six
+    bf16 passes in Mosaic as in XLA; at the default it is ONE, which
+    the interpreter does not show: ``chip_smoke.py --phases linear``
+    holds this solve to a float64 one on the chip). The heads' chains
+    are independent and written interleaved, a column of every head
+    before the next column."""
+    qn = systems[0][0].shape[-1]
+    per = _DIAGONAL // _SUBLANES
+    for lo in range(0, qn, _DIAGONAL):
+        first = lo // _SUBLANES
+        if lo:
+            for ls, xs in zip(systems, sides):
+                owed = jnp.dot(
+                    jnp.concatenate(ls[first:first + per], axis=0)[:, :lo],
+                    jnp.concatenate(xs[:first], axis=0),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=F32)
+                for t, part in enumerate(_row_tiles(owed)):
+                    xs[first + t] = xs[first + t] - part
+        for j in range(lo, lo + _DIAGONAL - 1):
+            tj, r = divmod(j, _SUBLANES)
+            for ls, xs in zip(systems, sides):
+                row = xs[tj][r:r + 1]
+                for t in range((j + 1) // _SUBLANES, first + per):
+                    xs[t] = xs[t] - ls[t][:, j:j + 1] * row
+
+
+def _gdn_chunk_scan_kernel(*refs, heads: int, share: int, dk: int, dv: int,
+                           has_state: bool, dtype):
+    """One chunk of ``heads`` value heads of one row. Rows of q, k, v
+    and o are positions, a head a lane-aligned slice; ``cols`` [Q, 2 Hb]
+    holds beta and the chunk's cumulative log-decay a head a lane,
+    ``rows`` [Hb, Q] the cumulative log-decay a head a sublane,
+    ``whole`` the chunk's whole decay a head, scalars in SMEM (Mosaic
+    does not broadcast a ``[1, 1]`` of ``cols`` over a ``[Dk, Dv]``
+    tile). The heads' states live in ``out_ref``, whose block index
+    does not move along the chunk axis: loaded at chunk 0, written back
+    after the last. Every product rounds what
+    :func:`gdn_chunked_scan`'s ``mm`` rounds."""
+    whole_ref, q_ref, k_ref, v_ref, cols_ref, rows_ref = refs[:6]
+    st_ref, o_ref, out_ref = refs[6:] if has_state else (None, *refs[6:])
+
+    @pl.when(pl.program_id(2) == 0)
+    def _load():
+        out_ref[...] = (st_ref[...] if has_state
+                        else jnp.zeros(out_ref.shape, F32))
+
+    qn = q_ref.shape[1]
+    # [B, nc, H] flat: this row, this chunk, this block's first head.
+    first = ((pl.program_id(0) * pl.num_programs(2) + pl.program_id(2))
+             * pl.num_programs(1) + pl.program_id(1)) * heads
+
+    def mm(lhs, rhs, contract):
+        return jax.lax.dot_general(
+            lhs.astype(dtype), rhs.astype(dtype), (contract, ((), ())),
+            preferred_element_type=F32)
+
+    nt, nn, tn = ((1,), (1,)), ((1,), (0,)), ((0,), (0,))
+    cols, cum_rows = cols_ref[0, 0, 0], rows_ref[0, 0, 0]
+    cum = cols[:, heads:]                               # [Q, Hb]
+    grown = jnp.exp(cum)
+    left = jnp.exp(cum[qn - 1:] - cum)
+    at = jax.lax.broadcasted_iota(jnp.int32, (qn, qn), 0)
+    seen = jax.lax.broadcasted_iota(jnp.int32, (qn, qn), 1)
+    lower, strict = at >= seen, at > seen
+
+    systems, sides, kept = [], [], []
+    for key in range(heads // share):
+        qf = q_ref[0, :, key * dk:(key + 1) * dk]       # [Q, Dk] float32
+        kf = k_ref[0, :, key * dk:(key + 1) * dk]
+        kr = kf.astype(dtype)
+        scores = mm(qf, kr, nt)                         # [Q, Q]
+        for h in range(key * share, (key + 1) * share):
+            b = cols[:, h:h + 1]
+            seg = cum[:, h:h + 1] - cum_rows[h:h + 1]
+            # Above the diagonal 1, not 0: both uses mask it themselves.
+            decay = jnp.exp(jnp.where(lower, seg, 0.0))
+            kb = kf * b
+            systems.append(_row_tiles(
+                jnp.where(strict, mm(kb, kr, nt) * decay, 0.0)))
+            # The values' part and the carry's part of the solve at once.
+            sides.append(_row_tiles(jnp.concatenate(
+                [v_ref[0, :, h * dv:(h + 1) * dv].astype(F32) * b,
+                 kb * grown[:, h:h + 1]], axis=1)))
+            kept.append((h, qf, kf, jnp.where(lower, scores * decay, 0.0)))
+    _solve_in_place(systems, sides)
+    for (h, qf, kf, attn), solved in zip(kept, sides):
+        solved = jnp.concatenate(solved, axis=0)        # [Q, Dv + Dk]
+        carry = out_ref[0, h]                           # [Dk, Dv]
+        cr = carry.astype(dtype)
+        u = solved[:, :dv] - mm(solved[:, dv:], cr, nn)
+        o_ref[0, :, h * dv:(h + 1) * dv] = (
+            mm(qf * grown[:, h:h + 1], cr, nn) + mm(attn, u, nn))
+        out_ref[0, h] = (carry * whole_ref[first + h]
+                         + mm(kf * left[:, h:h + 1], u, tn))
+
+
+# Jitted for its cache: a prefill program calls it once a run of linear
+# layers, and the kernel's body (every substitution step of four heads,
+# unrolled) is traced and lowered once a shape, not once a call.
+@functools.partial(jax.jit, static_argnames=("chunk", "dtype", "interpret"))
+def _gdn_chunk_scan_fused(q, k, v, g, beta, state, *, chunk: int, dtype,
+                          interpret: bool):
+    bsz, s, hk, dk = k.shape
+    h, dv = v.shape[-2:]
+    nc, hb = s // chunk, _scan_head_block(h, hk)
+    share, blocks = h // hk, h // hb
+    kb = hb // share                                    # key heads a block
+
+    def by_block(a):         # [B, S, H] -> [B, nc, H / Hb, Q, Hb]
+        return jnp.moveaxis(
+            a.astype(F32).reshape(bsz, nc, chunk, blocks, hb), 3, 2)
+
+    cum = jnp.cumsum(g.astype(F32).reshape(bsz, nc, chunk, h), axis=2)
+    cols = jnp.concatenate([by_block(beta), by_block(cum)], axis=-1)
+    rows = jnp.swapaxes(by_block(cum), -1, -2)
+    keyed = pl.BlockSpec((1, chunk, kb * dk), lambda i, j, c: (i, c, j))
+    valued = pl.BlockSpec((1, chunk, hb * dv), lambda i, j, c: (i, c, j))
+    held = pl.BlockSpec((1, hb, dk, dv), lambda i, j, c: (i, j, 0, 0))
+    operands = [jnp.exp(cum[:, :, -1]).reshape(-1),
+                q.astype(F32).reshape(bsz, s, hk * dk),
+                k.astype(F32).reshape(bsz, s, hk * dk),
+                v.reshape(bsz, s, h * dv), cols, rows]
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), keyed, keyed, valued,
+                pl.BlockSpec((1, 1, 1, chunk, 2 * hb),
+                             lambda i, j, c: (i, c, j, 0, 0)),
+                pl.BlockSpec((1, 1, 1, hb, chunk),
+                             lambda i, j, c: (i, c, j, 0, 0))]
+    if state is not None:
+        operands.append(state.astype(F32))
+        in_specs.append(held)
+    units = bsz * h * nc
+    o, state = pl.pallas_call(
+        functools.partial(_gdn_chunk_scan_kernel, heads=hb, share=share,
+                          dk=dk, dv=dv, has_state=state is not None,
+                          dtype=dtype),
+        grid=(bsz, blocks, nc),
+        in_specs=in_specs,
+        out_specs=[valued, held],
+        out_shape=[jax.ShapeDtypeStruct((bsz, s, h * dv), F32),
+                   jax.ShapeDtypeStruct((bsz, h, dk, dv), F32)],
+        interpret=interpret,
+        name="gdn_chunk_scan",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=units * chunk * (
+                2 * (2 * chunk * dk + 2 * dk * dv + chunk * dv + dk * dv)
+                + chunk * (dk + dv)),
+            transcendentals=units * chunk * (chunk + 3),
+            bytes_accessed=(8 * bsz * s * hk * dk + 8 * bsz * s * h
+                            + bsz * s * h * dv * (v.dtype.itemsize + 4)
+                            + 8 * bsz * h * dk * dv)),
+    )(*operands)
+    return o.reshape(bsz, s, h, dv), state
 
 
 # ---------------------------------------------------------------------------

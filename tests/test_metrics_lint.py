@@ -519,7 +519,8 @@ def test_checkpoint_plane_series_are_cataloged():
 # Framework-owned jax.jit call sites must go through the instrumented
 # wrapper (ray_tpu._private.xla_monitor.instrument) so every compile,
 # retrace and cost analysis is observed. Intentional raw jits are
-# allowlisted here WITH a reason.
+# allowlisted here WITH a reason: a whole file, or ``file::function`` for
+# ONE decorated function (the rest of its file stays linted).
 RAW_JIT_ALLOWLIST = {
     # The wrapper itself wraps jax.jit.
     "_private/xla_monitor.py": "the instrumented wrapper's own jit",
@@ -529,6 +530,12 @@ RAW_JIT_ALLOWLIST = {
     "rllib/env_runner.py": "RL env-loop jits",
     "rllib/multi_agent.py": "RL env-loop jits",
     "rllib/core.py": "RL learner jits",
+    # Not a program: an inner jit kept for its TRACE cache. The kernel's
+    # body is unrolled (every substitution step of four heads), and a
+    # prefill program calls it once a run of linear layers; it only runs
+    # inside ``cb_prefill``, which is instrumented (PR 44).
+    "ops/gated_delta.py::_gdn_chunk_scan_fused":
+        "gdn_chunk_scan's wrapper, traced once a shape",
 }
 
 
@@ -544,11 +551,17 @@ def test_framework_jits_go_through_the_instrumented_wrapper():
         rel = path.relative_to(root).as_posix()
         if rel in RAW_JIT_ALLOWLIST:
             continue
-        for lineno, line in enumerate(
-                path.read_text().splitlines(), start=1):
+        lines = path.read_text().splitlines()
+        for lineno, line in enumerate(lines, start=1):
             code = line.split("#", 1)[0]
-            if re.search(r"\bjax\.jit\b", code):
-                offenders.append(f"{rel}:{lineno}")
+            if not re.search(r"\bjax\.jit\b", code):
+                continue
+            # A decorator's function is the next ``def`` below it.
+            below = re.search(r"^def (\w+)", "\n".join(lines[lineno:]), re.M)
+            if (code.startswith("@") and below
+                    and f"{rel}::{below[1]}" in RAW_JIT_ALLOWLIST):
+                continue
+            offenders.append(f"{rel}:{lineno}")
     assert not offenders, (
         f"raw jax.jit call sites outside the allowlist: {offenders} — "
         f"route them through ray_tpu._private.xla_monitor.instrument "

@@ -17,6 +17,7 @@ the CPU, float32): 5e-5 of the logits' standard deviation.
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -282,6 +283,82 @@ def test_chunked_scan_is_the_token_recurrence(real, chunk):
         gdn.gdn_chunked_scan(q, k, v, g, beta, state, chunk=36)
 
 
+def _scan_inputs(rows, s, real, carried, seed=7):
+    """The kernel's shapes: 4 value heads over 2 key heads at 128 x 128,
+    ``real`` positions of ``s`` live (the rest ``g = beta = 0``)."""
+    q, k, v, g, beta, state = _rule_inputs(rows, s, 4, 128, 128, seed=seed)
+    live = (jnp.arange(s) < real)[None, :, None]
+    return (q[:, :, ::2], k[:, :, ::2], v, jnp.where(live, g, 0.0),
+            jnp.where(live, beta, 0.0), state if carried else None)
+
+
+# (positions, real): every position real; a right-padded row whose last
+# chunk is all padding; a row of ONE real token.
+@pytest.mark.parametrize("s,real", [(128, 128), (192, 100), (128, 1)])
+@pytest.mark.parametrize("carried", [True, False], ids=["carried", "fresh"])
+@pytest.mark.parametrize("rows", [1, 3])
+def test_chunk_scan_kernel_is_the_scan(rows, carried, s, real,
+                                       pallas_interpret):
+    """The prefill's kernel (interpreted) at the published head shape,
+    value heads reading their KEY head's q and k: the ``jax.numpy``
+    body's outputs and final state, and the per-token recurrence's."""
+    q, k, v, g, beta, state = _scan_inputs(rows, s, real, carried)
+    with jax.default_matmul_precision("highest"):
+        o, new = gdn.gdn_chunked_scan(q, k, v, g, beta, state, chunk=64,
+                                      use_kernel=True)
+        want_o, want = gdn.gdn_chunked_scan(q, k, v, g, beta, state,
+                                            chunk=64, use_kernel=False)
+        zeros = jnp.zeros((rows, 4, 128, 128))
+        loop_o, loop = _token_loop(*(
+            a[:, :real] for a in (jnp.repeat(q, 2, 2), jnp.repeat(k, 2, 2),
+                                  v, g, beta)), zeros if state is None
+            else state)
+    np.testing.assert_allclose(o, want_o, atol=2e-5)
+    np.testing.assert_allclose(new, want, atol=2e-5)
+    np.testing.assert_allclose(o[:, :real], loop_o, atol=2e-5)
+    np.testing.assert_allclose(new, loop, atol=2e-5)
+
+
+def test_chunk_scan_kernel_rounds_what_the_scan_rounds(pallas_interpret):
+    """At the model's dtype: kernel and ``jax.numpy`` body round the same
+    operands to bfloat16, so they part by no more than one bfloat16 ulp
+    of the products' operands (a float32 last bit that the two solves'
+    orders of addition leave different can flip one rounding)."""
+    q, k, v, g, beta, state = _scan_inputs(2, 128, 128, True, seed=8)
+    v = v.astype(jnp.bfloat16)
+    got = gdn.gdn_chunked_scan(q, k, v, g, beta, state, chunk=64,
+                               dtype=jnp.bfloat16, use_kernel=True)
+    want = gdn.gdn_chunked_scan(q, k, v, g, beta, state, chunk=64,
+                                dtype=jnp.bfloat16, use_kernel=False)
+    for a, b in zip(got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.max(np.abs(a - b)) <= 2.0 ** -8 * np.max(np.abs(b))
+        assert np.mean(np.abs(a - b) <= 1e-5) > 0.9
+
+
+def test_chunk_scan_kernel_is_taken_where_the_shapes_tile(pallas_interpret):
+    """The shape rule beside ``gdn_applicable``: the published 32 value
+    heads over 16 key heads at 128 x 128 in chunks of 64 take the kernel,
+    four value heads a grid step; the rehearsal's 16-wide heads, a
+    chunk that is no whole diagonal block, and value heads that do not
+    share key heads evenly do not, and are served by the ``jax.numpy``
+    body whatever ``use_kernel`` says."""
+    assert gdn.gdn_scan_applicable(32, 16, 128, 128, 64)
+    assert gdn._scan_head_block(32, 16) == 4
+    assert gdn._scan_head_block(4, 2) == 4 and gdn._scan_head_block(6, 2) == 3
+    assert not gdn.gdn_scan_applicable(4, 2, 16, 16, 16)     # the rehearsal
+    assert not gdn.gdn_scan_applicable(32, 16, 128, 128, 8)
+    assert not gdn.gdn_scan_applicable(32, 12, 128, 128, 64)
+    q, k, v, g, beta, state = _rule_inputs(2, 32, 4, 16, 16, seed=9)
+    q, k = q[:, :, ::2], k[:, :, ::2]
+    got = gdn.gdn_chunked_scan(q, k, v, g, beta, state, chunk=16,
+                               use_kernel=True)
+    want = gdn.gdn_chunked_scan(jnp.repeat(q, 2, 2), jnp.repeat(k, 2, 2), v,
+                                g, beta, state, chunk=16, use_kernel=False)
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)
+
+
 @pytest.mark.parametrize("layer", [0, 2])
 def test_gdn_step_kernel_is_the_step_in_place(layer, pallas_interpret):
     """(d) The tick's kernel (interpreted) at the published head shape:
@@ -303,17 +380,39 @@ def test_gdn_step_kernel_is_the_step_in_place(layer, pallas_interpret):
     assert not np.array_equal(new[layer], cache[layer])
 
 
-def test_a_padded_row_keeps_its_last_real_tokens_state_and_tail(model):
+@pytest.fixture(scope="module")
+def wide_mixer():
+    """One linear layer's mixer at a 128-wide head: the shape the
+    prefill's kernel takes."""
+    config = tiny(linear_key_head_dim=128, linear_value_head_dim=128)
+    return config, jax.tree.map(
+        lambda a: a[0], gated_delta.init_mixer(config, jax.random.PRNGKey(6),
+                                               1))
+
+
+@pytest.fixture(params=["jax.numpy", "kernel"])
+def scan_path(request, model, wide_mixer, pallas_interpret, monkeypatch):
+    """(config, layer): the tiny model's mixer through the ``jax.numpy``
+    scan, and a 128-wide one through the interpreted ``gdn_chunk_scan``
+    kernel (what the TPU's dispatch takes at that width)."""
+    if request.param == "kernel":
+        monkeypatch.setattr(gdn, "gdn_chunked_scan", functools.partial(
+            gdn.gdn_chunked_scan, use_kernel=True))
+        return wide_mixer
+    config, params = model
+    return config, jax.tree.map(lambda a: a[1], params["runs"][0])
+
+
+def test_a_padded_row_keeps_its_last_real_tokens_state_and_tail(scan_path):
     """(f) The mixer over a right-padded row: the state and the conv
     tail it returns are the unpadded row's, after the last REAL token,
     and a row shorter than the tail is zeros in front."""
-    config, params = model
-    layer = jax.tree.map(lambda a: a[1], params["runs"][0])
+    config, layer = scan_path
     h = jax.random.normal(jax.random.PRNGKey(3), (3, 32, 64), F32)
     lengths = jnp.asarray([32, 21, 2])
     with jax.default_matmul_precision("highest"):
-        out, state, tail = gated_delta.mixer_prefill(h, layer, config,
-                                                     lengths)
+        out, state, tail = gated_delta.mixer_prefill(
+            h, layer, config, lengths)
         for row, n in enumerate((32, 21, 2)):
             want = gated_delta.mixer_prefill(
                 h[row:row + 1, :n], layer, config, jnp.asarray([n]))
@@ -323,12 +422,12 @@ def test_a_padded_row_keeps_its_last_real_tokens_state_and_tail(model):
     assert not np.asarray(tail[2, 0]).any() and np.asarray(tail[2, 1]).any()
 
 
-def test_a_chunk_goes_on_from_the_carried_state_and_tail(model):
+def test_a_chunk_goes_on_from_the_carried_state_and_tail(scan_path):
     """The mixer over a row in two and three pieces, each handed the
     state and conv tail the piece before it returned (the last piece
-    padded), is the mixer over the row in one piece."""
-    config, params = model
-    layer = jax.tree.map(lambda a: a[0], params["runs"][2])
+    padded), is the mixer over the row in one piece: the hand-off
+    between ``cb_prefill`` calls, on both scan paths."""
+    config, layer = scan_path
     h = jax.random.normal(jax.random.PRNGKey(4), (2, 48, 64), F32)
     with jax.default_matmul_precision("highest"):
         want = gated_delta.mixer_prefill(h[:, :41], layer, config,

@@ -811,6 +811,28 @@ def test_gdn_step_lowers(slots):
     assert _kernel_names(exported.mlir_module()) == ["gdn_step"]
 
 
+@pytest.mark.parametrize("carried", [True, False], ids=["carried", "fresh"])
+@pytest.mark.parametrize("rows", [1, 8])
+def test_gdn_chunk_scan_lowers(rows, carried):
+    """The prefill's chunkwise delta rule at Qwen3-Next's widths (32
+    value heads over 16 key heads at 128 x 128, 1024 positions in chunks
+    of 64, bf16 operands): one Mosaic call, with a carried state and
+    from none."""
+    from ray_tpu.ops import gated_delta
+
+    f32 = jnp.float32
+    fn = functools.partial(gated_delta.gdn_chunked_scan, chunk=64,
+                           dtype=BF16, use_kernel=True)
+    assert gated_delta.gdn_scan_applicable(32, 16, 128, 128, 64)
+    keyed = S((rows, 1024, 16, 128), f32)
+    gate = S((rows, 1024, 32), f32)
+    args = (keyed, keyed, S((rows, 1024, 32, 128), BF16), gate, gate)
+    if carried:
+        args += (S((rows, 32, 128, 128), f32),)
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
+    assert _kernel_names(exported.mlir_module()) == ["gdn_chunk_scan"]
+
+
 def test_compiled_linear_tick_holds_no_copy_of_the_state_cache(v5e_chip):
     """The cell ``serve_linear_decode``'s tick, compiled for a described
     v5e at the cell's own sizes (Qwen3-Next's widths, 8 layers = (3
