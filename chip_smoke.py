@@ -2268,20 +2268,27 @@ def phase_multichip_train(rehearse: bool) -> None:
         rehearse=rehearse)
     # Spread, not replicated and not all on device 0: every parameter
     # and every optimizer moment has four shards on four devices, each a
-    # quarter of the whole.
-    leaves = jax.tree.leaves((state.params, state.opt_state))
-    sharded = 0
-    for leaf in leaves:
+    # quarter of the whole. The norm weights alone are whole on every
+    # device (logical axis "norm": a few KB that every layer of a scan
+    # reads, PR 45).
+    leaves = jax.tree_util.tree_leaves_with_path(
+        (state.params, state.opt_state))
+    sharded = whole = 0
+    for path, leaf in leaves:
         if leaf.ndim == 0:
             continue          # step counters are replicated scalars
         shards = leaf.addressable_shards
         assert len({s.device.id for s in shards}) == MIN_CHIPS_MULTI
-        assert all(s.data.size * MIN_CHIPS_MULTI == leaf.size
-                   for s in shards), (leaf.shape, shards[0].data.shape)
-        sharded += 1
+        is_norm = "_norm" in jax.tree_util.keystr(path)
+        parts = 1 if is_norm else MIN_CHIPS_MULTI
+        assert all(s.data.size * parts == leaf.size for s in shards), (
+            jax.tree_util.keystr(path), leaf.shape, shards[0].data.shape)
+        sharded += not is_norm
+        whole += is_norm
     _say(phase, f"{sharded} parameter/optimizer arrays, each in "
                 f"{MIN_CHIPS_MULTI} quarter shards on {MIN_CHIPS_MULTI} "
-                "distinct devices")
+                f"distinct devices; {whole} norm weights and their "
+                "moments whole on each")
     rel = abs(losses4[0] - losses1[0]) / abs(losses1[0])
     _say(phase, f"first-step loss: one chip {losses1[0]:.5f}, fsdp="
                 f"{MIN_CHIPS_MULTI} {losses4[0]:.5f} (relative difference "

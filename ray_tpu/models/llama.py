@@ -397,12 +397,12 @@ def scaling_pairs(group: Optional[Dict[str, Any]]):
 def logical_axes(config: LlamaConfig) -> Params:
     """Pytree of logical-axis tuples matching :func:`init_params`."""
     layer = {
-        "attn_norm": ("layers", "embed"),
+        "attn_norm": ("layers", "norm"),
         "wq": ("layers", "embed", "heads", "head_dim"),
         "wk": ("layers", "embed", "kv_heads", "head_dim"),
         "wv": ("layers", "embed", "kv_heads", "head_dim"),
         "wo": ("layers", "heads", "head_dim", "embed"),
-        "mlp_norm": ("layers", "embed"),
+        "mlp_norm": ("layers", "norm"),
         "w_gate": ("layers", "embed", "mlp"),
         "w_up": ("layers", "embed", "mlp"),
         "w_down": ("layers", "mlp", "embed"),
@@ -425,7 +425,7 @@ def logical_axes(config: LlamaConfig) -> Params:
     return {
         "embed": ("vocab", "embed"),
         "layers": layer,
-        "final_norm": ("embed",),
+        "final_norm": ("norm",),
         "lm_head": ("embed", "vocab"),
     }
 
@@ -1180,6 +1180,14 @@ def loss_fn(
     # Keep the head in the params' storage dtype: the chunk matmul runs
     # bf16 x bf16 -> fp32-accumulated logits (see forward()).
     head = params["lm_head"].astype(config.dtype)
+    if mesh is not None:
+        # Every chunk of the scan below needs the whole head. Closed over
+        # as the parameter's FSDP shard, its all-gather and its gradient's
+        # float32 all-reduce stand in the scan's body and run once a
+        # chunk; made whole here, the head is gathered once a step and
+        # each chip's partial gradient is summed once, after the backward
+        # scan. "vocab" keeps its tensor axis.
+        head = constrain(head, mesh, "act_embed", "vocab")
 
     s = x.shape[1]
     n_chunks = vocab_chunks

@@ -28,6 +28,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 #   "kv_seq"      – key/value sequence (ring-attention shifted axis)
 #   "experts"     – MoE expert dimension
 #   "layers"      – scanned layer dimension (never sharded)
+#   "norm"        – a norm weight's feature dimension (never sharded: a
+#                   few KB that every layer of a scan reads whole)
 
 LogicalRules = Tuple[Tuple[str, Union[str, Tuple[str, ...], None]], ...]
 
@@ -44,6 +46,7 @@ DEFAULT_RULES: LogicalRules = (
     ("vocab", "tensor"),
     ("experts", "expert"),
     ("layers", None),
+    ("norm", None),
     # activation axes (distinct from param axes: an activation's feature dim
     # stays unsharded on the fsdp axis — fsdp gathers params for compute)
     ("batch", ("data", "fsdp")),

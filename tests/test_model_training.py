@@ -87,6 +87,53 @@ def test_sharding_layouts_agree():
     assert abs(losses["dp"] - losses["fsdp_tp"]) < 1e-3, losses
 
 
+@pytest.mark.parametrize(
+    "mesh_cfg", [MeshConfig(fsdp=4), MeshConfig(fsdp=2, tensor=2)],
+    ids=["fsdp4", "fsdp2_tp2"])
+def test_sharded_step_matches_the_one_device_step(mesh_cfg):
+    """The head made whole before the loss scan, with its gradient summed
+    across chips once after it, and the norm weights never sharded: the
+    step's loss and gradients are the one-device step's, and ten steps'
+    losses follow it. Gradients are read off a plain SGD step
+    (``(before - after) / rate``), so it is the trainer's own step that
+    is compared."""
+    import optax
+
+    rate = 0.1
+    batch = synthetic_batch(4, 64, 256)
+
+    def ten_steps(cfg_mesh):
+        mesh = make_mesh(cfg_mesh, devices=jax.devices()[
+            :cfg_mesh.fsdp * cfg_mesh.tensor])
+        trainer = ShardedTrainer(llama.LlamaConfig.tiny(dtype=jnp.float32),
+                                 mesh, optimizer=optax.sgd(rate))
+        state = trainer.init_state(0)
+        before = jax.tree.map(np.asarray, state.params)
+        sharded = trainer.shard_batch(batch)
+        state, metrics = trainer.train_step(state, sharded)
+        grads = jax.tree.map(lambda b, a: (b - np.asarray(a)) / rate,
+                             before, state.params)
+        losses = [float(metrics["loss"])]
+        for _ in range(9):
+            state, metrics = trainer.train_step(state, sharded)
+            losses.append(float(metrics["loss"]))
+        return losses, grads
+
+    with jax.default_matmul_precision("highest"):
+        ref_losses, ref = ten_steps(MeshConfig(fsdp=1))
+        losses, got = ten_steps(mesh_cfg)
+    assert np.all(np.isfinite(losses))
+    assert losses[-1] < losses[0]
+    # test_sharding_layouts_agree's tolerance.
+    np.testing.assert_allclose(losses, ref_losses, atol=1e-3, rtol=0)
+    for name in ("lm_head", "attn_norm", "mlp_norm", "w_down"):
+        g, r = (tree[name] if name in tree else tree["layers"][name]
+                for tree in (got, ref))
+        assert np.abs(r).max() > 1e-3, name     # a gradient, not zeros
+        np.testing.assert_allclose(
+            g, r, rtol=0, atol=1e-4 * np.abs(r).max(), err_msg=name)
+
+
 def test_params_actually_sharded():
     cfg, trainer = _trainer(MeshConfig(data=1, fsdp=8))
     state = trainer.init_state(0)

@@ -177,17 +177,24 @@ _CELL_LAYERS = 16
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """One chip of a v5e that is described, not attached. Built inside a
-    fixture: only the worker that runs this file may load libtpu."""
+def v5e_host():
+    """The four chips of a v5e host that is described, not attached.
+    Built inside a fixture: only the worker that runs this file may load
+    libtpu."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - any failure means "cannot"
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_host):
+    """One chip of that host."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_host[0])
 
 
 @functools.lru_cache(maxsize=None)
@@ -972,3 +979,109 @@ def test_compiled_eva_prefill_chunk_lands_blocks_in_the_layer_loop(
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 1 << 30
     assert memory.alias_size_in_bytes >= 2 * cache.k.size * 2
+
+
+# ------------------------------------- the FSDP train step's collectives
+
+_HEAD_COLLECTIVE = re.compile(
+    r" = \(?(\w+)\[(?:\d+,)+(?:32768|16384)\][^=]* "
+    r"(all-gather|all-reduce|reduce-scatter)(?:-start)?\(.*channel_id=(\d+)")
+_NORM_GATHER = re.compile(
+    r" = \(?\w+\[(?:1,)?4096\][^=]* all-gather(?:-start)?\(")
+
+
+def _loops(hlo_text: str):
+    """``(outside, loops)`` of compiled HLO: the lines no ``while`` body
+    reaches, and for each ``while`` the lines of its body and of every
+    computation the body calls (fusions and nested loops included)."""
+    bodies, name = {}, None
+    for line in hlo_text.splitlines():
+        opened = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if opened and not line.startswith(" "):
+            name = opened.group(1)
+            bodies[name] = []
+        elif name is not None and not line.startswith("}"):
+            bodies[name].append(line)
+
+    def reach(start, seen):
+        if start in bodies and start not in seen:
+            seen.add(start)
+            for line in bodies[start]:
+                for called in re.findall(
+                        r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)",
+                        line):
+                    reach(called, seen)
+        return seen
+
+    reached = [reach(m.group(1), set())
+               for lines in bodies.values() for line in lines
+               if " while(" in line
+               for m in [re.search(r"body=%?([\w.\-]+)", line)]]
+    inside = set().union(*reached)
+    outside = [line for c, lines in bodies.items() if c not in inside
+               for line in lines]
+    return outside, [[line for c in sorted(loop) for line in bodies[c]]
+                     for loop in reached]
+
+
+@pytest.mark.parametrize("fsdp,tensor", [(4, 1), (2, 2)])
+def test_compiled_fsdp_step_gathers_what_its_loops_reuse_once(
+        v5e_host, fsdp, tensor):
+    """``train_fsdp4``'s step at Mistral-7B widths (two layers: the loops
+    are the same), compiled for the four described chips. Every chunk of
+    the loss scan needs the whole head and every layer a norm weight, so
+    the head is gathered over ``fsdp`` ONCE a step and its gradient
+    summed across chips once, and the norm weights are never sharded:
+
+    * no collective on the head stands in a loss-chunk loop (the parent
+      gathered ``bf16[4096,32768]`` in the forward and in the backward
+      loop and summed its gradient as ``f32[4096,32768]`` in the
+      backward loop: 14 gathers and 7 float32 reduces a step);
+    * the program has one gather of the head and one reduce of its
+      gradient, both begun and ended outside every loop. (The compiler
+      may spread the one gather under the forward LAYER loop as pieces
+      of an async collective: same channel, started in the entry.);
+    * no float32 collective on the head anywhere, ``kCustom`` fusions
+      included;
+    * no ``all-gather`` of a norm weight in any loop (the parent: 11 in
+      a forward layer, 26 in a backward one). Their gradients' one
+      ``all-reduce (bf16[4096], bf16[4096])`` a backward layer stays.
+
+    On ``fsdp=2 x tensor=2`` the vocabulary stays on ``tensor``: the
+    head is gathered as ``[4096,16384]``, never as ``[*,32768]``."""
+    from benchmark import manifest
+
+    cell = manifest.cell("train_fsdp4")
+    rows, tokens = (cell["traffic"][k]
+                    for k in ("batch_sequences", "sequence_tokens"))
+    config = manifest.llama_config(
+        dict(cell["config"], num_hidden_layers=2), max_seq_len=tokens,
+        remat=True, remat_policy=cell["workload"]["remat_policy"])
+    mesh = make_mesh(MeshConfig(fsdp=fsdp, tensor=tensor), devices=v5e_host)
+    trainer = ShardedTrainer(config, mesh)
+    state = jax.tree.map(
+        lambda a, sharding: S(a.shape, a.dtype, sharding=sharding),
+        jax.eval_shape(trainer._init._jitted, jax.random.PRNGKey(0)),
+        trainer.state_shardings)
+    batch = {k: S((rows, tokens), jnp.int32, sharding=trainer.batch_sharding)
+             for k in ("tokens", "mask")}
+    with mesh:
+        hlo = trainer._step._jitted.lower(state, batch).compile().as_text()
+    outside, loops = _loops(hlo)
+    assert len(loops) >= 4           # loss and layers, forward and backward
+
+    def head_collectives(lines):
+        return [m.groups() for line in lines
+                for m in [_HEAD_COLLECTIVE.search(line)] if m]
+
+    for loop in loops:
+        if any("loss_head" in line for line in loop):
+            assert head_collectives(loop) == []
+        assert [line for line in loop if _NORM_GATHER.search(line)] == []
+    begun = set(head_collectives(outside))
+    everywhere = set(head_collectives(hlo.splitlines()))
+    assert everywhere == begun, "a collective on the head lives in a loop"
+    assert sorted((dtype, op) for dtype, op, _ in begun) == [
+        ("bf16", "all-gather"), ("bf16", "all-reduce")]
+    if tensor > 1:
+        assert not re.search(r",32768\][^=]* all-gather", hlo)
