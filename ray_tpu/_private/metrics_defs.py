@@ -759,6 +759,19 @@ CB_EVA_UNCOMPRESSED_BYTES = Gauge(
     "Bytes of the blocks the same live contexts would hold with every "
     "key kept (what ray_tpu_cb_eva_cache_bytes is a share of)",
     ("engine",))
+CB_CCA_KV_BYTES = Gauge(
+    "ray_tpu_cb_cca_kv_bytes",
+    "Resident bytes of the K/V arena of a model with CCA layers "
+    "(layers x blocks x block x 2 planes x KV heads x head size x "
+    "itemsize): keys and values in the compressed latent",
+    ("engine",))
+CB_CCA_TAIL_BYTES = Gauge(
+    "ray_tpu_cb_cca_tail_bytes",
+    "Resident bytes of the tail cache a model with CCA layers keeps "
+    "beside the arena: one row [u | a | v2] a slot a layer (the two "
+    "convolutions' last inputs and the next token's shifted value half); "
+    "fixed at construction, whatever the contexts",
+    ("engine",))
 CB_STATE_INSTALLS = Counter(
     "ray_tpu_cb_state_installs_total",
     "Prompts whose final recurrent state a prefill installed in a slot "

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import xla_monitor
-from ray_tpu.models import eva, gated_delta, llama, mamba2
+from ray_tpu.models import cca, eva, gated_delta, llama, mamba2
 from ray_tpu.models import paged_kv
 from ray_tpu.models.inference import (ExternalLlamaDrafter, KVCache,
                                       SelfDrafter, _attend_cached,
@@ -67,7 +67,8 @@ from ray_tpu.models.llama import rms_norm
 from ray_tpu.models.paged_kv import (GARBAGE_BLOCK, BlockAllocator,
                                      LatentKVCache, PagedKVCache,
                                      RadixBlockIndex,
-                                     RingKVCache, StateCache, prompt_chunks,
+                                     RingKVCache, StateCache, TailCache,
+                                     prompt_chunks,
                                      quantize_kv, resolve_kv_dtype)
 from ray_tpu.models.sampling import (SPEC_DRAFT_SALT, SamplingParams,
                                      filtered_probs, sample_tokens,
@@ -320,7 +321,8 @@ _KIND_NAMES = {"mamba": "state-space",
                "linear_attention": "linear-attention",
                "sliding_attention": "sliding-window",
                "latent_attention": "latent-attention",
-               "eva_attention": "eva-attention"}
+               "eva_attention": "eva-attention",
+               "cca_attention": "cca-attention"}
 # A recurrent layer (Mamba-2 or Gated DeltaNet) keeps, beside its K/V, a
 # state a slot that only moves FORWARD and belongs to one request.
 _RECURRENT_CANNOT = {
@@ -386,6 +388,25 @@ _KIND_CANNOT = {
         "score_logprobs": "it runs llama.forward, the training forward, "
                           "which this serving-only family has none of",
     },
+    # K/V in the arena like any attention layer's, AND a row a slot in
+    # ``paged_kv.TailCache`` (``models/cca.py``): the last token's
+    # convolution inputs and shifted value half, which the next token's
+    # K/V are made from.
+    "cca_attention": {
+        "second_kind": "a layer's arena index and its tail's are one "
+                       "number, so every layer must keep both",
+        "kv_dtype": "the keys are unit vectors times a learned "
+                    "temperature, held to the reference as bf16 only: an "
+                    "8-bit arena is a different model output until it is",
+        "speculative": "a rejected draft cannot rewind the convolution "
+                       "tail",
+        "prefix_cache": "a hit restores K/V blocks, not the convolution "
+                        "tail after them",
+        "handoff": "the KV handoff carries the arena's blocks, not the "
+                   "convolution tail",
+        "score_logprobs": "it runs llama.forward, the training forward, "
+                          "which this serving-only family has none of",
+    },
 }
 
 
@@ -400,7 +421,8 @@ def _refuse_unsupported(config, asked: Dict[str, str]) -> None:
         # The arena's own layers sit beside any cache but the latent
         # one, which takes the arena's place.
         beside = kinds - {kind}
-        if kind not in ("latent_attention", "eva_attention"):
+        if kind not in ("latent_attention", "eva_attention",
+                        "cca_attention"):
             beside -= {"attention", "full_attention"}
         wants = dict(asked)
         if beside:
@@ -432,36 +454,73 @@ def _kind_rope(c, kind, cos, sin):
     return cos, sin
 
 
-def _residual(x, y, c):
-    """``x + residual_multiplier * y`` (GraniteMoeHybridDecoderLayer)."""
+def _residual(x, y, c, scaling=None):
+    """``x + residual_multiplier * y`` (GraniteMoeHybridDecoderLayer);
+    with ``scaling [4, E]`` (a ``residual_scaling`` model's ``res_attn``
+    or ``res_mlp``) ``(s_res x + t_res) + (s_out y + t_out)``, float32
+    inside."""
+    if scaling is not None:
+        s_res, t_res, s_out, t_out = scaling.astype(jnp.float32)
+        return (x.astype(jnp.float32) * s_res + t_res
+                + y.astype(jnp.float32) * s_out + t_out).astype(x.dtype)
     return x + y if c.residual_multiplier == 1.0 else (
         x + y * c.residual_multiplier)
 
 
+def _cca_qkv(x, layer, c, cos, sin, tail, lengths=None):
+    """A CCA layer's first half on x [B, S, E] (``models/cca.py``):
+    attn-norm, the one down-projection, the two convolutions behind
+    ``tail`` (each row's ``[u | a | v2]`` of the position before its
+    first; None = an empty history), the q-k mean, the L2 norms and the
+    keys' temperature, THEN rope. Returns (q, k, v, the rows' next
+    tail)."""
+    h = llama.norm(x, layer["attn_norm"], c).astype(c.dtype)
+    with jax.named_scope("cca/proj"):
+        u, v1, v2 = cca.project(h, layer, c)
+    with jax.named_scope("cca/mix"):
+        q, k, v, tail = cca.mix(u, v1, v2, tail, layer, c, lengths)
+        q = _rotate(q, cos, sin).astype(c.dtype)
+        k = _rotate(k, cos, sin).astype(c.dtype)
+    return q, k, v, tail
+
+
 def _layer_finish(x, mixed, layer, c, experts=None, li=None,
-                  use_kernel=None):
+                  use_kernel=None, route=None):
     """Every engine layer's second half: ``mixed`` [B, S, E], the token
     mixer's output (attention's :func:`_attn_out`, or the Mamba-2
     mixer's), joins the residual, then ``x + MLP(norm(x))`` through the
     family's one MLP function (:func:`llama.mlp_block`: dense SwiGLU, or
     the routed block reading the stacked ``experts`` at layer ``li``).
-    Returns (x, rows): the routed block's per-expert assignment counts,
-    None for a dense model. A HELD SHARE's rows carry each token's chosen
+    Returns (x, rows, route): the routed block's per-expert assignment
+    counts, None for a dense model, and what a router with a state over
+    depth (``router_hidden_size``; ``route`` is what the layer before
+    left) hands the next layer, None for any other. A HELD SHARE's rows
+    carry each token's chosen
     experts ``[T * k]`` behind the counts: its output leaves out what the
     absent experts add, so its routing shows nowhere else
     (:meth:`ContinuousBatcher.take_routes`)."""
     if c.sandwich_norms:
         mixed = llama.norm(mixed, layer["post_attn_norm"], c)
-    x = _residual(x, mixed, c)
+    x = _residual(x, mixed, c, layer.get("res_attn"))
     h = llama.norm(x, layer["mlp_norm"], c).astype(c.dtype)
     down, routed = llama.mlp_block(h, layer, c, experts, li,
-                                   use_kernel=use_kernel)
+                                   use_kernel=use_kernel, route=route)
     if c.sandwich_norms:
         down = llama.norm(down, layer["post_mlp_norm"], c)
     rows = None if routed is None else routed.rows
     if rows is not None and c.experts_held:
         rows = jnp.concatenate([rows, routed.experts.reshape(-1)])
-    return _residual(x, down, c), rows
+    return (_residual(x, down, c, layer.get("res_mlp")), rows,
+            None if routed is None else routed.carry)
+
+
+def _route_carry(c, tokens):
+    """What the first layer's router reads of "the layer before it": a
+    zero row a token for a router with a state over depth, None (nothing
+    in any program) for every other model."""
+    if not c.router_hidden_size:
+        return None
+    return jnp.zeros((tokens.size, c.router_hidden_size), jnp.float32)
 
 
 def _concat_runs(parts):
@@ -477,7 +536,8 @@ def _split_caches(caches):
     """(arena, second cache) of an engine program's ``caches`` operand:
     the arena alone for a model whose every layer keeps all its K/V (or
     the latent cache in its place), else the pair: with the state cache
-    (state-space layers) or the ring (sliding-window layers)."""
+    (state-space layers), the ring (sliding-window layers) or the tail
+    cache (CCA layers, which keep K/V in the arena as well)."""
     if isinstance(caches, (PagedKVCache, LatentKVCache)):
         return caches, None
     return caches
@@ -513,8 +573,11 @@ def _forward_paged(params, tokens, positions, tables, limits,
     :func:`gated_delta.mixer_step`; either advances every slot's row of
     the per-slot state cache beside the arena in place; the arena holds the
     attention layers alone, so each kind indexes its own cache by its
-    index among layers of its kind. ``caches`` is the arena, or the pair
-    (arena, state cache) for a model with state layers.
+    index among layers of its kind. A "cca_attention" layer (S = 1 only)
+    uses BOTH stores: its K/V go to the arena like any attention
+    layer's, made from the slot's row of the tail cache, which it
+    advances. ``caches`` is the arena, or the pair (arena, state cache)
+    for a model with state layers.
 
     Returns (fp32 logits [B, S, V] through the final norm + lm_head,
     the updated ``caches``, and a routed model's per-layer per-expert
@@ -560,7 +623,7 @@ def _forward_paged(params, tokens, positions, tables, limits,
         # The arena (and the state cache) ride the CARRY, updated in
         # place layer by layer, not scan xs/ys: as per-iteration
         # inputs/outputs XLA materializes full cache copies every tick.
-        x, arenas, held, li = carry
+        x, arenas, held, route, li = carry
         ki = li + shift if shift else li    # index among layers of its kind
         if kind == "mamba":
             h = rms_norm(x, layer["attn_norm"], c.rms_eps)
@@ -586,6 +649,20 @@ def _forward_paged(params, tokens, positions, tables, limits,
                     positions, visits, scale, use_kernel)
             with jax.named_scope("eva/out_proj"):
                 mixed = _attn_out(o.astype(c.dtype), layer, c)
+        elif kind == "cca_attention":
+            # One layer, two stores: the slot's tail row makes this
+            # token's K/V, which go to the arena.
+            q, k, v, tail = _cca_qkv(
+                x, layer, c, cos, sin, jax.lax.dynamic_index_in_dim(
+                    held[0], ki, 0, keepdims=False))
+            held = (jax.lax.dynamic_update_index_in_dim(
+                held[0], tail, ki, 0),)
+            with jax.named_scope("cca/attend"):
+                o, arenas = _write_then_attend(
+                    arenas, ki, q, k, v, block_idx, offset, tables,
+                    positions, visits, scale, use_kernel)
+            with jax.named_scope("cca/out_proj"):
+                mixed = _attn_out(o.astype(c.dtype), layer, c)
         else:
             with _kind_scope(kind):
                 q, k, v, gate = _layer_qkv(
@@ -601,16 +678,17 @@ def _forward_paged(params, tokens, positions, tables, limits,
                         arenas, ki, q, k, v, block_idx, offset, tables,
                         positions, visits, scale, use_kernel)
                 mixed = _attn_out(o.astype(x.dtype), layer, c, gate)
-        x, rows = _layer_finish(x, mixed, layer, c, experts, li,
-                                use_kernel)
-        return (x, arenas, held, li + 1), rows
+        x, rows, route = _layer_finish(x, mixed, layer, c, experts, li,
+                                       use_kernel, route)
+        return (x, arenas, held, route, li + 1), rows
 
     arenas, held = tuple(cache), tuple(state or ())
+    route = _route_carry(c, tokens)
     rows = []
     for (kind, start, _, kind_start), tree in runs:
-        (x, arenas, held, _), run_rows = jax.lax.scan(
+        (x, arenas, held, route, _), run_rows = jax.lax.scan(
             functools.partial(layer_fn, kind=kind, shift=kind_start - start),
-            (x, arenas, held, jnp.int32(start)), tree)
+            (x, arenas, held, route, jnp.int32(start)), tree)
         rows.append(run_rows)
     if c.eva_window:
         # The rows whose new key filled its window: pool the window into
@@ -651,8 +729,8 @@ def _draft_forward_dense(dparams, tokens, positions, dcache: KVCache,
         o = decode_attention_reference(q[:, 0], ck, cv, positions, scale)
         ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
         cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-        x, _ = _layer_finish(x, _attn_out(o[:, None], layer, c), layer, c,
-                             experts, li)
+        x, *_ = _layer_finish(x, _attn_out(o[:, None], layer, c), layer, c,
+                              experts, li)
         return (x, ck_all, cv_all, li + 1), None
 
     scanned, experts = llama.split_layers(dparams)
@@ -767,7 +845,7 @@ class _PagedPrefix(NamedTuple):
 
 def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                            quantized, last_idx, use_kernel=None,
-                           state: Optional[StateCache] = None, slots=None,
+                           state=None, slots=None,
                            paged: Optional[_PagedPrefix] = None,
                            land=None):
     """Prefill forward over ``[shared prefix ++ suffix]``.
@@ -803,7 +881,9 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     "linear_attention" layer (always under ``paged``) starts from the
     state and conv tail in rows ``slots`` of ``state`` when the chunk has
     earlier ones (``paged.tables`` is not empty), from an empty history
-    when it is the prompt's first, and installs what it leaves there; and
+    when it is the prompt's first, and installs what it leaves there (a
+    "cca_attention" layer does the same with its row of the tail cache,
+    and its K/V are the arena's like a full-attention layer's); and
     ``stored`` comes back as ``{cache kind: K/V of the layers that keep
     theirs there}`` (``"attention"``: the arena; ``"sliding_attention"``:
     the ring). An EVA-attention model's layers land their own blocks
@@ -820,7 +900,7 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     runs, experts = llama.layer_runs(c, params)
 
     def layer_fn(carry, inputs, kind, shift):
-        x, held, li = carry
+        x, held, route, li = carry
         kept = ()
         if kind == "mamba":
             layer, = inputs
@@ -871,6 +951,24 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                     for a, b in zip(held, blocks))
             with jax.named_scope("eva/out_proj"):
                 mixed = _attn_out(o.astype(c.dtype), layer, c)
+        elif kind == "cca_attention":
+            layer, = inputs
+            # A chunk that is not its prompt's first goes on from the
+            # row the chunk before it installed; each row leaves the
+            # tail of its last REAL token.
+            q, k, v, tail = _cca_qkv(
+                x, layer, c, cos, sin,
+                held[0][li + shift, slots] if paged.tables.shape[1]
+                else None, last_idx + 1)
+            held = (held[0].at[li + shift, slots].set(tail),)
+            kept = (k, v)
+            with jax.named_scope("cca/attend"):
+                o = paged_chunk_attention(
+                    q, k, v, paged.cache.k, paged.cache.v, li + shift,
+                    paged.tables, 0,
+                    paged.tables.shape[1] * paged.cache.block_size, scale)
+            with jax.named_scope("cca/out_proj"):
+                mixed = _attn_out(o.astype(c.dtype), layer, c)
         elif paged is not None:
             layer, = inputs
             with _kind_scope(kind):
@@ -906,10 +1004,12 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
             cv = jnp.concatenate([pv_l, v_att], axis=1)
             mixed = _attn_out(_attend_cached(q, ck, cv, positions, scale),
                               layer, c, gate)
-        x, _ = _layer_finish(x, mixed, layer, c, experts, li, use_kernel)
-        return (x, held, li + 1), kept
+        x, _, route = _layer_finish(x, mixed, layer, c, experts, li,
+                                    use_kernel, route)
+        return (x, held, route, li + 1), kept
 
     stored, held = [], tuple(state or ())
+    route = _route_carry(c, tokens)
     if c.eva_window:
         held = (paged.cache.k, paged.cache.v)
     by_kind: Dict[str, list] = {"attention": [], "sliding_attention": []}
@@ -919,10 +1019,10 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
             whole = count == pk.shape[0]
             inputs += tuple(a if whole else a[kind_start:kind_start + count]
                             for a in (pk, pv))
-        (x, held, _), kept = jax.lax.scan(
+        (x, held, route, _), kept = jax.lax.scan(
             functools.partial(layer_fn, kind=kind,
                               shift=kind_start - start),
-            (x, held, jnp.int32(start)), inputs)
+            (x, held, route, jnp.int32(start)), inputs)
         stored.append(kept or None)
         if kind not in llama.STATE_KINDS:
             by_kind["sliding_attention" if kind == "sliding_attention"
@@ -933,7 +1033,7 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     if c.eva_window:
         held = PagedKVCache(*held)
     else:
-        held = StateCache(*held) if held else None
+        held = type(state)(*held) if held else None
     if paged is not None:
         return logits, {k: _concat_runs(v) for k, v in by_kind.items()}, held
     return logits, _concat_runs(stored), held
@@ -951,9 +1051,10 @@ def _prefill_chunk_paged(params, tokens, positions, cache, second, ptables,
     decode queries still see. ``second`` is the cache beside the arena:
     the ring, the state cache of a model with linear-attention layers
     (each row's state and conv tail are read from and written to row
-    ``slots[i]``), or None. Returns (logits [N, 1, V], arena, second)."""
+    ``slots[i]``), the tail cache of one with CCA layers (likewise), or
+    None. Returns (logits [N, 1, V], arena, second)."""
     ring = second if isinstance(second, RingKVCache) else None
-    state = second if isinstance(second, StateCache) else None
+    state = second if isinstance(second, (StateCache, TailCache)) else None
     bs = cache.block_size
     npb = tokens.shape[1] // bs
     m = ptables.shape[1]
@@ -1505,7 +1606,8 @@ class ContinuousBatcher:
         use_kernel = self.use_decode_kernel
         sampling_cfg = self.sampling
         block_size_c = self.block_size
-        chunks_state = "linear_attention" in cfg.layer_types
+        chunks_state = bool({"linear_attention", "cca_attention"}
+                            & set(cfg.layer_types))
         eva_sb = (eva.summaries(cfg) // self.block_size if cfg.eva_window
                   else 0)     # blocks of summaries a closed window keeps
 
@@ -1569,7 +1671,8 @@ class ContinuousBatcher:
                     or (held is None
                         and m * block_size_c + s_pad > PREFILL_DENSE_KEYS)):
                 # A long prompt's chunk, sliding-window layers, or
-                # linear-attention layers (whose chunks carry a state):
+                # linear-attention or CCA layers (whose chunks carry a
+                # state or a convolution tail):
                 # the earlier keys are read where they lie, blockwise.
                 # (Not a model with Mamba-2 layers, whose prompt is one
                 # piece; nor an int8 arena: both keep the path they had.)
@@ -1693,11 +1796,14 @@ class ContinuousBatcher:
     def _new_state(self):
         """The second cache beside the arena: the state cache of a model
         with state-space layers, the ring of one with sliding-window
-        layers, None for any other."""
+        layers, the tail cache of one with CCA layers, None for any
+        other."""
         c = self.config
-        if not (c.state_layers or c.window_layers):
+        if not (c.state_layers or c.window_layers or c.cca_layers):
             return None
         with jax.default_device(self.device):
+            if c.cca_layers:
+                return self._place(TailCache.create(c, self.num_slots))
             if c.window_layers:
                 return self._place(RingKVCache.create(
                     c, self.num_slots, self.block_size))
@@ -2651,7 +2757,7 @@ class ContinuousBatcher:
                             0.0) if c.num_experts else 0.0)
         total = (self.param_bytes + live_bytes
                  - int(self._expert_param_bytes * idle_experts))
-        if c.state_layers:
+        if c.state_layers or c.cca_layers:
             # Every slot's state and conv tail, live or not, read and
             # written once a tick.
             total += 2 * self.state.nbytes
@@ -3673,6 +3779,10 @@ class ContinuousBatcher:
                 block_bytes * sum(-(-st["pos"] // self.block_size)
                                   for st in self._slots.values()),
                 tags=self._mtags)
+        if self.config.cca_layers:
+            mdefs.CB_CCA_KV_BYTES.set(
+                self.cache.k.nbytes + self.cache.v.nbytes, tags=self._mtags)
+            mdefs.CB_CCA_TAIL_BYTES.set(self.state.nbytes, tags=self._mtags)
         if self.config.latent_layers:
             mdefs.CB_LATENT_KV_BYTES.set(self.cache.nbytes, tags=self._mtags)
         if self._ring is not None:

@@ -188,6 +188,21 @@ class LlamaConfig:
     eva_chunk: int = 0
     num_pred_heads: int = 1
     fp32_residual: bool = False
+    # The ZAYA1 family (``model_type`` "zaya"; SERVING ONLY): every layer
+    # is "cca_attention", Compressed Convolutional Attention
+    # (``models/cca.py``): q and k live in latents of ``num_heads x
+    # head_dim`` and ``num_kv_heads x head_dim`` (both under
+    # ``hidden_size``) behind two causal convolutions, the value's second
+    # half is the previous token's; K/V go to the arena like any GQA
+    # layer's, and the convolutions' last inputs a slot to
+    # ``paged_kv.TailCache`` beside it. ``router_hidden_size``: the
+    # router is an MLP of that width with a state carried from layer to
+    # layer (``ops.moe.route_mlp_top1``; the tree's ``router``), top 1.
+    # ``residual_scaling``: each sublayer joins the stream as ``(s_res x
+    # + t_res) + (s_out f(norm(x)) + t_out)``, four learned ``[E]``
+    # vectors a sublayer (``res_attn``, ``res_mlp`` ``[4, E]``, float32).
+    router_hidden_size: int = 0
+    residual_scaling: bool = False
 
     @property
     def rotary_dim(self) -> int:
@@ -209,6 +224,11 @@ class LlamaConfig:
     def window_layers(self) -> int:
         """Layers that keep the last ``sliding_window`` keys only."""
         return sum(t == "sliding_attention" for t in self.layer_types)
+
+    @property
+    def cca_layers(self) -> int:
+        """Layers that keep a convolution tail a slot beside their K/V."""
+        return sum(t == "cca_attention" for t in self.layer_types)
 
     @property
     def attn_layers(self) -> int:
@@ -372,6 +392,23 @@ class LlamaConfig:
             layer_types=("eva_attention",) * kw.get("num_layers", 32),
             zero_centered_norms=True, eva_window=2048, eva_chunk=16,
             num_pred_heads=8, fp32_residual=True), **kw})
+
+    @staticmethod
+    def zaya1_8b(**kw) -> "LlamaConfig":
+        """Zyphra/ZAYA1-8B (``zaya``, 8.4B-A0.76B): 40 layers of hidden
+        2048, each CCA attention (8 query heads and 2 KV heads of 128 in
+        latents of 1024 and 256, two 2-tap convolutions, rope on half a
+        head, theta 5e6) then 16 SwiGLU experts of 2048, top 1 by an MLP
+        router of 256 with a carry over depth; scaled residuals; tied
+        262k vocabulary."""
+        return LlamaConfig(**{**dict(
+            vocab_size=262272, hidden_size=2048, intermediate_size=2048,
+            num_layers=40, num_heads=8, num_kv_heads=2, head_dim=128,
+            max_seq_len=131072, rope_theta=5e6, rms_eps=1e-5,
+            layer_types=("cca_attention",) * kw.get("num_layers", 40),
+            partial_rotary_factor=0.5, num_experts=16,
+            num_experts_per_tok=1, router_hidden_size=256,
+            residual_scaling=True, tie_word_embeddings=True), **kw})
 
     @staticmethod
     def tiny(**kw) -> "LlamaConfig":
@@ -618,6 +655,10 @@ def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
             from ray_tpu.models import mla
 
             tree.update(mla.init_attention(c, k[2], n))
+        elif kind == "cca_attention":
+            from ray_tpu.models import cca
+
+            tree.update(cca.init_attention(c, k[2], n))
         elif attention:
             tree.update({"wq": dense(k[2], E, n, E, H, D),
                          "wk": dense(k[3], E, n, E, KV, D),
@@ -630,6 +671,16 @@ def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
         if c.sandwich_norms:
             tree["post_attn_norm"] = norm(k[6], n, E)
             tree["post_mlp_norm"] = norm(k[7], n, E)
+        if c.residual_scaling:
+            # (s_res, t_res, s_out, t_out): scales in 0.5..1.5, shifts
+            # small, so that dropping any one shows.
+            for name, kr in (("res_attn", k[6]), ("res_mlp", k[7])):
+                ks, kt = jax.random.split(kr)
+                scale = jax.random.uniform(ks, (n, 2, E), jnp.float32,
+                                           0.5, 1.5)
+                shift = 0.02 * jax.random.normal(kt, (n, 2, E), jnp.float32)
+                tree[name] = jnp.stack([scale[:, 0], shift[:, 0],
+                                        scale[:, 1], shift[:, 1]], axis=1)
         if c.qk_norm_per_head and attention:
             tree["q_norm"] = norm(k[8], n, D)
             tree["k_norm"] = norm(k[9], n, D)
@@ -642,8 +693,14 @@ def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
                          "w_down": dense(k[13], Md, n, Md, E)})
         else:
             Ms = c.shared_intermediate_size
-            tree["w_router"] = jax.random.normal(
-                k[14], (n, E, X), jnp.float32) * E ** -0.5
+            if c.router_hidden_size:
+                from ray_tpu.ops import moe
+
+                tree["router"] = moe.init_mlp_router(
+                    k[14], n, E, c.router_hidden_size, X)
+            else:
+                tree["w_router"] = jax.random.normal(
+                    k[14], (n, E, X), jnp.float32) * E ** -0.5
             if c.router_score == "sigmoid":
                 tree["expert_bias"] = 0.01 * jax.random.normal(
                     k[15], (n, X), jnp.float32)
@@ -663,6 +720,12 @@ def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
         "layers": {},
         "runs": runs,
     }
+    if c.tie_word_embeddings:
+        # The head is the embedding, at a head's scale (see
+        # :func:`_init_hybrid_params`): a logit is one standard deviation.
+        del out["lm_head"]
+        out["embed"] = (out["embed"].astype(jnp.float32)
+                        * E ** -0.5).astype(c.dtype)
     if X:
         Lm, Xh = c.moe_layers, c.experts_here
         ke = jax.random.split(k_experts, 3)
@@ -724,14 +787,15 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
     """The layer stack as RUNS of equal layers, in order: a list of
     ``(kind, start, count, kind_start)`` where ``kind`` is "attention",
     "mamba", "linear_attention", "sliding_attention", "full_attention",
-    "latent_attention" or "eva_attention", ``start`` the run's first
-    GLOBAL layer and ``kind_start`` its first index among layers that
-    share its cache (the K/V arena's layer for attention and full
+    "latent_attention", "eva_attention" or "cca_attention", ``start`` the
+    run's first GLOBAL layer and ``kind_start`` its first index among
+    layers that share its cache (the K/V arena's layer for attention and full
     attention, the state cache's for mamba and for linear attention, the
     ring's for sliding attention, the latent cache's for latent
     attention, the arena's again for EVA attention, which no other kind
-    shares it with). A model without ``layer_types`` is one attention
-    run.
+    shares it with, and for CCA attention the arena's and the tail
+    cache's both, which are one index because every layer is such a
+    one). A model without ``layer_types`` is one attention run.
 
     With ``params``: ``(runs, experts)``, each run followed by the tree
     a ``lax.scan`` over its layers takes (``params["runs"][i]``; for a
@@ -744,7 +808,7 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
                          f"num_layers is {c.num_layers}")
     runs, seen = [], {"attention": 0, "mamba": 0, "sliding_attention": 0,
                       "latent_attention": 0, "linear_attention": 0,
-                      "eva_attention": 0}
+                      "eva_attention": 0, "cca_attention": 0}
     for i, kind in enumerate(types):
         # "full_attention" keeps all its K/V in the arena, as "attention"
         # does: they count as one kind of cache.
@@ -913,7 +977,7 @@ def _routed_pieces(c: LlamaConfig, tokens: int, width: int, dtype) -> int:
 
 
 def mlp_block(h, layer, c: LlamaConfig, experts=None, li=None,
-              mesh: Optional[Mesh] = None, use_kernel=None):
+              mesh: Optional[Mesh] = None, use_kernel=None, route=None):
     """The layer's MLP sublayer on normed ``h [B, S, E]``: ``(out,
     routed)``. Dense SwiGLU (``routed`` None), or, for a config with
     experts, the dropless routed block over ``experts`` (the stacked
@@ -921,12 +985,22 @@ def mlp_block(h, layer, c: LlamaConfig, experts=None, li=None,
     ``routed`` is its :class:`~ray_tpu.ops.moe.Routed`: per-expert
     assignment counts ``[X]`` and each token's experts ``[B * S, k]``.
     A layer whose tree has no router (the leading dense layers of a
-    config with ``num_dense_layers``) takes the dense branch."""
-    if c.num_experts and "w_router" in layer:
+    config with ``num_dense_layers``) takes the dense branch. A layer
+    whose router is the MLP with a state over depth (the tree's
+    ``router``; ``router_hidden_size``) routes from ``route [B * S, R]``,
+    what the layer before it left (zeros into the first), and leaves its
+    own in ``routed.carry``."""
+    if c.num_experts and ("w_router" in layer or "router" in layer):
         from ray_tpu.ops import moe
 
         b, s, e = h.shape
         extra = {}
+        carry = None
+        if "router" in layer:
+            with jax.named_scope("moe/router"):
+                weights, idx, carry = moe.route_mlp_top1(
+                    h.reshape(b * s, e), layer["router"], route, c.rms_eps)
+            extra["routing"] = (weights, idx)
         if c.router_score == "sigmoid":
             extra["route"] = functools.partial(
                 moe.route_sigmoid_topk, bias=layer["expert_bias"],
@@ -936,12 +1010,15 @@ def mlp_block(h, layer, c: LlamaConfig, experts=None, li=None,
         if c.num_dense_layers:
             li = li - c.num_dense_layers    # its index among routed layers
         block = functools.partial(
-            moe.routed_block, w_router=layer["w_router"], experts=experts,
+            moe.routed_block, w_router=layer.get("w_router"), experts=experts,
             layer=li, top_k=c.num_experts_per_tok,
             norm_topk=c.norm_topk_prob, use_kernel=use_kernel, **extra)
-        pieces = _routed_pieces(c, b * s, e, h.dtype)
+        # (Routed already, the rows cannot be cut into pieces here.)
+        pieces = 1 if carry is not None else _routed_pieces(
+            c, b * s, e, h.dtype)
         if pieces == 1:
             out, routed = block(h.reshape(b * s, e))
+            routed = routed._replace(carry=carry)
         else:
             # No token's result depends on the other rows, so the pieces'
             # results are the whole batch's.
@@ -1035,8 +1112,9 @@ def forward(
     if c.layer_types:
         raise NotImplementedError(
             "a config with layer_types (state-space, linear-attention, "
-            "sliding-window, latent-attention or eva-attention layers) is "
-            "served by the continuous-batching engine only: "
+            "sliding-window, latent-attention, eva-attention or "
+            "cca-attention layers) is served by the continuous-batching "
+            "engine only: "
             "llama.forward, loss_fn and LlamaGenerator do not run it")
     seq_len = tokens.shape[1]
     cos, sin = rope_frequencies(c.head_dim, seq_len, c.rope_theta)

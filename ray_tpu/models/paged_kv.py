@@ -257,6 +257,35 @@ class StateCache(NamedTuple):
         return int(self.ssm.nbytes + self.conv.nbytes)
 
 
+class TailCache(NamedTuple):
+    """What the layers of a compressed-convolutional-attention model
+    (``layer_types`` "cca_attention", ``models/cca.py``) keep for each
+    slot BESIDE their K/V in the arena: ``tail [L, slots, W]``, one row
+    a slot a layer in the model's dtype, the last token's ``[u | a |
+    v2]``: the two convolutions' last inputs and the half of the next
+    token's value that is this token's. No recurrent state (a
+    :class:`StateCache` would carry an empty one through every program).
+    Lives and dies as a state cache's row does: a prompt's first chunk
+    starts from zeros, a later chunk from the row the one before it
+    wrote, every tick advances it, the slot's next prefill overwrites
+    it."""
+
+    tail: jnp.ndarray
+
+    @classmethod
+    def create(cls, config: llama.LlamaConfig,
+               num_slots: int) -> "TailCache":
+        from ray_tpu.models.cca import tail_width
+
+        return cls(tail=jnp.zeros(
+            (config.cca_layers, num_slots, tail_width(config)),
+            config.dtype))
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.tail.nbytes)
+
+
 def ring_blocks(window: int, block_size: int) -> int:
     """Entries of a slot's ring for a window of ``window`` keys: the
     ``window // bs + 1`` blocks a query's keys can span, and one more,

@@ -194,6 +194,71 @@ def route_sigmoid_topk(h: jnp.ndarray, w_router: jnp.ndarray, k: int,
     return weights * scale, idx.astype(jnp.int32)
 
 
+def init_mlp_router(key, n: int, hidden: int, width: int, experts: int):
+    """``n`` layers' MLP routers (:func:`route_mlp_top1`), stacked,
+    float32 like every router. Seeded so that dropping a term shows and
+    a route has a margin: ``out`` at 5 x fan-in scale, so the chosen
+    expert's probability is some 0.3-0.6 of 16 and not 1/16 (top 1 is
+    NOT renormalised: at fan-in scale the routed block would add a
+    sixteenth of an expert); ``gamma`` uniform in 0.25..0.75; the norm's
+    weight in 0.5..1.5; the three biases normal at 0.1; ``beta`` normal
+    at 0.05, the spacing of the top probabilities."""
+    k = jax.random.split(key, 10)
+    f32 = jnp.float32
+
+    def dense(key, fan_in, *shape, gain=1.0):
+        return jax.random.normal(key, shape, f32) * gain * fan_in ** -0.5
+
+    def centred(key, *shape, gain=1.0):
+        # Every column sums to zero over its inputs: a gelu's output has
+        # a positive mean, which would otherwise reach every token's
+        # logits as the same offset an expert and collapse the load onto
+        # one or two of them.
+        w = dense(key, width, n, width, *shape, gain=gain)
+        return w - w.mean(axis=1, keepdims=True)
+
+    return {
+        "down": dense(k[0], hidden, n, hidden, width),
+        "down_b": 0.1 * jax.random.normal(k[1], (n, width), f32),
+        "gamma": jax.random.uniform(k[2], (n, width), f32, 0.25, 0.75),
+        "norm": jax.random.uniform(k[3], (n, width), f32, 0.5, 1.5),
+        "w1": dense(k[4], width, n, width, width),
+        "b1": 0.1 * jax.random.normal(k[5], (n, width), f32),
+        "w2": centred(k[6], width),
+        "b2": 0.1 * jax.random.normal(k[7], (n, width), f32),
+        "out": centred(k[8], experts, gain=5.0),
+        "beta": 0.05 * jax.random.normal(k[9], (n, experts), f32),
+    }
+
+
+def route_mlp_top1(h: jnp.ndarray, router: Dict[str, jnp.ndarray],
+                   carry: jnp.ndarray, eps: float):
+    """The ZAYA router: not ``h @ w_router`` but a small MLP with a state
+    that runs over DEPTH. ``r = h down + down_b`` (``[T, R]``); ``r +=
+    gamma * carry``, ``carry [T, R]`` the same tokens' ``r`` of the
+    layer BEFORE (zeros into the first layer, which so has no such
+    term); ``z = gelu(rms(r; norm) w1 + b1)``, ``z = gelu(z w2 + b2)``,
+    ``p = softmax(z out)`` over all X experts; the expert is
+    ``argmax(p + beta)`` (``beta`` steers the SELECTION only), its weight
+    that expert's ``p``, not renormalised. All float32, exact (erf)
+    gelu. Returns (weights [T, 1] float32, idx [T, 1] int32, ``r``: the
+    next layer's carry, the averaged one)."""
+    f32, hp = jnp.float32, jax.lax.Precision.HIGHEST
+    w = {name: a.astype(f32) for name, a in router.items()}
+
+    def dot(x, name):
+        return jnp.dot(x, w[name], precision=hp)
+
+    r = dot(h.astype(f32), "down") + w["down_b"] + w["gamma"] * carry
+    z = r * jax.lax.rsqrt(jnp.mean(r * r, axis=-1, keepdims=True) + eps)
+    z = jax.nn.gelu(dot(z * w["norm"], "w1") + w["b1"], approximate=False)
+    z = jax.nn.gelu(dot(z, "w2") + w["b2"], approximate=False)
+    probs = jax.nn.softmax(dot(z, "out"), axis=-1)
+    idx = jnp.argmax(probs + w["beta"], axis=-1)[:, None]
+    return (jnp.take_along_axis(probs, idx, axis=-1), idx.astype(jnp.int32),
+            r)
+
+
 # What one ``moe_gmm`` call may hold in VMEM, declared to the compiler
 # (``vmem_limit_bytes``; Mosaic's own default is 16 MiB of a v5e's 128).
 GMM_VMEM_BYTES = 40 << 20
@@ -414,6 +479,9 @@ class Routed(NamedTuple):
     """What :func:`routed_block` did besides its output."""
     rows: jnp.ndarray      # [X] int32: assignments each HELD expert computed
     experts: jnp.ndarray   # [T, k] int32: each token's experts, best first
+    # What a router with a state over depth hands the next layer's
+    # (:func:`route_mlp_top1`); None for every other router.
+    carry: Any = None
 
 
 def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
@@ -421,7 +489,8 @@ def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
                  top_k: int, norm_topk: bool = False,
                  use_kernel: Optional[bool] = None,
                  route=route_softmax_topk,
-                 held: Optional[Tuple[int, int]] = None
+                 held: Optional[Tuple[int, int]] = None,
+                 routing: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
                  ) -> Tuple[jnp.ndarray, Routed]:
     """The dropless SwiGLU expert block on normed tokens x [T, E]:
     ``sum_e p_e * down_e(silu(gate_e x) * up_e x)`` over each token's
@@ -429,7 +498,11 @@ def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
     and ``moe_down`` [L, X, M, E] read at ``layer``, or one layer's
     ``[X, ...]`` with ``layer`` None. No capacity: every assignment is
     computed, and a token's result does not depend on the other rows.
-    ``route(x, w_router, top_k, norm_topk)`` is the router. The experts
+    ``route(x, w_router, top_k, norm_topk)`` is the router; a caller
+    whose router is not a function of ``x`` and one matrix (it carries a
+    state from layer to layer: :func:`route_mlp_top1`) has routed
+    already and passes ``routing = (weights [T, k], idx [T, k])``, with
+    ``w_router`` None. The experts
     are TWO grouped calls on one schedule: :func:`grouped_swiglu` (gate
     and up: the activation from float32 accumulators, rounded once to
     ``x.dtype``; no ``[T * k, M]`` gate or up array exists), then
@@ -447,10 +520,14 @@ def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
     other chip's part of the sum.
     Returns (out [T, E], :class:`Routed`)."""
     t, _ = x.shape
-    num_experts = w_router.shape[-1]
+    num_experts = (experts["moe_gate"].shape[-3] if w_router is None
+                   else w_router.shape[-1])
     with jax.named_scope("moe"):
-        with jax.named_scope("route"):
-            weights, idx = route(x, w_router, top_k, norm_topk)
+        if routing is not None:
+            weights, idx = routing
+        else:
+            with jax.named_scope("route"):
+                weights, idx = route(x, w_router, top_k, norm_topk)
         with jax.named_scope("sort"):
             flat = idx.reshape(-1)                       # [T * k]
             if held is not None:

@@ -57,6 +57,9 @@ CONFIGS = {
     "eva_attention": lambda: llama.LlamaConfig.evabyte_6_5b(
         **_SMALL, num_layers=2, num_kv_heads=4, head_dim=16, eva_window=32,
         eva_chunk=4, num_pred_heads=2),
+    "cca_attention": lambda: llama.LlamaConfig.zaya1_8b(
+        **_SMALL, num_layers=2, num_kv_heads=2, head_dim=16, num_experts=4,
+        router_hidden_size=16),
 }
 
 # How a caller asks the constructor for a capability, and what the
@@ -73,7 +76,8 @@ A_SECOND_KIND = {
     "mamba": "linear_attention", "linear_attention": "sliding_attention",
     "sliding_attention": "latent_attention",
     "latent_attention": "full_attention",
-    "eva_attention": "full_attention"}
+    "eva_attention": "full_attention",
+    "cca_attention": "full_attention"}
 # The methods that offer a capability on a live engine.
 ASKED_OF_A_METHOD = {
     "handoff": [("export_kv_payload", (0,)), ("import_kv_payload", ({},)),
@@ -102,7 +106,7 @@ def _names(err, kind, capability, called):
 def test_the_table_covers_the_kinds_the_engine_keeps_a_cache_for():
     assert set(_KIND_CANNOT) == set(_KIND_NAMES) == set(CONFIGS) == {
         *llama.STATE_KINDS, "sliding_attention", "latent_attention",
-        "eva_attention"}
+        "eva_attention", "cca_attention"}
 
 
 @pytest.mark.parametrize("kind,capability", [

@@ -981,6 +981,93 @@ def test_compiled_eva_prefill_chunk_lands_blocks_in_the_layer_loop(
     assert memory.alias_size_in_bytes >= 2 * cache.k.size * 2
 
 
+# ------------------------- the CCA cell: one layer, two stores (PR 46)
+
+def _cca_cell(v5e_chip):
+    """(config, params, (arena, tail cache)) of the cell
+    ``serve_cca_decode`` as shapes on a described v5e."""
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models.paged_kv import PagedKVCache, TailCache
+
+    cfg = llama.LlamaConfig.zaya1_8b(num_layers=10, max_seq_len=7168,
+                                     experts_held=(0, 16))
+
+    def spec(a):
+        return S(a.shape, a.dtype, sharding=v5e_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        functools.partial(cb.init_engine_params, cfg),
+        jax.random.PRNGKey(0)))
+    cache = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        PagedKVCache.create, cfg, 96 * 112 + 1, 64)))
+    tail = jax.tree.map(spec, jax.eval_shape(functools.partial(
+        TailCache.create, cfg, 96)))
+    assert cache.k.shape == (10, 10753, 2, 64, 128)
+    assert tail.tail.shape == (10, 96, 2688)
+    return cfg, params, (cache, tail)
+
+
+def test_compiled_cca_tick_keeps_both_stores_in_place(v5e_chip):
+    """The cell ``serve_cca_decode``'s tick for a described v5e at the
+    cell's own sizes (ZAYA1-8B's widths, 10 layers, 96 slots over 10,753
+    blocks): ONE layer loop that writes the arena through
+    ``paged_kv_write`` and reads it through ``paged_decode_attn`` (the
+    kernels every attention family uses, at 2 KV heads), advances the
+    tail cache beside it and runs the top-1 experts through ``moe_gmm``;
+    the 7 GB arena and the tails are aliased in to out, and the scratch
+    beside 12.3 GB of arguments stays under half a gigabyte."""
+    from ray_tpu.models import continuous_batching as cb
+
+    cfg, params, caches = _cca_cell(v5e_chip)
+    row = S((96,), jnp.int32, sharding=v5e_chip)
+    tables = S((96, 112), jnp.int32, sharding=v5e_chip)
+    step = S((), jnp.int32, sharding=v5e_chip)
+    tick = functools.partial(cb._decode_tick_paged, config=cfg,
+                             use_kernel=True)
+    compiled = jax.jit(tick, donate_argnums=(5,)).lower(
+        params, row, row, tables, row, caches, step).compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 1
+    assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == 2
+    assert len(re.findall(r"%moe_gmm[.\d]* = ", hlo)) == 2
+    memory = compiled.memory_analysis()
+    arena = 2 * caches[0].k.size * 2
+    assert memory.alias_size_in_bytes >= arena + caches[1].tail.size * 2
+    assert memory.temp_size_in_bytes < 512 << 20
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15 << 30)
+
+
+@pytest.mark.parametrize("rows,earlier", [(8, 0), (8, 48), (1, 48)])
+def test_compiled_cca_prefill_chunk_fits_beside_the_arena(v5e_chip, rows,
+                                                          earlier):
+    """The cell's prefill chunks (1 and 8 rows x 1024 tokens, a prompt's
+    first and its fourth, over 48 earlier blocks): K/V through the
+    arena, the tails through rows ``slots`` of the tail cache, both
+    aliased in to out; arguments and scratch fit the chip."""
+    from ray_tpu.models import continuous_batching as cb
+
+    cfg, params, (cache, tail) = _cca_cell(v5e_chip)
+
+    def prefill(params, tokens, cache, tail, ptables, tables_w, last_idx,
+                slots):
+        positions = earlier * 64 + jnp.arange(tokens.shape[1])
+        return cb._prefill_chunk_paged(
+            params, tokens, positions, cache, tail, ptables, tables_w,
+            last_idx, slots, cfg, True)
+
+    def ints(*shape):
+        return S(shape, jnp.int32, sharding=v5e_chip)
+
+    compiled = jax.jit(prefill, donate_argnums=(2, 3)).lower(
+        params, ints(rows, 1024), cache, tail, ints(rows, earlier),
+        ints(rows, 16), ints(rows), ints(rows)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * cache.k.size * 2
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15 << 30)
+
+
 # ------------------------------------- the FSDP train step's collectives
 
 _HEAD_COLLECTIVE = re.compile(
