@@ -24,34 +24,45 @@ Two bandwidth levers stack:
   scalar prefetch and its length is the grid's bound, a traced scalar.
   A freed slot has no visit and its output row stays zero; a dead table
   entry is never stepped over, and a slot's last visit may be short: a
-  dead sub-block is neither fetched nor attended.
-  Blocks still arrive through BlockSpecs (one operand a sub-block, each
-  one whole block chosen by the schedule), which pipeline across slot
-  boundaries; a kernel that copies them itself out of ``pl.ANY``
-  operands cannot read an int8 arena, because Mosaic refuses any slice
+  dead sub-block is not fetched, and the mask gives its keys nothing.
+  The kernel copies a visit's K and V blocks out of the arena ITSELF
+  (``pl.ANY`` operands, one ``make_async_copy`` a sub-block into a
+  double buffer over the visits: a step starts the next visit's copies,
+  whichever slot it belongs to, before it waits for its own), because
+  the BlockSpec pipeline pays for every operand in scalar work: an
+  index map a step, a compare with the step before, the spills of 33
+  operands' state. At one operand a sub-block that was 1,790 of a
+  16-block step's 2,423 instruction bundles against 633 of arithmetic
+  (the compiler's own bundle dump, PR 47), and the step took longer
+  than its megabyte; the kernel's own copies cost 53 bundles a
+  sub-block and nothing for a dead one. Only an int8 arena's scales
+  still arrive through BlockSpecs (one operand a sub-block, a dead one
+  naming the entry it read the step before): Mosaic refuses any slice
   of an HBM array whose minor axis is under 128 lanes, and the scale
   sidecar's is ``block_size``.
   A grid step is not free: on the v5e one that ``pl.when`` skips costs
   0.11-0.2 us with no HBM traffic at all, so a ``(batch, table_blocks)``
   grid over 48 slots x 32 entries with 48 of them live spends five
   sixths of its time on entries that hold nothing (224 us against 34,
-  PERF.md section 6). A live one of one block costs 0.3 us beyond its
-  bytes, and that is the softmax chain's LATENCY (scores, max, exp,
-  sum, weighted values: each waits for the one before), not its work:
-  a step of several blocks whose chains the scheduler may interleave
-  (no branch between them, the state in registers) reads four blocks
-  in 1.4 us where four steps took 2.5 (PERF.md section 6, PR 33).
+  PERF.md section 6). A live one of one block costs 0.34 us beyond its
+  bytes, most of it the softmax chain's LATENCY (scores, max, exp,
+  sum, weighted values: each waits for the one before), so a step
+  covers about a megabyte of its slot's K and V whatever a block
+  weighs (four 262 KB blocks at 8 kv heads, sixteen 64 KB blocks at 2)
+  and folds them in ONE chain (:func:`_fold_blocks`): with no branch
+  in the body, a dead sub-block masked and not skipped.
 * **int8 KV quantization** — the arena stores K/V as int8 with
   per-token/per-kv-head fp32 scales kept in block-shaped sidecars
   (``[num_blocks, KVH, block_size]``), gathered by the same table;
   dequantization happens in-register after the block is resident, so
   bytes-per-token roughly halve against bf16.
 
-The online-softmax core (:func:`_init_state` / :func:`_attend_block` /
-:func:`_finalize`): K and V stream through VMEM in their storage dtype
-with fp32 accumulation and a running max/sum in VMEM scratch; heads are
-the batch dim of the two MXU contractions and GQA keeps each head's
-query group ``[G, D]`` resident, so a block is read exactly once.
+The online-softmax core (:func:`_init_state` / :func:`_block_scores` /
+:func:`_fold_blocks` / :func:`_finalize`): K and V stream through VMEM
+in their storage dtype with fp32 accumulation and a running max/sum in
+VMEM scratch; heads are the batch dim of the two MXU contractions and
+GQA keeps each head's query group ``[G, D]`` resident, so a block is
+read exactly once.
 Per-slot positions arrive via scalar prefetch and set both the schedule
 and the in-block causal mask.
 
@@ -223,33 +234,37 @@ def _block_scores(q, k, pos, first_col, *, scale, k_scale=None,
     return jnp.where(seen, s, MASK_VALUE)
 
 
-def _fold_block(s, m_prev, l_prev, acc, v, v_scale=None):
-    """One online-softmax step: fold a block's scores ``s`` [KVH, G, T]
-    and values ``v`` [KVH, T, D] into the running max and sum [KVH, G,
-    1] and the accumulator [KVH, G, D]; returns the three. ``v_scale``
-    [KVH, T] scales the probability columns, as ``k_scale`` the
-    scores'. A block no key of which is seen leaves all three as they
-    were: its probabilities are exp(MASK - m) = 0."""
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)                                   # [KVH, G, T]
+def _fold_blocks(scores, m_prev, l_prev, acc, values, v_scales):
+    """One online-softmax step over a visit's blocks: fold their scores
+    (``[KVH, G, T]`` each) and values (``[KVH, T, D]`` each) into the
+    running max and sum [KVH, G, 1] and the accumulator [KVH, G, D];
+    returns the three. ONE chain whatever the blocks' count: the
+    elementwise maximum of the score tiles, one cross-lane reduce, one
+    ``exp`` pass, one rescale of the accumulator, the ``p @ v`` products
+    summed; one block folds as it always did. A ``v_scales`` entry
+    [KVH, T] scales the probability columns, as ``k_scale`` the scores'.
+    A block no key of which is seen adds nothing: its probabilities are
+    exp(MASK - m) = 0."""
+    tile = functools.reduce(jnp.maximum, scores)
+    m_new = jnp.maximum(m_prev, jnp.max(tile, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    if v_scale is not None:
-        p = p * v_scale[:, None, :]
-    pv = jax.lax.dot_general(
-        p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)                  # [KVH, G, D]
-    return m_new, l_new, acc * alpha + pv
+    probs = [jnp.exp(s - m_new) for s in scores]             # [KVH, G, T]
+    l_new = alpha * l_prev + jnp.sum(functools.reduce(jnp.add, probs),
+                                     axis=-1, keepdims=True)
+    acc = acc * alpha
+    for p, v, v_scale in zip(probs, values, v_scales):
+        if v_scale is not None:
+            p = p * v_scale[:, None, :]
+        acc += jax.lax.dot_general(
+            p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)              # [KVH, G, D]
+    return m_new, l_new, acc
 
 
-def _attend_block(q, k, v, pos, first_col, acc_ref, m_ref, l_ref, *, scale,
-                  k_scale=None, v_scale=None, window: int = 0):
-    """:func:`_block_scores` then :func:`_fold_block` on the state in
-    VMEM scratch (the max and the sum broadcast along lanes)."""
-    s = _block_scores(q, k, pos, first_col, scale=scale, k_scale=k_scale,
-                      window=window)
-    m_new, l_new, acc = _fold_block(s, m_ref[:, :, :1], l_ref[:, :, :1],
-                                    acc_ref[:], v, v_scale)
+def _store_state(state, acc_ref, m_ref, l_ref):
+    """The softmax state back into VMEM scratch (the max and the sum
+    broadcast along lanes)."""
+    m_new, l_new, acc = state
     acc_ref[:] = acc
     m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -280,16 +295,28 @@ def _first_live(pos, window: int, block_size: int):
     return jnp.maximum(pos - (window - 1), 0) // block_size if window else 0
 
 
-# K and V bytes a grid step takes, about: a step covers as many
-# consecutive blocks of its slot as fit, at most ``MAX_VISIT_BLOCKS``
-# (nothing above was timed). One block a step spends half its time on
-# steps and on the softmax chain's latency at 65-86 live blocks a slot;
-# on the v5e four 262 KB blocks a step (8 kv heads, bf16) run at 1.14 ms
-# where one a step takes 1.92 and the bytes 1.00, and two 524 KB blocks
-# (16 kv heads) at 166 us against 188 and 138, three or four slower
-# again (`chip_smoke.py kernels` times 1 to 4; PERF.md section 6, PR 33).
+# K and V bytes of ONE slot a grid step takes, about, whatever a block
+# weighs: a step covers as many consecutive blocks of its slot as fit,
+# at most ``MAX_VISIT_BLOCKS`` (the widest step that was timed; a toy
+# arena's 8 KB blocks would ask for 128 copies a step). What a step
+# costs beyond its bytes is paid once a megabyte. Timed alone on the
+# v5e at each cell's fill, a call against its live bytes at 819 GB/s
+# (`chip_smoke.py kernels` times every width; PERF.md section 6, PR 33
+# for the 8- and 16-head rows, PR 47 for all of them with the one-chain
+# fold and the kernel's own copies):
+#   8 kv heads of 128, a 262 KB block: 65 live blocks a slot, one a
+#     step 1.94 ms, two 1.32, three 1.16, four 1.13, eight 1.13, the
+#     bytes 1.00;
+#   16 kv heads, 524 KB: 4-5 live blocks a slot, two a step 165 us, one
+#     192, four 172, the bytes 138;
+#   2 kv heads of 128, 64 KB: 33-100 live blocks a slot, four a step
+#     1.12 ms (the cap of PR 33, which was set for 8-head arenas: 1.21
+#     with BlockSpec operands), eight 0.78, sixteen 0.63 (0.87 with
+#     BlockSpec operands), the bytes 0.51;
+#   2 kv heads of 256, 131 KB: 17-32 live blocks a slot, four a step
+#     1.44 ms, eight 1.20, sixteen 1.18, the bytes 1.00.
 VISIT_BYTES = 1 << 20
-MAX_VISIT_BLOCKS = 4
+MAX_VISIT_BLOCKS = 16
 
 
 def visit_blocks(arena_k) -> int:
@@ -316,8 +343,9 @@ def paged_visits(tables, positions, limits=None, *, block_size: int,
     ``p`` of visit ``v`` at ``p * V + v``, an index into the flattened
     ``tables``), visits ``[1]``), the lists valid past the end. A DEAD
     sub-block (past the slot's last live block) names the entry its
-    operand read at the step before, so the pipeline fetches nothing for
-    it, and the kernel skips it.
+    operand read at the step before, so a BlockSpec pipeline (an int8
+    arena's scales here, the latent kernel's blocks) fetches nothing for
+    it; this kernel's own copies skip it, and its arithmetic masks it.
 
     With ``window`` the table is a slot's RING of ``nb`` entries and a
     query sees the last ``window`` keys: its live blocks are ``[(pos -
@@ -386,60 +414,96 @@ def paged_visits(tables, positions, limits=None, *, block_size: int,
 
 
 def _paged_kernel(layer_ref, tables_ref, pos_ref, slot_ref, block_ref,
-                  where_ref, q_ref, *rest, scale, block_size, num_blocks,
-                  per_visit, quantized, window=0):
+                  where_ref, count_ref, q_ref, k_hbm, v_hbm, *rest, scale,
+                  block_size, num_blocks, per_visit, listed, quantized,
+                  window=0):
     n = per_visit
-    k_refs, v_refs, rest = rest[:n], rest[n:2 * n], rest[2 * n:]
     if quantized:
         ks_refs, vs_refs, rest = rest[:n], rest[n:2 * n], rest[2 * n:]
-    _, o_ref, acc_ref, m_ref, l_ref = rest
+    _, o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref = rest
     visit = pl.program_id(0)
-    pos = pos_ref[slot_ref[visit]]
-    j = block_ref[visit]
-    # A ring's logical blocks run past its width; a table's do not.
-    last = pos // block_size
-    if not window:
-        last = jnp.minimum(last, num_blocks - 1)
+    half = visit % 2
+
+    def span(u):
+        """Visit ``u``'s query position, first block and the last live
+        block of its slot. A ring's logical blocks run past its width; a
+        table's do not: a query past its table's end sees the whole
+        table and no more."""
+        pos = pos_ref[slot_ref[u]]
+        if not window:
+            pos = jnp.minimum(pos, num_blocks * block_size - 1)
+        return pos, block_ref[u], pos // block_size
+
+    def copies(into, p, block):
+        """Sub-block ``p``'s K and V out of the arena into buffer half
+        ``into``."""
+        return [pltpu.make_async_copy(hbm.at[layer_ref[0], block],
+                                      buf.at[into, p], sems.at[into, c, p])
+                for c, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf)))]
+
+    def fetch(u, into):
+        """Start the copies of visit ``u``'s live sub-blocks (a slot's
+        last visit may be short, and a dead sub-block is not fetched):
+        the schedule names the table entry of each, the table the arena
+        block. A loop, so a short visit costs its own blocks only."""
+        _, first, last = span(u)
+
+        def start(p, carry):
+            for copy in copies(into, p,
+                               tables_ref[where_ref[p * listed + u]]):
+                copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(last - first + 1, n), start, 0)
+
+    # The kernel's own double buffer over the VISITS: this step starts
+    # the next visit's copies (whichever slot it belongs to) before it
+    # waits for its own, which the step before started.
+    @pl.when(visit == 0)
+    def _first():
+        fetch(0, 0)
+
+    @pl.when(visit + 1 < count_ref[0])
+    def _next():
+        fetch(visit + 1, 1 - half)
+
+    pos, j, last = span(visit)
+    for p in range(n):
+        @pl.when(j + p <= last)
+        def _arrived(p=p):
+            for copy in copies(half, p, 0):
+                copy.wait()
 
     @pl.when(j == _first_live(pos, window, block_size))
     def _init():
         _init_state(acc_ref, m_ref, l_ref)
 
-    # The visit's blocks in logical order, one online-softmax step each:
-    # the arithmetic of one block a step, whatever a step covers.
-    def keys(p):
-        return dict(k=k_refs[p][0, 0], first_col=(j + p) * block_size,
-                    k_scale=ks_refs[p][0, 0] if quantized else None)
+    # One body for every visit, one softmax chain for its blocks. A DEAD
+    # sub-block (past the slot's last block) was not fetched, its buffer
+    # holds whatever an earlier visit left there: every key of it lies
+    # past ``pos``, so the causal mask gives them probability 0, and its
+    # values are zeroed besides, because 0 x NaN is NaN and nothing says
+    # what memory nobody wrote holds (an int8 block holds no NaN; its
+    # scales may).
+    def live(p, a):
+        return a if p == 0 else jnp.where(j + p <= last, a,
+                                          jnp.zeros_like(a))
 
-    def values(p):
-        return dict(v=v_refs[p][0, 0],
-                    v_scale=vs_refs[p][0, 0] if quantized else None)
-
-    @pl.when(j + n - 1 <= last)
-    def _full():
-        # No branch between the blocks and the state in registers, so
-        # the scheduler overlaps one block's scores with the fold of the
-        # block before: the softmax chain's latency, not its work, is
-        # what a block costs beyond its bytes.
-        q = q_ref[0]
-        scores = [_block_scores(q, pos=pos, scale=scale, window=window,
-                                **keys(p)) for p in range(n)]
-        state = (m_ref[:, :, :1], l_ref[:, :, :1], acc_ref[:])
-        for p in range(n):
-            state = _fold_block(scores[p], *state, **values(p))
-        m_new, l_new, acc = state
-        acc_ref[:] = acc
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    # A slot's last visit, short: block by block, the dead ones (past
-    # the slot's last block) neither read nor attended.
-    for p in range(n - 1):
-        @pl.when((j + n - 1 > last) & (j + p <= last))
-        def _short(p=p):
-            _attend_block(q_ref[0], pos=pos, acc_ref=acc_ref, m_ref=m_ref,
-                          l_ref=l_ref, scale=scale, window=window,
-                          **keys(p), **values(p))
+    q = q_ref[0]
+    scores = [_block_scores(
+        q, k_buf[half, p], pos, (j + p) * block_size, scale=scale,
+        k_scale=ks_refs[p][0, 0] if quantized else None, window=window)
+        for p in range(n)]
+    if quantized:
+        values = [v_buf[half, p] for p in range(n)]
+        v_scales = [live(p, vs_refs[p][0, 0]) for p in range(n)]
+    else:
+        values = [live(p, v_buf[half, p]) for p in range(n)]
+        v_scales = [None] * n
+    _store_state(_fold_blocks(scores, m_ref[:, :, :1], l_ref[:, :, :1],
+                              acc_ref[:], values, v_scales),
+                 acc_ref, m_ref, l_ref)
 
     @pl.when(last < j + n)
     def _fin():
@@ -466,41 +530,41 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
     qg = q.reshape(b, hkv, group, d)
     q_spec = pl.BlockSpec(
         (1, hkv, group, d),
-        lambda v, ly, tab, po, sl, bl, wh: (sl[listed(v)], 0, 0, 0))
-
-    # The table gather IS the index_map: the scalar-prefetched layer,
-    # schedule and block tables choose which arena block each of a
-    # visit's operands streams into VMEM. One operand a sub-block, so
-    # the blocks still arrive through the BlockSpec pipeline, and one
-    # whose block does not change between two steps is not fetched again.
-    def specs(shape):
-        zeros = (0,) * (len(shape) - 2)
-        return [pl.BlockSpec(
-            shape, lambda v, ly, tab, po, sl, bl, wh, p=p: (
-                ly[0], tab[wh[p * n_visits + listed(v)]], *zeros))
-            for p in range(per)]
-
-    kv_specs = specs((1, 1, hkv, block_size, d))
-    in_specs = [q_spec] + kv_specs + kv_specs
-    inputs = [qg] + [arena_k] * per + [arena_v] * per
+        lambda v, ly, tab, po, sl, bl, wh, n: (sl[listed(v)], 0, 0, 0))
+    # K and V stay in HBM: the kernel copies a visit's blocks itself.
+    in_specs = [q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    inputs = [qg, arena_k, arena_v]
     if quantized:
-        sc_specs = specs((1, 1, hkv, block_size))
+        # The scales arrive through BlockSpecs, one operand a sub-block
+        # (the scalar-prefetched layer, schedule and tables choose the
+        # block; one whose block does not change between two steps is
+        # not fetched again): Mosaic refuses a slice of an HBM array
+        # whose minor axis is under 128 lanes, and theirs is
+        # ``block_size``.
+        sc_specs = [pl.BlockSpec(
+            (1, 1, hkv, block_size),
+            lambda v, ly, tab, po, sl, bl, wh, n, p=p: (
+                ly[0], tab[wh[p * n_visits + listed(v)]], 0, 0))
+            for p in range(per)]
         in_specs += sc_specs + sc_specs
         inputs += [k_scale] * per + [v_scale] * per
     # The output starts as zeros and only visited slots are written, so
     # a freed slot's row comes back zero at no grid step of its own.
     in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     inputs.append(jnp.zeros_like(qg))
+    buffers = [pltpu.VMEM((2, per, hkv, block_size, d), a.dtype)
+               for a in (arena_k, arena_v)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(count[0],),
         in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=_scratch(hkv, group, d),
+        scratch_shapes=buffers + [pltpu.SemaphoreType.DMA((2, 2, per))]
+        + _scratch(hkv, group, d),
     )
     kernel = functools.partial(
         _paged_kernel, scale=scale, block_size=block_size, num_blocks=nb,
-        per_visit=per, quantized=quantized, window=window)
+        per_visit=per, listed=n_visits, quantized=quantized, window=window)
     itemsize = jnp.dtype(arena_k.dtype).itemsize
     kv_bytes = 2 * b * nb * hkv * block_size * d * itemsize
     if quantized:
@@ -509,8 +573,8 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        # Operand index counts the six scalar-prefetch arrays.
-        input_output_aliases={6 + len(inputs) - 1: 0},
+        # Operand index counts the seven scalar-prefetch arrays.
+        input_output_aliases={7 + len(inputs) - 1: 0},
         interpret=interpret,
         name="paged_decode_attn",
         cost_estimate=pl.CostEstimate(
@@ -522,7 +586,8 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
             transcendentals=b * hq * nb * block_size,
         ),
     )(_layer_operand(layer), tables.astype(jnp.int32).reshape(-1),
-      positions.astype(jnp.int32), slot_of, block_of, where_of, *inputs)
+      positions.astype(jnp.int32), slot_of, block_of, where_of, count,
+      *inputs)
     return out.reshape(b, hq, d)
 
 

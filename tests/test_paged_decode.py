@@ -179,6 +179,19 @@ def _walk_case(hq, hkv, kv_dtype, layered, b=5, seed=11):
     return q, (ak, av, tables), kw
 
 
+def _assert_walks_rows(out, walk, per=None):
+    """A visit of ONE block is the walk's arithmetic: the same bits. A
+    wider visit folds its blocks in one softmax chain, the same float32
+    arithmetic in another order of rounding: a bf16 output may land one
+    ulp (2^-8 of its size) off the walk's. ``per`` None: the arena's own
+    rule, which gives a toy arena the cap."""
+    out, walk = np.asarray(out, np.float32), np.asarray(walk, np.float32)
+    if per == 1:
+        np.testing.assert_array_equal(out, walk)
+    else:
+        np.testing.assert_allclose(out, walk, rtol=2.0 ** -7, atol=1e-6)
+
+
 # The served models' head layouts: Mistral's GQA 32/8, OLMoE's MHA 16/16.
 @pytest.mark.parametrize("hq,hkv", [(32, 8), (16, 16)])
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
@@ -187,8 +200,9 @@ def test_live_block_visits_equal_every_entry_walk(pallas_interpret, hq, hkv,
                                                   kv_dtype, layered):
     """The kernel visits only the blocks a query may see; the walk it
     replaced (kept in ``chip_smoke.py``) stepped over every table entry
-    and skipped the dead ones. Same blocks, same order, same arithmetic:
-    the same bits, and both within tolerance of the XLA reference.
+    and skipped the dead ones. Same blocks, one softmax chain a visit
+    where the walk has one a block: the walk's rows within a bf16 ulp,
+    and both within tolerance of the XLA reference.
     Positions: a block's last row, the next block's first, the table's
     last row, one past the table (clamped to it), and 0."""
     from chip_smoke import walk_every_entry
@@ -197,8 +211,7 @@ def test_live_block_visits_equal_every_entry_walk(pallas_interpret, hq, hkv,
     bs, nb = 32, 4
     pos = jnp.asarray([bs - 1, bs, nb * bs - 1, nb * bs + 40, 0], jnp.int32)
     out = paged_decode_attention(q, *args, pos, use_kernel=True, **kw)
-    np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(walk_every_entry(q, *args, pos, **kw)))
+    _assert_walks_rows(out, walk_every_entry(q, *args, pos, **kw))
     ref = paged_attention_reference(q, *args, pos, **kw)
     np.testing.assert_allclose(np.asarray(out, jnp.float32),
                                np.asarray(ref, jnp.float32), atol=3e-2)
@@ -211,8 +224,8 @@ def test_limits_decide_which_slots_are_visited(pallas_interpret, kv_dtype,
                                                case):
     """``limits`` 0 marks a freed slot: never visited, its row exactly
     zero, whatever the garbage block holds (NaN here: nothing may read
-    it). Every live row is the walk's, bit for bit; without ``limits``
-    every slot is live."""
+    it). Every live row is the walk's (within a bf16 ulp: one chain a
+    visit); without ``limits`` every slot is live."""
     from chip_smoke import walk_every_entry
 
     q, (ak, av, tables), kw = _walk_case(32, 8, kv_dtype, layered=False)
@@ -239,7 +252,7 @@ def test_limits_decide_which_slots_are_visited(pallas_interpret, kv_dtype,
     want = np.asarray(walk_every_entry(q, ak, av, tables, pos, **kw),
                       np.float32)
     assert np.isfinite(out).all()
-    np.testing.assert_array_equal(out[live], want[live])
+    _assert_walks_rows(out[live], want[live])
     np.testing.assert_array_equal(out[~live], 0.0)
     if case == "omitted":
         np.testing.assert_array_equal(out, np.asarray(paged_decode_attention(
@@ -326,25 +339,45 @@ def test_visit_schedule_of_a_short_slot_is_one_step_of_one_block(per):
     assert (where[1:] == where[1:, :1]).all()
 
 
-def test_blocks_a_visit_follow_the_blocks_bytes():
-    """``visit_blocks``: about ``VISIT_BYTES`` of K and V a step, from the
-    arena's shape alone. The served shapes at 64-token blocks of 128:
-    8 kv heads in bf16 (262 KB a block) take four a step, 16 kv heads
-    (524 KB) two, a block over the budget one, and no shape more than
-    ``MAX_VISIT_BLOCKS``; a slab and the whole arena agree."""
-    def arena(hkv, dtype, bs=64, layers=None):
-        shape = (9, hkv, bs, 128) if layers is None else (layers, 9, hkv,
-                                                          bs, 128)
-        return jax.ShapeDtypeStruct(shape, dtype)
+def _arena(hkv, dtype, bs=64, d=128, layers=None):
+    shape = (9, hkv, bs, d) if layers is None else (layers, 9, hkv, bs, d)
+    return jax.ShapeDtypeStruct(shape, dtype)
 
-    assert VISIT_BYTES == 1 << 20 and MAX_VISIT_BLOCKS == 4
-    assert visit_blocks(arena(8, jnp.bfloat16)) == 4
-    assert visit_blocks(arena(8, jnp.bfloat16, layers=5)) == 4
-    assert visit_blocks(arena(16, jnp.bfloat16)) == 2
-    assert visit_blocks(arena(16, jnp.bfloat16, layers=12)) == 2
-    assert visit_blocks(arena(8, jnp.int8)) == 4            # capped
-    assert visit_blocks(arena(16, jnp.float32)) == 1
-    assert visit_blocks(arena(32, jnp.float32, bs=128)) == 1
+
+# The seven served arenas, blocks of 64 tokens in bf16: (kv heads, head
+# size) -> a block's K and V bytes -> blocks a grid step.
+@pytest.mark.parametrize("model,hkv,d,want", [
+    ("ZAYA1-8B", 2, 128, 16),               # 64 KB
+    ("Qwen3-Next", 2, 256, 8),              # 131 KB
+    ("Mistral-7B", 8, 128, 4),              # 262 KB
+    ("Granite-4.0-H", 8, 128, 4),
+    ("Trinity", 8, 128, 4),
+    ("OLMoE", 16, 128, 2),                  # 524 KB
+    ("EvaByte", 32, 128, 1),                # 1 MB
+])
+def test_blocks_a_visit_of_each_served_arena(model, hkv, d, want):
+    """``visit_blocks``: about ``VISIT_BYTES`` of ONE slot's K and V a
+    step whatever a block weighs, from the arena's shape alone; a slab
+    and the whole arena agree."""
+    assert visit_blocks(_arena(hkv, jnp.bfloat16, d=d)) == want
+    assert visit_blocks(_arena(hkv, jnp.bfloat16, d=d, layers=5)) == want
+    block = 2 * hkv * 64 * d * 2
+    assert want * block <= VISIT_BYTES < (want + 1) * block
+
+
+def test_blocks_a_visit_follow_the_blocks_bytes():
+    """The rule outside the served shapes: an int8 arena's block weighs
+    half and takes twice the blocks, a block over the budget one, and no
+    shape more than ``MAX_VISIT_BLOCKS`` (the widest step that was
+    timed): a toy arena's 8 KB blocks would ask for 128."""
+    assert VISIT_BYTES == 1 << 20 and MAX_VISIT_BLOCKS == 16
+    assert visit_blocks(_arena(8, jnp.int8)) == 8
+    assert visit_blocks(_arena(16, jnp.bfloat16, layers=12)) == 2
+    assert visit_blocks(_arena(16, jnp.float32)) == 1
+    assert visit_blocks(_arena(32, jnp.float32, bs=128)) == 1
+    assert visit_blocks(_arena(2, jnp.int8)) == MAX_VISIT_BLOCKS
+    assert visit_blocks(_arena(2, jnp.float32, bs=32, d=16)) == \
+        MAX_VISIT_BLOCKS
 
 
 # Live blocks a slot: 1, P-1, P, P+1, then an odd and an even count over
@@ -365,17 +398,17 @@ def _visits_for(ak, tables, pos, per, limits=None, window=0):
                         per_visit=per, window=window)
 
 
-@pytest.mark.parametrize("per", [2, 4])
+@pytest.mark.parametrize("per", [1, 2, 4])
 @pytest.mark.parametrize("hq,hkv", [(32, 8), (16, 16)])
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 @pytest.mark.parametrize("layered", [False, True])
 def test_visits_of_several_blocks_equal_every_entry_walk(
         pallas_interpret, hq, hkv, kv_dtype, layered, per):
     """A grid step covers up to ``per`` consecutive blocks of a slot and
-    attends them one after the other: the one-block walk's blocks in
-    the walk's order, so its bits, whether a slot's live count is
-    under, at or over a whole number of visits (a full visit and a
-    short one are two bodies of the kernel)."""
+    folds them in one softmax chain: the one-block walk's blocks, its
+    rows within a bf16 ulp, whether a slot's live count is under, at or
+    over a whole number of visits (a short visit's dead sub-blocks are
+    masked by the same body)."""
     from chip_smoke import walk_every_entry
 
     counts = _live_counts(per)
@@ -394,9 +427,8 @@ def test_visits_of_several_blocks_equal_every_entry_walk(
     out = paged_decode_attention(
         q, ak, av, tables, pos, use_kernel=True,
         visits=_visits_for(ak, tables, pos, per), **kw)
-    np.testing.assert_array_equal(
-        np.asarray(out),
-        np.asarray(walk_every_entry(q, ak, av, tables, pos, **kw)))
+    _assert_walks_rows(out, walk_every_entry(q, ak, av, tables, pos, **kw),
+                       per)
     ref = paged_attention_reference(q, ak, av, tables, pos, **kw)
     np.testing.assert_allclose(np.asarray(out, jnp.float32),
                                np.asarray(ref, jnp.float32), atol=3e-2)
@@ -410,7 +442,7 @@ def test_short_visits_between_freed_slots(pallas_interpret, kv_dtype, freed,
     """Live slots of every count interleaved with freed ones, the
     garbage block NaN: a freed slot has no visit and a zero row, a short
     last visit's dead sub-blocks are never read into the arithmetic, and
-    every live row is the walk's, bit for bit."""
+    every live row is the walk's within a bf16 ulp."""
     from chip_smoke import walk_every_entry
 
     counts = [n for n in _live_counts(per) for _ in (0, 1)]
@@ -439,7 +471,7 @@ def test_short_visits_between_freed_slots(pallas_interpret, kv_dtype, freed,
     want = np.asarray(walk_every_entry(q, ak, av, tables, pos, **kw),
                       np.float32)
     assert np.isfinite(out).all()
-    np.testing.assert_array_equal(out[live], want[live])
+    _assert_walks_rows(out[live], want[live], per)
     np.testing.assert_array_equal(out[~live], 0.0)
 
 
@@ -470,8 +502,9 @@ def test_ring_visits_equal_the_walk_from_the_first_live_block(
     """Over a ring a slot's visits start at its FIRST live block, which
     no multiple of ``per`` need be, and a visit's blocks lie in ring
     entries ``block % nb``, so a visit may straddle the ring's end:
-    still the one-block walk's bits, before the first wrap and after,
-    with a window that is no multiple of the block."""
+    still the one-block walk's rows (within a bf16 ulp), before the
+    first wrap and after, with a window that is no multiple of the
+    block."""
     from chip_smoke import walk_every_entry
 
     pos = _ring_positions(per)
@@ -495,9 +528,8 @@ def test_ring_visits_equal_the_walk_from_the_first_live_block(
     out = paged_decode_attention(
         q, ak, av, tables, pos, use_kernel=True,
         visits=_visits_for(ak, tables, pos, per, window=_RING_WINDOW), **kw)
-    np.testing.assert_array_equal(
-        np.asarray(out),
-        np.asarray(walk_every_entry(q, ak, av, tables, pos, **kw)))
+    _assert_walks_rows(out, walk_every_entry(q, ak, av, tables, pos, **kw),
+                       per)
     ref = paged_attention_reference(q, ak, av, tables, pos, **kw)
     np.testing.assert_allclose(np.asarray(out, jnp.float32),
                                np.asarray(ref, jnp.float32), atol=3e-2)
@@ -514,6 +546,115 @@ def test_ring_visit_schedule_runs_from_the_first_live_block(per):
                           per_visit=per, window=_RING_WINDOW)
     want = _expected_visits(tables, pos, limits, 32, per, _RING_WINDOW)
     assert {len(b) for _, _, b in want} >= {1, min(per, 2)}
+    _check_schedule(visits, want, tables, per)
+
+
+# ------------------------------------------ 8 and 16 blocks a grid step
+
+# Contexts (keys a slot holds) around a visit of ``per`` blocks of 32: a
+# slot whose only visit is short (1, bs - 1, bs keys), one key short of
+# a full visit, a full one, one key past it, and two visits and a bit;
+# a freed slot stands between two live ones.
+def _wide_contexts(per, bs=32):
+    return [1, bs - 1, bs, per * bs - 1, 0, per * bs, per * bs + 1,
+            2 * per * bs + 5 * bs + 3]
+
+
+@pytest.mark.parametrize("per", [8, 16])
+@pytest.mark.parametrize("hq,hkv,d", [(8, 2, 128), (16, 2, 256)])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_wide_visits_match_the_reference(pallas_interpret, hq, hkv, d,
+                                         kv_dtype, per):
+    """The two arenas of 2 kv heads (ZAYA1's query group of 4 at head
+    size 128, Qwen3-Next's 8 at 256) at the 8 and 16 blocks a step
+    their bytes ask for: every context around a visit's boundary
+    against the XLA reference, a freed slot's row zero, the garbage
+    block NaN (a dead sub-block's operand adds nothing), and the
+    one-block walk's rows within a bf16 ulp."""
+    from chip_smoke import walk_every_entry
+
+    contexts = _wide_contexts(per)
+    nb = -(-max(contexts) // 32)
+    q, _, _, ak, av, tables, _ = _paged_inputs(
+        b=len(contexts), hq=hq, hkv=hkv, d=d, nb_slot=nb, seed=23,
+        dtype=jnp.bfloat16)
+    kw = {}
+    if kv_dtype == "int8":
+        ak, kw["k_scale"] = quantize_kv(ak)
+        av, kw["v_scale"] = quantize_kv(av)
+        kw = {n: a.at[GARBAGE_BLOCK].set(jnp.nan) for n, a in kw.items()}
+    else:
+        ak, av = (a.at[GARBAGE_BLOCK].set(jnp.nan) for a in (ak, av))
+    live = np.asarray(contexts) > 0
+    tables = jnp.where(live[:, None], tables, GARBAGE_BLOCK)
+    pos = jnp.asarray([max(n - 1, 0) for n in contexts], jnp.int32)
+    limits = jnp.asarray(live * nb * 32, jnp.int32)
+    visits = _visits_for(ak, tables, pos, per, limits)
+    assert int(visits[3][0]) == sum(-(-n // (per * 32)) for n in contexts)
+    out = np.asarray(paged_decode_attention(
+        q, ak, av, tables, pos, use_kernel=True, visits=visits, **kw),
+        np.float32)
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out[~live], 0.0)
+    # The reference attends a freed slot's garbage; nothing reads it.
+    clean = {n: a.at[GARBAGE_BLOCK].set(0.0) for n, a in kw.items()}
+    ref = np.asarray(paged_attention_reference(
+        q, jnp.nan_to_num(ak), jnp.nan_to_num(av), tables, pos, **clean),
+        np.float32)
+    np.testing.assert_allclose(out[live], ref[live], atol=3e-2)
+    walk = np.asarray(walk_every_entry(q, ak, av, tables, pos, **kw),
+                      np.float32)
+    _assert_walks_rows(out[live], walk[live], per)
+
+
+@pytest.mark.parametrize("per", [8, 16])
+@pytest.mark.parametrize("hq,hkv,d", [(8, 2, 128), (16, 2, 256)])
+def test_wide_visits_over_a_ring(pallas_interpret, hq, hkv, d, per):
+    """A ring of 21 entries under a window of 19 blocks and 7 keys: a
+    query sees 20 or 21 blocks from a first live block that is no
+    multiple of ``per``, so a wide visit straddles the ring's end and
+    the slot's last is short, before the first wrap and after."""
+    ring, window = 21, 19 * 32 + 7
+    pos = [5, window - 1, 32 + window - 1, 9 * 32 + window + 11,
+           (3 * ring + 5) * 32 + window + 30]
+    q, _, _, ak, av, tables, _ = _paged_inputs(
+        b=len(pos), hq=hq, hkv=hkv, d=d, nb_slot=ring, seed=29,
+        dtype=jnp.bfloat16)
+    firsts = [max(p - window + 1, 0) // 32 for p in pos]
+    assert any(f % per for f in firsts) and any(f >= ring for f in firsts)
+    pos = jnp.asarray(pos, jnp.int32)
+    out = paged_decode_attention(
+        q, ak, av, tables, pos, use_kernel=True, window=window,
+        visits=_visits_for(ak, tables, pos, per, window=window))
+    ref = paged_attention_reference(q, ak, av, tables, pos, window=window)
+    np.testing.assert_allclose(np.asarray(out, jnp.float32),
+                               np.asarray(ref, jnp.float32), atol=3e-2)
+
+
+@pytest.mark.parametrize("window", [0, 19 * 32 + 7])
+@pytest.mark.parametrize("per", [8, 16])
+def test_wide_visit_schedule(per, window):
+    """``paged_visits`` at 8 and 16 blocks a step over tables of 40
+    entries: the count of visits, each visit's live blocks in order,
+    every dead sub-block naming the entry its operand read the step
+    before (so nothing is fetched for it), a freed slot none."""
+    nb = 40
+    lives = [1, per - 1, per, per + 1, 0, 2 * per + 1, nb, 1]
+    pos = [max(n * 32 - 7, 0) for n in lives]
+    limits = [int(n > 0) for n in lives]
+    if window:
+        pos = [p + 3 * nb * 32 for p in pos]    # some wraps in
+    tables = jnp.arange(1, len(pos) * nb + 1, dtype=jnp.int32).reshape(
+        len(pos), nb)
+    visits = paged_visits(tables, jnp.asarray(pos, jnp.int32),
+                          jnp.asarray(limits, jnp.int32), block_size=32,
+                          per_visit=per, window=window)
+    want = _expected_visits(tables, pos, limits, 32, per, window)
+    if not window:
+        assert len(want) == sum(-(-n // per) for n in lives)
+    # A ring's slots all hold the window's 20 or 21 blocks.
+    assert {len(b) for _, _, b in want} >= ({per, 20 % per} if window
+                                            else {1, per})
     _check_schedule(visits, want, tables, per)
 
 

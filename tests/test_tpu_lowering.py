@@ -274,7 +274,7 @@ def test_compiled_paged_tick_moves_no_arena_slab(v5e_chip, kv_dtype):
     assert len(re.findall(r"%paged_kv_write[.\d]* = ", hlo)) == writes
     assert len(re.findall(r"%paged_decode_attn[.\d]* = ", hlo)) == 1
     # Scratch: the kernel's schedule, made once before the layer loop
-    # (48 x 8 visits of four blocks against 48 slot ends, in fusions
+    # (48 x 8 visits of four bf16 blocks against 48 slot ends, in fusions
     # and no gather: a gather's index vectors pad to 128 lanes, 0.4 MB
     # each), and the zeroed output it fills in, 0.7-0.8 MB together
     # since PR 26 (782,848 and 879,616 bytes at PR 33); an int8 arena
@@ -288,7 +288,8 @@ def test_compiled_paged_tick_moves_no_arena_slab(v5e_chip, kv_dtype):
                 if re.search(r"%paged_decode_attn[.\d]* = ", c))
     # (Slot and first block a visit; a table entry a visit and sub-block,
     # which is also the flattened tables' length.)
-    per = 4         # 8 kv heads x 64 x 128 in bf16, and int8 at the cap
+    # 8 kv heads x 64 x 128: a megabyte is four bf16 blocks, eight int8.
+    per = 8 if kv_dtype == "int8" else 4
     visits = _TICK_SLOTS * -(-(_TICK_LEN // _TICK_BS) // per) + 1
     listed = [line.strip() for line in body.splitlines()
               if re.search(rf"= s32\[({visits}|{visits * per})\]", line)]
@@ -712,6 +713,43 @@ def test_paged_decode_attn_lowers_with_a_window(window):
     exported = jax.export.export(jax.jit(attend), platforms=["tpu"])(
         q, arena, arena, tables, pos, S((), jnp.int32))
     assert "tpu_custom_call" in exported.mlir_module()
+
+
+# The served arenas' block shapes (kv heads, query heads, head size) ->
+# blocks a grid step: Mosaic compiles the kernel's own copies at every
+# width, not only at the 8-head tick's four.
+@pytest.mark.parametrize("hkv,hq,d,kv_dtype,per", [
+    (2, 8, 128, "bf16", 16), (2, 16, 256, "bf16", 8),
+    (16, 16, 128, "bf16", 2), (32, 32, 128, "bf16", 1),
+    (2, 8, 128, "int8", 16), (8, 32, 128, "int8", 8),
+])
+def test_compiled_paged_decode_at_every_visit_width(v5e_chip, hkv, hq, d,
+                                                    kv_dtype, per):
+    """A real compile for the described chip (the export above stops
+    before Mosaic): sixteen sub-blocks a step are 32 copies into 2 MB of
+    double buffer, an int8 arena adds 32 BlockSpec operands of scales."""
+    from ray_tpu.ops.paged_decode_attention import visit_blocks
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    quantized = kv_dtype == "int8"
+    arena = on_chip((2, 65, hkv, 64, d), jnp.int8 if quantized else BF16)
+    assert visit_blocks(arena) == per
+    scales = [on_chip((2, 65, hkv, 64), jnp.float32)] * 2 if quantized \
+        else [None, None]
+
+    def attend(q, k, v, tables, pos, ks, vs):
+        return paged_decode_attention(
+            q, k, v, tables, pos, layer=jnp.int32(1), limits=pos,
+            k_scale=ks, v_scale=vs, use_kernel=True, interpret=False)
+
+    compiled = jax.jit(attend).lower(
+        on_chip((8, hq, d), BF16), arena, arena,
+        on_chip((8, 40), jnp.int32), on_chip((8,), jnp.int32),
+        *scales).compile()
+    assert len(re.findall(r"%paged_decode_attn[.\d]* = ",
+                          compiled.as_text())) == 1
 
 
 # ------------------------------------------------- latent attention (MLA)
