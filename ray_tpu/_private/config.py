@@ -84,6 +84,11 @@ class _ConfigDefaults:
 
     # --- metrics ----------------------------------------------------------
     metrics_report_interval_ms: int = 5000
+    # Seconds of device trace the XLA monitor keeps of a stalled stretch
+    # (engine ticks, prefill batches or train steps several times over
+    # their own median), started while it runs; 0 = never
+    # (``RAY_TPU_stall_capture_s=3`` in a replica's environment).
+    stall_capture_s: float = 0.0
 
     # --- memory monitor ---------------------------------------------------
     memory_usage_threshold: float = 0.95

@@ -827,6 +827,31 @@ XLA_MFU = Gauge(
     "Achieved FLOP/s over the chip's peak (emitted only on known "
     "device kinds)",
     ("program",))
+# The call record (``xla_monitor._CallRecord``): one record a measured
+# execution, fed by ``note_execution``; a stalled stretch books the last
+# two when it closes.
+XLA_RESULTS_READY = Counter(
+    "ray_tpu_xla_results_ready_at_fetch_total",
+    "Measured executions whose whole result answered is_ready() when "
+    "the host came to fetch it: the host was the slower side of that "
+    "call",
+    ("program",))
+XLA_FETCH_WAIT_SECONDS = Counter(
+    "ray_tpu_xla_fetch_wait_seconds_total",
+    "Seconds the host blocked in the fetch of measured executions "
+    "(the device, its runtime or the transfer back was the slower side)",
+    ("program",))
+XLA_STALL_STRETCHES = Counter(
+    "ray_tpu_xla_stall_stretches_total",
+    "Stalled stretches closed: runs of measured executions several "
+    "times over their shape's own median; side = host (results were "
+    "ready before the host came), device (the host waited), mixed",
+    ("side",))
+XLA_STALL_EXCESS_SECONDS = Counter(
+    "ray_tpu_xla_stall_excess_seconds_total",
+    "Seconds closed stalled stretches cost: each slow call's wall time "
+    "less its shape's median; side as on the stretches' counter",
+    ("side",))
 
 # --------------------------------------------- device memory vitals
 DEVICE_MEM_USED = Gauge(

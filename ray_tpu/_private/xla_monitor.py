@@ -32,6 +32,17 @@ surface, feeding the same planes:
   write the trace under the session dir and register it in the GCS KV
   under ``__profiles__``.
 
+* :meth:`InstrumentedJit.note_execution` also keeps ONE RECORD a
+  measured execution in a bounded per-process ring (:class:`_CallRecord`):
+  its wall time, when it was dispatched and when it landed, whether its
+  result was READY when the host came to fetch it and how long the fetch
+  then blocked. A run of calls several times over their shape's own
+  median is a STALLED STRETCH: it books the seconds it cost, says whose
+  they were (``host``: the results waited for the thread; ``device``:
+  the thread waited for them), writes itself to
+  ``<session dir>/stalls/`` and, where ``stall_capture_s`` asks for it,
+  keeps a device trace of itself through the capture plane below.
+
 Everything degrades gracefully on CPU (cost analysis works, memory
 stats return None, profiler traces still capture), so tier-1 exercises
 the full plane under ``JAX_PLATFORMS=cpu``. ``RAY_TPU_XLA_MONITOR=0``
@@ -41,14 +52,19 @@ turns the wrapper into a transparent ``jax.jit``.
 from __future__ import annotations
 
 import functools
+import gc
+import itertools
 import json
 import logging
 import os
+import resource
+import statistics
 import sys
+import tempfile
 import threading
 import time
-from collections import OrderedDict
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from collections import OrderedDict, deque
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 logger = logging.getLogger(__name__)
 
@@ -72,7 +88,10 @@ def _enabled() -> bool:
 
 
 def session_dir() -> str:
-    return os.environ.get("RAY_TPU_SESSION_DIR", "/tmp/ray_tpu_state")
+    """Where captures and stall dumps go: ``RAY_TPU_SESSION_DIR``, else
+    a folder under the process's own temporary directory (``TMPDIR``)."""
+    return os.environ.get("RAY_TPU_SESSION_DIR") or os.path.join(
+        tempfile.gettempdir(), "ray_tpu_state")
 
 
 # --------------------------------------------------------------- connection
@@ -84,6 +103,7 @@ _node_id: Optional[str] = None
 _conn_refs: Dict[str, int] = {}               # address -> connect() count
 _listeners: Dict[str, threading.Event] = {}   # address -> stop event
 _maintenance_stop: Optional[threading.Event] = None
+_maintenance_wake = threading.Event()         # a stalled stretch closed
 # (ns, key) -> [payload, tries]; insertion-ordered for bounded eviction.
 _pending_kv: OrderedDict = OrderedDict()
 _programs: Dict[str, "_ProgramRecord"] = {}
@@ -141,6 +161,7 @@ def stop_all() -> None:
             _maintenance_stop = None
     for s in stops:
         s.set()
+    _maintenance_wake.set()
 
 
 def _on_xla_activity() -> None:
@@ -518,7 +539,9 @@ class InstrumentedJit:
                               self._cost_for(self._last_key), dt)
 
     def note_execution(self, seconds: float,
-                       bytes_hint: Optional[float] = None
+                       bytes_hint: Optional[float] = None, *,
+                       calls: int = 1, shape: Any = None,
+                       call: Optional["Dispatched"] = None
                        ) -> Optional[Dict[str, float]]:
         """Feed back a MEASURED wall time for the most recent call (the
         serve tick measures dispatch→fetch, prefill measures
@@ -529,10 +552,21 @@ class InstrumentedJit:
         the achieved-bandwidth gauge: programs whose real traffic is
         data-dependent (the paged decode tick reads only LIVE KV blocks)
         would otherwise be priced at the compiled worst case — the
-        gauge must scale with live tokens, not ``S_max``."""
+        gauge must scale with live tokens, not ``S_max``.
+
+        ``seconds`` is the time of ``calls`` back-to-back calls of the
+        program (a chunked prefill batch's). The measurement is also one
+        record of the process's call record (:class:`_CallRecord`),
+        under ``shape``, whatever of the arguments its time depends on
+        (a tick's member count, a prefill batch's padded shape), with
+        the stamps and the fetch's reading ``call`` carries from the
+        dispatch (:class:`Dispatched`; without one the record is
+        stamped here and says nothing of the fetch)."""
         self._external_timing = True
         if seconds <= 0:
             return None
+        _calls.note(self.name, shape, seconds, calls, call)
+        seconds /= calls
         cost = self._cost_for(self._last_key)
         if bytes_hint is not None and bytes_hint > 0:
             cost = dict(cost) if cost else {}
@@ -628,6 +662,427 @@ def instrument(fn=None, *, name: Optional[str] = None,
                            shape_policy=shape_policy,
                            allowed_dims=allowed_dims, aot=aot,
                            **jit_kwargs)
+
+
+# ------------------------------------------------------------ call record
+# The rule for a stalled stretch. Constants, not options: CHANGES.md
+# (PR 48) says why these, from the ledger's ticks of 10.6 to 23.3 ms and
+# stalls of up to ten times that.
+RING_CALLS = 16384       # two minutes of the fastest cell's 10.6 ms ticks
+BASELINE_CALLS = 64      # a shape's median: over its last calls not slow,
+BASELINE_MIN = 8         # a baseline from this many of them on,
+BASELINE_EVERY = 32      # recomputed once in this many
+SLOW_FACTOR = 3.0        # slow: over this many medians of its shape
+SLOW_FLOOR_MS = 30.0     # and over this, a call
+CLOSE_AFTER = 8          # calls in a row under the rule close a stretch,
+IDLE_CLOSE_S = 2.0       # or this long with no call dispatched or landed
+LEAD_IN_CALLS = 256      # records a dump keeps from before its stretch
+DUMPS_KEPT = 256         # dumps of its own a process keeps; its oldest goes
+CAPTURE_AT_SLOW = 2      # a stretch's capture starts at this slow call,
+CAPTURE_EVERY_S = 60.0   # at most one in this long
+
+
+class Call(NamedTuple):
+    """One measured execution, as the ring and a dump keep it."""
+    seq: int
+    program: str
+    shape: Any
+    wall_s: float                   # what the caller booked, ``calls`` calls
+    calls: int
+    dispatch_ts: Optional[float]    # wall clock | perf_counter
+    dispatch_pc: Optional[float]
+    landed_ts: float
+    landed_pc: float
+    ready: Optional[bool]           # the whole result was there at the fetch
+    waited_s: Optional[float]       # the fetch then blocked this long
+    slow: bool
+
+
+class Dispatched:
+    """One measured call between its dispatch and its landing. The
+    caller mints it where it dispatches the program, asks
+    :meth:`fetching` just before it blocks on the result, stamps
+    :meth:`landed` after, and hands it to ``note_execution``. ``seq`` is
+    what the caller's dispatch and fetch annotations carry as keyword
+    metadata, so a dump's record and a trace's annotations are one
+    thing. One that never lands (its program raised) leaves nothing
+    behind."""
+
+    __slots__ = ("seq", "ts", "pc", "ready", "fetch_pc", "landed_ts",
+                 "landed_pc")
+
+    def __init__(self):
+        self.ts, self.pc = time.time(), time.perf_counter()
+        self.seq = _calls.mint(self.pc)
+        self.ready = self.fetch_pc = self.landed_ts = self.landed_pc = None
+
+    def fetching(self, parts) -> None:
+        """The host has come for ``parts``, the arrays of the result:
+        were they all there? A host call each, which does not block."""
+        self.ready = all(part.is_ready() for part in parts)
+        self.fetch_pc = time.perf_counter()
+
+    def landed(self) -> float:
+        """The result is on the host; returns the ``perf_counter``."""
+        self.landed_pc, self.landed_ts = time.perf_counter(), time.time()
+        return self.landed_pc
+
+
+class _Gen2Clock:
+    """``gc.callbacks`` hook: count and milliseconds of generation-2
+    collections; generations 0 and 1 return at once."""
+
+    def __init__(self):
+        self.count, self.ms, self._t0 = 0, 0.0, None
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.count += 1
+            self.ms += (time.perf_counter() - self._t0) * 1e3
+            self._t0 = None
+
+
+_gen2 = _Gen2Clock()
+
+
+def _snapshot() -> Dict[str, float]:
+    """What the process and its device look like now: taken as a stretch
+    opens and as it closes, never outside one."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    out = {"ts": time.time(), "cpu_user_s": ru.ru_utime,
+           "cpu_system_s": ru.ru_stime, "ctx_voluntary": ru.ru_nvcsw,
+           "ctx_involuntary": ru.ru_nivcsw, "major_faults": ru.ru_majflt,
+           "threads": threading.active_count(),
+           "gc2_collections": _gen2.count, "gc2_ms": _gen2.ms}
+    try:
+        out["load_1m"] = os.getloadavg()[0]
+    except OSError:
+        pass
+    jax = sys.modules.get("jax")
+    try:
+        stats = jax.local_devices()[0].memory_stats() if jax else None
+    except Exception:  # noqa: BLE001 - no backend, or one without stats
+        stats = None
+    for field in ("bytes_in_use", "peak_bytes_in_use"):
+        if stats and stats.get(field) is not None:
+            out["device_" + field] = int(stats[field])
+    return out
+
+
+class _Baseline:
+    """A (program, shape)'s last readings that were not slow, and their
+    median."""
+
+    __slots__ = ("recent", "median", "since")
+
+    def __init__(self):
+        self.recent: Optional[deque] = None     # None: no reading yet
+        self.median: Optional[float] = None
+        self.since = 0
+
+    def add(self, seconds: float) -> None:
+        recent = self.recent
+        if recent is None:
+            # A shape's first reading holds its compilation or its
+            # program's load: never a baseline.
+            self.recent = deque(maxlen=BASELINE_CALLS)
+            return
+        recent.append(seconds)
+        self.since += 1
+        if (self.since >= BASELINE_EVERY or self.median is None) \
+                and len(recent) >= BASELINE_MIN:
+            self.median = statistics.median(recent)
+            self.since = 0
+
+
+class _Stretch:
+    """An open stalled stretch: what its close books and dumps."""
+
+    __slots__ = ("program", "opened_n", "opened_ts", "closed_ts", "before",
+                 "slow", "normal_run", "excess_s", "host_sided",
+                 "device_sided", "capture", "capture_thread")
+
+    def __init__(self, rec: Call, opened_n: int):
+        self.program = rec.program
+        self.opened_n = opened_n
+        # From the slow call's dispatch where it is known: the stretch
+        # began when that call did, not when it at last came back.
+        self.opened_ts = rec.dispatch_ts or rec.landed_ts - rec.wall_s
+        self.before = _snapshot()
+        self.slow = self.normal_run = 0
+        self.excess_s = 0.0
+        self.host_sided = self.device_sided = 0
+        self.capture: Any = None
+        self.capture_thread: Optional[threading.Thread] = None
+
+    def add(self, rec: Call, median: float) -> None:
+        self.slow += 1
+        self.normal_run = 0
+        self.excess_s += rec.wall_s - median * rec.calls
+        # The stretch ends with its last slow call, not with the calls
+        # under the rule that show it has.
+        self.closed_ts = rec.landed_ts
+        if rec.ready is None or rec.waited_s is None:
+            return
+        if rec.ready and rec.waited_s < rec.wall_s / 2:
+            self.host_sided += 1
+        elif not rec.ready and rec.waited_s >= rec.wall_s / 2:
+            self.device_sided += 1
+
+    def side(self) -> str:
+        """Whose the stretch was, read off its slow calls: ``host``
+        where most results were ready before the host came for them (the
+        device idled inside the caller's clock), ``device`` where they
+        were not and the fetch's wait is most of their wall time (the
+        thread waited on the device, its runtime, the transfer back or
+        on getting the interpreter lock back after the wait)."""
+        if self.host_sided * 2 > self.slow:
+            return "host"
+        if self.device_sided * 2 > self.slow:
+            return "device"
+        return "mixed"
+
+
+class _CallRecord:
+    """The process's record of measured executions: a bounded ring of
+    :class:`Call`, a median per (program, shape), and the stalled
+    stretch, if one is open. One instance serves the process
+    (``_calls``); the tests of the rule make their own."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._ring: deque = deque(maxlen=RING_CALLS)
+        self._n = 0                          # records ever appended
+        self._seq = itertools.count(1)
+        self._last_pc = time.perf_counter()  # last dispatch or landing
+        # One median a (program, shape): as many as the callers' programs
+        # have shapes (a tick's member counts, the prefill's buckets).
+        self._baselines: Dict[Tuple[str, Any], _Baseline] = {}
+        self._stretch: Optional[_Stretch] = None
+        self._closed: List[Dict[str, Any]] = []  # stretches not yet written
+        self._written: deque = deque()           # this process's own dumps
+        self._last_capture: Optional[float] = None
+        self._hooked = False
+
+    def mint(self, pc: float) -> int:
+        """A call's ``seq``, at its dispatch. No lock: ``next`` on a
+        count is one step of the interpreter."""
+        self._last_pc = pc
+        return next(self._seq)
+
+    @property
+    def stretch_open(self) -> bool:
+        return self._stretch is not None
+
+    def records(self, last: int = RING_CALLS) -> List[Call]:
+        """The newest ``last`` records, oldest first."""
+        with self._lock:
+            return self._between(self._n - last, self._n)
+
+    def _between(self, first: int, last: int) -> List[Call]:
+        """Lock held. The records numbered ``first`` to ``last - 1`` (in
+        the order they were appended, from 0) that the ring still holds,
+        read from its newest end: a dump's few hundred cost that many
+        steps, not the ring's length."""
+        first = max(first, self._n - len(self._ring))
+        return list(itertools.islice(
+            reversed(self._ring), self._n - last, self._n - first))[::-1]
+
+    def note(self, program: str, shape: Any, wall_s: float, calls: int,
+             call: Optional[Dispatched]) -> bool:
+        """One measured execution: a record in the ring, the two
+        counters, a reading for its shape's median unless it is slow, a
+        step of the stalled stretch's rule. Returns whether the call was
+        slow. This runs on the caller's thread once a call
+        (``account_ms``): it is kept short on purpose."""
+        from ray_tpu._private import metrics_defs as mdefs
+
+        if not self._hooked:
+            self._hooked = True
+            if _gen2 not in gc.callbacks:
+                gc.callbacks.append(_gen2)
+        if call is None:
+            seq = next(self._seq)
+            dispatch_ts = dispatch_pc = ready = waited = None
+            landed_pc, landed_ts = time.perf_counter(), time.time()
+        else:
+            seq, dispatch_ts, dispatch_pc = call.seq, call.ts, call.pc
+            landed_pc, landed_ts, ready = (call.landed_pc, call.landed_ts,
+                                           call.ready)
+            waited = (None if call.fetch_pc is None
+                      else landed_pc - call.fetch_pc)
+        if ready:
+            mdefs.XLA_RESULTS_READY.inc(tags={"program": program})
+        if waited:
+            mdefs.XLA_FETCH_WAIT_SECONDS.inc(waited,
+                                             tags={"program": program})
+        per_call = wall_s / calls
+        closed = False
+        with self._lock:
+            self._last_pc = landed_pc
+            base = self._baselines.get((program, shape))
+            if base is None:
+                base = self._baselines[program, shape] = _Baseline()
+            median = base.median
+            slow = (median is not None and per_call > SLOW_FACTOR * median
+                    and per_call * 1e3 > SLOW_FLOOR_MS)
+            if not slow:
+                # Slow calls never enter the median: a 20 s stretch does
+                # not become the baseline.
+                base.add(per_call)
+            rec = Call(seq, program, shape, wall_s, calls, dispatch_ts,
+                       dispatch_pc, landed_ts, landed_pc, ready, waited,
+                       slow)
+            self._ring.append(rec)
+            self._n += 1
+            stretch = self._stretch
+            if slow:
+                if stretch is None:
+                    stretch = self._stretch = _Stretch(rec, self._n - 1)
+                    _ensure_maintenance()
+                stretch.add(rec, median)
+                if stretch.slow == CAPTURE_AT_SLOW:
+                    self._start_capture(stretch)
+            elif stretch is not None:
+                stretch.normal_run += 1
+                if stretch.normal_run >= CLOSE_AFTER:
+                    self._close("calls")
+                    closed = True
+        if closed:
+            _maintenance_wake.set()
+        return slow
+
+    def _start_capture(self, stretch: _Stretch) -> None:
+        """Where ``stall_capture_s`` asks for it, a device trace of the
+        open stretch, on a thread of its own (``start_trace`` takes
+        hundreds of milliseconds). From its SECOND slow call: one slow
+        call alone is over when it is known, a capture after it would
+        hold nothing of it, and it would take the minute's one capture
+        from the stretch of seconds that may follow. ``busy`` where
+        somebody holds the profiler or the minute's capture is spent.
+        Lock held."""
+        from ray_tpu._private.config import GLOBAL_CONFIG
+
+        seconds = GLOBAL_CONFIG.stall_capture_s
+        if seconds <= 0:
+            return
+        now = time.monotonic()
+        if _capture_lock.locked() or (
+                self._last_capture is not None
+                and now - self._last_capture < CAPTURE_EVERY_S):
+            stretch.capture = "busy"
+            return
+        self._last_capture = now
+        with _state_lock:
+            address = _gcs_address
+        stamp = time.strftime("%Y%m%dT%H%M%S", time.gmtime(stretch.opened_ts))
+
+        def run() -> None:
+            try:
+                got = _capture_trace(f"stall-{stamp}", seconds, address,
+                                     reason="stall", lean=True)
+            except Exception as e:  # noqa: BLE001 - nothing reaches callers
+                logger.exception("stall capture failed")
+                got = {"status": "failed", "error": repr(e)}
+            if got.get("status") == "busy":
+                # Another session held the profiler: no trace was taken,
+                # so the next stretch need not wait a minute for one.
+                self._last_capture = None
+                got = "busy"
+            stretch.capture = got
+
+        stretch.capture = "starting"
+        stretch.capture_thread = threading.Thread(
+            target=run, daemon=True, name="xla-stall-capture")
+        stretch.capture_thread.start()
+
+    def _close(self, why: str) -> None:
+        """Book the open stretch and queue it for the maintenance
+        thread, which writes its file and its line. Lock held."""
+        from ray_tpu._private import metrics_defs as mdefs
+
+        stretch, self._stretch = self._stretch, None
+        side = stretch.side()
+        mdefs.XLA_STALL_STRETCHES.inc(tags={"side": side})
+        mdefs.XLA_STALL_EXCESS_SECONDS.inc(stretch.excess_s,
+                                           tags={"side": side})
+        self._closed.append({"stretch": stretch, "after": _snapshot(),
+                             "closed_n": self._n, "side": side,
+                             "closed_by": why})
+
+    def maintain(self) -> None:
+        """The maintenance thread's part: close a stretch left open
+        when the calls stopped coming (the engine has no work), and
+        write what has closed."""
+        with self._lock:
+            if (self._stretch is not None
+                    and time.perf_counter() - self._last_pc > IDLE_CLOSE_S):
+                self._close("idle")
+            closed, self._closed = self._closed, []
+            for one in closed:
+                one["records"] = self._between(
+                    one["stretch"].opened_n - LEAD_IN_CALLS,
+                    one["closed_n"])
+        for one in closed:
+            try:
+                self._written.append(_write_stall(**one))
+                if len(self._written) > DUMPS_KEPT:
+                    os.remove(self._written.popleft())
+            except Exception:  # noqa: BLE001 - telemetry is best-effort
+                logger.exception("stall dump failed")
+
+
+def _write_stall(stretch: _Stretch, records: List[Call],
+                 after: Dict[str, float], closed_n: int, side: str,
+                 closed_by: str) -> str:
+    """ONE file and ONE warning line a closed stretch; returns the
+    file's path."""
+    if stretch.capture_thread is not None:
+        # The capture says what it got (a directory, or "busy") when it
+        # ends, a few seconds after the stretch opened.
+        stretch.capture_thread.join(timeout=120.0)
+    before = stretch.before
+    opened = time.strftime("%Y%m%dT%H%M%S", time.gmtime(stretch.opened_ts))
+    folder = os.path.join(session_dir(), "stalls")
+    path = os.path.join(
+        folder,
+        f"{opened}.{int(stretch.opened_ts % 1 * 1e3):03d}-{os.getpid()}.json")
+    os.makedirs(folder, exist_ok=True)
+    seconds = stretch.closed_ts - stretch.opened_ts
+    calls = closed_n - stretch.opened_n
+    doc = {
+        "program": stretch.program, "pid": os.getpid(),
+        "opened_ts": stretch.opened_ts, "closed_ts": stretch.closed_ts,
+        "seconds": seconds, "calls": calls,
+        "slow_calls": stretch.slow, "excess_s": stretch.excess_s,
+        "side": side, "closed_by": closed_by, "capture": stretch.capture,
+        "rule": {"slow_factor": SLOW_FACTOR, "slow_floor_ms": SLOW_FLOOR_MS,
+                 "baseline_calls": BASELINE_CALLS,
+                 "close_after": CLOSE_AFTER},
+        "before": before, "after": after,
+        "delta": {k: after[k] - before[k] for k in before if k in after},
+        "records": [r._asdict() for r in records]}
+    # Whole or not there: a reader (an operator's tail, a test) may list
+    # the directory while this thread writes.
+    partial = path + ".tmp"
+    with open(partial, "w") as f:
+        json.dump(doc, f, default=str)
+    os.replace(partial, path)
+    logger.warning(
+        "xla stall: %s went slow at %sZ for %.2f s: %d calls, %d of them "
+        "slow, %.3f s over their shapes' medians; side=%s; capture=%s; "
+        "dump %s", stretch.program, opened, seconds, calls,
+        stretch.slow, stretch.excess_s, side,
+        (stretch.capture.get("trace_dir") if isinstance(stretch.capture, dict)
+         else stretch.capture or "off"), path)
+    return path
+
+
+_calls = _CallRecord()
 
 
 # -------------------------------------------------------- device memory
@@ -763,7 +1218,20 @@ def _maintenance_loop(stop: threading.Event) -> None:
     from ray_tpu._private import metrics_pusher
 
     interval = metrics_pusher.push_interval_s()
-    while not stop.wait(interval):
+    push_at = time.monotonic() + interval
+    while not stop.is_set():
+        # Woken when a stalled stretch closes (its dump is written
+        # here, never on the caller's thread); while one is open, once a
+        # second, to close it if the engine has run out of work.
+        _maintenance_wake.wait(min(max(push_at - time.monotonic(), 0.0),
+                                   1.0 if _calls.stretch_open else interval))
+        _maintenance_wake.clear()
+        if stop.is_set():
+            return
+        _calls.maintain()
+        if time.monotonic() < push_at:
+            continue
+        push_at = time.monotonic() + interval
         try:
             _flush_pending_kv()
         except Exception:  # noqa: BLE001 — telemetry is best-effort
@@ -817,21 +1285,39 @@ def _matches_node(target: str) -> bool:
 
 
 def _do_capture(cmd: Dict[str, Any], address: str) -> None:
-    from ray_tpu._private import metrics_defs as mdefs
-    from ray_tpu._private import rpc
-    from ray_tpu.protobuf import ray_tpu_pb2 as pb
+    _capture_trace(str(cmd.get("capture_id") or "cap-unnamed"),
+                   float(cmd.get("duration_s", 2.0)), address,
+                   reason="command")
 
-    capture_id = str(cmd.get("capture_id") or "cap-unnamed")
-    duration = max(float(cmd.get("duration_s", 2.0)), 0.1)
+
+def _capture_trace(capture_id: str, duration_s: float,
+                   address: Optional[str], reason: str,
+                   lean: bool = False) -> Dict[str, Any]:
+    """Run ``jax.profiler`` for ``duration_s`` seconds, write the trace
+    under the session dir and, where a GCS ``address`` is known,
+    register it under ``__profiles__``: the listener's command and a
+    stalled stretch (``reason``) both come through here. ``lean``: the
+    host tracer at level 1 and the Python tracer off, as the benchmark
+    traces, for a capture that starts inside the stretch it watches.
+    Returns the registered record; ``status`` is ``busy`` where another
+    capture, or anybody else's profiler session, holds the profiler."""
+    from ray_tpu._private import metrics_defs as mdefs
+
+    duration = max(duration_s, 0.1)
     with _state_lock:
         node = (_node_id or "local")[:12]
     tag = f"{node}-{os.getpid()}"
     key = f"{capture_id}/{tag}"
     record: Dict[str, Any] = {
         "capture_id": capture_id, "node_id": node, "pid": os.getpid(),
-        "duration_s": duration, "ts": time.time()}
+        "duration_s": duration, "reason": reason, "ts": time.time()}
 
     def register() -> None:
+        if not address:
+            return
+        from ray_tpu._private import rpc
+        from ray_tpu.protobuf import ray_tpu_pb2 as pb
+
         try:
             gcs = rpc.get_stub("GcsService", address)
             gcs.KvPut(pb.KvRequest(ns=PROFILE_KV_NS, key=key,
@@ -848,7 +1334,7 @@ def _do_capture(cmd: Dict[str, Any], address: str) -> None:
         record.update(status="busy",
                       error="a capture is already in progress")
         register()
-        return
+        return record
     try:
         trace_dir = os.path.join(session_dir(), "profiles", capture_id,
                                  tag)
@@ -857,7 +1343,21 @@ def _do_capture(cmd: Dict[str, Any], address: str) -> None:
         register()
         import jax
 
-        jax.profiler.start_trace(trace_dir)
+        options = None
+        if lean:
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 1
+        try:
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
+        except RuntimeError as e:
+            if "already" not in str(e):
+                raise
+            # Somebody else's session (a benchmark's ``--trace 1``
+            # window): "Profile has already been started. Only one
+            # profile may be run at a time."
+            record.update(status="busy", error=str(e), end_ts=time.time())
+            return record
         try:
             time.sleep(duration)
         finally:
@@ -872,3 +1372,4 @@ def _do_capture(cmd: Dict[str, Any], address: str) -> None:
     finally:
         _capture_lock.release()
         register()
+    return record

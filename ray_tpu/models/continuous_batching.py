@@ -2668,8 +2668,9 @@ class ContinuousBatcher:
         table, ring = self._attended_blocks()
         return sum(ring), sum(table)
 
-    def _account_tick(self, tick_fn, wall_s: float, spec_k: int) -> None:
-        """Feed one tick to the XLA monitor, with the live-byte hint,
+    def _account_tick(self, tick_fn, tick: Dict[str, Any],
+                      spec_k: int) -> None:
+        """Feed one landed tick to the XLA monitor, with the live-byte hint,
         because the compiled cost prices every table entry as live, and
         book the share of entries
         that were, and how full the kernel's grid steps ran (a step
@@ -2718,8 +2719,10 @@ class ContinuousBatcher:
         if held:
             mdefs.CB_PAGED_VISIT_FILL_SHARE.observe(read / held,
                                                     tags=self._mtags)
-        tick_fn.note_execution(wall_s, bytes_hint=self.tick_bytes_estimate(
-            spec_k=spec_k, live_blocks=live))
+        tick_fn.note_execution(
+            tick["wall"], bytes_hint=self.tick_bytes_estimate(
+                spec_k=spec_k, live_blocks=live),
+            shape=len(tick["members"]), call=tick["call"])
 
     def tick_bytes_estimate(self, spec_k: Optional[int] = None,
                             live_blocks: Optional[int] = None) -> int:
@@ -3176,10 +3179,11 @@ class ContinuousBatcher:
             npb_c = eva.summaries(eva_c) // bs if eva_c else npb_w
             rows = [group[min(i, n - 1)] for i in range(n_pad)]
             live_before = len(self._slots)  # streams that stand still now
-            pt0 = time.time()  # wall-clock anchor for the prefill span
+            call = xla_monitor.Dispatched()
+            pt0 = call.ts  # wall-clock anchor for the prefill span
             with tracing.phase("engine.prefill", mdefs.CB_PREFILL_MS,
                                self._mtags, outer=admit) as prefill:
-                with _annotation("engine.prefill.dispatch"):
+                with _annotation("engine.prefill.dispatch", seq=call.seq):
                     slots = (None if self.state is None else self._place(
                         np.asarray([row[1] for row in rows], np.int32)))
                     firsts = []
@@ -3239,11 +3243,13 @@ class ContinuousBatcher:
                     self._empty_since = None
                     behind = (dispatched - prefill.t0) * 1e3
                 prefill.exclude(behind)
-                with _annotation("engine.prefill.fetch"):
+                with _annotation("engine.prefill.fetch", seq=call.seq):
                     # Only the last chunk's N ints are first tokens and
                     # fetched; the chunks before it are waited for, not
                     # fetched. Each is ready when its program ends, so
-                    # the time between two is a chunk's device time.
+                    # the time between two is a chunk's device time (and
+                    # the last one's answers for all of them).
+                    call.fetching(firsts[-1:])
                     for first in firsts:
                         with tracing.phase("engine.prefill.chunk",
                                            mdefs.CB_PREFILL_CHUNK_MS,
@@ -3257,6 +3263,7 @@ class ContinuousBatcher:
             # time: the XLA monitor turns it into achieved-FLOPs and
             # bandwidth gauges against this bucket's cost analysis, and
             # every stream that was live stood still through it.
+            call.landed()
             self._device_empty(prefill.t1, "prefill")
             prefill_ms = prefill.ms - behind
             self._stall_s += prefill_ms / 1e3
@@ -3269,7 +3276,8 @@ class ContinuousBatcher:
                                              tags=self._mtags)
             with tracing.phase("engine.account", mdefs.CB_STEP_ACCOUNT_MS,
                                self._mtags, outer=admit):
-                self._prefill.note_execution(prefill_ms / 1e3 / n_chunks)
+                self._prefill.note_execution(prefill_ms / 1e3, calls=n_chunks,
+                                             shape=shape, call=call)
             self._prefill_shapes.add((n_pad, padded_len))
             true_tokens = sum(len(row[4]) for row in group)
             self.prefill_requests += n
@@ -3805,8 +3813,9 @@ class ContinuousBatcher:
             self._upload_state(members)
         elif self._tables_stale:
             self._upload_tables(members)
-        w0 = time.time() if self._traced_live else None
-        t0 = time.perf_counter()
+        call = xla_monitor.Dispatched()     # the tick carries it to its landing
+        w0 = call.ts if self._traced_live else None
+        t0 = call.pc
         if self._empty_since is not None:
             # The device had nothing queued: it waited for this thread,
             # through the restart after a prefill or after its last tick.
@@ -3819,7 +3828,7 @@ class ContinuousBatcher:
             else:
                 self._book_empty(t0, mdefs.CB_STARVED_TICK_LATE_MS)
             self._empty_since = None
-        with _annotation("engine.tick.dispatch"):
+        with _annotation("engine.tick.dispatch", seq=call.seq):
             row = self._run_tick()
             for part in (row if isinstance(row, tuple) else (row,)):
                 part.copy_to_host_async()
@@ -3830,7 +3839,7 @@ class ContinuousBatcher:
             mdefs.CB_TICK_OVERLAPPED.inc(tags=self._mtags)
         self._inflight.append({"row": row, "members": members,
                                "k": self._last_tick_k, "t0": t0,
-                               "w0": w0, "wall": None,
+                               "w0": w0, "wall": None, "call": call,
                                "hold": self._held(),
                                "held": len(self._free)})
         if self.config.eva_window:
@@ -3857,12 +3866,16 @@ class ContinuousBatcher:
 
         if tick["wall"] is not None:
             return
-        with _annotation("engine.tick.fetch"):
+        call = tick["call"]
+        with _annotation("engine.tick.fetch", seq=call.seq):
             row = tick["row"]
+            # Was the row there before this thread came for it? Then the
+            # host is the slower side of this tick.
+            call.fetching(row if isinstance(row, tuple) else (row,))
             tick["row"] = (tuple(np.asarray(part) for part in row)
                            if isinstance(row, tuple) else np.asarray(row))
-        now = time.perf_counter()
-        tick["landed_ts"] = time.time()
+        now = call.landed()
+        tick["landed_ts"] = call.landed_ts
         tick["wall"] = now - max(self._row_landed, tick["t0"])
         self._row_landed = now
         wall_ms = tick["wall"] * 1e3
@@ -3890,7 +3903,7 @@ class ContinuousBatcher:
         with tracing.phase("engine.account", mdefs.CB_STEP_ACCOUNT_MS,
                            self._mtags):
             self._note_expert_rows([tick["row"]])
-            self._account_tick(tick_fn, tick["wall"], k)
+            self._account_tick(tick_fn, tick, k)
         self.landed_ts = tick["landed_ts"]
         self._apply_tokens(
             [tick["row"]], tick["members"],

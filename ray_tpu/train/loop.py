@@ -95,7 +95,7 @@ class AsyncStepLoop:
             # Windowed accounting: dispatch-of-first → fetch-complete,
             # split across the window's steps. Input stalls inside the
             # window inflate it — MFU errs LOW, never high.
-            step_jit.note_execution(per_step)
+            step_jit.note_execution(wall, calls=n)
         tags = {"trainer": self.name}
         for m in fetched:
             mdefs.TRAIN_STEP_SECONDS.observe(per_step, tags=tags)

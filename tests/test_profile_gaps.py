@@ -164,3 +164,118 @@ def test_cli_lists_the_recorded_traces_longest_gaps(capsys):
         assert "->" in row and "jit_" in " ".join(row), row
     assert "engine." in out.split("  gap at ", 1)[1]
     assert profile_gaps.main([RECORDED, "--gaps"]) == 2
+
+
+# ------------------------------------------------------- --calls (PR 48)
+
+
+def _six_calls(kind="engine.tick", program="jit_tick(1)"):
+    """Six calls 50 ms apart, each dispatch 0-1, program 2-12 on the
+    chip, fetch 1-12.5: launch 1, device 10, return 0.5 ms. Call 2's
+    program starts 9 ms late (launch), call 4's runs three times as long
+    with one instruction grown (device), call 5's fetch returns 8 ms
+    after its program ended (return). The host comes for each result
+    before it is there: none is late."""
+    annotations, modules, ops = [], [], []
+    for i in range(6):
+        t = 50 * i
+        start = t + 2 + (9 if i == 2 else 0)
+        length = 30 if i == 4 else 10
+        end = start + length
+        annotations += [(kind + ".dispatch", t * MS, 1 * MS, 100 + i),
+                        (kind + ".fetch", (t + 1) * MS,
+                         (end - t - 1) * MS + (8 * MS if i == 5 else 0)
+                         + MS // 2, 100 + i)]
+        modules.append((program, start * MS, length * MS))
+        ops += [("%fusion.1 = f32[8] fusion()", start * MS, 4 * MS),
+                ("%while.2 = () while()", (start + 4) * MS,
+                 (length - 4) * MS),
+                ("%copy.3 = f32[8] copy()", (start + 5) * MS, 2 * MS)]
+    return annotations, modules, ops
+
+
+def test_calls_names_the_slow_part_of_each_slow_call():
+    annotations, modules, ops = _six_calls()
+    # Events --calls must step over: an annotation with no seq (a trace
+    # of an older program), one whose program the trace cut off.
+    annotations += [("engine.tick.dispatch", 500 * MS, MS, None),
+                    ("engine.tick.dispatch", 600 * MS, MS, 999),
+                    ("engine.tick.fetch", 601 * MS, MS, 999)]
+    got = profile_gaps.calls(annotations, modules, ops)
+    assert [c["seq"] for c in got["calls"]] == [100 + i for i in range(6)]
+    parts = {c["seq"]: (c["launch_ms"], c["device_ms"], c["return_ms"])
+             for c in got["calls"]}
+    assert parts[100] == pytest.approx((1.0, 10.0, 0.5))
+    assert parts[102] == pytest.approx((10.0, 10.0, 0.5))
+    assert parts[104] == pytest.approx((1.0, 30.0, 0.5))
+    assert parts[105] == pytest.approx((1.0, 10.0, 8.5))
+    (group,) = got["by_program"]
+    assert (group["kind"], group["program"], group["n"]) == (
+        "engine.tick", "jit_tick(1)", 6)
+    assert group["median_ms"] == pytest.approx(
+        {"launch": 1.0, "device": 10.0, "return": 0.5, "late": 0.0})
+    assert all(c["late_ms"] == 0.0 for c in got["calls"])
+    far = group["furthest"]
+    assert far["launch"] == [[102, pytest.approx(9.0)]]
+    assert far["device"] == [[104, pytest.approx(20.0)]]
+    assert far["return"] == [[105, pytest.approx(8.0)]]
+    # Only call 104 is over two medians of the whole; the instruction
+    # that grew in it is the while's own time (its copy is a child).
+    assert group["slow"] == [104]
+    assert [c["slow"] for c in got["calls"]] == [False] * 4 + [True, False]
+    name, slow_ms, others_ms = group["grew"][0]
+    assert name == "while.2"
+    assert (slow_ms, others_ms) == pytest.approx((24.0, 4.0))
+    assert {g[0] for g in group["grew"]} == {"while.2", "fusion.1", "copy.3"}
+
+
+def test_calls_keeps_programs_and_kinds_apart_and_launch_where_the_chip_was_free():
+    ticks = _six_calls()
+    # A prefill batch of two chunk programs dispatched at 300, behind a
+    # tick that holds the chip until 310: the wait behind it is not
+    # launch, and both programs are the call's device time.
+    annotations = ticks[0] + [
+        ("engine.prefill.dispatch", 300 * MS, 2 * MS, 7),
+        ("engine.prefill.fetch", 305 * MS, 50 * MS, 7)]
+    modules = ticks[1] + [("jit_tick(1)", 290 * MS, 20 * MS),
+                          ("jit_prefill(2)", 311 * MS, 15 * MS),
+                          ("jit_prefill(2)", 326 * MS, 15 * MS)]
+    got = profile_gaps.calls(annotations, modules)
+    (prefill,) = [c for c in got["calls"] if c["kind"] == "engine.prefill"]
+    assert prefill["program"] == "jit_prefill(2)"
+    assert (prefill["launch_ms"], prefill["device_ms"],
+            prefill["return_ms"]) == pytest.approx((1.0, 30.0, 14.0))
+    # A host that came 200 ms after the tick's program had ended, and
+    # then had its row in 0.3 ms: late, not return.
+    late = profile_gaps.calls(
+        [("engine.tick.dispatch", 0, MS, 1),
+         ("engine.tick.fetch", 212 * MS, 3 * MS // 10, 1)],
+        [("jit_tick(1)", 2 * MS, 10 * MS)])["calls"][0]
+    assert (late["launch_ms"], late["device_ms"], late["return_ms"],
+            late["late_ms"]) == pytest.approx((1.0, 10.0, 0.3, 200.0))
+    assert [(g["kind"], g["n"]) for g in got["by_program"]] == [
+        ("engine.prefill", 1), ("engine.tick", 6)]
+    assert all(g["grew"] == [] for g in got["by_program"])     # no ops given
+    # The tick at 290 started after every dispatched call had landed:
+    # nobody's, and call 105 keeps its own 10 ms.
+    (last,) = [c for c in got["calls"] if c["seq"] == 105]
+    assert last["device_ms"] == pytest.approx(10.0)
+
+
+def test_cli_calls_prints_each_call_and_what_grew(capsys, monkeypatch):
+    annotations, modules, ops = _six_calls()
+    monkeypatch.setattr(
+        profile_gaps, "load_calls",
+        lambda path: ({"/device:TPU:0": (modules, ops)}, annotations))
+    assert profile_gaps.main(["trace.xplane.pb", "--calls"]) == 0
+    out = capsys.readouterr().out.splitlines()
+    assert out[0].startswith("/device:TPU:0: 6 engine calls")
+    calls = [line for line in out if " seq " in line and " at " in line]
+    assert len(calls) == 6 and calls[4].lstrip().startswith("* seq")
+    assert any("furthest over in launch: seq 102 +9.000" in l for l in out)
+    assert any("furthest over in return: seq 105 +8.000" in l for l in out)
+    assert any("grew in them: while.2" in l for l in out)
+    # The recorded v5e trace predates the seq: no call to list, no error.
+    monkeypatch.undo()
+    assert profile_gaps.main([RECORDED, "--calls"]) == 0
+    assert "0 engine calls" in capsys.readouterr().out

@@ -159,6 +159,48 @@ def test_note_execution_sets_achieved_gauges():
     assert samples.get(name, 0) > 0
 
 
+def test_note_execution_keeps_one_record_over_its_back_to_back_calls():
+    """``calls`` back-to-back calls in one reading (a chunked prefill
+    batch): the gauges price ONE call, the ring keeps the whole."""
+    name = _name("chunked")
+    w = xm.instrument(lambda x: jnp.dot(x, x), name=name)
+    w(jnp.ones((32, 32)))
+    one = w.note_execution(0.01)
+    four = w.note_execution(0.04, calls=4, shape=(4, 128))
+    assert four["achieved_flops_per_s"] == pytest.approx(
+        one["achieved_flops_per_s"])
+    first, second = xm._calls.records(last=2)
+    assert (first.program, first.calls, first.shape) == (name, 1, None)
+    assert (second.program, second.calls, second.shape,
+            second.wall_s) == (name, 4, (4, 128), 0.04)
+    assert second.seq == first.seq + 1 and not second.slow
+    # No ``Dispatched`` came with it: stamped here, silent on the fetch.
+    assert second.dispatch_pc is None and second.ready is None
+    assert second.landed_ts == pytest.approx(time.time(), abs=5)
+
+
+def test_train_loop_feeds_one_record_a_window():
+    """``AsyncStepLoop.sync`` is one measured execution of
+    ``sync_every`` steps: one record, its wall the window's."""
+    from ray_tpu.models import llama
+    from ray_tpu.models.training import ShardedTrainer, synthetic_batch
+    from ray_tpu.parallel import MeshConfig, make_mesh
+    from ray_tpu.train.loop import AsyncStepLoop
+
+    config = llama.LlamaConfig.tiny()
+    trainer = ShardedTrainer(config, make_mesh(MeshConfig(fsdp=-1)))
+    batch = trainer.shard_batch(synthetic_batch(8, 16, config.vocab_size))
+    loop = AsyncStepLoop(trainer, trainer.init_state(), sync_every=3)
+    seen = xm._calls.records(last=1)
+    last_seq = seen[0].seq if seen else 0
+    loop.run([batch] * 6)
+    mine = [r for r in xm._calls.records(last=64)
+            if r.seq > last_seq and r.program == "train_step"]
+    assert [r.calls for r in mine] == [3, 3]
+    assert sum(r.wall_s for r in mine) == pytest.approx(
+        loop.stats()["window_wall_s"])
+
+
 # --------------------------------- serve tick / train step integration
 
 
@@ -344,6 +386,39 @@ def test_capture_targets_other_node_is_ignored(gcs_server, tmp_path,
     time.sleep(1.0)
     assert not [e for e in xm.list_captures(address)
                 if e.get("capture_id") == capture_id]
+
+
+def test_a_stall_capture_is_listed_beside_the_commands(gcs_server, tmp_path,
+                                                       capsys, monkeypatch):
+    """A stalled stretch's capture goes through the function the
+    listener's command uses: same session dir, same ``__profiles__``
+    registry, ``reason: stall``, and ``ray-tpu profile list`` shows it."""
+    monkeypatch.setenv("RAY_TPU_SESSION_DIR", str(tmp_path))
+    monkeypatch.setenv("RAY_TPU_stall_capture_s", "0.2")
+    address = f"127.0.0.1:{gcs_server.port}"
+    xm.connect(address, node_id="stallnode")
+    jnp.dot(jnp.ones((32, 32)), jnp.ones((32, 32))).block_until_ready()
+    rec = xm._CallRecord()
+    for _ in range(xm.BASELINE_MIN + 1):
+        rec.note("p", 1, 0.01, 1, None)
+    rec.note("p", 1, 0.5, 1, None)
+    stretch = rec._stretch
+    assert stretch.capture_thread is None    # one slow call takes none
+    rec.note("p", 1, 0.5, 1, None)
+    stretch.capture_thread.join(timeout=120)
+    assert not stretch.capture_thread.is_alive()
+    assert stretch.capture["status"] == "done", stretch.capture
+    (entry,) = [e for e in xm.list_captures(address)
+                if e.get("reason") == "stall"]
+    assert entry["capture_id"].startswith("stall-")
+    assert entry["status"] == "done" and entry["node_id"] == "stallnode"
+    assert entry["trace_dir"].startswith(str(tmp_path / "profiles"))
+    from ray_tpu.scripts import cli
+
+    cli.main(["profile", "list", "--address", address])
+    out = capsys.readouterr().out
+    assert entry["capture_id"] in out and "done" in out
+    xm.disconnect(address)
 
 
 # --------------------------------------- metrics tail downsample hint
