@@ -18,6 +18,7 @@ library's order.
 """
 
 import dataclasses
+import math
 import os
 import sys
 
@@ -247,30 +248,58 @@ def _latent_case(positions, nb, limits=None, seed=0, h=4, w=128, rank=32,
             None if limits is None else jnp.asarray(limits, jnp.int32), rank)
 
 
-@pytest.mark.parametrize("positions,limits", [
-    ([0, 7, 8, 30, 39], None),                     # ragged, a full table
-    ([3, 0, 17, 0, 33], [40, 0, 40, 0, 40]),       # freed slots between
-    ([32, 15, 8], None),     # a short last visit: 5, 2 and 2 live blocks
+@pytest.mark.parametrize("positions,limits,nb,per,poison", [
+    ([0, 7, 8, 30, 39], None, 5, None, False),     # ragged, a full table
+    ([3, 0, 17, 0, 33], [40, 0, 40, 0, 40], 5, None, False),  # freed between
+    # A short last visit: 5, 2 and 2 live blocks of the rule's step.
+    ([32, 15, 8], None, 5, None, False),
+    # A last visit with ONE live sub-block of four: 4 + 1, 4 + 4 + 1, 4 + 1.
+    ([39, 71, 33], None, 9, 4, False),
+    # Consecutive visits of different slots, each with another count of
+    # live sub-blocks, a freed slot between: the copies of visit v + 1 are
+    # started from a step of another slot.
+    ([7, 20, 3, 39, 11], [40, 40, 0, 40, 40], 5, 2, False),
+    ([5], None, 5, None, False),        # one slot of one block: one visit
+    ([0, 7, 3], None, 5, 2, False),     # every slot's context is one block
+    # The blocks NO live table entry names hold NaN (the garbage block,
+    # the dead tails' blocks): nothing of them may reach the output.
+    ([39, 12, 71, 0], [72, 72, 72, 0], 9, 4, True),
 ])
-def test_latent_kernel_is_the_gather(positions, limits, pallas_interpret):
+def test_latent_kernel_is_the_gather(positions, limits, nb, per, poison,
+                                     pallas_interpret):
     """(d) ``latent_decode_attn`` (interpreted) against the ``jax.numpy``
     gather: every row scored over all its lanes, weighed by its first
-    ``rank``; a freed slot's row is zero."""
-    q, arena, tables, pos, lim, rank = _latent_case(positions, 5, limits)
+    ``rank``; a freed slot's row is zero. ``per``: blocks a grid step, the
+    cache's own rule when None. The interpreter starts the kernel's row
+    buffer as NaN, so a dead sub-block that was neither fetched nor
+    blanked shows in every case with a short visit."""
+    from ray_tpu.ops.paged_decode_attention import paged_visits
+
+    q, arena, tables, pos, lim, rank = _latent_case(positions, nb, limits)
     want = latent_attention_reference(q, arena, tables, pos, 0.3,
                                       rank=rank, layer=1)
-    got = latent_decode_attention(q, arena, tables, pos, 0.3, rank=rank,
-                                  layer=jnp.int32(1), limits=lim,
-                                  use_kernel=True)
     live = np.ones(len(positions), bool) if limits is None else (
         np.asarray(limits) > 0)
+    if poison:
+        named = np.zeros(arena.shape[1], bool)
+        for s, p in enumerate(positions):
+            if live[s]:
+                named[np.asarray(tables)[s, :p // BS + 1]] = True
+        assert not named[0] and named.sum() < named.size - 1
+        arena = arena.at[:, ~named].set(jnp.nan)
+    visits = None if per is None else paged_visits(
+        tables, pos, lim, block_size=BS, per_visit=per)
+    got = latent_decode_attention(q, arena, tables, pos, 0.3, rank=rank,
+                                  layer=jnp.int32(1), limits=lim,
+                                  visits=visits, use_kernel=True)
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
                                atol=2e-5)
     assert not np.asarray(got)[~live].any()
     # A slab with no layer axis is an arena of one layer.
     np.testing.assert_allclose(
         latent_decode_attention(q, arena[1], tables, pos, 0.3, rank=rank,
-                                limits=lim, use_kernel=True)[live],
+                                limits=lim, visits=visits,
+                                use_kernel=True)[live],
         np.asarray(want)[live], atol=2e-5)
 
 
@@ -305,14 +334,29 @@ def test_prefill_kernel_is_the_softmax_and_runs_merge(causal,
                          jnp.concatenate([v, v2], 2)), atol=2e-5)
 
 
-def test_a_latent_grid_step_covers_eight_blocks():
-    from ray_tpu.ops.latent_decode_attention import latent_visit_blocks
+def test_a_latent_grid_step_covers_about_a_megabyte():
+    """``latent_visit_blocks``: ``visit_blocks``' rule with the latent
+    kernel's own constants: about ``VISIT_BYTES`` of ONE slot's rows a
+    grid step, at most ``MAX_VISIT_BLOCKS``. Kimi's cache (blocks of 64
+    rows x 640 lanes, 80 KiB) gets the sixteen the chip timed best."""
+    from ray_tpu.ops.latent_decode_attention import (MAX_VISIT_BLOCKS,
+                                                     VISIT_BYTES,
+                                                     latent_visit_blocks)
 
-    assert latent_visit_blocks(jnp.zeros((5, 3, 1, 64, 640),
-                                         jnp.bfloat16)) == 8
-    # 1 MiB a step at most: a block of 512 rows is 655 KB.
-    assert latent_visit_blocks(jnp.zeros((3, 1, 512, 640),
-                                         jnp.bfloat16)) == 1
+    assert VISIT_BYTES == 5 << 18 and MAX_VISIT_BLOCKS == 16
+    for arena, want in (
+            (jnp.zeros((5, 3, 1, 64, 640), jnp.bfloat16), 16),   # the cell's
+            (jnp.zeros((3, 1, 128, 640), jnp.bfloat16), 8),      # a slab
+            (jnp.zeros((3, 1, 256, 640), jnp.float32), 2),
+            # A block over the rule's bytes is a step by itself.
+            (jnp.zeros((3, 1, 2048, 640), jnp.bfloat16), 1)):
+        block = math.prod(arena.shape[-2:]) * arena.dtype.itemsize
+        assert latent_visit_blocks(arena) == want
+        if want > 1:
+            assert want * block <= VISIT_BYTES < (want + 1) * block
+    # Small blocks meet the cap, not the bytes.
+    assert latent_visit_blocks(jnp.zeros((3, 1, 8, 128),
+                                         jnp.float32)) == MAX_VISIT_BLOCKS
 
 
 def test_absorbed_form_is_the_expanded_form(model):

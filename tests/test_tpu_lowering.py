@@ -755,23 +755,34 @@ def test_compiled_paged_decode_at_every_visit_width(v5e_chip, hkv, hq, d,
 # ------------------------------------------------- latent attention (MLA)
 
 _MLA_SLOTS, _MLA_LEN, _MLA_BS = 64, 10240, 64
+_MLA_VISIT = 16        # blocks a grid step of the latent kernel
 
 
 def test_latent_decode_kernel_lowers():
     """Kimi K2's head shape: 64 absorbed queries of 640 lanes (512 latent
-    + 64 rope + 64 pad) a slot, values the first 512, blocks of 64."""
-    from ray_tpu.ops.latent_decode_attention import latent_decode_attention
+    + 64 rope + 64 pad) a slot, values the first 512, blocks of 64, the
+    shipped blocks a grid step: one call, which takes the cache ONCE
+    (the kernel copies a visit's rows out of it itself; a BlockSpec'd
+    view a sub-block was one operand each)."""
+    from ray_tpu.ops.latent_decode_attention import (latent_decode_attention,
+                                                     latent_visit_blocks)
 
     nb = _MLA_LEN // _MLA_BS
     q = S((8, 64, 640), BF16)
     arena = S((5, 1 + 8 * nb, 1, _MLA_BS, 640), BF16)
+    assert latent_visit_blocks(arena) == _MLA_VISIT
     fn = functools.partial(latent_decode_attention, scale=0.14, rank=512,
                            use_kernel=True)
     exported = jax.export.export(jax.jit(
         lambda q, a, t, p, li: fn(q, a, t, p, layer=li)), platforms=["tpu"])(
         q, arena, S((8, nb), jnp.int32), S((8,), jnp.int32),
         S((), jnp.int32))
-    assert _kernel_names(exported.mlir_module()) == ["latent_decode_attn"]
+    module = exported.mlir_module()
+    assert _kernel_names(module) == ["latent_decode_attn"]
+    call, = [line for line in module.splitlines()
+             if "tpu_custom_call" in line]
+    operands = call[call.rindex(" : ("):call.rindex(") -> ")]
+    assert operands.count(f"tensor<5x{1 + 8 * nb}x1x{_MLA_BS}x640xbf16>") == 1
 
 
 def test_latent_prefill_kernel_lowers():
@@ -795,7 +806,9 @@ def test_compiled_latent_tick_reads_the_cache_through_its_kernels(v5e_chip):
     experts, 64 slots x 10240 over 10,241 blocks): each of the two runs'
     layer bodies touches the latent cache through ``paged_kv_write`` and
     ``latent_decode_attn`` and nothing else (no slab of ``[10241, 1, 64,
-    640]`` is sliced, copied or scattered), the routed run calls
+    640]`` is sliced, copied or scattered; the kernel takes the whole
+    cache as ONE operand and no visit's joined ``[1024, 640]`` tile
+    exists outside it), the routed run calls
     ``moe_gmm`` twice (gate and up in one call, then down), and beside
     the 11.2 GB of arguments (7.0 GB of weights, 4.2 GB of cache,
     donated) the program needs under 64 MB."""
@@ -829,10 +842,17 @@ def test_compiled_latent_tick_reads_the_cache_through_its_kernels(v5e_chip):
     for name, calls in (("latent_decode_attn", 2), ("paged_kv_write", 2),
                         ("moe_gmm", 2)):
         assert len(re.findall(rf"%{name}[.\d]* = ", hlo)) == calls, name
+    whole = f"bf16[5,{blocks},1,{_MLA_BS},640]"
+    for line in hlo.splitlines():
+        if re.search(r"%latent_decode_attn[.\d]* = ", line):
+            operands = line[line.index("operand_layout_constraints="):
+                            line.index("output_to_operand_aliasing=")]
+            assert operands.count(whole) == 1, operands
     shaped = re.compile(rf"= \(?\w+\[(\d+,)?{blocks},1,{_MLA_BS},640\]")
     moved = [line.strip() for line in hlo.splitlines()
              if shaped.search(line) and not any(f in line for f in _FREE)]
     assert moved == []
+    assert f"= bf16[{_MLA_VISIT * _MLA_BS},640]" not in hlo
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 64 << 20
     assert memory.alias_size_in_bytes == cache.k.size * 2
