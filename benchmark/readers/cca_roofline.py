@@ -1,4 +1,4 @@
-"""Two readings of a cell whose model attends in a compressed latent
+"""Three readings of a cell whose model attends in a compressed latent
 behind convolutions (``zaya``: a config with ``cca_time0``), ``stat``:
 
 ``paged_attn``: the ``paged_decode_attn`` kernel's share of its roofline
@@ -21,6 +21,12 @@ Both take the registry's deltas over the CAPTURE where the runner kept
 them (``trace.cca_capture``: the profiler's few seconds, the same as the
 kernel time's), else over the window.
 
+``kv_resident``: resident bytes of the model's two stores (the K/V arena
+in the latent, gauge ``ray_tpu_cb_cca_kv_bytes``, plus the tail cache,
+``ray_tpu_cb_cca_tail_bytes``; both fixed at construction) over what
+per-head K and V at the hidden width would take in the same blocks, in
+percent. Needs no trace.
+
 A trace without the by-kernel part, a program that never ran the kernel
 or books none of these series (the parent commit), or a configuration
 without ``cca_time0`` reads nothing.
@@ -33,6 +39,8 @@ from benchmark import flops_cca, peaks
 LIVE = "ray_tpu_cb_paged_live_block_share"
 DECODED = "ray_tpu_cb_decode_tokens_total"
 LOCAL = "ray_tpu_cb_moe_local_assignments_total"
+KV_BYTES = "ray_tpu_cb_cca_kv_bytes"
+TAIL_BYTES = "ray_tpu_cb_cca_tail_bytes"
 TOUCHED = "ray_tpu_cb_moe_experts_touched_share"
 # What a capture keeps (``runners/serve_cca.py::Trace``).
 CAPTURED = (LIVE + "_sum", LIVE + "_count", DECODED, LOCAL,
@@ -48,11 +56,21 @@ def _deltas(ctx):
             for name in CAPTURED}
 
 
-def read(ctx, stat: str, kernel: str, program: str) -> Optional[float]:
+def read(ctx, stat: str, kernel: Optional[str] = None,
+         program: Optional[str] = None) -> Optional[float]:
     config = ctx.get("config") or {}
     if (not config.get("cca_time0") or not ctx.get("registry_before")
             or not ctx.get("registry_after")):
         return None
+    if stat == "kv_resident":
+        after = ctx["registry_after"]
+        if not after.get(KV_BYTES):
+            return None
+        # Per-head K and V at the hidden width: 2 x hidden bf16 values a
+        # token a layer where the latent keeps ``kv_token_bytes``.
+        per_head = (after[KV_BYTES] * 2 * config["hidden_size"] * 2
+                    / flops_cca.kv_token_bytes(config))
+        return 100.0 * (after[KV_BYTES] + after.get(TAIL_BYTES, 0.0)) / per_head
     engine = ctx["engine"]
     bs = engine["block_size"]
     trace = ctx.get("trace") or {}

@@ -17,6 +17,8 @@ import pytest
 
 from benchmark import flops_cca, manifest, traffic
 from benchmark.runners import serve_cca
+from benchmark.tests.test_benchmark_entries import (entry_for,
+                                                    listed_as_it_was)
 from benchmark.tests.test_window import _metric, _registry
 
 NAME = "zaya1-8b-l10"
@@ -31,10 +33,17 @@ PUBLISHED = {
     "num_hidden_layers": 40, "num_key_value_heads": 2,
     "partial_rotary_factor": 0.5, "rms_norm_eps": 1e-05,
     "router_hidden_size": 256, "vocab_size": 262272}
-# Three of the sixteen ISSUE 46 lists: `per_layer` holds 128 entries at
-# most and had 125 (CHANGES.md, PR 46).
-NEW = ("paged_attn_roofline_share.cca", "moe_gmm_roofline_share.cca",
-       "tick_wall_ms.cca")
+# ISSUE 46's sixteen: four entries of the family's own (three of them PR
+# 46's, ``cca_kv_resident_share`` waited for room until PR 51) and the
+# twelve accepted measurements the cell joined when PR 51 made the room
+# (``tick_wall_ms.cca`` is ``tick_wall_ms.closed_loop`` since).
+OWN = ("paged_attn_roofline_share.cca", "moe_gmm_roofline_share.cca",
+       "cca_kv_resident_share")
+AGAIN = ("tick_wall_ms.closed_loop", "paged_attn_time_share",
+         "moe_gmm_time_share", "moe_experts_touched_share",
+         "moe_load_imbalance", "prefill_batch_ms", "prefill_chunk_ms",
+         "slot_occupancy", "decode_stall_share", "device_starved_share",
+         "tick_overlap_share", "ttft_p50_ms", "engine_queue_ms")
 
 
 def test_manifest_finds_the_cell_and_its_files():
@@ -43,37 +52,24 @@ def test_manifest_finds_the_cell_and_its_files():
         NAME, "reasoning_context_decode", 1)
     assert cell["workload"]["runner"] == "serve_cca"
     listed = manifest.names(cell["per_layer"])
-    # The new entries in their order among themselves, not "last".
-    assert tuple(n for n in listed if n in NEW) == NEW
+    assert set(OWN + AGAIN) <= set(listed) and len(OWN + AGAIN) == 16
     assert {"mosaic_time_share", "compiles_in_window"} <= set(listed)
     assert manifest.names(cell["end_to_end"]) == ["tokens_per_s", "setup_s"]
     bench = manifest.benchmark()
-    entries = {m["name"]: m for m in bench["per_layer"]}
-    for name in NEW:
-        assert entries[name]["workloads"] == [CELL]
-        assert entries[name]["moves"] == "tokens_per_s"
-    assert len(bench["per_layer"]) <= 128
+    for name in OWN:
+        entry, spec = entry_for(name, CELL)
+        assert entry["workloads"] == [CELL]
+        assert entry["moves"] == "tokens_per_s"
+        assert spec["reader"] == "cca_roofline"
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     entry = next(c for c in bench["configs"] if c["name"] == NAME)
     assert entry["reduced"] == ["num_hidden_layers", "layer_types"]
     assert entry["source"] == ZAYA["source"]
 
 
-@pytest.mark.parametrize("name", NEW)
-def test_a_layers_metric_listed_again_is_the_one_it_had(name):
-    """A metric this cell lists again under its own name keeps the
-    reader, arguments, unit, direction, source and layer of the entry it
-    repeats."""
-    bench = {m["name"]: m for m in manifest.benchmark()["per_layer"]}
-    first = next(n for n in (name[:-4] + ".eva", name[:-4] + ".linear",
-                             name[:-4] + ".window") if n in bench)
-    same = ("unit", "better", "source", "layer", "moves")
-    assert {k: bench[name][k] for k in same} == {
-        k: bench[first][k] for k in same}
-    if "roofline" not in name:      # those read this family's own flops
-        mine, theirs = manifest.metric_file(name), manifest.metric_file(first)
-        assert (mine["reader"], mine["args"]) == (theirs["reader"],
-                                                  theirs["args"])
+@pytest.mark.parametrize("name", AGAIN)
+def test_an_accepted_measurement_is_listed_for_the_cell(name):
+    listed_as_it_was(name, CELL)
 
 
 def test_file_keeps_every_published_number_but_the_reduced_ones():
@@ -182,15 +178,23 @@ def test_metrics_read_through_their_files_on_a_synthetic_ctx():
     assert _metric("moe_gmm_roofline_share.cca", windowed) == pytest.approx(
         100 * flops_cca.tick_gmm_seconds(ZAYA, 96, 0.9 * 16, V5E)
         / (1.5 / 20))
-    assert _metric("tick_wall_ms.cca", ctx) == pytest.approx(14.0)
+    assert _metric("tick_wall_ms.closed_loop", ctx) == pytest.approx(14.0)
+    # The two stores over per-head K and V at the hidden width (8 x the
+    # latent's bytes): gauges as the window closed, no trace needed.
+    gauged = dict(ctx, trace={}, registry_after=dict(
+        after, ray_tpu_cb_cca_kv_bytes=7_047_086_080.0,
+        ray_tpu_cb_cca_tail_bytes=5_160_960.0))
+    assert _metric("cca_kv_resident_share", gauged) == pytest.approx(
+        100 * (7_047_086_080 + 5_160_960) / (8 * 7_047_086_080))
+    assert _metric("cca_kv_resident_share", ctx) is None     # no gauge
     # The parent commit books none of it and traces none of it.
     bare = dict(ctx, registry_before={}, registry_after={}, trace={})
-    for name in ("paged_attn_roofline_share.cca",
-                 "moe_gmm_roofline_share.cca"):
+    for name in OWN:
         assert _metric(name, bare) is None
     # ... and another family's cell reads nothing here.
-    other = dict(ctx, config={"sliding_window": 4096})
-    assert _metric("moe_gmm_roofline_share.cca", other) is None
+    other = dict(gauged, trace=trace, config={"sliding_window": 4096})
+    for name in OWN:
+        assert _metric(name, other) is None
     # No share of a roofline over 100%: at the kernel's own time equal to
     # the least time it reads 100, whatever the blocks' overhang.
     least = flops_cca.tick_attn_seconds(ZAYA, tokens, 95, V5E)
@@ -266,8 +270,11 @@ def test_the_rehearsal_runs_end_to_end_and_is_correct():
     assert line["correct"] is True and line["failed"] == 0
     assert line["detail"]["rehearsal"] and line["device"]["platform"] == "cpu"
     # What needs no device trace is read on the CPU too.
-    for name in ("tick_wall_ms.cca", "compiles_in_window"):
+    for name in ("tick_wall_ms.closed_loop", "cca_kv_resident_share",
+                 "slot_occupancy", "prefill_chunk_ms", "compiles_in_window"):
         assert name in line["metrics"], name
+    # 2 x 2 x 16 of K/V against 2 x 64 at the hidden width, and the tail.
+    assert 50 < line["metrics"]["cca_kv_resident_share"]["value"] < 100
     assert line["metrics"]["compiles_in_window"]["value"] == 0
     assert line["detail"]["ray_tpu_cb_cca_tail_bytes"] == 4 * 3 * 208 * 2
     assert line["detail"]["cca_capture"][

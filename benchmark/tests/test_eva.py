@@ -17,6 +17,8 @@ import pytest
 
 from benchmark import flops_eva, manifest, traffic
 from benchmark.runners import serve_eva
+from benchmark.tests.test_benchmark_entries import (entry_for,
+                                                    listed_as_it_was)
 from benchmark.tests.test_window import _custom_call, _metric, _registry
 
 NAME = "evabyte-6.5b-l8"
@@ -31,13 +33,18 @@ PUBLISHED = {
     "num_hidden_layers": 32, "num_key_value_heads": 32, "num_pred_heads": 8,
     "rms_norm_eps": 1e-05, "rope_theta": 100000, "vocab_size": 320,
     "window_size": 2048}
-AGAIN = ("tick_wall_ms", "prefill_batch_ms", "prefill_chunk_ms",
-         "slot_occupancy", "decode_stall_share", "device_starved_share",
-         "tick_overlap_share", "ttft_p50_ms", "engine_queue_ms")
-NEW = ("eva_attn_time_share", "eva_attn_roofline_share",
-       "eva_compress_time_share", "eva_summary_key_share",
-       "eva_cache_resident_share", "eva_windows_closed_in_tick_share"
-       ) + tuple(name + ".eva" for name in AGAIN)
+# The accepted measurements the cell is listed for besides its own
+# (entries of their own, ``<name>.eva``, until PR 51 merged each into the
+# one entry of its reader and arguments; ``eva_attn_time_share`` was
+# ``paged_attn_time_share``'s reader and arguments under another name:
+# EvaByte's tick runs ``paged_decode_attn``).
+AGAIN = ("paged_attn_time_share", "tick_wall_ms.closed_loop",
+         "prefill_batch_ms", "prefill_chunk_ms", "slot_occupancy",
+         "decode_stall_share", "device_starved_share", "tick_overlap_share",
+         "ttft_p50_ms", "engine_queue_ms")
+OWN = ("eva_attn_roofline_share", "eva_compress_time_share",
+       "eva_summary_key_share", "eva_cache_resident_share",
+       "eva_windows_closed_in_tick_share")
 
 
 def test_manifest_finds_the_cell_and_its_files():
@@ -46,28 +53,23 @@ def test_manifest_finds_the_cell_and_its_files():
         NAME, "byte_context_decode", 1)
     assert cell["workload"]["runner"] == "serve_eva"
     listed = manifest.names(cell["per_layer"])
-    # The new entries in their order among themselves, not "last".
-    assert tuple(n for n in listed if n in NEW) == NEW and len(NEW) == 15
+    assert set(OWN + AGAIN) <= set(listed) and len(OWN + AGAIN) == 15
     assert {"mosaic_time_share", "compiles_in_window"} <= set(listed)
     assert manifest.names(cell["end_to_end"]) == ["tokens_per_s", "setup_s"]
     bench = manifest.benchmark()
-    entries = {m["name"]: m for m in bench["per_layer"]}
-    for name in NEW:
-        assert entries[name]["workloads"] == [CELL]
-        assert entries[name]["moves"] == "tokens_per_s"
+    for name in OWN:
+        entry, _ = entry_for(name, CELL)
+        assert entry["workloads"] == [CELL]
+        assert entry["moves"] == "tokens_per_s"
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     entry = next(c for c in bench["configs"] if c["name"] == NAME)
     assert entry["reduced"] == ["num_hidden_layers"]
     assert entry["source"] == EVA["source"]
 
 
-def test_a_layers_metric_listed_again_is_the_one_it_had():
-    for base in AGAIN:
-        twin = "engine_queue_ms.mla" if base == "engine_queue_ms" \
-            else base + ".linear"
-        old, new = manifest.metric_file(twin), manifest.metric_file(
-            base + ".eva")
-        assert (old["reader"], old["args"]) == (new["reader"], new["args"])
+@pytest.mark.parametrize("name", AGAIN)
+def test_an_accepted_measurement_is_listed_for_the_cell(name):
+    listed_as_it_was(name, CELL)
 
 
 def test_file_keeps_every_published_number_but_the_reduced_one():
@@ -166,7 +168,7 @@ def test_metrics_read_through_their_files_on_a_synthetic_ctx():
     # 300 blocks retired = 10 windows closed by ticks, of 40.
     assert _metric("eva_windows_closed_in_tick_share", ctx) == \
         pytest.approx(25.0)
-    assert _metric("eva_attn_time_share", ctx) == pytest.approx(
+    assert _metric("paged_attn_time_share", ctx) == pytest.approx(
         100 * 2.0 / 3.8)
     assert _metric("eva_attn_roofline_share", ctx) == pytest.approx(
         100 * least / (2.0 / 200))
@@ -174,7 +176,7 @@ def test_metrics_read_through_their_files_on_a_synthetic_ctx():
         100 * 0.019 / 3.8)
     # The parent commit books none of it and traces none of it.
     bare = dict(ctx, registry_before={}, registry_after={}, trace={})
-    for name in NEW[:6]:
+    for name in OWN + ("paged_attn_time_share",):
         assert _metric(name, bare) is None
     # ... and another family's cell reads nothing here.
     other = dict(ctx, config={"sliding_window": 4096})
@@ -285,8 +287,8 @@ def test_the_rehearsal_runs_end_to_end_and_is_correct():
     got = set(line["metrics"])
     # What needs no chip is there; the device's shares need one.
     assert {"eva_summary_key_share", "eva_cache_resident_share",
-            "eva_windows_closed_in_tick_share", "tick_wall_ms.eva",
-            "slot_occupancy.eva", "compiles_in_window"} <= got
+            "eva_windows_closed_in_tick_share", "tick_wall_ms.closed_loop",
+            "slot_occupancy", "compiles_in_window"} <= got
     assert line["metrics"]["compiles_in_window"]["value"] == 0
     for name in serve_eva.LISTED_ELSEWHERE:
         assert line["detail"][name] is not None

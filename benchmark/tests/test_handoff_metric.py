@@ -1,6 +1,7 @@
 """``stream_tokens_per_handoff`` (PR 41): a file over the accepted reader
 ``registry_delta``, names samples the program's registry defines, is
-listed for the seven serve cells at the end of ``per_layer``, reads the
+listed for the serve cells (PR 41's seven and whichever later PRs
+append), reads the
 tokens one tick-thread -> loop call carried, reads nothing against a
 program without the counter, and is reported by a serve cell's
 rehearsal."""
@@ -11,6 +12,7 @@ import pytest
 
 from benchmark import manifest
 from benchmark.readers import registry_delta
+from benchmark.tests.test_benchmark_entries import entry_for
 from benchmark.tests.test_rehearsal import _run
 from benchmark.tests.test_timeline_metrics import _registry_samples
 
@@ -21,22 +23,17 @@ SERVE_CELLS = ["serve_chat", "serve_prefill_heavy", "serve_moe_decode",
 
 
 def test_metric_file_and_entry():
-    spec = manifest.metric_file(NAME)
+    entry, spec = entry_for(NAME, *SERVE_CELLS)
     assert spec["reader"] == "registry_delta" and spec["doc"].strip()
     assert spec["args"] == {
         "num": ["ray_tpu_serve_stream_replica_items_total"],
         "den": ["ray_tpu_serve_stream_handoffs_total"]}
     assert set(spec["args"]["num"] + spec["args"]["den"]) \
         <= _registry_samples()
-    names = manifest.names(manifest.benchmark()["per_layer"])
-    # Appended: behind everything PR 40's benchmark had.
-    assert names.index(NAME) > names.index("admit_hold_share")
-    (entry,) = [m for m in manifest.benchmark()["per_layer"]
-                if m["name"] == NAME]
-    assert entry == {
+    assert dict(entry, workloads=None) == {
         "name": NAME, "unit": "ratio", "better": "higher",
         "source": "program_counter", "layer": "ingress and router",
-        "moves": "tokens_per_s", "workloads": SERVE_CELLS}
+        "moves": "tokens_per_s", "workloads": None}
 
 
 def test_reads_the_tokens_a_call_carried_and_the_parent_reads_nothing():
@@ -67,5 +64,5 @@ def test_rehearsal_of_a_serve_cell_reports_it():
     slots = manifest.cell("serve_linear_decode")["workload"][
         "rehearse"]["engine"]["num_slots"]
     assert 1 <= metrics[NAME]["value"] <= slots
-    for hop in ("stream_loop_ms.linear", "stream_items_per_pull.linear"):
+    for hop in ("stream_loop_ms", "stream_items_per_pull"):
         assert metrics[hop]["value"] > 0

@@ -12,6 +12,8 @@ import pytest
 from benchmark import flops_gdn, flops_window, manifest
 from benchmark.readers import gdn_roofline, kernel_time
 from benchmark.runners import serve_linear, serve_moe
+from benchmark.tests.test_benchmark_entries import (entry_for,
+                                                    listed_as_it_was)
 from benchmark.tests.test_window import _custom_call, _metric, _registry
 from ray_tpu.models import llama
 
@@ -32,16 +34,18 @@ PUBLISHED = {
     "rms_norm_eps": 1e-06, "rope_theta": 10000000,
     "shared_expert_intermediate_size": 512, "vocab_size": 151936}
 REDUCED = {"num_hidden_layers", "num_experts", "vocab_size"}
+# The accepted measurements the cell is listed for besides its own
+# (entries of their own, ``<name>.linear``, until PR 51 merged each into
+# the one entry of its reader and arguments).
 AGAIN = ("moe_gmm_time_share", "moe_local_assignment_share",
          "moe_experts_touched_share", "paged_attn_time_share",
-         "tick_wall_ms", "prefill_batch_ms", "prefill_chunk_ms",
+         "tick_wall_ms.closed_loop", "prefill_batch_ms", "prefill_chunk_ms",
          "slot_occupancy", "decode_stall_share", "device_starved_share",
          "tick_overlap_share", "ttft_p50_ms", "stream_loop_ms",
          "stream_items_per_pull")
-NEW = ("gdn_step_time_share", "gdn_step_roofline_share",
+OWN = ("gdn_step_time_share", "gdn_step_roofline_share",
        "state_cache_resident_share", "prefill_state_carry_share",
-       "moe_gmm_roofline_share.linear") + tuple(
-           name + ".linear" for name in AGAIN)
+       "moe_gmm_roofline_share.linear")
 
 
 def test_manifest_finds_the_cell_and_its_files():
@@ -50,45 +54,21 @@ def test_manifest_finds_the_cell_and_its_files():
         NAME, "context_decode", 1)
     assert cell["workload"]["runner"] == "serve_linear"
     listed = manifest.names(cell["per_layer"])
-    assert set(NEW) <= set(listed) and len(NEW) == 19
+    assert set(OWN + AGAIN) <= set(listed) and len(OWN + AGAIN) == 19
     # ... and the two every cell reports.
     assert {"mosaic_time_share", "compiles_in_window"} <= set(listed)
     assert manifest.names(cell["end_to_end"]) == ["tokens_per_s", "setup_s"]
-    entries = {m["name"]: m for m in manifest.benchmark()["per_layer"]}
-    for name in NEW:
-        assert entries[name]["workloads"] == [CELL]
-        assert entries[name]["moves"] == "tokens_per_s"
-        manifest.metric_file(name)
-    # The new entries are the LAST of their lists.
-    bench = manifest.benchmark()
-    assert bench["configs"][-1]["name"] == NAME
-    assert bench["workloads"][-1]["name"] == CELL
-    assert manifest.names(bench["per_layer"])[-len(NEW):] == [
-        "gdn_step_time_share", "gdn_step_roofline_share",
-        "state_cache_resident_share", "prefill_state_carry_share",
-        "moe_gmm_time_share.linear", "moe_gmm_roofline_share.linear",
-        "moe_local_assignment_share.linear",
-        "moe_experts_touched_share.linear", "paged_attn_time_share.linear",
-        "tick_wall_ms.linear", "prefill_batch_ms.linear",
-        "prefill_chunk_ms.linear", "slot_occupancy.linear",
-        "decode_stall_share.linear", "device_starved_share.linear",
-        "tick_overlap_share.linear", "ttft_p50_ms.linear",
-        "stream_loop_ms.linear", "stream_items_per_pull.linear"]
+    for name in OWN:
+        entry, _ = entry_for(name, CELL)
+        assert entry["workloads"] == [CELL]
+        assert entry["moves"] == "tokens_per_s"
     from benchmark.tests.test_rehearsal import CELLS
     assert CELL in CELLS
 
 
-@pytest.mark.parametrize("base", AGAIN)
-def test_a_layers_metric_listed_again_is_the_one_it_had(base):
-    name = base + ".linear"
-    spec, was = manifest.metric_file(name), manifest.metric_file(base)
-    assert (spec["reader"], spec.get("args")) == (was["reader"],
-                                                  was.get("args"))
-    assert spec["doc"].startswith(was["doc"])
-    entries = {m["name"]: m for m in manifest.benchmark()["per_layer"]}
-    assert all(entries[name][k] == entries[base][k]
-               for k in ("unit", "better", "source", "layer"))
-    assert CELL not in entries[base].get("workloads", [CELL + "?"])
+@pytest.mark.parametrize("name", AGAIN)
+def test_an_accepted_measurement_is_listed_for_the_cell(name):
+    listed_as_it_was(name, CELL)
 
 
 def test_file_keeps_every_published_number_but_the_reduced_ones():
@@ -213,9 +193,9 @@ def test_roofline_and_resident_share_on_a_synthetic_ctx():
            "engine": engine}
     assert _metric("gdn_step_time_share", ctx) == pytest.approx(
         100 * 20000 / 40000)
-    assert _metric("moe_gmm_time_share.linear", ctx) == pytest.approx(
+    assert _metric("moe_gmm_time_share", ctx) == pytest.approx(
         100 * 9000 / 40000)
-    assert _metric("paged_attn_time_share.linear", ctx) == pytest.approx(
+    assert _metric("paged_attn_time_share", ctx) == pytest.approx(
         100 * 3000 / 40000)
     # Counted over the 240 LIVE slots, though the kernel advances 256.
     least = flops_gdn.tick_step_seconds(QWEN, 240, V5E)

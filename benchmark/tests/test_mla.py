@@ -12,6 +12,8 @@ import pytest
 from benchmark import flops_mla, manifest, stats
 from benchmark.readers import kernel_time, mla_roofline
 from benchmark.runners import serve_mla, serve_moe
+from benchmark.tests.test_benchmark_entries import (entry_for,
+                                                    listed_as_it_was)
 from benchmark.tests.test_window import _custom_call, _metric, _registry
 from ray_tpu.models import llama
 
@@ -33,15 +35,17 @@ PUBLISHED = {
     "routed_scaling_factor": 2.827, "topk_group": 1, "v_head_dim": 128,
     "vocab_size": 163840}
 REDUCED = {"num_hidden_layers", "n_routed_experts", "vocab_size"}
-NEW = ("mla_attn_time_share", "mla_attn_roofline_share",
-       "latent_kv_resident_share", "moe_gmm_time_share.mla",
-       "moe_gmm_roofline_share.mla", "moe_local_assignment_share.mla",
-       "moe_experts_touched_share.mla", "tick_wall_ms.mla",
-       "prefill_batch_ms.mla", "prefill_chunk_ms.mla", "slot_occupancy.mla",
-       "ttft_p50_ms.mla", "decode_stall_share.mla",
-       "device_starved_share.mla", "prefill_row_fill_share.mla",
-       "tick_overlap_share.mla", "paged_visit_fill_share.mla",
-       "engine_queue_ms.mla")
+OWN = ("mla_attn_time_share", "mla_attn_roofline_share",
+       "latent_kv_resident_share", "moe_gmm_roofline_share.mla")
+# The accepted measurements the cell is listed for besides its own
+# (entries of their own, ``<name>.mla``, until PR 51 merged each into
+# the one entry of its reader and arguments).
+AGAIN = ("moe_gmm_time_share", "moe_local_assignment_share",
+         "moe_experts_touched_share", "tick_wall_ms.closed_loop",
+         "prefill_batch_ms", "prefill_chunk_ms", "slot_occupancy",
+         "ttft_p50_ms", "decode_stall_share", "device_starved_share",
+         "prefill_row_fill_share", "tick_overlap_share",
+         "paged_visit_fill_share", "engine_queue_ms")
 
 
 def test_manifest_finds_the_cell_and_its_files():
@@ -50,31 +54,21 @@ def test_manifest_finds_the_cell_and_its_files():
         "kimi-k2.7-code-l5-e12", "code_context_decode", 1)
     assert cell["workload"]["runner"] == "serve_mla"
     listed = manifest.names(cell["per_layer"])
-    assert set(NEW) <= set(listed)
+    assert set(OWN + AGAIN) <= set(listed) and len(OWN + AGAIN) == 18
     # ... and the two every cell reports.
     assert {"mosaic_time_share", "compiles_in_window"} <= set(listed)
     assert manifest.names(cell["end_to_end"]) == ["tokens_per_s", "setup_s"]
-    entries = {m["name"]: m for m in manifest.benchmark()["per_layer"]}
-    for name in NEW:
-        assert entries[name]["workloads"] == [CELL]
-        assert entries[name]["moves"] == "tokens_per_s"
-        manifest.metric_file(name)
+    for name in OWN:
+        entry, _ = entry_for(name, CELL)
+        assert entry["workloads"] == [CELL]
+        assert entry["moves"] == "tokens_per_s"
     from benchmark.tests.test_rehearsal import CELLS
     assert CELL in CELLS
 
 
-@pytest.mark.parametrize("name", [n for n in NEW if n.endswith(".mla")
-                                  and "roofline" not in n])
-def test_a_layers_metric_listed_again_is_the_one_it_had(name):
-    base = name[:-len(".mla")]
-    spec, was = manifest.metric_file(name), manifest.metric_file(base)
-    assert (spec["reader"], spec.get("args")) == (was["reader"],
-                                                  was.get("args"))
-    assert spec["doc"].startswith(was["doc"])
-    entries = {m["name"]: m for m in manifest.benchmark()["per_layer"]}
-    assert all(entries[name][k] == entries[base][k]
-               for k in ("unit", "better", "source", "layer"))
-    assert CELL not in entries[base].get("workloads", [CELL + "?"])
+@pytest.mark.parametrize("name", AGAIN)
+def test_an_accepted_measurement_is_listed_for_the_cell(name):
+    listed_as_it_was(name, CELL)
 
 
 def test_file_keeps_every_published_number_but_the_reduced_ones():
@@ -192,7 +186,7 @@ def test_roofline_and_resident_share_on_a_synthetic_ctx():
            "engine": engine}
     assert _metric("mla_attn_time_share", ctx) == pytest.approx(
         100 * 13000 / 24500)
-    assert _metric("moe_gmm_time_share.mla", ctx) == pytest.approx(
+    assert _metric("moe_gmm_time_share", ctx) == pytest.approx(
         100 * 3200 / 24500)
     least = flops_mla.tick_attn_seconds(KIMI, 670_000, 96, V5E)
     assert _metric("mla_attn_roofline_share", ctx) == pytest.approx(

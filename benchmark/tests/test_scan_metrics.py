@@ -12,6 +12,7 @@ import pytest
 from benchmark import flops_gdn, manifest
 from benchmark.readers import gdn_scan_roofline
 from benchmark.runners import serve_moe
+from benchmark.tests.test_benchmark_entries import entry_for
 from benchmark.tests.test_window import _custom_call, _metric, _registry
 
 CELL = "serve_linear_decode"
@@ -24,13 +25,11 @@ V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 @pytest.mark.parametrize("name", sorted(NEW))
 def test_metric_file_loads_and_is_listed_for_the_cell(name):
-    spec = manifest.metric_file(name)
+    entry, spec = entry_for(name, CELL)
     assert spec["reader"] == NEW[name][0] and spec["doc"].strip()
     reader = importlib.import_module("benchmark.readers." + spec["reader"])
     assert callable(reader.read)
     assert spec["args"]["kernel"] == "gdn_chunk_scan"
-    (entry,) = [m for m in manifest.benchmark()["per_layer"]
-                if m["name"] == name]
     assert entry == {"name": name, "unit": "%", "better": NEW[name][1],
                      "source": "device_trace",
                      "layer": "linear-attention mixer",

@@ -1,14 +1,15 @@
 """The per-layer metrics that read the engine's timeline and the
 streams' hops (PR 34): each is a file over the accepted reader
 ``registry_delta``, names samples the program's registry defines, is
-listed for the five serve cells, and reads a number in their
-rehearsals."""
+listed for the five serve cells of PR 34 (and whichever later PRs
+append), and reads a number in their rehearsals."""
 
 import json
 
 import pytest
 
 from benchmark import manifest
+from benchmark.tests.test_benchmark_entries import entry_for
 from benchmark.tests.test_rehearsal import _run
 
 NEW = ["device_starved_share", "starved_after_prefill_ms",
@@ -35,15 +36,12 @@ def _registry_samples():
 
 @pytest.mark.parametrize("name", NEW)
 def test_metric_file_loads_and_names_samples_the_registry_defines(name):
-    spec = manifest.metric_file(name)
+    entry, spec = entry_for(name, *SERVE_CELLS)
     assert spec["reader"] == "registry_delta" and spec["doc"].strip()
     args = spec["args"]
     assert set(args) <= {"num", "den", "scale"}
     assert args["num"] and args["den"]
     assert set(args["num"]) | set(args["den"]) <= _registry_samples()
-    (entry,) = [m for m in manifest.benchmark()["per_layer"]
-                if m["name"] == name]
-    assert entry["workloads"] == SERVE_CELLS
     assert entry["source"] == "program_counter"
     assert entry["moves"] == "tokens_per_s"
 

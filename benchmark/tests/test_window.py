@@ -12,6 +12,8 @@ from benchmark import flops_window, manifest
 from benchmark.readers import (kernel_time, kv_resident, registry_delta,
                                window_roofline)
 from benchmark.runners import serve_moe, serve_window
+from benchmark.tests.test_benchmark_entries import (entry_for,
+                                                    listed_as_it_was)
 from ray_tpu.models import llama
 
 TRINITY = manifest.load_json(
@@ -174,9 +176,9 @@ def test_kernel_metrics_read_through_their_files():
     ctx = {"trace": trace, "config": TRINITY, "registry_before": before,
            "registry_after": after, "device": {"kind": "TPU v5 lite"},
            "engine": {"num_slots": 48, "max_len": 7168, "block_size": 64}}
-    assert _metric("paged_attn_time_share.window", ctx) == pytest.approx(
+    assert _metric("paged_attn_time_share", ctx) == pytest.approx(
         100 * 11000 / 24500)
-    assert _metric("moe_gmm_time_share.window", ctx) == pytest.approx(
+    assert _metric("moe_gmm_time_share", ctx) == pytest.approx(
         100 * 3200 / 24500)
     full = 48 * 86 * 64
     window = min(48 * 65 * 64, 48 * 4096)
@@ -203,37 +205,29 @@ def test_kernel_metrics_read_through_their_files():
                             stat="time_share") is None
 
 
-LISTED_AGAIN = {
-    "window": ("serve_window_decode", (
-        "moe_experts_touched_share", "moe_load_imbalance",
-        "paged_live_block_share", "tick_overlap_share", "prefill_batch_ms",
-        "slot_occupancy", "engine_queue_ms", "tick_wall_ms",
-        "tick_thread_host_share", "ttft_p50_ms", "itl_p99_ms")),
-}
+# The accepted measurements the cell is listed for besides its own
+# (entries of their own, ``<name>.window``, until PR 51 merged each into
+# the one entry of its reader and arguments). ``itl_p99_ms.window`` is
+# still its own entry: ``itl_p99_ms`` moves ``itl_p50_ms``, which
+# ``serve_chat`` alone reports.
+LISTED_AGAIN = ("moe_experts_touched_share", "moe_load_imbalance",
+                "paged_live_block_share", "tick_overlap_share",
+                "prefill_batch_ms", "slot_occupancy", "engine_queue_ms",
+                "tick_wall_ms.closed_loop", "tick_thread_host_share",
+                "ttft_p50_ms", "paged_attn_time_share",
+                "moe_gmm_time_share")
 
 
-@pytest.mark.parametrize("suffix,name", [
-    (suffix, name) for suffix, (_, names) in LISTED_AGAIN.items()
-    for name in names])
-def test_a_layers_metric_listed_for_a_new_cell_is_the_one_it_had(suffix,
-                                                                 name):
-    """``<metric>.<cell>``: the accepted metric's reader and arguments
-    under a file and an entry of its own (an accepted entry may not be
-    edited), listed for the one new cell, same layer, unit and sense."""
-    cell = LISTED_AGAIN[suffix][0]
-    spec = manifest.metric_file(f"{name}.{suffix}")
-    base = manifest.metric_file(name)
-    assert (spec["reader"], spec.get("args")) == (base["reader"],
-                                                  base.get("args"))
-    assert spec["doc"].startswith(base["doc"])
-    entries = {m["name"]: m for m in manifest.benchmark()["per_layer"]}
-    mine, theirs = entries[f"{name}.{suffix}"], entries[name]
-    assert mine["workloads"] == [cell] and cell not in theirs["workloads"]
-    assert mine["moves"] == "tokens_per_s"
-    assert all(mine[k] == theirs[k]
-               for k in ("unit", "better", "source", "layer"))
-    assert f"{name}.{suffix}" in manifest.names(
-        manifest.cell(cell)["per_layer"])
+@pytest.mark.parametrize("name", LISTED_AGAIN)
+def test_an_accepted_measurement_is_listed_for_the_cell(name):
+    listed_as_it_was(name, "serve_window_decode")
+
+
+def test_itl_p99_of_the_window_cell_is_the_chat_cells_reading():
+    mine, spec = entry_for("itl_p99_ms.window", "serve_window_decode")
+    theirs, base = entry_for("itl_p99_ms", "serve_chat")
+    assert (spec["reader"], spec["args"]) == (base["reader"], base["args"])
+    assert (mine["moves"], theirs["moves"]) == ("tokens_per_s", "itl_p50_ms")
 
 
 def test_itl_p50_of_the_window_cell_reads_the_clients_gaps():
@@ -288,15 +282,15 @@ def test_the_configuration_states_its_two_limits():
 
 
 def test_new_per_layer_entries_name_their_cells():
-    entries = {m["name"]: m for m in manifest.benchmark()["per_layer"]}
     for name in ("window_live_block_share", "window_kv_resident_share",
-                 "moe_local_assignment_share", "paged_attn_time_share.window",
-                 "moe_gmm_time_share.window",
                  "paged_attn_roofline_share.window",
                  "moe_gmm_roofline_share.window"):
-        assert entries[name]["workloads"] == ["serve_window_decode"]
-        assert entries[name]["moves"] == "tokens_per_s"
-    assert entries["prefill_chunk_ms"]["workloads"] == ["serve_window_decode"]
+        entry, _ = entry_for(name, "serve_window_decode")
+        assert entry["workloads"] == ["serve_window_decode"]
+        assert entry["moves"] == "tokens_per_s"
+    for name in ("moe_local_assignment_share", "prefill_chunk_ms"):
+        entry, _ = entry_for(name, "serve_window_decode")
+        assert entry["moves"] == "tokens_per_s"
     cells = {w["name"]: w for w in manifest.benchmark()["workloads"]}
     assert cells["serve_window_decode"]["chips"] == 1
 
