@@ -60,6 +60,15 @@ seed), and checks what comes out by the repo's own means:
                 configuration file's limit; the program as published
                 passes, and five faults in the program and three in the
                 reference are read against it;
+* ``loop``      the cell ``serve_loop_decode``'s comparison at its own
+                sizes (Ouro-2.6B WHOLE at the published widths, 48
+                layers applied 4 times, through ``ContinuousBatcher``
+                with the cell's 8 slots and 89 blocks): the cell's
+                check prompts, chosen tokens against the float32
+                reference under the configuration file's limit, and
+                the exit gate after each step against the reference's;
+                the program as published passes (two sets of prompts),
+                float8 weights and six faults are read against it;
 * ``train``     ``ShardedTrainer`` on one device, batch 5 x 2048: loss
                 finite and falling, one compiled signature, the Mosaic
                 custom calls present in the compiled step;
@@ -97,18 +106,18 @@ import threading
 import time
 
 PHASES = ("kernels", "moe", "hybrid", "window", "mla", "linear", "eva",
-          "cca", "train", "serve", "multichip")
+          "cca", "loop", "train", "serve", "multichip")
 # The multichip phase is two children: the trainer's state must be gone
 # from the chips before four serving replicas load theirs.
 CHILDREN = {"kernels": ("kernels",), "moe": ("moe",),
             "hybrid": ("hybrid",), "window": ("window",), "mla": ("mla",),
             "linear": ("linear",), "eva": ("eva",), "cca": ("cca",),
-            "train": ("train",),
+            "loop": ("loop",), "train": ("train",),
             "serve": ("serve",),
             "multichip": ("multichip-train", "multichip-serve")}
 PHASE_TIMEOUT_S = {"kernels": 1200, "moe": 600, "hybrid": 1500,
                    "window": 2700, "mla": 2700, "linear": 3300, "eva": 3300,
-                   "cca": 3300,
+                   "cca": 3300, "loop": 3300,
                    "train": 480,
                    "serve": 600,
                    "multichip-train": 900, "multichip-serve": 900}
@@ -2194,6 +2203,200 @@ def phase_cca(rehearse: bool) -> None:
     assert not wrong, f"{wrong}: {results} against {tolerance}"
 
 
+def phase_loop(rehearse: bool) -> None:
+    """The cell ``serve_loop_decode``'s comparison with its reference,
+    and the faults it has to catch, AT THE CELL'S OWN SIZES: Ouro-2.6B
+    whole (48 layers at the published widths, applied four times) in the
+    engine the served path builds, with the cell's slots and blocks, so
+    the programs are the timed path's at the timed sizes; the cell's
+    check prompts and answer length, one request after another (a
+    prefill, then 31 ticks), greedy; held to
+    ``benchmark/reference_ouro.py`` by the runner's own
+    ``hold_to_reference`` under the configuration file's ONE limit.
+
+    First the program as published on two sets of prompts, which has to
+    pass; then every weight rounded to ``float8_e4m3`` and the six faults
+    of ``tests/test_ouro.py``, one at a time, each of which should FAIL
+    the limit (one that does not is printed, and PERF.md section 7 keeps
+    it). Beside the chosen tokens' gap, the EXIT GATE the tick fetches
+    after each step is held to the reference's at the same position:
+    what 48, 96, 144 and 192 layer applications in bf16 have drifted."""
+    phase = "loop"
+    info = _open_device(phase, rehearse)
+    import dataclasses
+    import gc
+    import itertools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import manifest, reference_ouro
+    from benchmark.runners import serve_loop
+    from benchmark.runners.serve import _prompts
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models import llama, looped
+
+    cell = manifest.cell("serve_loop_decode")
+    if rehearse:
+        cell = manifest.rehearsal(cell)
+    work, tolerance = cell["workload"], cell["config"]["tolerance"]
+    config = serve_loop.ouro_config(cell["config"],
+                                    max_seq_len=work["engine"]["max_len"])
+    layers, steps = config.num_layers, config.loop_steps
+    sets = [_prompts(np.random.default_rng(seed), config.vocab_size,
+                     work["check"]["prompt_tokens"],
+                     work["check"]["max_tokens"])
+            for seed in ((53,) if rehearse else (53, 54))]
+
+    def answers(config, sets, weights=None):
+        """Each request alone through the engine: its tokens, and the
+        gates ``[T, ticks]`` its ticks fetched."""
+        eng = cb.ContinuousBatcher(config, **work["engine"])
+        if weights is not None:
+            eng.params = weights(eng.params)
+        seen = []
+        book = eng._account_tick
+
+        def spy(tick_fn, tick, k):
+            slot = tick["members"][0][0]
+            seen.append(np.asarray(tick["row"][eng.num_slots:]).view(
+                np.float32).reshape(-1, eng.num_slots)[:, slot])
+            book(tick_fn, tick, k)
+
+        eng._account_tick = spy
+        out = []
+        for reqs in sets:
+            recs = []
+            for req in reqs:
+                del seen[:]
+                rid = eng.submit(req["prompt"], req["max_tokens"])
+                tokens = eng.run_to_completion()[rid]
+                recs.append({"tokens": tokens, "gates": np.stack(
+                    seen[:len(tokens) - 1], axis=1)})
+            out.append(list(zip(reqs, recs)))
+        # Weights and arena fill the chip: nothing of this engine may
+        # outlive it (the monitor's records keep a program's buffers).
+        del eng
+        gc.collect()
+        for array in jax.live_arrays():
+            array.delete()
+        return out
+
+    real = {(mod, name): getattr(mod, name) for mod, name in (
+        (looped, "step_end"), (jax.lax, "scan"),
+        (cb, "_write_then_attend"), (cb, "_layer_finish"))}
+    # A program calls ``step_end`` and scans the stack once a step, in
+    # order: the faults that need the step count their calls.
+    ends, scans = itertools.count(1), itertools.count()
+
+    def rows_of_step_0(arenas, li, *rest):
+        return real[cb, "_write_then_attend"](arenas, li % layers, *rest)
+
+    def reads_the_step_before(arenas, li, q, k, v, block_idx, offset, tables,
+                              positions, visits, scale, use_kernel):
+        # The write as it is (its own attention is dead code), then the
+        # kernel alone over the row a step back: a second write-then-attend
+        # whose arena is thrown away would copy 9 GB.
+        _, arenas = real[cb, "_write_then_attend"](
+            arenas, li, q, k, v, block_idx, offset, tables, positions,
+            visits, scale, use_kernel)
+        o = cb.paged_decode_attention(
+            q[:, 0], arenas[0], arenas[1], tables, positions[:, 0], scale,
+            layer=jnp.where(li >= layers, li - layers, li),
+            visits=visits and visits[0], use_kernel=use_kernel)
+        return o[:, None], arenas
+
+    def no_norm_between(x, params, c):
+        return (x if next(ends) % c.loop_steps
+                else real[looped, "step_end"](x, params, c))
+
+    def no_output_norms(x, mixed, layer, c, *rest):
+        return real[cb, "_layer_finish"](
+            x, mixed, layer, dataclasses.replace(c, sandwich_norms=False),
+            *rest)
+
+    def norms_of_another_layer(f, init, xs=None, **kw):
+        # Four sets of weights do not fit beside the arena (21 GB): a
+        # step re-indexes the four NORM vectors a layer, l -> l + step.
+        if isinstance(xs, dict) and "attn_norm" in xs:
+            step = next(scans) % steps
+            xs = {name: (jnp.roll(a, -step, axis=0)
+                         if name.endswith("norm") else a)
+                  for name, a in xs.items()}
+        return real[jax.lax, "scan"](f, init, xs, **kw)
+
+    def float8(tree):
+        # Two programs a leaf, not one jitted round trip: XLA folds a
+        # narrowing conversion and its inverse away (excess precision).
+        def rounded(a):
+            if a.dtype != jnp.bfloat16:
+                return a
+            out = a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+            a.delete()      # the chip has no room for two trees
+            return out
+
+        return jax.tree.map(rounded, tree)
+
+    cases = [
+        ("as published", config, None, {}),
+        ("weights rounded to float8_e4m3", config, float8, {}),
+        ("(i) every tick reads and writes step 0's rows", config, None,
+         {(cb, "_write_then_attend"): rows_of_step_0}),
+        ("(ii) step t reads step t - 1's rows", config, None,
+         {(cb, "_write_then_attend"): reads_the_step_before}),
+        ("(iii) no final norm between steps", config, None,
+         {(looped, "step_end"): no_norm_between}),
+        ("(iv) three steps for four",
+         dataclasses.replace(config, loop_steps=steps - 1), None, {}),
+        ("(v) the two output norms dropped", config, None,
+         {(cb, "_layer_finish"): no_output_norms}),
+        ("(vi) another layer's norms a step", config, None,
+         {(jax.lax, "scan"): norms_of_another_layer}),
+    ]
+    if rehearse:            # tiny sizes prove nothing about the faults
+        cases = cases[:3]
+    answered = {}
+    for name, run_config, weights, patch in cases:
+        for (mod, attr), fn in patch.items():
+            setattr(mod, attr, fn)
+        try:
+            answered[name] = answers(
+                run_config, sets if name == "as published" else sets[:1],
+                weights)
+        finally:
+            for (mod, attr), fn in real.items():
+                setattr(mod, attr, fn)
+        _say(phase, f"{name}: {len(answered[name])} x {len(sets[0])} check "
+                    f"requests answered")
+    _print_memory(phase)
+    params = jax.jit(lambda k: llama.init_params(config, k))(
+        jax.random.PRNGKey(0))
+    results = {}
+    for name, checked in answered.items():
+        _say(phase, name)
+        results[name] = [serve_loop.hold_to_reference(
+            params, config, checks, tolerance) for checks in checked]
+        # The gates: step t's over every tick of every check request.
+        drift = np.zeros(steps)
+        for req, rec in checked[0]:
+            want = np.asarray(reference_ouro.gaps(
+                params, req["prompt"], rec["tokens"], config)[1])[:, 1:]
+            got = rec["gates"]
+            drift[:len(got)] += np.abs(got - want[:len(got)]).mean(axis=1)
+        results[name][0]["gate_gap_by_step"] = [
+            float(d) / len(checked[0]) for d in drift]
+        _say(phase, f"    mean |gate - reference's| after step 0..{steps - 1}"
+                    f": {results[name][0]['gate_gap_by_step']}")
+    _finish(phase, info, faults=results, tolerance=tolerance)
+    sound = results["as published"]
+    assert all(r["ok"] for r in sound), f"as published: {sound}"
+    unseen = [name for name, rs in results.items()
+              if name != "as published" and any(r["ok"] for r in rs)]
+    if unseen:
+        _say(phase, f"INSIDE the limit, so not caught: {unseen}")
+
+
 def _train_once(phase, config, mesh, batch_size, seq_len, steps, rehearse):
     """Init + ``steps`` steps on one repeated batch. Returns (losses,
     trainer, state)."""
@@ -2601,7 +2804,7 @@ def _child(phase: str, rehearse: bool) -> int:
 CHILD_FNS = {"kernels": phase_kernels, "moe": phase_moe,
              "hybrid": phase_hybrid, "window": phase_window,
              "mla": phase_mla, "linear": phase_linear, "eva": phase_eva,
-             "cca": phase_cca, "train": phase_train,
+             "cca": phase_cca, "loop": phase_loop, "train": phase_train,
              "serve": phase_serve, "multichip-train": phase_multichip_train,
              "multichip-serve": phase_multichip_serve}
 

@@ -718,6 +718,25 @@ CB_PREFILL_STATE_CARRIES = Counter(
     "state cache: every chunk of a linear-attention model's prompt but "
     "its first (beside ray_tpu_cb_state_installs_total, the prompts)",
     ("engine",))
+CB_LOOP_ROWS = Counter(
+    "ray_tpu_cb_loop_rows_total",
+    "Live rows of the decode ticks of a model with a looped layer stack "
+    "(loop_steps > 1; models/looped.py): one a decoded token, the overrun "
+    "row of a request that had just ended included. No series exists for "
+    "a model without a loop",
+    ("engine",))
+CB_LOOP_STEPS = Counter(
+    "ray_tpu_cb_loop_steps_total",
+    "Passes of the layer stack the decode ticks of a looped model ran, "
+    "summed over their live rows: rows x steps, the steps read off the "
+    "gates the tick's row carries (beside ray_tpu_cb_loop_rows_total: "
+    "their ratio is loop_steps, or the program left work out)",
+    ("engine",))
+CB_LOOP_KV_BYTES = Gauge(
+    "ray_tpu_cb_loop_kv_bytes",
+    "Bytes of a looped model's K/V arena, loop_steps x num_layers rows a "
+    "token: fixed at construction",
+    ("engine",))
 CB_EVA_WINDOWS_CLOSED = Counter(
     "ray_tpu_cb_eva_windows_closed_total",
     "Windows of an EVA-attention model's contexts that filled and were "

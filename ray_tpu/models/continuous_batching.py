@@ -411,16 +411,16 @@ _KIND_CANNOT = {
 
 
 def _refuse_unsupported(config, asked: Dict[str, str]) -> None:
-    """Raise for the first capability in ``asked`` ({capability: what
-    the caller called it}) that a layer kind of ``config`` cannot have
-    (:data:`_KIND_CANNOT`)."""
+    """Raise for the first of ``asked`` ({capability: the caller's name})
+    that ``config`` cannot have: :data:`_KIND_CANNOT`, ``LOOP_CANNOT``."""
+    if config.loop_steps > 1:
+        from ray_tpu.models import looped
+        looped.refuse(asked)
     kinds = set(config.layer_types)
     for kind, cannot in _KIND_CANNOT.items():
         if kind not in kinds:
             continue
-        # The arena's own layers sit beside any cache but the latent
-        # one, which takes the arena's place.
-        beside = kinds - {kind}
+        beside = kinds - {kind}     # (the arena's own layers sit beside any)
         if kind not in ("latent_attention", "eva_attention",
                         "cca_attention"):
             beside -= {"attention", "full_attention"}
@@ -1744,12 +1744,12 @@ class ContinuousBatcher:
         self._tick = tick
         self._merge_tokens = merge_tokens
 
+        if cfg.loop_steps > 1:      # the two programs above, looped
+            from ray_tpu.models import looped
+            looped.install(self)
         if self.spec_k and self.drafter.external:
-            # The external drafter keeps its own dense per-slot cache;
-            # admission prefills the FULL prompt into it (the target's
-            # prefix cache shortens only the target's prefill), decode
-            # advances it inside the spec tick. No sampling: first
-            # tokens come from the target's prefill.
+            # The drafter's own dense cache: admission prefills the FULL
+            # prompt into it, decode advances it inside the spec tick.
             dcfg = self.drafter.config
             self._draft_cache = self._new_draft_cache()
 

@@ -71,7 +71,7 @@ class ExternalLlamaDrafter:
 
     def __init__(self, config: llama.LlamaConfig, params=None,
                  seed: int = 0):
-        self.config = config
+        self.config = _refuse_looped(config, "ExternalLlamaDrafter")
         self.params = params if params is not None else llama.init_params(
             config, jax.random.PRNGKey(seed))
 
@@ -172,7 +172,7 @@ class LlamaGenerator:
 
     def __init__(self, config: llama.LlamaConfig, params=None,
                  max_len: int = 512, seed: int = 0):
-        self.config = config
+        self.config = _refuse_looped(config, "LlamaGenerator")
         self.max_len = max_len
         self.params = params if params is not None else llama.init_params(
             config, jax.random.PRNGKey(seed))
@@ -221,3 +221,14 @@ class LlamaGenerator:
             last, cache = self._decode(self.params, nxt, cache, pos)
             pos += 1
         return jnp.stack(out, axis=1)
+
+
+def _refuse_looped(config: llama.LlamaConfig, service: str):
+    """``config``, unless ``service`` (which runs the layer stack once)
+    is handed a looped stack (``loop_steps > 1``): then it raises, naming
+    itself (``models/looped.py``)."""
+    if config.loop_steps > 1:
+        from ray_tpu.models import looped
+
+        looped.refuse_service(config, service)
+    return config

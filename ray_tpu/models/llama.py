@@ -203,6 +203,14 @@ class LlamaConfig:
     # vectors a sublayer (``res_attn``, ``res_mlp`` ``[4, E]``, float32).
     router_hidden_size: int = 0
     residual_scaling: bool = False
+    # The Ouro family (``model_type`` "ouro"; SERVING ONLY): the whole
+    # stack, a LLaMA block with ``sandwich_norms`` and no ``layer_types``,
+    # is applied ``loop_steps`` times with the SAME weights, the final
+    # norm between the steps and one exit-gate scalar a token a step
+    # (``exit_gate_w [E]``, ``exit_gate_b`` in the tree). A token keeps
+    # K/V for every (step, layer) pair, ``loop_steps x attn_layers`` rows
+    # of the arena: ``models/looped.py``. 1 = no loop, every other model.
+    loop_steps: int = 1
 
     @property
     def rotary_dim(self) -> int:
@@ -411,6 +419,18 @@ class LlamaConfig:
             residual_scaling=True, tie_word_embeddings=True), **kw})
 
     @staticmethod
+    def ouro_2_6b(**kw) -> "LlamaConfig":
+        """ByteDance/Ouro-2.6B (``ouro``, a looped language model): 48
+        LLaMA layers of hidden 2048, MHA 16/16 of 128, SwiGLU 5632, four
+        norms a layer, applied 4 times with shared weights, the final
+        norm between the steps; untied 49k vocabulary."""
+        return LlamaConfig(**{**dict(
+            vocab_size=49152, hidden_size=2048, intermediate_size=5632,
+            num_layers=48, num_heads=16, num_kv_heads=16, head_dim=128,
+            max_seq_len=65536, rope_theta=1e6, rms_eps=1e-6,
+            sandwich_norms=True, loop_steps=4), **kw})
+
+    @staticmethod
     def tiny(**kw) -> "LlamaConfig":
         """CPU-runnable config for tests (BASELINE.md config #1 analog)."""
         kw.setdefault("vocab_size", 256)
@@ -470,6 +490,8 @@ def logical_axes(config: LlamaConfig) -> Params:
 def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     """Random init (normal / scaled), stacked over layers for lax.scan."""
     c = config
+    if c.loop_steps > 1:
+        return _init_looped_params(c, key)
     if c.layer_types:
         return (_init_hybrid_params(c, key) if "mamba" in c.layer_types
                 else _init_windowed_params(c, key))
@@ -733,28 +755,6 @@ def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
                          "moe_up": dense(ke[1], E, Lm, Xh, E, M),
                          "moe_down": dense(ke[2], M, Lm, Xh, M, E)}
     return out
-
-
-def truncated(config: LlamaConfig, params: Params,
-              num_layers: int) -> Tuple[LlamaConfig, Params]:
-    """First-``num_layers`` view of a model: (config, params) where the
-    layer stack is sliced to the leading ``num_layers`` and the embedding,
-    final norm, and lm_head are shared (same arrays, zero copies).
-
-    This is the speculative-decode self-drafter (EAGLE/Medusa-style
-    truncated-depth draft): because the sliced stack computes bitwise the
-    SAME layer-0..n-1 activations and K/V as the full model, the drafter
-    can read and write the target's own paged KV arena for those layers —
-    no second checkpoint, no separate draft arena."""
-    if not 1 <= num_layers <= config.num_layers:
-        raise ValueError(
-            f"truncated depth must be in [1, {config.num_layers}], "
-            f"got {num_layers}")
-    cfg = dataclasses.replace(config, num_layers=num_layers)
-    sliced = dict(params)
-    sliced["layers"] = jax.tree.map(lambda a: a[:num_layers],
-                                    params["layers"])
-    return cfg, sliced
 
 
 EXPERT_KEYS = ("moe_gate", "moe_up", "moe_down")
@@ -1109,12 +1109,12 @@ def forward(
     otherwise just the logits array.
     """
     c = config
-    if c.layer_types:
+    if c.layer_types or c.loop_steps > 1:
         raise NotImplementedError(
             "a config with layer_types (state-space, linear-attention, "
             "sliding-window, latent-attention, eva-attention or "
-            "cca-attention layers) is served by the continuous-batching "
-            "engine only: "
+            "cca-attention layers) or with loop_steps > 1 (a looped stack) "
+            "is served by the continuous-batching engine only: "
             "llama.forward, loss_fn and LlamaGenerator do not run it")
     seq_len = tokens.shape[1]
     cos, sin = rope_frequencies(c.head_dim, seq_len, c.rope_theta)
@@ -1326,7 +1326,7 @@ def num_params(config: LlamaConfig) -> int:
                 + c.num_layers * common + c.attn_layers * attention
                 + c.state_layers * mamba)
     per_layer = (
-        2 * c.hidden_size
+        (4 if c.sandwich_norms else 2) * c.hidden_size
         + c.hidden_size * c.num_heads * c.head_dim * 2
         + c.hidden_size * c.num_kv_heads * c.head_dim * 2
         + 3 * c.hidden_size * c.intermediate_size * max(c.num_experts, 1)
@@ -1337,4 +1337,58 @@ def num_params(config: LlamaConfig) -> int:
         c.vocab_size * c.hidden_size * 2
         + c.hidden_size
         + c.num_layers * per_layer
+        + (c.loop_steps > 1) * (c.hidden_size + 1)      # the exit gate
     )
+
+
+def truncated(config: LlamaConfig, params: Params,
+              num_layers: int) -> Tuple[LlamaConfig, Params]:
+    """First-``num_layers`` view of a model: (config, params) where the
+    layer stack is sliced to the leading ``num_layers`` and the embedding,
+    final norm, and lm_head are shared (same arrays, zero copies).
+
+    This is the speculative-decode self-drafter (EAGLE/Medusa-style
+    truncated-depth draft): because the sliced stack computes bitwise the
+    SAME layer-0..n-1 activations and K/V as the full model, the drafter
+    can read and write the target's own paged KV arena for those layers —
+    no second checkpoint, no separate draft arena."""
+    if not 1 <= num_layers <= config.num_layers:
+        raise ValueError(
+            f"truncated depth must be in [1, {config.num_layers}], "
+            f"got {num_layers}")
+    cfg = dataclasses.replace(config, num_layers=num_layers)
+    sliced = dict(params)
+    sliced["layers"] = jax.tree.map(lambda a: a[:num_layers],
+                                    params["layers"])
+    return cfg, sliced
+
+
+def _init_looped_params(c: LlamaConfig, key: jax.Array) -> Params:
+    """The Ouro family's tree (``loop_steps > 1``): :func:`init_params`'
+    homogeneous tree, ``layers`` stacked ``[L, ...]`` ONCE however many
+    times the stack is applied, with the two output norms a layer
+    (``post_attn_norm``, ``post_mlp_norm``) and, at the top, the exit
+    gate ``exit_gate_w [E]`` (normal at ``E ** -0.5``) and ``exit_gate_b``
+    (0). Seeded so that dropping a term shows: the four norms a layer and
+    the final norm, which stands between the steps too, are uniform in
+    0.5..1.5, not ones."""
+    if c.layer_types or c.num_experts or not c.sandwich_norms:
+        raise ValueError(
+            "loop_steps > 1 is the Ouro family's: a dense stack with "
+            "sandwich_norms and no layer_types")
+    out = init_params(dataclasses.replace(c, loop_steps=1), key)
+    L, E = c.num_layers, c.hidden_size
+    k = jax.random.split(jax.random.fold_in(key, 0x100b), 6)
+
+    def norm(key, *shape):
+        return jax.random.uniform(key, shape, jnp.float32, 0.5,
+                                  1.5).astype(c.dtype)
+
+    out["layers"].update(
+        attn_norm=norm(k[0], L, E), post_attn_norm=norm(k[1], L, E),
+        mlp_norm=norm(k[2], L, E), post_mlp_norm=norm(k[3], L, E))
+    out["final_norm"] = norm(k[4], E)
+    out["exit_gate_w"] = (jax.random.normal(k[5], (E,), jnp.float32)
+                          * E ** -0.5).astype(c.dtype)
+    out["exit_gate_b"] = jnp.zeros((), jnp.float32)
+    return out

@@ -103,9 +103,9 @@ class PagedKVCache(NamedTuple):
     def create(cls, config: llama.LlamaConfig, num_blocks: int,
                block_size: int, kv_dtype: str = "bf16") -> "PagedKVCache":
         kv_dtype = resolve_kv_dtype(kv_dtype)
-        # Attention layers only: a state-space layer keeps no K/V.
-        shape = (config.attn_layers, num_blocks, config.num_kv_heads,
-                 block_size, config.head_dim)
+        # Attention layers only, times the steps of a looped stack.
+        shape = (config.loop_steps * config.attn_layers, num_blocks,
+                 config.num_kv_heads, block_size, config.head_dim)
         if kv_dtype == "int8":
             return cls(k=jnp.zeros(shape, jnp.int8),
                        v=jnp.zeros(shape, jnp.int8),
