@@ -975,11 +975,20 @@ def phase_kernels(rehearse: bool) -> None:
         # A tick's write (one token a slot) and a verify window's (4
         # tokens that straddle a block boundary in every third slot;
         # every fourth slot is freed and aims at the garbage block).
+        # The kernel moves the one 16-row (bf16) or 32-row (int8) tile
+        # a token lands in: a start in each tile of a block, and two
+        # windows across a tile boundary INSIDE a block (rows 14-17;
+        # rows 30-33, which is a boundary of both tilings).
         write = jax.jit(paged_kv_write, donate_argnums=(0,))
+        tiles = [bs + 16 * t + 5 for t in range(bs // 16)] \
+            + [bs + 14, 2 * bs + 30]
         for width in (1, 4):
             start = jnp.where(jnp.arange(slots) % 3 == 0, bs - 2,
                               positions % (s_max - width))
             start = start.at[0].set(0).at[1].set(s_max - width)
+            for i, at in zip([i for i in range(2, slots) if i % 4 != 3],
+                             tiles):
+                start = start.at[i].set(at)
             wpos = start[:, None] + jnp.arange(width)[None, :]
             blk = jnp.take_along_axis(tables, wpos // bs, axis=1)
             blk = jnp.where((jnp.arange(slots) % 4 == 3)[:, None], 0, blk)

@@ -596,22 +596,41 @@ def _paged_fused(q, arena_k, arena_v, tables, positions, visits, *, layer,
 # ---------------------------------------------------------------------------
 
 def _write_kernel(layer_ref, blk_ref, off_ref, new_ref, arena_ref, out_ref,
-                  *, window):
-    """Merge this slot's window rows into one arena block. Grid step
-    ``t`` of slot ``b`` holds the block that window token ``t * (S-1)``
-    lands in; every window token aimed at that block replaces its row,
-    all other bytes go back as read. The select is exact: bf16 rides
-    through fp32 and int8 through int32, the row test is on int32."""
+                  *, window, rows):
+    """Merge this slot's window rows into one ``rows``-row tile of an
+    arena block. Grid step ``t`` of slot ``b`` holds the tile that window
+    token ``t * (S-1)`` lands in; every window token aimed at that tile
+    of that block replaces its row, all other bytes go back as read. The
+    select is exact: bf16 rides through fp32 and int8 through int32, the
+    row test is on int32."""
     base = pl.program_id(0) * window
-    target = blk_ref[base + pl.program_id(1) * (window - 1)]
-    blk = arena_ref[0, 0]                            # [KVH, bs, ...]
+    held = base + pl.program_id(1) * (window - 1)
+    target = blk_ref[held]
+    first = jax.lax.div(off_ref[held], rows) * rows  # the tile's row 0
+    blk = arena_ref[0, 0]                            # [KVH, rows, ...]
     wide = jnp.int32 if blk.dtype == jnp.int8 else jnp.float32
-    rows = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
     merged = blk.astype(wide)
     for j in range(window):   # static: 1 for a tick, k+1 for verify
-        hit = (rows == off_ref[base + j]) & (blk_ref[base + j] == target)
+        # A row of another tile is no row of this one: below 0 or past it.
+        hit = ((row == off_ref[base + j] - first)
+               & (blk_ref[base + j] == target))
         merged = jnp.where(hit, new_ref[0, j].astype(wide), merged)
     out_ref[0, 0] = merged.astype(out_ref.dtype)
+
+
+def _write_rows(arena, s: int) -> int:
+    """Rows of a block one grid step of :func:`paged_kv_write` moves:
+    the arena dtype's native sublane tile (16 rows of bf16, 32 of int8)
+    when rows are the second-minor axis, a block is a whole number of
+    tiles and more than one, and a window of ``s`` tokens spans at most
+    two of them; the whole block otherwise (the fp32 scale sidecar,
+    whose minor axis IS the rows; the CPU rehearsals' 8- and 16-row
+    blocks; a verify window wider than a tile)."""
+    bs = arena.shape[3]
+    tile = 32 // jnp.dtype(arena.dtype).itemsize
+    tiled = arena.ndim > 4 and bs % tile == 0 and bs > tile and s - 1 <= tile
+    return tile if tiled else bs
 
 
 def paged_kv_write(arena, new, layer, block_idx, offset):
@@ -622,16 +641,19 @@ def paged_kv_write(arena, new, layer, block_idx, offset):
     slot's S consecutive tokens, token (b, j) bound for row ``offset[b,
     j]`` of block ``block_idx[b, j]`` of layer ``layer`` (traced).
 
-    Grid ``(B, min(S, 2))``, one whole block in and out per step. A
-    window of consecutive positions spans at most two blocks when
-    ``S - 1 <= bs``: the first and the last token's. A window inside one
-    block repeats it in the second step, which pallas neither re-fetches
+    Grid ``(B, min(S, 2))``, one tile of :func:`_write_rows` rows of one
+    block in and out per step (every head's): a block's other tiles are
+    never moved. A window of consecutive positions spans at most two
+    tiles when ``S - 1 <= rows``, across a block boundary or inside a
+    block alike: the first and the last token's. A window inside one
+    tile repeats it in the second step, which pallas neither re-fetches
     nor writes back in between, and the merge is idempotent. DIFFERENT
-    slots must not name the same block unless its bytes are never read:
-    the second slot's step would merge into the copy fetched before the
-    first one's write landed. The engine's live slots never do (a slot
-    writes only blocks it owns alone; prefix-shared blocks are full);
-    freed slots all aim at the garbage block.
+    slots must not name the same tile of the same block unless its bytes
+    are never read: the second slot's step would merge into the copy
+    fetched before the first one's write landed. The engine's live slots
+    never do (a slot writes only blocks it owns alone; prefix-shared
+    blocks are full); freed slots all aim at the garbage block, at
+    whatever tile.
     """
     b, s = block_idx.shape
     hkv, bs = arena.shape[2], arena.shape[3]
@@ -639,27 +661,30 @@ def paged_kv_write(arena, new, layer, block_idx, offset):
     if s - 1 > bs:
         raise ValueError(f"window of {s} tokens can span more than two "
                          f"blocks of {bs}")
+    rows = _write_rows(arena, s)
     zeros = (0,) * len(rest)
-    # Rows ride a unit axis where the block has ``bs``, so the kernel
+    # Rows ride a unit axis where the tile has ``rows``, so the kernel
     # broadcasts along it and never moves heads between tile axes.
     new = new.astype(arena.dtype).reshape(b, s, hkv, 1, *rest)
     new_spec = pl.BlockSpec(
         (1, s, hkv, 1, *rest),
         lambda b_, t, ly, blk, off: (b_, 0, 0, 0, *zeros))
-    arena_spec = pl.BlockSpec(
-        (1, 1, hkv, bs, *rest),
-        lambda b_, t, ly, blk, off: (
-            ly[0], blk[b_ * s + t * (s - 1)], 0, 0, *zeros))
+
+    def held(b_, t, ly, blk, off):
+        at = b_ * s + t * (s - 1)
+        return (ly[0], blk[at], 0, jax.lax.div(off[at], rows), *zeros)
+
+    arena_spec = pl.BlockSpec((1, 1, hkv, rows, *rest), held)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, min(s, 2)),
         in_specs=[new_spec, arena_spec],
         out_specs=arena_spec,
     )
-    block_bytes = (hkv * bs * math.prod(rest)
-                   * jnp.dtype(arena.dtype).itemsize)
+    tile_bytes = (hkv * rows * math.prod(rest)
+                  * jnp.dtype(arena.dtype).itemsize)
     return pl.pallas_call(
-        functools.partial(_write_kernel, window=s),
+        functools.partial(_write_kernel, window=s, rows=rows),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
         # Operand 4 counts the three scalar-prefetch arrays and ``new``.
@@ -668,7 +693,7 @@ def paged_kv_write(arena, new, layer, block_idx, offset):
         name="paged_kv_write",
         cost_estimate=pl.CostEstimate(
             flops=0, transcendentals=0,
-            bytes_accessed=2 * b * min(s, 2) * block_bytes),
+            bytes_accessed=2 * b * min(s, 2) * tile_bytes),
     )(_layer_operand(layer), block_idx.astype(jnp.int32).reshape(-1),
       offset.astype(jnp.int32).reshape(-1), new, arena)
 

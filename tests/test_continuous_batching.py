@@ -441,13 +441,15 @@ def test_tick_books_how_full_the_kernels_grid_steps_run(setup):
 
 
 def test_live_rows_never_share_a_write_block(setup, pallas_interpret):
-    """``paged_kv_write`` merges each row into the block as fetched, so
-    two rows of one tick may name the same block only if nothing reads
-    it. Over a run that crosses block boundaries, frees and re-admits
-    slots and splices shared prefix blocks: every live row of every tick
-    writes a block no other slot's table holds (shared prefix blocks are
-    full, hence never written), freed rows all aim at the garbage block,
-    and the kernel path's greedy tokens are the reference path's."""
+    """``paged_kv_write`` merges each row into the block's tile as
+    fetched, so two rows of one tick may name the same tile of a block
+    (the engine promises the stronger thing: the same block) only if
+    nothing reads it. Over a run that crosses block boundaries, frees
+    and re-admits slots and splices shared prefix blocks: every live row
+    of every tick writes a block no other slot's table holds (shared
+    prefix blocks are full, hence never written), freed rows all aim at
+    the garbage block, and the kernel path's greedy tokens are the
+    reference path's."""
     from ray_tpu.models.paged_kv import GARBAGE_BLOCK
 
     config, gen, _ = setup

@@ -763,12 +763,22 @@ def test_layer_argument_must_match_arena_rank():
         paged_decode_attention(q, ak, av, tables, pos, layer=0)
 
 
-def _write_case(hkv, kind, width, bs=32, d=16, slots=6, nb_slot=3, seed=0):
+def _write_tile(kind):
+    """Rows of the sublane tile ``paged_kv_write`` moves in an arena of
+    this kind when its blocks hold more than one (the scale sidecar
+    never takes the tile path; 16 only places its windows)."""
+    return 32 if kind == "int8" else 16
+
+
+def _write_case(hkv, kind, width, bs=32, d=16, slots=9, nb_slot=4, seed=0):
     """A 3-layer arena with live bytes everywhere, and one write: slot
     i's ``width`` consecutive tokens start at ``starts[i]`` of its own
     blocks. Slot 0 starts at row 0 of a block, slot 1 ends on the last
-    row of one, slot 2 straddles a boundary when ``width`` > 1, and
-    slots 3 and 5 are freed (every row aims at the garbage block)."""
+    row of one, slot 2 straddles a block boundary when ``width`` > 1,
+    slot 6 a TILE boundary inside a block, slots 7 and 8 start in a
+    block's middle tiles (with 0, 1 and 4: a start in every tile of a
+    64-row block, four of bf16 and two of int8), and slots 3 and 5 are
+    freed (every row aims at the garbage block)."""
     rng = np.random.default_rng(seed)
     nb = slots * nb_slot + 1
     trailing = () if kind == "scale" else (d,)
@@ -783,7 +793,9 @@ def _write_case(hkv, kind, width, bs=32, d=16, slots=6, nb_slot=3, seed=0):
         new = jnp.asarray(
             rng.standard_normal((slots, width, hkv) + trailing), dtype)
     tables = rng.permutation(np.arange(1, nb)).reshape(slots, nb_slot)
-    starts = np.array([0, bs - width, bs - 1, 5, bs + 3, 40])
+    starts = np.array([0, bs - width, bs - 1, 5, bs + 3, 40,
+                       bs + _write_tile(kind) - 2, 2 * bs + bs // 2 + 3,
+                       bs + 17])
     pos = starts[:, None] + np.arange(width)[None, :]
     block_idx = np.take_along_axis(tables, pos // bs, axis=1)
     block_idx[[3, 5]] = GARBAGE_BLOCK
@@ -792,21 +804,29 @@ def _write_case(hkv, kind, width, bs=32, d=16, slots=6, nb_slot=3, seed=0):
             jnp.asarray(pos % bs, jnp.int32))
 
 
-# kv heads of the MHA 16/16 and the GQA 32/8 shapes.
+# kv heads of the MHA 16/16 and the GQA 32/8 shapes. At ``bs`` 64 a
+# block is four tiles of bf16 and two of int8; at 32, two of bf16 and
+# one of int8 (the whole block, as the scale rows always). "wide" is the
+# narrowest window that can span three tiles: the whole block again.
 @pytest.mark.parametrize("hkv", [16, 8])
 @pytest.mark.parametrize("kind", ["bf16", "int8", "scale"])
-@pytest.mark.parametrize("width", [1, 4])
-def test_write_kernel_equals_xla_scatter(pallas_interpret, hkv, kind,
+@pytest.mark.parametrize("bs,width", [(32, 1), (32, 4), (64, 1), (64, 4),
+                                      (64, "wide")])
+def test_write_kernel_equals_xla_scatter(pallas_interpret, hkv, kind, bs,
                                          width):
     """``paged_kv_write`` stores byte for byte what the XLA scatter on
     the layer's slab stores (K/V rows of a bf16 or an int8 arena, and
     the fp32 scale rows through the same kernel): a tick's one token a
-    slot and a verify window's four; every other layer untouched. The
-    garbage block is compared nowhere: it holds whichever freed row the
-    scatter or the kernel happened to keep, and nothing reads it."""
+    slot and a verify window's four, moved a tile or a block a step;
+    every other layer, and every tile a token did not land in,
+    untouched. The garbage block is compared nowhere: it holds whichever
+    freed row the scatter or the kernel happened to keep, and nothing
+    reads it."""
     from ray_tpu.models.continuous_batching import _scatter_arena
 
-    arena, new, block_idx, offset = _write_case(hkv, kind, width)
+    if width == "wide":
+        width = _write_tile(kind) + 2
+    arena, new, block_idx, offset = _write_case(hkv, kind, width, bs=bs)
     li = 1
     got = paged_kv_write(arena, new, jnp.int32(li), block_idx, offset)
     want = _scatter_arena(arena[li], new.reshape(-1, *new.shape[2:]),
@@ -819,17 +839,39 @@ def test_write_kernel_equals_xla_scatter(pallas_interpret, hkv, kind,
         np.testing.assert_array_equal(np.asarray(got[other]),
                                       np.asarray(arena[other]))
     # The rows did land (the comparison above is not two no-ops).
-    b, j = 2, width - 1
-    np.testing.assert_array_equal(
-        np.asarray(got[li, block_idx[b, j], :, offset[b, j]]),
-        np.asarray(new[b, j]))
+    for b in (2, 6):
+        j = width - 1
+        np.testing.assert_array_equal(
+            np.asarray(got[li, block_idx[b, j], :, offset[b, j]]),
+            np.asarray(new[b, j]))
 
 
-def test_write_kernel_window_second_block_is_garbage(pallas_interpret):
+@pytest.mark.parametrize("kind,bs,width,rows", [
+    ("bf16", 64, 1, 16), ("bf16", 64, 17, 16), ("bf16", 64, 18, 64),
+    ("bf16", 32, 4, 16), ("bf16", 16, 1, 16), ("bf16", 8, 1, 8),
+    ("int8", 64, 4, 32), ("int8", 64, 34, 64), ("int8", 32, 1, 32),
+    ("scale", 64, 1, 64)])
+def test_write_kernel_states_the_bytes_it_moves(kind, bs, width, rows):
+    """The traced call's ``cost_estimate`` counts a tile in and out a
+    grid step on the tile path (a trailing axis, a block of more than
+    one whole tile, a window of at most two) and the block's otherwise."""
+    hkv, d, slots = 8, 16, 9
+    arena, new, block_idx, offset = _write_case(hkv, kind, width, bs=bs,
+                                                slots=slots, nb_slot=8)
+    jaxpr = jax.make_jaxpr(paged_kv_write)(arena, new, jnp.int32(0),
+                                           block_idx, offset)
+    call, = (e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call")
+    row_bytes = arena.dtype.itemsize * (1 if kind == "scale" else d)
+    assert call.params["cost_estimate"].bytes_accessed == (
+        2 * slots * min(width, 2) * hkv * rows * row_bytes)
+
+
+@pytest.mark.parametrize("bs", [32, 64])
+def test_write_kernel_window_second_block_is_garbage(pallas_interpret, bs):
     """A verify window that overruns its slot's reservation: the tokens
     before the boundary land in the slot's last block, the ones past it
     in the garbage block, and no other block changes."""
-    arena, new, block_idx, offset = _write_case(8, "bf16", 4)
+    arena, new, block_idx, offset = _write_case(8, "bf16", 4, bs=bs)
     block_idx = block_idx.at[2, 1:].set(GARBAGE_BLOCK)  # slot 2 straddles
     got = paged_kv_write(arena, new, jnp.int32(0), block_idx, offset)
     np.testing.assert_array_equal(
@@ -839,10 +881,16 @@ def test_write_kernel_window_second_block_is_garbage(pallas_interpret):
     rest = np.setdiff1d(np.arange(arena.shape[1]), touched)
     np.testing.assert_array_equal(np.asarray(got[0])[rest],
                                   np.asarray(arena[0])[rest])
+    # The slot's last block keeps every byte but the one row.
+    kept = np.array(got[0, block_idx[2, 0]])
+    kept[:, offset[2, 0]] = np.asarray(arena[0, block_idx[2, 0], :,
+                                             offset[2, 0]])
+    np.testing.assert_array_equal(kept,
+                                  np.asarray(arena[0, block_idx[2, 0]]))
     with pytest.raises(ValueError, match="two blocks"):
-        paged_kv_write(arena, jnp.zeros((1, 34, 8, 16), jnp.bfloat16),
-                       jnp.int32(0), jnp.zeros((1, 34), jnp.int32),
-                       jnp.zeros((1, 34), jnp.int32))
+        paged_kv_write(arena, jnp.zeros((1, bs + 2, 8, 16), jnp.bfloat16),
+                       jnp.int32(0), jnp.zeros((1, bs + 2), jnp.int32),
+                       jnp.zeros((1, bs + 2), jnp.int32))
 
 
 def test_paged_cache_create_dtypes():

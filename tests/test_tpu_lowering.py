@@ -120,13 +120,16 @@ def test_paged_decode_lowers(hq, hkv, kv_dtype, layers, limited):
                          li, lim) == 1
 
 
-@pytest.mark.parametrize("hkv", [16, 8])
+# The MHA 16/16 and GQA 32/8 shapes, the latent row (``mla.py``) and the
+# two wide heads of the linear-attention cell's full layers.
+@pytest.mark.parametrize("hkv,d", [(16, 128), (8, 128), (1, 640), (2, 256)])
 @pytest.mark.parametrize("kind", ["bf16", "int8", "scale"])
 @pytest.mark.parametrize("width", [1, 5])
-def test_paged_kv_write_lowers(hkv, kind, width):
+def test_paged_kv_write_lowers(hkv, d, kind, width):
     """The in-place write: a tick's one token a slot and a verify
-    window's five, into K/V of either dtype and the fp32 scale rows."""
-    trailing = () if kind == "scale" else (128,)
+    window's five, into K/V of either dtype (a tile of a 64-row block a
+    grid step) and the fp32 scale rows (the block)."""
+    trailing = () if kind == "scale" else (d,)
     dtype = {"bf16": BF16, "int8": jnp.int8, "scale": jnp.float32}[kind]
     arena = S((16, 257, hkv, 64) + trailing, dtype)
     new = S((32, width, hkv) + trailing, dtype)
