@@ -69,6 +69,15 @@ seed), and checks what comes out by the repo's own means:
                 the exit gate after each step against the reference's;
                 the program as published passes (two sets of prompts),
                 float8 weights and six faults are read against it;
+* ``jamba``     the cell ``serve_ssm_decode``'s comparison at its own
+                sizes (AI21-Jamba2-3B WHOLE at the published widths, 26
+                Mamba-1 mixers and 2 MQA 20/1 layers, through
+                ``ContinuousBatcher`` with the cell's 256 slots): the
+                cell's check prompts (one with a second chunk of ONE
+                token), chosen tokens against the float32 reference
+                under the configuration file's limit; the program as
+                published passes (two sets of prompts), float8 weights
+                and five faults are read against it;
 * ``train``     ``ShardedTrainer`` on one device, batch 5 x 2048: loss
                 finite and falling, one compiled signature, the Mosaic
                 custom calls present in the compiled step;
@@ -106,18 +115,18 @@ import threading
 import time
 
 PHASES = ("kernels", "moe", "hybrid", "window", "mla", "linear", "eva",
-          "cca", "loop", "train", "serve", "multichip")
+          "cca", "loop", "jamba", "train", "serve", "multichip")
 # The multichip phase is two children: the trainer's state must be gone
 # from the chips before four serving replicas load theirs.
 CHILDREN = {"kernels": ("kernels",), "moe": ("moe",),
             "hybrid": ("hybrid",), "window": ("window",), "mla": ("mla",),
             "linear": ("linear",), "eva": ("eva",), "cca": ("cca",),
-            "loop": ("loop",), "train": ("train",),
+            "loop": ("loop",), "jamba": ("jamba",), "train": ("train",),
             "serve": ("serve",),
             "multichip": ("multichip-train", "multichip-serve")}
 PHASE_TIMEOUT_S = {"kernels": 1200, "moe": 600, "hybrid": 1500,
                    "window": 2700, "mla": 2700, "linear": 3300, "eva": 3300,
-                   "cca": 3300, "loop": 3300,
+                   "cca": 3300, "loop": 3300, "jamba": 3300,
                    "train": 480,
                    "serve": 600,
                    "multichip-train": 900, "multichip-serve": 900}
@@ -2212,6 +2221,26 @@ def phase_cca(rehearse: bool) -> None:
     assert not wrong, f"{wrong}: {results} against {tolerance}"
 
 
+def _round_to_float8(tree):
+    """Every bf16 leaf of an engine's weights rounded to ``float8_e4m3``
+    and back, IN PLACE of the leaf (the chip has no room for two trees
+    beside the caches): the nearest precision below the one a
+    configuration states. Two programs a leaf, not one jitted round
+    trip: XLA folds a narrowing conversion and its inverse away (excess
+    precision)."""
+    import jax
+    import jax.numpy as jnp
+
+    def rounded(a):
+        if a.dtype != jnp.bfloat16:
+            return a
+        out = a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+        a.delete()
+        return out
+
+    return jax.tree.map(rounded, tree)
+
+
 def phase_loop(rehearse: bool) -> None:
     """The cell ``serve_loop_decode``'s comparison with its reference,
     and the faults it has to catch, AT THE CELL'S OWN SIZES: Ouro-2.6B
@@ -2335,21 +2364,9 @@ def phase_loop(rehearse: bool) -> None:
                   for name, a in xs.items()}
         return real[jax.lax, "scan"](f, init, xs, **kw)
 
-    def float8(tree):
-        # Two programs a leaf, not one jitted round trip: XLA folds a
-        # narrowing conversion and its inverse away (excess precision).
-        def rounded(a):
-            if a.dtype != jnp.bfloat16:
-                return a
-            out = a.astype(jnp.float8_e4m3fn).astype(a.dtype)
-            a.delete()      # the chip has no room for two trees
-            return out
-
-        return jax.tree.map(rounded, tree)
-
     cases = [
         ("as published", config, None, {}),
-        ("weights rounded to float8_e4m3", config, float8, {}),
+        ("weights rounded to float8_e4m3", config, _round_to_float8, {}),
         ("(i) every tick reads and writes step 0's rows", config, None,
          {(cb, "_write_then_attend"): rows_of_step_0}),
         ("(ii) step t reads step t - 1's rows", config, None,
@@ -2397,6 +2414,142 @@ def phase_loop(rehearse: bool) -> None:
             float(d) / len(checked[0]) for d in drift]
         _say(phase, f"    mean |gate - reference's| after step 0..{steps - 1}"
                     f": {results[name][0]['gate_gap_by_step']}")
+    _finish(phase, info, faults=results, tolerance=tolerance)
+    sound = results["as published"]
+    assert all(r["ok"] for r in sound), f"as published: {sound}"
+    unseen = [name for name, rs in results.items()
+              if name != "as published" and any(r["ok"] for r in rs)]
+    if unseen:
+        _say(phase, f"INSIDE the limit, so not caught: {unseen}")
+
+
+def phase_jamba(rehearse: bool) -> None:
+    """The cell ``serve_ssm_decode``'s comparison with its reference, and
+    the faults it has to catch, AT THE CELL'S OWN SIZES: AI21-Jamba2-3B
+    whole (28 layers at the published widths, 26 Mamba-1 mixers) in the
+    engine the served path builds, with the cell's 256 slots and its
+    blocks, so the programs are the timed path's at the timed sizes; the
+    cell's check prompts (inside the bucket, on its end, on a chunk's
+    last position, a second chunk of ONE token, the middle of a second
+    chunk) and answer length, one request after another, greedy; held to
+    ``benchmark/reference_jamba.py`` by the runner's own
+    ``hold_to_reference`` under the configuration file's ONE limit.
+
+    First the program as published on two sets of prompts, which has to
+    pass; then every bf16 weight rounded to ``float8_e4m3`` and five
+    faults, one at a time (patched in at ``models/mamba1.py``'s own
+    functions and the two ops it calls), each of which should FAIL the
+    limit (one that does not is printed, and PERF.md section 7 keeps
+    it)."""
+    phase = "jamba"
+    info = _open_device(phase, rehearse)
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import manifest
+    from benchmark.runners import serve_ssm
+    from benchmark.runners.serve import _prompts
+    from ray_tpu.models import continuous_batching as cb
+    from ray_tpu.models import llama, mamba1
+    from ray_tpu.ops import selective_scan
+
+    cell = manifest.cell("serve_ssm_decode")
+    if rehearse:
+        cell = manifest.rehearsal(cell)
+    work, tolerance = cell["workload"], cell["config"]["tolerance"]
+    config = serve_ssm.jamba_config(cell["config"],
+                                    max_seq_len=work["engine"]["max_len"])
+    sets = [_prompts(np.random.default_rng(seed), config.vocab_size,
+                     work["check"]["prompt_tokens"],
+                     work["check"]["max_tokens"])
+            for seed in ((55,) if rehearse else (55, 56))]
+
+    def answers(sets, weights=None):
+        """Each request alone through the engine: its tokens."""
+        eng = cb.ContinuousBatcher(config, **work["engine"])
+        if weights is not None:
+            eng.params = weights(eng.params)
+        out = []
+        for reqs in sets:
+            recs = []
+            for req in reqs:
+                rid = eng.submit(req["prompt"], req["max_tokens"])
+                recs.append({"tokens": eng.run_to_completion()[rid]})
+            out.append(list(zip(reqs, recs)))
+        # Weights and caches fill most of the chip: nothing of this
+        # engine may outlive it (the monitor keeps a program's buffers).
+        del eng
+        gc.collect()
+        for array in jax.live_arrays():
+            array.delete()
+        return out
+
+    real = {(mod, name): getattr(mod, name) for mod, name in (
+        (mamba1, "_gate_out"), (mamba1, "_rms"), (mamba1, "_conv_step"),
+        (selective_scan, "mamba1_scan"), (selective_scan, "mamba1_step"))}
+
+    def no_skip(y, u, z, layer, c):
+        return real[mamba1, "_gate_out"](
+            y, u, z, dict(layer, m1_d=jnp.zeros_like(layer["m1_d"])), c)
+
+    def no_dt_norm(x, weight, c):
+        if weight.shape[-1] == c.mamba_dt_rank:
+            return x.astype(jnp.float32)
+        return real[mamba1, "_rms"](x, weight, c)
+
+    def tail_out_of_order(tail, new, w, bias):
+        out, nxt = real[mamba1, "_conv_step"](tail, new, w, bias)
+        return out, jnp.roll(nxt, new.shape[-1], axis=1)
+
+    def padding_advances(u, dt, *rest, **kw):
+        # The mixer zeroes the padded positions' time steps; a softplus
+        # is never exactly 0, so these are they.
+        return real[selective_scan, "mamba1_scan"](
+            u, jnp.where(dt == 0.0, 0.05, dt), *rest, **kw)
+
+    def bf16_state(state_all, *rest, **kw):
+        # (An astype pair would be folded away: excess precision.)
+        y, new = real[selective_scan, "mamba1_step"](state_all, *rest, **kw)
+        return y, jax.lax.reduce_precision(new, exponent_bits=8,
+                                           mantissa_bits=7)
+
+    cases = [
+        ("as published", None, {}),
+        ("weights rounded to float8_e4m3", _round_to_float8, {}),
+        ("(i) no skip term D u", None, {(mamba1, "_gate_out"): no_skip}),
+        ("(ii) no norm on dt", None, {(mamba1, "_rms"): no_dt_norm}),
+        ("(iii) the tick's conv tail out of order", None,
+         {(mamba1, "_conv_step"): tail_out_of_order}),
+        ("(iv) padding advances the state", None,
+         {(selective_scan, "mamba1_scan"): padding_advances}),
+        ("(v) a bf16 state between ticks", None,
+         {(selective_scan, "mamba1_step"): bf16_state}),
+    ]
+    if rehearse:            # tiny sizes prove nothing about the faults
+        cases = cases[:3]
+    answered = {}
+    for name, weights, patch in cases:
+        for (mod, attr), fn in patch.items():
+            setattr(mod, attr, fn)
+        try:
+            answered[name] = answers(
+                sets if name == "as published" else sets[:1], weights)
+        finally:
+            for (mod, attr), fn in real.items():
+                setattr(mod, attr, fn)
+        _say(phase, f"{name}: {len(answered[name])} x {len(sets[0])} check "
+                    f"requests answered")
+    _print_memory(phase)
+    params = jax.jit(lambda k: llama.init_params(config, k))(
+        jax.random.PRNGKey(0))
+    results = {}
+    for name, checked in answered.items():
+        _say(phase, name)
+        results[name] = [serve_ssm.hold_to_reference(
+            params, config, checks, tolerance) for checks in checked]
     _finish(phase, info, faults=results, tolerance=tolerance)
     sound = results["as published"]
     assert all(r["ok"] for r in sound), f"as published: {sound}"
@@ -2813,7 +2966,8 @@ def _child(phase: str, rehearse: bool) -> int:
 CHILD_FNS = {"kernels": phase_kernels, "moe": phase_moe,
              "hybrid": phase_hybrid, "window": phase_window,
              "mla": phase_mla, "linear": phase_linear, "eva": phase_eva,
-             "cca": phase_cca, "loop": phase_loop, "train": phase_train,
+             "cca": phase_cca, "loop": phase_loop, "jamba": phase_jamba,
+             "train": phase_train,
              "serve": phase_serve, "multichip-train": phase_multichip_train,
              "multichip-serve": phase_multichip_serve}
 

@@ -317,13 +317,13 @@ def _attn_out(o, layer, c, gate=None):
     return jnp.einsum("bshd,hde->bse", o, layer["wo"].astype(c.dtype))
 
 
-_KIND_NAMES = {"mamba": "state-space",
+_KIND_NAMES = {"mamba": "state-space", "mamba1": "selective-scan",
                "linear_attention": "linear-attention",
                "sliding_attention": "sliding-window",
                "latent_attention": "latent-attention",
                "eva_attention": "eva-attention",
                "cca_attention": "cca-attention"}
-# A recurrent layer (Mamba-2 or Gated DeltaNet) keeps, beside its K/V, a
+# A recurrent layer (Mamba-2, Mamba-1 or Gated DeltaNet) keeps, not K/V, a
 # state a slot that only moves FORWARD and belongs to one request.
 _RECURRENT_CANNOT = {
     "second_kind": "the engine keeps one cache beside the arena, and the "
@@ -339,8 +339,8 @@ _RECURRENT_CANNOT = {
 # :func:`_refuse_unsupported`, for the first kind of the stack that names
 # it; "second_kind" is asked by the stack itself, the rest by the caller.
 _KIND_CANNOT = {
-    "mamba": _RECURRENT_CANNOT,
-    "linear_attention": _RECURRENT_CANNOT,
+    "mamba": _RECURRENT_CANNOT, "linear_attention": _RECURRENT_CANNOT,
+    "mamba1": dict(_RECURRENT_CANNOT, kv_dtype="its prefill lands bf16 K/V"),
     # The last ``sliding_window`` keys of a slot live in a ring beside
     # the arena, which overwrites the rest.
     "sliding_attention": {
@@ -1382,9 +1382,9 @@ class ContinuousBatcher:
         if chunk < self.block_size:
             raise ValueError(f"prefill_chunk {prefill_chunk} is under one "
                              f"block of {self.block_size}")
-        # None: a prompt is never split (Mamba-2 layers, whose scan is not
-        # given a carried state: ROADMAP R7). A linear-attention layer's
-        # chunk starts from the state and conv tail the one before left.
+        # None: a prompt is never split (Mamba-2's SSD scan is given no
+        # carried state: ROADMAP R7). A linear-attention, Mamba-1 or CCA
+        # chunk starts from the state or conv tail the one before left.
         self.prefill_chunk = None if "mamba" in config.layer_types else chunk
         self.prefix_cache = _resolve_prefix_cache(prefix_cache)
         self.use_decode_kernel = _resolve_decode_kernel(
@@ -1744,9 +1744,9 @@ class ContinuousBatcher:
         self._tick = tick
         self._merge_tokens = merge_tokens
 
-        if cfg.loop_steps > 1:      # the two programs above, looped
-            from ray_tpu.models import looped
-            looped.install(self)
+        if cfg.loop_steps > 1 or cfg.mamba_dt_rank:   # a family's own two
+            from ray_tpu.models import looped, mamba1
+            (looped if cfg.loop_steps > 1 else mamba1).install(self)
         if self.spec_k and self.drafter.external:
             # The drafter's own dense cache: admission prefills the FULL
             # prompt into it, decode advances it inside the spec tick.

@@ -42,7 +42,7 @@ compile_cache.ensure()
 Params = Dict[str, Any]
 
 # Layer kinds whose mixer keeps a recurrent state and a conv tail a slot.
-STATE_KINDS = ("mamba", "linear_attention")
+STATE_KINDS = ("mamba", "linear_attention", "mamba1")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,6 +93,22 @@ class LlamaConfig:
     mamba_d_state: int = 0
     mamba_n_groups: int = 1
     mamba_d_conv: int = 4
+    # The Jamba family (``model_type`` "jamba" with ``num_experts`` 1;
+    # SERVING ONLY): ``layer_types`` names "mamba1", a Mamba-1 mixer
+    # (``models/mamba1.py``; ``transformers`` calls the layer "mamba",
+    # which here is Mamba-2), or "attention", where the published
+    # ``i % attn_layer_period == attn_layer_offset``. Mamba-1 has a
+    # decay for EVERY (channel, state) pair (``A_log [d_inner, N]``), a
+    # time step a channel through a bottleneck of ``mamba_dt_rank``,
+    # RMSNorms on dt, B and C, a convolution over x alone and no gated
+    # norm; it has no heads, so ``d_inner`` (the published
+    # ``mamba_expand`` x hidden) is held as ONE head: ``mamba_n_heads``
+    # 1 x ``mamba_d_head``, with ``mamba_d_state`` N and
+    # ``mamba_d_conv`` as above. A slot keeps ``[N, d_inner]`` float32
+    # and the conv's last inputs a layer (``paged_kv.StateCache``), and
+    # a long prompt's chunk goes on from them. The engine's programs
+    # of this family are ``mamba1.install``'s. 0 = every other model.
+    mamba_dt_rank: int = 0
     # ``position_embedding_type`` "nope": no rotation of q and k.
     rope: bool = True
     # Softmax scale of attention; None = head_dim ** -0.5.
@@ -431,6 +447,24 @@ class LlamaConfig:
             sandwich_norms=True, loop_steps=4), **kw})
 
     @staticmethod
+    def jamba2_3b(**kw) -> "LlamaConfig":
+        """ai21labs/AI21-Jamba2-3B (``jamba``, ``num_experts`` 1): 28
+        layers of hidden 2560, 26 Mamba-1 mixers (5120 channels x 16
+        states, dt rank 160, 4 conv taps) and MQA 20/1 attention of 128
+        without positions at layers 7 and 21 (``i % 14 == 7``); a dense
+        SwiGLU of 8192 on every layer; tied 65k embedding; 3,029.3M
+        parameters, which one chip holds whole. The published
+        ``max_position_embeddings`` is 262144."""
+        pattern = ("mamba1",) * 7 + ("attention",) + ("mamba1",) * 6
+        return LlamaConfig(**{**dict(
+            vocab_size=65536, hidden_size=2560, intermediate_size=8192,
+            num_layers=28, num_heads=20, num_kv_heads=1, head_dim=128,
+            max_seq_len=262144, rms_eps=1e-6, layer_types=pattern * 2,
+            mamba_n_heads=1, mamba_d_head=5120, mamba_d_state=16,
+            mamba_d_conv=4, mamba_dt_rank=160, rope=False,
+            tie_word_embeddings=True), **kw})
+
+    @staticmethod
     def tiny(**kw) -> "LlamaConfig":
         """CPU-runnable config for tests (BASELINE.md config #1 analog)."""
         kw.setdefault("vocab_size", 256)
@@ -451,47 +485,13 @@ def scaling_pairs(group: Optional[Dict[str, Any]]):
     return None if group is None else tuple(sorted(group.items()))
 
 
-def logical_axes(config: LlamaConfig) -> Params:
-    """Pytree of logical-axis tuples matching :func:`init_params`."""
-    layer = {
-        "attn_norm": ("layers", "norm"),
-        "wq": ("layers", "embed", "heads", "head_dim"),
-        "wk": ("layers", "embed", "kv_heads", "head_dim"),
-        "wv": ("layers", "embed", "kv_heads", "head_dim"),
-        "wo": ("layers", "heads", "head_dim", "embed"),
-        "mlp_norm": ("layers", "norm"),
-        "w_gate": ("layers", "embed", "mlp"),
-        "w_up": ("layers", "embed", "mlp"),
-        "w_down": ("layers", "mlp", "embed"),
-    }
-    if "eva_attention" in config.layer_types:
-        layer["eva_phi"] = ("layers", "kv_heads", "head_dim")
-        layer["eva_mu"] = ("layers", "kv_heads", "head_dim")
-    if config.qk_norm:
-        layer["q_norm"] = ("layers", None)
-        layer["k_norm"] = ("layers", None)
-    if config.num_experts:
-        for name in ("w_gate", "w_up", "w_down"):
-            del layer[name]
-        layer.update({
-            "w_router": ("layers", "embed", None),
-            "moe_gate": ("layers", "experts", "embed", "mlp"),
-            "moe_up": ("layers", "experts", "embed", "mlp"),
-            "moe_down": ("layers", "experts", "mlp", "embed"),
-        })
-    return {
-        "embed": ("vocab", "embed"),
-        "layers": layer,
-        "final_norm": ("norm",),
-        "lm_head": ("embed", "vocab"),
-    }
-
-
 def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     """Random init (normal / scaled), stacked over layers for lax.scan."""
     c = config
     if c.loop_steps > 1:
         return _init_looped_params(c, key)
+    if c.mamba_dt_rank:
+        return _init_jamba_params(c, key)
     if c.layer_types:
         return (_init_hybrid_params(c, key) if "mamba" in c.layer_types
                 else _init_windowed_params(c, key))
@@ -786,11 +786,11 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
                num_layers: Optional[int] = None):
     """The layer stack as RUNS of equal layers, in order: a list of
     ``(kind, start, count, kind_start)`` where ``kind`` is "attention",
-    "mamba", "linear_attention", "sliding_attention", "full_attention",
+    "mamba", "mamba1", "linear_attention", "sliding_attention", "full_attention",
     "latent_attention", "eva_attention" or "cca_attention", ``start`` the
     run's first GLOBAL layer and ``kind_start`` its first index among
     layers that share its cache (the K/V arena's layer for attention and full
-    attention, the state cache's for mamba and for linear attention, the
+    attention, the state cache's for mamba, mamba1 and linear attention, the
     ring's for sliding attention, the latent cache's for latent
     attention, the arena's again for EVA attention, which no other kind
     shares it with, and for CCA attention the arena's and the tail
@@ -808,7 +808,7 @@ def layer_runs(config: LlamaConfig, params: Optional[Params] = None,
                          f"num_layers is {c.num_layers}")
     runs, seen = [], {"attention": 0, "mamba": 0, "sliding_attention": 0,
                       "latent_attention": 0, "linear_attention": 0,
-                      "eva_attention": 0, "cca_attention": 0}
+                      "eva_attention": 0, "cca_attention": 0, "mamba1": 0}
     for i, kind in enumerate(types):
         # "full_attention" keeps all its K/V in the arena, as "attention"
         # does: they count as one kind of cache.
@@ -1392,3 +1392,91 @@ def _init_looped_params(c: LlamaConfig, key: jax.Array) -> Params:
                           * E ** -0.5).astype(c.dtype)
     out["exit_gate_b"] = jnp.zeros((), jnp.float32)
     return out
+
+
+def _init_jamba_params(c: LlamaConfig, key: jax.Array) -> Params:
+    """The Jamba family's tree (``mamba_dt_rank > 0``), laid out as the
+    hybrids' is: ``embed`` (also the head), ``final_norm``, ``layers``
+    empty (a dense SwiGLU on every layer: no stacked experts) and
+    ``runs``: for each run of equal layers (:func:`layer_runs`) one tree
+    stacked over the run's layers, the two norms and the SwiGLU beside a
+    Mamba-1 mixer's weights (``mamba1.init_mixer``) or MQA attention's.
+    Seeded so that dropping a term shows: every norm's weight is uniform
+    in 0.5..1.5, not ones; the embedding is at ``E ** -0.5``, as
+    :func:`_init_hybrid_params` has it and for its reason."""
+    from ray_tpu.models import mamba1
+
+    if (c.num_experts or c.rope or not c.tie_word_embeddings
+            or set(c.layer_types) - {"mamba1", "attention"}):
+        raise ValueError(
+            "mamba_dt_rank > 0 is the Jamba family's: mamba1 and attention "
+            "layers, no positions, a dense MLP, a tied head")
+    E, M = c.hidden_size, c.intermediate_size
+    H, KV, D = c.num_heads, c.num_kv_heads, c.head_dim
+    k_embed, k_final, k_runs = jax.random.split(key, 3)
+
+    def dense(key, fan_in, *shape):
+        out = jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
+        return out.astype(c.dtype)
+
+    def norm(key, *shape):
+        return jax.random.uniform(key, shape, jnp.float32, 0.5,
+                                  1.5).astype(c.dtype)
+
+    runs = []
+    for r, (kind, _, n, _) in enumerate(layer_runs(c)):
+        k = jax.random.split(jax.random.fold_in(k_runs, r), 10)
+        tree = {
+            "attn_norm": norm(k[0], n, E), "mlp_norm": norm(k[1], n, E),
+            "w_gate": dense(k[2], E, n, E, M), "w_up": dense(k[3], E, n, E, M),
+            "w_down": dense(k[4], M, n, M, E),
+        }
+        if kind == "mamba1":
+            tree.update(mamba1.init_mixer(c, k[5], n))
+        else:
+            tree.update(wq=dense(k[6], E, n, E, H, D),
+                        wk=dense(k[7], E, n, E, KV, D),
+                        wv=dense(k[8], E, n, E, KV, D),
+                        wo=dense(k[9], H * D, n, H, D, E))
+        runs.append(tree)
+    return {
+        "embed": (jax.random.normal(k_embed, (c.vocab_size, E), jnp.float32)
+                  * E ** -0.5).astype(c.dtype),
+        "final_norm": norm(k_final, E), "layers": {}, "runs": runs,
+    }
+
+
+def logical_axes(config: LlamaConfig) -> Params:
+    """Pytree of logical-axis tuples matching :func:`init_params`."""
+    layer = {
+        "attn_norm": ("layers", "norm"),
+        "wq": ("layers", "embed", "heads", "head_dim"),
+        "wk": ("layers", "embed", "kv_heads", "head_dim"),
+        "wv": ("layers", "embed", "kv_heads", "head_dim"),
+        "wo": ("layers", "heads", "head_dim", "embed"),
+        "mlp_norm": ("layers", "norm"),
+        "w_gate": ("layers", "embed", "mlp"),
+        "w_up": ("layers", "embed", "mlp"),
+        "w_down": ("layers", "mlp", "embed"),
+    }
+    if "eva_attention" in config.layer_types:
+        layer["eva_phi"] = ("layers", "kv_heads", "head_dim")
+        layer["eva_mu"] = ("layers", "kv_heads", "head_dim")
+    if config.qk_norm:
+        layer["q_norm"] = ("layers", None)
+        layer["k_norm"] = ("layers", None)
+    if config.num_experts:
+        for name in ("w_gate", "w_up", "w_down"):
+            del layer[name]
+        layer.update({
+            "w_router": ("layers", "embed", None),
+            "moe_gate": ("layers", "experts", "embed", "mlp"),
+            "moe_up": ("layers", "experts", "embed", "mlp"),
+            "moe_down": ("layers", "experts", "mlp", "embed"),
+        })
+    return {
+        "embed": ("vocab", "embed"),
+        "layers": layer,
+        "final_norm": ("norm",),
+        "lm_head": ("embed", "vocab"),
+    }

@@ -224,7 +224,7 @@ class StateCache(NamedTuple):
     mixer's that the config names: Mamba-2 (``layer_types`` "mamba")
     ``[H, N / f, f * P]`` (``ops/ssm.py::packed_shape``) over ``x | B |
     C``; Gated DeltaNet ("linear_attention") ``[Hv, Dk, Dv]`` over ``q |
-    k | v`` (``models/gated_delta.py::state_shapes``). Neither is paged
+    k | v`` (``gated_delta.state_shapes``); "mamba1" ``[N, Di]``. Not paged
     nor shareable by prefix: a slot's row is installed by its prefill
     (a later CHUNK of a linear-attention prompt reads the row the chunk
     before it wrote, and overwrites it), advanced by every tick, and
@@ -238,10 +238,10 @@ class StateCache(NamedTuple):
     def create(cls, config: llama.LlamaConfig,
                num_slots: int) -> "StateCache":
         c = config
-        if "linear_attention" in c.layer_types:
-            from ray_tpu.models.gated_delta import state_shapes
-
-            state, tail = state_shapes(c)
+        if c.mamba_dt_rank or "linear_attention" in c.layer_types:
+            from ray_tpu.models import gated_delta, mamba1
+            state, tail = (mamba1 if c.mamba_dt_rank
+                           else gated_delta).state_shapes(c)
         else:
             from ray_tpu.ops.ssm import packed_shape
 

@@ -40,6 +40,10 @@ CONFIGS = {
         linear_num_value_heads=4, linear_key_head_dim=16,
         linear_value_head_dim=24, num_experts=8, num_experts_per_tok=2,
         shared_intermediate_size=48),
+    "mamba1": lambda: llama.LlamaConfig.jamba2_3b(
+        **_SMALL, num_layers=4,
+        layer_types=("mamba1", "attention", "mamba1", "mamba1"),
+        num_kv_heads=1, head_dim=16, mamba_d_head=128, mamba_dt_rank=8),
     "sliding_attention": lambda: llama.LlamaConfig.trinity_large_preview(
         **_SMALL, num_layers=5,
         layer_types=("sliding_attention",) * 3 + ("full_attention",
@@ -74,6 +78,7 @@ ASKED_OF_THE_CONSTRUCTOR = {
 # order to object (the refusal is the first kind's).
 A_SECOND_KIND = {
     "mamba": "linear_attention", "linear_attention": "sliding_attention",
+    "mamba1": "sliding_attention",
     "sliding_attention": "latent_attention",
     "latent_attention": "full_attention",
     "eva_attention": "full_attention",

@@ -1233,3 +1233,91 @@ def test_compiled_fsdp_step_gathers_what_its_loops_reuse_once(
         ("bf16", "all-gather"), ("bf16", "all-reduce")]
     if tensor > 1:
         assert not re.search(r",32768\][^=]* all-gather", hlo)
+
+
+# --------------------------------- Mamba-1 selective scan (Jamba, PR 55)
+
+_M1_N, _M1_D = 16, 5120          # Jamba2-3B: states a channel, channels
+
+
+def _m1_step_specs(slots, spec=S):
+    f32 = jnp.float32
+    return (spec((26, slots, _M1_N, _M1_D), f32), spec((), jnp.int32),
+            spec((slots, _M1_D), BF16), spec((slots, _M1_D), f32),
+            spec((_M1_N, _M1_D), f32), spec((slots, _M1_N), f32),
+            spec((slots, _M1_N), f32))
+
+
+def _m1_scan_specs(rows, s, carried, spec=S):
+    f32 = jnp.float32
+    args = (spec((rows, s, _M1_D), BF16), spec((rows, s, _M1_D), f32),
+            spec((_M1_N, _M1_D), f32), spec((rows, s, _M1_N), f32),
+            spec((rows, s, _M1_N), f32))
+    return args + ((spec((rows, _M1_N, _M1_D), f32),) if carried else ())
+
+
+@pytest.mark.parametrize("slots", [4, 256])
+def test_mamba1_step_lowers(slots):
+    """The tick's in-place selective-scan update at Jamba2-3B's widths
+    (a ``[16, 5120]`` float32 state a slot), on the whole ``[26, slots,
+    ...]`` cache at a traced layer: one block of few slots, and blocks
+    of eight."""
+    from ray_tpu.ops import selective_scan
+
+    fn = functools.partial(selective_scan.mamba1_step, use_kernel=True)
+    assert selective_scan.step_applicable(slots, _M1_N, _M1_D)
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *_m1_step_specs(slots))
+    assert _kernel_names(exported.mlir_module()) == ["mamba1_step"]
+
+
+@pytest.mark.parametrize("carried", [True, False], ids=["carried", "fresh"])
+@pytest.mark.parametrize("rows,s", [(16, 512), (1, 1024), (1, 16)])
+def test_mamba1_scan_lowers(rows, s, carried):
+    """The prefill's scan over positions at Jamba2-3B's widths: the
+    mix's bucket, a whole chunk, and a second chunk of ONE token (padded
+    to 16), with a carried state and from none."""
+    from ray_tpu.ops import selective_scan
+
+    fn = functools.partial(selective_scan.mamba1_scan, use_kernel=True)
+    assert selective_scan.scan_applicable(s, _M1_N, _M1_D)
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *_m1_scan_specs(rows, s, carried))
+    assert _kernel_names(exported.mlir_module()) == ["mamba1_scan"]
+
+
+@pytest.mark.parametrize("kernel", ["mamba1_step", "mamba1_scan",
+                                    "paged_decode_attn", "paged_kv_write"])
+def test_compiled_jamba_kernels_for_the_described_chip(v5e_chip, kernel):
+    """A real compile (the exports above stop before Mosaic) of what the
+    Jamba2-3B tick and prefill call: the two selective-scan kernels at
+    the cell's 256 slots and 16 x 512 rows, and the paged kernels at 20
+    query heads on ONE K/V head: a query group of 20 rows, which is no
+    multiple of the 8-row sublane tile, and a one-head arena."""
+    from ray_tpu.ops import selective_scan
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    arena = on_chip((2, 641, 1, 64, 128), BF16)
+    if kernel == "mamba1_step":
+        fn = functools.partial(selective_scan.mamba1_step, use_kernel=True)
+        specs = _m1_step_specs(256, on_chip)
+    elif kernel == "mamba1_scan":
+        fn = functools.partial(selective_scan.mamba1_scan, use_kernel=True)
+        specs = _m1_scan_specs(16, 512, True, on_chip)
+    elif kernel == "paged_decode_attn":
+        def fn(q, k, v, tables, pos):
+            return paged_decode_attention(
+                q, k, v, tables, pos, layer=jnp.int32(1), limits=pos,
+                use_kernel=True, interpret=False)
+        specs = (on_chip((16, 20, 128), BF16), arena, arena,
+                 on_chip((16, 40), jnp.int32), on_chip((16,), jnp.int32))
+    else:
+        fn = paged_kv_write
+        where = on_chip((16, 1), jnp.int32)
+        specs = (arena, on_chip((16, 1, 1, 128), BF16),
+                 on_chip((), jnp.int32), where, where)
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert len(re.findall(rf"%{kernel}[.\d]* = ",
+                          compiled.as_text())) == 1
