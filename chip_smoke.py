@@ -9,7 +9,10 @@ the full width of the 953M Llama-shaped config both benches use (hidden
 2048, 16 layers, 16 heads of 128, vocab 32,000; random weights from a
 seed), and checks what comes out by the repo's own means:
 
-* ``kernels``   flash attention forward/backward and paged decode
+* ``kernels``   flash attention forward/backward (at the two train
+                cells' shapes; each kernel's ms a call, its block-step
+                schedule and its TFLOP/s over the causal work, PR 56)
+                and paged decode
                 attention (bf16 and int8 arena), COMPILED, against their
                 references within the tolerances in ``TOLERANCE``; the
                 routed block's grouped matmul (``moe_gmm``) against a
@@ -510,6 +513,34 @@ def _time_us(call, q, arena, layers: int, reps: int) -> float:
     return (time.perf_counter() - t0) / reps * 1e6
 
 
+def _kernel_ms(call, args, names, reps: int = 6) -> dict:
+    """Median device milliseconds a call of each Pallas kernel in
+    ``names`` over ``reps`` runs of ``call(*args)``, from a profiler
+    trace's ``XLA Ops`` line (a kernel's event carries its ``name=``).
+    Empty where there is no device trace (the rehearsal)."""
+    import tempfile
+
+    import jax
+
+    from benchmark import trace_reduce
+
+    jax.block_until_ready(call(*args))
+    got: dict = {}
+    with tempfile.TemporaryDirectory() as log_dir:
+        with jax.profiler.trace(log_dir):
+            for _ in range(reps):
+                out = call(*args)
+            jax.block_until_ready(out)
+        path = trace_reduce.find(log_dir)
+        for lines in (trace_reduce.load(path) if path else {}).values():
+            for event, _, ns in lines.get(trace_reduce.OPS_LINE, []):
+                head = event.split(" = ")[0]
+                for name in names:
+                    if name in head:
+                        got.setdefault(name, []).append(ns / 1e6)
+    return {k: sorted(v)[len(v) // 2] for k, v in got.items()}
+
+
 def _time_paged_cells(phase: str, device_kind: str, rehearse: bool) -> None:
     """Time ``paged_decode_attn`` at each count of blocks a grid step
     (``PAGED_VISIT_BLOCKS`` up to twice the arena's ``visit_blocks``,
@@ -851,7 +882,7 @@ def phase_kernels(rehearse: bool) -> None:
 
     from ray_tpu.models.paged_kv import quantize_kv
     from ray_tpu.ops.attention import (flash_applicable, flash_attention,
-                                       mha_reference)
+                                       flash_block_steps, mha_reference)
     from ray_tpu.ops.dispatch import interpret_default
     from ray_tpu.ops.paged_decode_attention import (
         decode_attention_reference, paged_attention_reference,
@@ -865,36 +896,67 @@ def phase_kernels(rehearse: bool) -> None:
     keys = jax.random.split(jax.random.PRNGKey(0), 12)
 
     # -- flash attention, forward and backward -----------------------------
-    b, s, h, d = (1, 256, 2, 128) if rehearse else (2, 2048, 16, 128)
-    q, k, v, w = (jax.random.normal(keys[i], (b, s, h, d), jnp.float32)
-                  .astype(bf16) for i in range(4))
-    assert flash_applicable(s, s, d)
+    # The two train cells' attention calls: ``train_1chip``'s and
+    # ``train_fsdp4``'s a chip, both GQA. Checked against the float32
+    # reference, then each kernel timed alone with the schedule
+    # ``flash_block_steps`` counts (skipped | interior | diagonal block
+    # steps a (batch, head), the share of the run steps' sub-tiles the
+    # backward multiplies) and its TFLOP/s over the CAUSAL work: 2, 3 and
+    # 4 products of the s (s + 1) / 2 live scores.
+    shapes = [(1, 256, 2, 1)] if rehearse else \
+        [(4, 2048, 16, 8), (1, 4096, 32, 8)]
+    for b, s, h, hkv in shapes:
+        d = 128
+        q, w = (jax.random.normal(keys[i], (b, s, h, d), jnp.float32)
+                .astype(bf16) for i in (0, 3))
+        k, v = (jax.random.normal(keys[i], (b, s, hkv, d), jnp.float32)
+                .astype(bf16) for i in (1, 2))
+        assert flash_applicable(s, s, d)
 
-    def flash_loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True)
-                       .astype(jnp.float32) * w.astype(jnp.float32))
+        def flash_loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, causal=True)
+                           .astype(jnp.float32) * w.astype(jnp.float32))
 
-    def ref_loss(q, k, v):
-        return jnp.sum(mha_reference(q, k, v, causal=True)
-                       .astype(jnp.float32) * w.astype(jnp.float32))
+        def ref_loss(q, k, v):
+            return jnp.sum(mha_reference(q, k, v, causal=True)
+                           .astype(jnp.float32) * w.astype(jnp.float32))
 
-    fwd = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
-    bwd = jax.jit(jax.grad(flash_loss, argnums=(0, 1, 2)))
-    if not rehearse:
-        n_fwd = _mosaic_calls(fwd.lower(q, k, v).compile())
-        n_bwd = _mosaic_calls(bwd.lower(q, k, v).compile())
-        _say(phase, f"flash: {n_fwd} Mosaic call(s) forward, {n_bwd} in "
-                    "the gradient program")
-        assert n_fwd >= 1 and n_bwd >= 3
-    with jax.default_matmul_precision("highest"):
-        ref_out = jax.jit(
-            lambda q, k, v: mha_reference(q, k, v, causal=True))(q, k, v)
-        ref_g = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v)
-    _check(phase, f"flash forward [{b},{s},{h},{d}] bf16",
-           fwd(q, k, v), ref_out, TOLERANCE["flash_fwd"])
-    for name, g, rg in zip(("dq", "dk", "dv"), bwd(q, k, v), ref_g):
-        _check(phase, f"flash backward {name}", g, rg,
-               TOLERANCE["flash_bwd"])
+        fwd = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
+        bwd = jax.jit(jax.grad(flash_loss, argnums=(0, 1, 2)))
+        if not rehearse:
+            n_fwd = _mosaic_calls(fwd.lower(q, k, v).compile())
+            n_bwd = _mosaic_calls(bwd.lower(q, k, v).compile())
+            _say(phase, f"flash: {n_fwd} Mosaic call(s) forward, {n_bwd} in "
+                        "the gradient program")
+            assert n_fwd >= 1 and n_bwd >= 3
+        with jax.default_matmul_precision("highest"):
+            ref_out = jax.jit(
+                lambda q, k, v: mha_reference(q, k, v, causal=True))(q, k, v)
+            ref_g = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v)
+        tag = f"[{b},{s},{h}/{hkv},{d}] bf16"
+        _check(phase, f"flash forward {tag}",
+               fwd(q, k, v), ref_out, TOLERANCE["flash_fwd"])
+        for name, g, rg in zip(("dq", "dk", "dv"), bwd(q, k, v), ref_g):
+            _check(phase, f"flash backward {name} {tag}", g, rg,
+                   TOLERANCE["flash_bwd"])
+
+        block = min(1024, s)
+        skipped, interior, diagonal, share = flash_block_steps(
+            s, s, block, block)
+        _say(phase, f"flash {tag}: {skipped} | {interior} | {diagonal} block "
+                    f"steps skipped | interior | diagonal of "
+                    f"{(s // block) ** 2}, the backward multiplies "
+                    f"{share:.3f} of a run step's sub-tiles")
+        products = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
+        ms = _kernel_ms(bwd, (q, k, v), products)
+        for name, n in products.items():
+            if name not in ms:
+                _say(phase, f"{name} {tag}: not measured (no device trace)")
+                continue
+            flops = n * 2 * b * h * (s * (s + 1) // 2) * d
+            _say(phase, f"{name} {tag}: {ms[name]:.3f} ms a call, "
+                        f"{flops / ms[name] / 1e9:.1f} TFLOP/s over the "
+                        "causal work")
 
     # -- decode attention: paged bf16, paged int8 --------------------------
     # MHA at OLMoE's serving cell (16 KV heads, a query group of ONE, 48

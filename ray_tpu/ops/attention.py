@@ -87,6 +87,106 @@ def _column(row):
     return jnp.broadcast_to(row, (128, row.shape[1])).T[:, :1]
 
 
+# A block step's body depends on where the block stands to the causal
+# diagonal, which the kernel knows from its grid position (row ``r`` sees
+# the columns up to ``r + offs``; ``offs = sk - sq`` aligns the mask
+# bottom-right, as mha_reference's tril(k=sk-sq), so sq != sk works).
+# ABOVE the diagonal nothing runs, and no block is fetched for the
+# backward kernels. INTERIOR, every score live: no mask is built. ON it:
+# the backward kernels, which the MXU paces, cut the block ``_DIAG_SPLIT``
+# x ``_DIAG_SPLIT`` and multiply only the sub-tiles that hold a live
+# score, masking those the diagonal crosses; the forward, which its
+# softmax chain paces (max before exp before the second product), takes
+# the block whole under a mask the compiler folds. Operands enter the
+# products in the inputs' dtype (bf16: one MXU pass, float32 out); max,
+# sum, exp and the accumulators are float32; ``scale`` rides in the
+# multiply an exp has anyway: exp(scale s - m) = exp2((s - m') scale
+# log2 e).
+_DIAG_SPLIT = 4
+_LOG2E = math.log2(math.e)
+_NT = (((1,), (1,)), ((), ()))        # a @ b.T, float32 out
+
+
+def _causal_step(iq, ik, block_q, block_k, offs):
+    """``(run, interior)`` of block step ``(iq, ik)``: whether any of its
+    scores is live, and whether all are. Ints or traced scalars."""
+    first_row, first_col = iq * block_q + offs, ik * block_k
+    return (first_col <= first_row + (block_q - 1),
+            first_col + (block_k - 1) <= first_row)
+
+
+def _aligned(block_q, block_k, offs):
+    """Whether the diagonal runs through the corners of the blocks it
+    crosses (square blocks, ``offs`` a whole number of them): the mask of
+    such a block is static."""
+    return block_q == block_k and offs % block_k == 0
+
+
+def _diag_split(block_q, block_k, offs):
+    """Sub-tiles a side that the backward kernels cut a block ON the
+    diagonal into: the cut is static, so the block is aligned, and a
+    sub-tile is whole 128-lane tiles; else 1."""
+    return _DIAG_SPLIT if (_aligned(block_q, block_k, offs)
+                           and block_k % (128 * _DIAG_SPLIT) == 0) else 1
+
+
+def _block_step(step, iq, ik, *, causal, block_q, block_k, offs, n=1,
+                by_rows=True):
+    """Run ``step(bands, shift)`` over the live part of block step ``(iq,
+    ik)``. ``bands`` lists ``(band, parts)``: a slice of the block's rows
+    (``by_rows``; else of its columns) and the slices of the other axis it
+    is multiplied with, the sub-tiles under the diagonal as ONE part.
+    With a ``shift`` a band's LAST part is crossed by the diagonal: its
+    row ``r`` sees its columns up to ``r + shift`` (0 where the diagonal
+    runs through the block's corner, else traced, the block one piece)."""
+    own, other = (block_q, block_k) if by_rows else (block_k, block_q)
+    t, u = own // n, other // n
+
+    def band(i):
+        under = slice(0, i * u) if by_rows else slice((i + 1) * u, other)
+        return (slice(i * t, (i + 1) * t),
+                ([under] if under.start < under.stop else [])
+                + [slice(i * u, (i + 1) * u)])
+
+    whole = [(slice(0, own), [slice(0, other)])]
+    if not causal:
+        return step(whole, None)
+    run, interior = _causal_step(iq, ik, block_q, block_k, offs)
+    shift = (0 if _aligned(block_q, block_k, offs)
+             else iq * block_q + offs - ik * block_k)
+    pl.when(interior)(lambda: step(whole, None))
+    pl.when(jnp.logical_and(run, jnp.logical_not(interior)))(
+        lambda: step([band(i) for i in range(n)], shift))
+
+
+def _scores(a, b, shift, q_axis=0):
+    """Float32 ``a @ b.T``, the dead scores at the mask value: queries lie
+    along ``q_axis`` and query ``r`` sees keys up to ``r + shift``."""
+    s = jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+    if shift is None:
+        return s
+    q = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(q + shift >= k, s, DEFAULT_MASK_VALUE)
+
+
+def flash_block_steps(sq, sk, block_q, block_k, causal=True):
+    """The kernels' schedule a (batch, head), which is static: ``(skipped,
+    interior, diagonal, share)``: the block steps above, under and on the
+    causal diagonal, and the share of the RUN steps' sub-tiles that the
+    backward kernels multiply (the forward multiplies a run step whole).
+    1 | 1 | 2 of 4 at 2048 and 6 | 6 | 4 of 16 at 4096 in 1024-blocks."""
+    steps = [_causal_step(iq, ik, block_q, block_k, sk - sq) if causal
+             else (True, True)
+             for iq in range(sq // block_q) for ik in range(sk // block_k)]
+    interior = sum(i for _, i in steps)
+    diagonal = sum(r and not i for r, i in steps)
+    n = _diag_split(block_q, block_k, sk - sq)      # n (n + 1) / 2 of n x n
+    share = (interior + diagonal * (n + 1) / (2 * n)) / max(
+        interior + diagonal, 1)
+    return len(steps) - interior - diagonal, interior, diagonal, share
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, scale, causal, block_q, block_k, num_k_blocks, offs):
     iq, ik = pl.program_id(2), pl.program_id(3)
@@ -97,38 +197,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # For causal masks, k-blocks strictly above the diagonal contribute
-    # nothing. `offs = sk - sq` aligns the mask bottom-right (matching
-    # mha_reference's tril(k=sk-sq)) so sq != sk decode/chunked shapes work.
-    run = (ik * block_k <= iq * block_q + block_q - 1 + offs) if causal else True
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # [bq, d]
-        k = k_ref[0, 0].astype(jnp.float32)                  # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                    # [bq, bk]
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = (iq * block_q + rows + offs) >= (ik * block_k + cols)
-            s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
-
+    def step(_, shift):                                      # the block whole
+        v = v_ref[0, 0]                                      # [bk, d]
+        s = _scores(q_ref[0, 0], k_ref[0, 0], shift)         # [bq, bk], unscaled
         m_prev = m_ref[:, :1]                                # [bq, 1]
         l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                               # [bq, bk]
-        alpha = jnp.exp(m_prev - m_new)                      # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp2((s - m_new) * (scale * _LOG2E))         # [bq, bk]
+        alpha = jnp.exp2((m_prev - m_new) * (scale * _LOG2E))
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-
-        v = v_ref[0, 0].astype(jnp.float32)                  # [bk, d]
-        pv = jnp.dot(p, v, preferred_element_type=jnp.float32)
+        pv = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         acc_ref[:] = acc_ref[:] * alpha + pv
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    _block_step(step, iq, ik, causal=causal, block_q=block_q,
+                block_k=block_k, offs=offs)
 
     @pl.when(ik == num_k_blocks - 1)
     def _finalize():
@@ -136,7 +220,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         # Fully-masked rows (possible with padding) have l == 0; emit zeros.
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-        lse_ref[0, 0] = _row(m_ref[:, :1] + jnp.log(safe_l))
+        lse_ref[0, 0] = _row(m_ref[:, :1] * scale + jnp.log(safe_l))
 
 
 def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
@@ -164,6 +248,9 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
         pltpu.VMEM((block_q, 128), jnp.float32),
         pltpu.VMEM((block_q, 128), jnp.float32),
     ]
+    _, interior, diagonal, _ = flash_block_steps(sq, sk, block_q, block_k,
+                                                 causal)
+    scores = b * hq * (interior + diagonal) * block_q * block_k    # it runs
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -177,9 +264,9 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
         interpret=interpret,
         name="flash_fwd",
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * hq * sq * sk * d // (2 if causal else 1),
+            flops=4 * scores * d,
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
-            transcendentals=b * hq * sq * sk,
+            transcendentals=scores,
         ),
     )(q, k, v)
     return out, lse
@@ -193,29 +280,23 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    run = (ik * block_k <= iq * block_q + block_q - 1 + offs) if causal else True
+    def step(bands, shift):
+        for rows, parts in bands:
+            q, do = q_ref[0, 0, rows], do_ref[0, 0, rows]     # [tq, d]
+            lse = _column(lse_ref[0, 0, :, rows]) * _LOG2E    # [tq, 1]
+            delta = _column(delta_ref[0, 0, :, rows])
+            for cols in parts:
+                k, v = k_ref[0, 0, cols], v_ref[0, 0, cols]   # [tk, d]
+                s = _scores(q, k, shift if cols is parts[-1] else None)
+                p = jnp.exp2(s * (scale * _LOG2E) - lse)      # [tq, tk]
+                dp = jax.lax.dot_general(
+                    do, v, _NT, preferred_element_type=jnp.float32)
+                ds = (p * (dp - delta)).astype(k.dtype)
+                acc_ref[rows] += jnp.dot(
+                    ds, k, preferred_element_type=jnp.float32)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)                 # [bq, d]
-        lse = _column(lse_ref[0, 0])                          # [bq, 1]
-        delta = _column(delta_ref[0, 0])
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = (iq * block_q + rows + offs) >= (ik * block_k + cols)
-            s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse)                                  # [bq, bk]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)                                 # [bq, bk]
-        acc_ref[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+    _block_step(step, iq, ik, causal=causal, block_q=block_q, block_k=block_k,
+                offs=offs, n=_diag_split(block_q, block_k, offs))
 
     @pl.when(ik == num_k_blocks - 1)
     def _finalize():
@@ -232,40 +313,33 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = (iq * block_q + block_q - 1 + offs >= ik * block_k) if causal else True
+    # Scores, p and ds are made TRANSPOSED, [tk, tq]: the two products
+    # that contract over queries then take them as they are (no turn of a
+    # [tq, tk] tile through the transpose unit), and lse and delta are
+    # used as the rows they arrive as.
+    def step(bands, shift):
+        for cols, parts in bands:
+            k, v = k_ref[0, 0, cols], v_ref[0, 0, cols]       # [tk, d]
+            for rows in parts:
+                q, do = q_ref[0, 0, rows], do_ref[0, 0, rows]  # [tq, d]
+                lse = lse_ref[0, 0, :, rows] * _LOG2E          # [1, tq]
+                delta = delta_ref[0, 0, :, rows]
+                s = _scores(k, q, shift if rows is parts[-1] else None, 1)
+                p = jnp.exp2(s * (scale * _LOG2E) - lse)       # [tk, tq]
+                dp = jax.lax.dot_general(
+                    v, do, _NT, preferred_element_type=jnp.float32)
+                ds = (p * (dp - delta)).astype(q.dtype)
+                dv_acc[cols] += jnp.dot(p.astype(do.dtype), do,
+                                        preferred_element_type=jnp.float32)
+                dk_acc[cols] += jnp.dot(ds, q,         # `scale`: finalize
+                                        preferred_element_type=jnp.float32)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale           # [bq, d]
-        k = k_ref[0, 0].astype(jnp.float32)                   # [bk, d]
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = _column(lse_ref[0, 0])                          # [bq, 1]
-        delta = _column(delta_ref[0, 0])
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = (iq * block_q + rows + offs) >= (ik * block_k + cols)
-            s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse)                                  # [bq, bk]
-        # dv += p^T @ do
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)                                 # [bq, bk]
-        # dk += ds^T @ q  (q already carries `scale`)
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _block_step(step, iq, ik, causal=causal, block_q=block_q, block_k=block_k,
+                offs=offs, n=_diag_split(block_q, block_k, offs), by_rows=False)
 
     @pl.when(iq == num_q_blocks - 1)
     def _finalize():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0, 0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
@@ -282,9 +356,22 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
                     axis=-1)[:, :, None, :]
 
+    # A step above the diagonal asks for the block its row's last run step
+    # held (its column's first): an index that does not move fetches
+    # nothing, where a skipped step's 0.5 MB of blocks took longer than
+    # the step.
+    def last_k(i, j):
+        return jnp.minimum(j, (i * block_q + block_q - 1 + sk - sq)
+                           // block_k) if causal else j
+
+    def first_q(j, i):
+        return jnp.maximum(i, (j * block_k - (sk - sq)) // block_q
+                           ) if causal else i
+
     q_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j: (b_, h, i, 0))
-    kv_spec_dq = pl.BlockSpec((1, 1, block_k, d),
-                              lambda b_, h, i, j: (b_, h // group, j, 0))
+    kv_spec_dq = pl.BlockSpec(
+        (1, 1, block_k, d),
+        lambda b_, h, i, j: (b_, h // group, last_k(i, j), 0))
     row_spec = pl.BlockSpec((1, 1, 1, block_q), lambda b_, h, i, j: (b_, h, 0, i))
 
     dq = pl.pallas_call(
@@ -303,12 +390,14 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
     # dk/dv: grid over q-heads; each q-head contributes to its kv head. To
     # keep the accumulation race-free we compute per-q-head dk/dv and sum the
     # group afterwards (cheap: [b, hq, sk, d] f32 intermediate).
-    q_spec2 = pl.BlockSpec((1, 1, block_q, d), lambda b_, h, j, i: (b_, h, i, 0))
+    q_spec2 = pl.BlockSpec((1, 1, block_q, d),
+                           lambda b_, h, j, i: (b_, h, first_q(j, i), 0))
     kv_spec2 = pl.BlockSpec((1, 1, block_k, d),
                             lambda b_, h, j, i: (b_, h // group, j, 0))
     kv_out_spec = pl.BlockSpec((1, 1, block_k, d),
                                lambda b_, h, j, i: (b_, h, j, 0))
-    row_spec2 = pl.BlockSpec((1, 1, 1, block_q), lambda b_, h, j, i: (b_, h, 0, i))
+    row_spec2 = pl.BlockSpec((1, 1, 1, block_q),
+                             lambda b_, h, j, i: (b_, h, 0, first_q(j, i)))
 
     dk_ph, dv_ph = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,

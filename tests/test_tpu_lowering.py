@@ -93,6 +93,44 @@ def test_flash_statistics_cross_hbm_as_rows(hq, hkv):
     assert "x2048x1xf32>" not in module
 
 
+def test_flash_kernels_feed_the_mxu_bf16_and_mask_only_the_diagonal(
+        monkeypatch):
+    """The Mosaic text of the three kernels at ``train_fsdp4``'s shape.
+    Every product takes bf16 operands when the inputs are bf16 (float32
+    out): an upcast operand is a pass of the vector unit, which has no
+    bf16 lanes on a v5e. And a kernel is four predicated bodies, init |
+    interior | diagonal | finalize: the interior one, every score live,
+    builds no mask (no iota), the diagonal one does, and the backward
+    kernels cut a diagonal block 4 x 4, ten products of sixteen (PR 56)."""
+    from jax._src import tpu_custom_call
+
+    modules = []
+    real = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def spy(module, **kw):
+        modules.append(str(module))
+        return real(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", spy)
+    q = S((1, 4096, 32, 128), BF16)
+    kv = S((1, 4096, 8, 128), BF16)
+    jax.export.export(jax.jit(jax.grad(_flash_sum, argnums=(0, 1, 2))),
+                      platforms=["tpu"])(q, kv, kv)
+    assert len(modules) == 3                        # fwd, dq, dkv
+    # (products a live piece, pieces of a diagonal block)
+    for module, (products, pieces) in zip(modules, [(2, 1), (3, 7), (4, 7)]):
+        matmuls = [l for l in module.splitlines() if "tpu.matmul" in l]
+        for line in matmuls:
+            operands = re.findall(r"vector<[0-9x]+x(\w+)>", line.split(" : ")[1])
+            assert operands == ["bf16", "bf16", "f32", "f32"], line
+        init, interior, diagonal, finalize = module.split("scf.if")[1:]
+        assert [b.count("tpu.matmul") for b in (
+            init, interior, diagonal, finalize)] == [
+                0, products, products * pieces, 0]
+        assert "iota" not in interior
+        assert "iota" in diagonal
+
+
 @pytest.mark.parametrize("hq,hkv", HEADS)
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 @pytest.mark.parametrize("layers", [None, 16])
