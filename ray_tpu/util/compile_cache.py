@@ -6,6 +6,11 @@ second process into a file read. The directory is part of the cache key,
 so it must not move: if ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
 and nothing here overrides it; otherwise the cache lives at ONE fixed,
 git-ignored path inside the checkout. No other code sets a directory.
+
+What is cached must also be FOUND again: a program's key is made of its
+lowered text, and :func:`ensure` keeps source positions out of that text
+(see there), so an edit that moves lines re-keys nothing it did not
+change.
 """
 
 from __future__ import annotations
@@ -43,6 +48,13 @@ def ensure() -> str:
         if not _listening:
             jax.monitoring.register_event_listener(_on_event)
             _listening = True
+    # A Mosaic kernel's body is serialized into the program WITH its
+    # operations' locations, and is part of the cache key. JAX writes
+    # file, line and column of up to this many caller frames into every
+    # location (10 by default): with any, one line added above any
+    # kernel's call re-keys every program that holds a kernel, in every
+    # cell (PR 53: 25 of 210 programs, 80 s of set-up become 300).
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

@@ -8,6 +8,7 @@ import os
 import time
 import urllib.request
 
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -68,6 +69,28 @@ def test_aot_compile_goes_through_the_persistent_cache():
     after = compile_cache.counts()
     assert after["hits"] == before["hits"] + 1, (before, after)
     assert after["misses"] == before["misses"]
+
+
+def test_a_lowered_kernel_call_names_no_source_line(pallas_interpret):
+    """What the persistent cache keys a program by holds no source
+    position (``compile_cache.ensure``): the decode tick's lowered text,
+    kernels interpreted, debug info ON, names no line of the file it was
+    traced in, so an edit that moves lines there re-keys no program."""
+    from ray_tpu.models import llama
+    from ray_tpu.models.continuous_batching import ContinuousBatcher
+    from ray_tpu.util import compile_cache
+
+    compile_cache.ensure()
+    assert jax.config.jax_traceback_in_locations_limit == 0
+    eng = ContinuousBatcher(llama.LlamaConfig.tiny(dtype=jnp.float32),
+                            num_slots=2, max_len=32, block_size=8,
+                            use_decode_kernel=True)
+    row = jnp.zeros(2, jnp.int32)
+    text = eng._tick.lower(
+        eng.params, row, row, jnp.zeros((2, eng.max_blocks), jnp.int32), row,
+        eng.cache, jnp.int32(0)).as_text(debug_info=True)
+    assert "paged_decode_attn" in text          # debug info is there
+    assert "continuous_batching.py" not in text and ".py\":" not in text
 
 
 # -------------------------------------------------- retrace detection
