@@ -2352,19 +2352,18 @@ def phase_loop(rehearse: bool) -> None:
     def answers(config, sets, weights=None):
         """Each request alone through the engine: its tokens, and the
         gates ``[T, ticks]`` its ticks fetched."""
-        eng = cb.ContinuousBatcher(config, **work["engine"])
+        seen = []
+
+        class Spied(cb.ContinuousBatcher):
+            def _account_tick(self, tick_fn, tick, k):
+                slot = tick["members"][0][0]
+                seen.append(np.asarray(tick["row"][self.num_slots:]).view(
+                    np.float32).reshape(-1, self.num_slots)[:, slot])
+                super()._account_tick(tick_fn, tick, k)
+
+        eng = Spied(config, **work["engine"])
         if weights is not None:
             eng.params = weights(eng.params)
-        seen = []
-        book = eng._account_tick
-
-        def spy(tick_fn, tick, k):
-            slot = tick["members"][0][0]
-            seen.append(np.asarray(tick["row"][eng.num_slots:]).view(
-                np.float32).reshape(-1, eng.num_slots)[:, slot])
-            book(tick_fn, tick, k)
-
-        eng._account_tick = spy
         out = []
         for reqs in sets:
             recs = []

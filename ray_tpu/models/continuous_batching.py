@@ -58,7 +58,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import xla_monitor
-from ray_tpu.models import cca, eva, gated_delta, llama, mamba2
+from ray_tpu.models import (cca, eva, gated_delta, llama, looped, mamba1,
+                            mamba2)
 from ray_tpu.models import paged_kv
 from ray_tpu.models.inference import (ExternalLlamaDrafter, KVCache,
                                       SelfDrafter, _attend_cached,
@@ -322,7 +323,10 @@ _KIND_NAMES = {"mamba": "state-space", "mamba1": "selective-scan",
                "sliding_attention": "sliding-window",
                "latent_attention": "latent-attention",
                "eva_attention": "eva-attention",
-               "cca_attention": "cca-attention"}
+               "cca_attention": "cca-attention",
+               # Not a layer kind: the whole stack applied ``loop_steps``
+               # times (``models/looped.py``).
+               "looped": "a looped layer stack"}
 # A recurrent layer (Mamba-2, Mamba-1 or Gated DeltaNet) keeps, not K/V, a
 # state a slot that only moves FORWARD and belongs to one request.
 _RECURRENT_CANNOT = {
@@ -407,32 +411,70 @@ _KIND_CANNOT = {
         "score_logprobs": "it runs llama.forward, the training forward, "
                           "which this serving-only family has none of",
     },
+    # ``loop_steps > 1`` (``models/looped.py``): a K/V row for every
+    # (step, layer) pair. Each capability either runs every step or is
+    # refused here; none runs one pass of the stack silently, which would
+    # be another model's output under this model's name. The last three
+    # are services outside the engine (:func:`refuse_one_pass`).
+    "looped": {
+        "kv_dtype": "the arena's rows are held to the float32 reference as "
+                    "bf16 only: an 8-bit arena under four passes of the "
+                    "stack is a different model output until it is measured",
+        "speculative": "the self-draft runs the first layers of ONE pass, "
+                       "which is no shallow copy of a model that applies its "
+                       "stack loop_steps times, and the verify programs run "
+                       "one pass",
+        "handoff": "the KV handoff sizes and checks its payload by "
+                   "num_layers, and a looped arena has loop_steps x "
+                   "num_layers rows",
+        "score_logprobs": "it runs llama.forward, the training forward, "
+                          "which does not run the loop",
+        "llama.forward": "the training forward (and loss_fn on it) runs the "
+                         "stack once; the loop's objective also needs a "
+                         "weight on the exit entropy that the published "
+                         "config does not give",
+        "LlamaGenerator": "the dense-cache generator keeps num_layers cache "
+                          "rows and runs the stack once",
+        "ExternalLlamaDrafter": "a drafter's dense cache keeps num_layers "
+                                "rows and its forward runs the stack once",
+    },
 }
 
 
 def _refuse_unsupported(config, asked: Dict[str, str]) -> None:
     """Raise for the first of ``asked`` ({capability: the caller's name})
-    that ``config`` cannot have: :data:`_KIND_CANNOT`, ``LOOP_CANNOT``."""
-    if config.loop_steps > 1:
-        from ray_tpu.models import looped
-        looped.refuse(asked)
+    that ``config`` cannot have: :data:`_KIND_CANNOT`."""
     kinds = set(config.layer_types)
+    if config.loop_steps > 1:
+        kinds.add("looped")
     for kind, cannot in _KIND_CANNOT.items():
         if kind not in kinds:
             continue
-        beside = kinds - {kind}     # (the arena's own layers sit beside any)
+        # (The arena's own layers sit beside any; the loop is no layer.)
+        beside = kinds - {kind, "looped"}
         if kind not in ("latent_attention", "eva_attention",
                         "cca_attention"):
             beside -= {"attention", "full_attention"}
         wants = dict(asked)
         if beside:
             wants["second_kind"] = "another layer kind in the same stack"
+        said = (f"{_KIND_NAMES[kind]} (loop_steps > 1)" if kind == "looped"
+                else f"{_KIND_NAMES[kind]} layers (layer_types has {kind!r})")
         for capability, why in cannot.items():
             if capability in wants:
-                raise ValueError(
-                    f"{wants[capability]} is not supported for a model "
-                    f"with {_KIND_NAMES[kind]} layers (layer_types has "
-                    f"{kind!r}): {why}")
+                raise ValueError(f"{wants[capability]} is not supported for "
+                                 f"a model with {said}: {why}")
+
+
+def refuse_one_pass(config: llama.LlamaConfig, service: str) -> None:
+    """``service`` (a name of :data:`_KIND_CANNOT`'s "looped" entry) runs
+    the layer stack once: raise, naming it, for a config that loops."""
+    if config.loop_steps > 1:
+        raise NotImplementedError(
+            f"{service} does not run a model with a looped layer stack "
+            f"(loop_steps = {config.loop_steps}): "
+            f"{_KIND_CANNOT['looped'][service]}; the continuous-batching "
+            "engine serves it")
 
 
 _KIND_SCOPES = {"sliding_attention": "attn/window",
@@ -570,7 +612,8 @@ def _forward_paged(params, tokens, positions, tables, limits,
     (:func:`llama.layer_runs`; a homogeneous model is one run). A
     "mamba" layer (S = 1 only) mixes through
     :func:`mamba2.mixer_step`, a "linear_attention" layer through
-    :func:`gated_delta.mixer_step`; either advances every slot's row of
+    :func:`gated_delta.mixer_step`, a "mamba1" layer through
+    :func:`mamba1.mixer_step`; each advances every slot's row of
     the per-slot state cache beside the arena in place; the arena holds the
     attention layers alone, so each kind indexes its own cache by its
     index among layers of its kind. A "cca_attention" layer (S = 1 only)
@@ -579,9 +622,16 @@ def _forward_paged(params, tokens, positions, tables, limits,
     advances. ``caches`` is the arena, or the pair (arena, state cache)
     for a model with state layers.
 
+    A LOOPED stack (``loop_steps > 1``, ``models/looped.py``) runs the
+    scans ``loop_steps`` times over the same weights, the final norm
+    after every pass; pass ``t`` writes and attends rows ``t x L ..`` of
+    the arena.
+
     Returns (fp32 logits [B, S, V] through the final norm + lm_head,
-    the updated ``caches``, and a routed model's per-layer per-expert
-    row counts [L, X], None for a dense model)."""
+    the updated ``caches``, and what a plain tick's row carries behind
+    its tokens: a routed model's per-layer per-expert row counts [L, X],
+    a looped stack's exit gates [T, B, S] (:func:`looped.gate_bits`),
+    None for any other model)."""
     c = config
     cache, state = _split_caches(caches)
     bs = cache.block_size
@@ -635,6 +685,11 @@ def _forward_paged(params, tokens, positions, tables, limits,
             mixed, *held = gated_delta.mixer_step(h, layer, c, *held, ki,
                                                   use_kernel)
             held = tuple(held)
+        elif kind == "mamba1":
+            h = llama.norm(x, layer["attn_norm"], c).astype(c.dtype)
+            mixed, *held = mamba1.mixer_step(h, layer, c, *held, ki,
+                                             use_kernel)
+            held = tuple(held)
         elif kind == "latent_attention":
             mixed, latents = _mla().tick_layer(
                 x, layer, c, arenas[0], ki, cos, sin, block_idx, offset,
@@ -684,12 +739,19 @@ def _forward_paged(params, tokens, positions, tables, limits,
 
     arenas, held = tuple(cache), tuple(state or ())
     route = _route_carry(c, tokens)
-    rows = []
-    for (kind, start, _, kind_start), tree in runs:
-        (x, arenas, held, route, _), run_rows = jax.lax.scan(
-            functools.partial(layer_fn, kind=kind, shift=kind_start - start),
-            (x, arenas, held, route, jnp.int32(start)), tree)
-        rows.append(run_rows)
+    rows, gates = [], []
+    for step in range(c.loop_steps):        # one pass, but for a looped stack
+        if step:        # between two passes: the final norm, and the gate
+            x = looped.step_end(x, params, c)
+            gates.append(looped.exit_gate(x, params))
+        with looped.step_scope(c, step):
+            for (kind, start, _, kind_start), tree in runs:
+                (x, arenas, held, route, _), run_rows = jax.lax.scan(
+                    functools.partial(
+                        layer_fn, kind=kind,
+                        shift=kind_start - start + step * c.attn_layers),
+                    (x, arenas, held, route, jnp.int32(start)), tree)
+                rows.append(run_rows)
     if c.eva_window:
         # The rows whose new key filled its window: pool the window into
         # its summaries, in place (one run: no second kind beside EVA).
@@ -699,13 +761,15 @@ def _forward_paged(params, tokens, positions, tables, limits,
                 *arenas[:2], tables, true_positions[:, 0],
                 positions[:, 0] < limits, pooling["eva_phi"],
                 pooling["eva_mu"], c) + arenas[2:]
-    x = llama.norm(x, params["final_norm"], c)
+    x = looped.step_end(x, params, c)       # the final norm
+    if gates:
+        gates.append(looped.exit_gate(x, params))
     # lm_head in the params' storage dtype with fp32 accumulation
     # (shared with prefill): bf16 params are never upcast in HBM.
     logits = lm_head_logits(x, params, c)
     cache = type(cache)(*arenas)
     return (logits, (cache, type(state)(*held)) if held else cache,
-            _concat_runs(rows))
+            looped.gate_bits(gates) if gates else _concat_runs(rows))
 
 
 def _draft_forward_dense(dparams, tokens, positions, dcache: KVCache,
@@ -824,7 +888,8 @@ def _decode_tick_paged(params, tokens, positions, tables, limits,
     if rows is None:
         return state
     # A routed model's tick also reports each layer's per-expert row
-    # counts [L, X], packed BEHIND the token vector so the host's one
+    # counts [L, X], a looped stack's every slot's gate of every step
+    # [T, B, 1], packed BEHIND the token vector so the host's one
     # fetch a tick brings both (a second array would be a second sync).
     return state + (jnp.concatenate([next_tokens, rows.reshape(-1)]),)
 
@@ -847,7 +912,7 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                            quantized, last_idx, use_kernel=None,
                            state=None, slots=None,
                            paged: Optional[_PagedPrefix] = None,
-                           land=None):
+                           land=None, dense=False):
     """Prefill forward over ``[shared prefix ++ suffix]``.
 
     ``tokens`` [N, S] are the suffix at absolute ``positions`` [S]
@@ -886,12 +951,21 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     and its K/V are the arena's like a full-attention layer's); and
     ``stored`` comes back as ``{cache kind: K/V of the layers that keep
     theirs there}`` (``"attention"``: the arena; ``"sliding_attention"``:
-    the ring). An EVA-attention model's layers land their own blocks
-    in the arena through ``land [N, S / bs]`` as they go, the arena
-    riding the layer loop's carry (stacked as the loop's output, a
-    4-row chunk's raw keys of 8 layers would be a gigabyte that the
-    arena mostly never takes): the arena comes back in ``state``'s
-    place."""
+    the ring). A "mamba1" layer carries its state and conv tail from
+    chunk to chunk as a "linear_attention" layer does.
+
+    With ``land [N, S / bs]`` as well (an EVA-attention model, a looped
+    stack) the layers land their own blocks in the arena through it as
+    they go, the arena riding the layer loop's carry (stacked as the
+    loop's output, a 4-row EVA chunk's raw keys of 8 layers would be a
+    gigabyte that the arena mostly never takes, and the 192 rows of an
+    8 x 256 looped batch 3 GB beside an arena that fills the chip): the
+    arena comes back in ``state``'s place. A looped stack runs the scans
+    ``loop_steps`` times, the final norm between the passes; in pass
+    ``t`` layer ``l`` attends the earlier keys of ROW ``t x L + l`` and
+    its own, blockwise where they lie or, ``dense`` (the chunk's queries
+    see few enough keys: ``PREFILL_DENSE_KEYS``), all at once in float32
+    over the row's gathered blocks."""
     c = config
     cos, sin = _rope_tables(c, tokens.shape[1], positions)
     x = _embed(params, tokens, c)
@@ -900,7 +974,7 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
     runs, experts = llama.layer_runs(c, params)
 
     def layer_fn(carry, inputs, kind, shift):
-        x, held, route, li = carry
+        x, arenas, held, route, li = carry
         kept = ()
         if kind == "mamba":
             layer, = inputs
@@ -917,6 +991,16 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                        if paged.tables.shape[1] else None)
             mixed, *new = gated_delta.mixer_prefill(
                 h, layer, c, last_idx + 1, carried)
+            held = tuple(a.at[li + shift, slots].set(n.astype(a.dtype))
+                         for a, n in zip(held, new))
+        elif kind == "mamba1":
+            layer, = inputs
+            h = llama.norm(x, layer["attn_norm"], c).astype(c.dtype)
+            carried = (tuple(a[li + shift, slots] for a in held)
+                       if paged.tables.shape[1] else ())
+            mixed, *new = mamba1.mixer_prefill(
+                h, layer, c, last_idx + 1, *carried,
+                use_kernel=use_kernel or None)
             held = tuple(a.at[li + shift, slots].set(n.astype(a.dtype))
                          for a, n in zip(held, new))
         elif kind == "latent_attention":
@@ -940,15 +1024,15 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                 q, k, v, _ = _layer_qkv(x, layer, cos, sin, c)
             with jax.named_scope("eva/attend"):
                 o = paged_chunk_attention(
-                    q, k, v, *held, li + shift, paged.tables, 0,
+                    q, k, v, *arenas, li + shift, paged.tables, 0,
                     paged.tables.shape[1] * bs, scale)
             with jax.named_scope("eva/summarise"):
                 blocks = eva.chunk_blocks(
                     k, v, layer["eva_phi"], layer["eva_mu"],
                     last_idx == c.eva_window - 1, c, bs)
-                held = tuple(
+                arenas = tuple(
                     a.at[li + shift, land.reshape(-1)].set(b.astype(a.dtype))
-                    for a, b in zip(held, blocks))
+                    for a, b in zip(arenas, blocks))
             with jax.named_scope("eva/out_proj"):
                 mixed = _attn_out(o.astype(c.dtype), layer, c)
         elif kind == "cca_attention":
@@ -969,6 +1053,30 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                     paged.tables.shape[1] * paged.cache.block_size, scale)
             with jax.named_scope("cca/out_proj"):
                 mixed = _attn_out(o.astype(c.dtype), layer, c)
+        elif c.loop_steps > 1:
+            # A looped stack's layer: ``shift`` has the pass's first row
+            # in it, and both forms read the arena in the carry.
+            layer, = inputs
+            row, bs = li + shift, paged.cache.block_size
+            m = paged.tables.shape[1]
+            q, k, v, gate = _layer_qkv(x, layer, cos, sin, c)
+            if not dense:
+                o = paged_chunk_attention(q, k, v, *arenas, row,
+                                          paged.tables, 0, m * bs, scale)
+            else:
+                ck, cv = k, v
+                if m:       # the earlier keys as attention reads them
+                    pk_l, pv_l = (_blocks_to_ctx(
+                        a[row, paged.tables.reshape(-1)][None],
+                        tokens.shape[0])[0].astype(c.dtype) for a in arenas)
+                    ck = jnp.concatenate([pk_l, k], axis=1)
+                    cv = jnp.concatenate([pv_l, v], axis=1)
+                o = _attend_cached(q, ck, cv, positions, scale)
+            mixed = _attn_out(o, layer, c, gate)
+            arenas = tuple(
+                a.at[row, land.reshape(-1)].set(
+                    _ctx_to_blocks(new[None].astype(a.dtype), bs)[0])
+                for a, new in zip(arenas, (k, v)))
         elif paged is not None:
             layer, = inputs
             with _kind_scope(kind):
@@ -1006,32 +1114,40 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
                               layer, c, gate)
         x, _, route = _layer_finish(x, mixed, layer, c, experts, li,
                                     use_kernel, route)
-        return (x, held, route, li + 1), kept
+        return (x, arenas, held, route, li + 1), kept
 
     stored, held = [], tuple(state or ())
     route = _route_carry(c, tokens)
-    if c.eva_window:
-        held = (paged.cache.k, paged.cache.v)
+    # The arena rides the carry only where the layers land as they go.
+    arenas = () if land is None else (paged.cache.k, paged.cache.v)
     by_kind: Dict[str, list] = {"attention": [], "sliding_attention": []}
-    for (kind, start, count, kind_start), tree in runs:
-        inputs = (tree,)
-        if kind == "attention" and paged is None:
-            whole = count == pk.shape[0]
-            inputs += tuple(a if whole else a[kind_start:kind_start + count]
-                            for a in (pk, pv))
-        (x, held, route, _), kept = jax.lax.scan(
-            functools.partial(layer_fn, kind=kind,
-                              shift=kind_start - start),
-            (x, held, route, jnp.int32(start)), inputs)
-        stored.append(kept or None)
-        if kind not in llama.STATE_KINDS:
-            by_kind["sliding_attention" if kind == "sliding_attention"
-                    else "attention"].append(kept)
+    for step in range(c.loop_steps):        # one pass, but for a looped stack
+        if step:
+            x = looped.step_end(x, params, c)
+        with looped.step_scope(c, step):
+            for (kind, start, count, kind_start), tree in runs:
+                inputs = (tree,)
+                if kind == "attention" and paged is None:
+                    whole = count == pk.shape[0]
+                    inputs += tuple(
+                        a if whole else a[kind_start:kind_start + count]
+                        for a in (pk, pv))
+                (x, arenas, held, route, _), kept = jax.lax.scan(
+                    functools.partial(
+                        layer_fn, kind=kind,
+                        shift=kind_start - start + step * c.attn_layers),
+                    (x, arenas, held, route, jnp.int32(start)), inputs)
+                stored.append(kept or None)
+                if kind not in llama.STATE_KINDS:
+                    by_kind["sliding_attention" if kind == "sliding_attention"
+                            else "attention"].append(kept)
+    # The head reads one position a row: the (last) norm is taken there
+    # alone.
     x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)  # [N, 1, E]
-    x = llama.norm(x, params["final_norm"], c)
+    x = looped.step_end(x, params, c)
     logits = lm_head_logits(x, params, c)
-    if c.eva_window:
-        held = PagedKVCache(*held)
+    if arenas:
+        held = PagedKVCache(*arenas)
     else:
         held = type(state)(*held) if held else None
     if paged is not None:
@@ -1040,7 +1156,8 @@ def _prefill_forward_paged(params, tokens, positions, pk, pv, config,
 
 
 def _prefill_chunk_paged(params, tokens, positions, cache, second, ptables,
-                         tables_w, last_idx, slots, config, use_kernel):
+                         tables_w, last_idx, slots, config, use_kernel,
+                         dense=False):
     """One prefill CHUNK through the paged caches (``cb_prefill``'s body
     for a chunk of a long prompt, and for every prefill of a model with
     sliding-window layers): attends the earlier chunks where they lie
@@ -1052,7 +1169,10 @@ def _prefill_chunk_paged(params, tokens, positions, cache, second, ptables,
     the ring, the state cache of a model with linear-attention layers
     (each row's state and conv tail are read from and written to row
     ``slots[i]``), the tail cache of one with CCA layers (likewise), or
-    None. Returns (logits [N, 1, V], arena, second)."""
+    None. An EVA-attention model's layers and a looped stack's land
+    their own blocks as they go (``dense``: the looped stack's attention
+    form, :func:`_prefill_forward_paged`). Returns (logits [N, 1, V],
+    arena, second)."""
     ring = second if isinstance(second, RingKVCache) else None
     state = second if isinstance(second, (StateCache, TailCache)) else None
     bs = cache.block_size
@@ -1068,12 +1188,13 @@ def _prefill_chunk_paged(params, tokens, positions, cache, second, ptables,
         ring_write = jnp.where(
             real, jnp.take(own, (m + jnp.arange(npb)) % n_ring, axis=1),
             GARBAGE_BLOCK)
+    in_loop = bool(config.eva_window or config.loop_steps > 1)
     logits, stored, state = _prefill_forward_paged(
         params, tokens, positions, None, None, config, False, last_idx,
         use_kernel, state, slots,
         paged=_PagedPrefix(cache, ptables, ring, ring_tables),
-        land=tables_w)
-    if config.eva_window:       # landed layer by layer: the arena itself
+        land=tables_w if in_loop else None, dense=dense)
+    if in_loop:         # landed layer by layer: the arena itself
         return logits, state, None
 
     def land(into, kv, tables):
@@ -1606,7 +1727,7 @@ class ContinuousBatcher:
         use_kernel = self.use_decode_kernel
         sampling_cfg = self.sampling
         block_size_c = self.block_size
-        chunks_state = bool({"linear_attention", "cca_attention"}
+        chunks_state = bool({"linear_attention", "cca_attention", "mamba1"}
                             & set(cfg.layer_types))
         eva_sb = (eva.summaries(cfg) // self.block_size if cfg.eva_window
                   else 0)     # blocks of summaries a closed window keeps
@@ -1665,20 +1786,22 @@ class ContinuousBatcher:
             positions = m * block_size_c + jnp.arange(s_pad)
             if eva_sb:      # true positions: the table's blocks are windows
                 positions = m // eva_sb * cfg.eva_window + jnp.arange(s_pad)
+            dense = m * block_size_c + s_pad <= PREFILL_DENSE_KEYS
             if not cache.quantized and (
                     isinstance(held, RingKVCache) or chunks_state or eva_sb
                     or isinstance(cache, LatentKVCache)
-                    or (held is None
-                        and m * block_size_c + s_pad > PREFILL_DENSE_KEYS)):
+                    or cfg.loop_steps > 1 or (held is None and not dense)):
                 # A long prompt's chunk, sliding-window layers, or
-                # linear-attention or CCA layers (whose chunks carry a
-                # state or a convolution tail):
+                # linear-attention, Mamba-1 or CCA layers (whose chunks
+                # carry a state or a convolution tail):
                 # the earlier keys are read where they lie, blockwise.
                 # (Not a model with Mamba-2 layers, whose prompt is one
                 # piece; nor an int8 arena: both keep the path they had.)
+                # A looped stack's layers read and land their own rows
+                # of the arena in either form.
                 logits, cache, held = _prefill_chunk_paged(
                     params, tokens, positions, cache, held, ptables,
-                    tables_w, last_idx, slots, cfg, use_kernel)
+                    tables_w, last_idx, slots, cfg, use_kernel, dense)
                 first = _next_tokens(logits, pstep, sampling_cfg,
                                      salt=_PREFILL_SALT)
                 return first, (cache if held is None else (cache, held))
@@ -1744,9 +1867,11 @@ class ContinuousBatcher:
         self._tick = tick
         self._merge_tokens = merge_tokens
 
-        if cfg.loop_steps > 1 or cfg.mamba_dt_rank:   # a family's own two
-            from ray_tpu.models import looped, mamba1
-            (looped if cfg.loop_steps > 1 else mamba1).install(self)
+        if cfg.loop_steps > 1:
+            from ray_tpu._private import metrics_defs as mdefs
+
+            mdefs.CB_LOOP_KV_BYTES.set(
+                self.cache.k.nbytes + self.cache.v.nbytes, tags=self._mtags)
         if self.spec_k and self.drafter.external:
             # The drafter's own dense cache: admission prefills the FULL
             # prompt into it, decode advances it inside the spec tick.
@@ -2701,6 +2826,13 @@ class ContinuousBatcher:
             mdefs.CB_EVA_WINDOW_KEYS.observe(
                 sum(st["pos"] % c.eva_window + 1
                     for st in self._slots.values()), tags=self._mtags)
+        if self.config.loop_steps > 1:
+            # The tick's live rows and the steps the program ran for
+            # them, read off the ROW (the gates it carries).
+            rows = len(tick["members"])
+            steps = (len(tick["row"]) - self.num_slots) // self.num_slots
+            mdefs.CB_LOOP_ROWS.inc(rows, tags=self._mtags)
+            mdefs.CB_LOOP_STEPS.inc(rows * steps, tags=self._mtags)
         if self.config.latent_layers:
             # The positions the tick's queries attended, summed over the
             # slots: what the latent kernel must read a layer, in tokens.
@@ -2758,7 +2890,9 @@ class ContinuousBatcher:
         idle_experts = (max(1.0 - self.num_slots * (1 + spec_k)
                             * c.num_experts_per_tok / c.num_experts,
                             0.0) if c.num_experts else 0.0)
+        # (A looped stack streams its layers' weights once a step.)
         total = (self.param_bytes + live_bytes
+                 + (c.loop_steps - 1) * self._layer_param_bytes
                  - int(self._expert_param_bytes * idle_experts))
         if c.state_layers or c.cca_layers:
             # Every slot's state and conv tail, live or not, read and

@@ -226,9 +226,9 @@ class LlamaGenerator:
 def _refuse_looped(config: llama.LlamaConfig, service: str):
     """``config``, unless ``service`` (which runs the layer stack once)
     is handed a looped stack (``loop_steps > 1``): then it raises, naming
-    itself (``models/looped.py``)."""
+    itself (``continuous_batching.refuse_one_pass``)."""
     if config.loop_steps > 1:
-        from ray_tpu.models import looped
+        from ray_tpu.models import continuous_batching
 
-        looped.refuse_service(config, service)
+        continuous_batching.refuse_one_pass(config, service)
     return config

@@ -106,8 +106,7 @@ class LlamaConfig:
     # 1 x ``mamba_d_head``, with ``mamba_d_state`` N and
     # ``mamba_d_conv`` as above. A slot keeps ``[N, d_inner]`` float32
     # and the conv's last inputs a layer (``paged_kv.StateCache``), and
-    # a long prompt's chunk goes on from them. The engine's programs
-    # of this family are ``mamba1.install``'s. 0 = every other model.
+    # a long prompt's chunk goes on from them. 0 = every other model.
     mamba_dt_rank: int = 0
     # ``position_embedding_type`` "nope": no rotation of q and k.
     rope: bool = True
@@ -485,6 +484,42 @@ def scaling_pairs(group: Optional[Dict[str, Any]]):
     return None if group is None else tuple(sorted(group.items()))
 
 
+def logical_axes(config: LlamaConfig) -> Params:
+    """Pytree of logical-axis tuples matching :func:`init_params`."""
+    layer = {
+        "attn_norm": ("layers", "norm"),
+        "wq": ("layers", "embed", "heads", "head_dim"),
+        "wk": ("layers", "embed", "kv_heads", "head_dim"),
+        "wv": ("layers", "embed", "kv_heads", "head_dim"),
+        "wo": ("layers", "heads", "head_dim", "embed"),
+        "mlp_norm": ("layers", "norm"),
+        "w_gate": ("layers", "embed", "mlp"),
+        "w_up": ("layers", "embed", "mlp"),
+        "w_down": ("layers", "mlp", "embed"),
+    }
+    if "eva_attention" in config.layer_types:
+        layer["eva_phi"] = ("layers", "kv_heads", "head_dim")
+        layer["eva_mu"] = ("layers", "kv_heads", "head_dim")
+    if config.qk_norm:
+        layer["q_norm"] = ("layers", None)
+        layer["k_norm"] = ("layers", None)
+    if config.num_experts:
+        for name in ("w_gate", "w_up", "w_down"):
+            del layer[name]
+        layer.update({
+            "w_router": ("layers", "embed", None),
+            "moe_gate": ("layers", "experts", "embed", "mlp"),
+            "moe_up": ("layers", "experts", "embed", "mlp"),
+            "moe_down": ("layers", "experts", "mlp", "embed"),
+        })
+    return {
+        "embed": ("vocab", "embed"),
+        "layers": layer,
+        "final_norm": ("norm",),
+        "lm_head": ("embed", "vocab"),
+    }
+
+
 def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     """Random init (normal / scaled), stacked over layers for lax.scan."""
     c = config
@@ -758,6 +793,111 @@ def _init_windowed_params(c: LlamaConfig, key: jax.Array) -> Params:
 
 
 EXPERT_KEYS = ("moe_gate", "moe_up", "moe_down")
+
+
+def _init_looped_params(c: LlamaConfig, key: jax.Array) -> Params:
+    """The Ouro family's tree (``loop_steps > 1``): :func:`init_params`'
+    homogeneous tree, ``layers`` stacked ``[L, ...]`` ONCE however many
+    times the stack is applied, with the two output norms a layer
+    (``post_attn_norm``, ``post_mlp_norm``) and, at the top, the exit
+    gate ``exit_gate_w [E]`` (normal at ``E ** -0.5``) and ``exit_gate_b``
+    (0). Seeded so that dropping a term shows: the four norms a layer and
+    the final norm, which stands between the steps too, are uniform in
+    0.5..1.5, not ones."""
+    if c.layer_types or c.num_experts or not c.sandwich_norms:
+        raise ValueError(
+            "loop_steps > 1 is the Ouro family's: a dense stack with "
+            "sandwich_norms and no layer_types")
+    out = init_params(dataclasses.replace(c, loop_steps=1), key)
+    L, E = c.num_layers, c.hidden_size
+    k = jax.random.split(jax.random.fold_in(key, 0x100b), 6)
+
+    def norm(key, *shape):
+        return jax.random.uniform(key, shape, jnp.float32, 0.5,
+                                  1.5).astype(c.dtype)
+
+    out["layers"].update(
+        attn_norm=norm(k[0], L, E), post_attn_norm=norm(k[1], L, E),
+        mlp_norm=norm(k[2], L, E), post_mlp_norm=norm(k[3], L, E))
+    out["final_norm"] = norm(k[4], E)
+    out["exit_gate_w"] = (jax.random.normal(k[5], (E,), jnp.float32)
+                          * E ** -0.5).astype(c.dtype)
+    out["exit_gate_b"] = jnp.zeros((), jnp.float32)
+    return out
+
+
+def _init_jamba_params(c: LlamaConfig, key: jax.Array) -> Params:
+    """The Jamba family's tree (``mamba_dt_rank > 0``), laid out as the
+    hybrids' is: ``embed`` (also the head), ``final_norm``, ``layers``
+    empty (a dense SwiGLU on every layer: no stacked experts) and
+    ``runs``: for each run of equal layers (:func:`layer_runs`) one tree
+    stacked over the run's layers, the two norms and the SwiGLU beside a
+    Mamba-1 mixer's weights (``mamba1.init_mixer``) or MQA attention's.
+    Seeded so that dropping a term shows: every norm's weight is uniform
+    in 0.5..1.5, not ones; the embedding is at ``E ** -0.5``, as
+    :func:`_init_hybrid_params` has it and for its reason."""
+    from ray_tpu.models import mamba1
+
+    if (c.num_experts or c.rope or not c.tie_word_embeddings
+            or set(c.layer_types) - {"mamba1", "attention"}):
+        raise ValueError(
+            "mamba_dt_rank > 0 is the Jamba family's: mamba1 and attention "
+            "layers, no positions, a dense MLP, a tied head")
+    E, M = c.hidden_size, c.intermediate_size
+    H, KV, D = c.num_heads, c.num_kv_heads, c.head_dim
+    k_embed, k_final, k_runs = jax.random.split(key, 3)
+
+    def dense(key, fan_in, *shape):
+        out = jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
+        return out.astype(c.dtype)
+
+    def norm(key, *shape):
+        return jax.random.uniform(key, shape, jnp.float32, 0.5,
+                                  1.5).astype(c.dtype)
+
+    runs = []
+    for r, (kind, _, n, _) in enumerate(layer_runs(c)):
+        k = jax.random.split(jax.random.fold_in(k_runs, r), 10)
+        tree = {
+            "attn_norm": norm(k[0], n, E), "mlp_norm": norm(k[1], n, E),
+            "w_gate": dense(k[2], E, n, E, M), "w_up": dense(k[3], E, n, E, M),
+            "w_down": dense(k[4], M, n, M, E),
+        }
+        if kind == "mamba1":
+            tree.update(mamba1.init_mixer(c, k[5], n))
+        else:
+            tree.update(wq=dense(k[6], E, n, E, H, D),
+                        wk=dense(k[7], E, n, E, KV, D),
+                        wv=dense(k[8], E, n, E, KV, D),
+                        wo=dense(k[9], H * D, n, H, D, E))
+        runs.append(tree)
+    return {
+        "embed": (jax.random.normal(k_embed, (c.vocab_size, E), jnp.float32)
+                  * E ** -0.5).astype(c.dtype),
+        "final_norm": norm(k_final, E), "layers": {}, "runs": runs,
+    }
+
+
+def truncated(config: LlamaConfig, params: Params,
+              num_layers: int) -> Tuple[LlamaConfig, Params]:
+    """First-``num_layers`` view of a model: (config, params) where the
+    layer stack is sliced to the leading ``num_layers`` and the embedding,
+    final norm, and lm_head are shared (same arrays, zero copies).
+
+    This is the speculative-decode self-drafter (EAGLE/Medusa-style
+    truncated-depth draft): because the sliced stack computes bitwise the
+    SAME layer-0..n-1 activations and K/V as the full model, the drafter
+    can read and write the target's own paged KV arena for those layers —
+    no second checkpoint, no separate draft arena."""
+    if not 1 <= num_layers <= config.num_layers:
+        raise ValueError(
+            f"truncated depth must be in [1, {config.num_layers}], "
+            f"got {num_layers}")
+    cfg = dataclasses.replace(config, num_layers=num_layers)
+    sliced = dict(params)
+    sliced["layers"] = jax.tree.map(lambda a: a[:num_layers],
+                                    params["layers"])
+    return cfg, sliced
 
 
 def split_layers(params: Params, num_layers: Optional[int] = None):
@@ -1339,144 +1479,3 @@ def num_params(config: LlamaConfig) -> int:
         + c.num_layers * per_layer
         + (c.loop_steps > 1) * (c.hidden_size + 1)      # the exit gate
     )
-
-
-def truncated(config: LlamaConfig, params: Params,
-              num_layers: int) -> Tuple[LlamaConfig, Params]:
-    """First-``num_layers`` view of a model: (config, params) where the
-    layer stack is sliced to the leading ``num_layers`` and the embedding,
-    final norm, and lm_head are shared (same arrays, zero copies).
-
-    This is the speculative-decode self-drafter (EAGLE/Medusa-style
-    truncated-depth draft): because the sliced stack computes bitwise the
-    SAME layer-0..n-1 activations and K/V as the full model, the drafter
-    can read and write the target's own paged KV arena for those layers —
-    no second checkpoint, no separate draft arena."""
-    if not 1 <= num_layers <= config.num_layers:
-        raise ValueError(
-            f"truncated depth must be in [1, {config.num_layers}], "
-            f"got {num_layers}")
-    cfg = dataclasses.replace(config, num_layers=num_layers)
-    sliced = dict(params)
-    sliced["layers"] = jax.tree.map(lambda a: a[:num_layers],
-                                    params["layers"])
-    return cfg, sliced
-
-
-def _init_looped_params(c: LlamaConfig, key: jax.Array) -> Params:
-    """The Ouro family's tree (``loop_steps > 1``): :func:`init_params`'
-    homogeneous tree, ``layers`` stacked ``[L, ...]`` ONCE however many
-    times the stack is applied, with the two output norms a layer
-    (``post_attn_norm``, ``post_mlp_norm``) and, at the top, the exit
-    gate ``exit_gate_w [E]`` (normal at ``E ** -0.5``) and ``exit_gate_b``
-    (0). Seeded so that dropping a term shows: the four norms a layer and
-    the final norm, which stands between the steps too, are uniform in
-    0.5..1.5, not ones."""
-    if c.layer_types or c.num_experts or not c.sandwich_norms:
-        raise ValueError(
-            "loop_steps > 1 is the Ouro family's: a dense stack with "
-            "sandwich_norms and no layer_types")
-    out = init_params(dataclasses.replace(c, loop_steps=1), key)
-    L, E = c.num_layers, c.hidden_size
-    k = jax.random.split(jax.random.fold_in(key, 0x100b), 6)
-
-    def norm(key, *shape):
-        return jax.random.uniform(key, shape, jnp.float32, 0.5,
-                                  1.5).astype(c.dtype)
-
-    out["layers"].update(
-        attn_norm=norm(k[0], L, E), post_attn_norm=norm(k[1], L, E),
-        mlp_norm=norm(k[2], L, E), post_mlp_norm=norm(k[3], L, E))
-    out["final_norm"] = norm(k[4], E)
-    out["exit_gate_w"] = (jax.random.normal(k[5], (E,), jnp.float32)
-                          * E ** -0.5).astype(c.dtype)
-    out["exit_gate_b"] = jnp.zeros((), jnp.float32)
-    return out
-
-
-def _init_jamba_params(c: LlamaConfig, key: jax.Array) -> Params:
-    """The Jamba family's tree (``mamba_dt_rank > 0``), laid out as the
-    hybrids' is: ``embed`` (also the head), ``final_norm``, ``layers``
-    empty (a dense SwiGLU on every layer: no stacked experts) and
-    ``runs``: for each run of equal layers (:func:`layer_runs`) one tree
-    stacked over the run's layers, the two norms and the SwiGLU beside a
-    Mamba-1 mixer's weights (``mamba1.init_mixer``) or MQA attention's.
-    Seeded so that dropping a term shows: every norm's weight is uniform
-    in 0.5..1.5, not ones; the embedding is at ``E ** -0.5``, as
-    :func:`_init_hybrid_params` has it and for its reason."""
-    from ray_tpu.models import mamba1
-
-    if (c.num_experts or c.rope or not c.tie_word_embeddings
-            or set(c.layer_types) - {"mamba1", "attention"}):
-        raise ValueError(
-            "mamba_dt_rank > 0 is the Jamba family's: mamba1 and attention "
-            "layers, no positions, a dense MLP, a tied head")
-    E, M = c.hidden_size, c.intermediate_size
-    H, KV, D = c.num_heads, c.num_kv_heads, c.head_dim
-    k_embed, k_final, k_runs = jax.random.split(key, 3)
-
-    def dense(key, fan_in, *shape):
-        out = jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
-        return out.astype(c.dtype)
-
-    def norm(key, *shape):
-        return jax.random.uniform(key, shape, jnp.float32, 0.5,
-                                  1.5).astype(c.dtype)
-
-    runs = []
-    for r, (kind, _, n, _) in enumerate(layer_runs(c)):
-        k = jax.random.split(jax.random.fold_in(k_runs, r), 10)
-        tree = {
-            "attn_norm": norm(k[0], n, E), "mlp_norm": norm(k[1], n, E),
-            "w_gate": dense(k[2], E, n, E, M), "w_up": dense(k[3], E, n, E, M),
-            "w_down": dense(k[4], M, n, M, E),
-        }
-        if kind == "mamba1":
-            tree.update(mamba1.init_mixer(c, k[5], n))
-        else:
-            tree.update(wq=dense(k[6], E, n, E, H, D),
-                        wk=dense(k[7], E, n, E, KV, D),
-                        wv=dense(k[8], E, n, E, KV, D),
-                        wo=dense(k[9], H * D, n, H, D, E))
-        runs.append(tree)
-    return {
-        "embed": (jax.random.normal(k_embed, (c.vocab_size, E), jnp.float32)
-                  * E ** -0.5).astype(c.dtype),
-        "final_norm": norm(k_final, E), "layers": {}, "runs": runs,
-    }
-
-
-def logical_axes(config: LlamaConfig) -> Params:
-    """Pytree of logical-axis tuples matching :func:`init_params`."""
-    layer = {
-        "attn_norm": ("layers", "norm"),
-        "wq": ("layers", "embed", "heads", "head_dim"),
-        "wk": ("layers", "embed", "kv_heads", "head_dim"),
-        "wv": ("layers", "embed", "kv_heads", "head_dim"),
-        "wo": ("layers", "heads", "head_dim", "embed"),
-        "mlp_norm": ("layers", "norm"),
-        "w_gate": ("layers", "embed", "mlp"),
-        "w_up": ("layers", "embed", "mlp"),
-        "w_down": ("layers", "mlp", "embed"),
-    }
-    if "eva_attention" in config.layer_types:
-        layer["eva_phi"] = ("layers", "kv_heads", "head_dim")
-        layer["eva_mu"] = ("layers", "kv_heads", "head_dim")
-    if config.qk_norm:
-        layer["q_norm"] = ("layers", None)
-        layer["k_norm"] = ("layers", None)
-    if config.num_experts:
-        for name in ("w_gate", "w_up", "w_down"):
-            del layer[name]
-        layer.update({
-            "w_router": ("layers", "embed", None),
-            "moe_gate": ("layers", "experts", "embed", "mlp"),
-            "moe_up": ("layers", "experts", "embed", "mlp"),
-            "moe_down": ("layers", "experts", "mlp", "embed"),
-        })
-    return {
-        "embed": ("vocab", "embed"),
-        "layers": layer,
-        "final_norm": ("norm",),
-        "lm_head": ("embed", "vocab"),
-    }

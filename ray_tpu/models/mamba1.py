@@ -1,9 +1,10 @@
 """The Mamba-1 token mixer of the Jamba family (``layer_types``
 "mamba1"; ``transformers`` calls the layer "mamba", which in this
 program means Mamba-2), as the engine runs it: a form for whole rows or
-for a CHUNK that goes on from a carried state and conv tail, a one-token
-form for the decode tick, and the two engine programs of a stack that
-holds such layers (:func:`install`).
+for a CHUNK that goes on from a carried state and conv tail, and a
+one-token form for the decode tick; the engine's two forwards dispatch
+to them where they meet the kind (``continuous_batching._forward_paged``,
+``_prefill_forward_paged``).
 
 Read from ``transformers`` 4.57 ``modeling_jamba.py``,
 ``JambaMambaMixer.slow_forward`` (lines 725-808), on the layer's normed
@@ -33,33 +34,15 @@ rounds it to the model's dtype before the product with C; this does not),
 and the convolution's last ``K - 1`` inputs, in the model's dtype. A
 prefill CHUNK that is not a prompt's first reads both from the slot's
 row, where the chunk before it left them.
-
-Why the engine's programs of this stack live HERE and not as branches of
-``continuous_batching._forward_paged``: those functions are on the traced
-stack of every other model's kernels, and a Mosaic kernel's serialized
-body (part of the compile-cache key) carries their source positions, so
-a line added there re-keys every program of every cell (PR 53). The
-constructor asks :func:`install` for the two programs, as it asks
-``looped.install``; the layer itself is written with the engine's own
-pieces (``_layer_qkv``, ``_write_then_attend``, ``_attn_out``,
-``_layer_finish``).
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
-from ray_tpu._private import xla_monitor
-from ray_tpu.models import continuous_batching as cb
-from ray_tpu.models import llama
 from ray_tpu.models.gated_delta import _next_tail
-from ray_tpu.models.inference import lm_head_logits
-from ray_tpu.models.paged_kv import GARBAGE_BLOCK, PagedKVCache, StateCache
 from ray_tpu.ops import selective_scan, ssm
-from ray_tpu.ops.attention import paged_chunk_attention
 
 F32 = jnp.float32
 
@@ -214,145 +197,3 @@ def mixer_step(h, layer, c, state_all, conv_all, index, use_kernel=None):
             state_all, index, u[:, 0], dt[:, 0], _a_t(layer), b[:, 0],
             cc[:, 0], use_kernel=use_kernel)
     return _gate_out(y[:, None], u, z, layer, c), state_all, conv_all
-
-
-# ---------------------------------------------------------------------------
-# The engine's two programs of a stack with Mamba-1 layers
-# ---------------------------------------------------------------------------
-
-def _scan_runs(layer_fn, x, arenas, held, runs):
-    """The stack, a RUN of equal layers a ``lax.scan``
-    (``llama.layer_runs``): ``layer_fn(carry, layer, kind, shift)`` with
-    the carry ``(x, arenas, held, global layer index)``, both caches
-    riding it; ``shift`` turns the global index into the layer's index
-    among layers of its kind."""
-    for (kind, start, _, kind_start), tree in runs:
-        (x, arenas, held, _), _ = jax.lax.scan(
-            functools.partial(layer_fn, kind=kind, shift=kind_start - start),
-            (x, arenas, held, jnp.int32(start)), tree)
-    return x, arenas, held
-
-
-def forward_paged(params, tokens, positions, tables, limits, caches,
-                  config: llama.LlamaConfig, use_kernel: bool):
-    """``continuous_batching._forward_paged`` for a stack of "mamba1"
-    and "attention" layers: each slot's ONE token ``[B, 1]`` at
-    ``positions [B, 1]``; a Mamba layer advances every slot's row of the
-    state cache in place, an attention layer (no positions: Jamba's
-    takes none) writes and attends its row of the arena. ``caches`` is
-    (arena, state cache). Returns (float32 logits ``[B, 1, V]``,
-    ``caches``)."""
-    c = config
-    cache, state = caches
-    bs = cache.block_size
-    x = cb._embed(params, tokens, c)
-    gathered = jnp.take_along_axis(tables, positions // bs, axis=1)
-    block_idx = jnp.where(positions < limits[:, None], gathered,
-                          GARBAGE_BLOCK)
-    offset = positions % bs
-    visits = cb._window_visits(tables, positions, limits, cache.k, use_kernel)
-    runs, _ = llama.layer_runs(c, params)
-
-    def layer_fn(carry, layer, kind, shift):
-        x, arenas, held, li = carry
-        ki = li + shift                 # index among layers of its kind
-        if kind == "mamba1":
-            h = llama.norm(x, layer["attn_norm"], c).astype(c.dtype)
-            mixed, *held = mixer_step(h, layer, c, *held, ki, use_kernel)
-            held = tuple(held)
-        else:
-            q, k, v, gate = cb._layer_qkv(x, layer, None, None, c)
-            o, arenas = cb._write_then_attend(
-                arenas, ki, q, k, v, block_idx, offset, tables, positions,
-                visits, c.attn_scale, use_kernel)
-            mixed = cb._attn_out(o.astype(x.dtype), layer, c, gate)
-        x, _, _ = cb._layer_finish(x, mixed, layer, c, None, li, use_kernel)
-        return (x, arenas, held, li + 1), None
-
-    x, arenas, held = _scan_runs(layer_fn, x, tuple(cache), tuple(state),
-                                 runs)
-    x = llama.norm(x, params["final_norm"], c)
-    return (lm_head_logits(x, params, c),
-            (type(cache)(*arenas), StateCache(*held)))
-
-
-def prefill_forward(params, tokens, caches, ptables, tables_w, last_idx,
-                    slots, config: llama.LlamaConfig, use_kernel=None):
-    """A prefill (or one chunk of it) of a stack of "mamba1" and
-    "attention" layers: ``tokens [N, S]`` behind each row's ``m`` earlier
-    blocks (``ptables [N, m]``: the prompt's earlier chunks; the prefix
-    cache is refused). A Mamba layer starts from the state and conv tail
-    in rows ``slots`` of the state cache when the chunk has earlier ones
-    (``m > 0``), from an empty history when it is the prompt's first,
-    and installs what it leaves there; an attention layer attends the
-    earlier keys blockwise where they lie, then its own, and lands its
-    K/V in its row of the arena through ``tables_w [N, S / bs]``. Both
-    caches ride the layer loop's carry. Returns (logits ``[N, 1, V]`` at
-    ``last_idx``, ``caches``)."""
-    c = config
-    cache, state = caches
-    bs = cache.block_size
-    m = ptables.shape[1]
-    x = cb._embed(params, tokens, c)
-    runs, _ = llama.layer_runs(c, params)
-    flat_w = tables_w.reshape(-1)
-
-    def layer_fn(carry, layer, kind, shift):
-        x, arenas, held, li = carry
-        ki = li + shift
-        if kind == "mamba1":
-            h = llama.norm(x, layer["attn_norm"], c).astype(c.dtype)
-            # A chunk that is not its prompt's first goes on from the
-            # rows the chunk before it installed.
-            carried = tuple(a[ki, slots] for a in held) if m else ()
-            mixed, *new = mixer_prefill(h, layer, c, last_idx + 1, *carried,
-                                        use_kernel=use_kernel)
-            held = tuple(a.at[ki, slots].set(n.astype(a.dtype))
-                         for a, n in zip(held, new))
-        else:
-            q, k, v, gate = cb._layer_qkv(x, layer, None, None, c)
-            o = paged_chunk_attention(q, k, v, arenas[0], arenas[1], ki,
-                                      ptables, 0, m * bs, c.attn_scale)
-            mixed = cb._attn_out(o, layer, c, gate)
-            arenas = tuple(
-                a.at[ki, flat_w].set(
-                    cb._ctx_to_blocks(new[None].astype(a.dtype), bs)[0])
-                for a, new in zip(arenas, (k, v)))
-        x, _, _ = cb._layer_finish(x, mixed, layer, c, None, li)
-        return (x, arenas, held, li + 1), None
-
-    x, arenas, held = _scan_runs(layer_fn, x, (cache.k, cache.v),
-                                 tuple(state), runs)
-    x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)  # [N, 1, E]
-    x = llama.norm(x, params["final_norm"], c)
-    return (lm_head_logits(x, params, c),
-            (PagedKVCache(*arenas), StateCache(*held)))
-
-
-def install(eng) -> None:
-    """Give a ``ContinuousBatcher`` whose stack has "mamba1" layers its
-    two programs, under the names, shape policies and donations of the
-    ones it has: called last in its constructor, so that the constructor
-    itself stays what it was for every other model."""
-    cfg, use_kernel, sampling = eng.config, eng.use_decode_kernel, eng.sampling
-
-    @xla_monitor.instrument(name="cb_prefill", shape_policy="bucketed",
-                            allowed_dims=tuple(eng._prefill.allowed_dims),
-                            donate_argnums=(2,))
-    def prefill(params, tokens, caches, ptables, tables_w, last_idx, pstep,
-                slots=None):
-        logits, caches = prefill_forward(
-            params, tokens, caches, ptables, tables_w, last_idx, slots, cfg,
-            use_kernel or None)
-        return cb._next_tokens(logits, pstep, sampling,
-                               salt=cb._PREFILL_SALT), caches
-
-    @xla_monitor.instrument(name="cb_tick", donate_argnums=(5,))
-    def tick(params, tokens, positions, tables, limits, caches, step):
-        logits, caches = forward_paged(
-            params, tokens[:, None], positions[:, None], tables, limits,
-            caches, cfg, use_kernel)
-        return (cb._next_tokens(logits, step, sampling), positions + 1,
-                caches, step + 1)
-
-    eng._prefill, eng._tick = prefill, tick

@@ -14,10 +14,11 @@ their families: they pin the wording a user of that family greps for.
 import dataclasses
 import functools
 
+import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import llama
+from ray_tpu.models import inference, llama
 from ray_tpu.models.continuous_batching import (_KIND_CANNOT, _KIND_NAMES,
                                                 ContinuousBatcher)
 
@@ -64,6 +65,9 @@ CONFIGS = {
     "cca_attention": lambda: llama.LlamaConfig.zaya1_8b(
         **_SMALL, num_layers=2, num_kv_heads=2, head_dim=16, num_experts=4,
         router_hidden_size=16),
+    # Not a layer kind: the whole stack applied four times.
+    "looped": lambda: llama.LlamaConfig.ouro_2_6b(
+        **_SMALL, num_layers=3, num_kv_heads=4, head_dim=16, loop_steps=4),
 }
 
 # How a caller asks the constructor for a capability, and what the
@@ -89,6 +93,15 @@ ASKED_OF_A_METHOD = {
                 ("reserve_import", (8, 4))],
     "score_logprobs": [("score_logprobs", ([1, 2], [3]))],
 }
+# The services outside the engine that run the layer stack once: each
+# refuses a looped stack itself, with the table's reason.
+ASKED_OF_A_SERVICE = {
+    "llama.forward": lambda c: llama.forward(
+        llama.init_params(c, jax.random.PRNGKey(0)),
+        jnp.zeros((1, 8), jnp.int32), c),
+    "LlamaGenerator": inference.LlamaGenerator,
+    "ExternalLlamaDrafter": inference.ExternalLlamaDrafter,
+}
 
 ENTRIES = [(kind, capability) for kind, cannot in _KIND_CANNOT.items()
            for capability in cannot]
@@ -104,14 +117,15 @@ def _engine(kind):
 def _names(err, kind, capability, called):
     said = str(err.value)
     assert called in said.split(" is not supported")[0], said
-    assert f"{_KIND_NAMES[kind]} layers (layer_types has {kind!r})" in said
+    assert (f"{_KIND_NAMES[kind]} (loop_steps > 1)" if kind == "looped" else
+            f"{_KIND_NAMES[kind]} layers (layer_types has {kind!r})") in said
     assert said.endswith(_KIND_CANNOT[kind][capability])
 
 
 def test_the_table_covers_the_kinds_the_engine_keeps_a_cache_for():
     assert set(_KIND_CANNOT) == set(_KIND_NAMES) == set(CONFIGS) == {
         *llama.STATE_KINDS, "sliding_attention", "latent_attention",
-        "eva_attention", "cca_attention"}
+        "eva_attention", "cca_attention", "looped"}
 
 
 @pytest.mark.parametrize("kind,capability", [
@@ -144,8 +158,22 @@ def test_a_live_engines_methods_refuse_every_entry(kind, capability, method,
     _names(err, kind, capability, method)
 
 
+@pytest.mark.parametrize("kind,service", [
+    e for e in ENTRIES if e[1] in ASKED_OF_A_SERVICE], ids=lambda v: v)
+def test_a_service_that_runs_the_stack_once_refuses_every_entry(kind,
+                                                                service):
+    with pytest.raises(NotImplementedError) as err:
+        ASKED_OF_A_SERVICE[service](CONFIGS[kind]())
+    said = str(err.value)
+    assert "loop_steps" in said
+    if service != "llama.forward":      # its message is the forward's own
+        assert said.startswith(f"{service} does not run")
+        assert _KIND_CANNOT[kind][service] in said
+
+
 def test_every_capability_of_the_table_is_asked_for_above():
-    askable = {"second_kind", *ASKED_OF_THE_CONSTRUCTOR, *ASKED_OF_A_METHOD}
+    askable = {"second_kind", *ASKED_OF_THE_CONSTRUCTOR, *ASKED_OF_A_METHOD,
+               *ASKED_OF_A_SERVICE}
     assert {c for _, c in ENTRIES} <= askable
 
 

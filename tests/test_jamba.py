@@ -279,7 +279,8 @@ def test_the_kernels_state_the_bytes_and_operations_they_move():
 # --------------------------------- the engine's two programs, by hand
 
 class Hand:
-    """``mamba1.prefill_forward`` and ``forward_paged`` driven by hand,
+    """The engine's two forwards (``cb._prefill_chunk_paged``,
+    ``cb._forward_paged``) driven by hand over a Mamba-1 stack,
     teacher-forced, for ONE sequence in slot 1 of 2: a prompt in chunks
     of ``chunk`` tokens (each goes on from the state, the conv tail and
     the arena's keys the one before it left), then a tick a token."""
@@ -290,10 +291,13 @@ class Hand:
         self.caches = (PagedKVCache.create(config, blocks, BS, "bf16"),
                        StateCache.create(config, 2))
         self.table = list(range(1, 17))
-        self._prefill = jax.jit(lambda *a: mamba1.prefill_forward(
-            *a, self.c, self.kernel or None))
-        self._tick = jax.jit(lambda *a: mamba1.forward_paged(
-            *a, self.c, self.kernel))
+        self._prefill = jax.jit(
+            lambda params, row, caches, ptables, *rest:
+            cb._prefill_chunk_paged(
+                params, row, ptables.shape[1] * BS + jnp.arange(row.shape[1]),
+                *caches, ptables, *rest, self.c, self.kernel))
+        self._tick = jax.jit(lambda *a: cb._forward_paged(
+            *a, self.c, self.kernel)[:2])
 
     def prefill(self, tokens, chunk):
         logits = None
@@ -302,7 +306,7 @@ class Hand:
             padded = -(-len(piece) // BS) * BS
             row = jnp.asarray([piece + [0] * (padded - len(piece))])
             m, own = first // BS, padded // BS
-            logits, self.caches = self._prefill(
+            logits, *self.caches = self._prefill(
                 self.params, row, self.caches,
                 jnp.asarray([self.table[:m]], jnp.int32).reshape(1, m),
                 jnp.asarray([self.table[m:m + own]], jnp.int32),

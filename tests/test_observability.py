@@ -202,6 +202,24 @@ def test_job_reconciler_spares_heartbeating_client(monkeypatch):
 # -------------------------------------------- e2e: workload -> dashboard
 
 
+def _forget_engine_series():
+    """Drop this PROCESS's samples that carry an ``engine`` tag. Every
+    ``ContinuousBatcher`` a test file built earlier on this xdist worker
+    left some forty series under a tag of its own, the driver's pusher
+    ships them all to the head, and the head's TSDB keeps 4096 series:
+    behind a few dozen engine-building files the cluster's own series
+    found no room and the cases below failed in a whole run while
+    passing alone."""
+    from ray_tpu.util import metrics
+
+    for metric in metrics.all_metrics():
+        with metric._lock:
+            for name in ("_values", "_counts", "_sums", "_totals"):
+                store = getattr(metric, name, None)
+                for key in [k for k in store or () if "engine" in dict(k)]:
+                    del store[key]
+
+
 @pytest.fixture(scope="module")
 def metrics_cluster():
     # Module-scoped: one multi-node cluster serves every e2e test below
@@ -209,6 +227,7 @@ def metrics_cluster():
     # headroom). Module scope rules out monkeypatch for the env knob.
     import os
 
+    _forget_engine_series()
     old = os.environ.get("RAY_TPU_METRICS_PUSH_INTERVAL_S")
     os.environ["RAY_TPU_METRICS_PUSH_INTERVAL_S"] = "0.25"
     if ray_tpu.is_initialized():

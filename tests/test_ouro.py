@@ -3,9 +3,10 @@
 (``benchmark/reference_ouro.py``), on the CPU at a small size: 3 layers
 applied 4 times at hidden 64, seeded weights, a float32 engine.
 
-The engine's two looped forwards are driven by hand, teacher-forced
-(:class:`Hand`: the host's half, tables and blocks, is done here), and
-their logits held to the reference's one full pass; then the engine
+The engine's two forwards are driven by hand over a looped stack,
+teacher-forced (:class:`Hand`: the host's half, tables and blocks, is
+done here), and their logits held to the reference's one full pass; then
+the engine
 itself, whose tokens must be the reference's argmax; then each named
 fault, switched on by patching, must FAIL that comparison; then what a
 looped stack refuses, by name; and that a config with ``loop_steps ==
@@ -61,12 +62,15 @@ def _close(got, want, rel=1e-4):
 
 
 class Hand:
-    """The engine's two looped forwards driven by hand, teacher-forced:
-    a prompt in chunks of ``chunk`` tokens (each attends the earlier ones
-    out of the arena, as a chunked prefill or a prefix hit does), then a
-    tick a token."""
+    """The engine's two forwards (``cb._prefill_chunk_paged``,
+    ``cb._forward_paged``) driven by hand over a looped stack,
+    teacher-forced: a prompt in chunks of ``chunk`` tokens (each attends
+    the earlier ones out of the arena, as a chunked prefill or a prefix
+    hit does; ``dense``: all its keys at once, as ``cb_prefill`` has it
+    under ``PREFILL_DENSE_KEYS`` of them, else blockwise), then a tick a
+    token."""
 
-    def __init__(self, config, params, kernel=False, blocks=64):
+    def __init__(self, config, params, kernel=False, blocks=64, dense=True):
         self.c, self.kernel = config, kernel
         self.params = llama.heads_major(params)
         self.cache = PagedKVCache.create(config, blocks, BS, "bf16")
@@ -76,9 +80,11 @@ class Hand:
         # One program a shape, as the engine's ``cb_prefill`` and
         # ``cb_tick`` are; traced by THIS hand, after any fault is patched in.
         self._prefill = jax.jit(
-            lambda *a: looped.prefill_forward(*a, self.c))
+            lambda params, tokens, positions, cache, *tables:
+            cb._prefill_chunk_paged(params, tokens, positions, cache, None,
+                                    *tables, None, self.c, False, dense)[:2])
         self._tick = jax.jit(
-            lambda *a: looped.forward_paged(*a, self.c, self.kernel))
+            lambda *a: cb._forward_paged(*a, self.c, self.kernel))
 
     def prefill(self, prompt, chunk=None, shared=()):
         """``shared``: blocks another prompt with the same first tokens
@@ -113,20 +119,21 @@ class Hand:
                 blocks.append(self.free.pop())
             tables[i] = blocks + [blocks[-1]] * (self.width - len(blocks))
             limits[i] = len(blocks) * BS
-        logits, self.cache, gates = self._tick(
+        logits, self.cache, bits = self._tick(
             self.params, jnp.asarray([[t] for t, _, _ in rows]),
             jnp.asarray([[p] for _, p, _ in rows]), jnp.asarray(tables),
             jnp.asarray(limits), self.cache)
-        self.gates.append(np.asarray(gates[:, :, 0]))
+        # What the tick's row carries behind its tokens: the gates' bits.
+        self.gates.append(np.asarray(bits[:, :, 0]).view(np.float32))
         return np.asarray(logits[:, 0])
 
 
 def _held_to_reference(config, params, lengths, kernel=False, chunk=None,
-                       ref_config=None, ref_params=None):
+                       ref_config=None, ref_params=None, dense=True):
     """Prefill, then ``TICKS`` teacher-forced ticks of all rows together,
     each row's logits and gates against the reference's full pass."""
     seqs = _prompts([n + TICKS + 1 for n in lengths], seed=7)
-    hand = Hand(config, params, kernel)
+    hand = Hand(config, params, kernel, dense=dense)
     got, rows = [], []
     for seq, n in zip(seqs, lengths):
         first, blocks = hand.prefill(seq[:n], chunk)
@@ -201,15 +208,13 @@ def test_prefill_then_ticks_give_the_references_logits(model, kernel,
     _held_to_reference(config, params, (13, 16, 17), kernel)
 
 
-@pytest.mark.parametrize("dense_keys", [1024, 8],
+@pytest.mark.parametrize("dense", [True, False],
                          ids=["scores-at-once", "blockwise"])
-def test_a_prompt_in_chunks_reads_each_steps_own_rows(model, monkeypatch,
-                                                      dense_keys):
+def test_a_prompt_in_chunks_reads_each_steps_own_rows(model, dense):
     """A chunk of 8 under prompts of 21 and 24: step t of chunks 2 and 3
     reads step t's rows of the chunks before, in both prefill forms."""
     config, params = model
-    monkeypatch.setattr(cb, "PREFILL_DENSE_KEYS", dense_keys)
-    _held_to_reference(config, params, (21, 24), chunk=8)
+    _held_to_reference(config, params, (21, 24), chunk=8, dense=dense)
 
 
 def test_a_shared_first_block_is_read_in_all_its_rows(model):
@@ -390,8 +395,8 @@ NAMED = {"kv_dtype": "kv_dtype='int8'", "speculative": "speculative decoding",
 
 
 def test_the_table_and_the_cases_are_one_list():
-    assert set(SERVICES) == set(looped.LOOP_CANNOT)
-    assert not set(looped.LOOP_CANNOT) & {"prefix_cache"}
+    assert set(SERVICES) == set(cb._KIND_CANNOT["looped"])
+    assert not set(cb._KIND_CANNOT["looped"]) & {"prefix_cache"}
 
 
 @pytest.mark.parametrize("service", list(SERVICES))
@@ -404,7 +409,7 @@ def test_a_service_that_runs_one_pass_refuses_by_name(service):
     assert NAMED.get(service, service) in said
     assert "loop" in said
     if service != "llama.forward":      # its message is the forward's own
-        assert looped.LOOP_CANNOT[service] in said
+        assert cb._KIND_CANNOT["looped"][service] in said
 
 
 def test_loss_fn_refuses_with_the_forward(model):
@@ -428,8 +433,8 @@ def _tick_text(config, params=None, **how):
 
 def test_one_step_builds_what_a_config_without_the_key_builds():
     """``loop_steps == 1`` is every model so far: the same config object,
-    tree, arena and programs, and the loop's module leaves the engine as
-    it was."""
+    tree, arena and programs: the loop in the engine's forwards runs
+    once and leaves no trace in them."""
     plain = llama.LlamaConfig.tiny(dtype=jnp.float32)
     one = llama.LlamaConfig.tiny(dtype=jnp.float32, loop_steps=1)
     assert plain == one and hash(plain) == hash(one)
