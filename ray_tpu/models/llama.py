@@ -1102,8 +1102,8 @@ def _swiglu(h, w_gate, w_up, w_down, c: LlamaConfig,
 # that are gathered, never multiplied, and thrown away: a longer batch
 # runs the block a piece of its tokens at a time. (8 x 1024 tokens of
 # Kimi K2's 7168 at top 8 are 940 MB, beside as much again for the
-# results and twice that for their float32 sum; Trinity's 201 MB stay one
-# piece.)
+# results and again for their copy in top-k order, which the weighted
+# sum reads; Trinity's 201 MB stay one piece.)
 ROUTED_SORT_BYTES = 256 << 20
 
 
@@ -1165,6 +1165,12 @@ def mlp_block(h, layer, c: LlamaConfig, experts=None, li=None,
             out, routed = jax.lax.map(block, h.reshape(pieces, -1, e))
             routed = moe.Routed(routed.rows.sum(axis=0),
                                 routed.experts.reshape(b * s, -1))
+        if c.shared_intermediate_size:
+            # The routed sum stands as an array of its own: left free,
+            # XLA fuses it into the epilogue of the shared down
+            # projection, where its gather runs at the matmul's tiling
+            # (PERF.md, PR 58).
+            out = jax.lax.optimization_barrier(out)
         out = out.reshape(b, s, e)
         if c.shared_intermediate_size:
             # GraniteMoeHybridDecoderLayer.forward: moe(h) + shared_mlp(h),

@@ -506,7 +506,9 @@ def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
     are TWO grouped calls on one schedule: :func:`grouped_swiglu` (gate
     and up: the activation from float32 accumulators, rounded once to
     ``x.dtype``; no ``[T * k, M]`` gate or up array exists), then
-    :func:`grouped_matmul` (down), at :func:`_gmm_tiles`' tiles.
+    :func:`grouped_matmul` (down), at :func:`_gmm_tiles`' tiles. The
+    weighted sum is float32, in the token's own top-k order, rounded
+    once; no float32 copy of the assignments exists.
 
     ``held = (first, count)``: of the router's X experts, ``experts``
     holds ``[first, first + count)`` (``[L, count, ...]``): the chip's
@@ -548,13 +550,19 @@ def routed_block(x: jnp.ndarray, w_router: jnp.ndarray,
             ys = grouped_matmul(act, experts["moe_down"], rows, layer,
                                 use_kernel=use_kernel)
         with jax.named_scope("combine"):
-            # Back to [T, k, E] in each token's own top-k order, so the
-            # weighted sum adds the same terms in the same order whoever
-            # else is in the batch.
+            # ONE k-major gather of the rows as the dtype they have, the
+            # slabs widened, weighted and added in float32 in top-k
+            # order: the same terms in the same order whoever else is in
+            # the batch. Not a gather a ``j``: unrolled gathers grow every
+            # program's load time (PERF.md, PR 58).
             place = jnp.zeros_like(order).at[order].set(
-                jnp.arange(order.shape[0], dtype=order.dtype))
-            mine = ys[place].reshape(t, top_k, -1).astype(jnp.float32)
-            if held is not None:
-                mine = jnp.where(here[..., None], mine, 0.0)
-            out = jnp.einsum("tk,tke->te", weights, mine)
+                jnp.arange(order.shape[0], dtype=order.dtype)
+            ).reshape(t, top_k)
+            mine = ys[place.T.reshape(-1)].reshape(top_k, t, -1)
+            out = jnp.zeros((t, ys.shape[-1]), jnp.float32)
+            for j in range(top_k):
+                term = weights[:, j, None] * mine[j].astype(jnp.float32)
+                if held is not None:
+                    term = jnp.where(here[:, j, None], term, 0.0)
+                out = out + term
     return out.astype(x.dtype), Routed(rows, idx)

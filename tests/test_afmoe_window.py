@@ -189,16 +189,56 @@ def test_absent_assignments_never_read_an_unwritten_row(pallas_interpret):
     assert np.array_equal(np.asarray(out), np.zeros_like(out))
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_a_held_shares_sum_selects_unwritten_rows_away(monkeypatch, dtype):
+    """Trinity's share in small (top 4, 4 of 16 experts held, so three
+    assignments in four are absent) with every row the grouped
+    multiplication did not write made NaN: the weighted sum still reads
+    one row an assignment, and an absent one is SELECTED away (0 x NaN
+    would be NaN), so the result is finite and is the sum with those
+    rows zero; a token none of whose experts is held gets exact zeros."""
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(size=(40, 32)), dtype)
+    w_router = jnp.asarray(rng.normal(size=(32, 16)) / 32 ** 0.5, jnp.float32)
+    share = {k: v.astype(dtype) for k, v in _experts(rng, 4, 32, 16).items()}
+    route = lambda *a: moe.route_sigmoid_topk(  # noqa: E731
+        *a, bias=jnp.zeros(16), scale=2.448)
+    block = lambda: moe.routed_block(  # noqa: E731
+        x, w_router, share, 0, top_k=4, norm_topk=True, route=route,
+        held=(8, 4))
+    clean, routed = block()
+    written = int(routed.rows.sum())
+    assert 0 < written < 40 * 4 // 2
+    matmul = moe.grouped_matmul
+
+    def poisoned(lhs, rhs, sizes, layer=None, **kw):
+        ys = matmul(lhs, rhs, sizes, layer, **kw)
+        return jnp.where(jnp.arange(ys.shape[0])[:, None] < sizes.sum(),
+                         ys, jnp.nan)
+
+    monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+    out, _ = block()
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    assert np.array_equal(np.asarray(out, np.float32),
+                          np.asarray(clean, np.float32))
+    experts = np.asarray(routed.experts)
+    nothing_held = ~((experts >= 8) & (experts < 12)).any(axis=1)
+    assert nothing_held.any()
+    assert not np.asarray(out, np.float32)[nothing_held].any()
+
+
 def test_held_none_and_a_softmax_router_are_the_parents_block():
-    """``held=None`` with the default router runs the ops PR 25 wrote:
-    the same jaxpr as the block with its new arguments left out."""
+    """``held=None`` with the default router is the block with its new
+    arguments left out, bit for bit, and computes what the ops PR 25
+    wrote compute (their weighted sum an einsum over a float32 copy:
+    the same terms, so equal to float32 summation order)."""
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(size=(12, 32)), jnp.float32)
     w_router = jnp.asarray(rng.normal(size=(32, 8)), jnp.float32)
     experts = _experts(rng, 8, 32, 16)
 
-    def parent(x, w, e):
-        """``routed_block`` as the parent commit has it."""
+    def pr25(x, w, e):
         t, top_k = x.shape[0], 2
         weights, idx = moe.route_softmax_topk(x, w, top_k, False)
         flat = idx.reshape(-1)
@@ -215,8 +255,12 @@ def test_held_none_and_a_softmax_router_are_the_parents_block():
                               jnp.float32)).astype(x.dtype)
 
     new = moe.routed_block(x, w_router, experts, 0, top_k=2)[0]
-    assert np.array_equal(np.asarray(new),
-                          np.asarray(parent(x, w_router, experts)))
+    spelled = moe.routed_block(x, w_router, experts, 0, top_k=2, held=None,
+                               route=moe.route_softmax_topk, routing=None)[0]
+    assert np.array_equal(np.asarray(new), np.asarray(spelled))
+    np.testing.assert_allclose(np.asarray(new),
+                               np.asarray(pr25(x, w_router, experts)),
+                               rtol=1e-5, atol=1e-6)
 
 
 # ------------------------------------------------- the window's kernels

@@ -125,6 +125,137 @@ def test_routed_block_computes_every_assignment(tokens, use_kernel):
         np.bincount(np.asarray(routed.experts).ravel(), minlength=8))
 
 
+def _combine_case(top_k, held, dtype, tokens=24, seed=0):
+    """Operands of one routed layer over 16 experts, E = 64, M = 32:
+    ``held`` a ``(first, count)`` share of them or None."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    _, count = held or (0, 16)
+    x = jax.random.normal(keys[0], (tokens, 64)).astype(dtype)
+    w_router = jax.random.normal(keys[1], (64, 16))
+    experts = {
+        "moe_gate": (jax.random.normal(keys[2], (count, 64, 32)) / 8
+                     ).astype(dtype),
+        "moe_up": (jax.random.normal(keys[3], (count, 64, 32)) / 8
+                   ).astype(dtype),
+        "moe_down": (jax.random.normal(keys[4], (count, 32, 64)) / 6
+                     ).astype(dtype)}
+    return x, w_router, experts
+
+
+def _plain_combine(x, w_router, experts, top_k, held):
+    """``sum_j w[t, j] * float32(ys_j)`` in ``j`` order with numpy, absent
+    assignments skipped, rounded once: the sorted rows and the grouped
+    calls are the block's own, the sum is not."""
+    t = x.shape[0]
+    weights, idx = moe.route_softmax_topk(x, w_router, top_k)
+    weights, flat = np.asarray(weights), np.asarray(idx).reshape(-1)
+    first, count = held or (0, w_router.shape[1])
+    here = (flat >= first) & (flat < first + count)
+    flat = np.where(here, flat - first, count)
+    order = np.argsort(flat, kind="stable")
+    rows = jnp.asarray(np.bincount(flat, minlength=count + 1)[:count],
+                       jnp.int32)
+    act = moe.grouped_swiglu(x[order // top_k], experts["moe_gate"],
+                             experts["moe_up"], rows)
+    ys = np.asarray(moe.grouped_matmul(act, experts["moe_down"], rows),
+                    np.float32)
+    place = np.empty_like(order)
+    place[order] = np.arange(order.size)
+    place, here = place.reshape(t, top_k), here.reshape(t, top_k)
+    out = np.zeros((t, ys.shape[1]), np.float32)
+    for j in range(top_k):
+        out = out + np.where(here[:, j, None],
+                             weights[:, j, None] * ys[place[:, j]], 0)
+    return np.asarray(jnp.asarray(out).astype(x.dtype), np.float32)
+
+
+COMBINE_CASES = [(1, None), (4, (4, 6)), (8, None), (10, (2, 9))]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("top_k,held", COMBINE_CASES,
+                         ids=["top1", "top4_held", "top8", "top10_held"])
+def test_combine_is_the_float32_sum_in_topk_order_rounded_once(
+        top_k, held, dtype):
+    """The weighted sum over a token's assignments, top 1 (no sum) to top
+    10, whole and as a held share (most assignments absent): float32
+    products added in the token's own order and rounded ONCE to the
+    block's dtype, an absent assignment adding nothing; and a token's
+    result is the same bits when every other row of the batch is
+    replaced."""
+    x, w_router, experts = _combine_case(top_k, held, dtype)
+    block = jax.jit(lambda x: moe.routed_block(
+        x, w_router, experts, top_k=top_k, held=held)[0])
+    got = np.asarray(block(x), np.float32)
+    want = _plain_combine(x, w_router, experts, top_k, held)
+    if dtype == jnp.float32:
+        # the same products; a fused multiply-add may round a sum's last bit
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    else:
+        # one rounding to bfloat16: that last bit can move a result
+        # across a rounding boundary, one step (2 ** -8 relative), rarely
+        np.testing.assert_allclose(got, want, rtol=2.0 ** -7, atol=1e-6)
+        assert np.mean(got == want) > 0.99
+    others = jax.random.normal(jax.random.PRNGKey(7), x.shape).astype(dtype)
+    mixed = jnp.concatenate([x[:5], others[5:]])
+    np.testing.assert_array_equal(np.asarray(block(mixed), np.float32)[:5],
+                                  got[:5])
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            if hasattr(value, "jaxpr"):
+                yield from _eqns(value.jaxpr)
+
+
+@pytest.mark.parametrize("top_k,held", COMBINE_CASES[1:],
+                         ids=["top4_held", "top8", "top10_held"])
+def test_routed_block_holds_no_float32_copy_of_the_assignments(top_k, held):
+    """No value of ``routed_block``'s jaxpr is a float32 array of
+    ``T * k * E`` elements (the ``[T, k, E]`` copy the weighted sum
+    once made of ``ys``, twice its bytes): every value of that size
+    (the sorted rows, the results, the results in top-k order) is
+    bfloat16, as the block's input is."""
+    x, w_router, experts = _combine_case(top_k, held, jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda x: moe.routed_block(
+        x, w_router, experts, top_k=top_k, held=held)[0])(x)
+    size = x.shape[0] * top_k * x.shape[1]
+    big = [v.aval for eqn in _eqns(jaxpr.jaxpr) for v in eqn.outvars
+           if getattr(v.aval, "size", 0) == size]
+    assert len(big) >= 2 and {a.dtype for a in big} == {
+        jnp.dtype(jnp.bfloat16)}, big
+
+
+@pytest.mark.parametrize("shared", [0, 48],
+                         ids=["no_shared_expert", "shared_expert"])
+def test_a_shared_experts_sum_meets_the_routed_sum_behind_a_barrier(shared):
+    """Where a shared expert's output is added, the routed sum is an
+    array of its own behind ONE optimisation barrier, taken as the block
+    returns it, ``[T, E]``: left free, XLA fuses the sum's gather into
+    the shared down projection's epilogue, and behind the reshape to
+    ``[B, S, E]`` the barrier compiles to float32 slabs (PERF.md, PR 58).
+    A layer with no shared expert has no barrier: its sum may fuse into
+    the residual's addition."""
+    x, w_router, experts = _combine_case(4, None, jnp.bfloat16)
+    config = tiny(hidden_size=64, num_experts=16, num_experts_per_tok=4,
+                  shared_intermediate_size=shared, dtype=jnp.bfloat16)
+    layer = {"w_router": w_router}
+    if shared:
+        layer.update(
+            shared_gate=jnp.ones((64, shared), jnp.bfloat16),
+            shared_up=jnp.ones((64, shared), jnp.bfloat16),
+            shared_down=jnp.ones((shared, 64), jnp.bfloat16))
+    jaxpr = jax.make_jaxpr(lambda h: llama.mlp_block(
+        h, layer, config, experts)[0])(x.reshape(2, 12, 64))
+    barred = [eqn.invars[0].aval.shape for eqn in _eqns(jaxpr.jaxpr)
+              if eqn.primitive.name == "optimization_barrier"]
+    assert barred == ([(24, 64)] if shared else [])
+
+
 # The fused gate-and-up call: the four configurations' (K, N) an eighth
 # (Kimi: a sixteenth) as wide, with what a schedule can meet. ``sizes``
 # are the groups' rows in order; ``rows`` the row count (past the
@@ -223,12 +354,8 @@ def test_grouped_swiglu_kernel_and_ragged_dot_agree(case):
 
 
 def _pallas_calls(jaxpr):
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn
-        for value in eqn.params.values():
-            if hasattr(value, "jaxpr"):
-                yield from _pallas_calls(value.jaxpr)
+    return (eqn for eqn in _eqns(jaxpr)
+            if eqn.primitive.name == "pallas_call")
 
 
 @pytest.mark.parametrize("tm,tiles_m", [(128, 5), (16, 35)])
